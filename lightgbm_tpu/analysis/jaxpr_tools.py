@@ -60,7 +60,7 @@ class RefInfo:
     role: str          # "scalar" | "in" | "out" | "scratch"
     shape: tuple
     dtype: str
-    space: str         # "smem" | "vmem" | "any" | "semaphore" | "?"
+    space: str         # "smem" | "vmem" | "hbm" | "any" | "semaphore" | "?"
 
     @property
     def nbytes(self) -> int:
@@ -92,7 +92,9 @@ class PallasCallInfo:
                 if r.space == "vmem" and r.role in roles]
 
     def any_refs(self) -> List[RefInfo]:
-        return [r for r in self.refs if r.space == "any"]
+        """Unblocked refs the kernel DMAs by hand: ``pltpu.HBM`` and
+        ``pl.ANY`` (which Mosaic places in HBM) alike."""
+        return [r for r in self.refs if r.space in ("hbm", "any")]
 
 
 def _space_of(aval) -> str:
@@ -100,7 +102,7 @@ def _space_of(aval) -> str:
     s = str(ms).lower() if ms is not None else ""
     if "sem" in s:
         return "semaphore"
-    for name in ("smem", "vmem", "any"):
+    for name in ("smem", "vmem", "hbm", "any"):
         if name in s:
             return name
     # blocked BlockSpecs without an explicit space land in VMEM

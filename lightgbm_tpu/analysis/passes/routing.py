@@ -352,8 +352,9 @@ def _check_matrix(ctx) -> List[Finding]:
         # rule that cost it — and serve_forest_overwide is a PURE
         # SHAPE rule, valid only on cells whose key carries the
         # over-wide forest fact (ow=1).  This is the static proof of
-        # the ~2MB engagement rule: fitting forests on the TPU backend
-        # under default knobs MUST ride the kernel.
+        # the ~2MB engagement rule: a fitting forest whose kernel was
+        # asked for (LGBM_TPU_SERVE_KERNEL=1 on the TPU backend, or
+        # the interpret seam) MUST ride the kernel.
         if ppath == "compiled" and not pkernel and not kreasons:
             out.append(Finding(
                 pass_name=PASS_NAME,

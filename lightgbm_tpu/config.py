@@ -222,7 +222,10 @@ ENV_KNOBS: Dict[str, tuple] = {
                                     "interpreter off-TPU"),
     "LGBM_TPU_COMB_PACK": ("1", "2 packs two logical comb rows per "
                                 "128-lane line (half the partition DMA "
-                                "bytes per logical row)"),
+                                "bytes per logical row); refused by "
+                                "the v5e compiler on jax 0.9.0 as of "
+                                "PR 22 (Unsupported target bitwidth "
+                                "for truncation)"),
     "LGBM_TPU_COMB_DT": ("f32", "bf16 stores the physical comb matrix "
                                 "in bf16 (blocked by Mosaic tiling "
                                 "today; profile_partition records "
@@ -257,24 +260,32 @@ ENV_KNOBS: Dict[str, tuple] = {
                                "TraceAnnotations and bench records "
                                "gain a device block; decode with "
                                "obs attr"),
-    "LGBM_TPU_PEAK_BW_GBPS": ("819", "roofline HBM peak for obs report "
-                                     "--roofline (v5e default)"),
-    "LGBM_TPU_PEAK_TFLOPS": ("197", "roofline compute peak for obs "
-                                    "report --roofline (v5e bf16 "
-                                    "default)"),
-    "LGBM_TPU_VMEM_GEN": ("v5e", "TPU generation whose VMEM size the "
-                                 "static analyzer's vmem-budget pass "
-                                 "prices kernels against (v4 / v5e / "
-                                 "v5p)"),
+    "LGBM_TPU_PEAK_BW_GBPS": ("auto", "roofline HBM peak in GB/s for "
+                                      "obs report --roofline; auto = "
+                                      "the record's device_kind in "
+                                      "costmodel.DEVICE_KINDS (an "
+                                      "unknown kind is an error)"),
+    "LGBM_TPU_PEAK_TFLOPS": ("auto", "roofline compute peak in TFLOP/s "
+                                     "for obs report --roofline; auto "
+                                     "= the record's device_kind"),
+    "LGBM_TPU_VMEM_GEN": ("auto", "TPU generation whose VMEM size the "
+                                  "static analyzer's vmem-budget pass "
+                                  "prices kernels against (v4 / v5e / "
+                                  "v5p); auto = the live TPU's "
+                                  "device_kind (unknown kinds are an "
+                                  "error), off-chip the v5e analysis "
+                                  "target"),
     "LGBM_TPU_VMEM_LIMIT_MB": ("off", "absolute per-kernel VMEM "
                                       "budget in MiB for python -m "
                                       "lightgbm_tpu.analysis "
                                       "(overrides the per-generation "
                                       "size minus compiler reserve)"),
-    "LGBM_TPU_HBM_GEN": ("v5e", "TPU generation whose HBM size the "
-                                "footprint model (obs mem) and the "
-                                "analyzer's hbm-budget pass price "
-                                "residency against (v4 / v5e / v5p)"),
+    "LGBM_TPU_HBM_GEN": ("auto", "TPU generation whose HBM size the "
+                                 "footprint model (obs mem) and the "
+                                 "analyzer's hbm-budget pass price "
+                                 "residency against (v4 / v5e / v5p); "
+                                 "auto = the live TPU's device_kind, "
+                                 "off-chip the v5e analysis target"),
     "LGBM_TPU_HBM_LIMIT_GB": ("off", "absolute per-chip HBM budget in "
                                      "GiB for obs mem and python -m "
                                      "lightgbm_tpu.analysis (overrides "
@@ -364,16 +375,18 @@ ENV_KNOBS: Dict[str, tuple] = {
                                "predict_decide rules)"),
     "LGBM_TPU_SERVE_KERNEL": ("auto", "VMEM-resident Pallas serving "
                                       "traversal (ops/pallas/"
-                                      "serve_kernel.py): auto engages "
-                                      "when the stacked forest fits "
-                                      "the layout.serve_forest_fit "
-                                      "VMEM cap (over-wide forests "
-                                      "fall back to the XLA gather "
-                                      "walk via the loud "
-                                      "serve_forest_overwide routing "
-                                      "rule), 1 makes that fallback "
-                                      "warn, 0 keeps every dispatch "
-                                      "on the XLA gather walk"),
+                                      "serve_kernel.py): refused by "
+                                      "the v5e compiler on jax 0.9.0 "
+                                      "as of PR 22 (Only 2D gather is "
+                                      "supported), so auto and 0 keep "
+                                      "every dispatch on the XLA "
+                                      "gather walk; only 1 asks for "
+                                      "the kernel (TPU backend, forest "
+                                      "inside the layout."
+                                      "serve_forest_fit VMEM cap, else "
+                                      "the loud serve_forest_overwide "
+                                      "fallback) and fails with the "
+                                      "compiler's error"),
     "LGBM_TPU_SERVE_INTERP": ("off", "kernel runs the REAL serving "
                                      "traversal kernel body through "
                                      "the Pallas interpreter off-TPU "

@@ -36,18 +36,48 @@ one-hot contractions (2 flops per MAC), good to the leading term.
 from __future__ import annotations
 
 import os
+import sys
 from typing import Any, Dict, List, Optional
 
 LANE = 128          # ops/pallas/layout.py contract (no jax import here)
 HIST_CH = 2         # grad / hess histogram channels
 F32 = 4             # histogram accumulator width (always f32)
 
-# roofline peaks: v5e-class defaults, overridable per run (env) or per
-# report (--peak-bw / --peak-tflops)
+# What is known of a chip, keyed by the ``device_kind`` JAX reports and
+# a record's provenance block carries: its generation (the key of the
+# VMEM/HBM size tables below) and its roofline peaks.  Source: Google
+# Cloud documentation, "TPU v5e" (819 GB/s HBM, 197 TFLOP/s bf16).  A
+# kind that is not in the table is an error, never a v5e default; a
+# roof can still be named explicitly per run (env) or per report
+# (--peak-bw / --peak-tflops).
 PEAK_BW_ENV = "LGBM_TPU_PEAK_BW_GBPS"
 PEAK_TFLOPS_ENV = "LGBM_TPU_PEAK_TFLOPS"
-DEFAULT_PEAK_BW_GBPS = 819.0     # TPU v5e HBM bandwidth
-DEFAULT_PEAK_TFLOPS = 197.0      # TPU v5e bf16 MXU peak
+DEVICE_KINDS = {
+    "TPU v5 lite": {"gen": "v5e", "bw_gbps": 819.0, "tflops": 197.0},
+}
+# off-chip (the static analyzer, CPU tests) the VMEM/HBM budgets
+# describe the chip this repo is built for, not the host
+ANALYSIS_TARGET_GEN = "v5e"
+
+
+def device_generation() -> str:
+    """Generation the VMEM/HBM budgets are priced for: the live TPU's,
+    by its ``device_kind``; off-chip, the analysis target."""
+    jax = sys.modules.get("jax")   # no jax import from a cost model
+    dev = jax.devices()[0] if jax is not None else None
+    if dev is None or dev.platform != "tpu":
+        return ANALYSIS_TARGET_GEN
+    if dev.device_kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"unknown TPU device_kind {dev.device_kind!r}: add it to "
+            f"costmodel.DEVICE_KINDS (known: {sorted(DEVICE_KINDS)}) or "
+            f"set {VMEM_GEN_ENV}/{HBM_GEN_ENV}")
+    return DEVICE_KINDS[dev.device_kind]["gen"]
+
+
+def _generation(gen: Optional[str], env_name: str) -> str:
+    g = gen or os.environ.get(env_name)
+    return (device_generation() if g in (None, "", "auto") else g).lower()
 
 # ---------------------------------------------------------------------
 # VMEM budget (the static analyzer's vmem-budget pass, ISSUE 7).
@@ -62,7 +92,6 @@ DEFAULT_PEAK_TFLOPS = 197.0      # TPU v5e bf16 MXU peak
 # ---------------------------------------------------------------------
 VMEM_GEN_ENV = "LGBM_TPU_VMEM_GEN"
 VMEM_LIMIT_ENV = "LGBM_TPU_VMEM_LIMIT_MB"
-DEFAULT_VMEM_GEN = "v5e"
 VMEM_BYTES_BY_GEN = {
     "v4": 128 << 20,
     "v5e": 128 << 20,
@@ -72,9 +101,9 @@ VMEM_RESERVE_FRACTION = 0.25     # compiler headroom below physical
 
 
 def vmem_generation_bytes(gen: Optional[str] = None):
-    """(physical VMEM bytes, generation name) for ``gen`` or the
-    LGBM_TPU_VMEM_GEN / default generation."""
-    g = (gen or os.environ.get(VMEM_GEN_ENV, DEFAULT_VMEM_GEN)).lower()
+    """(physical VMEM bytes, generation name) for ``gen``, else
+    LGBM_TPU_VMEM_GEN, else the device's generation."""
+    g = _generation(gen, VMEM_GEN_ENV)
     if g not in VMEM_BYTES_BY_GEN:
         raise ValueError(
             f"unknown TPU generation {g!r} for the VMEM budget; known: "
@@ -112,7 +141,6 @@ def buffer_bytes(shape, itemsize: int) -> int:
 # ---------------------------------------------------------------------
 HBM_GEN_ENV = "LGBM_TPU_HBM_GEN"
 HBM_LIMIT_ENV = "LGBM_TPU_HBM_LIMIT_GB"
-DEFAULT_HBM_GEN = "v5e"
 HBM_BYTES_BY_GEN = {
     "v4": 32 << 30,
     "v5e": 16 << 30,
@@ -122,9 +150,9 @@ HBM_RESERVE_FRACTION = 1.0 / 64.0   # 16 GiB -> 15.75 GiB usable
 
 
 def hbm_generation_bytes(gen: Optional[str] = None):
-    """(physical HBM bytes, generation name) for ``gen`` or the
-    LGBM_TPU_HBM_GEN / default generation."""
-    g = (gen or os.environ.get(HBM_GEN_ENV, DEFAULT_HBM_GEN)).lower()
+    """(physical HBM bytes, generation name) for ``gen``, else
+    LGBM_TPU_HBM_GEN, else the device's generation."""
+    g = _generation(gen, HBM_GEN_ENV)
     if g not in HBM_BYTES_BY_GEN:
         raise ValueError(
             f"unknown TPU generation {g!r} for the HBM budget; known: "
@@ -367,6 +395,34 @@ def learner_dispatch_bytes(kind: str, *, f_pad: int, padded_bins: int,
 class RecordModelError(ValueError):
     """A bench record lacks the fields the cost model needs (untraced,
     or pre-v3 without the ``shape`` block)."""
+
+
+class RooflineNotMeasured(RecordModelError):
+    """The record ran on the CPU: its walls are not device times, so
+    there is no roofline share to report."""
+
+
+def roofline_peak(rec: Dict[str, Any], which: str,
+                  explicit: Optional[float] = None) -> float:
+    """The roof a record's walls are judged against — ``which`` is
+    ``"bw_gbps"`` or ``"tflops"``: the explicit value, else the env
+    override, else the table entry of ``provenance.device_kind``."""
+    prov = rec.get("provenance") or {}
+    if "cpu" in (prov.get("backend"), rec.get("backend")):
+        raise RooflineNotMeasured(
+            "not measured (the record ran on the CPU; a roofline share "
+            "comes only from a chip run)")
+    env = {"bw_gbps": PEAK_BW_ENV, "tflops": PEAK_TFLOPS_ENV}[which]
+    named = explicit or os.environ.get(env)
+    if named and named != "auto":
+        return float(named)
+    kind = prov.get("device_kind")
+    if kind not in DEVICE_KINDS:
+        raise RecordModelError(
+            f"no roofline peak for device_kind {kind!r} (known: "
+            f"{sorted(DEVICE_KINDS)}); name the roof with "
+            f"--peak-bw/--peak-tflops or {env}")
+    return DEVICE_KINDS[kind][which]
 
 
 def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
@@ -861,12 +917,9 @@ def roofline_table(rec: Dict[str, Any], *,
     """Join predicted phase bytes/FLOPs with the record's measured
     phase walls into roofline-utilization rows (one per phase that has
     both a prediction and a measured wall)."""
-    peak_bw = float(peak_bw_gbps
-                    or os.environ.get(PEAK_BW_ENV, DEFAULT_PEAK_BW_GBPS))
-    peak_tf = float(peak_tflops
-                    or os.environ.get(PEAK_TFLOPS_ENV,
-                                      DEFAULT_PEAK_TFLOPS))
     model = phase_model(rec)
+    peak_bw = roofline_peak(rec, "bw_gbps", peak_bw_gbps)
+    peak_tf = roofline_peak(rec, "tflops", peak_tflops)
     phases = rec.get("phases", {})
     rows: List[Dict[str, Any]] = []
     for name, pred in model.items():

@@ -343,24 +343,21 @@ def print_bench_report(paths: List[str], roofline: bool = False,
 
 def _print_roofline(rec: dict, peak_bw: float,
                     peak_tflops: float) -> int:
-    import os
-
-    from .costmodel import (DEFAULT_PEAK_BW_GBPS, DEFAULT_PEAK_TFLOPS,
-                            PEAK_BW_ENV, PEAK_TFLOPS_ENV,
-                            RecordModelError, roofline_table)
+    from .costmodel import (RecordModelError, RooflineNotMeasured,
+                            roofline_peak, roofline_table)
     try:
         rows = roofline_table(rec, peak_bw_gbps=peak_bw or None,
                               peak_tflops=peak_tflops or None)
+        # the header states the roof roofline_table judged against
+        # (flag, then env override, then the record's device_kind)
+        bw = roofline_peak(rec, "bw_gbps", peak_bw or None)
+        tf = roofline_peak(rec, "tflops", peak_tflops or None)
+    except RooflineNotMeasured as e:
+        print(f"    roofline: {e}")
+        return 0
     except RecordModelError as e:
         print(f"    roofline: {e}")
         return 1
-    # header peaks must resolve exactly as roofline_table did (flag,
-    # then env override, then default) or the printed %bw/%flops
-    # columns disagree with the stated roof
-    bw = peak_bw or float(os.environ.get(PEAK_BW_ENV,
-                                         DEFAULT_PEAK_BW_GBPS))
-    tf = peak_tflops or float(os.environ.get(PEAK_TFLOPS_ENV,
-                                             DEFAULT_PEAK_TFLOPS))
     print(f"    roofline (peak {bw:g} GB/s, {tf:g} TFLOPs):")
     print(f"      {'phase':<20} {'pred GB':>9} {'wall':>9} "
           f"{'GB/s':>8} {'%bw':>6} {'%flops':>7}  bound")
@@ -396,10 +393,12 @@ def main(argv=None) -> int:
                          "with measured phase walls (traced v3 records)")
     rp.add_argument("--peak-bw", type=float, default=0.0,
                     help="roofline HBM peak in GB/s (default: "
-                         "LGBM_TPU_PEAK_BW_GBPS or the v5e 819)")
+                         "LGBM_TPU_PEAK_BW_GBPS, else the record's "
+                         "device_kind in costmodel.DEVICE_KINDS)")
     rp.add_argument("--peak-tflops", type=float, default=0.0,
                     help="roofline compute peak in TFLOPs (default: "
-                         "LGBM_TPU_PEAK_TFLOPS or the v5e 197)")
+                         "LGBM_TPU_PEAK_TFLOPS, else the record's "
+                         "device_kind)")
     atp = sub.add_parser("attr", help="device-time kernel attribution "
                                       "from an xplane capture")
     atp.add_argument("xplane", help="capture dir (recursive "
@@ -412,7 +411,8 @@ def main(argv=None) -> int:
                      help="with --bench: add %%-of-peak-BW columns")
     atp.add_argument("--peak-bw", type=float, default=0.0,
                      help="roofline HBM peak in GB/s (default: "
-                          "LGBM_TPU_PEAK_BW_GBPS or the v5e 819)")
+                          "LGBM_TPU_PEAK_BW_GBPS, else the record's "
+                          "device_kind)")
     atp.add_argument("--top", type=int, default=0,
                      help="also print per-plane detail with the top N "
                           "raw op names")

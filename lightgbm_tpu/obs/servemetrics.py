@@ -17,7 +17,7 @@ under ``LGBM_TPU_SERVE_METRICS=<dir>``.  This module consumes them:
 * SLO-threshold findings ride the shared ``obs/findings.py`` schema:
   a retrace-after-warmup is ALWAYS an error (the same-bucket
   contract); ``--slo-p99-ms`` / ``--slo-p999-ms`` / ``--max-pad-waste``
-  opt into latency and waste gates; ``serve_error_*`` taxonomy events
+  opt into latency and waste gates; ``serve_error_*`` class events
   surface as warnings.
 
 Exit codes follow the shared contract: 0 clean, 1 error-severity

@@ -899,15 +899,15 @@ def run_attr(xplane: str, *, bench: str = "", roofline: bool = False,
     model = None
     peak = peak_bw
     if rec is not None:
-        from .costmodel import (DEFAULT_PEAK_BW_GBPS, PEAK_BW_ENV,
-                                RecordModelError, kernel_model)
-        if not peak:
-            peak = float(os.environ.get(PEAK_BW_ENV,
-                                        DEFAULT_PEAK_BW_GBPS))
+        from .costmodel import (RecordModelError, kernel_model,
+                                roofline_peak)
         try:
             model = kernel_model(rec)
+            if roofline:
+                peak = roofline_peak(rec, "bw_gbps", peak or None)
         except RecordModelError as e:
             print(f"obs attr: cost-model join skipped: {e}")
+            model = None
     planes_detail = None
     if top:
         planes_detail = []
@@ -994,6 +994,7 @@ def synthetic_bench_record() -> Dict[str, Any]:
         "value": 1.0,
         "unit": "iters/sec",
         "backend": "tpu",
+        "provenance": {"device_kind": "TPU v5 lite"},
         "counters": {"splits": 30.0, "rows_partitioned": 200000.0,
                      "rows_histogrammed": 150000.0, "fused_splits": 30.0},
         "shape": {"rows": 10000, "features": 28, "f_pad": 32,

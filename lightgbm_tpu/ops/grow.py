@@ -157,8 +157,8 @@ def _pack_si(si: "SplitInfo") -> jnp.ndarray:
 def pack_tree_arrays(tas):
     """Flatten a list of TreeArrays into ONE f32 device buffer so a single
     host transfer materialises every deferred tree (each per-array pull —
-    and each eager ravel/astype op — pays a round trip on remote/tunneled
-    devices; jit makes the whole pack one dispatch)."""
+    and each eager ravel/astype op — is a dispatch of its own; jit makes
+    the whole pack one)."""
     parts = []
     for ta in tas:
         for x in ta:

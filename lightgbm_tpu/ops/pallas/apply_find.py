@@ -62,11 +62,6 @@ _VMEM_BASE = 14_000_000
 _VMEM_PER_FB = 4800
 _VMEM_CAP = 96 * 1024 * 1024
 
-# newer JAX renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams;
-# resolve whichever this release ships so the tail survives both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 
 def vmem_limit_for(f: int, b: int) -> int:
     return _VMEM_BASE + _VMEM_PER_FB * f * b
@@ -540,7 +535,7 @@ def make_apply_find(hp: SplitHyperParams, *, L: int, f: int, b: int,
             ],
             input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3},
             interpret=interpret,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=vmem_limit_for(f, b)),
         )(sel_i, sel_f, h2, fmask, consts, iscat, mono_s,
           best, lstate, nodes, seg)
@@ -584,7 +579,7 @@ def make_apply_find_pool(hp: SplitHyperParams, *, L: int, f: int, b: int,
             scratch_shapes=[pltpu.VMEM((f, 4, b), jnp.float32),
                             pltpu.SemaphoreType.DMA],
             input_output_aliases={7: 0, 8: 1, 9: 2, 10: 3, 11: 4},
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=vmem_limit_for(f, b)),
         )(sel_i, sel_f, h_small, fmask, consts, iscat, mono_s,
           best, lstate, nodes, seg, pool)

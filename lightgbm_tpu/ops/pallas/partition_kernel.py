@@ -56,10 +56,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# newer JAX spells the unblocked HBM memory space pltpu.HBM; older
-# releases only have ANY (which the Mosaic compiler places in HBM for
-# manually-DMA'd refs anyway)
-_HBM = getattr(pltpu, "HBM", pltpu.ANY)
+# the unblocked HBM memory space of manually-DMA'd refs
+_HBM = pltpu.HBM
 
 # sel layout (SMEM i32[8]): s0, par_cnt, feat_col, sbin, default_left,
 # is_cat, nan_bin (== num_bins-1 if feature has a NaN bin else -1), spare

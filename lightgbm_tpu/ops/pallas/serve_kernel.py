@@ -55,10 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# newer JAX spells the unblocked HBM memory space pltpu.HBM; older
-# releases only have ANY (which the Mosaic compiler places in HBM for
-# manually-DMA'd refs anyway)
-_HBM = getattr(pltpu, "HBM", pltpu.ANY)
+# the unblocked HBM memory space of manually-DMA'd refs
+_HBM = pltpu.HBM
 
 # default row-block height of the streamed input tile; buckets are
 # pow2 >= 64 so any bucket either divides it or equals BR after the

@@ -686,12 +686,17 @@ PREDICT_RULES: Tuple[Rule, ...] = (
          lambda i: i.serve_kernel_env == "0"),
     Rule("serve_kernel_backend_auto", "serve_kernel",
          "LGBM_TPU_SERVE_KERNEL",
-         "the Pallas traversal kernel compiles for TPU only; off-TPU "
-         "the compiled path runs the XLA gather walk "
+         "LGBM_TPU_SERVE_KERNEL=auto runs the XLA gather walk on every "
+         "backend: the v5e compiler refuses the Pallas traversal kernel "
+         "on jax 0.9.0 (NotImplementedError: Only 2D gather is "
+         "supported; PR 22).  Only LGBM_TPU_SERVE_KERNEL=1 asks for the "
+         "kernel, on the TPU backend alone, where it fails with the "
+         "compiler's own error until the kernel is rewritten or deleted "
          "(LGBM_TPU_SERVE_INTERP=kernel engages the interpreter-mode "
          "kernel anywhere for parity tests)",
-         lambda i: (i.serve_kernel_env in ("auto", "1")
-                    and i.backend != "tpu")),
+         lambda i: (i.serve_kernel_env == "auto"
+                    or (i.serve_kernel_env == "1"
+                        and i.backend != "tpu"))),
     Rule("serve_forest_overwide", "serve_kernel", "num_iterations",
          "the stacked forest exceeds the kernel's VMEM scratch cap "
          "(layout.serve_forest_fit); the compiled path runs the XLA "

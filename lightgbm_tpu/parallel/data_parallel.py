@@ -40,7 +40,7 @@ from ..ops.grow import (MeshPhysicalPieces, TreeArrays, make_grow_fn,
                         phys_init_comb)
 from ..ops.split import SplitHyperParams
 from ..utils import log
-from .mesh import DATA_AXIS, build_mesh, pad_rows_to_shards, shard_map
+from .mesh import DATA_AXIS, build_mesh, pad_rows_to_shards
 
 
 class DataParallelGrower:
@@ -121,7 +121,7 @@ class DataParallelGrower:
             # prices that width, not the bundled storage width
             if pieces.padded_bins:
                 self._padded_bins = int(pieces.padded_bins)
-            self._sharded_core = jax.jit(shard_map(
+            self._sharded_core = jax.jit(jax.shard_map(
                 pieces.core, mesh=self.mesh,
                 in_specs=(row2d, row2d, row, row, row, rep, rep, rep,
                           rep, rep, rep),
@@ -142,7 +142,7 @@ class DataParallelGrower:
                     bins_local = _ingest(bins_local)
                 return _init_part(bins_local)
 
-            self._sharded_init = jax.jit(shard_map(
+            self._sharded_init = jax.jit(jax.shard_map(
                 _init_local,
                 mesh=self.mesh, in_specs=(row2d,), out_specs=row2d,
                 check_vma=False,
@@ -154,7 +154,7 @@ class DataParallelGrower:
                 use_dp=use_dp, axis_name=DATA_AXIS,
                 hist_scatter=self.hist_scatter,
                 n_hist_shards=self.num_shards, **grow_kwargs)
-            self._sharded_grow = jax.jit(shard_map(
+            self._sharded_grow = jax.jit(jax.shard_map(
                 grow, mesh=self.mesh,
                 in_specs=(row2d, row, row, row, rep, rep, rep, rep, rep),
                 out_specs=(tree_specs, row),
@@ -191,7 +191,7 @@ class DataParallelGrower:
                     body, (comb, scratch), (gradK, hessK, fmK, seedK))
                 return treeK, lidK, comb, scratch
 
-            self._sharded_batch = jax.jit(shard_map(
+            self._sharded_batch = jax.jit(jax.shard_map(
                 _core_k, mesh=self.mesh,
                 in_specs=(row2d, row2d, krow, krow, row, rep, rep,
                           rep, rep, rep),

@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.grow import TreeArrays, make_grow_fn
 from ..ops.split import SplitHyperParams
 from ..utils import log
-from .mesh import DATA_AXIS, FEATURE_AXIS, pad_rows_to_shards, shard_map
+from .mesh import DATA_AXIS, FEATURE_AXIS, pad_rows_to_shards
 
 
 class MeshProbe:
@@ -97,7 +97,7 @@ class FeatureParallelGrower:
         col = P(FEATURE_AXIS)
         rep = P()
         tree_specs = TreeArrays(*([rep] * len(TreeArrays._fields)))
-        self._sharded_grow = jax.jit(shard_map(
+        self._sharded_grow = jax.jit(jax.shard_map(
             grow, mesh=self.mesh,
             in_specs=(P(data_ax, FEATURE_AXIS), row, row, row,
                       col, col, col, col, rep),

@@ -22,32 +22,6 @@ DATA_AXIS = "data"
 FEATURE_AXIS = "feature"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-guarded ``shard_map``: newer JAX exposes ``jax.shard_map``
-    (with the ``check_vma`` kwarg); older releases only have
-    ``jax.experimental.shard_map.shard_map`` (where the same knob is
-    spelled ``check_rep``).  The learners all go through this wrapper so
-    a JAX upgrade/downgrade never strands them on a removed alias."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is not None:
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        try:
-            return impl(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, **kwargs)
-        except TypeError as e:
-            # transitional releases take check_rep instead of check_vma —
-            # but only retry for THAT TypeError, not e.g. bad in_specs
-            if "check_vma" not in str(e):
-                raise
-            kwargs = {} if check_vma is None else {"check_rep": check_vma}
-            return impl(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as impl_exp
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return impl_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kwargs)
-
-
 def parse_mesh_axes(spec: str) -> Dict[str, int]:
     out: Dict[str, int] = {}
     for part in (spec or "").split(","):

@@ -132,7 +132,7 @@ class ServingEngine:
         self._warm = True
 
     def _note_error(self, code: str) -> None:
-        """Error-taxonomy event on the raise paths (off the dispatch
+        """Error-class event on the raise paths (off the dispatch
         hot path; a no-op when metrics are off)."""
         if self._flight is not None:
             self._flight.record_event(self.model.digest,
@@ -140,16 +140,11 @@ class ServingEngine:
 
     def stats(self) -> dict:
         """Program-cache facts the retrace pin reads: ``programs`` is
-        the live jit cache size (falls back to the bucket count when
-        the runtime hides it), which must equal ``len(buckets)`` after
+        the live jit cache size, which must equal ``len(buckets)`` after
         warmup and never grow mid-serving."""
-        try:
-            programs = int(self._fn._cache_size())
-        except Exception:   # pragma: no cover - jax-version dependent
-            programs = len(self._buckets)
         return {
             "buckets": sorted(self._buckets),
-            "programs": programs,
+            "programs": int(self._fn._cache_size()),
             "dispatches": self.dispatches,
             "rows_true": self.rows_true,
             "rows_padded": self.rows_padded,

@@ -26,7 +26,7 @@ counterpart, built the way "millions of users" deployments expect:
 * **queue depth / occupancy sampling**, **padding-waste bytes**
   (padded minus true rows, priced via
   ``obs.costmodel.serving_traversal_bytes``), **retrace-after-warmup**
-  and **error-taxonomy events**.
+  and **error-class events**.
 
 Purity discipline (the ``grow-counters-off`` pattern): the recorder
 lives entirely on the host side of the dispatch — nothing it does is
@@ -348,7 +348,7 @@ class ServingFlightRecorder:
             w.queue_depth_cap = max(w.queue_depth_cap, cap)
 
     def record_event(self, digest: str, name: str) -> None:
-        """Error-taxonomy / lifecycle event (``serve_error_*``)."""
+        """Error-class / lifecycle event (``serve_error_*``)."""
         with self._lock:
             w = self._window(digest, self._clock())
             w.events[name] = w.events.get(name, 0) + 1

@@ -198,7 +198,7 @@ def test_registered_entries_trace_to_pallas_calls():
         assert calls, f"{name} traced to no pallas_call"
         for c in calls:
             # every kernel-visible ref is classified
-            assert all(r.space in ("smem", "vmem", "any", "semaphore")
+            assert all(r.space in ("smem", "vmem", "hbm", "semaphore")
                        for r in c.refs), (name, c.refs)
 
 

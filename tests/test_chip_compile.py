@@ -1,0 +1,184 @@
+"""Compile-for-the-chip guard: the default training path's kernels at
+the Higgs width, and the compiled serving walk, must get through the
+v5e compiler (Mosaic + XLA:TPU) — and today's known refusals stay
+pinned so the PR that fixes one has to flip its pin.
+
+Nothing here runs on a device: the TPU compiler is installed with
+libtpu and compiles for a DESCRIBED ``v5e:2x2`` chip under
+``JAX_PLATFORMS=cpu``.  Interpret-mode parity (every other test of
+these kernels) says nothing about lowering; this file is the off-chip
+half of ``python chip_smoke.py``.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may hold libtpu, so nothing chip-related may
+happen at import/collection time (every xdist worker imports this
+file), and all compile cases live in this ONE file so they land on one
+worker.
+"""
+from __future__ import annotations
+
+import functools
+
+import pytest
+
+# Higgs-like 1M x 28 on the default physical+stream+fused route:
+# 1,000,000 rows pad to a multiple of R=512, plus PHYS_ROW_SLACK
+N_PAD, N_ALLOC, C, F_PAD, BINS, LEAVES, R = (
+    1_000_448, 1_005_568, 128, 32, 256, 255, 512)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A described-device compile is written to the persistent cache
+    but cannot be read back without a chip; keep these out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _part_args():
+    """(sel, rows, scratch, grid_blocks) of the dynamic-grid scans."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import partition_args, sds
+    return partition_args(N_ALLOC, C) + (sds((), jnp.int32),)
+
+
+def _fused(scan: str):
+    from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
+    fn = make_fused_split(N_ALLOC, C, f_pad=F_PAD, padded_bins=BINS, R=R,
+                          dynamic=True, scan=scan)
+    return fn, _part_args()
+
+
+def _partition_perm():
+    from lightgbm_tpu.ops.pallas.partition_kernel3 import \
+        make_partition_perm
+    return (make_partition_perm(N_ALLOC, C, R=R, dynamic=True),
+            _part_args())
+
+
+def _stream(which: str):
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.pallas.stream_grad import (N_CONSTS, make_init,
+                                                     make_refresh)
+    kw = dict(kind="binary", sigmoid=1.0, f=F_PAD, n_alloc=N_ALLOC,
+              n_pad=N_PAD, C=C, R=R)
+    comb = sds((N_ALLOC, C), jnp.float32)
+    if which == "init":
+        return make_init(f_real=F_PAD, **kw), (
+            comb, sds((N_PAD, F_PAD), jnp.uint8),
+            sds((2 + N_CONSTS["binary"], N_PAD), jnp.float32))
+    fn = make_refresh(root_hist=which == "refresh_root",
+                      padded_bins=BINS, **kw)
+    return fn, (comb, sds((1, N_PAD), jnp.float32))
+
+
+def _hist_comb_root():
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.pallas.hist_kernel2 import build_histogram_comb
+    fn = functools.partial(build_histogram_comb, f_pad=F_PAD, size=N_PAD,
+                           padded_bins=BINS)
+    return fn, (sds((N_ALLOC, C), jnp.float32),) + (sds((), jnp.int32),) * 3
+
+
+def _apply_find_pool(f: int):
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.pallas.apply_find import (_finder_args,
+                                                    make_apply_find_pool)
+    from lightgbm_tpu.ops.split import SplitHyperParams
+    fn = make_apply_find_pool(SplitHyperParams(min_data_in_leaf=20),
+                              L=LEAVES, f=f, b=BINS, max_depth=-1)
+    return fn, _finder_args(LEAVES, f, BINS, ()) + (
+        sds((LEAVES, f, 4, BINS), jnp.float32),)
+
+
+def _serve_forest():
+    """The XLA gather walk ``Booster.predict`` runs by default, over a
+    100-tree x 255-leaf forest (a 4096-row bucket: XLA:TPU's compile
+    time grows with the bucket, 2.5 s here against 45 s at the 65536
+    cap, and the program is the same)."""
+    from lightgbm_tpu.analysis.entries import serve_forest_args
+    from lightgbm_tpu.ops.predict import forest_scores_flat
+    fn = functools.partial(forest_scores_flat, n_steps=24)
+    return fn, serve_forest_args(n=4096, t=100, ni=256, nl=256, f=28,
+                                 b=256, w=0, k=1, f_orig=28)
+
+
+def _registered(name: str):
+    from lightgbm_tpu.analysis.registry import collect
+    return collect()[name].builder()
+
+
+# (builder, must the HLO hold a Mosaic kernel?)
+COMPILES = {
+    "fused_split_permute": (functools.partial(_fused, "permute"), True),
+    "fused_split_matmul": (functools.partial(_fused, "matmul"), True),
+    "partition_perm": (_partition_perm, True),
+    "stream_init": (functools.partial(_stream, "init"), True),
+    "stream_refresh": (functools.partial(_stream, "refresh"), True),
+    "stream_refresh_root": (functools.partial(_stream, "refresh_root"),
+                            True),
+    "hist_comb_root": (_hist_comb_root, True),
+    "apply_find_pool_f28": (functools.partial(_apply_find_pool, 28), True),
+    "apply_find_pool_f32": (functools.partial(_apply_find_pool, 32), True),
+    "serve_forest_100x255": (_serve_forest, False),
+}
+
+
+def _compile(builder, one_chip):
+    import jax
+    fn, args = builder()
+    args = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                 for a in args)
+    return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("name", sorted(COMPILES))
+def test_default_path_compiles_for_v5e(name, one_chip, no_compile_cache):
+    builder, is_kernel = COMPILES[name]
+    compiled = _compile(builder, one_chip)
+    if is_kernel:
+        assert "tpu_custom_call" in compiled.as_text(), (
+            f"{name} compiled without a Mosaic kernel — an interpret-"
+            "mode or XLA fallback slipped onto the chip path")
+
+
+# Off the default path, refused by the v5e compiler on jax 0.9.0 /
+# libtpu 0.0.34 (PR 22).  serve_traverse: ``sf[gidx]`` gathers a flat
+# VMEM vector by a [BR, T] index array ("Only 2D gather is supported");
+# pack=2: ``arith.trunci`` i8 -> i1 ("Unsupported target bitwidth for
+# truncation").  Opt-in only: LGBM_TPU_SERVE_KERNEL=1 /
+# LGBM_TPU_COMB_PACK=2.  Flip the pin in the PR that fixes the kernel.
+@pytest.mark.parametrize("name", ["serve_traverse", "partition_p2",
+                                  "fused_split_p2"])
+def test_known_refusals_stay_pinned(name, one_chip, no_compile_cache):
+    with pytest.raises(
+            Exception,
+            match="Only 2D gather|Unsupported target bitwidth"):
+        _compile(functools.partial(_registered, name), one_chip)

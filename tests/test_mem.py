@@ -607,7 +607,10 @@ def test_obs_mem_join_flags_measured_over_predicted(tmp_path):
 
 def test_obs_mem_failure_modes(tmp_path):
     # legacy multichip artifact: clear message, exit 2
-    rc, out = _run_cli(["mem", "MULTICHIP_r03.json"])
+    legacy = tmp_path / "MULTICHIP_r99.json"
+    legacy.write_text(json.dumps({"n_devices": 8, "rc": 0, "ok": True,
+                                  "skipped": False, "tail": "dryrun ok"}))
+    rc, out = _run_cli(["mem", str(legacy)])
     assert rc == 2 and "legacy multichip" in out
     # truncated JSON: exit 2, no traceback
     p = tmp_path / "trunc.json"

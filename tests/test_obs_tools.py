@@ -530,22 +530,37 @@ class TestCliRobustness:
     def test_roofline_header_matches_env_peaks(self, tmp_path, capsys,
                                                monkeypatch):
         """The printed roof must be the one utilization was computed
-        against — flag, then env override, then default."""
-        monkeypatch.setenv("LGBM_TPU_PEAK_BW_GBPS", "400")
-        p = tmp_path / "v3.json"
-        p.write_text(json.dumps({
+        against — flag, then env override, then the record's
+        device_kind; an unknown kind is an error and a CPU record is
+        "not measured", never a v5e default."""
+        rec = {
             "schema": "lightgbm_tpu/bench/v3", "metric": "m",
             "value": 1.0, "unit": "iters/sec",
+            "provenance": {"device_kind": "TPU v5 lite"},
             "counters": {"splits": 4, "rows_partitioned": 1000,
                          "rows_histogrammed": 800, "fused_splits": 4},
             "shape": {"rows": 500, "f_pad": 16, "padded_bins": 64,
                       "trees": 1},
             "knobs": {"comb_pack": 1, "fused": True},
             "phases": {"Split": {"total_s": 0.01, "count": 1,
-                                 "mean_s": 0.01}}}))
-        assert report_main(["report", "--bench", "--roofline",
-                            str(p)]) == 0
-        assert "peak 400 GB/s" in capsys.readouterr().out
+                                 "mean_s": 0.01}}}
+        p = tmp_path / "v3.json"
+
+        def roofline(**over):
+            p.write_text(json.dumps({**rec, **over}))
+            rc = report_main(["report", "--bench", "--roofline", str(p)])
+            return rc, capsys.readouterr().out
+
+        rc, out = roofline()
+        assert rc == 0 and "peak 819 GB/s, 197 TFLOPs" in out
+        rc, out = roofline(provenance={"device_kind": "TPU v9"})
+        assert rc == 1 and "device_kind 'TPU v9'" in out
+        rc, out = roofline(provenance={"device_kind": "cpu",
+                                       "backend": "cpu"})
+        assert rc == 0 and "roofline: not measured" in out
+        monkeypatch.setenv("LGBM_TPU_PEAK_BW_GBPS", "400")
+        rc, out = roofline()
+        assert rc == 0 and "peak 400 GB/s, 197 TFLOPs" in out
 
     def test_roofline_cli_on_untraced_record(self, tmp_path, capsys):
         p = tmp_path / "v2.json"
@@ -706,10 +721,17 @@ def test_env_knob_docs_stay_in_sync():
     code this PR touches so retuning a default without regenerating
     docs/Parameters.md fails here instead of rotting silently."""
     from lightgbm_tpu.config import ENV_KNOBS
-    assert ENV_KNOBS["LGBM_TPU_PEAK_BW_GBPS"][0] == str(int(
-        costmodel.DEFAULT_PEAK_BW_GBPS))
-    assert ENV_KNOBS["LGBM_TPU_PEAK_TFLOPS"][0] == str(int(
-        costmodel.DEFAULT_PEAK_TFLOPS))
+    # roofline peaks have no default: they come from the record's
+    # device_kind, and an unknown kind is an error
+    assert ENV_KNOBS["LGBM_TPU_PEAK_BW_GBPS"][0] == "auto"
+    assert ENV_KNOBS["LGBM_TPU_PEAK_TFLOPS"][0] == "auto"
+    assert costmodel.roofline_peak(
+        {"provenance": {"device_kind": "TPU v5 lite"}}, "bw_gbps") == 819.0
+    with pytest.raises(costmodel.RecordModelError, match="device_kind"):
+        costmodel.roofline_peak(
+            {"provenance": {"device_kind": "TPU v9"}}, "tflops")
+    with pytest.raises(costmodel.RooflineNotMeasured):
+        costmodel.roofline_peak({"backend": "cpu"}, "bw_gbps", 819.0)
     from lightgbm_tpu.obs.tracer import Tracer
     assert ENV_KNOBS["LGBM_TPU_TRACE_MAX_EVENTS"][0] == str(
         Tracer()._max_events)

@@ -322,14 +322,22 @@ class TestPredictRouting:
         """ISSUE 18: the serve_kernel dimension — engagement needs the
         compiled path AND no serve_kernel rule firing."""
         from lightgbm_tpu.ops import routing as R
+        # default knobs on the TPU backend: compiled, XLA gather walk
+        # (the v5e compiler refuses the Pallas kernel, PR 22)
         d = R.predict_decide(R.PredictInputs(backend="tpu",
                                              serve_env="auto"))
-        assert d.path == "compiled" and d.kernel
+        assert d.path == "compiled" and not d.kernel
+        assert d.kernel_reasons == ("serve_kernel_backend_auto",)
+        # only the explicit ask engages the kernel, on TPU alone
+        d = R.predict_decide(R.PredictInputs(
+            backend="tpu", serve_env="auto", serve_kernel_env="1"))
+        assert d.path == "compiled" and d.kernel and d.kernel_requested
         # VMEM-overwide forest: compiled path stays, kernel drops loud
         d = R.predict_decide(R.PredictInputs(
-            backend="tpu", serve_env="auto", forest_overwide=True))
+            backend="tpu", serve_env="auto", serve_kernel_env="1",
+            forest_overwide=True))
         assert d.path == "compiled" and not d.kernel
-        assert "serve_forest_overwide" in d.kernel_reasons
+        assert d.kernel_reasons == ("serve_forest_overwide",)
         # kernel env off: quiet
         d = R.predict_decide(R.PredictInputs(
             backend="tpu", serve_env="auto", serve_kernel_env="0"))

@@ -407,15 +407,17 @@ PYEOF
     # gate 4: legacy MULTICHIP_r*.json artifacts are tolerated with a
     # clear fallback message (report) and refused cleanly (gate,
     # exit 2) — never a traceback
+    echo '{"n_devices": 8, "rc": 0, "ok": true, "skipped": false, "tail": "dryrun ok"}' \
+        > "$tmp/MULTICHIP_legacy.json"
     env JAX_PLATFORMS=cpu python -m lightgbm_tpu.obs report --bench \
-        MULTICHIP_r03.json > "$tmp/legacy.out" 2>&1
+        "$tmp/MULTICHIP_legacy.json" > "$tmp/legacy.out" 2>&1
     if [ $? -ne 0 ] || ! grep -q "legacy multichip dryrun" \
         "$tmp/legacy.out"; then
         echo "mesh-obs leg: legacy MULTICHIP reader fallback missing"
         cat "$tmp/legacy.out"
         return 1
     fi
-    python tools/perf_gate.py MULTICHIP_r03.json "$tmp/mc.json" \
+    python tools/perf_gate.py "$tmp/MULTICHIP_legacy.json" "$tmp/mc.json" \
         > "$tmp/legacy_diff.out" 2>&1
     if [ $? -ne 2 ] || grep -q "Traceback" "$tmp/legacy_diff.out"; then
         echo "mesh-obs leg: legacy record diff must exit 2 cleanly"
@@ -566,8 +568,10 @@ PYEOF
                   "$rpp) was NOT accepted by the hbm-budget pass"; \
              return 1; }
     # gate 6: legacy records degrade with a message, never a traceback
+    echo '{"n_devices": 8, "rc": 0, "ok": true, "skipped": false, "tail": "dryrun ok"}' \
+        > "$tmp/MULTICHIP_legacy.json"
     env JAX_PLATFORMS=cpu python -m lightgbm_tpu.obs mem \
-        MULTICHIP_r03.json > "$tmp/legacy.out" 2>&1
+        "$tmp/MULTICHIP_legacy.json" > "$tmp/legacy.out" 2>&1
     if [ $? -ne 2 ] || grep -q "Traceback" "$tmp/legacy.out"; then
         echo "mem leg: legacy record must exit 2 cleanly"
         cat "$tmp/legacy.out"
