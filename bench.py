@@ -11,8 +11,8 @@ file (the BENCH_r*.json round artifacts), readable with
 ``python -m lightgbm_tpu.obs report --bench``.
 
 With ``LGBM_TPU_TRACE`` set the whole run is traced (obs tracer): the
-record gains per-phase breakdowns (BeforeTrain / ConstructHistogram /
-FindBestSplits / Split / UpdateScore ...), device counter totals and
+record gains per-phase breakdowns (BeforeTrain / Tree::grow /
+UpdateScore and their ``::wait`` children ...), work counter totals and
 the per-iteration run-ledger trajectory (``obs/metrics.py``), and
 ``"traced": true`` flags that the barriers perturb the iters/sec number
 — capture the metric of record and the phase profile in separate runs.

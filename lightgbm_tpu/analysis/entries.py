@@ -9,8 +9,8 @@ Registered here:
 * ``grow_physical`` — the physical-partition grow core (off-TPU this
   traces the interpret reference path; the compiled kernel geometry is
   covered by the per-kernel registrations).
-* purity pins ``grow-counters-off`` and ``grow-obs-lifecycle`` — the
-  registered home of the "telemetry off => identical program"
+* purity pins ``grow-tracer-live`` and ``grow-obs-lifecycle`` — the
+  registered home of the "telemetry on or off => identical program"
   invariant that used to live as ad-hoc string compares in
   tests/test_obs.py.
 * mesh configs (ISSUE 8) — the PADDED feature counts the gbdt
@@ -45,8 +45,7 @@ def _hp():
 def _grow_serial():
     from ..ops.grow import make_grow_fn
     n, f, b = 128, 8, 32
-    fn = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                      counters=False)
+    fn = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
     return fn, _grow_args(n, f)
 
 
@@ -246,17 +245,30 @@ def _pin_paged_off():
             ("paged", paged._grow_p, args)]
 
 
-@register_purity_pin("grow-counters-off")
-def _pin_counters_off():
-    """counters=False must compile the identical program to a build
-    that never heard of counters (the default)."""
+@register_purity_pin("grow-tracer-live")
+def _pin_tracer_live():
+    """Turning the tracer on changes no compiled program (ISSUE 27): a
+    grow program built AND traced while the tracer is live must be the
+    program of a build that never saw it.  The work counters come from
+    the finished tree on the host (obs/counters.counters_from_tree),
+    so nothing in ``make_grow_fn`` may look at the tracer."""
+    from ..obs import tracer
     from ..ops.grow import make_grow_fn
     n, f, b = 128, 8, 32
     args = _grow_args(n, f)
-    off = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                       counters=False)
-    default = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
-    return [("counters=False", off, args), ("default", default, args)]
+    off = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
+
+    def live(*a):
+        was = tracer.enabled
+        tracer.enable(None)
+        try:
+            return make_grow_fn(_hp(), num_leaves=8, padded_bins=b)(*a)
+        finally:
+            if not was:
+                tracer.disable()
+                tracer.reset()
+
+    return [("tracer-off", off, args), ("tracer-live", live, args)]
 
 
 @register_purity_pin("grow-obs-lifecycle")
@@ -269,8 +281,7 @@ def _pin_obs_lifecycle():
     from ..ops.grow import make_grow_fn
     n, f, b = 128, 8, 32
     args = _grow_args(n, f)
-    before = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                          counters=False)
+    before = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
     tracer.enable(None)
     with tracer.span("analysis-probe"):
         pass
@@ -278,8 +289,7 @@ def _pin_obs_lifecycle():
     tracer.disable()
     tracer.reset()
     obs.reset_run()
-    after = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                         counters=False)
+    after = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
     return [("before-obs", before, args), ("after-obs", after, args)]
 
 
@@ -296,8 +306,7 @@ def _pin_pulse_off():
     from ..ops.grow import make_grow_fn
     n, f, b = 128, 8, 32
     args = _grow_args(n, f)
-    before = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                          counters=False)
+    before = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
     prev = os.environ.get(pulse.PULSE_ENV)
     os.environ[pulse.PULSE_ENV] = "mem"
     try:
@@ -312,8 +321,7 @@ def _pin_pulse_off():
         else:
             os.environ[pulse.PULSE_ENV] = prev
         pulse._reset()
-    after = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                         counters=False)
+    after = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
     return [("before-pulse", before, args),
             ("after-pulse", after, args)]
 
@@ -349,8 +357,7 @@ def _pin_phase_hbm():
     from ..ops.grow import make_grow_fn
     n, f, b = 128, 8, 32
     args = _grow_args(n, f)
-    before = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                          counters=False)
+    before = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
     tracer.enable(None)
     tracer.instant("hbm_live_bytes", phase="Tree::grow", bytes=0)
     obs.ledger.record_phase_hbm("Tree::grow", 0)
@@ -358,8 +365,7 @@ def _pin_phase_hbm():
     tracer.disable()
     tracer.reset()
     obs.reset_run()
-    after = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
-                         counters=False)
+    after = make_grow_fn(_hp(), num_leaves=8, padded_bins=b)
     return [("before-mem-sampling", before, args),
             ("after-mem-sampling", after, args)]
 

@@ -2,8 +2,9 @@
 invariants.
 
 The obs layer's contract since PR 2 is that telemetry is FREE when
-off: ``make_grow_fn(counters=False)`` must compile the bit-identical
-jaxpr to a build that never heard of counters, and exercising the
+off, and since ISSUE 27 when on too: a grow program built while the
+tracer is live must be the bit-identical jaxpr of a build that never
+saw it (``grow-tracer-live``), and exercising the
 tracer / ledger / reset lifecycle must not leak into a later build.
 Those pins used to live as ad-hoc ``jax.make_jaxpr`` string compares
 inside individual tests; they are now REGISTERED invariants

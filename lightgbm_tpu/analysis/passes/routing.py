@@ -419,8 +419,7 @@ def _serial_build(env: dict = None):
     n, f, b = 128, 8, 32
     hp = SplitHyperParams(min_data_in_leaf=2)
     with _env(env or {}):
-        fn = make_grow_fn(hp, num_leaves=8, padded_bins=b,
-                          counters=False)
+        fn = make_grow_fn(hp, num_leaves=8, padded_bins=b)
     args = (sds((n, f), jnp.uint8), sds((n,), jnp.float32),
             sds((n,), jnp.float32), sds((n,), jnp.float32),
             sds((f,), jnp.float32), sds((f,), jnp.int32),

@@ -114,14 +114,16 @@ class TraceCallback:
     ``lightgbm_tpu.obs`` tracer).
 
     Records, for every iteration: wall time since the previous
-    iteration, the device counter totals (splits, rows partitioned /
-    histogrammed, fused-kernel engagements — populated when tracing is
-    on, see obs/counters.py), and the evaluation results.  The records
+    iteration, the work counter totals (splits, rows partitioned /
+    histogrammed, fused-kernel engagements — derived from each finished
+    tree while tracing is on, see obs/counters.py), and the evaluation
+    results.  The records
     accumulate on ``self.history`` and are mirrored into the tracer as
     instant events, so they land in the ``LGBM_TPU_TRACE`` file next to
     the phase spans.  With ``enable_trace=True`` the callback turns the
     tracer on at its first call (in-memory unless ``trace_path`` is
-    given), so users get counters without touching env vars::
+    given), so users get counters — from the second iteration's tree
+    on — without touching env vars::
 
         cb = lgb.TraceCallback(period=10)
         lgb.train(params, ds, callbacks=[cb])
@@ -169,9 +171,9 @@ class TraceCallback:
             obs_ledger.sample(env.iteration, wall_s=rec["iter_wall_s"],
                               eval_results=rec["eval"],
                               trees=rec["trees"])
-        obs_tracer.instant("TraceCallback", iteration=env.iteration,
-                           counters=rec["counters"],
-                           iter_wall_s=rec["iter_wall_s"])
+        obs_tracer.instant("TraceCallback", **{
+            "iteration": env.iteration, "counters": rec["counters"],
+            "iter_wall_s": rec["iter_wall_s"]})
         if self.logger and (env.iteration + 1) % self.period == 0:
             c = rec["counters"]
             log.info(

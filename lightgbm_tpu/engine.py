@@ -204,10 +204,13 @@ def train(
                     evaluation_result_list = (booster.eval_train(feval)
                                               + booster.eval_valid(feval))
                 try:
-                    for cb in cbs_after:
-                        cb(callback_mod.CallbackEnv(
-                            booster, params, it, 0, num_boost_round,
-                            evaluation_result_list))
+                    # the callbacks' host work (and whatever pulls or
+                    # profiler calls they make) under a name of its own
+                    with obs_tracer.span("Callbacks"):
+                        for cb in cbs_after:
+                            cb(callback_mod.CallbackEnv(
+                                booster, params, it, 0, num_boost_round,
+                                evaluation_result_list))
                 except callback_mod.EarlyStopException as e:
                     booster.best_iteration = e.best_iteration + 1
                     _record_best(booster, e.best_score)

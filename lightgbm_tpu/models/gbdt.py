@@ -25,6 +25,7 @@ from ..config import Config
 from ..io.dataset_core import BinnedDataset
 from ..metric import Metric
 from ..obs import counters as obs_counters
+from ..obs import counters_from_tree as obs_counters_from_tree
 from ..obs import events as obs_events
 from ..obs import hbm_live_bytes as obs_hbm_live_bytes
 from ..obs import ledger as obs_ledger
@@ -404,11 +405,6 @@ class GBDT:
                     # carry no count channel, and the padded layout's
                     # zero-weight slack rows must not count at the root
                     "count": int(ds.num_data)})
-                # telemetry counters ride the grow return ONLY when the
-                # tracer is live at construction time — the default
-                # build compiles the exact same HLO as before (the
-                # acceptance contract tests/test_obs.py pins)
-                self._obs_counters = bool(obs_tracer.enabled)
                 # paged comb (ISSUE 15): when the routing model says
                 # the footprint cannot sit fully resident (or
                 # LGBM_TPU_PAGED=1 forces it), plan the page geometry
@@ -451,7 +447,6 @@ class GBDT:
                     physical_bins=self.dd.bins if use_phys else None,
                     stream=stream_spec,
                     paged=page_plan,
-                    counters=self._obs_counters,
                     numerics=self._numerics,
                     **self._grow_kwargs,
                 )
@@ -1012,11 +1007,12 @@ class GBDT:
     ) -> bool:
         """One boosting iteration.  Returns True when training cannot
         continue (no splittable leaves), like GBDT::TrainOneIter."""
-        if not obs_tracer.enabled:
-            return self._train_one_iter_impl(gradients, hessians)
+        # one call site whether tracing or not: the Python stack above a
+        # Pallas kernel is part of how its compiled program is found in
+        # the persistent cache, so a second branch here would make a run
+        # with the tracer live from the start compile everything anew
         with obs_tracer.span("GBDT::TrainOneIter", iteration=self.iter_):
-            out = self._train_one_iter_impl(gradients, hessians)
-        return out
+            return self._train_one_iter_impl(gradients, hessians)
 
     def _sample_phase_hbm(self, phase: str) -> None:
         """Live-buffer watermark census (obs.hbm_live_bytes) at PHASE
@@ -1032,9 +1028,10 @@ class GBDT:
         reimport must keep this generation's samples in ITS OWN
         ledger — a call-time import resolves through sys.modules to
         the newest generation and records into someone else's."""
-        b = obs_hbm_live_bytes()
-        obs_tracer.instant("hbm_live_bytes", phase=phase, bytes=b)
-        obs_ledger.record_phase_hbm(phase, b)
+        with obs_tracer.span("HbmCensus", phase=phase):
+            b = obs_hbm_live_bytes()
+            obs_tracer.instant("hbm_live_bytes", phase=phase, bytes=b)
+            obs_ledger.record_phase_hbm(phase, b)
 
     def _skip_poisoned_tree(self, exc) -> None:
         """Policy ``skip`` (ISSUE 13): drop the poisoned tree and keep
@@ -1085,10 +1082,13 @@ class GBDT:
                         tree_to_device(t, self.train_set))
                     self._device_linear.append(None)
                     continue
+                with obs_tracer.span("GradSlice", kidx=kidx):
+                    # eager device slices (two small programs a class;
+                    # placeholders in stream mode, sliced all the same)
+                    g_k, h_k = grad[kidx], hess[kidx]
                 try:
                     tree = self._train_one_tree(
-                        grad[kidx], hess[kidx], inbag, kidx,
-                        init_scores[kidx])
+                        g_k, h_k, inbag, kidx, init_scores[kidx])
                 except resilience_numerics.NumericsSkip as e:
                     self._skip_poisoned_tree(e)
                     should_continue = True
@@ -1104,19 +1104,8 @@ class GBDT:
         # all-stump iterations are nearly free, so a stall still stops
         # training within ~10 cheap iterations instead of the 32-flush.
         if self._nl_pending and self.iter_ % 8 == 0:
-            # FIFO dispatch completes in order, so probe only the HEAD
-            while self._nl_pending:
-                it, nl = self._nl_pending[0]
-                if hasattr(nl, "is_ready") and not nl.is_ready():
-                    break
-                self._nl_pending.pop(0)
-                self._nl_seen.setdefault(it, []).append(int(nl))
-            for it, counts in list(self._nl_seen.items()):
-                if len(counts) == self._nl_expected.get(it, -1):
-                    if all(c <= 1 for c in counts):
-                        self._stalled = True
-                    del self._nl_seen[it]
-                    del self._nl_expected[it]
+            with obs_tracer.span("StallProbe"):
+                self._probe_stall()
         # fallback periodic flush keeps host trees warm and catches the
         # stall even if is_ready never reports
         if self._pending and self.iter_ % 32 == 0:
@@ -1127,6 +1116,21 @@ class GBDT:
             log.warning("Stopped training because there are no more leaves "
                         "that meet the split requirements")
         return not should_continue
+
+    def _probe_stall(self) -> None:
+        # FIFO dispatch completes in order, so probe only the HEAD
+        while self._nl_pending:
+            it, nl = self._nl_pending[0]
+            if hasattr(nl, "is_ready") and not nl.is_ready():
+                break
+            self._nl_pending.pop(0)
+            self._nl_seen.setdefault(it, []).append(int(nl))
+        for it, counts in list(self._nl_seen.items()):
+            if len(counts) == self._nl_expected.get(it, -1):
+                if all(c <= 1 for c in counts):
+                    self._stalled = True
+                del self._nl_seen[it]
+                del self._nl_expected[it]
 
     def _train_iter_batched(self, grad, hess, inbag,
                             init_scores) -> bool:
@@ -1186,30 +1190,17 @@ class GBDT:
             hK = hess * act[:, None]
         with global_timer.time("GBDT::grow"), \
                 obs_tracer.span("Tree::grow", batched=k) as _gsp:
-            if obs_tracer.enabled and self._obs_counters:
-                for kidx in range(k):
-                    if active[kidx]:
-                        self._trace_grow_phases(
-                            grad[kidx], hess[kidx], inbag, fmK[kidx])
             obs_events.record("grow_dispatch")
             taK, leaf_idK = self.grow.grow_batch(
                 self.dd.bins, gK, hK, inbag, fmK,
                 self.dd.num_bins, self.dd.has_nan, self.dd.is_cat,
                 np.asarray(seeds, np.int32))
             if obs_tracer.enabled:
-                _gsp.block_on(leaf_idK)
+                _gsp.wait(leaf_idK)
+                self._record_work_counters(
+                    _gsp, taK, [kidx for kidx in range(k) if active[kidx]])
         if obs_tracer.enabled:
             self._sample_phase_hbm("Tree::grow")
-        if self._obs_counters:
-            ctrK = getattr(self.grow, "last_counters", None)
-            if ctrK is not None:
-                ctrK = np.asarray(ctrK)
-                for kidx in range(k):
-                    if not active[kidx]:
-                        continue
-                    d = obs_counters.record(np.asarray(ctrK[kidx]))
-                    for _name, _val in d.items():
-                        obs_tracer.count(_name, _val, kidx=kidx)
         badK = None
         if (self._numerics in ("raise", "skip")
                 and getattr(self.grow, "last_numerics_bad", None)
@@ -1374,7 +1365,6 @@ class GBDT:
     def _train_one_tree(self, g, h, inbag, kidx, init_score) -> Optional[Tree]:
         """Grow, renew, shrink, update scores; returns finalized host Tree
         or None when the tree is a stump (no split possible)."""
-        ctr = None
         # held so a numerics sentinel below can roll the CEGB paid
         # mask back when it drops the tree that advanced it (the grow
         # call does not donate this buffer, so the old array stays
@@ -1385,13 +1375,6 @@ class GBDT:
             tree_seed = (self.iter_ * max(self.num_tree_per_iteration, 1)
                          + kidx)
             fmask = self._feature_mask(tree_seed)
-            if obs_tracer.enabled and self._obs_counters:
-                # sampled per-phase dispatches (ConstructHistogram /
-                # FindBestSplits / Split) — see _trace_grow_phases.
-                # Serial learner only (_obs_counters is set exactly
-                # there): the probes jit single-device ops and must not
-                # touch the mesh learners' sharded global arrays
-                self._trace_grow_phases(g, h, inbag, fmask)
             # grow-dispatch ledger pin (ISSUE 19): the serial loop pays
             # one grow dispatch PER CLASS TREE; the batched multiclass
             # path records exactly one per iteration
@@ -1413,30 +1396,16 @@ class GBDT:
                     self.dd.num_bins, self.dd.has_nan, self.dd.is_cat,
                     tree_seed, self._cegb_paid)
                 ta, leaf_id, self._cegb_paid = out[:3]
-                if self._obs_counters and len(out) > 3:
-                    ctr = out[3]
             else:
-                out = self.grow(
+                ta, leaf_id = self.grow(
                     self.dd.bins, g, h, inbag, fmask,
                     self.dd.num_bins, self.dd.has_nan, self.dd.is_cat,
                     tree_seed)
-                ta, leaf_id = out[0], out[1]
-                if self._obs_counters:
-                    # the physical wrapper strips the vector itself and
-                    # parks it on .last_counters; the plain jitted grow
-                    # appends it to the return tuple
-                    ctr = (out[2] if len(out) > 2
-                           else getattr(self.grow, "last_counters", None))
             if obs_tracer.enabled:
-                _gsp.block_on(leaf_id)
+                _gsp.wait(leaf_id)
+                self._record_work_counters(_gsp, ta, [kidx])
         if obs_tracer.enabled:
             self._sample_phase_hbm("Tree::grow")
-        if ctr is not None:
-            # host pull of 4 floats — only while tracing, where the grow
-            # span above already barriered the dispatch chain
-            d = obs_counters.record(np.asarray(ctr))
-            for _name, _val in d.items():
-                obs_tracer.count(_name, _val, kidx=kidx)
         if (self._numerics in ("raise", "skip")
                 and getattr(self.grow, "last_numerics_bad", None)
                 is not None):
@@ -1525,81 +1494,33 @@ class GBDT:
         self._device_linear.append(self._linear_params_of(tree))
         return tree
 
-    _phase_probe = None
-
-    _obs_counters = False
-
-    def _trace_grow_phases(self, g, h, inbag, fmask) -> None:
-        """Sampled reference-phase timings while tracing.
-
-        The whole tree grows inside ONE jitted loop (ops/grow.py), so
-        true per-split ConstructHistogram / FindBestSplits / Split
-        times are not host-observable without de-fusing the loop.  With
-        tracing on we dispatch each phase's REAL op once per tree at
-        root scale — the histogram build, the best-split search over
-        it, and the partition compaction of the winning split — each
-        barriered, and record them as child spans of Tree::grow tagged
-        ``sample="root"``.  Kernel-level attribution of the fused loop
-        itself comes from ``tools/profile_lib.xplane_capture``.
-        """
-        if (self.dd.bundle is not None or getattr(self, "_pre_part", False)
-                or self.num_tree_per_iteration < 1):
-            return
-        if self._stream_grad:
-            # stream mode keeps gradients in the row matrix; compute a
-            # real gradient sample for the probe from current scores
-            g, h = self._compute_gradients(self.get_training_score())
-            g, h, inbag = g[0], h[0], self._valid_rows
-        if self._phase_probe is None:
-            from ..ops.histogram import build_histogram
-            from ..ops.split import find_best_split
-            hp = self.hp
-            bins = self.dd.bins
-            pb = self.dd.padded_bins
-            rpb = self.config.tpu_rows_per_block
-            nbins, hn, ic = (self.dd.num_bins, self.dd.has_nan,
-                             self.dd.is_cat)
-            mono = self._grow_kwargs.get("monotone")
-            mono = None if mono is None else jnp.asarray(mono, jnp.int32)
-            n_rows = int(bins.shape[0])
-
-            @jax.jit
-            def p_hist(g, h, w):
-                gv = jnp.stack([g * w, h * w], axis=1)
-                return build_histogram(bins, gv, padded_bins=pb,
-                                       rows_per_block=rpb)
-
-            @jax.jit
-            def p_find(hist, g, h, w, fm):
-                sg, sh = jnp.sum(g * w), jnp.sum(h * w)
-                si = find_best_split(
-                    hist, sg, sh, jnp.sum(w), nbins, hn, ic, fm,
-                    jnp.asarray(True), hp, monotone=mono)
-                return si.feature, si.threshold_bin, si.gain
-
-            @jax.jit
-            def p_split(feat, sbin):
-                col = jnp.take(bins, feat, axis=1).astype(jnp.int32)
-                glb = col <= sbin
-                li = jnp.cumsum(glb.astype(jnp.int32))
-                ri = jnp.cumsum((~glb).astype(jnp.int32))
-                nleft = li[-1]
-                pos = jnp.arange(n_rows, dtype=jnp.int32)
-                dst = jnp.where(glb, li - 1, nleft + ri - 1)
-                return (jnp.zeros((n_rows,), jnp.int32).at[dst].set(pos),
-                        nleft)
-
-            self._phase_probe = (p_hist, p_find, p_split)
-        p_hist, p_find, p_split = self._phase_probe
-        with obs_tracer.span("ConstructHistogram", sample="root") as sp:
-            hist = p_hist(g, h, inbag)
-            sp.block_on(hist)
-        with obs_tracer.span("FindBestSplits", sample="root") as sp:
-            feat, sbin, gain = p_find(hist, g, h, inbag, fmask)
-            sp.block_on(gain)
-        with obs_tracer.span("Split", sample="root") as sp:
-            order, nleft = p_split(feat, sbin)
-            sp.block_on(nleft)
+    def _record_work_counters(self, span, ta, kidxs) -> None:
+        """The four work counters (obs/counters.py) of the tree(s) just
+        grown, derived on the host from the tree itself: one pull of
+        its small arrays (a few KB; a transfer, not a program) after
+        the ``Tree::grow`` barrier, recorded as before
+        (``obs_counters.record`` + ``tracer.count``) and set as args of
+        the ``Tree::grow`` span.  Only ever called while tracing, and
+        the same whether the tracer was enabled before the booster was
+        built or after: the grow program knows nothing of it.  A
+        batched grow hands ``ta`` stacked [K, ...]; ``kidxs`` then
+        names the active classes."""
+        with obs_tracer.span("WorkCounters"):
+            small = jax.device_get(
+                (ta.num_leaves, ta.left_child, ta.right_child,
+                 ta.internal_count, ta.leaf_count))
+        fused = (bool(getattr(self.grow, "fused", False))
+                 and jax.default_backend() == "tpu")
+        batched = np.ndim(small[0]) > 0
+        total: Dict[str, float] = {}
+        for kidx in kidxs:
+            arrs = tuple(a[kidx] for a in small) if batched else small
+            d = obs_counters.record(
+                obs_counters_from_tree(*arrs, fused=fused))
+            for name, val in d.items():
+                obs_tracer.count(name, val, kidx=kidx)
+                total[name] = total.get(name, 0.0) + val
+        span.set(**total)
 
     def _async_tail_fn(self):
         """One jitted dispatch for the whole post-grow tail (train-score
@@ -1639,14 +1560,20 @@ class GBDT:
         delta on device, matching the sync path's skip."""
         rate = self.shrinkage_rate
         tail = self._async_tail_fn()
-        new_score, new_vscores, dt = tail(
-            ta, leaf_id, self.train_score[kidx],
-            tuple(vs.bins for vs in self.valid_sets),
-            tuple(vs.score[kidx] for vs in self.valid_sets),
-            jnp.float32(rate), jnp.float32(init_score))
-        self.train_score = self.train_score.at[kidx].set(new_score)
-        for vs, sk in zip(self.valid_sets, new_vscores):
-            vs.score = vs.score.at[kidx].set(sk)
+        # the eager ops and the jitted tail each may block in the
+        # runtime (a launch, an allocation), so each has its own name
+        with obs_tracer.span("UpdateScore::set", op="slice"):
+            score_k = self.train_score[kidx]
+            vscores_k = tuple(vs.score[kidx] for vs in self.valid_sets)
+        with obs_tracer.span("UpdateScore::tail"):
+            new_score, new_vscores, dt = tail(
+                ta, leaf_id, score_k,
+                tuple(vs.bins for vs in self.valid_sets), vscores_k,
+                jnp.float32(rate), jnp.float32(init_score))
+        with obs_tracer.span("UpdateScore::set", op="set"):
+            self.train_score = self.train_score.at[kidx].set(new_score)
+            for vs, sk in zip(self.valid_sets, new_vscores):
+                vs.score = vs.score.at[kidx].set(sk)
         self._device_trees.append(dt)
         self._device_linear.append(None)
         self.models.append(None)
@@ -1692,6 +1619,10 @@ class GBDT:
         (per-array pulls pay a device-to-host transfer each)."""
         if not self._pending:
             return
+        with obs_tracer.span("FlushPending", trees=len(self._pending)):
+            self._flush_pending_impl()
+
+    def _flush_pending_impl(self) -> None:
         from ..ops.grow import pack_tree_arrays, unpack_tree_arrays
         # chunked so the jitted pack's trace size (14 ops/tree) stays
         # bounded no matter how many trees deferred; chunks PAD to CHUNK
@@ -1813,8 +1744,6 @@ class GBDT:
         Rank metrics (AUC/NDCG) evaluate ON DEVICE when possible — the
         host path pulls the full score vector every eval, ~44 MB/iter at
         Higgs scale with metric_freq=1; the device path pulls scalars."""
-        if not obs_tracer.enabled:
-            return self._eval_impl()
         with obs_tracer.span("Eval"):
             return self._eval_impl()
 

@@ -6,11 +6,13 @@ section):
 
 * ``tracer`` — nested wall-clock spans with device barriers, JSON-lines
   / Chrome-trace output.  Enable with ``LGBM_TPU_TRACE=/path.jsonl`` or
-  ``tracer.enable(path)``.  Phase names mirror the reference hot path
-  (BeforeTrain / ConstructHistogram / FindBestSplits / Split).
-* ``counters`` — per-tree device counters (splits, rows partitioned,
-  rows histogrammed, fused-kernel engagements) derived inside the grow
-  jit when tracing is on, plus ``hbm_live_bytes`` watermark sampling.
+  ``tracer.enable(path)``, before or after the booster is built: the
+  compiled programs are the same either way.  Barriers are child spans
+  ``<name>::wait``; JAX's builds are ``jax::*`` events.
+* ``counters`` — per-tree work counters (splits, rows partitioned,
+  rows histogrammed, fused-kernel engagements) derived on the host
+  from the finished tree while tracing, plus ``hbm_live_bytes``
+  watermark sampling.
 * ``ledger`` (``obs/metrics.py``) — the per-iteration time-series
   registry: phase-wall deltas, counter deltas, eval history, HBM
   watermark and mesh-collective records, embedded in ``bench/v3``
@@ -46,7 +48,8 @@ state (counters, events, ledger, warn-once caches) and is called
 between ``lgb.train`` runs.
 """
 from .counters import (COUNTER_NAMES, CounterStore, EventCounter,
-                       counters, counters_to_dict, events,
+                       counters, counters_from_tree, counters_to_dict,
+                       events,
                        hbm_high_water_bytes, hbm_live_bytes, on_reset)
 from .counters import reset_all as reset_run
 from .metrics import (LEDGER_SCHEMA, MULTICHIP_SCHEMA, RunLedger,
@@ -56,6 +59,7 @@ from .tracer import TRACE_ENV, TRACE_SCHEMA, Tracer, tracer
 __all__ = [
     "tracer", "Tracer", "TRACE_ENV", "TRACE_SCHEMA",
     "counters", "CounterStore", "COUNTER_NAMES", "counters_to_dict",
+    "counters_from_tree",
     "events", "EventCounter", "hbm_live_bytes", "hbm_high_water_bytes",
     "ledger", "RunLedger", "LEDGER_SCHEMA", "MULTICHIP_SCHEMA",
     "provenance",

@@ -433,13 +433,13 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     by bench.py since bench/v3).
 
     Predictions are matched to what each measured span actually
-    covers.  The tree grows inside ONE jitted loop, so the traced
-    ``Split`` / ``ConstructHistogram`` walls are root-scale SAMPLED
-    dispatches — one per tree, over the full in-bag row range
-    (gbdt._trace_grow_phases) — and their rows here price exactly that
-    one dispatch per tree.  The whole-loop totals derived from the
-    device counters (every split of every tree) are reported as
-    ``Tree::grow``, whose measured span does cover the full loop.
+    covers.  The tree grows inside ONE jitted loop, so the span the
+    host can time is ``Tree::grow``, which covers every split of the
+    tree: its row carries the whole-loop totals derived from the work
+    counters (every split of every tree).  Per-kernel attribution of
+    the loop is the device trace's (``kernel_model``); the root-scale
+    sampled ``Split`` / ``ConstructHistogram`` probes that used to be
+    priced here went with their producer (ISSUE 27).
     Partition copyback traffic is data-dependent (the right-segment
     size of every split), so partition rows carry ``bytes_lo`` /
     ``bytes_hi`` bounds (all-left / all-right) with ``bytes`` at the
@@ -478,16 +478,8 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
         }
 
     out: Dict[str, Dict[str, float]] = {}
-    # sampled root-scale dispatches: one per tree over the in-bag range
     root_rows = n_rows * trees
-    out["Split"] = _part_row(root_rows)
-    out["ConstructHistogram"] = {
-        "bytes": root_rows * lrb
-        + trees * hist_out_bytes(f_pad, padded_bins),
-        "flops": float(hist_flops(root_rows, f_pad=f_pad,
-                                  padded_bins=padded_bins)),
-    }
-    # whole-loop totals from the device counters — joined with the
+    # whole-loop totals from the work counters — joined with the
     # Tree::grow wall, which is the span that covers every split.
     # Histogram traffic mirrors the per-split contracts above: fused
     # writes BOTH children per split and re-reads nothing (children

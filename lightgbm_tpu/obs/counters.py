@@ -1,9 +1,11 @@
-"""Device training counters and live-buffer watermarks.
+"""Training work counters and live-buffer watermarks.
 
-The grow loop (``ops/grow.py``) derives a small counter vector inside
-the SAME jit that grows the tree — no extra dispatches — when built
-with ``counters=True`` (the booster requests that iff tracing is on,
-so the default compiled HLO is untouched).  Counter semantics:
+The four work counters of a tree are functions of the finished tree,
+so while tracing the booster derives them on the host
+(``counters_from_tree``) from one pull of the tree's small arrays after
+the ``Tree::grow`` barrier — no second grow program, no extra
+dispatch, and the same whether the tracer was enabled before the
+booster was built or after.  Counter semantics:
 
   splits            — splits taken (== num_leaves - 1 of the tree)
   rows_partitioned  — in-bag rows moved by the physical/logical
@@ -15,8 +17,9 @@ so the default compiled HLO is untouched).  Counter semantics:
                       child of every split (the subtraction trick,
                       serial_tree_learner.cpp:287-327)
   fused_splits      — splits executed by the fused partition+histogram
-                      Pallas kernel (LGBM_TPU_FUSED path); 0 on the
-                      unfused / non-physical paths
+                      Pallas kernel (LGBM_TPU_FUSED path): ``splits``
+                      on that route on a TPU, 0 on the unfused /
+                      non-physical / interpreted paths
 
 Plus HBM watermark sampling: ``hbm_live_bytes`` is the cheap
 ``jax.live_arrays`` census of live device buffers (catches leaks and
@@ -52,6 +55,33 @@ def counters_to_dict(vec) -> Dict[str, float]:
     """Name a raw [4] counter vector from the grow call."""
     a = np.asarray(vec, np.float64).reshape(-1)
     return {name: float(a[i]) for i, name in enumerate(COUNTER_NAMES)}
+
+
+def counters_from_tree(num_leaves, left_child, right_child,
+                       internal_count, leaf_count, *,
+                       fused: bool) -> np.ndarray:
+    """The [4] counter vector (``COUNTER_NAMES`` order) of one finished
+    tree, from its host arrays.  Counts are integral f32 below 2^24
+    each; sums run in float64, exact far beyond the ~n*log2(L) a tree
+    can reach (84M at Higgs 10.5M)."""
+    splits = int(num_leaves) - 1
+    leaf_c = np.asarray(leaf_count, np.float64)
+    if splits <= 0:
+        # a stump: the root pass is all the work there was
+        return np.array([0.0, 0.0, leaf_c[0], 0.0])
+    int_c = np.asarray(internal_count, np.float64)[:splits]
+
+    def child_count(child):
+        # leaves are encoded ~leaf and read leaf_count, inner nodes
+        # read internal_count
+        c = np.asarray(child, np.int64)[:splits]
+        return np.where(c < 0, leaf_c[np.clip(-c - 1, 0, len(leaf_c) - 1)],
+                        int_c[np.clip(c, 0, splits - 1)])
+
+    smaller = np.minimum(child_count(left_child), child_count(right_child))
+    # node 0 is the root: its count is the root pass
+    return np.array([splits, int_c.sum(), int_c[0] + smaller.sum(),
+                     splits if fused else 0], np.float64)
 
 
 class CounterStore:

@@ -4,15 +4,16 @@ Generalizes ``utils/timer.py`` (the reference ``Common::Timer`` /
 ``FunctionTimer`` analog, utils/common.h:973) from flat named
 accumulators into a structured trace: nested spans, JSON-lines output
 that doubles as Chrome-trace events, per-phase accumulators, and
-counter channels.  Phase names mirror the reference hot path
-(BeforeTrain / ConstructHistogram / FindBestSplits / Split,
-serial_tree_learner.cpp) so traces are comparable across ports.
+counter channels.
 
-Enable with ``LGBM_TPU_TRACE=/path/to/trace.jsonl`` (read at first
-use), or programmatically via ``tracer.enable(path)``.  Disabled (the
-default) every ``span`` entry is a single attribute check — the hot
-path pays nothing and the booster compiles the exact same HLO (see
-tests/test_obs.py::test_tracing_off_changes_nothing).
+**The rule: turning the tracer on, at any moment, changes no compiled
+program and dispatches no extra one; it only adds names.**  It may be
+enabled before the booster is built (``LGBM_TPU_TRACE=/path.jsonl``,
+read at first use) or after (``tracer.enable(path)``, at any
+iteration): the grow program and every other program are the same
+either way (``tests/test_obs.py`` holds the lowered text equal, the
+``grow-tracer-live`` purity pin the jaxpr).  Disabled (the default)
+every ``span`` entry is a single attribute check.
 
 Output format: one JSON object per line.  The first line is a metadata
 record carrying the schema version; every span line is a valid Chrome
@@ -20,22 +21,61 @@ record carrying the schema version; every span line is a valid Chrome
 ``python -m lightgbm_tpu.obs report --chrome out.json`` only has to
 wrap the lines in an array for chrome://tracing / Perfetto.
 
+The span tree of one boosting iteration (serial learner, fast path;
+the names the per-layer metrics of ``benchmarks/`` are keyed on)::
+
+    Train::iteration                    engine.py, one per iteration
+      GBDT::TrainOneIter
+        BeforeTrain                     bagging, boost-from-average
+          Boosting                      gradient pass (not in stream mode)
+            Boosting::wait
+        HbmCensus                       live-array census (obs mem)
+        GradSlice                       eager grad[k], hess[k]
+        GBDT::grow                      utils/timer.py's twin of the next
+          Tree::grow                    args: the four work counters
+            Tree::grow::wait            the device runs the grow program
+            WorkCounters                pull of the tree's small arrays
+        HbmCensus
+        UpdateScore
+          UpdateScore::tail             dispatch of the score/valid tail
+          UpdateScore::set              eager slice + .at[].set
+          UpdateScore::wait
+        HbmCensus
+        StallProbe                      every 8th iteration
+        FlushPending                    every 32nd iteration
+      Eval                              when a metric is due
+      Callbacks                         cbs_after (a benchmark's pulls)
+
 Device work is asynchronous under JAX: a span that covers a dispatch
 measures only the enqueue unless it blocks.  ``span(...)`` yields a
-handle; call ``handle.block_on(x)`` to make span exit run
-``jax.block_until_ready(x)`` before the clock stops (the host-pull
-barrier the profiling tools use lives one level up, in
-``tools/profile_lib.py``).
+handle; ``handle.block_on(x)`` makes span exit run
+``jax.block_until_ready(x)`` before the clock stops, and
+``handle.wait(x)`` runs the same barrier at once.  Either way the
+barrier is recorded as a child span ``<name>::wait``, so a parent's
+time outside its ``::wait`` child is its own: dispatch and host work.
+The parent's duration is what it always was.
 
-Xplane correlation (ISSUE 6): while an xplane capture is active —
-``tools/profile_lib.xplane_capture`` (and ``bench.py`` under
-``LGBM_TPU_XPLANE``) toggles ``tracer.annotate(True)`` — every span
-additionally enters a ``jax.profiler.TraceAnnotation("obs::<name>")``,
-so the capture's host plane carries the obs phase names and
-``python -m lightgbm_tpu.obs attr`` (obs/xattr.py) can join device
-kernels back to phases.  Off by default: with no capture active the
-span fast path is byte-for-byte the PR-2 one and the counters=False
-grow jaxpr pin is untouched.
+Build events: while enabled, the tracer listens to JAX's monitoring
+durations and records each as an ``X`` event that ends at the
+callback and lasts the reported seconds, with ``parent`` the span open
+on that thread: ``jax::trace`` (a jaxpr was traced), ``jax::lower``
+(lowered to MLIR), ``jax::backend_compile`` (compiled, or fetched:
+JAX reports the fetch under this name too) and ``jax::cache_load``
+(read from the persistent cache, inside the former).  A stall that is
+a load or a retrace says so, inside the span it happened in.
+
+Work counters are derived on the host from the finished tree
+(``obs/counters.counters_from_tree``) after the ``Tree::grow``
+barrier, and set as args of that span.
+
+Xplane correlation: while ``tracer.annotate(True)`` — a profiler
+capture is live — every span additionally enters a
+``jax.profiler.TraceAnnotation("obs::<name>")``, so the capture's host
+plane carries the span names on the device's clock.  Spans already
+open on the calling thread when annotation is switched on are mirrored
+from that moment, and spans still open when it is switched off are
+closed on the mirror at that moment, so the edges of a capture taken
+from inside a callback are named too.
 """
 from __future__ import annotations
 
@@ -45,24 +85,58 @@ import json
 import os
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional
 
 TRACE_SCHEMA = "lightgbm_tpu/trace/v1"
 TRACE_ENV = "LGBM_TPU_TRACE"
+WAIT_SUFFIX = "::wait"
+
+# JAX's monitoring durations, by the names the installed JAX gives
+# them (jax/_src/dispatch.py, jax/_src/compiler.py), and the build
+# event each is recorded as
+BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax::trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax::lower",
+    "/jax/core/compile/backend_compile_duration": "jax::backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax::cache_load",
+}
+# JAX has no public way to take a listener back, so one module-level
+# listener is registered at the first enable() and hands each duration
+# to the tracers that are enabled at that moment
+_LISTENING: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_registered = False
+
+
+def _on_jax_duration(event: str, secs: float, **kwargs) -> None:
+    name = BUILD_EVENTS.get(event)
+    if name is None:
+        return
+    for t in list(_LISTENING):
+        if t._enabled:
+            t._build_event(name, secs, kwargs)
 
 
 class _SpanHandle:
     """Mutable handle yielded by ``Tracer.span``: lets the body attach
     late args and a device value to barrier on at exit."""
 
-    __slots__ = ("args", "_block")
+    __slots__ = ("args", "_block", "_tracer", "_name")
 
-    def __init__(self, args: dict):
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.args = args
         self._block = None
+        self._tracer = tracer
+        self._name = name
 
     def block_on(self, value) -> None:
         self._block = value
+
+    def wait(self, value) -> None:
+        """The barrier ``block_on`` defers to span exit, run now (as
+        the same ``<name>::wait`` child), for a body that has host work
+        to do after the device is done."""
+        self._tracer._barrier(self._name, value)
 
     def set(self, **kwargs) -> None:
         self.args.update(kwargs)
@@ -76,6 +150,9 @@ class _NoopHandle:
     args: dict = {}
 
     def block_on(self, value) -> None:
+        pass
+
+    def wait(self, value) -> None:
         pass
 
     def set(self, **kwargs) -> None:
@@ -118,6 +195,7 @@ class Tracer:
         (summary / counters still work; nothing is written)."""
         self._env_checked = True
         self._enabled = True
+        self._listen()
         if path and path != self._path:
             self._close_file()
             self._path = path
@@ -132,12 +210,40 @@ class Tracer:
         self._env_checked = True
         self._enabled = False
 
+    def _listen(self) -> None:
+        """Hear JAX's build durations from now on (``_on_jax_duration``
+        drops them while this tracer is disabled)."""
+        global _registered
+        _LISTENING.add(self)
+        if not _registered:
+            try:
+                from jax import monitoring
+                monitoring.register_event_duration_secs_listener(
+                    _on_jax_duration)
+                _registered = True
+            except Exception:   # no jax: spans and counters still work
+                pass
+
     def annotate(self, on: bool) -> None:
         """Toggle ``jax.profiler.TraceAnnotation`` emission around
-        spans — on only while an xplane capture is active
-        (``profile_lib.xplane_capture`` flips it), so device events can
-        be joined back to obs phases by ``obs attr``."""
-        self._annotate = bool(on)
+        spans — on only while an xplane capture is active, so that the
+        capture's host plane carries the span names on the device's
+        clock.  Called from inside open spans (a callback that starts
+        or stops a capture), the calling thread's open spans are
+        mirrored from, respectively up to, this moment: the profiler
+        keeps only annotations that begin and end while it runs."""
+        on = bool(on)
+        if on == self._annotate:
+            return
+        self._annotate = on
+        stack = self._stack()
+        if on:
+            for entry in stack:
+                if entry[1] is None:
+                    entry[1] = self._mirror(entry[0])
+        else:
+            for entry in reversed(stack):
+                self._unmirror(entry)
 
     @property
     def annotating(self) -> bool:
@@ -168,10 +274,40 @@ class Tracer:
 
     # -- spans -----------------------------------------------------------
     def _stack(self) -> list:
+        """This thread's open spans, outermost first: ``[name, mirror]``
+        with ``mirror`` the live TraceAnnotation or None."""
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
         return st
+
+    @staticmethod
+    def _mirror(name: str):
+        """Enter ``obs::<name>`` on the capture's host plane."""
+        try:
+            import jax.profiler
+            annotation = jax.profiler.TraceAnnotation("obs::" + name)
+            annotation.__enter__()
+            return annotation
+        except Exception:   # no live profiler session / old jax
+            return None
+
+    @staticmethod
+    def _unmirror(entry: list) -> None:
+        if entry[1] is not None:
+            try:
+                entry[1].__exit__(None, None, None)
+            except Exception:
+                pass
+            entry[1] = None
+
+    def _barrier(self, name: str, value) -> None:
+        """``jax.block_until_ready(value)`` as the child span
+        ``<name>::wait``: what the host spent waiting for the device
+        (or sat in the runtime), apart from its own work."""
+        with self.span(name + WAIT_SUFFIX):
+            import jax
+            jax.block_until_ready(value)
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
@@ -182,42 +318,39 @@ class Tracer:
             yield _NOOP_HANDLE
             return
         stack = self._stack()
-        handle = _SpanHandle(dict(args))
-        parent = stack[-1] if stack else None
-        annotation = None
-        if self._annotate:
-            # mirror the span as a TraceMe region on the capture's host
-            # plane; entered before the clock starts and exited after
-            # the device barrier so the annotated window covers what
-            # the span wall covers
-            try:
-                import jax.profiler
-                annotation = jax.profiler.TraceAnnotation("obs::" + name)
-                annotation.__enter__()
-            except Exception:   # no live profiler session / old jax
-                annotation = None
-        stack.append(name)
+        handle = _SpanHandle(self, name, dict(args))
+        parent = stack[-1][0] if stack else None
+        # the mirror is entered before the clock starts and exited
+        # after the device barrier so the annotated window covers what
+        # the span wall covers
+        entry = [name, self._mirror(name) if self._annotate else None]
+        stack.append(entry)
         start = time.perf_counter()
         try:
             yield handle
         finally:
             try:
                 if handle._block is not None:
-                    import jax
-                    jax.block_until_ready(handle._block)
+                    self._barrier(name, handle._block)
             finally:
                 # the span must unwind and record even when the barrier
                 # surfaces a device error — a stale stack entry would
                 # corrupt every later span's parent/depth in this thread
                 dur = time.perf_counter() - start
                 stack.pop()
-                if annotation is not None:
-                    try:
-                        annotation.__exit__(None, None, None)
-                    except Exception:
-                        pass
+                self._unmirror(entry)
                 self._record(name, start, dur, parent, len(stack),
                              handle.args)
+
+    def _build_event(self, name: str, secs: float, kwargs: dict) -> None:
+        """One of JAX's build durations as a complete event that ends
+        now, inside the span open on this thread."""
+        stack = self._stack()
+        end = time.perf_counter()
+        args = {k: v for k, v in kwargs.items()
+                if isinstance(v, (str, int, float))}
+        self._record(name, end - secs, secs,
+                     stack[-1][0] if stack else None, len(stack), args)
 
     def _record(self, name, start, dur, parent, depth, args) -> None:
         with self._lock:

@@ -38,8 +38,8 @@ xplane capture is active (``tools/profile_lib.xplane_capture`` /
 ``LGBM_TPU_XPLANE`` through ``bench.py``) every obs span also enters a
 ``jax.profiler.TraceAnnotation("obs::<name>")``, so host-plane TraceMe
 events carry the obs phase names and xprof timelines line up with the
-trace JSONL.  Off by default — the counters=False grow jaxpr pin is
-untouched.
+trace JSONL.  Off by default; on or off, the grow program is the same
+(the ``grow-tracer-live`` purity pin).
 """
 from __future__ import annotations
 
@@ -531,9 +531,7 @@ CLASS_ORDER: Tuple[str, ...] = tuple(c for c, _ in KERNEL_CLASSES) \
 
 # which kernel classes execute under which traced obs phase — the
 # phase <-> kernel join (host wall minus summed device time = dispatch
-# overhead).  The sampled root-scale probes (Split /
-# ConstructHistogram / FindBestSplits) dispatch the same kernels, so
-# only the two phases whose walls cover WHOLE dispatch windows join.
+# overhead): the two phases whose walls cover WHOLE dispatch windows.
 PHASE_KERNELS: Dict[str, Tuple[str, ...]] = {
     "Tree::grow": ("fused_split", "partition_scan",
                    "partition_copyback", "hist_build", "find_split",

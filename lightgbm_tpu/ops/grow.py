@@ -408,13 +408,6 @@ def make_grow_fn(
                              # (ISSUE 15) — physical serial only; the
                              # plan geometry must match the engaged
                              # comb layout exactly
-    counters: bool = False,  # telemetry (obs/counters.py): grow returns
-                             # an extra [4] i32 vector [splits,
-                             # rows_partitioned, rows_histogrammed,
-                             # fused_splits] derived from the finished
-                             # loop state INSIDE the same jit — no
-                             # loop-carried additions, no extra
-                             # dispatches; False compiles identical HLO
     numerics: str = "off",   # NaN/Inf guardrails (ISSUE 13,
                              # resilience/numerics.py): "clamp"
                              # sanitizes grad/hess at the grow entry;
@@ -449,12 +442,6 @@ def make_grow_fn(
     """
     L = int(num_leaves)
     fax = feature_axis_name
-    use_counters = bool(counters) and not debug_state
-    if use_counters and (axis_name is not None
-                         or feature_axis_name is not None):
-        raise ValueError(
-            "telemetry counters are wired for the serial learner only "
-            "(the mesh growers' out_specs do not carry the vector)")
     if numerics not in ("off", "raise", "skip", "clamp"):
         raise ValueError(
             f"numerics must be off/raise/skip/clamp, got {numerics!r}")
@@ -2207,43 +2194,6 @@ def make_grow_fn(
             num_leaves=state.num_leaves,
             cat_members=state.cat_members,
         )
-        if use_counters:
-            # telemetry counters (obs/counters.py), derived from the
-            # finished loop state inside this jit: splits and
-            # rows_partitioned reproduce the tree structure EXACTLY
-            # (num_leaves - 1 and the internal_count sum); rows_
-            # histogrammed is the root pass plus every split's smaller
-            # child (the subtraction trick's real histogram work); the
-            # fused count marks splits run by the fused
-            # partition+histogram kernel.
-            # counts live in f32 state but are integral and < 2^24 each
-            # (the physical row-id limit); SUMS must accumulate in i32 —
-            # an f32 sum rounds above 2^24 and the per-tree totals can
-            # reach ~n*log2(L) (84M at Higgs 10.5M) — so exactness holds
-            # to 2^31 partitioned rows per tree
-            splits_i = state.num_leaves - jnp.int32(1)
-            ni_live = (jnp.arange(L - 1, dtype=jnp.int32)
-                       < state.num_leaves - 1)
-            rows_part = jnp.sum(jnp.where(
-                ni_live, nodes[:, 9], 0.0).astype(jnp.int32))
-            lc_i = nodes[:, 5].astype(jnp.int32)
-            rc_i = nodes[:, 6].astype(jnp.int32)
-
-            def _cnt_of(c):
-                # child count: leaves (~leaf encoding) read lstate, inner
-                # nodes read internal_count
-                leaf_c = lstate[jnp.clip(-c - 1, 0, L - 1), _SC]
-                int_c = nodes[jnp.clip(c, 0, max(L - 2, 0)), 9]
-                return jnp.where(c < 0, leaf_c, int_c)
-
-            small_c = jnp.minimum(_cnt_of(lc_i), _cnt_of(rc_i))
-            rows_hist = (c0.astype(jnp.int32)
-                         + jnp.sum(jnp.where(
-                             ni_live, small_c, 0.0).astype(jnp.int32)))
-            fused_i = jnp.int32(1 if (physical and not _phys_interp
-                                      and _use_fused) else 0)
-            ctr = jnp.stack([splits_i, rows_part, rows_hist,
-                             splits_i * fused_i])
         # reconstruct the per-row leaf assignment ONCE from the partition
         # (row_order/permuted rows + seg tile [0, n)), instead of
         # scattering a [n] leaf_id vector on every split: sort leaves by
@@ -2263,10 +2213,6 @@ def make_grow_fn(
         else:
             leaf_id = jnp.zeros((n,), jnp.int32).at[state.row_order].set(
                 leaf_of_pos)
-        def _out(*xs):
-            """Append the counter vector to any return shape."""
-            return xs + ((ctr,) if use_counters else ())
-
         if debug_state:
             return tree, leaf_id, state.best, state.lstate
         if physical and stream is not None:
@@ -2288,15 +2234,15 @@ def make_grow_fn(
                 # the blocks it already holds in VMEM
                 comb_r, root_next = _refresh_fn(
                     state.comb, lv_row.reshape(1, n))
-                return _out(tree, leaf_id, comb_r, state.scratch,
-                            root_next)
+                return (tree, leaf_id, comb_r, state.scratch,
+                        root_next)
             comb_r = _refresh_fn(state.comb, lv_row.reshape(1, n))
-            return _out(tree, leaf_id, comb_r, state.scratch)
+            return tree, leaf_id, comb_r, state.scratch
         if physical:
-            return _out(tree, leaf_id, state.comb, state.scratch)
+            return tree, leaf_id, state.comb, state.scratch
         if use_cegb_lazy:
-            return _out(tree, leaf_id, state.paid)
-        return _out(tree, leaf_id)
+            return tree, leaf_id, state.paid
+        return tree, leaf_id
 
     if physical:
         if _fused_root:
@@ -2403,7 +2349,7 @@ def make_grow_fn(
             stream_init=(_stream_init_fn
                          if stream is not None else None),
             dtype=_COMB_DT, fused=_use_fused,
-            root0_fn=_root0_fn, counters=use_counters,
+            root0_fn=_root0_fn,
             pack=_comb_pack, ingest=_efb_ingest,
             paged_plan=paged, reanchor_fn=_reanchor_fn))
 
@@ -2483,7 +2429,7 @@ class _PhysicalGrow:
 
     def __init__(self, grow_p, bins_dev, n_alloc, C, f_pad,
                  stream_init=None, dtype=jnp.float32, fused=False,
-                 root0_fn=None, counters=False, pack=1, ingest=None,
+                 root0_fn=None, pack=1, ingest=None,
                  paged_plan=None, reanchor_fn=None):
         self._grow_p = grow_p
         self._bins_dev = bins_dev
@@ -2504,8 +2450,6 @@ class _PhysicalGrow:
         self.fused = fused           # fused partition+histogram splits
         self._root0_fn = root0_fn    # fused stream: tree-0 root hist
         self._root_hist = None       # fused stream: carried root hist
-        self.counters = counters     # telemetry vector rides the return
-        self.last_counters = None    # [4] device vector of the last call
         # paged comb (ISSUE 15): pages live host-side between trees and
         # stream through the double-buffered page buffers per call
         self.paged = paged_plan      # plan dict or None
@@ -2631,8 +2575,6 @@ class _PhysicalGrow:
                 feature_mask, num_bins, has_nan, is_cat, seed, rate)
             ta, leaf_id, comb_n, self._scratch = out[:4]
         self._put_window(comb_n)
-        if self.counters:
-            self.last_counters = out[-1]
         return ta, leaf_id
 
     def batched_fn(self):
@@ -2651,7 +2593,6 @@ class _PhysicalGrow:
         dispatches."""
         if self._grow_batch_p is None:
             raw = self._grow_p.__wrapped__
-            use_ctr = self.counters
 
             def _scan_k(comb, scratch, gradK, hessK, inbag, fmK,
                         num_bins, has_nan, is_cat, seedK):
@@ -2662,15 +2603,11 @@ class _PhysicalGrow:
                               num_bins, has_nan, is_cat, sd,
                               jnp.float32(0.0))
                     ta, lid, comb_n, scr_n = out[:4]
-                    ys = (ta, lid) + ((out[-1],) if use_ctr else ())
-                    return (comb_n, scr_n), ys
+                    return (comb_n, scr_n), (ta, lid)
 
                 (comb, scratch), ys = jax.lax.scan(
                     body, (comb, scratch), (gradK, hessK, fmK, seedK))
-                res = (ys[0], ys[1], comb, scratch)
-                if use_ctr:
-                    res = res + (ys[2],)
-                return res
+                return ys[0], ys[1], comb, scratch
 
             self._grow_batch_p = jax.jit(_scan_k, donate_argnums=(0, 1))
         return self._grow_batch_p
@@ -2700,9 +2637,6 @@ class _PhysicalGrow:
             self._comb, self._scratch, gradK, hessK, inbag, fmK,
             num_bins, has_nan, is_cat, jnp.asarray(seedK, jnp.int32))
         taK, leaf_idK, self._comb, self._scratch = out[:4]
-        if self.counters:
-            # stacked [K, 4] — the caller records per-class rows
-            self.last_counters = out[-1]
         return taK, leaf_idK
 
     def paged_geometry(self):
@@ -2732,7 +2666,7 @@ class _NumericsGuard:
       NumericsSkip) so the async dispatch chain stays intact until the
       booster decides to look.
 
-    Everything else (``pack``, ``last_counters``, ``set_stream_aux``,
+    Everything else (``pack``, ``fused``, ``set_stream_aux``,
     ``reset_stream``) delegates to the wrapped callable.  ``off``
     never constructs this class at all — ``make_grow_fn`` returns the
     unwrapped program (the ``grow-numerics-off`` purity pin)."""
@@ -2770,5 +2704,5 @@ class _NumericsGuard:
 
     def __getattr__(self, name):
         # only reached when normal lookup fails: delegate wrapped-fn
-        # attributes (pack, counters, last_counters, stream hooks)
+        # attributes (pack, fused, stream hooks)
         return getattr(self._fn, name)

@@ -523,6 +523,7 @@ def make_apply_find(hp: SplitHyperParams, *, L: int, f: int, b: int,
                    best, lstate, nodes, seg):
         return pl.pallas_call(
             kern,
+            name="lgbm_apply_find",
             in_specs=[smem(), smem(), vmem(), vmem(), vmem(), smem(),
                       smem(),
                       vmem(), vmem(), vmem(), vmem()],
@@ -565,6 +566,7 @@ def make_apply_find_pool(hp: SplitHyperParams, *, L: int, f: int, b: int,
         # h_small and pool use the [.., F, 4, B] channel-second layout
         return pl.pallas_call(
             kern,
+            name="lgbm_apply_find",
             in_specs=[smem(), smem(), vmem(), vmem(), vmem(), smem(),
                       smem(),
                       vmem(), vmem(), vmem(), vmem(), hbm()],

@@ -345,6 +345,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     def _call(sel, rows, scratch, grid_blocks):
         rows1, scratch1, res, hist2 = pl.pallas_call(
             kern,
+            name="lgbm_split_scan",
             grid=(grid_blocks,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                       pl.BlockSpec(memory_space=_HBM),
@@ -416,6 +417,7 @@ def _make_fused_p2(n: int, *, R: int, size: int, dtype, dynamic: bool,
     def _call(sel, rows, scratch, grid_blocks):
         rows1, scratch1, res, hist2 = pl.pallas_call(
             kern,
+            name="lgbm_split_scan",
             grid=(grid_blocks,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                       pl.BlockSpec(memory_space=_HBM),

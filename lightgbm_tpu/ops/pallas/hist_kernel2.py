@@ -224,6 +224,7 @@ def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
     )
     out = pl.pallas_call(
         kern,
+        name="lgbm_hist",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ngroups, m, nn), jnp.float32),
         interpret=interpret,
@@ -338,6 +339,7 @@ def build_histogram_pallas2(
                              ngroups=ngroups)
     out = pl.pallas_call(
         kern,
+        name="lgbm_hist",
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((rpb, f_pad), lambda i: (i, 0),

@@ -324,6 +324,7 @@ def copyback_call(sel, rows1, scratch1, nleft, m, *, R: int,
     nb_cb = jnp.maximum(-(-m // cb_block), 1)
     return pl.pallas_call(
         cb_kern,
+        name="lgbm_copyback",
         grid=(nb_cb,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=_HBM),

@@ -561,6 +561,7 @@ def copyback_call_p2(sel, rows1, scratch1, nleft, m, *, R: int,
     np_phys = n // 2
     return pl.pallas_call(
         cb_kern,
+        name="lgbm_copyback",
         grid=(nb_cb,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=_HBM),

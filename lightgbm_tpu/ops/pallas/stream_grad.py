@@ -514,6 +514,7 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
         def refresh_h(comb, lv2d):
             comb_r, out = pl.pallas_call(
                 kern_h,
+                name="lgbm_refresh",
                 grid=(nblocks,),
                 in_specs=[
                     pl.BlockSpec((1, R), lambda i: (0, i),
@@ -556,6 +557,7 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
     def refresh(comb, lv2d):
         return pl.pallas_call(
             kern,
+            name="lgbm_refresh",
             grid=(nblocks,),
             in_specs=[
                 pl.BlockSpec((1, R), lambda i: (0, i),
@@ -609,6 +611,7 @@ def _make_refresh_p2(*, kind, sigmoid, f, n_alloc, n_pad, C, R, dtype,
         def refresh_h(comb, lv2d):
             comb_r, out = pl.pallas_call(
                 kern_h,
+                name="lgbm_refresh",
                 grid=(nblocks,),
                 in_specs=[
                     pl.BlockSpec((2, P), lambda i: (0, i),
@@ -651,6 +654,7 @@ def _make_refresh_p2(*, kind, sigmoid, f, n_alloc, n_pad, C, R, dtype,
     def refresh(comb, lv2d):
         return pl.pallas_call(
             kern,
+            name="lgbm_refresh",
             grid=(nblocks,),
             in_specs=[
                 pl.BlockSpec((2, P), lambda i: (0, i),

@@ -28,7 +28,7 @@ counterpart, built the way "millions of users" deployments expect:
   ``obs.costmodel.serving_traversal_bytes``), **retrace-after-warmup**
   and **error-class events**.
 
-Purity discipline (the ``grow-counters-off`` pattern): the recorder
+Purity discipline (the ``grow-tracer-live`` pattern): the recorder
 lives entirely on the host side of the dispatch — nothing it does is
 visible to jit, so metrics on/off compiles the IDENTICAL serving
 program (the jitted entry is cached per (n_steps, digest) and shared);

@@ -4,8 +4,7 @@ Reference analog: Common::Timer / FunctionTimer (utils/common.h:973-1057),
 which accumulate per-phase wall time and dump at exit when built with
 -DUSE_TIMETAG.  Here timing is always available (enable with
 ``global_timer.enable()``) and phase names mirror the reference hot path
-(BeforeTrain / ConstructHistogram / FindBestSplits / Split) so traces are
-comparable.  Device work is asynchronous under JAX; callers that want accurate
+(BeforeTrain / Boosting / GBDT::grow) so traces are comparable.  Device work is asynchronous under JAX; callers that want accurate
 device timings should pass ``block=True`` which calls
 ``jax.block_until_ready`` on the result of the timed region.
 """

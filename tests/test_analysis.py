@@ -304,7 +304,7 @@ def test_cli_exit_codes(capsys):
 def test_purity_pins_registered_and_hold():
     from lightgbm_tpu.analysis import registry
     registry.collect()
-    assert {"grow-counters-off", "grow-obs-lifecycle",
+    assert {"grow-tracer-live", "grow-obs-lifecycle",
             "grow-numerics-off",
             "grow-pulse-off"} <= set(registry.PURITY_PINS)
     rep = run_analysis(passes=["purity-pin"], strict=True)

@@ -159,14 +159,56 @@ def _compile(builder, one_chip):
     return jax.jit(fn).lower(*args).compile()
 
 
+@pytest.fixture(scope="module")
+def compiled_text(one_chip, no_compile_cache):
+    """``COMPILES[name]`` through the v5e compiler, as HLO text; each
+    program is compiled once for all the tests that read it."""
+    texts = {}
+
+    def get(name):
+        if name not in texts:
+            texts[name] = _compile(COMPILES[name][0], one_chip).as_text()
+        return texts[name]
+
+    return get
+
+
 @pytest.mark.parametrize("name", sorted(COMPILES))
-def test_default_path_compiles_for_v5e(name, one_chip, no_compile_cache):
-    builder, is_kernel = COMPILES[name]
-    compiled = _compile(builder, one_chip)
-    if is_kernel:
-        assert "tpu_custom_call" in compiled.as_text(), (
+def test_default_path_compiles_for_v5e(name, compiled_text):
+    if COMPILES[name][1]:
+        assert "tpu_custom_call" in compiled_text(name), (
             f"{name} compiled without a Mosaic kernel — an interpret-"
             "mode or XLA fallback slipped onto the chip path")
+
+
+# The names the kernels of the ``higgs`` route carry into the compiled
+# program (``pallas_call(name=...)``).  The profiler's ``XLA Ops`` line
+# names an event by its HLO instruction, so these are what the per-layer
+# metrics of ``benchmarks/`` are keyed on (``split_scan_ms_per_iter``
+# reads ``lgbm_split_scan``); unnamed, the trace calls a kernel after
+# whatever encloses it (``%body.23``), which moves with any refactor.
+KERNEL_NAMES = {
+    "lgbm_split_scan": "fused_split_permute",
+    "lgbm_copyback": "fused_split_permute",
+    "lgbm_refresh": "stream_refresh_root",
+    "lgbm_hist": "hist_comb_root",
+    "lgbm_apply_find": "apply_find_pool_f32",
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNEL_NAMES))
+def test_kernel_names_reach_the_compiled_program(kernel, compiled_text):
+    import re
+    text = compiled_text(KERNEL_NAMES[kernel])
+    named = re.findall(
+        r"%(" + kernel + r")(?:\.\d+)? = [^\n]*custom-call\([^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"", text)
+    assert named, f"no Mosaic kernel named {kernel} in the compiled HLO"
+    # and no Mosaic kernel of these programs is left to be named after
+    # its surroundings
+    every = re.findall(r"%([A-Za-z_][\w-]*?)(?:\.\d+)? = [^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert set(every) <= set(KERNEL_NAMES), every
 
 
 # Off the default path, refused by the v5e compiler on jax 0.9.0 /

@@ -2,8 +2,11 @@
 
 Covers the ISSUE-2 acceptance contract: span nesting + JSON schema
 round-trip, enable/disable semantics, counter exactness against a
-deterministic tree, and — the critical one — that with tracing off the
-grow build is unchanged (same jaxpr, same outputs, no counter work).
+deterministic tree, and — the critical one, since ISSUE 27 in both
+directions — that the tracer changes no program: on before the booster
+is built, on after it, or never, the grow program lowers to the same
+text; barriers are ``<name>::wait`` children, JAX's builds are
+``jax::*`` events, and the work counters come from the finished tree.
 """
 import json
 import os
@@ -115,21 +118,160 @@ def test_tracer_enable_from_env(tmp_path, monkeypatch):
     assert meta["schema"] and [e["name"] for e in events] == ["via-env"]
 
 
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what the
+    tracer mirrors, in order, without a profiler session."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+
+
+@pytest.fixture
+def mirror_log(monkeypatch):
+    import jax.profiler
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    _FakeAnnotation.log = []
+    return _FakeAnnotation.log
+
+
+@pytest.mark.parametrize("annotating", [False, True])
+@pytest.mark.parametrize("how", ["block_on", "wait"])
+def test_barrier_is_a_nested_wait_span(how, annotating, mirror_log):
+    """A barrier is the child span ``<name>::wait`` (deferred to span
+    exit by ``block_on``, at once by ``wait``), nested inside its
+    parent, whose duration still covers it; both are mirrored as
+    ``obs::<name>`` only while annotating."""
+    import jax.numpy as jnp
+    t = Tracer()
+    t.enable(None)
+    t.annotate(annotating)
+    value = jnp.ones((8,), jnp.float32)
+    with t.span("outer"):
+        with t.span("Phase", kidx=0) as h:
+            getattr(h, how)(value)
+            h.set(after_barrier=how == "wait")
+    spans = [e for e in t.events
+             if e["ph"] == "X" and not e["name"].startswith("jax::")]
+    assert [e["name"] for e in spans] == ["Phase::wait", "Phase", "outer"]
+    wait, phase, _ = spans
+    assert wait["args"]["parent"] == "Phase" and wait["args"]["depth"] == 2
+    assert phase["args"]["parent"] == "outer"
+    assert phase["args"]["after_barrier"] == (how == "wait")
+    assert wait["ts"] >= phase["ts"]
+    assert wait["ts"] + wait["dur"] <= phase["ts"] + phase["dur"] + 1
+    entered = [n for kind, n in mirror_log if kind == "enter"]
+    assert entered == (["obs::outer", "obs::Phase", "obs::Phase::wait"]
+                       if annotating else [])
+    assert len(mirror_log) == 2 * len(entered)    # every one exited
+
+
+def test_annotate_mirrors_the_spans_open_around_a_capture(mirror_log):
+    """A capture started and stopped from inside a callback: the spans
+    already open are mirrored from ``annotate(True)`` on and closed on
+    the mirror at ``annotate(False)`` (innermost first), because the
+    profiler keeps only annotations that begin and end while it runs;
+    their own exit then mirrors nothing twice."""
+    t = Tracer()
+    t.enable(None)
+    with t.span("Train::iteration"):
+        with t.span("Callbacks"):
+            t.annotate(True)
+        with t.span("inside"):
+            pass
+        with t.span("Callbacks"):
+            t.annotate(False)
+    assert mirror_log == [
+        ("enter", "obs::Train::iteration"), ("enter", "obs::Callbacks"),
+        ("exit", "obs::Callbacks"),
+        ("enter", "obs::inside"), ("exit", "obs::inside"),
+        ("enter", "obs::Callbacks"),
+        ("exit", "obs::Callbacks"), ("exit", "obs::Train::iteration")]
+    assert [e["name"] for e in t.events] == [
+        "Callbacks", "inside", "Callbacks", "Train::iteration"]
+
+
+@pytest.mark.parametrize("built", [True, False])
+def test_a_build_is_a_jax_event_inside_the_open_span(built):
+    """A jit built inside an open span yields exactly one
+    ``jax::backend_compile`` (JAX reports a cache fetch under that name
+    too, with a ``jax::cache_load`` inside it) whose parent is that
+    span, lasting the seconds JAX reported and ending inside the span;
+    a call that builds nothing yields no ``jax::*`` event at all."""
+    import jax
+    fn = jax.jit(lambda v: v * 3.0 + 1.0)
+    arg = np.arange(24, dtype=np.float32).reshape(4, 6)
+    if not built:
+        fn(arg).block_until_ready()          # built outside the span
+    tracer.enable(None)
+    with tracer.span("UpdateScore::tail") as h:
+        h.block_on(fn(arg))
+    events = tracer.events
+    jax_events = [e for e in events if e["name"].startswith("jax::")]
+    if not built:
+        assert jax_events == []
+        return
+    compiles = [e for e in jax_events
+                if e["name"] == "jax::backend_compile"]
+    assert len(compiles) == 1
+    span = next(e for e in events if e["name"] == "UpdateScore::tail")
+    assert {"jax::trace", "jax::backend_compile"} <= {
+        e["name"] for e in jax_events}
+    for e in jax_events:
+        assert e["ph"] == "X" and e["dur"] > 0
+        assert e["args"]["parent"] == "UpdateScore::tail"
+        assert e["ts"] + e["dur"] <= span["ts"] + span["dur"] + 1
+    # a disabled tracer hears nothing
+    tracer.disable()
+    jax.jit(lambda v: v - 7.0)(arg).block_until_ready()
+    assert len(tracer.events) == len(events)
+
+
 # ---------------------------------------------------------------------
-# device counters
+# work counters
 # ---------------------------------------------------------------------
-def test_counters_match_tree_structure(tmp_path):
-    """Counters from the grow jit must reproduce the trained model's
+class _EnableAfter:
+    """A callback that turns the tracer on after iteration ``at`` —
+    the benchmark's way: the booster is long built by then."""
+
+    def __init__(self, at):
+        self.at = at
+
+    def __call__(self, env):
+        if env.iteration == self.at:
+            tracer.enable(None)
+
+
+@pytest.mark.parametrize("enabled_after", [None, 0])
+def test_counters_match_tree_structure(tmp_path, enabled_after):
+    """The host-derived counters must reproduce the trained model's
     actual tree structure: splits == num_leaves-1 summed, rows
-    partitioned == the internal_count sum."""
-    tracer.enable(str(tmp_path / "ctr.jsonl"))
+    partitioned == the internal_count sum — with the tracer live
+    before the booster is built, and enabled after its first
+    iteration (the trees from then on)."""
+    cbs = []
+    if enabled_after is None:
+        tracer.enable(str(tmp_path / "ctr.jsonl"))
+    else:
+        cbs = [_EnableAfter(enabled_after)]
     x, y = _make_problem()
     ds = lgb.Dataset(x, label=y, params={"max_bin": 63})
     bst = lgb.train({"objective": "binary", "num_leaves": 8,
                      "min_data_in_leaf": 20, "verbosity": -1,
-                     "max_bin": 63}, ds, num_boost_round=3)
+                     "max_bin": 63}, ds, num_boost_round=3,
+                    callbacks=cbs)
     bst._inner._flush_pending()
     models = bst._inner.models
+    if enabled_after is not None:
+        models = models[enabled_after + 1:]
+        assert len(models) == 2
     splits_model = sum(int(t.num_leaves) - 1 for t in models)
     rows_model = sum(int(t.internal_count.sum()) for t in models
                     if t.num_leaves > 1)
@@ -145,12 +287,18 @@ def test_counters_match_tree_structure(tmp_path):
     for rec, t in zip(counters.per_tree, models):
         assert rec["splits"] == int(t.num_leaves) - 1
     assert set(rec) == set(COUNTER_NAMES)
+    # ... and ride the Tree::grow spans as args, which is how a reducer
+    # that keeps only X events reads them
+    grows = [e for e in tracer.events if e["name"] == "Tree::grow"]
+    assert len(grows) == len(models)
+    for e, rec in zip(grows, counters.per_tree):
+        assert {k: e["args"][k] for k in COUNTER_NAMES} == rec
 
 
 def test_tracing_off_changes_nothing():
-    """With the tracer off: grow compiles the IDENTICAL jaxpr to a
-    counter-free build (no carried counter state, no extra outputs),
-    and training emits no events and records no counters.
+    """With the tracer off: training emits no events and records no
+    counters, and the grow program is the one a live tracer gets too
+    (no counter state, no extra outputs either way).
 
     Since ISSUE 7 the jaxpr-identity pins themselves live in the
     static analyzer's purity-pin REGISTRY (one source of truth for
@@ -165,15 +313,16 @@ def test_tracing_off_changes_nothing():
     from lightgbm_tpu.ops.split import SplitHyperParams
 
     registry.collect()
-    # the registered pins: counters=False == default build, and the
-    # obs tracer/ledger/reset lifecycle (ISSUE-5 hooks) leaks nothing
-    for pin in ("grow-counters-off", "grow-obs-lifecycle"):
+    # the registered pins: a build under a live tracer == the default
+    # build, and the obs tracer/ledger/reset lifecycle (ISSUE-5 hooks)
+    # leaks nothing
+    for pin in ("grow-tracer-live", "grow-obs-lifecycle"):
         findings = purity.check_pin(pin, registry.PURITY_PINS[pin])
         assert findings == [], \
             f"purity pin {pin} diverged: " \
             f"{[f.message for f in findings]}"
 
-    # counter-free build returns (tree, leaf_id) only, on real data
+    # the grow program returns (tree, leaf_id) only, on real data
     hp = SplitHyperParams(min_data_in_leaf=2)
     n, f, B = 128, 8, 32
     rng = np.random.default_rng(0)
@@ -192,38 +341,53 @@ def test_tracing_off_changes_nothing():
     bst = lgb.train({"objective": "binary", "num_leaves": 6,
                      "verbosity": -1, "max_bin": 63}, ds,
                     num_boost_round=2)
-    assert bst._inner._obs_counters is False
     assert counters.totals()["splits"] == 0
     assert tracer.events == []
 
 
-def test_counters_on_adds_one_output():
-    """counters=True appends exactly one [4] f32 vector to the grow
-    return and leaves (tree, leaf_id) bit-identical."""
-    import jax.numpy as jnp
+@pytest.mark.parametrize("entry", ["grow_serial", "grow_physical",
+                                   "grow_stream"])
+def test_grow_program_is_the_same_whenever_the_tracer_comes_on(entry):
+    """Turning the tracer on changes no compiled program: the grow
+    program (row-order, physical, and the stream route ``higgs`` takes)
+    lowers to the same text with the tracer enabled before the program
+    is built, after it, and never."""
+    import jax
 
-    from lightgbm_tpu.ops.grow import make_grow_fn
-    from lightgbm_tpu.ops.split import SplitHyperParams
+    from lightgbm_tpu.analysis import registry
+    builder = registry.collect()[entry].builder
 
-    hp = SplitHyperParams(min_data_in_leaf=2)
-    n, f, B = 128, 8, 32
-    rng = np.random.default_rng(1)
-    args = (jnp.asarray(rng.integers(0, 31, (n, f)).astype(np.uint8)),
-            jnp.asarray(rng.normal(size=n).astype(np.float32)),
-            jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32),
-            jnp.ones((f,), jnp.float32), jnp.full((f,), 31, jnp.int32),
-            jnp.zeros((f,), bool), jnp.zeros((f,), bool), jnp.int32(0))
-    ta0, lid0 = make_grow_fn(hp, num_leaves=8, padded_bins=B)(*args)
-    ta1, lid1, ctr = make_grow_fn(hp, num_leaves=8, padded_bins=B,
-                                  counters=True)(*args)
-    assert ctr.shape == (4,)
-    np.testing.assert_array_equal(np.asarray(lid0), np.asarray(lid1))
-    for a, b in zip(ta0, ta1):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    nl = int(ta1.num_leaves)
-    assert int(ctr[0]) == nl - 1
-    assert float(ctr[1]) == pytest.approx(
-        float(np.asarray(ta1.internal_count)[:nl - 1].sum()), abs=0.5)
+    def lowered(when):
+        if when == "before":
+            tracer.enable(None)
+        fn, args = builder()
+        if when == "after":
+            tracer.enable(None)
+        try:
+            with tracer.span("Tree::grow"):
+                return jax.jit(fn).lower(*args).as_text()
+        finally:
+            tracer.disable()
+
+    never = lowered("never")
+    assert "func.func" in never
+    assert lowered("before") == never
+    assert lowered("after") == never
+
+
+def test_counters_from_tree_by_hand():
+    """The four numbers on a tree small enough to check by eye: root
+    (100 rows) splits 60 | 40, then the 60 splits 45 | 15.  Leaves are
+    encoded ~leaf; padding past ``num_leaves`` is ignored."""
+    from lightgbm_tpu.obs import counters_from_tree
+    got = counters_from_tree(
+        3, left_child=[1, -1, 7, 7], right_child=[-2, -3, 7, 7],
+        internal_count=[100.0, 60.0, 999.0, 999.0],
+        leaf_count=[45.0, 40.0, 15.0, 999.0], fused=True)
+    # rows_histogrammed: the root pass + min(60, 40) + min(45, 15)
+    assert got.tolist() == [2.0, 160.0, 100.0 + 40.0 + 15.0, 2.0]
+    stump = counters_from_tree(1, [0], [0], [0.0], [77.0], fused=False)
+    assert stump.tolist() == [0.0, 0.0, 77.0, 0.0]
 
 
 # ---------------------------------------------------------------------
@@ -241,15 +405,23 @@ def test_training_trace_has_nested_grow_phases(tmp_path):
     tracer.close()
     events, _ = load_events(path)
     spans = {e["name"]: e for e in events if e["ph"] == "X"}
-    for name in ("Train::iteration", "GBDT::TrainOneIter", "BeforeTrain",
-                 "Boosting", "Tree::grow", "ConstructHistogram",
-                 "FindBestSplits", "Split", "UpdateScore"):
+    parents = {
+        "Train::iteration": None, "GBDT::TrainOneIter": "Train::iteration",
+        "BeforeTrain": "GBDT::TrainOneIter", "Boosting": "BeforeTrain",
+        "Boosting::wait": "Boosting", "HbmCensus": "GBDT::TrainOneIter",
+        "GradSlice": "GBDT::TrainOneIter",
+        "Tree::grow::wait": "Tree::grow", "WorkCounters": "Tree::grow",
+        "UpdateScore": "GBDT::TrainOneIter",
+        "UpdateScore::tail": "UpdateScore",
+        "UpdateScore::set": "UpdateScore",
+        "UpdateScore::wait": "UpdateScore",
+        "Eval": "Train::iteration", "Callbacks": "Train::iteration"}
+    for name, parent in parents.items():
         assert name in spans, f"missing span {name}"
-    # the reference grow phases nest under Tree::grow; gradient refresh
-    # nests under BeforeTrain
+        assert spans[name]["args"].get("parent") == parent, name
+    # the root-scale sampled probes went with their producer
     for name in ("ConstructHistogram", "FindBestSplits", "Split"):
-        assert spans[name]["args"]["parent"] == "Tree::grow"
-    assert spans["Boosting"]["args"]["parent"] == "BeforeTrain"
+        assert name not in spans
     assert spans["BeforeTrain"]["args"]["parent"] == "GBDT::TrainOneIter"
     # TraceCallback history carries the counter telemetry
     assert len(cb.history) == 3

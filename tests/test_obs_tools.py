@@ -261,28 +261,23 @@ class TestCostModelExactness:
                       "trees": 2, "stream": True},
             "knobs": {"comb_pack": 2, "partition": "permute",
                       "fused": True},
-            "phases": {"Split": {"total_s": 0.01, "count": 4,
-                                 "mean_s": 0.0025}},
+            "phases": {"Tree::grow": {"total_s": 0.01, "count": 2,
+                                      "mean_s": 0.005}},
         }
         model = costmodel.phase_model(rec)
         lrb = costmodel.logical_row_bytes(pack=2)
-        # Split/ConstructHistogram price the SAMPLED root-scale
-        # dispatches their measured walls cover: one per tree over the
-        # in-bag range (rows * trees), not the whole-loop counters
-        root_rows = 10_000 * 2
-        assert model["Split"]["bytes_lo"] == 2 * root_rows * lrb
-        assert model["Split"]["bytes_hi"] == 4 * root_rows * lrb
-        # the whole-loop counter totals land on Tree::grow (whose
-        # measured span covers every split)
-        assert model["Tree::grow"]["bytes"] > model["Split"]["bytes"]
+        # the whole-loop counter totals land on Tree::grow, the one
+        # span whose measured wall covers every split; the root-scale
+        # sampled Split / ConstructHistogram probes are gone (ISSUE 27)
+        assert "Split" not in model and "ConstructHistogram" not in model
         assert model["Tree::grow"]["bytes_lo"] >= 2 * 50_000 * lrb
-        assert "ConstructHistogram" in model and "Boosting" in model
+        assert model["Tree::grow"]["bytes_hi"] >= 4 * 50_000 * lrb
+        assert "Boosting" in model
         # only the partition copyback is data-dependent: bytes sits at
-        # the midpoint of the lo/hi bounds for every bounded row
-        for name in ("Split", "Tree::grow"):
-            m = model[name]
-            assert m["bytes"] == pytest.approx(
-                (m["bytes_lo"] + m["bytes_hi"]) / 2), name
+        # the midpoint of the lo/hi bounds
+        m = model["Tree::grow"]
+        assert m["bytes"] == pytest.approx(
+            (m["bytes_lo"] + m["bytes_hi"]) / 2)
         # unfused vs fused, mirroring the per-split contracts: the
         # smaller-child re-read comes back (rows_hist 40k vs the 20k
         # root passes) and one histogram write per split replaces two
@@ -295,10 +290,10 @@ class TestCostModelExactness:
             == (40_000 - 20_000) * lrb - 10 * hw
         rows = costmodel.roofline_table(rec, peak_bw_gbps=819,
                                         peak_tflops=197)
-        split = next(r for r in rows if r["phase"] == "Split")
-        assert split["gbps"] == pytest.approx(
-            model["Split"]["bytes"] / 0.01 / 1e9)
-        assert 0 < split["bw_util"] < 1
+        grow = next(r for r in rows if r["phase"] == "Tree::grow")
+        assert grow["gbps"] == pytest.approx(
+            model["Tree::grow"]["bytes"] / 0.01 / 1e9)
+        assert 0 < grow["bw_util"] < 1
         # untraced / pre-v3 records get a clear error, not a KeyError
         with pytest.raises(costmodel.RecordModelError,
                            match="TRACED bench/v3"):

@@ -228,8 +228,9 @@ def _check_trace(n_rows: int = 50_048, num_leaves: int = 31,
         from lightgbm_tpu.obs.report import load_events, phase_summary
         events, meta = load_events(path)   # raises on malformed lines
         names = {ev["name"] for ev in events}
-        need = {"BeforeTrain", "ConstructHistogram", "FindBestSplits",
-                "Split", "Boosting"}
+        need = {"BeforeTrain", "Tree::grow", "Tree::grow::wait",
+                "WorkCounters", "UpdateScore", "UpdateScore::tail",
+                "UpdateScore::set", "UpdateScore::wait", "HbmCensus"}
         missing = need - names
         if missing:
             raise RuntimeError(f"trace is missing phase spans: {missing}")
