@@ -111,6 +111,37 @@ def _space_of(aval) -> str:
     return "?"
 
 
+def _ref_info(role: str, aval) -> RefInfo:
+    return RefInfo(
+        role=role,
+        shape=tuple(int(d) for d in getattr(aval, "shape", ())),
+        dtype=str(getattr(aval, "dtype", "")),
+        space=_space_of(aval))
+
+
+def _scoped_peak(jaxpr) -> List[RefInfo]:
+    """The refs of the dearest chain of nested ``pl.run_scoped``
+    allocations inside a kernel jaxpr, as scratch: a scope's buffers
+    live while its body runs, so nested scopes add and sibling scopes
+    (the two parity branches of a ping-pong body) do not."""
+    best: List[RefInfo] = []
+    inner = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in getattr(inner, "eqns", ()):
+        own: List[RefInfo] = []
+        if eqn.primitive.name == "run_scoped":
+            # the body's invars are the allocated refs (what it closes
+            # over arrives as constvars)
+            body = eqn.params["jaxpr"]
+            own = [_ref_info("scratch", v.aval)
+                   for v in getattr(body, "jaxpr", body).invars]
+        for sub in _sub_jaxprs(eqn):
+            chain = own + _scoped_peak(sub)
+            if (sum(r.nbytes for r in chain)
+                    > sum(r.nbytes for r in best)):
+                best = chain
+    return best
+
+
 def pallas_calls(traced) -> List[PallasCallInfo]:
     """Extract every pallas_call (recursively) from a traced
     entrypoint."""
@@ -144,14 +175,8 @@ def pallas_calls(traced) -> List[PallasCallInfo]:
                 f"({n_scalar}+{n_in}+{n_out}+{n_scr}) do not cover "
                 f"{len(invars)} kernel refs — jax GridMapping layout "
                 f"drifted; update jaxpr_tools.pallas_calls")
-        refs = []
-        for role, v in zip(roles, invars):
-            aval = v.aval
-            refs.append(RefInfo(
-                role=role,
-                shape=tuple(int(d) for d in getattr(aval, "shape", ())),
-                dtype=str(getattr(aval, "dtype", "")),
-                space=_space_of(aval)))
+        refs = [_ref_info(role, v.aval) for role, v in zip(roles, invars)]
+        refs += _scoped_peak(kj)
         cp = p.get("compiler_params")
         vlim = None
         if cp is not None:

@@ -211,7 +211,7 @@ ENV_KNOBS: Dict[str, tuple] = {
     "LGBM_TPU_FUSED": ("1", "0 disables the fused partition+histogram "
                             "split kernel (separate pallas_call pair)"),
     "LGBM_TPU_PARTITION": ("permute", "single-scan partition packing: "
-                                      "permute (O(log R) rolls) or "
+                                      "permute (O(log R) rounds) or "
                                       "matmul ([R,R] one-hot)"),
     "LGBM_TPU_PART": ("ss", "3ph restores the 3-phase partition kernel "
                             "(implies the unfused split path)"),

@@ -220,7 +220,7 @@ def _empty_tree(num_leaves: int, cat_b: int = 0) -> TreeArrays:
 # LGBM_TPU_PART=3ph restores the 3-phase kernel (bisection knob);
 # LGBM_TPU_PART_R overrides the single-scan kernel's block rows.
 # LGBM_TPU_PARTITION selects the single-scan kernel's per-block
-# compaction: "permute" (default — roll-routing permutation,
+# compaction: "permute" (default — butterfly-routing permutation,
 # O(log R)/row, partition_kernel3) or "matmul" (the [R, R] one-hot
 # contraction, O(R)/row, partition_kernel2) — bit-identical packed
 # layouts, so trees match byte-for-byte across the knob (tpu_smoke

@@ -10,16 +10,18 @@ at 10.5M rows; docs/PERF_NOTES.md "Next levers" #3).
 This kernel runs the single-scan two-sided compaction UNCHANGED — same
 block schedule, same overlapping garbage-tail writes, same copyback
 sub-call, with the per-block packing selected through _scan_kernel's
-``pack_impl`` hook (permute roll-routing by default, the one-hot
+``pack_impl`` hook (permute butterfly routing by default, the one-hot
 matmul under LGBM_TPU_PARTITION=matmul; bit-identical packed layouts
 either way) — and additionally
 accumulates BOTH children's 2-channel (grad, hess) histograms in VMEM
 from the row block already resident for the compaction matmul:
 
-  * the split column is extracted a second time in ROW orientation
-    ([R, 1] matvec — the scan's [1, R] lane layout cannot mask the
-    [R, 2] value columns without a relayout), go-left bits recomputed,
-    and the block's values masked per side;
+  * the block's values are masked per side with the go-left /
+    go-right bits the permute compaction hands over (row-oriented and
+    lane-replicated, from its one MXU transpose); under the matmul
+    compaction, whose bits are lane-oriented, the split column is
+    extracted a second time in ROW orientation ([R, 1] matvec) and the
+    bits recomputed;
   * the nibble-decomposed one-hot contraction of hist_kernel2.py then
     accumulates each side into one [2, ngroups, M, N] VMEM block
     (constant index map -> resident across the dynamic grid).  The
@@ -34,8 +36,14 @@ from the row block already resident for the compaction matmul:
 
 Both sides are accumulated because the smaller child is only known when
 the scan finishes (and, under the mesh learners, only after a psum over
-shards) — the extra MXU work rides entirely under the scan's DMA
-shadow, while the unfused path's child-histogram HBM re-read is gone.
+shards); the unfused path's child-histogram HBM re-read is gone.  That
+trade is NOT free on the v5e: there is no DMA shadow to ride under.  A
+512-row step moves ~1.5 KB a row, 0.94 us at 819 GB/s, and takes 5.9
+us (11.4 ns a row visit at 10.5M rows; 9.7 us before ISSUE 28): the
+scan is bound by what it computes in VMEM, and of the 6.1k VLIW
+bundles of a step ~4.6k are this hook (both sides' one-hot
+contractions over EVERY parent row, where the unfused pair
+histograms only the smaller child's rows: PERF.md, Findings, PR 28).
 
 Layout/contract: identical to partition_kernel2.make_partition_ss, plus
 ``f_pad`` value/bin column conventions from hist_kernel2's comb-direct
@@ -65,8 +73,10 @@ from .partition_kernel2 import _scan_kernel, copyback_call
 _CHANNELS = 2       # (grad, hess) — the 2-channel histogram layout
 
 # VMEM budget for the resident [2, ngroups, M, N] accumulator pair (the
-# scan's four [R, C] buffers and the per-block one-hot temporaries ride
-# on top; cap conservatively below apply_find's scoped-VMEM limit)
+# scan's four [R, C] buffers, the permute compaction's three scoped
+# ones - routing word + two staging blocks, 768 KB at R = 512 - and the
+# per-block one-hot temporaries ride on top; cap conservatively below
+# apply_find's scoped-VMEM limit)
 _HIST_VMEM_CAP = 32 * 1024 * 1024
 
 
@@ -192,27 +202,35 @@ def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
     def _hist_init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
-    def _hist_block(x, blk, cnt):
+    def _hist_block(x, blk, cnt, side):
         # ---- dual histogram accumulation (the fusion) ----
-        # go-left bits again in ROW orientation: a [1, R] -> [R, 1]
-        # relayout is a Mosaic transpose; a second exact matvec
-        # against the same one-hot column is ~R*C MACs, noise next
-        # to the [R, R] compaction matmul
-        e_colv = (jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-                  == sel_ref[SEL_FEAT]).astype(jnp.float32)
-        col2 = jax.lax.dot_general(
-            x.astype(jnp.float32), e_colv,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [R, 1]
-        pos_c = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
-        valid2 = pos_c < (cnt - blk * R)
-        gl2 = _go_left(col2, sel_ref) & valid2
-        gr2 = jnp.logical_xor(gl2, valid2)
         # Mosaic has no direct bf16 -> i32 cast; hop through f32
         bins_i = x[:, :f_pad].astype(jnp.float32).astype(jnp.int32)
         v = x[:, f_pad:f_pad + _CHANNELS].astype(jnp.float32)
-        v_l = v * gl2.astype(jnp.float32)
-        v_r = v * gr2.astype(jnp.float32)
+        if side is not None:
+            # the compaction's own go-left / go-right bits, already
+            # row-oriented and lane-replicated: take the value lanes
+            flag_l, flag_r = side
+            glf = flag_l[:, f_pad:f_pad + _CHANNELS]
+            grf = flag_r[:, f_pad:f_pad + _CHANNELS]
+        else:
+            # the matmul compaction's bits are lane-oriented ([1, R]);
+            # a [1, R] -> [R, 1] relayout is a Mosaic transpose, a
+            # second exact matvec against the same one-hot column is
+            # ~R*C MACs, noise next to its [R, R] contraction
+            e_colv = (jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+                      == sel_ref[SEL_FEAT]).astype(jnp.float32)
+            col2 = jax.lax.dot_general(
+                x.astype(jnp.float32), e_colv,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)      # [R, 1]
+            pos_c = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+            valid2 = pos_c < (cnt - blk * R)
+            gl2 = _go_left(col2, sel_ref) & valid2
+            glf = gl2.astype(jnp.float32)
+            grf = jnp.logical_xor(gl2, valid2).astype(jnp.float32)
+        v_l = v * glf
+        v_r = v * grf
         _hist_accumulate2(bins_i, v_l, v_r, hist_ref, b_hi=b_hi,
                           g=g, lo_n=lo_n, ngroups=ngroups)
 
@@ -237,7 +255,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     [f_pad, padded_bins, 2] f32 histograms, accumulated during the scan.
 
     ``scan`` selects the per-block compaction plugged into the shared
-    schedule: ``"permute"`` (partition_kernel3's roll routing — the
+    schedule: ``"permute"`` (partition_kernel3's butterfly routing — the
     LGBM_TPU_PARTITION default) or ``"matmul"`` (the one-hot
     contraction).  Both produce bit-identical packed layouts, so the
     dual-histogram hooks and everything downstream are scheme-blind.
@@ -258,9 +276,10 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     ``interpret_kernel=True`` the partition piece is the REAL scan +
     copyback run through the Pallas interpreter (compiled row order),
     letting CPU tests pin the cross-scheme identity at kernel depth.
-    ``fused_kernel_interpret=True`` (pack=2 only) instead builds the
-    REAL fused scan+dual-histogram kernel and runs it through the
-    Pallas interpreter — the off-chip pin for the kernel body itself."""
+    ``fused_kernel_interpret=True`` instead builds the REAL fused
+    scan+dual-histogram kernel and runs it through the Pallas
+    interpreter (static grids only) — the off-chip pin for the kernel
+    body itself, hooks included."""
     from .layout import check_lane_width
     check_lane_width(C, dtype)
     if scan not in ("matmul", "permute"):
@@ -284,7 +303,11 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                               dynamic=dynamic, cb_block=cb_block,
                               f_pad=f_pad, b=b, b_hi=b_hi, g=g, m=m,
                               nn=nn, ngroups=ngroups, interpret=True)
-    if interpret:
+    if fused_kernel_interpret and dynamic:
+        raise ValueError(
+            "fused_kernel_interpret supports static grids only (the "
+            "Pallas interpreter cannot run a traced grid bound)")
+    if interpret and not fused_kernel_interpret:
         if pack == 2:
             from .partition_kernel3 import make_partition_p2
             part = make_partition_p2(
@@ -370,10 +393,12 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                             pltpu.SemaphoreType.DMA,
                             pltpu.SemaphoreType.DMA],
             input_output_aliases={1: 0, 2: 1},
+            interpret=fused_kernel_interpret,
         )(sel, rows, scratch)
         nleft, mm = res[0], res[1]
         rows2 = copyback_call(sel, rows1, scratch1, nleft, mm, R=R,
-                              cb_block=cb_block, n=n, C=C, dtype=dtype)
+                              cb_block=cb_block, n=n, C=C, dtype=dtype,
+                              interpret=fused_kernel_interpret)
         h_l = _diag_extract(hist2[0], ngroups, g, b_hi, _CHANNELS, _LO_N,
                             f_pad, b)
         h_r = _diag_extract(hist2[1], ngroups, g, b_hi, _CHANNELS, _LO_N,

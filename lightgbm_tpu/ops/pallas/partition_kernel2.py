@@ -54,7 +54,7 @@ Round 6 (ISSUE 3): the per-block compaction is now a PLUGGABLE
 ``pack_impl`` hook on ``_scan_kernel`` — the matmul packing below is
 the ``LGBM_TPU_PARTITION=matmul`` bisection scheme, while the default
 ``permute`` packing (partition_kernel3.py) computes destinations with
-prefix sums and moves rows with O(log R) roll routing, producing a
+prefix sums and moves rows with O(log R) butterfly routing, producing a
 bit-identical packed layout.  The schedule, cursor math and copyback
 in this file serve both schemes unchanged.
 
@@ -85,14 +85,17 @@ from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, \
 _CUR_L, _CUR_TL, _CUR_R = 0, 1, 2
 
 
-def _pack_matmul(x, sel_ref, cnt, blk, is_last, *, R: int, C: int):
+def _pack_matmul(x, sel_ref, cnt, blk, is_last, out_ref, *, R: int,
+                 C: int):
     """One-hot-matmul block compaction (the original single-scan
     scheme): left rows ascending at [loff, loff + nl), right rows
     REVERSED at [R - nr, R), via one [R, R] one-hot contraction.
-    Returns ``(packed [R, C], nl, nr)``.
+    Writes the packed [R, C] block to ``out_ref`` and returns ``(nl,
+    nr, None)``: its go-left bits are lane-oriented, so the scan's
+    ``block_cb`` computes its own.
 
     This is the ``LGBM_TPU_PARTITION=matmul`` packing; the default
-    permutation packing (same output layout, O(log R) roll routing
+    permutation packing (same output layout, O(log R) butterfly routing
     instead of the O(R)-per-row matmul) lives in
     partition_kernel3._pack_permute.  Both produce IDENTICAL packed
     buffers bit-for-bit for bf16-exact columns — the permute scheme
@@ -135,7 +138,8 @@ def _pack_matmul(x, sel_ref, cnt, blk, is_last, *, R: int, C: int):
     packed = jax.lax.dot_general(
         PT, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # [R, C]
-    return packed.astype(x.dtype), nl, nr
+    out_ref[:] = packed.astype(x.dtype)
+    return nl, nr, None
 
 
 def _scan_kernel(sel_ref, rows_in, scratch_in,
@@ -147,23 +151,28 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
     """Single-phase scan.  out_ref SMEM i32[2]: [0] nleft, [1] m (rows
     to copy back: left tail + right zone).
 
-    ``init_cb()`` / ``block_cb(x, blk, cnt)`` are OPTIONAL trace-time
-    hooks for
+    ``init_cb()`` / ``block_cb(x, blk, cnt, side)`` are OPTIONAL
+    trace-time hooks for
     kernels that extend the scan with extra per-block VMEM compute
     (fused_split.py accumulates child histograms from the resident
     block): init_cb runs in the blk == 0 init, block_cb runs on each
-    live block's [R, C] rows right after the compaction matmul, before
-    the write waits.  Hooks must not touch the DMA/cursor state — the
+    live block's [R, C] rows right after the compaction, before
+    the write waits; ``side`` is what the compaction hands on (below).
+    Hooks must not touch the DMA/cursor state — the
     schedule's safety argument above assumes this body is the only
     writer.
 
-    ``pack_impl(x, sel_ref, cnt, blk, is_last) -> (packed, nl, nr)``
-    swaps the per-block compaction implementation (default: the one-hot
-    matmul above; partition_kernel3 plugs the roll-routing permutation
-    in).  Every implementation must produce the SAME packed layout —
-    left rows ascending at [loff, loff + nl), right rows reversed at
-    [R - nr, R) — so the block schedule, cursor math and copyback stay
-    scheme-independent and have exactly one home here."""
+    ``pack_impl(x, sel_ref, cnt, blk, is_last, out_ref) -> (nl, nr,
+    side)`` swaps the per-block compaction implementation (default: the
+    one-hot matmul above; partition_kernel3 plugs the butterfly-routing
+    permutation in).  Every implementation must write the SAME packed
+    layout to ``out_ref`` — left rows ascending at [loff, loff + nl),
+    right rows reversed at [R - nr, R) — so the block schedule, cursor
+    math and copyback stay scheme-independent and have exactly one home
+    here.
+    ``side`` is ``None`` or the block's ``(go_left, go_right)`` bits as
+    [R, C] lane-replicated f32 0/1 arrays (invalid rows 0 in both), so
+    ``block_cb`` need not extract the split column a second time."""
     blk = pl.program_id(0)
     s0 = sel_ref[SEL_S0]
     cnt = sel_ref[SEL_CNT]
@@ -211,11 +220,10 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
 
             x = vx_cur[:]
             pack = pack_impl or functools.partial(_pack_matmul, R=R, C=C)
-            packed, nl, nr = pack(x, sel_ref, cnt, blk, is_last)
-            pk[:] = packed
+            nl, nr, side = pack(x, sel_ref, cnt, blk, is_last, pk)
 
             if block_cb is not None:
-                block_cb(x, blk, cnt)
+                block_cb(x, blk, cnt, side)
 
             # overlapping same-side writes must issue in order: wait the
             # previous same-side write first (its latency hid behind this
@@ -360,7 +368,7 @@ def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
     path's static bucket classes are exactly that shape.
 
     ``pack_impl`` swaps the per-block compaction (see _scan_kernel);
-    partition_kernel3.make_partition_perm passes the roll-routing
+    partition_kernel3.make_partition_perm passes the butterfly-routing
     permutation packing through here so the schedule has one home."""
     from .layout import check_lane_width
     check_lane_width(C, dtype)

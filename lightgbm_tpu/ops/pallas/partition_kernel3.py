@@ -4,46 +4,53 @@ single-scan partition (+ the pack=2 half-width comb variant).
 The single-scan kernel's block schedule (partition_kernel2.py: one
 read of the parent, overlapping garbage-tail writes behind a 1-block
 read-ahead, exactly-sized copyback) left ONE compute-bound stage: the
-per-block compaction ran as an [R, R] one-hot matmul — R*C MACs PER
-ROW (R=512, C=128: 65k), measured ~4.4x above the ~2.5 ns/row DMA
-floor at 10.5M rows (docs/PERF_NOTES.md round-3 composition; levers
-#1-2).  XGBoost's GPU partition computes row destinations with warp
-prefix sums and moves rows by address, never through a dense
-permutation matrix — this module is that idea in Mosaic terms:
+per-block compaction ran as an [R, R] one-hot matmul - R*C MACs PER
+ROW (R=512, C=128: 65k).  XGBoost's GPU partition computes row
+destinations with warp prefix sums and moves rows by address, never
+through a dense permutation matrix - this module is that idea in
+Mosaic terms.  What decides its cost on the chip is the shape of the
+arrays it works on: a vreg is [8, 128], so a [R, 1] column of per-row
+state is R / 8 vregs - as many as the whole [R, 128] block - and the
+v5e has 64 of them, so every such column is also spilled and
+reloaded.  A [K, R] array (K <= 8) is R / 128 vregs.  Hence (pack=1,
+ISSUE 28; 6.2k -> 1.5k VLIW bundles a block in the unfused scan,
+11.0k -> 6.1k in the fused one, PERF.md section 5):
 
-* per-row go-left bits in ROW orientation (one exact [R, C] x [C, 1]
-  matvec — the only MXU use left);
-* destinations from a SUBLANE Hillis-Steele prefix scan: log2(R)
-  rounds of static ``pltpu.roll`` + masked add on an [R, 1] vector —
-  O(log R) work per row;
-* the move itself as LSB-first BIT-SERIAL ROTATE ROUTING: log2(R)
-  rounds of (static sublane roll of the [R, C] block + per-row
-  select).  Each round moves every row whose remaining displacement
-  has the current bit set by 2^k rows.  For a strict compaction
-  (destinations strictly increasing over kept rows, dst[r] <= r,
-  displacement r - dst[r] non-decreasing) the routing is
-  collision-free and order-preserving: clearing bit k preserves the
-  non-decreasing displacement order, and the strict-monotonicity of
-  destinations bounds adjacent-row position gaps from below by 2^k
-  whenever exactly the upper row moves (tests/test_partition_perm.py
-  fuzzes this against a numpy oracle).  O(log R) selects per row
-  replace the O(R) MAC column of the one-hot matmul;
-* the right side is compacted ascending then REVERSED with log2(R)
-  constant index-XOR exchange rounds, reproducing the matmul scheme's
-  descending right order EXACTLY — so permute and matmul kernels
-  produce BIT-IDENTICAL row layouts (not just equal multisets) and
-  compiled trees match byte-for-byte across
-  ``LGBM_TPU_PARTITION=permute|matmul`` (the tpu_smoke identity gate);
-* the last block's left tail lands below the right zone via ONE
-  dynamic whole-block roll (``tpu.dynamic_rotate``).
+* everything that is ONE NUMBER A ROW lives in LANE orientation: the
+  split column (one exact [1, C] x [R, C]^T matvec), the go-left
+  bits, both sides' prefix positions (a lane Hillis-Steele scan on one
+  [2, R] array), the destinations, and all log2(R) rounds of the
+  routing's bookkeeping (_route_words);
+* rows move through an LSB-first BUTTERFLY: in round k the row in
+  slot j goes to slot j ^ k iff bit k of (j XOR its destination) is
+  set.  For a side's kept rows - consecutive destinations in row
+  order, ascending or DESCENDING - the routing is collision-free
+  (_route_words states the argument; tests/test_partition_perm.py
+  fuzzes it against a numpy oracle), so the right side goes straight
+  to the matmul scheme's reversed order and the last block's left
+  tail straight to its offset: no reversal pass, no dynamic rotate.
+  O(log R) selects per row replace the O(R) MAC column of the one-hot
+  matmul;
+* the finished routing word crosses to ROW orientation ONCE, lane-
+  replicated, through one small exact MXU contraction
+  (_rows_from_lanes), together with the go-left / go-right bits the
+  fused scan's histogram hook needs (it no longer extracts the split
+  column a second time);
+* on the [R, C] block the three in-vreg rounds are one sublane gather
+  a vreg, and the rounds across vregs run three at a time on eight
+  vregs held in registers (_route_rows): a vreg is loaded and stored
+  twice a side, not once a round.
 
-Because rows move through selects and rotates — never through the MXU
-— the permutation packing preserves ARBITRARY f32 column values
+permute and matmul kernels produce BIT-IDENTICAL row layouts (not
+just equal multisets) and compiled trees match byte-for-byte across
+``LGBM_TPU_PARTITION=permute|matmul`` (the tpu_smoke identity gate).
+
+Because rows move through selects and gathers - never through the MXU
+- the permutation packing preserves ARBITRARY f32 column values
 exactly; the matmul scheme's "columns must be bf16-exact" constraint
-now binds only the histogram kernels.  dtype-agnostic: the same
-routing runs on bf16 blocks at double lane density (the HBM-side
-(8,128)x2 bf16 tiling restriction on dynamic row offsets still gates
-``LGBM_TPU_COMB_DT=bf16``; see ops/grow.py).
+now binds only the histogram kernels.  dtype-agnostic in the
+interpreter (the HBM-side (8,128)x2 bf16 tiling restriction on dynamic
+row offsets still gates ``LGBM_TPU_COMB_DT=bf16``; see ops/grow.py).
 
 The block schedule itself is NOT duplicated: ``_pack_permute`` plugs
 into partition_kernel2's ``_scan_kernel`` through its ``pack_impl``
@@ -51,7 +58,10 @@ hook, so the DMA/cursor safety argument keeps exactly one home.
 
 ``pack=2`` (two logical rows per 128-lane line — ops/pallas/layout.py
 ``comb_layout``) has its own scan + copyback kernels at the bottom of
-this file: the same routing runs in the LOGICAL row domain (an extra
+this file, still in the older ROW-oriented form (sublane prefix scan,
+rotate routing, XOR-exchange reversal: _prefix_rows, _compact_logical,
+_reverse_rows; refused by Mosaic on the chip, ROADMAP A12): the
+routing runs in the LOGICAL row domain (an extra
 bit-0 round exchanges lane halves), every physical memref stays
 128-wide f32, and partition DMA bytes per logical row HALVE.  Cursor
 parity is absorbed by one dynamic logical roll of the packed buffer
@@ -82,9 +92,9 @@ def _row_iota(R: int):
 
 
 def _prefix_rows(v, *, R: int):
-    """Inclusive prefix sum along sublanes of a [R, 1] f32 vector:
-    log2(R) Hillis-Steele rounds of static roll + masked add (wrapped
-    lanes zeroed).  Exact for 0/1 flags (integer sums < 2^24)."""
+    """(pack=2 only.)  Inclusive prefix sum along sublanes of a [R, 1]
+    f32 vector: log2(R) Hillis-Steele rounds of static roll + masked
+    add (wrapped lanes zeroed).  Exact for 0/1 flags (integer sums < 2^24)."""
     row = _row_iota(R)
     p = v
     k = 1
@@ -94,31 +104,10 @@ def _prefix_rows(v, *, R: int):
     return p
 
 
-def _compact_rows(y, d, *, R: int):
-    """Route rows to ``dst[r] = r - d[r]`` (backward compaction) with
-    LSB-first bit-serial rotate routing.  ``d`` is [R, 1] i32: the
-    non-negative displacement for kept rows, 0 for garbage rows (they
-    never move and are freely overwritten).  Requires the kept rows'
-    destinations to be strictly increasing with d non-decreasing — the
-    compaction shape — for collision freedom (module docstring)."""
-    k = 1
-    while k < R:
-        dr = pltpu.roll(d, R - k, 0)       # d of the row at slot j + k
-        yr = pltpu.roll(y, R - k, 0)
-        arrive = jnp.bitwise_and(dr, k) > 0
-        depart = jnp.bitwise_and(d, k) > 0
-        y = jnp.where(arrive, yr, y)
-        # a slot whose row departed with no arrival keeps a stale copy;
-        # zero its displacement so the copy can never move again
-        d = jnp.where(arrive, dr - k, jnp.where(depart, 0, d))
-        k *= 2
-    return y
-
-
 def _reverse_rows(y, *, R: int):
-    """Full sublane reversal (slot j -> R - 1 - j) as log2(R) constant
-    index-XOR exchange rounds: y'[j] = y[j ^ 2^k] composes to the full
-    bit complement."""
+    """(pack=2 only.)  Full sublane reversal (slot j -> R - 1 - j) as
+    log2(R) constant index-XOR exchange rounds: y'[j] = y[j ^ 2^k]
+    composes to the full bit complement."""
     row = _row_iota(R)
     k = 1
     while k < R:
@@ -129,42 +118,230 @@ def _reverse_rows(y, *, R: int):
     return y
 
 
-def _pack_permute(x, sel_ref, cnt, blk, is_last, *, R: int, C: int):
+def _lane_iota(R: int):
+    return jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+
+
+def _prefix_lanes(v, *, R: int):
+    """Inclusive prefix sum along LANES of a [K, R] f32 array (K rows
+    scanned at once): log2(R) Hillis-Steele rounds of static lane roll
+    + masked add.  Exact for 0/1 flags.  A [K, R] array with K <= 8 is
+    R / 128 vregs, so a round costs what ONE row of the block costs in
+    row orientation."""
+    lane = _lane_iota(R)
+    p = v
+    k = 1
+    while k < R:
+        p = p + jnp.where(lane >= k, pltpu.roll(p, k, 1), 0.0)
+        k *= 2
+    return p
+
+
+def _xchg_lanes(a, k: int, *, R: int):
+    """a[:, j ^ k] for a [K, R] array (k a power of two < R)."""
+    hi = jnp.bitwise_and(_lane_iota(R), k) > 0
+    return jnp.where(hi, pltpu.roll(a, k, 1), pltpu.roll(a, R - k, 1))
+
+
+_SUB = 8          # rows of one 32-bit vreg: they move together
+
+
+def _vreg(v: int):
+    """Rows of vreg ``v`` of a [R, C] block."""
+    return slice(v * _SUB, (v + 1) * _SUB)
+
+
+def _xchg_rows(y, k: int):
+    """y[j ^ k] for a [n, C] array, k a power of two and a whole
+    number of vregs: a static renaming of vregs (aligned slices), no
+    data moves."""
+    return jnp.concatenate(
+        [y[(i ^ 1) * k:((i ^ 1) + 1) * k] for i in range(y.shape[0] // k)],
+        axis=0)
+
+
+def _route_words(flags, dst, *, R: int):
+    """The bookkeeping of the bit-serial routing, LANE-dense.
+
+    ``flags`` [K, R] bool marks, per side (one sublane each), the rows
+    that side keeps; ``dst`` [K, R] i32 their destination slots, which
+    within a side are consecutive in row order (ascending or
+    descending).  Rows move through an LSB-first butterfly: in round k
+    the row in slot j goes to slot j ^ k iff bit k of ``j ^ dst`` is
+    set.  After round k a kept row sits in the slot made of its source
+    index's bits above k and its destination's bits up to k; two kept
+    rows of one side that share the upper bits are fewer than 2k rows
+    apart, so their destinations differ by less than 2k and not by 0,
+    and the slots differ: no collision, in either direction of travel
+    (tests/test_partition_perm.py fuzzes it against a numpy oracle).
+    A slot whose row left without a successor keeps a stale copy; its
+    state is zeroed so the copy never moves again.
+
+    Returns the [K, R] i32 routing word of every slot j.  Bits 0-2:
+    the sublane, within j's own vreg, of the row that slot j holds
+    after the three in-vreg rounds (k = 1, 2, 4) - those rounds are one
+    sublane gather on the block.  Bit b >= 3: "in round k = 2**b slot j
+    takes the row of slot j ^ k".  The whole of it - 9 rounds at R =
+    512 - runs on R / 128 vregs; the [R, C] block sees only the
+    finished word."""
+    lane = _lane_iota(R)
+    rel = jnp.where(flags, jnp.bitwise_xor(lane, dst), 0)
+    word = jnp.broadcast_to(jnp.bitwise_and(lane, _SUB - 1), rel.shape)
+    k = 1
+    while k < R:
+        relx = _xchg_lanes(rel, k, R=R)
+        bit = jnp.bitwise_and(relx, k)
+        if k < _SUB:
+            word = jnp.where(bit > 0, _xchg_lanes(word, k, R=R), word)
+        else:
+            word = jnp.bitwise_or(word, bit)
+        # bits below k are never read again, so an arriving state keeps
+        # its bit k; a departed slot with no arrival is zeroed
+        rel = jnp.where(bit > 0, relx,
+                        jnp.where(jnp.bitwise_and(rel, k) > 0, 0, rel))
+        k *= 2
+    return word
+
+
+_PIECE_BITS = 8   # the routing word crosses the MXU in bf16-exact pieces
+_BIAS = 23        # ... on top of 2**23, so the f32 result's low mantissa
+#                   bits ARE the word: a bitcast, no f32 -> i32 convert
+
+
+def _rows_from_lanes(w, glf, grf, *, R: int, C: int):
+    """Lane-oriented [1, R] i32 word (< 2**23) and f32 side flags ->
+    row-oriented, lane-REPLICATED [R, C] arrays: the word (i32; bits
+    23 and up are the bias) and the two flags (f32).  One exact MXU
+    contraction over the 16 sublanes of a bf16 [16, R] operand does the
+    transpose AND the lane broadcast (out[r, c] = sum_k W[k, r] *
+    O[k, c]: integer operands < 256 against power-of-two weights, f32
+    accumulation below 2**24); a [1, R] -> [R, 1] relayout or a lane
+    broadcast of a column would cost a vreg op a row tile on the
+    vector units."""
+    m = (1 << _PIECE_BITS) - 1
+    pieces = [jnp.bitwise_and(jnp.right_shift(w, i * _PIECE_BITS), m)
+              .astype(jnp.float32) for i in range(3)]
+    one = jnp.ones((1, R), jnp.float32)
+    W = jnp.concatenate(
+        pieces + [one, glf, grf, jnp.zeros((10, R), jnp.float32)],
+        axis=0).astype(jnp.bfloat16)                     # [16, R]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (16, 3 * C), 0)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (16, 3 * C), 1) // C
+    # column block 0: the word (rows 0-2 its pieces, row 3 the bias);
+    # blocks 1 / 2: left / right flag (rows 4 / 5)
+    O = jnp.where(blk == 0, 0.0, (sub == blk + 3).astype(jnp.float32))
+    for i, e in enumerate((0, _PIECE_BITS, 2 * _PIECE_BITS, _BIAS)):
+        O = jnp.where((blk == 0) & (sub == i), float(1 << e), O)
+    out = jax.lax.dot_general(
+        W, O.astype(jnp.bfloat16), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)              # [R, 3C]
+    return (jax.lax.bitcast_convert_type(out[:, 0:C], jnp.int32),
+            out[:, C:2 * C], out[:, 2 * C:3 * C])
+
+
+def _route_rows(x, w_ref, shift: int, stg_ref, emit, *, R: int):
+    """Move the rows of the [R, C] block ``x`` through the butterfly
+    that the lane-replicated word in ``w_ref`` (bits [shift, shift +
+    log2 R) of it) describes, and hand every finished vreg to
+    ``emit(v, rows8)``.
+
+    Staged so that a vreg is loaded and stored once for every THREE
+    rounds, not once a round: a stage takes the 2**m vregs whose
+    indices differ in its m <= 3 bits, runs those rounds on them in
+    registers and puts them down again (``stg_ref`` [2, R, C] between
+    stages).  Stage 0 also does the in-vreg rounds, as one sublane
+    gather a vreg."""
+    nv = (R // _SUB).bit_length() - 1       # index bits of a vreg
+    chunks = [range(b, min(b + 3, nv)) for b in range(0, nv, 3)] or [()]
+    for s, bits in enumerate(chunks):
+        m, b0 = len(bits), (bits[0] if bits else 0)
+        last = s == len(chunks) - 1
+        for g in range(R // _SUB >> m):
+            # the stage's groups: vreg bits [b0, b0 + m) free, rest = g
+            base = (g >> b0 << b0 + m) | (g & (1 << b0) - 1)
+            vs = [base | c << b0 for c in range(1 << m)]
+            wg = jnp.concatenate([w_ref[_vreg(v)] for v in vs], axis=0)
+            if s == 0:
+                idx = jnp.bitwise_and(
+                    jnp.right_shift(wg, shift) if shift else wg, _SUB - 1)
+                yg = jnp.concatenate(
+                    [jnp.take_along_axis(
+                        x[_vreg(v)], idx[_vreg(c)], axis=0,
+                        mode="promise_in_bounds")
+                     for c, v in enumerate(vs)], axis=0)
+            else:
+                yg = jnp.concatenate(
+                    [stg_ref[(s - 1) % 2, _vreg(v)] for v in vs], axis=0)
+            for i, b in enumerate(bits):
+                k = _SUB << b
+                yg = jnp.where(jnp.bitwise_and(wg, k << shift) > 0,
+                               _xchg_rows(yg, _SUB << i), yg)
+            for c, v in enumerate(vs):
+                if last:
+                    emit(v, yg[_vreg(c)])
+                else:
+                    stg_ref[s % 2, _vreg(v)] = yg[_vreg(c)]
+
+
+def _pack_permute(x, sel_ref, cnt, blk, is_last, out_ref, *, R: int,
+                  C: int):
     """Permutation packing for _scan_kernel's pack_impl hook: same
     output layout as _pack_matmul (left rows ascending at [loff,
     loff + nl), right rows REVERSED at [R - nr, R)) with O(log R)
-    roll-routing per row instead of the [R, R] one-hot contraction."""
-    # split column + go-left bits in ROW orientation (one exact matvec;
-    # same construction as fused_split's dual-histogram hook)
-    e_colv = (jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-              == sel_ref[SEL_FEAT]).astype(jnp.float32)
+    routing per row instead of the [R, R] one-hot contraction.
+
+    Everything that is one number a row - the split column, go-left
+    flags, prefix positions, destinations and the nine rounds' masks -
+    is computed in LANE orientation ([K, R]: R / 128 vregs an
+    operation, where a [R, 1] column costs R / 8) and crosses to row
+    orientation once, through the MXU (_rows_from_lanes).  Writes the
+    packed block to ``out_ref`` and returns ``(nl, nr, (flag_l,
+    flag_r))``; the flags are the [R, C] lane-replicated f32 go-left /
+    go-right bits, for the scan's ``block_cb``."""
+    nb = R.bit_length() - 1
+    lane = _lane_iota(R)
+    e_col = (jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+             == sel_ref[SEL_FEAT]).astype(jnp.float32)
     col = jax.lax.dot_general(
-        x.astype(jnp.float32), e_colv, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # [R, 1]
-    row = _row_iota(R)
-    valid = row < (cnt - blk * R)
+        e_col, x.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)              # [1, R]
+    valid = lane < (cnt - blk * R)
     gl = _go_left(col, sel_ref) & valid
     gr = jnp.logical_xor(gl, valid)
     glf = gl.astype(jnp.float32)
     grf = gr.astype(jnp.float32)
     nl = jnp.sum(glf).astype(jnp.int32)
     nr = jnp.sum(grf).astype(jnp.int32)
-    # exclusive prefix positions -> backward displacements (0 for
-    # garbage rows: they never move)
-    pos_l = (_prefix_rows(glf, R=R) - glf).astype(jnp.int32)
-    pos_r = (_prefix_rows(grf, R=R) - grf).astype(jnp.int32)
-    d_l = jnp.where(gl, row - pos_l, 0)
-    d_r = jnp.where(gr, row - pos_r, 0)
-    yl = _compact_rows(x, d_l, R=R)                      # left at [0, nl)
-    yr = _reverse_rows(_compact_rows(x, d_r, R=R), R=R)  # right rows at
-    #                                [R - nr, R), reversed — the exact
-    #                                order the matmul scheme produces
-    # last block: left tail directly below the right zone (ONE dynamic
-    # whole-block rotate; 0 on every other block)
+    f2 = jnp.concatenate([glf, grf], axis=0)             # [2, R]
+    pos = (_prefix_lanes(f2, R=R) - f2).astype(jnp.int32)
+    # last block: left tail directly below the right zone
     loff = jnp.where(is_last, R - nr - nl, 0)
-    yl = pltpu.roll(yl, loff, 0)
-    packed = jnp.where(row >= R - nr, yr, yl)
-    return packed.astype(x.dtype), nl, nr
+    left = jax.lax.broadcasted_iota(jnp.int32, (2, R), 0) == 0
+    dst = jnp.where(left, loff + pos, (R - 1) - pos)
+    word = _route_words(f2 > 0, dst, R=R)
+    # both sides in one word: left at bits [0, nb), right at [nb, 2nb)
+    w, flag_l, flag_r = _rows_from_lanes(
+        jnp.bitwise_or(word[0:1], jnp.left_shift(word[1:2], nb)),
+        glf, grf, R=R, C=C)
+
+    def _route(w_ref, stg_ref):
+        w_ref[...] = w
+
+        def _left(v, piece):          # left at [loff, loff + nl)
+            out_ref[_vreg(v)] = piece
+
+        def _right(v, piece):         # right REVERSED at [R - nr, R)
+            row = v * _SUB + jax.lax.broadcasted_iota(
+                jnp.int32, (_SUB, C), 0)
+            out_ref[_vreg(v)] = jnp.where(row >= R - nr, piece,
+                                          out_ref[_vreg(v)])
+
+        _route_rows(x, w_ref, 0, stg_ref, _left, R=R)
+        _route_rows(x, w_ref, nb, stg_ref, _right, R=R)
+
+    pl.run_scoped(_route, pltpu.VMEM((R, C), jnp.int32),
+                  pltpu.VMEM((2, R, C), x.dtype))
+    return nl, nr, (flag_l, flag_r)
 
 
 def perm_pack_impl(R: int, C: int):
@@ -172,11 +349,12 @@ def perm_pack_impl(R: int, C: int):
     schedule — single home for the power-of-two precondition, used by
     make_partition_perm AND fused_split.make_fused_split so the fused
     and unfused paths cannot diverge on it."""
-    if R & (R - 1):
+    if R & (R - 1) or not _SUB <= R <= 1 << (_BIAS - 1) // 2:
+        # both sides' log2(R)-bit routing words share one biased word
         raise ValueError(
             f"permutation packing needs a power-of-two block size "
-            f"(got R={R}); use LGBM_TPU_PART_R or "
-            f"LGBM_TPU_PARTITION=matmul")
+            f"in [{_SUB}, {1 << (_BIAS - 1) // 2}] (got R={R}); use "
+            f"LGBM_TPU_PART_R or LGBM_TPU_PARTITION=matmul")
     return functools.partial(_pack_permute, R=R, C=C)
 
 
@@ -250,10 +428,13 @@ def _pk2_mask(mA, mB):
 
 
 def _compact_logical(y, dA, dB, *, R: int, P: int):
-    """pack=2 twin of _compact_rows: route logical rows backward by
-    per-row displacements carried as an [P, 1] i32 pair (half A / half
-    B of each line).  Same LSB-first collision-freedom argument, stated
-    over logical indices."""
+    """Route logical rows backward to ``dst = r - d[r]`` with LSB-first
+    bit-serial ROTATE routing (round k moves every row whose remaining
+    displacement has bit k set by k rows); displacements carried as an
+    [P, 1] i32 pair (half A / half B of each line), 0 for garbage rows.
+    For a strict compaction (destinations strictly increasing over
+    kept rows, displacement non-decreasing) it is collision-free and
+    order-preserving."""
     k = 1
     while k < R:
         if k == 1:
@@ -679,7 +860,7 @@ from ...analysis.registry import partition_args, register_kernel, sds
 
 
 @register_kernel("partition_ss_permute", kind="partition",
-                 note="single-scan kernel, roll-routing permutation "
+                 note="single-scan kernel, butterfly-routing permutation "
                       "packing (the shipping default)")
 def _analysis_partition_perm():
     n, C = 7168, 128

@@ -352,3 +352,72 @@ def test_registered_mesh_configs_guard_padding():
         assert check_hist_scatter(mc.f_log, mc.n_shards), (
             f"padded mesh config {mc} fails the reduce-scatter "
             "precondition")
+
+
+def test_pack_permute_row_bookkeeping_is_lane_dense():
+    """ISSUE 28's static pin on the waste itself.  A [R, 1] column is
+    R / 8 vregs at 1 / 128 lane use: an operation on it costs what an
+    operation on the whole [R, 128] block costs.  Before ISSUE 28 the
+    permute compaction's unrolled rounds held 416 equations with such
+    an operand (358 produced one) plus 124 that produced a full block,
+    at R = 512, C = 128; the lane-dense bookkeeping has none, and
+    produces a full block only at its edges (the split-column matvec's
+    operand and the one MXU transpose's result slices).  Trace only."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from lightgbm_tpu.analysis.jaxpr_tools import walk_eqns
+    from lightgbm_tpu.ops.pallas.partition_kernel3 import perm_pack_impl
+    R, C = 512, 128
+    pack = perm_pack_impl(R, C)
+
+    def kern(sel_ref, x_ref, o_ref, n_ref):
+        n_ref[0], n_ref[1], _ = pack(x_ref[...], sel_ref, sel_ref[1], 0,
+                                     True, o_ref)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    jaxpr = jax.make_jaxpr(lambda sel, x: pl.pallas_call(
+        kern, in_specs=[smem, vmem], out_specs=[vmem, smem],
+        out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
+                   jax.ShapeDtypeStruct((2,), jnp.int32)])(sel, x))(
+        jnp.zeros((8,), jnp.int32), jnp.zeros((R, C), jnp.float32))
+
+    def shapes(vs):
+        return [tuple(getattr(getattr(v, "aval", None), "shape", ()))
+                for v in vs]
+
+    eqns = list(walk_eqns(jaxpr))
+    columns = [e for e in eqns
+               if (R, 1) in shapes(list(e.invars) + list(e.outvars))]
+    blocks = [e for e in eqns
+              if any(len(s) == 2 and s[0] == R for s in shapes(e.outvars))]
+    assert len(eqns) > 1000, "the rounds are no longer unrolled here"
+    assert not columns, [str(e.primitive) for e in columns][:8]
+    assert len(blocks) <= 16, [str(e.primitive) for e in blocks]
+
+
+@pytest.mark.parametrize("entry,scoped", [
+    ("fused_split", True), ("partition_ss_permute", True),
+    ("partition_ss_matmul", False)])
+def test_vmem_budget_prices_run_scoped_buffers(entry, scoped):
+    """The permute compaction allocates its routing word and staging
+    blocks with ``pl.run_scoped`` inside the kernel (ISSUE 28): the
+    vmem-budget pass has to price them as scratch, once (the two
+    parity branches hold sibling scopes, not nested ones)."""
+    import jax
+
+    from lightgbm_tpu.analysis.jaxpr_tools import pallas_calls
+    from lightgbm_tpu.analysis.passes.vmem import kernel_vmem_bytes
+    from lightgbm_tpu.analysis.registry import collect
+    fn, args = collect()[entry].builder()
+    scan = pallas_calls(jax.make_jaxpr(fn)(*args))[0]
+    block = 512 * 128 * 4
+    shapes = [r.shape for r in scan.vmem_refs(roles=("scratch",))]
+    assert shapes.count((512, 128)) == (5 if scoped else 4), shapes
+    assert shapes.count((2, 512, 128)) == int(scoped), shapes
+    blocked = sum(r.nbytes for r in scan.vmem_refs(roles=("in", "out")))
+    assert kernel_vmem_bytes(scan) == (2 * blocked
+                                       + block * (7 if scoped else 4))

@@ -262,6 +262,211 @@ def test_pack2_fused_kernel_contract():
                 atol=1e-4, err_msg=str((cfg, i)))
 
 
+# ---------------------------------------------------------------------
+# ISSUE 28: the permute compaction's row bookkeeping is LANE-dense
+# (flags, prefix positions, destinations and the routing rounds on
+# [K, R] arrays), crosses to row orientation once through the MXU, and
+# hands its go-left bits to the fused scan's hook.  One block at a time,
+# through the Pallas interpreter.
+# ---------------------------------------------------------------------
+def _go_left_np(col, sel):
+    """numpy twin of partition_kernel._go_left."""
+    from lightgbm_tpu.ops.pallas.partition_kernel import (
+        SEL_CAT, SEL_DL, SEL_MEMBER, SEL_NANB, SEL_SBIN)
+    col = np.asarray(col)
+    at_nan = (sel[SEL_NANB] >= 0) & (col == sel[SEL_NANB])
+    num = ((col <= sel[SEL_SBIN]) & ~at_nan) | (at_nan & (sel[SEL_DL] > 0))
+    if len(sel) > SEL_MEMBER:
+        b = col.astype(np.int64)
+        words = np.asarray(sel[SEL_MEMBER:], np.int64)[b >> 5]
+        cat = ((words >> (b & 31)) & 1) > 0
+    else:
+        cat = col == sel[SEL_SBIN]
+    return cat if sel[SEL_CAT] > 0 else num
+
+
+def _pack_block(x, sel, blk, is_last, r):
+    """One call of _pack_permute on one [r, C] block: (packed, nl, nr,
+    flag_l, flag_r)."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from lightgbm_tpu.ops.pallas.partition_kernel3 import perm_pack_impl
+    pack = perm_pack_impl(r, C)
+
+    def kern(sel_ref, at_ref, x_ref, o_ref, n_ref, fl_ref, fr_ref):
+        nl, nr, (fl, fr) = pack(x_ref[...], sel_ref, sel_ref[SEL_CNT],
+                                at_ref[0], at_ref[1] > 0, o_ref)
+        n_ref[0], n_ref[1] = nl, nr
+        fl_ref[...], fr_ref[...] = fl, fr
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    blk_f32 = jax.ShapeDtypeStruct((r, C), jnp.float32)
+    out = pl.pallas_call(
+        kern, in_specs=[smem, smem, vmem],
+        out_specs=[vmem, smem, vmem, vmem],
+        out_shape=[jax.ShapeDtypeStruct((r, C), x.dtype),
+                   jax.ShapeDtypeStruct((2,), jnp.int32), blk_f32, blk_f32],
+        interpret=True,
+    )(jnp.asarray(sel, jnp.int32),
+      jnp.asarray([blk, int(is_last)], jnp.int32), jnp.asarray(x))
+    packed, n, fl, fr = (np.asarray(o) for o in out)
+    return packed, int(n[0]), int(n[1]), fl, fr
+
+
+# (r, cnt, blk, is_last, sbin): cnt counts from the segment start, the
+# block holds rows [blk * r, (blk + 1) * r) of it
+PACK_CASES = {
+    "all_left": (128, 1024, 2, False, 63),
+    "all_right": (128, 1024, 2, False, -1),
+    "empty": (128, 256, 2, True, 20),
+    "cnt_not_multiple_of_R": (128, 300, 2, True, 20),
+    "last_block_loff": (128, 384, 2, True, 40),
+    "last_block_all_right": (128, 300, 2, True, -1),
+    "mid_block_mixed": (128, 1024, 1, False, 31),
+    "real_block_size": (512, 1300, 2, True, 25),
+    "three_stage_block": (1024, 2600, 2, True, 25),
+    "one_vreg_block": (8, 21, 2, True, 25),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACK_CASES))
+def test_pack_block_layout(case):
+    """Left rows ascending at [loff, loff + nl) (loff = R - nr - nl on
+    the last block, else 0), right rows reversed at [R - nr, R): the
+    layout _pack_matmul produces, from the lane-dense bookkeeping."""
+    r, cnt, blk, is_last, sbin = PACK_CASES[case]
+    x = _rows(n=r, seed=len(case))
+    sel = np.asarray(_sel(0, cnt, 3, sbin))
+    packed, nl, nr, _, _ = _pack_block(x, sel, blk, is_last, r)
+    valid = np.arange(r) < cnt - blk * r
+    gl = _go_left_np(x[:, 3], sel) & valid
+    gr = valid & ~gl
+    assert (nl, nr) == (int(gl.sum()), int(gr.sum()))
+    loff = r - nr - nl if is_last else 0
+    np.testing.assert_array_equal(packed[loff:loff + nl], x[gl])
+    np.testing.assert_array_equal(packed[r - nr:], x[gr][::-1])
+
+
+@pytest.mark.parametrize("r", [4, 96, 4096])
+def test_permute_block_size_bounds(r):
+    """A power of two, at least one vreg of rows, and small enough for
+    both sides' routing words to share the one biased f32 word."""
+    from lightgbm_tpu.ops.pallas.partition_kernel3 import perm_pack_impl
+    with pytest.raises(ValueError, match="power-of-two block size"):
+        perm_pack_impl(r, C)
+
+
+@pytest.mark.parametrize("pattern", ["all_left", "all_right", "empty",
+                                     "partial", "random"])
+def test_prefix_lanes_matches_cumsum(pattern):
+    """The lane-oriented prefix (both sides scanned in one [2, R]
+    array) against numpy.cumsum, at the real block size."""
+    import jax
+    from jax.experimental import pallas as pl
+    from lightgbm_tpu.ops.pallas.partition_kernel3 import _prefix_lanes
+    r = 512
+    rng = np.random.default_rng(4)
+    left = {"all_left": np.ones(r), "all_right": np.zeros(r),
+            "empty": np.zeros(r), "partial": np.arange(r) % 3 == 0,
+            "random": rng.random(r) < 0.4}[pattern].astype(bool)
+    valid = {"empty": np.zeros(r, bool),
+             "partial": np.arange(r) < 300}.get(pattern, np.ones(r, bool))
+    f2 = np.stack([left & valid, ~left & valid]).astype(np.float32)
+
+    def kern(f_ref, o_ref):
+        o_ref[...] = _prefix_lanes(f_ref[...], R=r)
+
+    out = pl.pallas_call(
+        kern, out_shape=jax.ShapeDtypeStruct((2, r), jnp.float32),
+        interpret=True)(jnp.asarray(f2))
+    np.testing.assert_array_equal(np.asarray(out), np.cumsum(f2, axis=1))
+
+
+def _flag_sel(kind):
+    from lightgbm_tpu.ops.pallas.layout import CAT_BITSET_WORDS
+    from lightgbm_tpu.ops.pallas.partition_kernel import (
+        SEL_CAT, SEL_DL, SEL_MEMBER, SEL_NANB)
+    sel = np.zeros(8 + (CAT_BITSET_WORDS if kind == "cat_bitset" else 0),
+                   np.int32)
+    sel[SEL_CNT], sel[2], sel[3], sel[SEL_NANB] = 100, 5, 20, -1
+    if kind.startswith("nan"):
+        sel[SEL_NANB], sel[SEL_DL] = 63, int(kind == "nan_default_left")
+    if kind.startswith("cat"):
+        sel[SEL_CAT] = 1
+    if kind == "cat_bitset":
+        for b in (1, 7, 20, 33, 62):       # bins 33, 62: second word
+            sel[SEL_MEMBER + b // 32] |= np.int32(1) << (b % 32)
+    return sel
+
+
+@pytest.mark.parametrize("kind", ["numerical", "nan_default_left",
+                                  "nan_default_right", "cat_onehot",
+                                  "cat_bitset"])
+def test_hook_flags_match_recomputation(kind):
+    """The go-left / go-right bits the compaction hands the fused
+    scan's hook equal the hook's own row-oriented recomputation (the
+    path the matmul compaction still takes), on every lane."""
+    from lightgbm_tpu.ops.pallas.partition_kernel import _go_left
+    r = 128
+    x = _rows(n=r, seed=9)
+    sel = _flag_sel(kind)
+    _, nl, nr, fl, fr = _pack_block(x, sel, 0, True, r)
+    # fused_split._hist_block's fallback: [R, 1] column, _go_left, valid
+    valid = (np.arange(r) < sel[SEL_CNT])[:, None]
+    gl2 = np.asarray(_go_left(jnp.asarray(x[:, 5:6]), jnp.asarray(sel))
+                     ) & valid
+    gr2 = valid & ~gl2
+    assert gl2.any() and gr2.any(), "degenerate case"
+    np.testing.assert_array_equal(gl2[:, 0], _go_left_np(x[:, 5], sel)
+                                  & valid[:, 0])
+    np.testing.assert_array_equal(fl, np.broadcast_to(gl2, (r, C)))
+    np.testing.assert_array_equal(fr, np.broadcast_to(gr2, (r, C)))
+    assert (nl, nr) == (int(gl2.sum()), int(gr2.sum()))
+
+
+@pytest.mark.parametrize("kind", ["numerical", "nan_default_left",
+                                  "cat_bitset"])
+def test_fused_kernel_hook_takes_the_compactions_flags(kind):
+    """The REAL pack=1 fused scan + dual-histogram kernel through the
+    Pallas interpreter: under the permute compaction the hook masks
+    with the flags the compaction hands it, under the matmul one it
+    recomputes them - same rows, same nleft and BITWISE the same two
+    histograms; and both agree with the reference composition
+    (partition kernel + per-side comb histogram) to its
+    accumulation-grouping tolerance."""
+    import ml_dtypes
+    from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
+    f_pad, bins = 32, 64
+    rng = np.random.default_rng(7)
+    rows = np.zeros((N, C), np.float32)
+    rows[:, :f_pad] = rng.integers(0, bins, size=(N, f_pad))
+    rows[:, f_pad:f_pad + 2] = rng.normal(size=(N, 2)).astype(
+        ml_dtypes.bfloat16).astype(np.float32)
+    rj = jnp.asarray(rows)
+    sel = _flag_sel(kind)
+    sel[SEL_S0], sel[SEL_CNT] = 64, 900
+    sel = jnp.asarray(sel)
+    kw = dict(f_pad=f_pad, padded_bins=bins, R=R, size=SIZE)
+    real = {scan: make_fused_split(N, C, scan=scan,
+                                   fused_kernel_interpret=True, **kw)(
+        sel, rj, jnp.zeros_like(rj)) for scan in ("permute", "matmul")}
+    for i in (0, 2, 3, 4):      # rows, nleft, h_left, h_right
+        np.testing.assert_array_equal(np.asarray(real["permute"][i]),
+                                      np.asarray(real["matmul"][i]))
+    comp = make_fused_split(N, C, interpret=True, interpret_kernel=True,
+                            hist_rpb=R, **kw)(sel, rj, jnp.zeros_like(rj))
+    np.testing.assert_array_equal(np.asarray(real["permute"][0]),
+                                  np.asarray(comp[0]))
+    nleft = int(real["permute"][2])
+    assert 0 < nleft == int(comp[2]) < 900
+    for i in (3, 4):
+        assert np.abs(np.asarray(comp[i])).sum() > 0
+        np.testing.assert_allclose(np.asarray(real["permute"][i]),
+                                   np.asarray(comp[i]), rtol=0, atol=1e-4)
+
+
 class TestLaneContract:
     """Off-chip pin for the BENCH_r03 Mosaic regression class: every
     kernel column-slice/comb width in the repo must be a multiple of

@@ -3,7 +3,7 @@
 Measures the single-scan partition's per-row cost for every
 combination of
 
-  * scheme:  permute (roll-routing, O(log R)/row)  vs  matmul
+  * scheme:  permute (butterfly routing, O(log R)/row)  vs  matmul
              ([R, R] one-hot contraction, O(R)/row)
   * R:       block rows (LGBM_TPU_PART_R candidates; the round-3b
              sweep put the matmul scheme's knee at 512)

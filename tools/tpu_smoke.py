@@ -163,7 +163,7 @@ def _check_partition_identity():
     BYTE-identical trees (ISSUE 3): the permute packing reproduces the
     matmul scheme's exact row layout — reversed right segments included
     — so every histogram accumulates in the same order.  Any
-    divergence here means the roll routing reordered rows."""
+    divergence here means the butterfly routing reordered rows."""
     _check_knob_identity("LGBM_TPU_PARTITION", ("permute", "matmul"),
                          "partition-identity")
 
