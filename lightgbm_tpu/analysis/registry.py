@@ -190,5 +190,7 @@ def partition_args(n: int, C: int, sel_words: int = 0):
     partition contract.  ``sel_words`` appends that many categorical
     bitset membership words to the 8-slot split descriptor (ISSUE 16)."""
     import jax.numpy as jnp
-    return (sds((8 + sel_words,), jnp.int32), sds((n, C), jnp.float32),
-            sds((n, C), jnp.float32))
+    from ..ops.pallas.layout import comb_shape
+    shape = comb_shape(n, C)         # the comb is plane-major
+    return (sds((8 + sel_words,), jnp.int32), sds(shape, jnp.float32),
+            sds(shape, jnp.float32))

@@ -68,6 +68,11 @@ class ObjectiveFunction:
         """score -> (grad, hess), all [n] (or [K, n])."""
         raise NotImplementedError
 
+    def span_args(self) -> dict:
+        """Host-derived counters of the gradient pass, set as args of
+        the ``Boosting`` span (obs/tracer.py); none by default."""
+        return {}
+
     def boost_from_score(self) -> np.ndarray:
         """Initial raw score(s) (reference BoostFromScore; one per model)."""
         return np.zeros(self.num_models(), dtype=np.float64)

@@ -27,7 +27,10 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
     Train::iteration                    engine.py, one per iteration
       GBDT::TrainOneIter
         BeforeTrain                     bagging, boost-from-average
-          Boosting                      gradient pass (not in stream mode)
+          Boosting                      gradient pass (not in stream mode);
+                                        args: the objective's span_args()
+                                        (ranking: queries, buckets,
+                                        pairs_visited, pair_slots)
             Boosting::wait
         HbmCensus                       live-array census (obs mem)
         GradSlice                       eager grad[k], hess[k]

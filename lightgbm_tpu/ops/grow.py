@@ -665,7 +665,8 @@ def make_grow_fn(
         # columns each logical row owns (64), and the comb/scratch
         # matrices are [_n_alloc // 2, _C_PHYS] packed lines.
         from .device_data import comb_pack_choice
-        from .pallas.layout import PACK_W, comb_layout
+        from .pallas.layout import (PACK_W, comb_layout, comb_planes,
+                                    set_cols, to_rows)
         _pack_fit = comb_pack_choice(f_pad_p, _n_extra)
         if _comb_pack == 2 and _pack_fit == 1:
             _warn_pack_fallback(
@@ -676,6 +677,9 @@ def make_grow_fn(
         _C_PHYS, _comb_pack = comb_layout(
             f_pad_p + _n_extra, pack=_comb_pack, dtype=_COMB_DT)
         _CW = PACK_W if _comb_pack == 2 else _C_PHYS
+        # the comb is stored plane-major (layout.py): _PLANES matrices
+        # of [lines, 128], one after the other in one array
+        _PLANES = comb_planes(_C_PHYS)
         if _comb_pack == 2:
             # pack=2 routing is permutation-only; under
             # LGBM_TPU_PARTITION=matmul trees still match bit-for-bit
@@ -913,7 +917,7 @@ def make_grow_fn(
             # 512 B/row, the round-2 OOM).
             def _comb_logical(c):
                 return (c.reshape(_n_alloc, _CW) if _comb_pack == 2
-                        else c)
+                        else to_rows(c, _C_PHYS))
 
             def _decode_rid(c):
                 if _comb_pack == 2:
@@ -924,10 +928,19 @@ def make_grow_fn(
                               .at[off_h + f + 5, h].set(1.0))
                     # [n_phys, 2] -> interleaved == logical order
                     return jnp.matmul(c, rw).reshape(-1)
-                rid_w = (jnp.zeros((_C_PHYS,), jnp.float32)
-                         .at[f + 3].set(65536.0).at[f + 4].set(256.0)
-                         .at[f + 5].set(1.0))
-                return jnp.matmul(c, rid_w)
+                import numpy as _np
+                rid_w = _np.zeros((_C_PHYS,), _np.float32)
+                rid_w[f + 3:f + 6] = (65536.0, 256.0, 1.0)
+                # one matvec a plane that holds a row-id byte column
+                out = None
+                for p in range(_PLANES):
+                    w_p = rid_w[p * 128:(p + 1) * 128]
+                    if w_p.any():
+                        part = jnp.matmul(
+                            c[p * _n_alloc:(p + 1) * _n_alloc],
+                            jnp.asarray(w_p))
+                        out = part if out is None else out + part
+                return out
 
         def expand(h):
             """Physical -> logical histogram (EFB): gather every logical
@@ -1161,9 +1174,7 @@ def make_grow_fn(
                 comb = (comb_in * keep[None, :]
                         + jnp.matmul(gv6, place)).astype(comb_in.dtype)
             else:
-                comb = jax.lax.dynamic_update_slice(
-                    comb_in, gvp.astype(comb_in.dtype),
-                    (jnp.int32(0), jnp.int32(f)))
+                comb = set_cols(comb_in, gvp, f, _C_PHYS)
             gvals = gvp                     # root histogram values
             # full-width bins slice only for the off-TPU reference path;
             # on TPU the comb-direct kernel reads the matrix in place
@@ -1319,7 +1330,7 @@ def make_grow_fn(
                 comb, jnp.int32(0), jnp.int32(0), jnp.int32(n),
                 f_pad=f, size=n, padded_bins=padded_bins,
                 rows_per_block=min(rows_per_block, _HIST_RPB),
-                pack=_comb_pack)
+                pack=_comb_pack, planes=_PLANES)
             root_hist = merge_kernel_hist(root_hist)
         else:
             root_hist = expand(hist_merge(
@@ -1756,7 +1767,8 @@ def make_grow_fn(
                             jnp.where(done, 0, child_cnt),
                             f_pad=f, size=s_child,
                             padded_bins=padded_bins,
-                            rows_per_block=rpb_h, pack=_comb_pack)
+                            rows_per_block=rpb_h, pack=_comb_pack,
+                            planes=_PLANES)
                     return (st.row_order, combp, scrp,
                             nleft_, small_left_, h, st.paid,
                             jnp.zeros((1, 2), jnp.float32))
@@ -1825,7 +1837,7 @@ def make_grow_fn(
                         jnp.where(done, 0, child_cnt), f_pad=f,
                         padded_bins=padded_bins,
                         rows_per_block=min(rows_per_block, _HIST_RPB),
-                        pack=_comb_pack))
+                        pack=_comb_pack, planes=_PLANES))
                 row_order = st.row_order
                 paid_n = st.paid
                 u2 = jnp.zeros((1, 2), jnp.float32)
@@ -2287,7 +2299,8 @@ def make_grow_fn(
                 @jax.jit
                 def _root0_fn(comb):
                     comb_l = (comb.reshape(_n_alloc, _CW)
-                              if _comb_pack == 2 else comb)
+                              if _comb_pack == 2
+                              else to_rows(comb, _C_PHYS))
                     pos_al = jnp.arange(_n_alloc, dtype=jnp.int32)
                     gv = (jax.lax.slice(comb_l, (0, f_pad_p),
                                         (_n_alloc, f_pad_p + 3))
@@ -2306,7 +2319,7 @@ def make_grow_fn(
                         jnp.int32(n_rows_p), f_pad=f_pad_p,
                         size=n_rows_p, padded_bins=padded_bins,
                         rows_per_block=min(rows_per_block, _HIST_RPB),
-                        pack=_comb_pack)
+                        pack=_comb_pack, planes=_PLANES)
         else:
             _root0_fn = None
         if stream is not None:
@@ -2325,8 +2338,11 @@ def make_grow_fn(
             # recombination rounds), and byte-identical resume is the
             # contract.
             def _reanchor_bins(comb):
+                # (a transposing copy above one plane; once a
+                # checkpoint, not once a tree)
                 comb_l = (comb.reshape(_n_alloc, _CW)
-                          if _comb_pack == 2 else comb)
+                          if _comb_pack == 2
+                          else to_rows(comb, _C_PHYS))
                 rid_w = (jnp.zeros((_CW,), jnp.float32)
                          .at[f_pad_p + 3].set(65536.0)
                          .at[f_pad_p + 4].set(256.0)
@@ -2405,19 +2421,33 @@ def phys_init_comb(bins_local, n_alloc: int, C: int, f_pad: int,
     may be bfloat16 (half the DMA bytes of f32).  With ``pack=2`` the
     returned matrix is [n_alloc // 2, C] packed lines (layout
     comb_layout pack=2); the logical-view reshape here is a one-time
-    init cost — the per-tree hot paths never unpack to HBM."""
-    cw = C // pack
-    comb = jnp.zeros((n_alloc, cw), dtype)
-    comb = jax.lax.dynamic_update_slice(
-        comb, bins_local.astype(dtype), (0, 0))
+    init cost — the per-tree hot paths never unpack to HBM.  With
+    ``pack=1`` it is plane-major (layout.py), built a plane at a time:
+    no [n_alloc, C] row matrix is ever made."""
+    from .pallas.layout import LANE, set_cols
     rid = jnp.arange(n_alloc, dtype=jnp.int32)
-    comb = comb.at[:, f_pad + 3].set((rid // 65536).astype(dtype))
-    comb = comb.at[:, f_pad + 4].set(
-        ((rid // 256) % 256).astype(dtype))
-    comb = comb.at[:, f_pad + 5].set((rid % 256).astype(dtype))
+    rid_bytes = (rid // 65536, (rid // 256) % 256, rid % 256)
     if pack == 2:
-        comb = comb.reshape(n_alloc // 2, C)
+        comb = jnp.zeros((n_alloc, C // 2), dtype)
+        comb = jax.lax.dynamic_update_slice(
+            comb, bins_local.astype(dtype), (0, 0))
+        for i, v in enumerate(rid_bytes):
+            comb = comb.at[:, f_pad + 3 + i].set(v.astype(dtype))
+        return comb.reshape(n_alloc // 2, C)
+    planes = []
+    for lo in range(0, C, LANE):
+        plane = jnp.zeros((n_alloc, LANE), dtype)
+        if lo < bins_local.shape[1]:
+            plane = jax.lax.dynamic_update_slice(
+                plane, bins_local[:, lo:lo + LANE].astype(dtype), (0, 0))
+        planes.append(plane)
+    comb = planes[0] if len(planes) == 1 else jnp.concatenate(planes)
+    for i, v in enumerate(rid_bytes):
+        comb = set_cols(comb, v.astype(dtype)[:, None], f_pad + 3 + i, C)
     return comb
+
+
+from .pallas.layout import comb_shape  # noqa: E402
 
 
 class _PhysicalGrow:
@@ -2497,11 +2527,11 @@ class _PhysicalGrow:
         if comb is None:
             return False
         bins_anchored = self._reanchor_fn(comb)
-        n_phys = self._n_alloc // self.pack
-        comb0 = jnp.zeros((n_phys, self._C), self._dtype)
+        shape = comb_shape(self._n_alloc // self.pack, self._C)
+        comb0 = jnp.zeros(shape, self._dtype)
         self._put_window(self._stream_init(
             comb0, bins_anchored, self._stream_aux_fn()))
-        self._scratch = jnp.zeros((n_phys, self._C), self._dtype)
+        self._scratch = jnp.zeros(shape, self._dtype)
         self._root_hist = None
         return True
 
@@ -2522,7 +2552,7 @@ class _PhysicalGrow:
 
     def _init_buffers(self):
         f_pad, n_alloc, C = self._f_pad, self._n_alloc, self._C
-        n_phys = n_alloc // self.pack
+        shape = comb_shape(n_alloc // self.pack, C)
         bins_src = (self._bins_dev if self._ingest is None
                     else self._ingest(self._bins_dev))
         if self.paged is not None and self._pages is None:
@@ -2535,7 +2565,7 @@ class _PhysicalGrow:
             if self._stream_aux_fn is None:
                 raise RuntimeError(
                     "stream mode needs set_stream_aux before training")
-            comb0 = jnp.zeros((n_phys, C), self._dtype)
+            comb0 = jnp.zeros(shape, self._dtype)
             comb = self._stream_init(
                 comb0, bins_src, self._stream_aux_fn())
         else:
@@ -2544,7 +2574,7 @@ class _PhysicalGrow:
                 dtype=self._dtype, pack=self.pack))
             comb = init(bins_src)
         self._put_window(comb)
-        self._scratch = jnp.zeros((n_phys, self._C), self._dtype)
+        self._scratch = jnp.zeros(shape, self._dtype)
 
     def __call__(self, bins, grad, hess, inbag, feature_mask, num_bins,
                  has_nan, is_cat, seed):

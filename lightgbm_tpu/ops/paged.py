@@ -293,17 +293,21 @@ class PageStore:
         import jax
         import jax.numpy as jnp
         if self._jit_update is None:
-            n_lines, C = self.n_lines, self.C
+            from .pallas.layout import comb_operand
+            C = self.C
 
             def upd(window, page_buf, line0, valid_lines):
                 # land only the page's VALID lines: a mid-window page
-                # must not smear its slack tail over its neighbor
-                lines = jnp.arange(page_buf.shape[0])[:, None]
-                cur = jax.lax.dynamic_slice(
-                    window, (line0, 0), page_buf.shape)
+                # must not smear its slack tail over its neighbor.  The
+                # window is plane-major (layout.py): a page is the same
+                # line range of every plane
+                view = comb_operand(window, C)
+                at = (0,) * (view.ndim - 2) + (line0, 0)
+                lines = jnp.arange(page_buf.shape[-2])[:, None]
+                cur = jax.lax.dynamic_slice(view, at, page_buf.shape)
                 mixed = jnp.where(lines < valid_lines, page_buf, cur)
                 return jax.lax.dynamic_update_slice(
-                    window, mixed, (line0, 0))
+                    view, mixed, at).reshape(window.shape)
 
             self._jit_update = jax.jit(upd, donate_argnums=(0,))
         return self._jit_update
@@ -312,11 +316,14 @@ class PageStore:
         """window, line0 -> one page buffer (the write-back slice)."""
         import jax
         if self._jit_extract is None:
-            page_lines, C = self.page_lines, self.C
+            from .pallas.layout import comb_operand, comb_operand_shape
+            C = self.C
+            page = comb_operand_shape(self.page_lines, C)
 
             def ext(window, line0):
+                view = comb_operand(window, C)
                 return jax.lax.dynamic_slice(
-                    window, (line0, 0), (page_lines, C))
+                    view, (0,) * (view.ndim - 2) + (line0, 0), page)
 
             self._jit_extract = jax.jit(ext)
         return self._jit_extract
@@ -363,7 +370,8 @@ class PageStore:
         if bad:
             raise RuntimeError(f"page schedule failed its own audit: "
                                f"{bad}")
-        window = jnp.zeros((self.n_lines, self.C), self.dtype)
+        from .pallas.layout import comb_shape
+        window = jnp.zeros(comb_shape(self.n_lines, self.C), self.dtype)
         upd = self._update_fn()
         bufs: List = [None, None]
         for kind, p, b in sched:

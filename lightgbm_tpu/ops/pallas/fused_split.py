@@ -192,8 +192,8 @@ def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
                        rows_ref, scratch_ref, out_ref, hist_ref,
                        vx0, vx1, pk0, pk1, cursor,
                        sem_r, sem_wl, sem_wr,
-                       *, R: int, C: int, f_pad: int, b_hi: int, g: int,
-                       lo_n: int, ngroups: int, pack_impl=None):
+                       *, R: int, C: int, n: int, f_pad: int, b_hi: int,
+                       g: int, lo_n: int, ngroups: int, pack_impl=None):
     """partition_kernel2._scan_kernel + per-block dual histogram
     accumulation, injected through the scan's trace-time hooks so the
     compaction/DMA schedule (and its safety argument) has exactly one
@@ -238,7 +238,7 @@ def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
                  rows_ref, scratch_ref, out_ref,
                  vx0, vx1, pk0, pk1, cursor,
                  sem_r, sem_wl, sem_wr,
-                 R=R, C=C, init_cb=_hist_init, block_cb=_hist_block,
+                 R=R, C=C, n=n, init_cb=_hist_init, block_cb=_hist_block,
                  pack_impl=pack_impl)
 
 
@@ -280,7 +280,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     scan+dual-histogram kernel and runs it through the Pallas
     interpreter (static grids only) — the off-chip pin for the kernel
     body itself, hooks included."""
-    from .layout import check_lane_width
+    from .layout import check_lane_width, comb_planes, comb_shape
     check_lane_width(C, dtype)
     if scan not in ("matmul", "permute"):
         raise ValueError(f"unknown scan scheme {scan!r}")
@@ -338,7 +338,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
             return build_histogram_comb(
                 rows1, start, jnp.int32(0), count, f_pad=f_pad,
                 size=h_size, padded_bins=b, rows_per_block=hist_rpb,
-                interpret=True, pack=pack)
+                interpret=True, pack=pack, planes=comb_planes(C))
 
         def _fused_i(sel, rows, scratch, *gb):
             rows1, scratch1, nleft = part(sel, rows, scratch, *gb)
@@ -361,9 +361,9 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                               f_pad=f_pad, b=b, b_hi=b_hi, g=g, m=m,
                               nn=nn, ngroups=ngroups)
     nblocks = max((size + R - 1) // R, 1)
-    kern = functools.partial(_fused_scan_kernel, R=R, C=C, f_pad=f_pad,
-                             b_hi=b_hi, g=g, lo_n=_LO_N, ngroups=ngroups,
-                             pack_impl=_pack)
+    kern = functools.partial(_fused_scan_kernel, R=R, C=C, n=n,
+                             f_pad=f_pad, b_hi=b_hi, g=g, lo_n=_LO_N,
+                             ngroups=ngroups, pack_impl=_pack)
 
     def _call(sel, rows, scratch, grid_blocks):
         rows1, scratch1, res, hist2 = pl.pallas_call(
@@ -379,8 +379,8 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                        pl.BlockSpec((2, ngroups, m, nn),
                                     lambda i: (0, 0, 0, 0),
                                     memory_space=pltpu.VMEM)],
-            out_shape=[jax.ShapeDtypeStruct((n, C), dtype),
-                       jax.ShapeDtypeStruct((n, C), dtype),
+            out_shape=[jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
+                       jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
                        jax.ShapeDtypeStruct((2,), jnp.int32),
                        jax.ShapeDtypeStruct((2, ngroups, m, nn),
                                             jnp.float32)],

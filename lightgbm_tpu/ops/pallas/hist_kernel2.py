@@ -35,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..histogram import feature_group_size
+from .layout import load_rows
 
 _LO_N = 16   # hi/lo nibble split shared by every histogram kernel
 
@@ -129,7 +130,7 @@ def _hist2_comb_kernel(sel_ref, comb_ref, out_ref, *, b_hi, g, c, lo_n,
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    rows = comb_ref[:]                          # [R, C] f32/bf16
+    rows = load_rows(comb_ref)                  # [R, C] f32/bf16
     # Mosaic has no direct bf16 -> i32 cast; hop through f32
     b = rows[:, :f_pad].astype(jnp.float32).astype(jnp.int32)
     off, cnt = sel_ref[1], sel_ref[2]
@@ -181,7 +182,7 @@ def _diag_extract(out, ngroups, g, b_hi, c, lo_n, f_pad, b):
 
 
 def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
-                    interpret, channels=2, pack=1):
+                    interpret, channels=2, pack=1, planes=1):
     """Shared tail of the comb-direct histogram: start-block clamp (both
     ways — a garbage-negative start from a dead partition call must not
     become an OOB DMA), scalar-prefetch grid, diagonal extraction.
@@ -189,9 +190,11 @@ def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
     (Mosaic dynamic grid).  ``rpb`` counts LOGICAL rows per block; under
     ``pack=2`` each block is rpb // 2 physical lines of the packed comb
     and the kernel unpacks the lane halves in register."""
-    from .layout import PACK_W, check_lane_width
-    n_phys, C = comb.shape
-    check_lane_width(C, comb.dtype)
+    from .layout import (LANE, PACK_W, check_lane_width, comb_block_spec,
+                         comb_operand)
+    # the comb is plane-major (layout.py): ``planes`` x [n_phys, 128]
+    n_phys, C = comb.shape[0] // planes, planes * LANE
+    check_lane_width(comb.shape[1], comb.dtype)
     if pack == 2 and f_pad + channels > PACK_W:
         raise ValueError(
             f"pack=2 comb histogram needs f_pad + {channels} <= "
@@ -217,8 +220,8 @@ def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec((rpb_p, C), lambda i, s: (s[0] + i, 0),
-                               memory_space=pltpu.VMEM)],
+        in_specs=[comb_block_spec(rpb_p, C, lambda i, s: s[0] + i,
+                                  memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((ngroups, m, nn), lambda i, s: (0, 0, 0),
                                memory_space=pltpu.VMEM),
     )
@@ -228,7 +231,7 @@ def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ngroups, m, nn), jnp.float32),
         interpret=interpret,
-    )(sel, comb)
+    )(sel, comb_operand(comb, C))
     return _diag_extract(out, ngroups, g, b_hi, c, lo_n, f_pad, b)
 
 
@@ -241,9 +244,10 @@ def _comb_rpb(rows_per_block: int, cap: int, pack: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "f_pad", "padded_bins", "rows_per_block", "interpret", "pack"))
+    "f_pad", "padded_bins", "rows_per_block", "interpret", "pack",
+    "planes"))
 def build_histogram_comb_dyn(
-    comb: jnp.ndarray,       # [n_alloc // pack, C] physical row matrix
+    comb: jnp.ndarray,       # plane-major [planes * n_alloc // pack, 128]
     start: jnp.ndarray,      # i32 scalar: first row of the parent range
     off: jnp.ndarray,        # i32 scalar: valid rows begin at start+off...
     count: jnp.ndarray,      # ...and span count rows
@@ -253,6 +257,7 @@ def build_histogram_comb_dyn(
     rows_per_block: int = 2048,
     interpret: bool = False,
     pack: int = 1,
+    planes: int = 1,
 ) -> jnp.ndarray:
     """Dynamic-grid variant of build_histogram_comb: the block count is a
     TRACED value (ceil(count / rows_per_block) + 1 alignment block), so
@@ -261,19 +266,19 @@ def build_histogram_comb_dyn(
     per branch per split otherwise) and no masked overhang blocks
     (static classes run up to 2x the parent rows).  ``start``/``off``/
     ``count`` are LOGICAL rows at every pack."""
-    n_phys, _ = comb.shape
+    n_phys = comb.shape[0] // planes
     rpb = _comb_rpb(rows_per_block, n_phys * pack, pack)
     nblocks = jnp.maximum(-(-count // rpb) + 1, 1)
     return _comb_hist_call(comb, start, off, count, nblocks,
                            f_pad=f_pad, b=int(padded_bins), rpb=rpb,
-                           interpret=interpret, pack=pack)
+                           interpret=interpret, pack=pack, planes=planes)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "f_pad", "size", "padded_bins", "rows_per_block", "interpret",
-    "pack"))
+    "pack", "planes"))
 def build_histogram_comb(
-    comb: jnp.ndarray,       # [n_alloc // pack, C] physical row matrix
+    comb: jnp.ndarray,       # plane-major [planes * n_alloc // pack, 128]
     start: jnp.ndarray,      # i32 scalar: first row of the parent range
     off: jnp.ndarray,        # i32 scalar: valid rows begin at start+off...
     count: jnp.ndarray,      # ...and span count rows
@@ -284,6 +289,7 @@ def build_histogram_comb(
     rows_per_block: int = 2048,
     interpret: bool = False,
     pack: int = 1,
+    planes: int = 1,
 ) -> jnp.ndarray:
     """Histogram of comb rows [start+off, start+off+count) WITHOUT
     materialising any sliced copy: the kernel reads [R, C] blocks of the
@@ -293,7 +299,7 @@ def build_histogram_comb(
     ``pack=2`` the comb holds two logical rows per 128-lane line and
     the kernel unpacks them in register — half the HBM bytes per
     logical row; ``start``/``off``/``count``/``size`` stay logical."""
-    n_phys, _ = comb.shape
+    n_phys = comb.shape[0] // planes
     rpb = _comb_rpb(rows_per_block, size, pack)
     # block-align the dynamic start: one extra block covers the head
     # misalignment, the off/count window masks the rest
@@ -305,7 +311,7 @@ def build_histogram_comb(
             f"{n_phys * pack}); pad the row matrix")
     return _comb_hist_call(comb, start, off, count, nblocks,
                            f_pad=f_pad, b=int(padded_bins), rpb=rpb,
-                           interpret=interpret, pack=pack)
+                           interpret=interpret, pack=pack, planes=planes)
 
 
 @functools.partial(jax.jit, static_argnames=("padded_bins", "rows_per_block",

@@ -16,8 +16,21 @@ Also the home of ``comb_layout`` — the (C, pack, dtype) decision the
 ISSUE-3 pack-aware data path threads through ops/grow.py,
 ops/device_data.py and the partition kernels:
 
-* ``pack=1``: one logical row per 128-lane line (today's layout); C is
-  the column count rounded up to a multiple of 128.
+* ``pack=1``: one logical row per line of C lanes; C is the column
+  count rounded up to a multiple of 128.  In HBM the comb is stored
+  PLANE-MAJOR (ISSUE 29): plane p holds lanes [128 p, 128 p + 128) of
+  every row as an [n, 128] matrix, and the C // 128 planes lie one
+  after the other in ONE [C // 128 * n, 128] array (``to_planes`` /
+  ``to_rows``).  A [n, 256] f32 array is tiled (8, 128) in HBM, so a
+  row DMA at an arbitrary row offset - every segment start of the
+  partition scan - is refused by Mosaic ("tile index in dimension 0
+  is divisible by the tiling (8)"); a [n, 128] matrix is linear in
+  memory and takes any row offset.  With one plane (C = 128) the
+  plane-major array IS the [n, 128] row matrix.  In VMEM a block stays
+  [R, C]: the kernels move it as C // 128 row DMAs, one a plane, into
+  and out of its 128-lane column tiles (``plane_copies``), or take it
+  through a [C // 128, R, 128] block of the free 3-D view
+  (``comb_block_spec`` / ``load_rows`` / ``store_rows``).
 * ``pack=2``: TWO logical rows per 128-lane line (logical row 2p in
   lanes [0, 64), row 2p+1 in lanes [64, 128) of physical line p).
   Halves partition DMA bytes per logical row while every physical
@@ -28,6 +41,7 @@ ops/device_data.py and the partition kernels:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 LANE = 128          # TPU minor-dim tile: every HBM row DMA moves
@@ -157,6 +171,129 @@ def check_lane_width(C: int, dtype=jnp.float32) -> int:
             f"time — the BENCH_r03 regression); pad the column count "
             f"to a multiple of {LANE}")
     return C
+
+
+def comb_planes(C: int) -> int:
+    """128-lane planes of a comb line of ``C`` lanes."""
+    return check_lane_width(C) // LANE
+
+
+def comb_shape(n: int, C: int):
+    """HBM shape of the plane-major comb of ``n`` lines of ``C`` lanes."""
+    return comb_planes(C) * int(n), LANE
+
+
+def to_planes(x):
+    """[n, C] rows -> the plane-major [C // 128 * n, 128] comb (the
+    identity at C = 128; a transposing copy above it, so a program
+    that runs at a real size builds its planes directly)."""
+    n, C = x.shape
+    P = comb_planes(C)
+    if P == 1:
+        return x
+    return x.reshape(n, P, LANE).swapaxes(0, 1).reshape(P * n, LANE)
+
+
+def to_rows(y, C: int):
+    """The inverse of :func:`to_planes`: the [n, C] row view the
+    off-chip reference paths slice."""
+    P = comb_planes(C)
+    if P == 1:
+        return y
+    n = y.shape[0] // P
+    return y.reshape(P, n, LANE).swapaxes(0, 1).reshape(n, C)
+
+
+def plane_view(y, C: int):
+    """The plane-major comb as [C // 128, n, 128]: a split of the major
+    dimension, free in XLA."""
+    P = comb_planes(C)
+    return y.reshape(P, y.shape[0] // P, LANE)
+
+
+def set_cols(y, vals, col0: int, C: int):
+    """The plane-major comb with logical columns [col0, col0 + k) of
+    every row replaced by ``vals`` [n, k]: one update a plane touched."""
+    n = y.shape[0] // comb_planes(C)
+    k, done = vals.shape[1], 0
+    while done < k:
+        p, lane = divmod(col0 + done, LANE)
+        w = min(k - done, LANE - lane)
+        y = jax.lax.dynamic_update_slice(
+            y, vals[:, done:done + w].astype(y.dtype),
+            (jnp.int32(p * n), jnp.int32(lane)))
+        done += w
+    return y
+
+
+def plane_copies(hbm_ref, start, rows: int, vmem_ref, sem, *, n: int,
+                 C: int, to_hbm: bool = False):
+    """The row DMAs that move comb rows [start, start + rows) between
+    the plane-major HBM array ``hbm_ref`` (``n`` rows a plane) and the
+    [rows, C] VMEM block ``vmem_ref``: one descriptor a plane, all on
+    ``sem``.  Start every one, wait every one."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    P = comb_planes(C)
+    out = []
+    for p in range(P):
+        h = hbm_ref.at[pl.ds(p * n + start, rows)]
+        v = vmem_ref if P == 1 else vmem_ref.at[:, pl.ds(p * LANE, LANE)]
+        out.append(pltpu.make_async_copy(v, h, sem) if to_hbm
+                   else pltpu.make_async_copy(h, v, sem))
+    return out
+
+
+def hbm_copies(src_ref, src_start, dst_ref, dst_start, rows: int, sem, *,
+               n: int, C: int):
+    """HBM -> HBM: rows [src_start, + rows) of one plane-major comb to
+    rows [dst_start, + rows) of another, one descriptor a plane."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return [pltpu.make_async_copy(
+        src_ref.at[pl.ds(p * n + src_start, rows)],
+        dst_ref.at[pl.ds(p * n + dst_start, rows)], sem)
+        for p in range(comb_planes(C))]
+
+
+def comb_block_spec(rows: int, C: int, index, **kw):
+    """BlockSpec of a [rows, C] block of comb rows for a kernel that
+    takes the comb through :func:`comb_operand`; ``index(*grid ids)``
+    gives the row-block index."""
+    from jax.experimental import pallas as pl
+    if comb_planes(C) == 1:
+        return pl.BlockSpec((rows, C), lambda *a: (index(*a), 0), **kw)
+    return pl.BlockSpec((comb_planes(C), rows, LANE),
+                        lambda *a: (0, index(*a), 0), **kw)
+
+
+def comb_operand(y, C: int):
+    """The comb as a BlockSpec kernel takes it (and hands it back):
+    itself with one plane, the 3-D plane view above."""
+    return y if comb_planes(C) == 1 else plane_view(y, C)
+
+
+def comb_operand_shape(n: int, C: int):
+    """Shape of :func:`comb_operand` for ``n`` lines of ``C`` lanes."""
+    P = comb_planes(C)
+    return (int(n), C) if P == 1 else (P, int(n), LANE)
+
+
+def load_rows(ref):
+    """A :func:`comb_block_spec` block as its [rows, C] value (placing
+    128-lane tiles side by side moves no data)."""
+    if len(ref.shape) == 2:
+        return ref[...]
+    return jnp.concatenate([ref[p] for p in range(ref.shape[0])], axis=1)
+
+
+def store_rows(ref, x):
+    """Write a [rows, C] value back into a comb block."""
+    if len(ref.shape) == 2:
+        ref[...] = x
+        return
+    for p in range(ref.shape[0]):
+        ref[p] = x[:, p * LANE:(p + 1) * LANE]
 
 
 def comb_layout(n_cols: int, *, pack: int = 1, dtype=jnp.float32):

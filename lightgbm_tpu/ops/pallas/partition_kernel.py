@@ -279,7 +279,8 @@ def make_partition(n: int, C: int, *, R: int = 1024, size: int = 0,
     0 <= par_cnt <= size and s0 + ceil(par_cnt/R)*R <= n; par_cnt == 0
     is a supported dead call (rows untouched, nleft == 0 — used when a
     tree finishes early)."""
-    from .layout import check_lane_width
+    from .layout import (LANE, check_lane_width, comb_planes,
+                         plane_view)
     check_lane_width(C, dtype)
     nblocks = max((size + R - 1) // R, 1)
     kern = functools.partial(_partition_kernel, R=R, C=C)
@@ -290,11 +291,15 @@ def make_partition(n: int, C: int, *, R: int = 1024, size: int = 0,
         # semantics (unwritten regions of the aliased outputs come back
         # zeroed), so emulate the kernel's contract directly.
         def partition(sel, rows, scratch):
+            # rows is the plane-major comb (layout.py): work on its
+            # [planes, n, 128] view
+            rows3 = plane_view(rows, C)
             s0, cnt = sel[0], sel[1]
             pos = jnp.arange(n, dtype=jnp.int32)
             in_rng = (pos >= s0) & (pos < s0 + cnt)
-            col = jnp.take(rows, sel[SEL_FEAT], axis=1).astype(
-                jnp.float32)
+            col = jnp.take(
+                jnp.take(rows3, sel[SEL_FEAT] // LANE, axis=0),
+                sel[SEL_FEAT] % LANE, axis=1).astype(jnp.float32)
             sbin = sel[SEL_SBIN].astype(jnp.float32)
             nanb = sel[SEL_NANB]
             at_nan = (nanb >= 0) & (col == nanb.astype(jnp.float32))
@@ -315,13 +320,20 @@ def make_partition(n: int, C: int, *, R: int = 1024, size: int = 0,
                 jnp.where(gr,
                           s0 + nleft + jnp.cumsum(gr.astype(jnp.int32))
                           - 1, pos))
-            rows_new = jnp.zeros_like(rows).at[dst].set(rows)
-            return rows_new, scratch, nleft
+            rows_new = jnp.zeros_like(rows3).at[:, dst].set(rows3)
+            return rows_new.reshape(rows.shape), scratch, nleft
 
         if dynamic:
             return lambda sel, rows, scratch, grid_blocks: partition(
                 sel, rows, scratch)
         return partition
+
+    if comb_planes(C) > 1:
+        raise ValueError(
+            f"the 3-phase bisection kernel (LGBM_TPU_PART=3ph) moves "
+            f"[R, {C}] blocks of a row-major comb and was not ported "
+            f"to the plane-major layout of lines wider than {LANE} "
+            f"lanes; unset LGBM_TPU_PART")
 
     def _call(sel, rows, scratch, grid_blocks):
         rows_out, scratch_out, nsplit = pl.pallas_call(

@@ -78,6 +78,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .layout import (check_lane_width, comb_shape, hbm_copies,
+                     plane_copies)
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, \
     _go_left, make_partition as _make_partition3
 
@@ -146,10 +148,14 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
                  rows_ref, scratch_ref, out_ref,
                  vx0, vx1, pk0, pk1, cursor,
                  sem_r, sem_wl, sem_wr,
-                 *, R: int, C: int, init_cb=None, block_cb=None,
+                 *, R: int, C: int, n: int, init_cb=None, block_cb=None,
                  pack_impl=None):
     """Single-phase scan.  out_ref SMEM i32[2]: [0] nleft, [1] m (rows
     to copy back: left tail + right zone).
+
+    rows / scratch are plane-major (layout.py): ``n`` rows a plane, and
+    every transfer of an [R, C] block is one row DMA a plane on one
+    semaphore (``plane_copies``), all started, then all waited.
 
     ``init_cb()`` / ``block_cb(x, blk, cnt, side)`` are OPTIONAL
     trace-time hooks for
@@ -178,6 +184,15 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
     cnt = sel_ref[SEL_CNT]
     nb_live = (cnt + R - 1) // R
 
+    def _read(start, vx, sem):
+        return plane_copies(rows_in, start, R, vx, sem, n=n, C=C)
+
+    def _wait_write(sem):
+        # a write's descriptors again, for their sizes only
+        for cp in plane_copies(rows_ref, 0, R, pk0, sem, n=n, C=C,
+                               to_hbm=True):
+            cp.wait()
+
     @pl.when(blk == 0)
     def _init0():
         cursor[_CUR_L] = s0
@@ -200,23 +215,19 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
 
         @pl.when(blk == 0)
         def _prime():
-            cp = pltpu.make_async_copy(
-                rows_in.at[pl.ds(start, R)], vx0, sem_r.at[0])
-            cp.start()
+            for cp in _read(start, vx0, sem_r.at[0]):
+                cp.start()
 
         parity = jax.lax.rem(blk, 2)
 
         def _do(vx_cur, vx_next, pk, cur_slot, nxt_slot):
-            pltpu.make_async_copy(
-                rows_in.at[pl.ds(start, R)], vx_cur,
-                sem_r.at[cur_slot]).wait()
+            for cp in _read(start, vx_cur, sem_r.at[cur_slot]):
+                cp.wait()
 
             @pl.when(blk + 1 < nb_live)
             def _ra():
-                cpn = pltpu.make_async_copy(
-                    rows_in.at[pl.ds(start + R, R)], vx_next,
-                    sem_r.at[nxt_slot])
-                cpn.start()
+                for cpn in _read(start + R, vx_next, sem_r.at[nxt_slot]):
+                    cpn.start()
 
             x = vx_cur[:]
             pack = pack_impl or functools.partial(_pack_matmul, R=R, C=C)
@@ -230,13 +241,13 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
             # block's compute, so the wait is normally already satisfied)
             @pl.when(blk > 0)
             def _wl_wait():
-                pltpu.make_async_copy(pk0, pk0, sem_wl).wait()
+                _wait_write(sem_wl)
 
             @pl.when(jnp.logical_not(is_last))
             def _wl_go():
-                cpo = pltpu.make_async_copy(
-                    pk, rows_ref.at[pl.ds(cursor[_CUR_L], R)], sem_wl)
-                cpo.start()
+                for cpo in plane_copies(rows_ref, cursor[_CUR_L], R, pk,
+                                        sem_wl, n=n, C=C, to_hbm=True):
+                    cpo.start()
                 cursor[_CUR_L] = cursor[_CUR_L] + nl
 
             @pl.when(is_last)
@@ -245,11 +256,11 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
 
             @pl.when(blk > 0)
             def _wr_wait():
-                pltpu.make_async_copy(pk0, pk0, sem_wr).wait()
+                _wait_write(sem_wr)
 
-            cpr = pltpu.make_async_copy(
-                pk, scratch_ref.at[pl.ds(cursor[_CUR_R] - R, R)], sem_wr)
-            cpr.start()
+            for cpr in plane_copies(scratch_ref, cursor[_CUR_R] - R, R,
+                                    pk, sem_wr, n=n, C=C, to_hbm=True):
+                cpr.start()
             cursor[_CUR_R] = cursor[_CUR_R] - nr
 
         @pl.when(parity == 0)
@@ -265,7 +276,7 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
     # _wl_wait; the final block issues no left write of its own)
     @pl.when((blk == nb_live - 1) & (nb_live > 0))
     def _fin():
-        pltpu.make_async_copy(pk0, pk0, sem_wr).wait()  # last scratch write
+        _wait_write(sem_wr)                      # last scratch write
         tl = cursor[_CUR_TL]
         nleft = cursor[_CUR_L] - s0 + tl
         out_ref[0] = nleft
@@ -274,12 +285,19 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
 
 def _copyback_kernel(sel_ref, scratch_in, rows_in, rows_ref,
                      va, vb, sem,
-                     *, R: int, CB: int, C: int):
+                     *, R: int, CB: int, C: int, n: int):
     """Move the contiguous span scratch[src0, src0+m) to
     rows[dst0, dst0+m); the tail block read-merges rows' own content
-    beyond the span.  sel: [src0, dst0, m]."""
+    beyond the span.  sel: [src0, dst0, m].  Plane-major like the scan:
+    every move is one DMA a plane."""
     blk = pl.program_id(0)
     src0, dst0, m = sel_ref[0], sel_ref[1], sel_ref[2]
+
+    def _run(copies):
+        for cp in copies:
+            cp.start()
+        for cp in copies:
+            cp.wait()
 
     @pl.when(blk * CB < m)
     def _go():
@@ -287,29 +305,20 @@ def _copyback_kernel(sel_ref, scratch_in, rows_in, rows_ref,
 
         @pl.when(jnp.logical_not(last))
         def _full():
-            cp = pltpu.make_async_copy(
-                scratch_in.at[pl.ds(src0 + blk * CB, CB)],
-                rows_ref.at[pl.ds(dst0 + blk * CB, CB)], sem)
-            cp.start()
-            cp.wait()
+            _run(hbm_copies(scratch_in, src0 + blk * CB, rows_ref,
+                            dst0 + blk * CB, CB, sem, n=n, C=C))
 
         @pl.when(last)
         def _tail():
-            cp = pltpu.make_async_copy(
-                scratch_in.at[pl.ds(src0 + blk * CB, CB)], va, sem)
-            cp.start()
-            cp.wait()
-            cpi = pltpu.make_async_copy(
-                rows_in.at[pl.ds(dst0 + blk * CB, CB)], vb, sem)
-            cpi.start()
-            cpi.wait()
+            _run(plane_copies(scratch_in, src0 + blk * CB, CB, va, sem,
+                              n=n, C=C))
+            _run(plane_copies(rows_in, dst0 + blk * CB, CB, vb, sem,
+                              n=n, C=C))
             rid = jax.lax.broadcasted_iota(jnp.int32, (CB, C), 0)
             live = rid < (m - blk * CB)
             va[:] = jnp.where(live, va[:], vb[:])
-            cpo = pltpu.make_async_copy(
-                va, rows_ref.at[pl.ds(dst0 + blk * CB, CB)], sem)
-            cpo.start()
-            cpo.wait()
+            _run(plane_copies(rows_ref, dst0 + blk * CB, CB, va, sem,
+                              n=n, C=C, to_hbm=True))
 
 
 def copyback_call(sel, rows1, scratch1, nleft, m, *, R: int,
@@ -323,7 +332,8 @@ def copyback_call(sel, rows1, scratch1, nleft, m, *, R: int,
 
     m = tl + nright with nright = cnt - nleft; the scan left the span
     contiguous at [T - m, T)."""
-    cb_kern = functools.partial(_copyback_kernel, R=R, CB=cb_block, C=C)
+    cb_kern = functools.partial(_copyback_kernel, R=R, CB=cb_block, C=C,
+                                n=n)
     cnt = sel[SEL_CNT]
     tl = m - (cnt - nleft)
     T = sel[SEL_S0] + (jnp.maximum(-(-cnt // R), 0) + 1) * R
@@ -338,7 +348,7 @@ def copyback_call(sel, rows1, scratch1, nleft, m, *, R: int,
                   pl.BlockSpec(memory_space=_HBM),
                   pl.BlockSpec(memory_space=_HBM)],
         out_specs=pl.BlockSpec(memory_space=_HBM),
-        out_shape=jax.ShapeDtypeStruct((n, C), dtype),
+        out_shape=jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
         scratch_shapes=[pltpu.VMEM((cb_block, C), dtype),
                         pltpu.VMEM((cb_block, C), dtype),
                         pltpu.SemaphoreType.DMA],
@@ -370,7 +380,6 @@ def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
     ``pack_impl`` swaps the per-block compaction (see _scan_kernel);
     partition_kernel3.make_partition_perm passes the butterfly-routing
     permutation packing through here so the schedule has one home."""
-    from .layout import check_lane_width
     check_lane_width(C, dtype)
     if interpret and not interpret_kernel:
         return _make_partition3(n, C, R=R, size=size, dtype=dtype,
@@ -380,7 +389,8 @@ def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
             "interpret_kernel supports static grids only (the Pallas "
             "interpreter cannot run a traced grid bound)")
     nblocks = max((size + R - 1) // R, 1)
-    kern = functools.partial(_scan_kernel, R=R, C=C, pack_impl=pack_impl)
+    kern = functools.partial(_scan_kernel, R=R, C=C, n=n,
+                             pack_impl=pack_impl)
 
     def _call(sel, rows, scratch, grid_blocks):
         rows1, scratch1, res = pl.pallas_call(
@@ -392,8 +402,8 @@ def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
             out_specs=[pl.BlockSpec(memory_space=_HBM),
                        pl.BlockSpec(memory_space=_HBM),
                        pl.BlockSpec(memory_space=pltpu.SMEM)],
-            out_shape=[jax.ShapeDtypeStruct((n, C), dtype),
-                       jax.ShapeDtypeStruct((n, C), dtype),
+            out_shape=[jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
+                       jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
                        jax.ShapeDtypeStruct((2,), jnp.int32)],
             scratch_shapes=[pltpu.VMEM((R, C), dtype),
                             pltpu.VMEM((R, C), dtype),

@@ -47,6 +47,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .layout import (comb_block_spec, comb_operand,
+                     comb_operand_shape, load_rows, store_rows, to_planes,
+                     to_rows)
+
 # column offsets relative to f (the bin column count)
 COL_G, COL_H, COL_CNT = 0, 1, 2
 COL_RID = 3            # 3 columns
@@ -169,7 +173,7 @@ def _extract(x, src_cols, *, C: int):
 
 def _refresh_kernel(lv_ref, comb_in, comb_ref, *, kind: str, sigmoid: float,
                     f: int, R: int, C: int, nc: int):
-    x = comb_in[:].astype(jnp.float32)                   # [R, C]
+    x = load_rows(comb_in).astype(jnp.float32)           # [R, C]
     cols = ([f + COL_SC, f + COL_SC + 1, f + COL_SC + 2, f + COL_CNT]
             + [f + COL_CONSTS + i for i in range(nc)])
     V = _extract(x, cols, C=C)
@@ -180,10 +184,10 @@ def _refresh_kernel(lv_ref, comb_in, comb_ref, *, kind: str, sigmoid: float,
     sh, sm, sl = split_bf16_3(s, mosaic=True)
     g = g.astype(jnp.bfloat16).astype(jnp.float32)
     h = h.astype(jnp.bfloat16).astype(jnp.float32)
-    comb_ref[:] = _writeback(
+    store_rows(comb_ref, _writeback(
         x, [g, h, sh, sm, sl],
         [f + COL_G, f + COL_H, f + COL_SC, f + COL_SC + 1, f + COL_SC + 2],
-        R=R, C=C).astype(comb_ref.dtype)
+        R=R, C=C).astype(comb_ref.dtype))
     return x, g, h
 
 
@@ -369,12 +373,12 @@ def _init_kernel(bins_ref, aux_ref, comb_in, comb_ref, *, kind: str,
     sh, sm, sl = split_bf16_3(s, mosaic=True)
     g = g.astype(jnp.bfloat16).astype(jnp.float32)
     h = h.astype(jnp.bfloat16).astype(jnp.float32)
-    comb_ref[:] = _writeback(
+    store_rows(comb_ref, _writeback(
         base, [g, h, cnt, sh, sm, sl] + consts,
         [f + COL_G, f + COL_H, f + COL_CNT,
          f + COL_SC, f + COL_SC + 1, f + COL_SC + 2]
         + [f + COL_CONSTS + i for i in range(nc)],
-        R=R, C=C).astype(comb_ref.dtype)
+        R=R, C=C).astype(comb_ref.dtype))
 
 
 def _xla_refresh(comb, lv2d, *, kind, sigmoid, f, n_pad, C, nc,
@@ -473,7 +477,11 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
                 n_pad=n_pad, C=cw, nc=nc, round_bf16=False,
                 padded_bins=int(padded_bins), rows_per_block=root_rpb))
             if pack == 1:
-                return ref_h
+                def refresh_h1(comb, lv2d):
+                    comb_l, hist = ref_h(to_rows(comb, C), lv2d)
+                    return to_planes(comb_l), hist
+
+                return jax.jit(refresh_h1)
 
             def refresh_h2(comb, lv2d):
                 comb_l, hist = ref_h(comb.reshape(n_alloc, cw), lv2d)
@@ -484,7 +492,10 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
             _xla_refresh, kind=kind, sigmoid=sigmoid, f=f, n_pad=n_pad,
             C=cw, nc=nc, round_bf16=False))
         if pack == 1:
-            return ref
+            def refresh1(comb, lv2d):
+                return to_planes(ref(to_rows(comb, C), lv2d))
+
+            return jax.jit(refresh1)
 
         def refresh2(comb, lv2d):
             return ref(comb.reshape(n_alloc, cw),
@@ -519,17 +530,18 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
                 in_specs=[
                     pl.BlockSpec((1, R), lambda i: (0, i),
                                  memory_space=pltpu.VMEM),
-                    pl.BlockSpec((R, C), lambda i: (i, 0),
-                                 memory_space=pltpu.VMEM),
+                    comb_block_spec(R, C, lambda i: i,
+                                    memory_space=pltpu.VMEM),
                 ],
                 out_specs=[
-                    pl.BlockSpec((R, C), lambda i: (i, 0),
-                                 memory_space=pltpu.VMEM),
+                    comb_block_spec(R, C, lambda i: i,
+                                    memory_space=pltpu.VMEM),
                     pl.BlockSpec((ngroups, m, nn), lambda i: (0, 0, 0),
                                  memory_space=pltpu.VMEM),
                 ],
                 out_shape=[
-                    jax.ShapeDtypeStruct((n_alloc, C), dtype),
+                    jax.ShapeDtypeStruct(comb_operand_shape(n_alloc, C),
+                                         dtype),
                     jax.ShapeDtypeStruct((ngroups, m, nn), jnp.float32),
                 ],
                 input_output_aliases={1: 0},
@@ -541,9 +553,9 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
                     transcendentals=n_pad,
                 ),
                 interpret=kernel_interpret,
-            )(lv2d, comb)
-            return comb_r, _diag_extract(out, ngroups, hg, b_hi, 2,
-                                         lo_n, f, b)
+            )(lv2d, comb_operand(comb, C))
+            return (comb_r.reshape(comb.shape),
+                    _diag_extract(out, ngroups, hg, b_hi, 2, lo_n, f, b))
 
         return refresh_h
 
@@ -562,12 +574,13 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
             in_specs=[
                 pl.BlockSpec((1, R), lambda i: (0, i),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((R, C), lambda i: (i, 0),
-                             memory_space=pltpu.VMEM),
+                comb_block_spec(R, C, lambda i: i,
+                                memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((R, C), lambda i: (i, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((n_alloc, C), dtype),
+            out_specs=comb_block_spec(R, C, lambda i: i,
+                                      memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct(
+                comb_operand_shape(n_alloc, C), dtype),
             input_output_aliases={1: 0},
             cost_estimate=pl.CostEstimate(
                 flops=2 * n_pad * C * (R + 16),
@@ -575,7 +588,7 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
                 transcendentals=n_pad,
             ),
             interpret=kernel_interpret,
-        )(lv2d, comb)
+        )(lv2d, comb_operand(comb, C)).reshape(comb.shape)
 
     return refresh
 
@@ -735,7 +748,10 @@ def make_init(*, kind: str, sigmoid: float, f_real: int, f: int,
             _xla_init, kind=kind, sigmoid=sigmoid, f=f, n_pad=n_pad,
             C=cw, nc=nc, round_bf16=False))
         if pack == 1:
-            return ini
+            def init1(comb0, bins, aux):
+                return to_planes(ini(to_rows(comb0, C), bins, aux))
+
+            return jax.jit(init1)
 
         def init2(comb0, bins, aux):
             return ini(comb0.reshape(n_alloc, cw), bins,
@@ -793,12 +809,13 @@ def make_init(*, kind: str, sigmoid: float, f_real: int, f: int,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((k_aux, R), lambda i: (0, i),
                              memory_space=pltpu.VMEM),
-                pl.BlockSpec((R, C), lambda i: (i, 0),
-                             memory_space=pltpu.VMEM),
+                comb_block_spec(R, C, lambda i: i,
+                                memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((R, C), lambda i: (i, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((n_alloc, C), dtype),
+            out_specs=comb_block_spec(R, C, lambda i: i,
+                                      memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct(
+                comb_operand_shape(n_alloc, C), dtype),
             input_output_aliases={2: 0},
             cost_estimate=pl.CostEstimate(
                 flops=2 * n_pad * C * (R + f_real + 16),
@@ -806,7 +823,7 @@ def make_init(*, kind: str, sigmoid: float, f_real: int, f: int,
                 transcendentals=n_pad,
             ),
             interpret=kernel_interpret,
-        )(bins, aux, comb0)
+        )(bins, aux, comb_operand(comb0, C)).reshape(comb0.shape)
 
     return init
 

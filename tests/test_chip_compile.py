@@ -1,5 +1,6 @@
 """Compile-for-the-chip guard: the default training path's kernels at
-the Higgs width, and the compiled serving walk, must get through the
+the Higgs width and at the MS LTR width (137 features: a comb line of
+two 128-lane planes), and the compiled serving walk, must get through the
 v5e compiler (Mosaic + XLA:TPU) — and today's known refusals stay
 pinned so the PR that fixes one has to flip its pin.
 
@@ -25,6 +26,13 @@ import pytest
 # 1,000,000 rows pad to a multiple of R=512, plus PHYS_ROW_SLACK
 N_PAD, N_ALLOC, C, F_PAD, BINS, LEAVES, R = (
     1_000_448, 1_005_568, 128, 32, 256, 255, 512)
+HIGGS = (N_PAD, N_ALLOC, C, F_PAD)
+# MS LTR, 2,270,296 x 137 on the non-stream physical route: 137 features
+# pad to 144 columns, + 6 value / row-id columns = 150 lanes, C = 256.
+# A [n, 256] f32 array is tiled (8, 128) in HBM and Mosaic refuses a row
+# DMA at an arbitrary row offset into it; the comb is plane-major
+# (ops/pallas/layout.py), which is what these cases hold
+MSLTR = (2_270_720, 2_275_840, 256, 144)
 
 
 @pytest.fixture(scope="module")
@@ -59,25 +67,27 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _part_args():
+def _part_args(n_alloc, c):
     """(sel, rows, scratch, grid_blocks) of the dynamic-grid scans."""
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.registry import partition_args, sds
-    return partition_args(N_ALLOC, C) + (sds((), jnp.int32),)
+    return partition_args(n_alloc, c) + (sds((), jnp.int32),)
 
 
-def _fused(scan: str):
+def _fused(scan: str, geom=HIGGS):
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
-    fn = make_fused_split(N_ALLOC, C, f_pad=F_PAD, padded_bins=BINS, R=R,
+    _, n_alloc, c, f_pad = geom
+    fn = make_fused_split(n_alloc, c, f_pad=f_pad, padded_bins=BINS, R=R,
                           dynamic=True, scan=scan)
-    return fn, _part_args()
+    return fn, _part_args(n_alloc, c)
 
 
-def _partition_perm():
+def _partition_perm(geom=HIGGS):
     from lightgbm_tpu.ops.pallas.partition_kernel3 import \
         make_partition_perm
-    return (make_partition_perm(N_ALLOC, C, R=R, dynamic=True),
-            _part_args())
+    _, n_alloc, c, _ = geom
+    return (make_partition_perm(n_alloc, c, R=R, dynamic=True),
+            _part_args(n_alloc, c))
 
 
 def _stream(which: str):
@@ -97,13 +107,16 @@ def _stream(which: str):
     return fn, (comb, sds((1, N_PAD), jnp.float32))
 
 
-def _hist_comb_root():
+def _hist_comb_root(geom=HIGGS):
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.registry import sds
     from lightgbm_tpu.ops.pallas.hist_kernel2 import build_histogram_comb
-    fn = functools.partial(build_histogram_comb, f_pad=F_PAD, size=N_PAD,
-                           padded_bins=BINS)
-    return fn, (sds((N_ALLOC, C), jnp.float32),) + (sds((), jnp.int32),) * 3
+    from lightgbm_tpu.ops.pallas.layout import comb_planes, comb_shape
+    n_pad, n_alloc, c, f_pad = geom
+    fn = functools.partial(build_histogram_comb, f_pad=f_pad, size=n_pad,
+                           padded_bins=BINS, planes=comb_planes(c))
+    return fn, (sds(comb_shape(n_alloc, c), jnp.float32),) + (
+        sds((), jnp.int32),) * 3
 
 
 def _apply_find_pool(f: int):
@@ -148,6 +161,14 @@ COMPILES = {
     "apply_find_pool_f28": (functools.partial(_apply_find_pool, 28), True),
     "apply_find_pool_f32": (functools.partial(_apply_find_pool, 32), True),
     "serve_forest_100x255": (_serve_forest, False),
+    "fused_split_permute_msltr": (
+        functools.partial(_fused, "permute", MSLTR), True),
+    "fused_split_matmul_msltr": (
+        functools.partial(_fused, "matmul", MSLTR), True),
+    "partition_perm_msltr": (
+        functools.partial(_partition_perm, MSLTR), True),
+    "hist_comb_root_msltr": (
+        functools.partial(_hist_comb_root, MSLTR), True),
 }
 
 
@@ -209,6 +230,16 @@ def test_kernel_names_reach_the_compiled_program(kernel, compiled_text):
     every = re.findall(r"%([A-Za-z_][\w-]*?)(?:\.\d+)? = [^\n]*"
                        r"custom_call_target=\"tpu_custom_call\"", text)
     assert set(every) <= set(KERNEL_NAMES), every
+
+
+def test_the_finder_at_the_msltr_width_is_the_xla_tail():
+    """144 columns x 256 bins is past the Pallas finder's scoped-VMEM
+    budget (apply_find.tail_supported), so that route's split finder is
+    the XLA tail and ``lgbm_apply_find`` is not in its program.  Flip
+    this pin in the PR that tiles the finder over features."""
+    from lightgbm_tpu.ops.pallas.apply_find import tail_supported
+    assert tail_supported(F_PAD, BINS)
+    assert not tail_supported(MSLTR[3], BINS)
 
 
 # Off the default path, refused by the v5e compiler on jax 0.9.0 /

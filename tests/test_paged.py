@@ -447,3 +447,22 @@ class TestCkptAtRefresh:
             params=CKPT_PARAMS)
         assert info2["path"] == "physical"
         assert txt == ref
+
+
+@pytest.mark.parametrize("C", [128, 256])
+def test_page_store_round_trips_a_plane_major_window(C):
+    """A page is the same line range of every 128-lane plane of the
+    comb (ops/pallas/layout.py): flushing a window to host pages and
+    fetching it back is the identity at one plane and at two."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.grow import PHYS_ROW_SLACK
+    from lightgbm_tpu.ops.paged import PageStore
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    n_alloc = 4096 + PHYS_ROW_SLACK
+    store = PageStore(n_alloc=n_alloc, C=C, rows_per_page=1024)
+    shape = comb_shape(n_alloc, C)
+    window = jnp.arange(shape[0] * shape[1], dtype=jnp.float32).reshape(shape)
+    store.flush_window(window)
+    assert store._pages[0].shape[-2:] == (store.page_lines, 128)
+    np.testing.assert_array_equal(np.asarray(store.fetch_window()),
+                                  np.asarray(window))

@@ -467,6 +467,97 @@ def test_fused_kernel_hook_takes_the_compactions_flags(kind):
                                    np.asarray(comp[i]), rtol=0, atol=1e-4)
 
 
+# ---------------------------------------------------------------------
+# ISSUE 29: a comb line wider than 128 lanes is stored plane-major and
+# moves as one row DMA a plane.  The same scans, two planes, through the
+# Pallas interpreter; the split column and the value columns sit in the
+# SECOND plane.
+# ---------------------------------------------------------------------
+C2 = 2 * LANE
+
+
+def _rows2(seed=0):
+    rng = np.random.default_rng(seed)
+    rows = np.zeros((N, C2), np.float32)
+    rows[:, :8] = rng.integers(0, 64, size=(N, 8))
+    rows[:, 130:138] = rng.integers(0, 64, size=(N, 8))
+    rows[:, 140] = rng.normal(size=N)
+    rows[:, 255] = rng.random(size=N)
+    return rows
+
+
+@pytest.mark.parametrize("cfg", [(64, 900, 131, 20), (0, 1024, 0, 31),
+                                 (513, 1, 135, 10), (100, 0, 2, 5),
+                                 (17, 1000, 137, 40)])
+def test_two_plane_scans_match_the_oracle(cfg):
+    from lightgbm_tpu.ops.pallas.layout import comb_shape, to_planes, \
+        to_rows
+    s0, cnt, feat, sbin = cfg
+    rows = _rows2()
+    rj = to_planes(jnp.asarray(rows))
+    assert rj.shape == comb_shape(N, C2) == (2 * N, LANE)
+    np.testing.assert_array_equal(np.asarray(to_rows(rj, C2)), rows)
+    sel = _sel(*cfg)
+    out = {}
+    for name, make in (("permute", make_partition_perm),
+                       ("matmul", make_partition_ss)):
+        fn = make(N, C2, R=R, size=SIZE, interpret=True,
+                  interpret_kernel=True)
+        r, _, nl = fn(sel, rj, jnp.zeros_like(rj))
+        out[name] = (np.asarray(to_rows(r, C2)), int(nl))
+    np.testing.assert_array_equal(out["permute"][0], out["matmul"][0])
+    seg = rows[s0:s0 + cnt]
+    gl = seg[:, feat] <= sbin
+    got, nl = out["permute"]
+    assert nl == out["matmul"][1] == int(gl.sum())
+    np.testing.assert_array_equal(got[s0:s0 + nl], seg[gl])
+    np.testing.assert_array_equal(got[s0 + nl:s0 + cnt], seg[~gl][::-1])
+    np.testing.assert_array_equal(got[:s0], rows[:s0])
+    np.testing.assert_array_equal(got[s0 + cnt:], rows[s0 + cnt:])
+    # and the XLA emulation the off-chip grow path runs, on the same
+    # plane-major array: same segments, stable order
+    emu = make_partition_perm(N, C2, R=R, size=SIZE, interpret=True)
+    r_e, _, nl_e = emu(sel, rj, jnp.zeros_like(rj))
+    assert int(nl_e) == nl
+    np.testing.assert_array_equal(
+        np.asarray(to_rows(r_e, C2))[s0:s0 + cnt],
+        np.concatenate([seg[gl], seg[~gl]]))
+
+
+def test_two_plane_fused_kernel_matches_the_composition():
+    """The REAL fused scan + dual histogram at 144 feature columns (the
+    MS LTR layout: bins through lane 143, values at 144-145, both planes
+    live) against partition kernel + per-side comb histogram."""
+    import ml_dtypes
+    from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
+    from lightgbm_tpu.ops.pallas.layout import to_planes
+    f_pad, bins = 144, 64
+    rng = np.random.default_rng(9)
+    rows = np.zeros((N, C2), np.float32)
+    rows[:, :f_pad] = rng.integers(0, bins, size=(N, f_pad))
+    rows[:, f_pad:f_pad + 2] = rng.normal(size=(N, 2)).astype(
+        ml_dtypes.bfloat16).astype(np.float32)
+    rj = to_planes(jnp.asarray(rows))
+    sel = _sel(64, 900, 133, 30)
+    kw = dict(f_pad=f_pad, padded_bins=bins, R=R, size=SIZE)
+    real = {scan: make_fused_split(N, C2, scan=scan,
+                                   fused_kernel_interpret=True, **kw)(
+        sel, rj, jnp.zeros_like(rj)) for scan in ("permute", "matmul")}
+    for i in (0, 2, 3, 4):
+        np.testing.assert_array_equal(np.asarray(real["permute"][i]),
+                                      np.asarray(real["matmul"][i]))
+    comp = make_fused_split(N, C2, interpret=True, interpret_kernel=True,
+                            hist_rpb=R, **kw)(sel, rj, jnp.zeros_like(rj))
+    np.testing.assert_array_equal(np.asarray(real["permute"][0]),
+                                  np.asarray(comp[0]))
+    nleft = int(real["permute"][2])
+    assert 0 < nleft == int(comp[2]) < 900
+    for i in (3, 4):
+        assert np.abs(np.asarray(comp[i])[128:]).sum() > 0   # plane 1
+        np.testing.assert_allclose(np.asarray(real["permute"][i]),
+                                   np.asarray(comp[i]), rtol=0, atol=1e-4)
+
+
 class TestLaneContract:
     """Off-chip pin for the BENCH_r03 Mosaic regression class: every
     kernel column-slice/comb width in the repo must be a multiple of
