@@ -1514,10 +1514,12 @@ class GBDT:
         return tree
 
     def _record_work_counters(self, span, ta, kidxs) -> None:
-        """The four work counters (obs/counters.py) of the tree(s) just
+        """The work counters (obs/counters.py) of the tree(s) just
         grown, derived on the host from the tree itself: one pull of
-        its small arrays (a few KB; a transfer, not a program) after
-        the ``Tree::grow`` barrier, recorded as before
+        its small arrays (a few KB; a transfer, not a program; with
+        them the two numbers the grow program counts for its fused
+        scan, ``ta.side_miss``) after the ``Tree::grow`` barrier,
+        recorded as before
         (``obs_counters.record`` + ``tracer.count``) and set as args of
         the ``Tree::grow`` span.  Only ever called while tracing, and
         the same whether the tracer was enabled before the booster was
@@ -1527,7 +1529,7 @@ class GBDT:
         with obs_tracer.span("WorkCounters"):
             small = jax.device_get(
                 (ta.num_leaves, ta.left_child, ta.right_child,
-                 ta.internal_count, ta.leaf_count))
+                 ta.internal_count, ta.leaf_count, ta.side_miss))
         fused = (bool(getattr(self.grow, "fused", False))
                  and jax.default_backend() == "tpu")
         batched = np.ndim(small[0]) > 0

@@ -24,9 +24,13 @@ Byte contracts (physical comb layout, ``ops/pallas/layout.py``):
 * a comb-direct histogram build reads each in-window row once and
   writes the [f_pad, padded_bins, 2] f32 histogram once (accumulation
   lives in VMEM);
-* the fused split kernel pays the partition traffic plus BOTH
-  children's histogram writes — and nothing else: the smaller-child
-  re-read the unfused pipeline pays is exactly what fusion deletes;
+* the fused split kernel pays the partition traffic plus ONE child's
+  histogram write (ISSUE 30: the child the finder's record calls
+  smaller); only at a split whose record named the larger child
+  (``side_miss_splits`` of them) does a comb-direct build of the
+  smaller one follow (``rows_rehistogrammed`` rows in all) - so the
+  smaller-child re-read the unfused pipeline pays at every split is
+  what fusion deletes, up to the misses;
 * a stream refresh pass reads and rewrites every comb line once
   (plus one root-histogram write when the fused root carry is on).
 
@@ -242,13 +246,22 @@ def hist_build_bytes(cnt: int, *, f_pad: int, padded_bins: int,
 
 def fused_split_bytes(cnt: int, nleft: int, *, f_pad: int,
                       padded_bins: int, pack: int = 1,
-                      itemsize: int = F32, c_phys: int = LANE) -> int:
-    """Exact HBM bytes one FUSED partition+histogram split moves:
-    the partition traffic plus both children's histogram writes (the
-    child rows are histogrammed from VMEM — no re-read)."""
-    return (partition_split_bytes(cnt, nleft, pack=pack,
-                                  itemsize=itemsize, c_phys=c_phys)
-            + 2 * hist_out_bytes(f_pad, padded_bins))
+                      itemsize: int = F32, c_phys: int = LANE,
+                      rehist_rows: int = 0) -> int:
+    """Exact HBM bytes one FUSED partition+histogram split moves: the
+    partition traffic and the scan's ONE histogram write (the named
+    child's rows are histogrammed from VMEM — no re-read); where the
+    scan was told the larger child, also a comb-direct build of the
+    smaller one's ``rehist_rows = min(nleft, cnt - nleft)`` rows (0:
+    the scan was told the smaller child, nothing follows it)."""
+    out = (partition_split_bytes(cnt, nleft, pack=pack,
+                                 itemsize=itemsize, c_phys=c_phys)
+           + hist_out_bytes(f_pad, padded_bins))
+    if rehist_rows:
+        out += hist_build_bytes(rehist_rows, f_pad=f_pad,
+                                padded_bins=padded_bins, pack=pack,
+                                itemsize=itemsize, c_phys=c_phys)
+    return out
 
 
 def unfused_split_bytes(cnt: int, nleft: int, *, f_pad: int,
@@ -464,6 +477,8 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     splits = int(counters.get("splits", 0))
     rows_part = int(counters.get("rows_partitioned", 0))
     rows_hist = int(counters.get("rows_histogrammed", 0))
+    rows_rehist = int(counters.get("rows_rehistogrammed", 0))
+    misses = int(counters.get("side_miss_splits", 0))
     lrb = logical_row_bytes(pack=pack)
 
     def _part_row(cnt: int) -> Dict[str, float]:
@@ -482,19 +497,21 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     # whole-loop totals from the work counters — joined with the
     # Tree::grow wall, which is the span that covers every split.
     # Histogram traffic mirrors the per-split contracts above: fused
-    # writes BOTH children per split and re-reads nothing (children
-    # accumulate from the scan's VMEM-resident blocks, root passes
-    # stay); unfused re-reads the smaller child (rows_hist already
-    # counts it) and writes ONE histogram per split (the sibling comes
-    # from the subtraction, in registers) plus one per tree root.
+    # writes ONE child's histogram per split from the scan's
+    # VMEM-resident blocks, and re-reads and writes again only the
+    # smaller children of the side_miss_splits whose record named the
+    # larger one (rows_rehistogrammed; root passes stay); unfused
+    # re-reads the smaller child (rows_hist already counts it) and
+    # writes ONE histogram per split (the sibling comes from the
+    # subtraction, in registers) plus one per tree root.
     # These writes are deterministic, so they land in ALL of bytes /
     # bytes_lo / bytes_hi — only the partition copyback term varies.
     grow = _part_row(rows_part)
     # fused root passes cover at most the in-bag rows per tree
     # (bagging makes them fewer; rows_hist is the honest ceiling)
-    hist_reads = (min(root_rows, rows_hist) if fused else rows_hist) \
-        * lrb
-    hist_writes = (trees + (2 if fused else 1) * splits) \
+    hist_reads = (min(root_rows, rows_hist) + rows_rehist if fused
+                  else rows_hist) * lrb
+    hist_writes = (trees + splits + (misses if fused else 0)) \
         * hist_out_bytes(f_pad, padded_bins)
     for key in ("bytes", "bytes_lo", "bytes_hi"):
         grow[key] += hist_reads + hist_writes
@@ -520,10 +537,11 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     is judged on the time the kernels actually ran.
 
     Attribution follows the engaged path: with ``fused`` on, the scan,
-    copyback and both children's histogram writes all execute inside
-    the fused kernel (the separate classes predict 0 and the root
-    passes land on ``hist_build`` — or ride ``stream_refresh`` when the
-    fused root carry is on); unfused splits split the same traffic
+    copyback and one child's histogram write execute inside the fused
+    kernel, the builds of the ``side_miss_splits`` (their writes, the
+    ``rows_rehistogrammed`` reads) land on ``hist_build`` with the
+    root passes (which ride ``stream_refresh`` instead when the fused
+    root carry is on); unfused splits split the same traffic
     across partition_scan / partition_copyback / hist_build.  Copyback
     traffic is data-dependent, so classes that include it carry
     ``bytes_lo`` / ``bytes_hi`` bounds with ``bytes`` at the midpoint.
@@ -547,6 +565,8 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     splits = int(counters.get("splits", 0))
     rows_part = int(counters.get("rows_partitioned", 0))
     rows_hist = int(counters.get("rows_histogrammed", 0))
+    rows_rehist = int(counters.get("rows_rehistogrammed", 0))
+    misses = int(counters.get("side_miss_splits", 0))
     lrb = logical_row_bytes(pack=pack)
     hw = hist_out_bytes(f_pad, padded_bins)
     root_rows = n_rows * trees
@@ -557,19 +577,19 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
 
     out: Dict[str, Dict[str, float]] = {}
     if fused:
-        # scan + copyback + BOTH children's histogram writes, one kernel
+        # scan + copyback + ONE child's histogram write, one kernel
         out["fused_split"] = {
-            "bytes_lo": 2.0 * rows_part * lrb + 2.0 * splits * hw,
-            "bytes_hi": 4.0 * rows_part * lrb + 2.0 * splits * hw,
-            "bytes": 3.0 * rows_part * lrb + 2.0 * splits * hw,
+            "bytes_lo": 2.0 * rows_part * lrb + splits * hw,
+            "bytes_hi": 4.0 * rows_part * lrb + splits * hw,
+            "bytes": 3.0 * rows_part * lrb + splits * hw,
         }
-        if stream:
-            # the fused root carry builds root histograms inside the
-            # refresh pass — hist_build runs nothing on this path
-            out["hist_build"] = _exact(0.0)
-        else:
-            out["hist_build"] = _exact(
-                min(root_rows, rows_hist) * lrb + trees * hw)
+        # the builds of the missed splits; the fused root carry
+        # builds root histograms inside the refresh pass, else they
+        # are hist_build's too
+        rehist = rows_rehist * lrb + misses * hw
+        out["hist_build"] = _exact(
+            rehist if stream
+            else rehist + min(root_rows, rows_hist) * lrb + trees * hw)
     else:
         out["partition_scan"] = _exact(2.0 * rows_part * lrb)
         out["partition_copyback"] = {

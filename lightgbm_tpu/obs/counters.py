@@ -1,11 +1,15 @@
 """Training work counters and live-buffer watermarks.
 
-The four work counters of a tree are functions of the finished tree,
-so while tracing the booster derives them on the host
+Four work counters of a tree are functions of the finished tree, so
+while tracing the booster derives them on the host
 (``counters_from_tree``) from one pull of the tree's small arrays after
 the ``Tree::grow`` barrier — no second grow program, no extra
 dispatch, and the same whether the tracer was enabled before the
-booster was built or after.  Counter semantics:
+booster was built or after.  Two more are not (which child the fused
+scan was told to histogram is forgotten once the split is done): the
+grow program counts them in its state, always, traced or not, and
+they ride the same pull as ``TreeArrays.side_miss``.  Counter
+semantics:
 
   splits            — splits taken (== num_leaves - 1 of the tree)
   rows_partitioned  — in-bag rows moved by the physical/logical
@@ -20,6 +24,21 @@ booster was built or after.  Counter semantics:
                       Pallas kernel (LGBM_TPU_FUSED path): ``splits``
                       on that route on a TPU, 0 on the unfused /
                       non-physical / interpreted paths
+  side_miss_splits  — fused physical route: splits at which the child
+                      the finder's record called smaller (its
+                      hessian-derived left count, ops/grow.py
+                      ``pred_left``) was not the smaller one by the
+                      exact counts, so the scan's one-sided histogram
+                      was of the wrong child and the smaller one was
+                      histogrammed again from the comb.  Counted off
+                      the chip too, where the reference path takes
+                      the comb-direct histogram at every split; 0 on
+                      the other routes
+  rows_rehistogrammed — the rows of those smaller children (global
+                      under the mesh learners): over
+                      ``rows_partitioned`` it is the share of the
+                      scan's row visits the estimate cost a second
+                      read for (benchmarks: ``scan_side_miss``)
 
 Plus HBM watermark sampling: ``hbm_live_bytes`` is the cheap
 ``jax.live_arrays`` census of live device buffers (catches leaks and
@@ -48,27 +67,29 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 COUNTER_NAMES = ("splits", "rows_partitioned", "rows_histogrammed",
-                 "fused_splits")
+                 "fused_splits", "side_miss_splits", "rows_rehistogrammed")
 
 
 def counters_to_dict(vec) -> Dict[str, float]:
-    """Name a raw [4] counter vector from the grow call."""
+    """Name a raw counter vector (``COUNTER_NAMES`` order)."""
     a = np.asarray(vec, np.float64).reshape(-1)
     return {name: float(a[i]) for i, name in enumerate(COUNTER_NAMES)}
 
 
 def counters_from_tree(num_leaves, left_child, right_child,
-                       internal_count, leaf_count, *,
+                       internal_count, leaf_count, side_miss=(0, 0), *,
                        fused: bool) -> np.ndarray:
-    """The [4] counter vector (``COUNTER_NAMES`` order) of one finished
-    tree, from its host arrays.  Counts are integral f32 below 2^24
-    each; sums run in float64, exact far beyond the ~n*log2(L) a tree
-    can reach (84M at Higgs 10.5M)."""
+    """The counter vector (``COUNTER_NAMES`` order) of one finished
+    tree, from its host arrays; ``side_miss`` is the pair the grow
+    program counted (``TreeArrays.side_miss``).  Counts are integral
+    f32 below 2^24 each; sums run in float64, exact far beyond the
+    ~n*log2(L) a tree can reach (84M at Higgs 10.5M)."""
     splits = int(num_leaves) - 1
     leaf_c = np.asarray(leaf_count, np.float64)
+    miss = [float(v) for v in np.asarray(side_miss).reshape(2)]
     if splits <= 0:
         # a stump: the root pass is all the work there was
-        return np.array([0.0, 0.0, leaf_c[0], 0.0])
+        return np.array([0.0, 0.0, leaf_c[0], 0.0] + miss)
     int_c = np.asarray(internal_count, np.float64)[:splits]
 
     def child_count(child):
@@ -81,7 +102,7 @@ def counters_from_tree(num_leaves, left_child, right_child,
     smaller = np.minimum(child_count(left_child), child_count(right_child))
     # node 0 is the root: its count is the root pass
     return np.array([splits, int_c.sum(), int_c[0] + smaller.sum(),
-                     splits if fused else 0], np.float64)
+                     splits if fused else 0] + miss, np.float64)
 
 
 class CounterStore:

@@ -35,7 +35,7 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
         HbmCensus                       live-array census (obs mem)
         GradSlice                       eager grad[k], hess[k]
         GBDT::grow                      utils/timer.py's twin of the next
-          Tree::grow                    args: the four work counters
+          Tree::grow                    args: the work counters
             Tree::grow::wait            the device runs the grow program
             WorkCounters                pull of the tree's small arrays
         HbmCensus
@@ -68,8 +68,9 @@ JAX reports the fetch under this name too) and ``jax::cache_load``
 a load or a retrace says so, inside the span it happened in.
 
 Work counters are derived on the host from the finished tree
-(``obs/counters.counters_from_tree``) after the ``Tree::grow``
-barrier, and set as args of that span.
+(``obs/counters.counters_from_tree``; two of them are counted by the
+grow program, traced or not, and come with the tree) after the
+``Tree::grow`` barrier, and set as args of that span.
 
 Xplane correlation: while ``tracer.annotate(True)`` — a profiler
 capture is live — every span additionally enters a

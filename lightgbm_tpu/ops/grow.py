@@ -70,6 +70,12 @@ class TreeArrays(NamedTuple):
     # when the sorted-subset search is off (one-hot sets are then implied
     # by threshold_bin).
     cat_members: jnp.ndarray
+    # i32 [2], telemetry of the fused scan's one-sided histogram hook
+    # (obs/counters.py): splits whose predicted smaller child was not
+    # the smaller one, and the rows re-histogrammed for them.  Global
+    # under the mesh learners; zeros off the fused physical route.  Not
+    # part of the model: models/tree.py does not read it.
+    side_miss: jnp.ndarray
 
 
 class _GrowState(NamedTuple):
@@ -119,6 +125,7 @@ class _GrowState(NamedTuple):
     paid: jnp.ndarray            # CEGB lazy paid-rows mask [F, n] bool
                                  # ([1, 1] when off); persists ACROSS
                                  # trees via the grow return value
+    side_miss: jnp.ndarray       # i32 [2]: TreeArrays.side_miss so far
 
 
 # _GrowState.best column indices
@@ -213,6 +220,7 @@ def _empty_tree(num_leaves: int, cat_b: int = 0) -> TreeArrays:
         num_leaves=jnp.int32(1),
         cat_members=jnp.zeros((ni, cat_b) if cat_b else (1, 1),
                               jnp.float32),
+        side_miss=zi(2),
     )
 
 
@@ -732,10 +740,12 @@ def make_grow_fn(
         _phys_interp = jax.default_backend() != "tpu"
         # fused partition+histogram split kernel (fused_split.py): one
         # dynamic-grid scan per split compacts the parent AND
-        # accumulates both children's histograms from the VMEM-resident
-        # row blocks — the separate child-histogram kernel (and its HBM
-        # re-read of the rows the scan just streamed) disappears.  The
-        # 3-phase bisection knob keeps the fully-unfused pipeline.
+        # accumulates one child's histogram from the VMEM-resident row
+        # blocks, the child the finder's record calls smaller — the
+        # separate child-histogram kernel (and its HBM re-read of the
+        # rows the scan just streamed) has work only where the record
+        # named the larger one.  The 3-phase bisection knob keeps the
+        # fully-unfused pipeline.
         from .pallas.fused_split import fused_supported
         _use_fused = (FUSED_IMPL != "0" and PART_IMPL != "3ph"
                       and fused_supported(f_pad_p, int(padded_bins)))
@@ -1447,6 +1457,7 @@ def make_grow_fn(
                    if use_mono_inter else jnp.zeros((1, 1), jnp.float32)),
             paid=(paid_in if use_cegb_lazy
                   else jnp.zeros((1, 1), jnp.bool_)),
+            side_miss=jnp.zeros((2,), jnp.int32),
         )
 
         def body(i, st: _GrowState) -> _GrowState:
@@ -1563,6 +1574,16 @@ def make_grow_fn(
             par_cnt = st.seg[leaf, 1]
             par_sel = (jax.lax.pmax(par_cnt, axis_name)
                        if axis_name is not None else par_cnt)
+            # the child the finder's record says is smaller (its left
+            # count is the reference's hessian-derived estimate,
+            # split.derived_counts; global under the mesh learners, so
+            # every shard names the same side without a psum): the
+            # fused scan histograms that one, and a split where the
+            # exact counts say otherwise re-histograms below
+            lc_rec = brow[_BLC]
+            if n_forced:
+                lc_rec = jnp.where(use_forced, f_lc, lc_rec)
+            pred_left = lc_rec * 2 <= lrow[_SC]
 
             def make_bucket(size):
                 def fn(_):
@@ -1679,7 +1700,8 @@ def make_grow_fn(
                     h = hist_merge(b_part, vals,
                                    min(rows_per_block, size))
                     return (row_order_new, st.comb, st.scratch,
-                            nleft_, small_left_, h, paid_n, u2)
+                            nleft_, small_left_, h, paid_n, u2,
+                            jnp.minimum(nl_g, par_g - nl_g))
                 return fn
 
             def make_bucket_phys(size):
@@ -1728,37 +1750,24 @@ def make_grow_fn(
                     if _phys_interp:
                         # off-TPU reference path: explicit slice + mask
                         # (over the logical view, so pack=2 runs the
-                        # identical arithmetic on identical values)
-                        combp_l = _comb_logical(combp)
-
-                        def _side_hist(start_s, cnt_s):
-                            start_c = jnp.clip(start_s, 0,
-                                               _n_alloc - s_child)
-                            off = start_s - start_c
-                            rowsl = jax.lax.dynamic_slice(
-                                combp_l, (start_c, jnp.int32(0)),
-                                (s_child, _CW))
-                            posr = jnp.arange(s_child, dtype=jnp.int32)
-                            m = ((posr >= off) & (posr < off + cnt_s)
-                                 & ~done).astype(jnp.float32)
-                            return hist_merge(
-                                rowsl[:, :f],
-                                rowsl[:, f:f + 2] * m[:, None], rpb_h)
-
-                        if _use_fused:
-                            # fused reference: BOTH children
-                            # histogrammed (mirroring the compiled
-                            # kernel's dual accumulation), smaller one
-                            # selected afterwards.  The selected side
-                            # runs the exact computation the unfused
-                            # path runs for (child_start, child_cnt),
-                            # so trees stay bit-identical.
-                            h_l = _side_hist(s0, nleft_)
-                            h_r = _side_hist(s0 + nleft_,
-                                             par_cnt - nleft_)
-                            h = jnp.where(small_left_, h_l, h_r)
-                        else:
-                            h = _side_hist(child_start, child_cnt)
+                        # identical arithmetic on identical values).
+                        # Fused or not: the compiled fused route hands
+                        # on the scan's histogram where it named the
+                        # smaller child and the comb-direct one of
+                        # (child_start, child_cnt) where it did not,
+                        # so its reference is the unfused computation
+                        start_c = jnp.clip(child_start, 0,
+                                           _n_alloc - s_child)
+                        off = child_start - start_c
+                        rowsl = jax.lax.dynamic_slice(
+                            _comb_logical(combp),
+                            (start_c, jnp.int32(0)), (s_child, _CW))
+                        posr = jnp.arange(s_child, dtype=jnp.int32)
+                        m = ((posr >= off) & (posr < off + child_cnt)
+                             & ~done).astype(jnp.float32)
+                        h = hist_merge(
+                            rowsl[:, :f],
+                            rowsl[:, f:f + 2] * m[:, None], rpb_h)
                     else:
                         from .pallas.hist_kernel2 import \
                             build_histogram_comb
@@ -1771,7 +1780,8 @@ def make_grow_fn(
                             planes=_PLANES)
                     return (st.row_order, combp, scrp,
                             nleft_, small_left_, h, st.paid,
-                            jnp.zeros((1, 2), jnp.float32))
+                            jnp.zeros((1, 2), jnp.float32),
+                            jnp.minimum(nlg_, parg_ - nlg_))
                 return fn
 
             if physical and not _phys_interp:
@@ -1787,7 +1797,7 @@ def make_grow_fn(
                 sel = jnp.stack([
                     s0, cnt_eff, feat, sbin, dl.astype(jnp.int32),
                     cat.astype(jnp.int32), nanb_sel,
-                    jnp.int32(0)]).astype(jnp.int32)
+                    pred_left.astype(jnp.int32)]).astype(jnp.int32)
                 if hp.use_cat_subset:
                     # membership bitset rides the descriptor (see the
                     # bucket path above); sel stays i32[8] with the
@@ -1800,15 +1810,12 @@ def make_grow_fn(
                            if _comb_pack == 2
                            else jnp.maximum(-(-cnt_eff // _PHYS_R), 1))
                 if _use_fused:
-                    # ONE kernel: compaction scan + both children's
-                    # histograms from the VMEM-resident blocks; the
-                    # separate child-histogram pass (and its HBM
-                    # re-read) is gone
-                    comb_n, scratch_n, nleft, h_l, h_r = _fused_dyn(
+                    # ONE kernel: compaction scan + the histogram of
+                    # the child sel[SEL_SIDE] names, from the
+                    # VMEM-resident blocks
+                    comb_n, scratch_n, nleft, h_side = _fused_dyn(
                         sel, st.comb, st.scratch, nb_part)
                 else:
-                    from .pallas.hist_kernel2 import \
-                        build_histogram_comb_dyn
                     comb_n, scratch_n, nleft = _part_dyn(
                         sel, st.comb, st.scratch, nb_part)
                 # smaller child by GLOBAL counts so every shard
@@ -1820,24 +1827,38 @@ def make_grow_fn(
                 else:
                     nl_g, par_g = nleft, par_cnt
                 small_is_left = nl_g * 2 <= par_g
-                if _use_fused:
-                    # the smaller side is only known now (psum over
-                    # shards under the mesh learners) — select it from
-                    # the pair the scan accumulated; the sibling comes
-                    # from parent-minus-child exactly as before
-                    h_small = merge_kernel_hist(
-                        jnp.where(small_is_left, h_l, h_r))
-                else:
-                    child_cnt = jnp.where(small_is_left, nleft,
-                                          par_cnt - nleft)
-                    child_start = jnp.where(small_is_left, s0,
-                                            s0 + nleft)
-                    h_small = merge_kernel_hist(build_histogram_comb_dyn(
-                        comb_n, child_start, jnp.int32(0),
-                        jnp.where(done, 0, child_cnt), f_pad=f,
+                child_cnt = jnp.where(small_is_left, nleft,
+                                      par_cnt - nleft)
+                child_start = jnp.where(small_is_left, s0, s0 + nleft)
+                # the exactly smaller child is histogrammed directly,
+                # the sibling is parent minus child: unfused at every
+                # split, fused only where the scan was told the other
+                # side.  The cond's branches only READ the comb (one
+                # that handed it on would put 5.4e9 bytes at 10.5M
+                # rows at a branch boundary, like the static-bucket
+                # switch above); a kernel gated by a row count of 0
+                # instead cost 28 ms a tree at 144 columns in launches
+                # and in extracting histograms nobody read (PERF.md,
+                # PR 30)
+                from .pallas.hist_kernel2 import build_histogram_comb_dyn
+
+                def _child_hist(comb_c, cnt_c):
+                    return build_histogram_comb_dyn(
+                        comb_c, child_start, jnp.int32(0), cnt_c, f_pad=f,
                         padded_bins=padded_bins,
                         rows_per_block=min(rows_per_block, _HIST_RPB),
-                        pack=_comb_pack, planes=_PLANES))
+                        pack=_comb_pack, planes=_PLANES)
+
+                if _use_fused:
+                    h_small = jax.lax.cond(
+                        (pred_left != small_is_left) & ~done,
+                        lambda comb_c, _: _child_hist(comb_c, child_cnt),
+                        lambda _, h: h, comb_n, h_side)
+                else:
+                    h_small = _child_hist(
+                        comb_n, jnp.where(done, 0, child_cnt))
+                h_small = merge_kernel_hist(h_small)
+                small_g = jnp.minimum(nl_g, par_g - nl_g)
                 row_order = st.row_order
                 paid_n = st.paid
                 u2 = jnp.zeros((1, 2), jnp.float32)
@@ -1851,7 +1872,16 @@ def make_grow_fn(
                         sizes_arr >= jnp.maximum(par_sel, 1)) - 1
                     out = jax.lax.switch(bidx, branches, None)
                 (row_order, comb_n, scratch_n, nleft, small_is_left,
-                 h_small, paid_n, u2) = out
+                 h_small, paid_n, u2, small_g) = out
+            side_miss = st.side_miss
+            if physical and _use_fused:
+                # counted here, from the record and the exact counts,
+                # so that the off-chip reference reads what the chip
+                # does (obs/counters.py: side_miss_splits,
+                # rows_rehistogrammed)
+                side_miss += jnp.where(
+                    (pred_left != small_is_left) & ~done,
+                    jnp.stack([jnp.int32(1), small_g]), 0)
             h_small = expand(h_small)   # EFB physical -> logical
             rows_parent = par_cnt
 
@@ -1908,6 +1938,7 @@ def make_grow_fn(
                         finder_consts, iscat_i, mono_s_t,
                         st.best, st.lstate, st.nodes, st.seg, st.pool)
                 return st._replace(
+                    side_miss=side_miss,
                     row_order=row_order, comb=comb_n, scratch=scratch_n,
                     seg=seg_n, pool=pool_n,
                     best=best_n, lstate=lstate_n, nodes=nodes_n,
@@ -1938,6 +1969,7 @@ def make_grow_fn(
                     finder_consts, iscat_i, mono_s_t,
                     st.best, st.lstate, st.nodes, st.seg)
                 return st._replace(
+                    side_miss=side_miss,
                     row_order=row_order, comb=comb_n, scratch=scratch_n,
                     seg=seg_n, pool=pool,
                     best=best_n, lstate=lstate_n, nodes=nodes_n,
@@ -2161,6 +2193,7 @@ def make_grow_fn(
                 inter_n = st.inter
 
             return st._replace(
+                side_miss=side_miss,
                 inter=inter_n, paid=paid_n,
                 row_order=row_order, comb=comb_n, scratch=scratch_n,
                 cat_members=cat_members_n,
@@ -2205,6 +2238,7 @@ def make_grow_fn(
             leaf_count=lstate[:, _SC].astype(jnp.float32),
             num_leaves=state.num_leaves,
             cat_members=state.cat_members,
+            side_miss=state.side_miss,
         )
         # reconstruct the per-row leaf assignment ONCE from the partition
         # (row_order/permuted rows + seg tile [0, n)), instead of

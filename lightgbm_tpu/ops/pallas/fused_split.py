@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused single-scan partition + child histograms.
+"""Pallas TPU kernel: fused single-scan partition + one child's histogram.
 
 Per-split the unfused pipeline is TWO pallas_call entries (partition
 scan, smaller-child comb-direct histogram) plus the copyback — ~8-10
@@ -12,48 +12,53 @@ block schedule, same overlapping garbage-tail writes, same copyback
 sub-call, with the per-block packing selected through _scan_kernel's
 ``pack_impl`` hook (permute butterfly routing by default, the one-hot
 matmul under LGBM_TPU_PARTITION=matmul; bit-identical packed layouts
-either way) — and additionally
-accumulates BOTH children's 2-channel (grad, hess) histograms in VMEM
-from the row block already resident for the compaction matmul:
+either way) — and additionally accumulates ONE child's 2-channel
+(grad, hess) histogram in VMEM from the row block already resident for
+the compaction:
 
-  * the block's values are masked per side with the go-left /
-    go-right bits the permute compaction hands over (row-oriented and
-    lane-replicated, from its one MXU transpose); under the matmul
-    compaction, whose bits are lane-oriented, the split column is
-    extracted a second time in ROW orientation ([R, 1] matvec) and the
-    bits recomputed;
-  * the nibble-decomposed one-hot contraction of hist_kernel2.py then
-    accumulates each side into one [2, ngroups, M, N] VMEM block
-    (constant index map -> resident across the dynamic grid).  The
-    one-hot construction (hi_rep / lo_rep / oh_hi) is SHARED between
-    the sides — only the channel expansion and the final [M, N]
-    contraction run twice;
-  * the wrapper extracts the same-feature diagonal blocks once per
-    split (hist_kernel2._diag_extract) and returns BOTH child
-    histograms; the caller selects the (globally) smaller child and
-    derives the sibling by parent-minus-child subtraction exactly as
-    on the unfused path.
+  * ``sel[SEL_SIDE]`` names the child (> 0 the left one, else the
+    right): a traced scalar in SMEM, so one compiled kernel serves
+    both;
+  * the block's values are masked with that child's go-left /
+    go-right bits as the permute compaction hands them over
+    (row-oriented and lane-replicated, from its one MXU transpose);
+    under the matmul compaction, whose bits are lane-oriented, the
+    split column is extracted a second time in ROW orientation ([R, 1]
+    matvec) and the bits recomputed;
+  * hist_kernel2._hist_accumulate — the comb-direct kernel's own
+    nibble one-hot contraction, the one accumulate body there is —
+    adds them into one [ngroups, M, N] VMEM block (constant index map
+    -> resident across the dynamic grid);
+  * the wrapper extracts the same-feature diagonal blocks
+    (hist_kernel2._diag_extract) and returns that child's histogram.
 
-Both sides are accumulated because the smaller child is only known when
-the scan finishes (and, under the mesh learners, only after a psum over
-shards); the unfused path's child-histogram HBM re-read is gone.  That
-trade is NOT free on the v5e: there is no DMA shadow to ride under.  A
-512-row step moves ~1.5 KB a row, 0.94 us at 819 GB/s, and takes 5.9
-us (11.4 ns a row visit at 10.5M rows; 9.7 us before ISSUE 28): the
-scan is bound by what it computes in VMEM, and of the 6.1k VLIW
-bundles of a step ~4.6k are this hook (both sides' one-hot
-contractions over EVERY parent row, where the unfused pair
-histograms only the smaller child's rows: PERF.md, Findings, PR 28).
+Which child: the caller wants the SMALLER one (the sibling is parent
+minus child), and that is known exactly only when the scan finishes
+(under the mesh learners after a psum over shards).  But the finder's
+best-split record holds the left child's count before the scan is
+dispatched (ops/split.py ``derived_counts``: the reference's estimate
+from the hessian sums), so ops/grow.py names the side that record says
+is smaller, and on a split where the estimate named the wrong one - a
+split close to even - it histograms the exactly smaller child with the
+comb-direct kernel over the child's now contiguous rows, as the unfused
+path does at every split.  Accumulating BOTH children and throwing one
+away is not free on the v5e: there is no DMA shadow for it to ride
+under.  A 512-row step moves ~1.5 KB a row, 0.94 us at 819 GB/s, and
+takes 5.0 us (9.8 ns a row visit at 10.5M rows; 5.9 us with both
+sides): the scan is bound by what it computes in VMEM, and a side's
+contraction is ~1.2k of a step's VLIW bundles (PERF.md, Findings, PR 28
+and PR 30).
 
 Layout/contract: identical to partition_kernel2.make_partition_ss, plus
 ``f_pad`` value/bin column conventions from hist_kernel2's comb-direct
 kernel (bins at cols [0, f_pad), (g*w, h*w) at [f_pad, f_pad+2)).
-Trained trees must stay bit-identical to the unfused path: the per-side
-accumulation visits rows in the same ascending block order the
-comb-direct kernel does, masked instead of sliced.  The interpret
+Trained trees must stay bit-identical to the unfused path: the
+accumulation visits the child's rows in the same ascending block order
+the comb-direct kernel does, masked instead of sliced.  The interpret
 builder COMPOSES the reference implementations (3-phase partition
-emulation + comb-direct histogram per side) so off-TPU tests exercise
-the fused orchestration with exactly the unfused arithmetic.
+emulation + comb-direct histogram of the named child's range) so
+off-TPU tests exercise the fused orchestration with exactly the
+unfused arithmetic.
 """
 from __future__ import annotations
 
@@ -64,24 +69,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .hist_kernel2 import _LO_N, _diag_extract, \
-    build_histogram_comb, hist_geometry, onehot_consts
-from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, \
+from .hist_kernel2 import _LO_N, _diag_extract, _hist_accumulate, \
+    build_histogram_comb, hist_geometry
+from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, SEL_SIDE, \
     _go_left, make_partition as _make_partition3
 from .partition_kernel2 import _scan_kernel, copyback_call
 
 _CHANNELS = 2       # (grad, hess) — the 2-channel histogram layout
 
-# VMEM budget for the resident [2, ngroups, M, N] accumulator pair (the
-# scan's four [R, C] buffers, the permute compaction's three scoped
-# ones - routing word + two staging blocks, 768 KB at R = 512 - and the
-# per-block one-hot temporaries ride on top; cap conservatively below
-# apply_find's scoped-VMEM limit)
+# VMEM budget, priced as TWO resident [ngroups, M, N] accumulators
+# although the one-sided hook keeps one: the predicate decides routes,
+# and widening it is not ISSUE 30's (the scan's four [R, C] buffers,
+# the permute compaction's three scoped ones - routing word + two
+# staging blocks, 768 KB at R = 512 - and the per-block one-hot
+# temporaries ride on top; cap conservatively below apply_find's
+# scoped-VMEM limit)
 _HIST_VMEM_CAP = 32 * 1024 * 1024
 
 
 def fused_supported(f_pad: int, b: int) -> bool:
-    """Whether the fused kernel's resident histogram accumulators fit
+    """Whether the fused kernel's resident histogram accumulator fits
     the VMEM budget (grow falls back to the separate partition+hist
     pair above it).  Mirrors hist_kernel2's geometry constraints."""
     b_hi, g, m, nn = hist_geometry(b, _CHANNELS)
@@ -91,46 +98,10 @@ def fused_supported(f_pad: int, b: int) -> bool:
     return 2 * ngroups * m * nn * 4 <= _HIST_VMEM_CAP
 
 
-def _hist_accumulate2(bins_i, v_l, v_r, hist_ref, *, b_hi, g, lo_n,
-                      ngroups):
-    """Dual-side nibble one-hot contraction: bins_i [R, F] i32, v_l/v_r
-    [R, 2] f32 (per-side masked values), accumulated into hist_ref
-    [2, ngroups, M, N].  Same math as hist_kernel2._hist_accumulate with
-    the constant one-hot construction shared between the sides."""
-    c = _CHANNELS
-    e_hi, e_lo, e_v, lane_hi, lane_lo = onehot_consts(b_hi, g, c, lo_n)
-
-    hi = bins_i // lo_n
-    lo = bins_i - hi * lo_n
-
-    # channel expansion per side: [R, N] f32
-    vt_l = jax.lax.dot_general(
-        v_l, e_v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    vt_r = jax.lax.dot_general(
-        v_r, e_v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    for grp in range(ngroups):
-        f0 = grp * g
-        hi_g = hi[:, f0:f0 + g].astype(jnp.float32)     # [R, G]
-        lo_g = lo[:, f0:f0 + g].astype(jnp.float32)
-        hi_rep = jax.lax.dot_general(
-            hi_g, e_hi, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [R, M]
-        lo_rep = jax.lax.dot_general(
-            lo_g, e_lo, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [R, N]
-        oh_hi = (hi_rep == lane_hi).astype(jnp.bfloat16)
-        lo_hit = lo_rep == lane_lo
-        lo_v_l = jnp.where(lo_hit, vt_l, 0.0).astype(jnp.bfloat16)
-        lo_v_r = jnp.where(lo_hit, vt_r, 0.0).astype(jnp.bfloat16)
-        hist_ref[0, grp] += jax.lax.dot_general(
-            oh_hi, lo_v_l, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [M, N]
-        hist_ref[1, grp] += jax.lax.dot_general(
-            oh_hi, lo_v_r, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+def _side_flag(sel_ref, go_left, go_right):
+    """The f32 0/1 row mask of the child ``sel[SEL_SIDE]`` names, from
+    the two sides' masks (same shape, f32)."""
+    return jnp.where(sel_ref[SEL_SIDE] > 0, go_left, go_right)
 
 
 def _fused_scan_kernel_p2(sel_ref, rows_in, scratch_in,
@@ -141,12 +112,12 @@ def _fused_scan_kernel_p2(sel_ref, rows_in, scratch_in,
                           *, R: int, f_pad: int, b_hi: int, g: int,
                           lo_n: int, ngroups: int):
     """pack=2 twin of _fused_scan_kernel: partition_kernel3's
-    _scan_kernel_p2 + per-block dual histogram accumulation through its
-    trace-time hooks.  Each [P, 128] block holds R = 2P logical rows;
-    both lane halves are unpacked in register (static lane slices) and
-    pushed through the shared dual-side contraction, even half first
-    then odd — the same in-block order the pack=2 comb-direct histogram
-    kernel uses."""
+    _scan_kernel_p2 + per-block histogram accumulation of the named
+    child through its trace-time hooks.  Each [P, 128] block holds
+    R = 2P logical rows; both lane halves are unpacked in register
+    (static lane slices) and pushed through the shared contraction,
+    even half first then odd — the same in-block order the pack=2
+    comb-direct histogram kernel uses."""
     from .layout import PACK_W
     from .partition_kernel3 import _scan_kernel_p2
 
@@ -175,10 +146,10 @@ def _fused_scan_kernel_p2(sel_ref, rows_in, scratch_in,
                       .astype(jnp.int32))
             v = (x[:, h0 + f_pad:h0 + f_pad + _CHANNELS]
                  .astype(jnp.float32))
-            _hist_accumulate2(bins_i, v * gl.astype(jnp.float32),
-                              v * gr.astype(jnp.float32), hist_ref,
-                              b_hi=b_hi, g=g, lo_n=lo_n,
-                              ngroups=ngroups)
+            flag = _side_flag(sel_ref, gl.astype(jnp.float32),
+                              gr.astype(jnp.float32))
+            _hist_accumulate(bins_i, v * flag, hist_ref, b_hi=b_hi, g=g,
+                             c=_CHANNELS, lo_n=lo_n, ngroups=ngroups)
 
     _scan_kernel_p2(sel_ref, rows_in, scratch_in,
                     rows_ref, scratch_ref, out_ref,
@@ -194,16 +165,17 @@ def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
                        sem_r, sem_wl, sem_wr,
                        *, R: int, C: int, n: int, f_pad: int, b_hi: int,
                        g: int, lo_n: int, ngroups: int, pack_impl=None):
-    """partition_kernel2._scan_kernel + per-block dual histogram
-    accumulation, injected through the scan's trace-time hooks so the
-    compaction/DMA schedule (and its safety argument) has exactly one
-    home.  The hooks are pure VMEM compute — no DMA/cursor state."""
+    """partition_kernel2._scan_kernel + per-block histogram
+    accumulation of the named child, injected through the scan's
+    trace-time hooks so the compaction/DMA schedule (and its safety
+    argument) has exactly one home.  The hooks are pure VMEM compute —
+    no DMA/cursor state."""
 
     def _hist_init():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
     def _hist_block(x, blk, cnt, side):
-        # ---- dual histogram accumulation (the fusion) ----
+        # ---- one child's histogram accumulation (the fusion) ----
         # Mosaic has no direct bf16 -> i32 cast; hop through f32
         bins_i = x[:, :f_pad].astype(jnp.float32).astype(jnp.int32)
         v = x[:, f_pad:f_pad + _CHANNELS].astype(jnp.float32)
@@ -229,10 +201,9 @@ def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
             gl2 = _go_left(col2, sel_ref) & valid2
             glf = gl2.astype(jnp.float32)
             grf = jnp.logical_xor(gl2, valid2).astype(jnp.float32)
-        v_l = v * glf
-        v_r = v * grf
-        _hist_accumulate2(bins_i, v_l, v_r, hist_ref, b_hi=b_hi,
-                          g=g, lo_n=lo_n, ngroups=ngroups)
+        _hist_accumulate(bins_i, v * _side_flag(sel_ref, glf, grf),
+                         hist_ref, b_hi=b_hi, g=g, c=_CHANNELS, lo_n=lo_n,
+                         ngroups=ngroups)
 
     _scan_kernel(sel_ref, rows_in, scratch_in,
                  rows_ref, scratch_ref, out_ref,
@@ -250,36 +221,37 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                      interpret_kernel: bool = False, pack: int = 1,
                      fused_kernel_interpret: bool = False):
     """Build ``fused(sel, rows, scratch[, grid_blocks]) -> (rows, scratch,
-    nleft, h_left, h_right)`` — the single-scan partition contract of
-    partition_kernel2.make_partition_ss extended with both children's
-    [f_pad, padded_bins, 2] f32 histograms, accumulated during the scan.
+    nleft, h_side)`` — the single-scan partition contract of
+    partition_kernel2.make_partition_ss extended with ONE child's
+    [f_pad, padded_bins, 2] f32 histogram, accumulated during the scan:
+    the left child's where ``sel[SEL_SIDE] > 0``, else the right's.
 
     ``scan`` selects the per-block compaction plugged into the shared
     schedule: ``"permute"`` (partition_kernel3's butterfly routing — the
     LGBM_TPU_PARTITION default) or ``"matmul"`` (the one-hot
     contraction).  Both produce bit-identical packed layouts, so the
-    dual-histogram hooks and everything downstream are scheme-blind.
+    histogram hook and everything downstream are scheme-blind.
 
     ``pack=2`` runs the two-logical-rows-per-line scan
     (partition_kernel3._scan_kernel_p2; ``n``/``size``/``sel``/
     ``nleft`` stay LOGICAL, rows/scratch are [n // 2, 128] packed) with
-    the dual-histogram hooks unpacking both lane halves in register —
+    the histogram hook unpacking both lane halves in register —
     half the partition DMA bytes per logical row.  pack=2 routing is
     permutation-only; the ``scan`` knob is accepted and ignored there
     (both pack=1 schemes produce the identical layout the pack=2
     kernel reproduces in the logical domain).
 
     The interpret path COMPOSES the reference pieces (partition
-    emulation, then the comb-direct histogram of each contiguous child
-    range) so the fused orchestration can be tested off-TPU with
-    arithmetic identical to the unfused path's; with
+    emulation, then the comb-direct histogram of the named child's
+    contiguous range) so the fused orchestration can be tested off-TPU
+    with arithmetic identical to the unfused path's; with
     ``interpret_kernel=True`` the partition piece is the REAL scan +
     copyback run through the Pallas interpreter (compiled row order),
     letting CPU tests pin the cross-scheme identity at kernel depth.
     ``fused_kernel_interpret=True`` instead builds the REAL fused
-    scan+dual-histogram kernel and runs it through the Pallas
+    scan+histogram kernel and runs it through the Pallas
     interpreter (static grids only) — the off-chip pin for the kernel
-    body itself, hooks included."""
+    body itself, hook included."""
     from .layout import check_lane_width, comb_planes, comb_shape
     check_lane_width(C, dtype)
     if scan not in ("matmul", "permute"):
@@ -342,10 +314,11 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
 
         def _fused_i(sel, rows, scratch, *gb):
             rows1, scratch1, nleft = part(sel, rows, scratch, *gb)
-            cnt = sel[SEL_CNT]
-            h_l = _hist_side(rows1, sel[SEL_S0], nleft)
-            h_r = _hist_side(rows1, sel[SEL_S0] + nleft, cnt - nleft)
-            return rows1, scratch1, nleft, h_l, h_r
+            left = sel[SEL_SIDE] > 0
+            h_side = _hist_side(
+                rows1, sel[SEL_S0] + jnp.where(left, 0, nleft),
+                jnp.where(left, nleft, sel[SEL_CNT] - nleft))
+            return rows1, scratch1, nleft, h_side
 
         if dynamic:
             def fused(sel, rows, scratch, grid_blocks):
@@ -366,7 +339,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                              ngroups=ngroups, pack_impl=_pack)
 
     def _call(sel, rows, scratch, grid_blocks):
-        rows1, scratch1, res, hist2 = pl.pallas_call(
+        rows1, scratch1, res, hist = pl.pallas_call(
             kern,
             name="lgbm_split_scan",
             grid=(grid_blocks,),
@@ -376,13 +349,13 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
             out_specs=[pl.BlockSpec(memory_space=_HBM),
                        pl.BlockSpec(memory_space=_HBM),
                        pl.BlockSpec(memory_space=pltpu.SMEM),
-                       pl.BlockSpec((2, ngroups, m, nn),
-                                    lambda i: (0, 0, 0, 0),
+                       pl.BlockSpec((ngroups, m, nn),
+                                    lambda i: (0, 0, 0),
                                     memory_space=pltpu.VMEM)],
             out_shape=[jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
                        jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
                        jax.ShapeDtypeStruct((2,), jnp.int32),
-                       jax.ShapeDtypeStruct((2, ngroups, m, nn),
+                       jax.ShapeDtypeStruct((ngroups, m, nn),
                                             jnp.float32)],
             scratch_shapes=[pltpu.VMEM((R, C), dtype),
                             pltpu.VMEM((R, C), dtype),
@@ -399,11 +372,8 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
         rows2 = copyback_call(sel, rows1, scratch1, nleft, mm, R=R,
                               cb_block=cb_block, n=n, C=C, dtype=dtype,
                               interpret=fused_kernel_interpret)
-        h_l = _diag_extract(hist2[0], ngroups, g, b_hi, _CHANNELS, _LO_N,
-                            f_pad, b)
-        h_r = _diag_extract(hist2[1], ngroups, g, b_hi, _CHANNELS, _LO_N,
-                            f_pad, b)
-        return rows2, scratch1, nleft, h_l, h_r
+        return rows2, scratch1, nleft, _diag_extract(
+            hist, ngroups, g, b_hi, _CHANNELS, _LO_N, f_pad, b)
 
     if dynamic:
         def fused(sel, rows, scratch, grid_blocks):
@@ -421,7 +391,7 @@ def _make_fused_p2(n: int, *, R: int, size: int, dtype, dynamic: bool,
                    interpret: bool = False):
     """Compiled pack=2 fused split: the pack=2 scan's pallas_call
     (scratch/carry/cursor shapes from make_partition_p2) extended with
-    the resident dual-histogram accumulator output."""
+    the resident histogram accumulator output."""
     from .layout import LANE, PACK_W
     from .partition_kernel3 import copyback_call_p2
     if n % 2 or R % 2:
@@ -440,7 +410,7 @@ def _make_fused_p2(n: int, *, R: int, size: int, dtype, dynamic: bool,
                              ngroups=ngroups)
 
     def _call(sel, rows, scratch, grid_blocks):
-        rows1, scratch1, res, hist2 = pl.pallas_call(
+        rows1, scratch1, res, hist = pl.pallas_call(
             kern,
             name="lgbm_split_scan",
             grid=(grid_blocks,),
@@ -450,13 +420,13 @@ def _make_fused_p2(n: int, *, R: int, size: int, dtype, dynamic: bool,
             out_specs=[pl.BlockSpec(memory_space=_HBM),
                        pl.BlockSpec(memory_space=_HBM),
                        pl.BlockSpec(memory_space=pltpu.SMEM),
-                       pl.BlockSpec((2, ngroups, m, nn),
-                                    lambda i: (0, 0, 0, 0),
+                       pl.BlockSpec((ngroups, m, nn),
+                                    lambda i: (0, 0, 0),
                                     memory_space=pltpu.VMEM)],
             out_shape=[jax.ShapeDtypeStruct((np_phys, LANE), dtype),
                        jax.ShapeDtypeStruct((np_phys, LANE), dtype),
                        jax.ShapeDtypeStruct((2,), jnp.int32),
-                       jax.ShapeDtypeStruct((2, ngroups, m, nn),
+                       jax.ShapeDtypeStruct((ngroups, m, nn),
                                             jnp.float32)],
             scratch_shapes=[pltpu.VMEM((P, LANE), dtype),
                             pltpu.VMEM((P, LANE), dtype),
@@ -477,11 +447,8 @@ def _make_fused_p2(n: int, *, R: int, size: int, dtype, dynamic: bool,
         rows2 = copyback_call_p2(sel, rows1, scratch1, nleft, mm, R=R,
                                  cb_block=cb_block, n=n, dtype=dtype,
                                  interpret=interpret)
-        h_l = _diag_extract(hist2[0], ngroups, g, b_hi, _CHANNELS,
-                            _LO_N, f_pad, b)
-        h_r = _diag_extract(hist2[1], ngroups, g, b_hi, _CHANNELS,
-                            _LO_N, f_pad, b)
-        return rows2, scratch1, nleft, h_l, h_r
+        return rows2, scratch1, nleft, _diag_extract(
+            hist, ngroups, g, b_hi, _CHANNELS, _LO_N, f_pad, b)
 
     if dynamic:
         def fused(sel, rows, scratch, grid_blocks):
@@ -498,7 +465,7 @@ from ...analysis.registry import partition_args, register_kernel, sds
 
 
 @register_kernel("fused_split", kind="fused",
-                 note="fused partition+dual-histogram scan "
+                 note="fused partition+child-histogram scan "
                       "(LGBM_TPU_FUSED default path)")
 def _analysis_fused():
     n, C, f, b = 7168, 128, 16, 32
@@ -518,7 +485,7 @@ def _analysis_fused_cat():
 
 
 @register_kernel("fused_split_p2", kind="fused", pack=2,
-                 note="pack=2 fused scan + dual-histogram hooks")
+                 note="pack=2 fused scan + child-histogram hook")
 def _analysis_fused_p2():
     import jax.numpy as jnp
     n, f, b = 7168, 16, 32      # n LOGICAL rows over [n//2, 128] lines

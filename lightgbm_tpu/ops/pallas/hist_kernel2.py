@@ -44,8 +44,8 @@ def hist_geometry(b: int, channels: int = 2):
     """(b_hi, g, m, nn) of the [ngroups, M, N] nibble-one-hot
     accumulator layout for padded_bins ``b`` — the single source of
     truth for every kernel that embeds this accumulation (hist_kernel2
-    itself, fused_split's dual-child variant, stream_grad's fused
-    refresh+root pass)."""
+    itself, fused_split's scan hook, stream_grad's fused refresh+root
+    pass)."""
     b_hi = max(b // _LO_N, 1)
     g = feature_group_size(b)
     return b_hi, g, g * b_hi, g * _LO_N * channels
@@ -57,8 +57,9 @@ def onehot_consts(b_hi, g, c, lo_n):
     from iotas so kernels capture no array constants (pallas
     requirement); Mosaic hoists them out of the grid loop.  Single
     source of truth: the fused/unfused bit-identity contract depends on
-    every kernel embedding this accumulation (here and in
-    fused_split._hist_accumulate2) using byte-identical constants."""
+    every kernel embedding this accumulation (_hist_accumulate's
+    callers here, in fused_split and in stream_grad) using
+    byte-identical constants."""
     m = g * b_hi
     n_cols = g * lo_n * c
     col_m = jax.lax.broadcasted_iota(jnp.int32, (g, m), 1)

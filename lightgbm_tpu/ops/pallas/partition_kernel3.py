@@ -533,7 +533,7 @@ def _scan_kernel_p2(sel_ref, rows_in, scratch_in,
 
     ``init_cb()`` / ``block_cb(x, blk, cnt, par0)`` mirror
     partition_kernel2._scan_kernel's trace-time extension hooks
-    (fused_split's pack=2 dual-histogram accumulation): init_cb runs in
+    (fused_split's pack=2 histogram accumulation): init_cb runs in
     the blk == 0 init, block_cb sees each live block's [P, 128] packed
     lines right after the read wait.  The extra ``par0`` operand is the
     segment-start parity the hook needs to place logical rows.  Hooks

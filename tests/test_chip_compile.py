@@ -119,6 +119,22 @@ def _hist_comb_root(geom=HIGGS):
         sds((), jnp.int32),) * 3
 
 
+def _hist_comb_dyn(geom=HIGGS):
+    """The dynamic-grid comb histogram: the unfused route's child pass,
+    and the fused route's re-histogram of a split whose scan was told
+    the other child (ops/grow.py; ISSUE 30)."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.pallas.hist_kernel2 import \
+        build_histogram_comb_dyn
+    from lightgbm_tpu.ops.pallas.layout import comb_planes, comb_shape
+    _, n_alloc, c, f_pad = geom
+    fn = functools.partial(build_histogram_comb_dyn, f_pad=f_pad,
+                           padded_bins=BINS, planes=comb_planes(c))
+    return fn, (sds(comb_shape(n_alloc, c), jnp.float32),) + (
+        sds((), jnp.int32),) * 3
+
+
 def _apply_find_pool(f: int):
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.registry import sds
@@ -169,6 +185,9 @@ COMPILES = {
         functools.partial(_partition_perm, MSLTR), True),
     "hist_comb_root_msltr": (
         functools.partial(_hist_comb_root, MSLTR), True),
+    "hist_comb_dyn": (_hist_comb_dyn, True),
+    "hist_comb_dyn_msltr": (functools.partial(_hist_comb_dyn, MSLTR),
+                            True),
 }
 
 
@@ -230,6 +249,23 @@ def test_kernel_names_reach_the_compiled_program(kernel, compiled_text):
     every = re.findall(r"%([A-Za-z_][\w-]*?)(?:\.\d+)? = [^\n]*"
                        r"custom_call_target=\"tpu_custom_call\"", text)
     assert set(every) <= set(KERNEL_NAMES), every
+
+
+@pytest.mark.parametrize("name,groups", [
+    ("fused_split_permute", 4), ("fused_split_matmul", 4),
+    ("fused_split_permute_msltr", 18), ("fused_split_matmul_msltr", 18)])
+def test_the_fused_scan_accumulates_one_child(name, groups, compiled_text):
+    """ISSUE 30: the scan's resident accumulator is ONE [groups, M, N]
+    block - the child ``sel[SEL_SIDE]`` names - at the Higgs width (32
+    columns, 4 groups of 8) and at 144 columns over two planes (18
+    groups): 0.5 MB and 2.4 MB of VMEM where the two-sided hook held
+    twice that."""
+    import re
+    scans = re.findall(r"%lgbm_split_scan(?:\.\d+)? = (\([^\n]*?\)) "
+                       r"custom-call", compiled_text(name))
+    assert len(scans) == 1, scans
+    assert f"f32[{groups},128,256]" in scans[0]
+    assert f"f32[2,{groups},128,256]" not in scans[0]
 
 
 def test_the_finder_at_the_msltr_width_is_the_xla_tail():

@@ -2,9 +2,10 @@
 
 The compiled fused kernel (ops/pallas/fused_split.py) only lowers on
 TPU; off-TPU the fused path runs its interpret/XLA reference composition
-(both children histogrammed from their contiguous ranges, smaller one
-selected, sibling by subtraction — the same orchestration the kernel
-implements, built from the exact arithmetic the unfused path uses).
+(the smaller child histogrammed from its contiguous range, sibling by
+subtraction — what the compiled route hands on whether its scan named
+the smaller child or it had to histogram it again, built from the exact
+arithmetic the unfused path uses).
 These tests pin the contract the compiled path must also satisfy (and
 tools/tpu_smoke.py re-checks on the real chip): trained trees are
 BIT-identical with LGBM_TPU_FUSED on and off.
@@ -31,6 +32,15 @@ from conftest import restore_env_knobs as _restore_env
 from conftest import save_env_knobs as _save_env
 
 
+def _tree_bytes(models):
+    """What 'the same trees' compares: structure, thresholds and the
+    leaf values' BYTES."""
+    return [(int(t.num_leaves),
+             t.split_feature[:int(t.num_leaves) - 1].tolist(),
+             t.threshold_bin[:int(t.num_leaves) - 1].tolist(),
+             np.asarray(t.leaf_value).tobytes()) for t in models]
+
+
 def _fresh_train(fused, n=3000, f=6, rounds=4, objective="binary",
                  part_interp="", partition="", **params):
     saved = _save_env()
@@ -54,12 +64,7 @@ def _fresh_train(fused, n=3000, f=6, rounds=4, objective="binary",
         p.update(params)
         ds = lgb.Dataset(x, label=y)
         bst = lgb.train(p, ds, num_boost_round=rounds)
-        trees = [(int(t.num_leaves),
-                  t.split_feature[:int(t.num_leaves) - 1].tolist(),
-                  t.threshold_bin[:int(t.num_leaves) - 1].tolist(),
-                  np.asarray(t.leaf_value).tobytes())
-                 for t in bst._models]
-        return np.asarray(bst.predict(x)), trees
+        return np.asarray(bst.predict(x)), _tree_bytes(bst._models)
     finally:
         _restore_env(saved)
         _purge()
@@ -129,14 +134,104 @@ def test_fused_engaged_and_flagged():
             _purge()
 
 
-def test_fused_kernel_contract_interpret():
+# ---------------------------------------------------------------------
+# ISSUE 30: the scan histograms ONE child, the one the finder's record
+# (a hessian-derived left count) calls smaller; where the exact counts
+# say otherwise the smaller child is histogrammed again from the comb.
+# The grow program counts those splits and their rows, on every route.
+# ---------------------------------------------------------------------
+def _train_counted(fused, *, weighted, part_interp="", mesh=False,
+                   n=3000, rounds=3):
+    """Train l2 under LGBM_TPU_PHYS=interpret with a live tracer;
+    returns (trees, counter totals).  ``weighted`` puts the hessian
+    mass on the FEWER rows: 30% of the rows carry weight 20, the rest
+    0.05, and the label steps where the weight does, so the record's
+    left count is far off the rows' at the first splits."""
+    saved = _save_env()
+    os.environ["LGBM_TPU_PHYS"] = "interpret"
+    os.environ["LGBM_TPU_FUSED"] = fused
+    if part_interp:
+        os.environ["LGBM_TPU_PART_INTERP"] = part_interp
+    try:
+        _purge()
+        import lightgbm_tpu as lgb
+        from lightgbm_tpu.obs import counters, tracer
+        rng = np.random.default_rng(5)
+        x = rng.normal(size=(n, 5)).astype(np.float32)
+        heavy = x[:, 0] > 0.5
+        y = (2.0 * heavy + 0.3 * x[:, 1]
+             + 0.1 * rng.normal(size=n)).astype(np.float32)
+        w = np.where(heavy, 20.0, 0.05).astype(np.float32)
+        ds = lgb.Dataset(x, label=y, weight=w if weighted else None)
+        p = {"objective": "regression", "num_leaves": 15,
+             "min_data_in_leaf": 5, "verbosity": -1}
+        if mesh:
+            p.update({"tree_learner": "data", "max_bin": 31})
+        tracer.enable(None)
+        try:
+            bst = lgb.train(p, ds, num_boost_round=rounds)
+            bst._inner._flush_pending()
+            tot = counters.totals()
+            args = [e["args"] for e in tracer.events
+                    if e["name"] == "Tree::grow"]
+        finally:
+            tracer.disable()
+        return _tree_bytes(bst._inner.models), tot, args
+    finally:
+        _restore_env(saved)
+        _purge()
+
+
+@pytest.mark.parametrize("variant", ["phys_interpret", "part_kernel",
+                                     "mesh8"])
+def test_side_miss_rehistograms_to_the_unfused_trees(variant):
+    """Hessian mass on the side with fewer rows: the record names the
+    wrong child at some splits (``side_miss_splits > 0``), those
+    children are histogrammed again, and the trees are byte-identical
+    to LGBM_TPU_FUSED=0 - on one device, through the real partition
+    kernel bodies, and over the 8-shard mesh."""
+    kw = {"phys_interpret": {}, "part_kernel": {"part_interp": "kernel",
+                                                "rounds": 2},
+          "mesh8": {"mesh": True, "rounds": 2}}[variant]
+    t0, tot0, _ = _train_counted("0", weighted=True, **kw)
+    t1, tot1, args = _train_counted("1", weighted=True, **kw)
+    assert t0 == t1
+    assert tot1["side_miss_splits"] > 0
+    # a missed split re-reads the SMALLER child: at most half its parent
+    assert 0 < tot1["rows_rehistogrammed"] <= tot1["rows_partitioned"] / 2
+    assert tot1["side_miss_splits"] <= tot1["splits"]
+    # the unfused route predicts nothing
+    assert tot0["side_miss_splits"] == tot0["rows_rehistogrammed"] == 0
+    assert tot0["splits"] == tot1["splits"]
+    # both ride the Tree::grow span, which is where the benchmark's
+    # scan_side_miss reads them
+    assert sum(a["side_miss_splits"] for a in args) \
+        == tot1["side_miss_splits"]
+    assert sum(a["rows_rehistogrammed"] for a in args) \
+        == tot1["rows_rehistogrammed"]
+
+
+def test_constant_hessians_never_miss():
+    """Unweighted l2: every hessian is 1, the record's left count IS
+    the row count, so the scan is always told the smaller child."""
+    t0, _, _ = _train_counted("0", weighted=False)
+    t1, tot, _ = _train_counted("1", weighted=False)
+    assert t0 == t1 and tot["splits"] > 0
+    assert tot["side_miss_splits"] == 0
+    assert tot["rows_rehistogrammed"] == 0
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_fused_kernel_contract_interpret(side):
     """Kernel-level contract via the interpret builder: partition result
-    matches make_partition_ss and the per-side histograms equal the
-    comb-direct histograms of the two contiguous child ranges."""
+    matches make_partition_ss and the histogram of the child
+    ``sel[SEL_SIDE]`` names equals the comb-direct histogram of that
+    child's contiguous range."""
     import jax.numpy as jnp
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
     from lightgbm_tpu.ops.pallas.hist_kernel2 import build_histogram_comb
-    from lightgbm_tpu.ops.pallas.partition_kernel import SEL_S0, SEL_CNT
+    from lightgbm_tpu.ops.pallas.partition_kernel import (SEL_CNT, SEL_S0,
+                                                          SEL_SIDE)
     from lightgbm_tpu.ops.pallas.partition_kernel2 import make_partition_ss
 
     rng = np.random.default_rng(11)
@@ -151,35 +246,33 @@ def test_fused_kernel_contract_interpret():
     sel = np.zeros((8,), np.int32)
     sel[SEL_S0], sel[SEL_CNT], sel[2], sel[3] = s0, cnt, 3, b // 3
     sel[6] = -1                                    # no NaN bin
+    sel[SEL_SIDE] = side == "left"
     sel_j = jnp.asarray(sel)
     rows_j = jnp.asarray(rows)
     scr_j = jnp.zeros_like(rows_j)
 
     fused = make_fused_split(n, C, f_pad=f_pad, padded_bins=b, R=R,
                              size=size, interpret=True)
-    rows_f, _, nleft_f, h_l, h_r = fused(sel_j, rows_j, scr_j)
+    rows_f, _, nleft_f, h_side = fused(sel_j, rows_j, scr_j)
 
     part = make_partition_ss(n, C, R=R, size=size, interpret=True)
     rows_p, _, nleft_p = part(sel_j, rows_j, jnp.zeros_like(rows_j))
-    assert int(nleft_f) == int(nleft_p)
+    assert 0 < int(nleft_f) == int(nleft_p) < cnt
     np.testing.assert_array_equal(np.asarray(rows_f), np.asarray(rows_p))
 
-    h_l_ref = build_histogram_comb(
-        rows_f, jnp.int32(s0), jnp.int32(0), nleft_f, f_pad=f_pad,
-        size=size, padded_bins=b, interpret=True)
-    h_r_ref = build_histogram_comb(
-        rows_f, jnp.int32(s0) + nleft_f, jnp.int32(0),
-        jnp.int32(cnt) - nleft_f, f_pad=f_pad, size=size,
-        padded_bins=b, interpret=True)
-    np.testing.assert_array_equal(np.asarray(h_l), np.asarray(h_l_ref))
-    np.testing.assert_array_equal(np.asarray(h_r), np.asarray(h_r_ref))
-    # the two sides together cover the parent exactly once (bf16
-    # tolerance: the histogram kernel multiplies values at bf16 operand
-    # precision; this numpy reference is exact f32)
-    tot = np.asarray(h_l) + np.asarray(h_r)
-    seg = rows[s0:s0 + cnt]
+    lo, hi = ((s0, s0 + int(nleft_f)) if side == "left"
+              else (s0 + int(nleft_f), s0 + cnt))
+    h_ref = build_histogram_comb(
+        rows_f, jnp.int32(lo), jnp.int32(0), jnp.int32(hi - lo),
+        f_pad=f_pad, size=size, padded_bins=b, interpret=True)
+    np.testing.assert_array_equal(np.asarray(h_side), np.asarray(h_ref))
+    # ... which covers that child's rows exactly once (bf16 tolerance:
+    # the histogram kernel multiplies values at bf16 operand precision;
+    # this numpy reference is exact f32)
+    seg = np.asarray(rows_f)[lo:hi]
     for feat in (0, 3, f_pad - 1):
         ref = np.zeros((b, 2), np.float32)
         for r in seg:
             ref[int(r[feat])] += r[f_pad:f_pad + 2]
-        np.testing.assert_allclose(tot[feat], ref, rtol=4e-2, atol=4e-2)
+        np.testing.assert_allclose(np.asarray(h_side)[feat], ref,
+                                   rtol=4e-2, atol=4e-2)

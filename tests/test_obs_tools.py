@@ -186,13 +186,20 @@ class TestCostModelExactness:
             assert costmodel.hist_build_bytes(
                 cnt, f_pad=f_pad, padded_bins=padded_bins,
                 pack=pack) == contract_bytes
-        # fused = partition + BOTH children's histogram writes, nothing
-        # else (the deleted child re-read is the fusion win)
+        # fused = partition + the scan's ONE histogram write, nothing
+        # else (the deleted child re-read is the fusion win) - and a
+        # whole build of the smaller child where the scan was told the
+        # other side
         nl = 400
-        assert costmodel.fused_split_bytes(
-            cnt, nl, f_pad=f_pad, padded_bins=padded_bins, pack=1) \
-            == costmodel.partition_split_bytes(cnt, nl, pack=1) \
-            + 2 * costmodel.hist_out_bytes(f_pad, padded_bins)
+        part = costmodel.partition_split_bytes(cnt, nl, pack=1)
+        hw = costmodel.hist_out_bytes(f_pad, padded_bins)
+        kw = dict(f_pad=f_pad, padded_bins=padded_bins, pack=1)
+        assert costmodel.fused_split_bytes(cnt, nl, **kw) == part + hw
+        assert costmodel.fused_split_bytes(cnt, nl, rehist_rows=nl, **kw) \
+            == part + hw + costmodel.hist_build_bytes(nl, **kw)
+        # ... which is the unfused pair's traffic plus the scan's write
+        assert costmodel.fused_split_bytes(cnt, nl, rehist_rows=nl, **kw) \
+            == costmodel.unfused_split_bytes(cnt, nl, **kw) + hw
 
     def test_cat_bitset_sel_bytes_match_kernel_contract(self):
         """ISSUE 16: the split descriptor's categorical bitset
@@ -280,14 +287,24 @@ class TestCostModelExactness:
             (m["bytes_lo"] + m["bytes_hi"]) / 2)
         # unfused vs fused, mirroring the per-split contracts: the
         # smaller-child re-read comes back (rows_hist 40k vs the 20k
-        # root passes) and one histogram write per split replaces two
+        # root passes); one histogram write per split either way
         unfused = dict(rec, knobs={"comb_pack": 2,
                                    "partition": "permute",
                                    "fused": False})
         mu = costmodel.phase_model(unfused)
         hw = costmodel.hist_out_bytes(32, 256)
         assert mu["Tree::grow"]["bytes"] - model["Tree::grow"]["bytes"] \
-            == (40_000 - 20_000) * lrb - 10 * hw
+            == (40_000 - 20_000) * lrb
+        # ... less what the fused route read and wrote again for the
+        # splits whose record named the larger child
+        missed = dict(rec, counters=dict(rec["counters"],
+                                         side_miss_splits=2,
+                                         rows_rehistogrammed=3_000))
+        assert costmodel.phase_model(missed)["Tree::grow"]["bytes"] \
+            - model["Tree::grow"]["bytes"] == 3_000 * lrb + 2 * hw
+        km = costmodel.kernel_model(missed)
+        assert km["fused_split"]["bytes_lo"] == 2 * 50_000 * lrb + 10 * hw
+        assert km["hist_build"]["bytes"] == 3_000 * lrb + 2 * hw
         rows = costmodel.roofline_table(rec, peak_bw_gbps=819,
                                         peak_tflops=197)
         grow = next(r for r in rows if r["phase"] == "Tree::grow")
@@ -622,7 +639,8 @@ class TestLifecycle:
         def hammer():
             for _ in range(per_thread):
                 obs.events.record("e")
-                obs.counters.record(np.asarray([1.0, 2.0, 3.0, 4.0]))
+                obs.counters.record(
+                    np.asarray([1.0, 2.0, 3.0, 4.0, 0.0, 0.0]))
 
         ts = [threading.Thread(target=hammer) for _ in range(n_threads)]
         for t in ts:
@@ -863,10 +881,12 @@ class TestKernelModel:
         lrb = costmodel.logical_row_bytes(pack=2)
         hw = costmodel.hist_out_bytes(32, 256)
         fs = model["fused_split"]
-        assert fs["bytes_lo"] == 2 * 200_000 * lrb + 2 * 30 * hw
-        assert fs["bytes_hi"] == 4 * 200_000 * lrb + 2 * 30 * hw
+        # the scan writes ONE child's histogram a split (ISSUE 30) ...
+        assert fs["bytes_lo"] == 2 * 200_000 * lrb + 30 * hw
+        assert fs["bytes_hi"] == 4 * 200_000 * lrb + 30 * hw
         assert fs["bytes"] == pytest.approx(
             (fs["bytes_lo"] + fs["bytes_hi"]) / 2)
+        # ... and no split of this record named the larger child;
         # fused root carry: root histograms ride the stream refresh
         assert model["hist_build"]["bytes"] == 0
         assert model["stream_refresh"]["bytes"] == \

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 
 from lightgbm_tpu.ops.pallas.layout import LANE, check_lane_width, \
     comb_layout
-from lightgbm_tpu.ops.pallas.partition_kernel import SEL_S0, SEL_CNT
+from lightgbm_tpu.ops.pallas.partition_kernel import SEL_S0, SEL_CNT, \
+    SEL_SIDE
 from lightgbm_tpu.ops.pallas.partition_kernel2 import make_partition_ss
 from lightgbm_tpu.ops.pallas.partition_kernel3 import make_partition_p2, \
     make_partition_perm
@@ -47,11 +48,16 @@ def _rows(n=N, c=C, seed=0):
     return rows                            # bit-exactly (no MXU pass)
 
 
-def _sel(s0, cnt, feat, sbin):
+def _sel(s0, cnt, feat, sbin, side="right"):
+    """``side``: the child the fused scan's hook histograms."""
     sel = np.zeros((8,), np.int32)
     sel[SEL_S0], sel[SEL_CNT], sel[2], sel[3] = s0, cnt, feat, sbin
     sel[6] = -1
+    sel[SEL_SIDE] = side == "left"
     return jnp.asarray(sel)
+
+
+SIDES = ["left", "right"]
 
 
 @pytest.mark.parametrize("cfg", CONFIGS)
@@ -169,25 +175,26 @@ def test_pack2_kernel_contract(cfg):
     np.testing.assert_array_equal(out_e[s0:s0 + nl], seg[gl])
 
 
-def test_fused_scan_selection_bitwise():
+@pytest.mark.parametrize("side", SIDES)
+def test_fused_scan_selection_bitwise(side):
     """make_fused_split(scan=permute) partitions bit-identically to
-    scan=matmul AND to the standalone kernels, with equal dual
-    histograms (kernel-interpret composition)."""
+    scan=matmul AND to the standalone kernels, with equal histograms of
+    the named child (kernel-interpret composition)."""
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
     rows = _rows()
     rj = jnp.asarray(rows)
-    sel = _sel(64, 900, 3, 20)
+    sel = _sel(64, 900, 3, 20, side)
     outs = {}
     for scan in ("permute", "matmul"):
         fused = make_fused_split(N, C, f_pad=32, padded_bins=64, R=R,
                                  size=SIZE, interpret=True, scan=scan,
                                  interpret_kernel=True)
         outs[scan] = fused(sel, rj, jnp.zeros_like(rj))
-    # rows / nleft / both histograms must match bitwise; scratch (index
+    # rows / nleft / the histogram must match bitwise; scratch (index
     # 1) is contractually don't-care between calls and its GARBAGE
     # regions differ by scheme (the matmul packs zeros into unoccupied
     # slots, the permute leaves stale copies)
-    for i in (0, 2, 3, 4):
+    for i in (0, 2, 3):
         np.testing.assert_array_equal(np.asarray(outs["permute"][i]),
                                       np.asarray(outs["matmul"][i]))
     pm = make_partition_perm(N, C, R=R, size=SIZE, interpret=True,
@@ -226,12 +233,14 @@ def test_pack2_comb_histogram_kernel_bitwise():
         np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
 
 
-def test_pack2_fused_kernel_contract():
-    """The REAL pack=2 fused scan+dual-histogram kernel (Pallas
+@pytest.mark.parametrize("side", SIDES)
+def test_pack2_fused_kernel_contract(side):
+    """The REAL pack=2 fused scan+histogram kernel (Pallas
     interpreter) partitions bit-identically to the reference
-    composition (pack=2 partition + per-side comb histogram) and its
-    dual histograms match the composition's to accumulation-grouping
-    tolerance — the off-chip pin for _fused_scan_kernel_p2."""
+    composition (pack=2 partition + the named child's comb histogram)
+    and its histogram matches the composition's to
+    accumulation-grouping tolerance — the off-chip pin for
+    _fused_scan_kernel_p2."""
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
     r2, size2, f_pad = 64, 512, 16
     n2 = size2 + 4 * r2 + 256
@@ -250,16 +259,18 @@ def test_pack2_fused_kernel_contract():
                             fused_kernel_interpret=True, cb_block=64)
     for cfg in [(64, 400, 3, 15), (65, 401, 3, 15), (0, 512, 0, 16),
                 (33, 64, 2, 0), (200, 0, 1, 9), (17, 511, 7, 30)]:
-        sel = _sel(*cfg)
+        sel = _sel(*cfg, side)
         rc = comp(sel, packed, jnp.zeros_like(packed))
         rk = real(sel, packed, jnp.zeros_like(packed))
         np.testing.assert_array_equal(np.asarray(rc[0]),
                                       np.asarray(rk[0]))
         assert int(rc[2]) == int(rk[2]), cfg
-        for i in (3, 4):
-            np.testing.assert_allclose(
-                np.asarray(rc[i]), np.asarray(rk[i]), rtol=0,
-                atol=1e-4, err_msg=str((cfg, i)))
+        np.testing.assert_allclose(
+            np.asarray(rc[3]), np.asarray(rk[3]), rtol=0,
+            atol=1e-4, err_msg=str(cfg))
+        # an empty child's histogram is empty (cfgs with cnt == 0)
+        n_side = int(rc[2]) if side == "left" else cfg[1] - int(rc[2])
+        assert (np.abs(np.asarray(rk[3])).sum() > 0) == (n_side > 0), cfg
 
 
 # ---------------------------------------------------------------------
@@ -426,15 +437,17 @@ def test_hook_flags_match_recomputation(kind):
     assert (nl, nr) == (int(gl2.sum()), int(gr2.sum()))
 
 
+@pytest.mark.parametrize("side", SIDES)
 @pytest.mark.parametrize("kind", ["numerical", "nan_default_left",
+                                  "nan_default_right", "cat_onehot",
                                   "cat_bitset"])
-def test_fused_kernel_hook_takes_the_compactions_flags(kind):
-    """The REAL pack=1 fused scan + dual-histogram kernel through the
-    Pallas interpreter: under the permute compaction the hook masks
-    with the flags the compaction hands it, under the matmul one it
-    recomputes them - same rows, same nleft and BITWISE the same two
-    histograms; and both agree with the reference composition
-    (partition kernel + per-side comb histogram) to its
+def test_fused_kernel_hook_takes_the_compactions_flags(kind, side):
+    """The REAL pack=1 fused scan + histogram kernel through the Pallas
+    interpreter: under the permute compaction the hook masks with the
+    named child's flags as the compaction hands them over, under the
+    matmul one it recomputes them - same rows, same nleft and BITWISE
+    the same histogram; and both agree with the reference composition
+    (partition kernel + that child's comb histogram) to its
     accumulation-grouping tolerance."""
     import ml_dtypes
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
@@ -447,12 +460,13 @@ def test_fused_kernel_hook_takes_the_compactions_flags(kind):
     rj = jnp.asarray(rows)
     sel = _flag_sel(kind)
     sel[SEL_S0], sel[SEL_CNT] = 64, 900
+    sel[SEL_SIDE] = side == "left"
     sel = jnp.asarray(sel)
     kw = dict(f_pad=f_pad, padded_bins=bins, R=R, size=SIZE)
     real = {scan: make_fused_split(N, C, scan=scan,
                                    fused_kernel_interpret=True, **kw)(
         sel, rj, jnp.zeros_like(rj)) for scan in ("permute", "matmul")}
-    for i in (0, 2, 3, 4):      # rows, nleft, h_left, h_right
+    for i in (0, 2, 3):         # rows, nleft, h_side
         np.testing.assert_array_equal(np.asarray(real["permute"][i]),
                                       np.asarray(real["matmul"][i]))
     comp = make_fused_split(N, C, interpret=True, interpret_kernel=True,
@@ -461,10 +475,9 @@ def test_fused_kernel_hook_takes_the_compactions_flags(kind):
                                   np.asarray(comp[0]))
     nleft = int(real["permute"][2])
     assert 0 < nleft == int(comp[2]) < 900
-    for i in (3, 4):
-        assert np.abs(np.asarray(comp[i])).sum() > 0
-        np.testing.assert_allclose(np.asarray(real["permute"][i]),
-                                   np.asarray(comp[i]), rtol=0, atol=1e-4)
+    assert np.abs(np.asarray(comp[3])).sum() > 0
+    np.testing.assert_allclose(np.asarray(real["permute"][3]),
+                               np.asarray(comp[3]), rtol=0, atol=1e-4)
 
 
 # ---------------------------------------------------------------------
@@ -524,10 +537,11 @@ def test_two_plane_scans_match_the_oracle(cfg):
         np.concatenate([seg[gl], seg[~gl]]))
 
 
-def test_two_plane_fused_kernel_matches_the_composition():
-    """The REAL fused scan + dual histogram at 144 feature columns (the
-    MS LTR layout: bins through lane 143, values at 144-145, both planes
-    live) against partition kernel + per-side comb histogram."""
+@pytest.mark.parametrize("side", SIDES)
+def test_two_plane_fused_kernel_matches_the_composition(side):
+    """The REAL fused scan + histogram at 144 feature columns (the MS
+    LTR layout: bins through lane 143, values at 144-145, both planes
+    live) against partition kernel + the named child's comb histogram."""
     import ml_dtypes
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
     from lightgbm_tpu.ops.pallas.layout import to_planes
@@ -538,12 +552,12 @@ def test_two_plane_fused_kernel_matches_the_composition():
     rows[:, f_pad:f_pad + 2] = rng.normal(size=(N, 2)).astype(
         ml_dtypes.bfloat16).astype(np.float32)
     rj = to_planes(jnp.asarray(rows))
-    sel = _sel(64, 900, 133, 30)
+    sel = _sel(64, 900, 133, 30, side)
     kw = dict(f_pad=f_pad, padded_bins=bins, R=R, size=SIZE)
     real = {scan: make_fused_split(N, C2, scan=scan,
                                    fused_kernel_interpret=True, **kw)(
         sel, rj, jnp.zeros_like(rj)) for scan in ("permute", "matmul")}
-    for i in (0, 2, 3, 4):
+    for i in (0, 2, 3):
         np.testing.assert_array_equal(np.asarray(real["permute"][i]),
                                       np.asarray(real["matmul"][i]))
     comp = make_fused_split(N, C2, interpret=True, interpret_kernel=True,
@@ -552,10 +566,9 @@ def test_two_plane_fused_kernel_matches_the_composition():
                                   np.asarray(comp[0]))
     nleft = int(real["permute"][2])
     assert 0 < nleft == int(comp[2]) < 900
-    for i in (3, 4):
-        assert np.abs(np.asarray(comp[i])[128:]).sum() > 0   # plane 1
-        np.testing.assert_allclose(np.asarray(real["permute"][i]),
-                                   np.asarray(comp[i]), rtol=0, atol=1e-4)
+    assert np.abs(np.asarray(comp[3])[128:]).sum() > 0       # plane 1
+    np.testing.assert_allclose(np.asarray(real["permute"][3]),
+                               np.asarray(comp[3]), rtol=0, atol=1e-4)
 
 
 class TestLaneContract:

@@ -10,8 +10,12 @@ kernel outputs (nleft + histogram sum), barriered by a HOST VALUE PULL
 
   pair   — make_partition_ss + build_histogram_comb_dyn of the smaller
            child: the unfused production path's two pallas_call entries
-  fused  — make_fused_split: one scan, both children's histograms
-           accumulated from the VMEM-resident blocks
+  fused  — what ops/grow.py runs at a split whose record named the
+           smaller child (ISSUE 30): make_fused_split, one scan that
+           histograms that child from the VMEM-resident blocks, and a
+           lax.cond that has nothing to do
+  miss   — the same at a split whose record named the LARGER child:
+           the cond histograms the smaller one from the comb
 
 Env: LS=1024,4096 (leaf-row sweep), REPS=1000 (in-jit splits per
 timing; keep >= 1000 or the ~20-50 ms dispatch floor pollutes the
@@ -49,7 +53,7 @@ def make_leaf(n_alloc: int, L: int, seed: int = 0):
     return jnp.asarray(comb), jnp.zeros((n_alloc, C), jnp.float32)
 
 
-def build(var: str, L: int, R: int, interpret: bool):
+def build(var: str, L: int, R: int, interpret: bool, small_left: bool):
     from lightgbm_tpu.ops.pallas.partition_kernel2 import make_partition_ss
     from lightgbm_tpu.ops.pallas.partition_kernel3 import \
         make_partition_perm
@@ -68,16 +72,31 @@ def build(var: str, L: int, R: int, interpret: bool):
     sel = jnp.asarray([0, L, 3, B // 2, 1, 0, -1, 0], jnp.int32)
     nb = jnp.maximum(-(-jnp.int32(L) // R), 1)
 
-    if var == "fused":
+    def hist_child(comb, nleft):
+        small_left = nleft * 2 <= L
+        return build_histogram_comb_dyn(
+            comb, jnp.where(small_left, 0, nleft), jnp.int32(0),
+            jnp.where(small_left, nleft, L - nleft), f_pad=F_PAD,
+            padded_bins=B, rows_per_block=min(HIST_RPB, L),
+            interpret=interpret)
+
+    if var in ("fused", "miss"):
+        from lightgbm_tpu.ops.pallas.partition_kernel import SEL_SIDE
         fused = make_fused_split(n_alloc, C, f_pad=F_PAD, padded_bins=B,
                                  R=R, size=L if interpret else 0,
                                  dynamic=True, interpret=interpret,
                                  scan=scheme)
 
+        # the record's side: the leaf is re-split on the same column
+        # every time, so the smaller child is known beforehand
+        side = small_left if var == "fused" else not small_left
+        sel = sel.at[SEL_SIDE].set(int(side))
+
         def split(comb, scratch):
-            comb, scratch, nleft, h_l, h_r = fused(sel, comb, scratch, nb)
-            small_left = nleft * 2 <= L
-            h = jnp.where(small_left, h_l, h_r)
+            comb, scratch, nleft, h_side = fused(sel, comb, scratch, nb)
+            h = jax.lax.cond((nleft * 2 <= L) != side,
+                             lambda c, _: hist_child(c, nleft),
+                             lambda _, h_: h_, comb, h_side)
             return comb, scratch, nleft.astype(jnp.float32) + jnp.sum(h)
     else:
         mk = (make_partition_perm if scheme == "permute"
@@ -89,16 +108,10 @@ def build(var: str, L: int, R: int, interpret: bool):
 
         def split(comb, scratch):
             comb, scratch, nleft = part(sel, comb, scratch, nb)
-            small_left = nleft * 2 <= L
-            child_cnt = jnp.where(small_left, nleft, L - nleft)
-            child_start = jnp.where(small_left, 0, nleft)
-            h = build_histogram_comb_dyn(
-                comb, child_start, jnp.int32(0), child_cnt, f_pad=F_PAD,
-                padded_bins=B, rows_per_block=min(HIST_RPB, L),
-                interpret=interpret)
+            h = hist_child(comb, nleft)
             return comb, scratch, nleft.astype(jnp.float32) + jnp.sum(h)
 
-    return split, n_alloc
+    return split
 
 
 def main():
@@ -113,17 +126,20 @@ def main():
 
     for L in sizes:
         base = {}
-        for var in ("pair", "fused"):
-            split, n_alloc = build(var, L, R, interpret)
+        for var in ("pair", "fused", "miss"):
+            n_alloc = L + 2 * R + 2 * HIST_RPB
             comb, scratch = make_leaf(n_alloc, L)
+            n_left = int((np.asarray(comb[:L, 3]) <= B // 2).sum())
+            split = build(var, L, R, interpret, n_left * 2 <= L)
 
             dt, _ = bench_chain(split, comb, scratch, reps=reps)
             base[var] = dt
             print(f"L={L:6d} {var:5s}: {dt*1e6:8.1f} us/split  "
                   f"({dt/L*1e9:6.2f} ns/row)", flush=True)
-        red = 100.0 * (1.0 - base["fused"] / base["pair"])
-        print(f"L={L:6d} fused vs pair: {red:+.1f}% floor reduction",
-              flush=True)
+        for var in ("fused", "miss"):
+            red = 100.0 * (1.0 - base[var] / base["pair"])
+            print(f"L={L:6d} {var} vs pair: {red:+.1f}% floor reduction",
+                  flush=True)
 
 
 if __name__ == "__main__":
