@@ -338,11 +338,8 @@ def run_bench(n_rows: int, num_iters: int, num_leaves: int,
         rows=n_rows, iters=num_iters, leaves=num_leaves,
         # A/B provenance: the knobs that reroute the trained path ride
         # in every record so BENCH_r* artifacts can't be confused
-        # across pack / partition-scheme / fused sweeps.  comb_pack is
-        # the pack the grower ACTUALLY engaged (a too-wide layout
-        # falls back to 1 with a warning), not the env request
+        # across partition-scheme / fused sweeps
         knobs={
-            "comb_pack": int(getattr(booster._inner.grow, "pack", 1)),
             "partition": os.environ.get("LGBM_TPU_PARTITION",
                                         "permute"),
             "fused": os.environ.get("LGBM_TPU_FUSED", "1") != "0",
@@ -386,7 +383,7 @@ def run_bench(n_rows: int, num_iters: int, num_leaves: int,
           for k, v in obs_events.totals().items()
           if v - _ev0.get(k, 0) > 0}
     if ev:
-        # structural events (e.g. hist_scatter psum fallback, comb-pack
+        # structural events (e.g. hist_scatter psum fallback, a routing
         # fallback) recorded by THIS point — a bench that silently took
         # a slow path is visible in its own artifact
         rec["events"] = ev

@@ -2,7 +2,7 @@
 
 Every kernel lever since round 3 shipped with hand-grown runtime
 guards — the 128-lane ``check_lane_width`` contract, the
-tracer-live jaxpr-identity pin, the pack=2 bytes-halved equality —
+tracer-live jaxpr-identity pin, the cost model's byte contracts —
 because a bad BlockSpec or an unpaired DMA wait only surfaces as a
 Mosaic error on the next chip run (the BENCH_r03 64-wide-slice
 regression class).  This package is the compile-time equivalent of the

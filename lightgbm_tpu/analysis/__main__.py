@@ -83,8 +83,7 @@ def main(argv=None) -> int:
         from . import registry
         registry.collect()
         for name, e in sorted(registry.KERNELS.items()):
-            print(f"{name:32s} kind={e.kind:<10s} pack={e.pack} "
-                  f"[{e.module}]")
+            print(f"{name:32s} kind={e.kind:<10s} [{e.module}]")
         for name in sorted(registry.PURITY_PINS):
             print(f"{name:32s} kind=purity-pin")
         return 0

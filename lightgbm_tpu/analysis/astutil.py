@@ -110,9 +110,9 @@ class ModuleAnalysis:
         for child in ast.walk(node):
             if isinstance(child, (ast.FunctionDef,
                                   ast.AsyncFunctionDef)):
-                # simple names COLLIDE across builders (stream_grad
-                # has two ``def kern`` wrappers, pack=1 vs pack=2), so
-                # every def per name is kept and downstream consumers
+                # simple names COLLIDE across builders (``kern`` is
+                # bound in several of them), so every def per name is
+                # kept and downstream consumers
                 # scan all of them
                 self.functions.setdefault(child.name, []).append(child)
 

@@ -58,7 +58,7 @@ def _grow_physical():
     n, f, b = 4096, 16, 32
     gp = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
                       physical_bins=sds((n, f), jnp.uint8))
-    n_phys = gp._n_alloc // gp.pack
+    n_phys = gp._n_alloc
     args = (sds((n_phys, gp._C), jnp.float32),
             sds((n_phys, gp._C), jnp.float32),
             sds((n,), jnp.float32), sds((n,), jnp.float32),
@@ -80,7 +80,7 @@ def _grow_physical_mc():
     n, f, b, k = 4096, 16, 32, 4
     gp = make_grow_fn(_hp(), num_leaves=8, padded_bins=b,
                       physical_bins=sds((n, f), jnp.uint8))
-    n_phys = gp._n_alloc // gp.pack
+    n_phys = gp._n_alloc
     args = (sds((n_phys, gp._C), jnp.float32),
             sds((n_phys, gp._C), jnp.float32),
             sds((k, n), jnp.float32), sds((k, n), jnp.float32),
@@ -134,7 +134,7 @@ def _grow_physical_efb():
                       bundle=bundle,
                       physical_bins=sds((n, f_phys), jnp.uint8))
     assert gp._f_pad == f_log, gp._f_pad   # unbundled width engaged
-    n_phys = gp._n_alloc // gp.pack
+    n_phys = gp._n_alloc
     args = (sds((n_phys, gp._C), jnp.float32),
             sds((n_phys, gp._C), jnp.float32),
             sds((n,), jnp.float32), sds((n,), jnp.float32),
@@ -158,7 +158,7 @@ def _grow_stream():
         _hp(), num_leaves=8, padded_bins=b,
         physical_bins=sds((n, f), jnp.uint8),
         stream={"kind": "binary", "sigmoid": 1.0, "count": n})
-    n_phys = gp._n_alloc // gp.pack
+    n_phys = gp._n_alloc
     args = [sds((n_phys, gp._C), jnp.float32),
             sds((n_phys, gp._C), jnp.float32),
             sds((1,), jnp.float32), sds((1,), jnp.float32),
@@ -191,7 +191,7 @@ def _paged_window_update():
     from ..ops.paged import PageStore
     store = PageStore(n_alloc=4096 + 5120, C=128, rows_per_page=2048)
     fn = store._update_fn()
-    return fn, (sds((store.n_lines, store.C), jnp.float32),
+    return fn, (sds((store.n_alloc, store.C), jnp.float32),
                 sds((store.page_lines, store.C), jnp.float32),
                 sds((), jnp.int32), sds((), jnp.int32))
 
@@ -206,7 +206,7 @@ def _paged_page_extract():
     from ..ops.paged import PageStore
     store = PageStore(n_alloc=4096 + 5120, C=128, rows_per_page=2048)
     fn = store._extract_fn()
-    return fn, (sds((store.n_lines, store.C), jnp.float32),
+    return fn, (sds((store.n_alloc, store.C), jnp.float32),
                 sds((), jnp.int32))
 
 
@@ -230,7 +230,7 @@ def _pin_paged_off():
         physical_bins=sds((n, f), jnp.uint8),
         stream={"kind": "binary", "sigmoid": 1.0, "count": n},
         paged={"rows_per_page": 2048})
-    n_phys = unpaged._n_alloc // unpaged.pack
+    n_phys = unpaged._n_alloc
     args = [sds((n_phys, unpaged._C), jnp.float32),
             sds((n_phys, unpaged._C), jnp.float32),
             sds((1,), jnp.float32), sds((1,), jnp.float32),

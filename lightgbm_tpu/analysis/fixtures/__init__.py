@@ -208,14 +208,14 @@ def _bad_mesh() -> FixtureBundle:
 # cannot see an unjustified 25x loss is blind to ROADMAP item 4)
 # ---------------------------------------------------------------------
 def _bad_route() -> FixtureBundle:
-    key = ("learner=serial;shards=1;be=tpu;efb=0;u8=1;over=0;wide=0;"
+    key = ("learner=serial;shards=1;be=tpu;efb=0;u8=1;over=0;"
            "fdiv=1;dp=0;cegb=0;cat=0;bag=0;lin=0;boost=gbdt;"
            "obj=binary;k=1;forced=0;mono=0;cegbc=0;phys=auto;"
-           "stream=auto;pack=1;part=permute;impl=ss;fused=1;scat=1;"
+           "stream=auto;part=permute;fused=1;scat=1;"
            "ob=0;pg=auto;fixture=bad_route")
-    cell = ("path=row_order;pack=1;scheme=none;fused=0;merge=none;"
-            "paged=0;why=-;pack_why=-;merge_why=-;paged_why=-;"
-            "prog=row_order|pack1|none|fused0|serial|shards1|none|"
+    cell = ("path=row_order;scheme=none;fused=0;merge=none;"
+            "paged=0;why=-;merge_why=-;paged_why=-;"
+            "prog=row_order|none|fused0|serial|shards1|none|"
             "dp0|cegb0|cat0|efb0|u81|paged0")
     return FixtureBundle(routing_cells=[(key, cell)])
 
@@ -230,15 +230,15 @@ def _bad_route() -> FixtureBundle:
 # (ROUTING_EFB_OVERWIDE_UNJUSTIFIED).
 # ---------------------------------------------------------------------
 def _efb_overwide() -> FixtureBundle:
-    key = ("learner=serial;shards=1;be=tpu;efb=1;u8=1;over=0;wide=0;"
+    key = ("learner=serial;shards=1;be=tpu;efb=1;u8=1;over=0;"
            "ew=0;fdiv=1;dp=0;cegb=0;cat=0;bag=0;lin=0;boost=gbdt;"
            "obj=binary;k=1;forced=0;mono=0;cegbc=0;phys=auto;"
-           "stream=auto;pack=1;part=permute;impl=ss;fused=1;scat=1;"
+           "stream=auto;part=permute;fused=1;scat=1;"
            "ob=0;pg=auto;fixture=efb_overwide")
-    cell = ("path=row_order;pack=1;scheme=none;fused=0;merge=none;"
-            "paged=0;why=efb_overwide;pack_why=-;merge_why=-;"
+    cell = ("path=row_order;scheme=none;fused=0;merge=none;"
+            "paged=0;why=efb_overwide;merge_why=-;"
             "paged_why=-;"
-            "prog=row_order|pack1|none|fused0|serial|shards1|none|"
+            "prog=row_order|none|fused0|serial|shards1|none|"
             "dp0|cegb0|cat0|efb1|u81|paged0")
     return FixtureBundle(routing_cells=[(key, cell)])
 
@@ -402,15 +402,15 @@ def _bad_mc_batch() -> FixtureBundle:
 
         return fn, (jax.ShapeDtypeStruct((k, f, b), jnp.float32),)
 
-    key = ("learner=serial;shards=1;be=tpu;efb=0;u8=1;over=0;wide=0;"
+    key = ("learner=serial;shards=1;be=tpu;efb=0;u8=1;over=0;"
            "ew=0;fdiv=1;dp=0;cegb=0;cat=0;bag=0;lin=0;boost=gbdt;"
            "obj=other;k=multi;forced=0;mono=0;cegbc=0;phys=auto;"
-           "stream=auto;pack=1;part=permute;impl=ss;fused=1;scat=1;"
+           "stream=auto;part=permute;fused=1;scat=1;"
            "ob=0;pg=auto;mcb=auto;fixture=bad_mc_batch")
-    cell = ("path=physical;pack=1;scheme=permute;fused=1;merge=none;"
-            "paged=0;mcb=0;why=-;pack_why=-;merge_why=-;paged_why=-;"
+    cell = ("path=physical;scheme=permute;fused=1;merge=none;"
+            "paged=0;mcb=0;why=-;merge_why=-;paged_why=-;"
             "mcb_why=-;"
-            "prog=physical|pack1|permute|fused1|serial|shards1|none|"
+            "prog=physical|permute|fused1|serial|shards1|none|"
             "dp0|cegb0|cat0|efb0|u81|paged0|mcb0")
     return FixtureBundle(
         entries=[_entry("fixture_bad_mc_batch", "hist", builder)],

@@ -16,9 +16,7 @@ Three checks, all off-chip:
   donation that was silently dropped (no shape/dtype-matching output)
   double-allocates the buffer every call — at comb scale that is
   gigabytes of phantom residency.  This subsumes the legacy
-  ``tools/check_hbm_alias.py`` stage-0 probe's static half (the
-  on-device DMA-semantics scenario stays runnable as
-  ``tools/profile_legacy.py hbm_alias``).
+  ``tools/check_hbm_alias.py`` stage-0 probe's static half.
 * **geometry** — training shapes passed via ``--hbm-geometry
   ROWS,F_PAD[,PADDED_BINS[,ROWS_PER_PAGE]]`` are priced with the exact
   footprint
@@ -146,7 +144,7 @@ def entry_residency_bytes(text: str, original_args=(),
 
 def check_geometry(rows: int, f_pad: int, padded_bins: int = 256,
                    rows_per_page: int = 0, *, num_leaves: int = 255,
-                   pack: int = 1, stream: bool = True,
+                   stream: bool = True,
                    n_shards: int = 1) -> List[Finding]:
     """Price one training geometry against the HBM budget; the
     in-process half of ``--hbm-geometry`` (tests and the planner
@@ -159,7 +157,7 @@ def check_geometry(rows: int, f_pad: int, padded_bins: int = 256,
     if rows_per_page:
         plan = costmodel.page_schedule(
             rows=rows, f_pad=f_pad, padded_bins=padded_bins,
-            num_leaves=num_leaves, pack=pack, stream=stream,
+            num_leaves=num_leaves, stream=stream,
             n_shards=n_shards, rows_per_page=rows_per_page)
         if not plan.get("fits"):
             out.append(Finding(
@@ -174,7 +172,7 @@ def check_geometry(rows: int, f_pad: int, padded_bins: int = 256,
         return out
     fp = costmodel.grow_footprint(
         rows=rows, f_pad=f_pad, padded_bins=padded_bins,
-        num_leaves=num_leaves, pack=pack, stream=stream,
+        num_leaves=num_leaves, stream=stream,
         n_shards=n_shards)
     if fp["peak_bytes"] > limit:
         out.append(Finding(

@@ -19,8 +19,7 @@ Two halves, both CPU-only and trace-only:
   program key, not of build order — ``ROUTING_PROGRAM_DIVERGES``);
   flipping a knob the routing model declares irrelevant for a cell
   must not change its digest (``ROUTING_KNOB_LEAKS``, generalizing the
-  PR-7 purity pins — e.g. a pack=2 request on a too-wide layout must
-  compile the EXACT pack=1 program); donations declared on the cell
+  PR-7 purity pins); donations declared on the cell
   must survive in the lowered program (``ROUTING_DONATION_DROPPED``);
   and registered retrace pins — variants that share one shape bucket
   by contract, the ISSUE-2 serving engine's bucketed-batch design —
@@ -399,7 +398,7 @@ def _phys_build(f_pad: int, env: dict = None):
     with _env(env or {}):
         gp = make_grow_fn(hp, num_leaves=8, padded_bins=b,
                           physical_bins=sds((n, f_pad), jnp.uint8))
-    n_phys = gp._n_alloc // gp.pack
+    n_phys = gp._n_alloc
     args = (sds((n_phys, gp._C), jnp.float32),
             sds((n_phys, gp._C), jnp.float32),
             sds((n,), jnp.float32), sds((n,), jnp.float32),
@@ -430,8 +429,8 @@ def _serial_build(env: dict = None):
 
 # knobs to UNSET for every audited build: the audit pins the shipping
 # cells, and an exported sweep knob would silently re-route them
-_CLEAN = {"LGBM_TPU_COMB_PACK": None, "LGBM_TPU_STREAM": None,
-          "LGBM_TPU_PHYS": None, "LGBM_TPU_HIST_SCATTER": None}
+_CLEAN = {"LGBM_TPU_STREAM": None, "LGBM_TPU_PHYS": None,
+          "LGBM_TPU_HIST_SCATTER": None}
 
 
 def _audit_recompile(ctx) -> List[Finding]:
@@ -451,13 +450,13 @@ def _audit_recompile(ctx) -> List[Finding]:
         if d_a != d_b:
             finding(
                 "ROUTING_PROGRAM_DIVERGES",
-                "cell:physical/pack1/permute",
+                "cell:physical/permute",
                 f"two independent builds of the same lattice cell "
                 f"trace to DIFFERENT programs ({d_a[:12]} != "
                 f"{d_b[:12]}): the compile set is not a function of "
                 f"the program key, so every rebuild recompiles")
     except Exception as e:
-        finding("ROUTING_AUDIT_FAILED", "cell:physical/pack1/permute",
+        finding("ROUTING_AUDIT_FAILED", "cell:physical/permute",
                 f"recompile audit build raised: "
                 f"{type(e).__name__}: {e}")
         d_a = None
@@ -467,7 +466,7 @@ def _audit_recompile(ctx) -> List[Finding]:
     # digest must not move (the purity-pin idea generalized to the
     # routing lattice)
     flips = [
-        ("physical/pack1", "LGBM_TPU_HIST_SCATTER", "0",
+        ("physical", "LGBM_TPU_HIST_SCATTER", "0",
          lambda: _phys_build(16, dict(_CLEAN,
                                       LGBM_TPU_HIST_SCATTER="0")),
          lambda: (gp_a, args_a) if d_a is not None
@@ -494,40 +493,15 @@ def _audit_recompile(ctx) -> List[Finding]:
             finding("ROUTING_AUDIT_FAILED", f"cell:{label} knob:{knob}",
                     f"knob-flip audit raised: {type(e).__name__}: {e}")
 
-    # 3. the pack-fallback identity: a pack=2 request on a too-wide
-    # layout must compile the EXACT pack=1 program (the routing matrix
-    # prices that cell pack=1 with pack_layout_too_wide; anything else
-    # means a shadow pack path recompiles behind the warning).  64
-    # feature columns + 6 extras > PACK_W=64.
+    # 3. donations survive at a second width (64 feature columns):
+    # the declared comb/scratch aliases must appear in the LOWERED
+    # program (lowering only; backend_compile is never reached)
     try:
-        wide_base, wb_args = _phys_build(64, dict(_CLEAN))
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            wide_p2, wp_args = _phys_build(
-                64, dict(_CLEAN, LGBM_TPU_COMB_PACK="2"))
-        if wide_p2.pack != 1:
-            finding(
-                "ROUTING_PROGRAM_DIVERGES", "cell:physical/pack-wide",
-                f"grower engaged pack={wide_p2.pack} on a layout the "
-                f"routing model prices as too wide for pack=2")
-        elif jaxpr_digest(wide_base._grow_p, wb_args) != \
-                jaxpr_digest(wide_p2._grow_p, wp_args):
-            finding(
-                "ROUTING_KNOB_LEAKS",
-                "cell:physical/pack-wide knob:LGBM_TPU_COMB_PACK",
-                "an ineligible pack=2 request (layout too wide) "
-                "compiles a DIFFERENT program than pack=1 — the "
-                "fallback must be the identical program, not a "
-                "recompile")
-        # 4. donations survive on the audited cell REGARDLESS of the
-        # digest verdict above (a knob leak must not mask a dropped
-        # donation): the declared comb/scratch aliases must appear in
-        # the LOWERED program (lowering only; backend_compile is
-        # never reached)
+        wide, w_args = _phys_build(64, dict(_CLEAN))
         from .hbm import entry_residency_bytes
         with warnings.catch_warnings():
             warnings.simplefilter("ignore")
-            lowered = wide_p2._grow_p.lower(*wp_args)
+            lowered = wide._grow_p.lower(*w_args)
         kept = None
         try:
             kv = lowered._lowering.compile_args.get("kept_var_idx")
@@ -536,20 +510,19 @@ def _audit_recompile(ctx) -> List[Finding]:
         except Exception:
             kept = None
         _, aliased = entry_residency_bytes(
-            lowered.as_text(), wp_args, kept=kept)
+            lowered.as_text(), w_args, kept=kept)
         for argnum in (0, 1):
             if argnum not in aliased:
                 finding(
                     "ROUTING_DONATION_DROPPED",
-                    f"cell:physical/pack-wide arg:{argnum}",
+                    f"cell:physical/wide arg:{argnum}",
                     f"the comb/scratch donation (argnum {argnum}) "
                     f"was dropped in the lowered program of this "
-                    f"lattice cell — the fallback variant "
-                    f"double-allocates what the shipping cell "
-                    f"donates")
+                    f"lattice cell — every grow call double-"
+                    f"allocates the buffer")
     except Exception as e:
-        finding("ROUTING_AUDIT_FAILED", "cell:physical/pack-wide",
-                f"pack-fallback audit raised: {type(e).__name__}: {e}")
+        finding("ROUTING_AUDIT_FAILED", "cell:physical/wide",
+                f"donation audit raised: {type(e).__name__}: {e}")
     return out
 
 

@@ -38,7 +38,6 @@ class KernelEntry:
     kind: str                  # partition / hist / stream / fused /
                                # find / grow
     builder: Builder
-    pack: int = 1
     module: str = ""
     note: str = ""
     fixture: bool = False
@@ -116,8 +115,8 @@ MESH_CONFIGS: List[MeshConfig] = []
 _collected = False
 
 
-def register_kernel(name: str, *, kind: str, pack: int = 1,
-                    note: str = "", donate: Tuple[int, ...] = ()):
+def register_kernel(name: str, *, kind: str, note: str = "",
+                    donate: Tuple[int, ...] = ()):
     """Decorator for kernel modules: registers ``builder`` under
     ``name``.  The builder runs lazily (first trace), so registration
     costs nothing at import time.  ``donate`` declares the argnums the
@@ -126,7 +125,7 @@ def register_kernel(name: str, *, kind: str, pack: int = 1,
     output in the lowered program."""
     def deco(builder: Builder) -> Builder:
         KERNELS[name] = KernelEntry(
-            name=name, kind=kind, builder=builder, pack=pack,
+            name=name, kind=kind, builder=builder,
             module=getattr(builder, "__module__", ""), note=note,
             donate=tuple(donate))
         return builder

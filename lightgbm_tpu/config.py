@@ -213,19 +213,11 @@ ENV_KNOBS: Dict[str, tuple] = {
     "LGBM_TPU_PARTITION": ("permute", "single-scan partition packing: "
                                       "permute (O(log R) rounds) or "
                                       "matmul ([R,R] one-hot)"),
-    "LGBM_TPU_PART": ("ss", "3ph restores the 3-phase partition kernel "
-                            "(implies the unfused split path)"),
     "LGBM_TPU_PART_R": ("512", "partition block rows for the "
                                "single-scan kernel"),
     "LGBM_TPU_PART_INTERP": ("off", "kernel runs the REAL scan/copyback "
                                     "bodies through the Pallas "
                                     "interpreter off-TPU"),
-    "LGBM_TPU_COMB_PACK": ("1", "2 packs two logical comb rows per "
-                                "128-lane line (half the partition DMA "
-                                "bytes per logical row); refused by "
-                                "the v5e compiler on jax 0.9.0 as of "
-                                "PR 22 (Unsupported target bitwidth "
-                                "for truncation)"),
     "LGBM_TPU_COMB_DT": ("f32", "bf16 stores the physical comb matrix "
                                 "in bf16 (blocked by Mosaic tiling "
                                 "today; profile_partition records "
@@ -753,40 +745,6 @@ class Config:
             log.fatal("Unknown device_type %s", self.device_type)
         if self.tree_learner not in ("serial", "feature", "data", "voting"):
             log.fatal("Unknown tree_learner %s", self.tree_learner)
-        # LGBM_TPU_COMB_PACK knob validation (the pack=2 trained path,
-        # ops/pallas/layout.py comb_layout): fail HERE with a clear
-        # message for combos the packed comb layout cannot support,
-        # instead of a trace-time kernel error mid-Booster-construction.
-        # Layout-dependent limits (padded feature count <= 64 columns)
-        # are only known at grow-build time and fall back to pack=1
-        # there — since ISSUE 12 that diagnosis states the COMPUTED
-        # post-unbundle column breakdown (grow._warn_pack_fallback), so
-        # the enable_bundle x COMB_PACK=2 interplay (EFB unbundles onto
-        # the physical path, widening the comb to the LOGICAL feature
-        # count) is diagnosable from the message alone.  Nothing to
-        # refuse here: bundling composes with pack=2 whenever the
-        # unbundled width fits, which no config-time fact decides.
-        import os as _os
-        _pack_env = _os.environ.get("LGBM_TPU_COMB_PACK", "1")
-        if _pack_env not in ("1", "2"):
-            log.fatal("LGBM_TPU_COMB_PACK must be 1 or 2 (got %r)",
-                      _pack_env)
-        if _pack_env == "2":
-            if self.max_bin > 256:
-                log.fatal(
-                    "LGBM_TPU_COMB_PACK=2 requires max_bin <= 256: the "
-                    "physical comb layout stores uint8 bins, and "
-                    "max_bin > 256 keeps the row_order path where the "
-                    "pack knob has no effect")
-            if self.gpu_use_dp:
-                log.fatal(
-                    "LGBM_TPU_COMB_PACK=2 is incompatible with "
-                    "gpu_use_dp (double-precision histograms disable "
-                    "the physical comb path entirely)")
-            if _os.environ.get("LGBM_TPU_PART", "") == "3ph":
-                log.fatal(
-                    "LGBM_TPU_COMB_PACK=2 requires the single-scan "
-                    "partition kernel; unset LGBM_TPU_PART=3ph")
 
     # ------------------------------------------------------------------
     def to_param_string(self) -> str:

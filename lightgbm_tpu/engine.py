@@ -45,7 +45,7 @@ def train(
     # fresh per-run observability state (ISSUE 5 lifecycle): counter
     # history, event totals, the run ledger and every warn-once cache
     # restart HERE — before Booster construction, so fallbacks fired
-    # while building THIS run's grower (pack/psum warnings) are
+    # while building THIS run's grower (psum warnings) are
     # attributed to this run, and nothing leaks in from a previous
     # train() in the same process.  The stores are process-global:
     # concurrent train() calls in different threads share them, so

@@ -328,7 +328,7 @@ class GBDT:
                     # reduce-scatter mode pads the feature axis to an
                     # lcm(group, n_shards) multiple — the fast-path
                     # precondition (f_log % n_sh == 0) without the old
-                    # group x shards over-padding that evicted pack=2
+                    # group x shards over-padding
                     # (device_data.pad_features_to_shards)
                     self.dd = to_device(
                         ds, row_pad_multiple=1,
@@ -372,13 +372,11 @@ class GBDT:
                         **self._grow_kwargs)
                     log.info(
                         "Using data-parallel tree learner over %d devices"
-                        "%s%s%s", grower.num_shards,
+                        "%s%s", grower.num_shards,
                         " (reduce-scattered histograms)"
                         if grower.hist_scatter else "",
                         " (physical row partition)"
-                        if grower.physical else "",
-                        " (pack=2 comb lines)"
-                        if getattr(grower, "pack", 1) == 2 else "")
+                        if grower.physical else "")
                 self.grow = grower
                 self._row_put = (jnp.asarray if self._pre_part
                                  else grower.shard_rows)
@@ -424,7 +422,7 @@ class GBDT:
                 # the footprint cannot sit fully resident (or
                 # LGBM_TPU_PAGED=1 forces it), plan the page geometry
                 # off-chip (costmodel.page_schedule over the ENGAGED
-                # pack/stream/fused, LGBM_TPU_PAGE_ROWS override) and
+                # stream/fused, LGBM_TPU_PAGE_ROWS override) and
                 # hand it to the grower — the kernels' row-block grids
                 # extend over host-resident pages streamed through the
                 # double-buffered page buffers
@@ -439,7 +437,6 @@ class GBDT:
                         f_pad=self.dd.phys_f_pad,
                         padded_bins=self.dd.phys_padded_bins,
                         num_leaves=cfg.num_leaves,
-                        pack=self._routing.pack,
                         stream=use_stream,
                         fused=self._routing.fused,
                         stream_kind=(obj_kind if use_stream
@@ -491,13 +488,6 @@ class GBDT:
                             page_plan["limit_bytes"] / 2**30,
                             page_plan["overhead_s_per_tree"],
                             page_plan["host_bw_gbps"])
-                    if getattr(self.grow, "pack", 1) == 2:
-                        # ops/device_data.comb_pack_choice accepted the
-                        # LGBM_TPU_COMB_PACK=2 layout
-                        log.info(
-                            "pack=2 comb layout engaged (two logical "
-                            "rows per 128-lane line; partition DMA "
-                            "bytes per row halved)")
                 if "cegb_lazy" in self._grow_kwargs:
                     # persistent per-(feature, row) acquisition mask
                     # (feature_used_in_data_, cost_effective_gradient_
@@ -513,14 +503,6 @@ class GBDT:
         # use_phys=False of earlier rounds
         from ..ops import routing as _routing_mod
         _routing_mod.report_fallbacks(self._routing)
-        _eng_pack = int(getattr(self.grow, "pack", 1))
-        if (self._routing.path != "row_order"
-                and _eng_pack != self._routing.pack):
-            log.warning(
-                "routing model drift: predicted pack=%d but the grower "
-                "engaged pack=%d — update ops/routing.py and regenerate "
-                "lightgbm_tpu/analysis/routing_matrix.json",
-                self._routing.pack, _eng_pack)
         # score/gradient arrays live at padded length — the LOCAL one
         # under pre-partitioned multi-process data (only the grower
         # boundary sees the assembled global arrays)
@@ -593,7 +575,7 @@ class GBDT:
         """RouteInputs snapshot for the ENGAGED learner and FINAL
         device layout (ISSUE 10): the config / dataset / env-knob
         facts the declarative routing model (``ops/routing.py``)
-        decides the physical/stream/pack/merge path from.  The same
+        decides the physical/stream/merge path from.  The same
         fields key the static routing matrix, so the cell this returns
         is directly testable against the golden enumeration
         (tests/test_routing.py).  Call AFTER ``_build_constraints``:

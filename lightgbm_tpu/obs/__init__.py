@@ -18,7 +18,7 @@ section):
   watermark and mesh-collective records, embedded in ``bench/v3``
   artifacts with a ``provenance()`` header (git SHA, jax version,
   device kind).
-* ``costmodel`` — pack- and scheme-aware per-phase HBM-bytes / FLOPs
+* ``costmodel`` — scheme-aware per-phase HBM-bytes / FLOPs
   predictions for the hist / partition / fused / stream kernels,
   joined with measured walls by ``obs report --roofline``.
 * ``python -m lightgbm_tpu.obs report`` / ``... diff`` — summarize

@@ -17,8 +17,8 @@ capture: the scale-out path would be flown on an unvalidated model.
   per learner grow dispatch, each carrying the analytical per-shard
   ``bytes_moved``.
 
-The comparison is EXACT-OR-FLAGGED, the same discipline as the pack=2
-bytes-halved equality (``tests/test_obs_tools.py``): per shard plane,
+The comparison is EXACT-OR-FLAGGED, the same discipline as the cost
+model's byte contracts (``tests/test_obs_tools.py``): per shard plane,
 measured bytes must equal the summed per-dispatch prediction to the
 byte, or the plane is flagged ``MISMATCH`` with the signed delta —
 a tolerance here would let the cost model drift exactly where ROADMAP

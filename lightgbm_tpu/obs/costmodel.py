@@ -14,9 +14,8 @@ measured phase walls.
 
 Byte contracts (physical comb layout, ``ops/pallas/layout.py``):
 
-* every logical row occupies ``C_phys * itemsize / pack`` bytes of a
-  128-lane line (pack=2 puts two logical rows on one line — HALF the
-  bytes per logical row, the ISSUE-4 claim this model makes checkable);
+* every logical row occupies one comb line: ``C_phys * itemsize``
+  bytes, ``C_phys`` a whole number of 128-lane planes;
 * a partition split over ``cnt`` rows streams each row through the
   scan once (1 read + 1 write: left rows land in place, right rows in
   scratch) and the copyback moves the right segment back
@@ -182,25 +181,22 @@ def hbm_limit_bytes(gen: Optional[str] = None) -> int:
     return int(phys * (1.0 - HBM_RESERVE_FRACTION))
 
 
-def logical_row_bytes(*, pack: int = 1, itemsize: int = F32,
-                      c_phys: int = LANE) -> int:
+def logical_row_bytes(*, itemsize: int = F32, c_phys: int = LANE) -> int:
     """Bytes one LOGICAL row moves per line touch (the
-    ``dma_bytes_per_logical_row`` of profile_partition.py)."""
-    if pack not in (1, 2):
-        raise ValueError(f"pack must be 1 or 2, got {pack}")
-    return c_phys * itemsize // pack
+    ``dma_bytes_per_logical_row`` of profile_partition.py): a row is a
+    line."""
+    return c_phys * itemsize
 
 
 # ---------------------------------------------------------------------
 # kernel-level contracts (exact; pinned by tests/test_obs_tools.py)
 # ---------------------------------------------------------------------
-def partition_split_bytes(cnt: int, nleft: int, *, pack: int = 1,
-                          itemsize: int = F32,
+def partition_split_bytes(cnt: int, nleft: int, *, itemsize: int = F32,
                           c_phys: int = LANE) -> int:
     """Exact HBM bytes one partition split over ``cnt`` logical rows
     moves: scan read + scan write of every row, copyback read + write
     of the ``cnt - nleft`` right-segment rows."""
-    lrb = logical_row_bytes(pack=pack, itemsize=itemsize, c_phys=c_phys)
+    lrb = logical_row_bytes(itemsize=itemsize, c_phys=c_phys)
     return (2 * cnt + 2 * (cnt - nleft)) * lrb
 
 
@@ -236,16 +232,16 @@ def hist_out_bytes(f_pad: int, padded_bins: int) -> int:
 
 
 def hist_build_bytes(cnt: int, *, f_pad: int, padded_bins: int,
-                     pack: int = 1, itemsize: int = F32,
+                     itemsize: int = F32,
                      c_phys: int = LANE) -> int:
     """Exact HBM bytes one comb-direct histogram build over ``cnt``
     logical rows moves: each row read once + one histogram write."""
-    lrb = logical_row_bytes(pack=pack, itemsize=itemsize, c_phys=c_phys)
+    lrb = logical_row_bytes(itemsize=itemsize, c_phys=c_phys)
     return cnt * lrb + hist_out_bytes(f_pad, padded_bins)
 
 
 def fused_split_bytes(cnt: int, nleft: int, *, f_pad: int,
-                      padded_bins: int, pack: int = 1,
+                      padded_bins: int,
                       itemsize: int = F32, c_phys: int = LANE,
                       rehist_rows: int = 0) -> int:
     """Exact HBM bytes one FUSED partition+histogram split moves: the
@@ -254,37 +250,37 @@ def fused_split_bytes(cnt: int, nleft: int, *, f_pad: int,
     scan was told the larger child, also a comb-direct build of the
     smaller one's ``rehist_rows = min(nleft, cnt - nleft)`` rows (0:
     the scan was told the smaller child, nothing follows it)."""
-    out = (partition_split_bytes(cnt, nleft, pack=pack,
+    out = (partition_split_bytes(cnt, nleft,
                                  itemsize=itemsize, c_phys=c_phys)
            + hist_out_bytes(f_pad, padded_bins))
     if rehist_rows:
         out += hist_build_bytes(rehist_rows, f_pad=f_pad,
-                                padded_bins=padded_bins, pack=pack,
+                                padded_bins=padded_bins,
                                 itemsize=itemsize, c_phys=c_phys)
     return out
 
 
 def unfused_split_bytes(cnt: int, nleft: int, *, f_pad: int,
-                        padded_bins: int, pack: int = 1,
+                        padded_bins: int,
                         itemsize: int = F32, c_phys: int = LANE) -> int:
     """Unfused pipeline: partition, then re-read the SMALLER child for
     its histogram (subtraction trick), then one histogram write (the
     sibling comes from the subtraction, in registers)."""
     small = min(nleft, cnt - nleft)
-    return (partition_split_bytes(cnt, nleft, pack=pack,
+    return (partition_split_bytes(cnt, nleft,
                                   itemsize=itemsize, c_phys=c_phys)
             + hist_build_bytes(small, f_pad=f_pad,
-                               padded_bins=padded_bins, pack=pack,
+                               padded_bins=padded_bins,
                                itemsize=itemsize, c_phys=c_phys))
 
 
-def stream_refresh_bytes(n_rows: int, *, pack: int = 1,
+def stream_refresh_bytes(n_rows: int, *,
                          itemsize: int = F32, c_phys: int = LANE,
                          root_hist: bool = False, f_pad: int = 0,
                          padded_bins: int = 0) -> int:
     """Per-tree stream refresh: read + rewrite every comb line once;
     with the fused root carry, one extra root-histogram write."""
-    lrb = logical_row_bytes(pack=pack, itemsize=itemsize, c_phys=c_phys)
+    lrb = logical_row_bytes(itemsize=itemsize, c_phys=c_phys)
     out = 2 * n_rows * lrb
     if root_hist:
         out += hist_out_bytes(f_pad, padded_bins)
@@ -353,11 +349,11 @@ def hist_flops(cnt: int, *, f_pad: int, padded_bins: int) -> int:
 
 
 def partition_flops(cnt: int, *, scheme: str = "permute", R: int = 512,
-                    pack: int = 1, c_phys: int = LANE) -> int:
+                    c_phys: int = LANE) -> int:
     """Per-split compaction compute: the matmul scheme contracts a
     [R, R] one-hot per block (O(R)/row); the permute scheme pays one
     go-left matvec plus ~log2(R) select/roll rounds (O(log R)/row)."""
-    lines = max(cnt // pack, 1)
+    lines = max(cnt, 1)
     if scheme == "matmul":
         return 2 * R * c_phys * lines
     rolls = max(int(R).bit_length() - 1, 1)
@@ -467,7 +463,6 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
             f"got schema {rec.get('schema', '(unversioned)')!r})")
     f_pad = int(shape["f_pad"])
     padded_bins = int(shape["padded_bins"])
-    pack = int(rec.get("knobs", {}).get("comb_pack", 1))
     scheme = str(rec.get("knobs", {}).get("partition", "permute"))
     fused = bool(rec.get("knobs", {}).get("fused", True))
     stream = bool(shape.get("stream", False))
@@ -479,7 +474,7 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     rows_hist = int(counters.get("rows_histogrammed", 0))
     rows_rehist = int(counters.get("rows_rehistogrammed", 0))
     misses = int(counters.get("side_miss_splits", 0))
-    lrb = logical_row_bytes(pack=pack)
+    lrb = logical_row_bytes()
 
     def _part_row(cnt: int) -> Dict[str, float]:
         # scan touches every partitioned row twice; copyback adds 0..2
@@ -488,8 +483,7 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
             "bytes_lo": 2 * cnt * lrb,
             "bytes_hi": 4 * cnt * lrb,
             "bytes": 3 * cnt * lrb,
-            "flops": float(partition_flops(cnt, scheme=scheme,
-                                           pack=pack)),
+            "flops": float(partition_flops(cnt, scheme=scheme)),
         }
 
     out: Dict[str, Dict[str, float]] = {}
@@ -521,7 +515,7 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     if stream and n_rows and trees:
         out["Boosting"] = {
             "bytes": trees * stream_refresh_bytes(
-                n_rows, pack=pack, root_hist=fused, f_pad=f_pad,
+                n_rows, root_hist=fused, f_pad=f_pad,
                 padded_bins=padded_bins),
             "flops": 2.0 * trees * n_rows * 8,  # score+grad+hess math
         }
@@ -557,7 +551,6 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
             f"got schema {rec.get('schema', '(unversioned)')!r})")
     f_pad = int(shape["f_pad"])
     padded_bins = int(shape["padded_bins"])
-    pack = int(rec.get("knobs", {}).get("comb_pack", 1))
     fused = bool(rec.get("knobs", {}).get("fused", True))
     stream = bool(shape.get("stream", False))
     n_rows = int(shape.get("rows", rec.get("rows", 0)))
@@ -567,7 +560,7 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     rows_hist = int(counters.get("rows_histogrammed", 0))
     rows_rehist = int(counters.get("rows_rehistogrammed", 0))
     misses = int(counters.get("side_miss_splits", 0))
-    lrb = logical_row_bytes(pack=pack)
+    lrb = logical_row_bytes()
     hw = hist_out_bytes(f_pad, padded_bins)
     root_rows = n_rows * trees
 
@@ -602,7 +595,7 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
                                    + (trees + splits) * hw)
     if stream and n_rows and trees:
         out["stream_refresh"] = _exact(trees * stream_refresh_bytes(
-            n_rows, pack=pack, root_hist=fused, f_pad=f_pad,
+            n_rows, root_hist=fused, f_pad=f_pad,
             padded_bins=padded_bins))
     coll = sum(float(c.get("bytes_moved", 0.0))
                for c in (rec.get("ledger") or {}).get("collectives", []))
@@ -616,12 +609,12 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
 #
 # Prices every persistent training buffer of the physical-partition
 # trained path as a closed-form function of (rows, features, bins,
-# pack, dtype, stream, n_shards) — the residency twin of the traffic
+# dtype, stream, n_shards) — the residency twin of the traffic
 # contracts above.  The shapes here are EXACT: they reproduce the
 # layout decisions ops/grow.py makes (PHYS_ROW_SLACK, comb_layout,
 # stream_columns) from the same shared primitives, and
 # tests/test_mem.py asserts equality against buffer sizes extracted
-# from the real grow jaxprs across the pack x stream x mesh matrix.
+# from the real grow jaxprs across the planes x stream x mesh matrix.
 # Per-phase live-sets make the PEAK a prediction, not a guess — the
 # paged-comb refactor (ROADMAP item 5) is designed against this model
 # off-chip instead of discovered on-chip by OOM.
@@ -632,7 +625,7 @@ DEFAULT_PEAK_HOST_BW_GBPS = 32.0   # PCIe-class host<->HBM staging BW
 
 def _phys_r_and_slack():
     """(PHYS_R, PHYS_ROW_SLACK) from the loaded grow generation (lazy:
-    grow.py reads the LGBM_TPU_PART* env at import)."""
+    grow.py reads LGBM_TPU_PART_R at import)."""
     from ..ops.grow import PHYS_R, PHYS_ROW_SLACK
     return int(PHYS_R), int(PHYS_ROW_SLACK)
 
@@ -654,7 +647,7 @@ def _buf(shape, itemsize: int, scope: str, dtype: str,
 
 
 def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
-                   num_leaves: int, pack: int = 1,
+                   num_leaves: int,
                    stream: bool = False, fused: bool = True,
                    stream_kind: str = "binary", n_shards: int = 1,
                    num_class: int = 1, itemsize: int = F32,
@@ -675,12 +668,10 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
     the no-bundling identity.  Buffer shapes reproduce
     ops/grow.py's layout decisions exactly:
 
-    * comb/scratch are ``[n_alloc // pack, C]`` lines where
-      ``n_alloc = n_local + PHYS_ROW_SLACK`` and ``(C, pack)`` come
-      from ``layout.comb_layout`` over ``f_pad`` plus the value/rid
-      extras (6, or ``stream_columns(kind)`` in stream mode) — pack=2
-      falls back to 1 when the columns exceed the 64-lane half, the
-      same ``comb_pack_choice`` rule the grower applies;
+    * comb/scratch are ``n_alloc`` lines of ``C`` lanes where
+      ``n_alloc = n_local + PHYS_ROW_SLACK`` and ``C`` comes from
+      ``layout.comb_layout`` over ``f_pad`` plus the value/rid
+      extras (6, or ``stream_columns(kind)`` in stream mode);
     * the histogram arena is the grow loop's ``[L, f_pad, 4, B]`` pool
       (channel-second chan4 layout), live only during ``Tree::grow``;
     * stream+fused carries the ``[f_pad, B, 2]`` root histogram across
@@ -698,7 +689,7 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
       allocator peak and the hbm-budget pass checks against the
       per-generation budget.
     """
-    from ..ops.pallas.layout import PACK_W, comb_layout
+    from ..ops.pallas.layout import comb_layout
     phys_r, slack = _phys_r_and_slack()
     n_shards = max(int(n_shards), 1)
     n_pad = int(rows) if rows_padded else pad_rows(rows, n_shards)
@@ -716,18 +707,15 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
         n_consts = N_CONSTS[stream_kind]
     else:
         n_extra, n_consts = 6, 0
-    pack = int(pack)
-    if pack == 2 and f_pad + n_extra > PACK_W:
-        pack = 1            # comb_pack_choice: layout too wide
-    C, pack = comb_layout(f_pad + n_extra, pack=pack)
+    C = comb_layout(f_pad + n_extra)
     n_alloc = n_local + slack
     L = int(num_leaves)
     dt_name = "bfloat16" if itemsize == 2 else "float32"
 
     bufs: Dict[str, Dict[str, Any]] = {}
-    bufs["comb"] = _buf((n_alloc // pack, C), itemsize, "persistent",
+    bufs["comb"] = _buf((n_alloc, C), itemsize, "persistent",
                         dt_name, donated=True)
-    bufs["scratch"] = _buf((n_alloc // pack, C), itemsize, "persistent",
+    bufs["scratch"] = _buf((n_alloc, C), itemsize, "persistent",
                            dt_name, donated=True)
     _bc = int(bins_cols) or int(f_pad)
     _bi = max(int(bins_itemsize), 1)
@@ -766,7 +754,7 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
                            "bytes": tree_bytes * k_stack}
     # init-scoped: building the comb allocates its output while the
     # zeros/bins inputs are alive (no donation on the one-time init)
-    bufs["comb_init_tmp"] = _buf((n_alloc // pack, C), itemsize, "init",
+    bufs["comb_init_tmp"] = _buf((n_alloc, C), itemsize, "init",
                                  dt_name)
     if stream:
         bufs["stream_aux"] = _buf((2 + n_consts, n_local), F32, "init",
@@ -795,7 +783,7 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
         "geometry": {
             "rows": n_pad, "n_local": n_local, "n_alloc": n_alloc,
             "f_pad": int(f_pad), "padded_bins": int(padded_bins),
-            "C": C, "pack": pack, "n_extra": n_extra,
+            "C": C, "n_extra": n_extra,
             "bins_cols": _bc, "bins_itemsize": _bi,
             "num_leaves": L, "stream": bool(stream),
             "fused": bool(fused), "n_shards": n_shards,
@@ -812,7 +800,7 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
 
 
 def page_schedule(*, rows: int, f_pad: int, padded_bins: int = 256,
-                  num_leaves: int = 255, pack: int = 1,
+                  num_leaves: int = 255,
                   stream: bool = True, fused: bool = True,
                   stream_kind: str = "binary",
                   n_shards: int = 1, num_class: int = 1,
@@ -854,7 +842,7 @@ def page_schedule(*, rows: int, f_pad: int, padded_bins: int = 256,
     # grow outputs are never [K]-stacked here: mc_batched=False
     full = grow_footprint(rows=rows, f_pad=f_pad,
                           padded_bins=padded_bins,
-                          num_leaves=num_leaves, pack=pack,
+                          num_leaves=num_leaves,
                           stream=stream, fused=fused,
                           stream_kind=stream_kind,
                           n_shards=n_shards,
@@ -864,13 +852,13 @@ def page_schedule(*, rows: int, f_pad: int, padded_bins: int = 256,
     out: Dict[str, Any] = {
         "rows": int(rows), "n_local": geo["n_local"],
         "limit_bytes": limit, "unpaged_peak_bytes": full["peak_bytes"],
-        "host_bw_gbps": host_bw, "pack": geo["pack"],
+        "host_bw_gbps": host_bw,
     }
     if (full["peak_bytes"] <= limit and rows_per_page is None
             and not force):
         out.update({"paged": False, "fits": True})
         return out
-    lrb = geo["C"] * itemsize // geo["pack"]
+    lrb = geo["C"] * itemsize
     # fixed overhead: everything in the full footprint that is NOT a
     # comb-scale buffer (pool, tree state, root carry, per-row vectors
     # shrink to page scale and are dominated by the page buffers)
@@ -903,8 +891,7 @@ def page_schedule(*, rows: int, f_pad: int, padded_bins: int = 256,
     dma_per_tree = sweeps * 2 * geo["n_local"] * lrb
     # fixed page-buffer size in comb LINES (the PageStore contract:
     # owned rows + the kernels' DMA-tail slack, clamped to the window)
-    n_lines = geo["n_alloc"] // geo["pack"]
-    page_lines = min((rpp + slack) // geo["pack"], n_lines)
+    page_lines = min(rpp + slack, geo["n_alloc"])
     out.update({
         "paged": True,
         "rows_per_page": rpp,

@@ -53,7 +53,7 @@ stores are lock-guarded so concurrent recording never corrupts the
 structures, and reset between ``lgb.train`` calls via ``reset_all()``
 (called at the top of ``engine.train``), which ALSO clears every
 warn-once set registered through ``on_reset`` — so a second training
-run re-reports the psum / pack fallbacks its own configuration
+run re-reports the psum / routing fallbacks its own configuration
 triggers instead of inheriting the first run's suppression.  Note the
 stores are still ONE per process: two ``lgb.train`` calls running
 concurrently in different threads share (and reset) the same state,
@@ -168,9 +168,10 @@ events = EventCounter()
 
 
 # -- run lifecycle ----------------------------------------------------
-# warn-once caches elsewhere in the library (grow.py's psum / pack
-# fallback shape sets) register a clear-callback here so one reset
-# call restarts the whole observability state between training runs
+# warn-once caches elsewhere in the library (grow.py's psum fallback
+# shape set, routing.py's fallback sets) register a clear-callback here
+# so one reset call restarts the whole observability state between
+# training runs
 _RESET_HOOKS: List[Callable[[], None]] = []
 _RESET_LOCK = threading.Lock()
 

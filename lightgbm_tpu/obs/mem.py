@@ -66,7 +66,6 @@ def footprint_from_record(rec: Dict[str, Any]) -> Dict[str, Any]:
         f_pad=int(shape["f_pad"]),
         padded_bins=int(shape["padded_bins"]),
         num_leaves=int(rec.get("leaves", 31)),
-        pack=int(knobs.get("comb_pack", 1)),
         stream=bool(shape.get("stream", False)),
         fused=bool(knobs.get("fused", True)),
         n_shards=int(mc.get("n_shards", 1)),
@@ -162,7 +161,7 @@ def print_mem_report(rec: Dict[str, Any], path: str,
     print(f"{path}: memory [{MEM_SCHEMA}]")
     print(f"  geometry: rows={geo['rows']} (n_local={geo['n_local']}, "
           f"n_alloc={geo['n_alloc']}), f_pad={geo['f_pad']}, "
-          f"bins={geo['padded_bins']}, pack={geo['pack']}, "
+          f"bins={geo['padded_bins']}, "
           f"C={geo['C']}, stream={'on' if geo['stream'] else 'off'}, "
           f"fused={'on' if geo['fused'] else 'off'}, "
           f"shards={geo['n_shards']}, leaves={geo['num_leaves']}")
@@ -217,15 +216,15 @@ def print_mem_report(rec: Dict[str, Any], path: str,
 
 
 def print_plan(*, rows: int, f_pad: int, padded_bins: int,
-               num_leaves: int, pack: int, stream: bool,
+               num_leaves: int, stream: bool,
                n_shards: int, rows_per_page: Optional[int] = None
                ) -> int:
     plan = costmodel.page_schedule(
         rows=rows, f_pad=f_pad, padded_bins=padded_bins,
-        num_leaves=num_leaves, pack=pack, stream=stream,
+        num_leaves=num_leaves, stream=stream,
         n_shards=n_shards, rows_per_page=rows_per_page)
     print(f"page schedule: rows={plan['rows']} "
-          f"(n_local={plan['n_local']}), pack={plan['pack']}, "
+          f"(n_local={plan['n_local']}), "
           f"HBM budget {plan['limit_bytes'] / 2**30:.2f} GiB")
     print(f"  unpaged peak: {_mb(plan['unpaged_peak_bytes'])}")
     if not plan.get("paged"):
@@ -255,7 +254,7 @@ def print_plan(*, rows: int, f_pad: int, padded_bins: int,
 # ---------------------------------------------------------------------
 def synthetic_mem_record() -> Dict[str, Any]:
     """A deterministic traced-record stand-in: the 50k/63-leaf smoke
-    shape on the pack=2 stream path, with a hand-written residency
+    shape on the stream path, with a hand-written residency
     trajectory sitting safely below the model's predicted peak."""
     iters = []
     for i in range(3):
@@ -277,8 +276,7 @@ def synthetic_mem_record() -> Dict[str, Any]:
         "unit": "iters/sec",
         "backend": "tpu",
         "leaves": 63,
-        "knobs": {"comb_pack": 2, "partition": "permute",
-                  "fused": True},
+        "knobs": {"partition": "permute", "fused": True},
         "shape": {"rows": 50_000, "features": 28, "f_pad": 28,
                   "padded_bins": 256, "trees": 3, "stream": True},
         "traced": True,
@@ -316,7 +314,7 @@ def _regen_fixture() -> None:  # pragma: no cover - dev tool
 def run_mem(paths: List[str], *, plan: bool = False,
             rows: int = 0, features: int = 0,
             bins: Optional[int] = None, leaves: Optional[int] = None,
-            pack: Optional[int] = None, shards: Optional[int] = None,
+            shards: Optional[int] = None,
             stream: Optional[bool] = None, rows_per_page: int = 0,
             tol: float = DEFAULT_MEM_TOL) -> int:
     """CLI body for ``python -m lightgbm_tpu.obs mem``.  ``None``
@@ -334,7 +332,6 @@ def run_mem(paths: List[str], *, plan: bool = False,
                 rows=rows, f_pad=features,
                 padded_bins=256 if bins is None else bins,
                 num_leaves=255 if leaves is None else leaves,
-                pack=1 if pack is None else pack,
                 stream=True if stream is None else stream,
                 n_shards=1 if shards is None else shards,
                 rows_per_page=rows_per_page or None)
@@ -371,8 +368,6 @@ def run_mem(paths: List[str], *, plan: bool = False,
                                  if bins is None else bins),
                     num_leaves=(int(rec.get("leaves", 255))
                                 if leaves is None else leaves),
-                    pack=(int(knobs.get("comb_pack", 1))
-                          if pack is None else pack),
                     stream=(bool(shape.get("stream", True))
                             if stream is None else stream),
                     n_shards=(int(mc.get("n_shards", 1))

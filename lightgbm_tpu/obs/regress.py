@@ -21,7 +21,7 @@ every difference:
   trees or took a different kernel path, and is flagged regardless of
   tolerance;
 * **events gate structure** — an obs event appearing in the candidate
-  (``comb_pack_fallback``, ``hist_scatter_psum_fallback``) means a
+  (``hist_scatter_psum_fallback``, a ``routing_fallback_*``) means a
   slow path silently engaged: flagged;
 * **device kernels are thresholded like walls** (ISSUE 6) — records
   carrying a ``device`` block (xplane-attributed per-kernel device
@@ -35,7 +35,7 @@ every difference:
   compare under the same ``--wall-tol`` when BOTH records measured;
   peaks below 64 KiB are allocator-rounding noise and ignored;
 * **knob mismatches are incomparable** — records captured under
-  different engaged knob sets (comb_pack / partition / fused) answer
+  different engaged knob sets (partition / fused) answer
   different questions; the diff refuses (exit 2) unless
   ``--allow-knob-mismatch``;
 * **mesh records gate the flight recorder** (ISSUE 8) — records whose

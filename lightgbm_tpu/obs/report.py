@@ -455,9 +455,6 @@ def main(argv=None) -> int:
     mp.add_argument("--leaves", type=int, default=None,
                     help="plan geometry: num_leaves (default: the "
                          "record's, else 255)")
-    mp.add_argument("--pack", type=int, default=None,
-                    help="plan geometry: comb pack (default: the "
-                         "record's engaged pack, else 1)")
     mp.add_argument("--shards", type=int, default=None,
                     help="plan geometry: row shards (default: the "
                          "record's, else 1)")
@@ -624,7 +621,7 @@ def main(argv=None) -> int:
         return _F.guard("obs mem")(run_mem)(
             args.paths, plan=args.plan, rows=args.rows,
             features=args.features, bins=args.bins,
-            leaves=args.leaves, pack=args.pack,
+            leaves=args.leaves,
             shards=args.shards, stream=args.stream,
             rows_per_page=args.rows_per_page,
             tol=(args.mem_tol if args.mem_tol is not None

@@ -983,7 +983,7 @@ def synthetic_xspace(device_planes: int = 2,
 
 def synthetic_bench_record() -> Dict[str, Any]:
     """The traced bench/v3 record the fixture's cost-model join uses:
-    pack=2, fused, streamed — so fused_split and stream_refresh carry
+    fused, streamed — so fused_split and stream_refresh carry
     the byte contracts and the table exercises the achieved-GB/s
     column."""
     return {
@@ -997,7 +997,7 @@ def synthetic_bench_record() -> Dict[str, Any]:
                      "rows_histogrammed": 150000.0, "fused_splits": 30.0},
         "shape": {"rows": 10000, "features": 28, "f_pad": 32,
                   "padded_bins": 256, "trees": 3, "stream": True},
-        "knobs": {"comb_pack": 2, "partition": "permute", "fused": True},
+        "knobs": {"partition": "permute", "fused": True},
         "phases": {"Tree::grow": {"total_s": 0.05, "count": 3,
                                   "mean_s": 0.05 / 3},
                    "Boosting": {"total_s": 0.012, "count": 3,
@@ -1101,7 +1101,7 @@ def synthetic_multichip_record() -> Dict[str, Any]:
         "shape": {"rows": 8192, "features": 20, "f_pad": 32,
                   "padded_bins": 64, "trees": MESH_DISPATCHES,
                   "stream": False},
-        "knobs": {"comb_pack": 2, "partition": "permute",
+        "knobs": {"partition": "permute",
                   "fused": True, "tree_learner": "data"},
         "phases": {"Tree::grow": {"total_s": 0.04,
                                   "count": MESH_DISPATCHES,
@@ -1135,7 +1135,6 @@ def synthetic_multichip_record() -> Dict[str, Any]:
             "learner": "data",
             "physical": True,
             "hist_scatter": True,
-            "comb_pack": 2,
             "events": {},
         },
     }

@@ -9,10 +9,10 @@ the histogram kernel's feature groups tile exactly onto the MXU
 width, features padded to a multiple of the matmul group size).
 
 Downstream, physical-partition mode widens these bins into the comb row
-matrix whose LINE layout (128-lane width, optional two-logical-rows-per-
-line packing) is governed by ``ops/pallas/layout.py comb_layout`` — the
-contract every partition/histogram/stream kernel builder validates at
-trace time (the round-3 64-lane regression class, BENCH_r03.json).
+matrix whose LINE layout (one logical row a line, in 128-lane planes) is
+governed by ``ops/pallas/layout.py comb_layout`` — the contract every
+partition/histogram/stream kernel builder validates at trace time (the
+round-3 64-lane regression class, BENCH_r03.json).
 """
 from __future__ import annotations
 
@@ -37,12 +37,12 @@ def pad_features_to_shards(f: int, group: int, n_shards: int) -> int:
     This is the ROADMAP-item-3 fix for ``hist_scatter_psum_fallback``:
     the old layout multiplied the group size by the shard count
     (``group * n_shards`` columns of padding granularity), which both
-    over-padded (f=28, group=8, 8 shards -> 64 columns instead of 32 —
-    wide enough to evict the pack=2 comb layout) and was skipped
-    entirely by direct ``to_device`` callers, leaving their mesh runs
-    on the silent full-psum path.  The static analyzer registers this
-    function's outputs as mesh configs (``analysis/entries.py``) so a
-    regression here is a lint finding, not a run-time warning."""
+    over-padded (f=28, group=8, 8 shards -> 64 columns instead of 32)
+    and was skipped entirely by direct ``to_device`` callers, leaving
+    their mesh runs on the silent full-psum path.  The static analyzer
+    registers this function's outputs as mesh configs
+    (``analysis/entries.py``) so a regression here is a lint finding,
+    not a run-time warning."""
     import math
     if n_shards <= 1:
         m = max(int(group), 1)
@@ -83,19 +83,6 @@ def unbundle_bins(bins: jnp.ndarray, bundle) -> jnp.ndarray:
     in_range = (v >= off[None, :]) & (v < (off + nb)[None, :])
     return jnp.where(in_range, v - off[None, :],
                      dflt[None, :]).astype(jnp.uint8)
-
-
-def comb_pack_choice(f_pad: int, n_extra: int) -> int:
-    """Logical rows per 128-lane comb line the physical-partition path
-    will use: 2 when ``LGBM_TPU_COMB_PACK=2`` AND the layout fits (all
-    of the padded feature columns plus the value/rid/stream extras in
-    one 64-lane half — ``layout.comb_layout`` pack=2 contract), else 1.
-    Since ISSUE 10 this delegates to the declarative routing model
-    (``ops/routing.py pack_choice`` — the same pack rules the static
-    routing matrix enumerates), so ops/grow.py's engaged pack and the
-    analyzer's predicted pack can never disagree."""
-    from .routing import pack_choice
-    return pack_choice(int(f_pad) + int(n_extra))
 
 
 @dataclasses.dataclass
@@ -171,8 +158,8 @@ def to_device(ds: BinnedDataset, row_pad_multiple: int = 1,
     ``col_shard_multiple`` instead pads the feature axis to the smallest
     multiple of lcm(group, n_shards) — the data-parallel reduce-scatter
     merge only needs ``f_log % n_shards == 0``, and the lcm padding keeps
-    that WITHOUT the group x shards over-padding that used to evict the
-    pack=2 comb layout (``pad_features_to_shards``).
+    that WITHOUT the group x shards over-padding
+    (``pad_features_to_shards``).
     ``use_bundles=False`` disables the EFB physical layout (the
     feature-parallel learner shards physical columns and needs the
     identity mapping)."""

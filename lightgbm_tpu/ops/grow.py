@@ -225,7 +225,6 @@ def _empty_tree(num_leaves: int, cat_b: int = 0) -> TreeArrays:
 
 
 # physical-mode partition kernel selection + block size.
-# LGBM_TPU_PART=3ph restores the 3-phase kernel (bisection knob);
 # LGBM_TPU_PART_R overrides the single-scan kernel's block rows.
 # LGBM_TPU_PARTITION selects the single-scan kernel's per-block
 # compaction: "permute" (default — butterfly-routing permutation,
@@ -241,7 +240,6 @@ def _empty_tree(num_leaves: int, cat_b: int = 0) -> TreeArrays:
 # the stable XLA emulation (compiled row order; equivalence-matrix
 # tests use it to pin cross-scheme identity at kernel depth).
 import os as _os_mod
-PART_IMPL = _os_mod.environ.get("LGBM_TPU_PART", "ss")
 PARTITION_IMPL = _os_mod.environ.get("LGBM_TPU_PARTITION", "permute")
 if PARTITION_IMPL not in ("permute", "matmul"):
     raise ValueError(
@@ -249,15 +247,16 @@ if PARTITION_IMPL not in ("permute", "matmul"):
         f"{PARTITION_IMPL!r}")
 PART_INTERP = _os_mod.environ.get("LGBM_TPU_PART_INTERP", "")
 FUSED_IMPL = _os_mod.environ.get("LGBM_TPU_FUSED", "1")
-PHYS_R = (512 if PART_IMPL == "3ph"
-          else int(_os_mod.environ.get("LGBM_TPU_PART_R", "512")))
+PHYS_R = int(_os_mod.environ.get("LGBM_TPU_PART_R", "512"))
 # physical-mode row slack: partition DMA tails (2 * PHYS_R — the
 # single-scan kernel's right-zone scratch writes start one block past
-# s0 and round up to a full block; the pack=2 scan needs up to 3 *
-# PHYS_R for its head-parity spill block, covered for PHYS_R <= 4096
-# because the histogram term below exceeds PHYS_R) + two comb-direct
-# histogram blocks (2 * 2048 logical rows at any pack); callers gating
-# on the 2^24 row-id limit must subtract this (gbdt use_phys decision)
+# s0 and round up to a full block) + two comb-direct histogram blocks
+# (2 * 2048 rows); callers gating on the 2^24 row-id limit must
+# subtract this (gbdt use_phys decision).  The histogram term was also
+# what covered a 3 * PHYS_R spill bound of a row format that is gone
+# (ISSUE 32); the value stays, because n_alloc is part of every
+# compiled program's shapes and of the measured memory peak - whether
+# it can be smaller is ROADMAP C12.
 PHYS_ROW_SLACK = 2 * PHYS_R + 2 * 2048
 
 
@@ -287,52 +286,12 @@ def _warn_hist_scatter_fallback(f_log: int, n_shards: int) -> None:
         "path.", f_log, n_shards, n_shards)
 
 
-_PACK_FALLBACK_WARNED = set()
-
-
-def _warn_pack_fallback(n_cols: int, f_cols: int = None,
-                        n_extra: int = None,
-                        efb_src_cols: int = None) -> None:
-    """LGBM_TPU_COMB_PACK=2 with a comb layout wider than 64 logical
-    columns (wide feature pads, hist_scatter column padding on
-    small-bin meshes, or an EFB dataset whose bundles unbundle wide):
-    warn once per width, record an obs event, train on pack=1 — a
-    mid-training crash would be worse than the unpacked DMA rate.
-
-    The message states the COMPUTED column breakdown (the ISSUE-12
-    check_conflicts satellite): config-time validation cannot know the
-    post-unbundle feature count, so this layout-time diagnosis must be
-    self-sufficient — naming only the knobs left the enable_bundle x
-    COMB_PACK=2 interplay undiagnosable without reading layout.py."""
-    from ..obs.counters import events as _obs_events
-    from ..utils import log
-    _obs_events.record("comb_pack_fallback")
-    if n_cols in _PACK_FALLBACK_WARNED:
-        return
-    _PACK_FALLBACK_WARNED.add(n_cols)
-    if f_cols is None:
-        detail = "padded features + value/rid/stream columns"
-    else:
-        efb = ("" if efb_src_cols is None else
-               f" — EFB unbundled {efb_src_cols} bundled storage "
-               f"column(s) into the {f_cols} logical ones "
-               f"(enable_bundle=false would not help: the unbundled "
-               f"width is the logical feature count)")
-        detail = (f"{f_cols} post-unbundle feature columns "
-                  f"+ {n_extra} value/rid/stream columns{efb}")
-    log.warning(
-        "LGBM_TPU_COMB_PACK=2 needs <= 64 comb columns per logical row "
-        "but this layout has %d (%s); training on pack=1",
-        n_cols, detail)
-
-
 # warn-once suppression is PER RUN, not per process: obs.reset_run()
 # (called between lgb.train calls, engine.py) clears these sets so a
 # second training run re-reports the fallbacks ITS configuration takes
 from ..obs.counters import on_reset as _obs_on_reset
 
 _obs_on_reset(_HIST_SCATTER_WARNED.clear)
-_obs_on_reset(_PACK_FALLBACK_WARNED.clear)
 
 
 def hist_scatter_eligible(hp, *, bundle=None, voting: bool = False,
@@ -596,30 +555,7 @@ def make_grow_fn(
                 "physical mode does not support gpu_use_dp (the "
                 "comb-direct histogram kernel accumulates f32; disable "
                 "one of them)")
-        # comb line packing (ops/pallas/layout.py comb_layout):
-        # LGBM_TPU_COMB_PACK=2 packs TWO logical rows per 128-lane line
-        # — every partition / histogram / stream / copyback DMA moves
-        # half the bytes per logical row.  Knob-level validation (clear
-        # errors for still-unsupported combos) lives in
-        # config.check_conflicts; the column-budget fit (f_pad + extras
-        # <= 64) is only known here and falls back to pack=1 with a
-        # warning (wide layouts — e.g. hist_scatter column padding on
-        # small-bin meshes — must keep training).
-        from ..config import env_knob as _env_knob
-        _comb_pack = int(_env_knob("LGBM_TPU_COMB_PACK"))
-        if _comb_pack == 2 and PART_IMPL == "3ph":
-            raise ValueError(
-                "LGBM_TPU_COMB_PACK=2 requires the single-scan "
-                "partition kernel (unset LGBM_TPU_PART=3ph)")
-        if _comb_pack == 2 and PHYS_R > 4096:
-            # PHYS_ROW_SLACK (2R + 4096) covers the pack=2 scan's
-            # 3R head-parity spill bound only up to R = 4096
-            raise ValueError(
-                f"LGBM_TPU_COMB_PACK=2 supports LGBM_TPU_PART_R <= "
-                f"4096 (got {PHYS_R}): the packed scan's scratch "
-                f"spill bound (3R) exceeds PHYS_ROW_SLACK above that")
-        _part_kernel_interp = (PART_INTERP == "kernel"
-                               and PART_IMPL != "3ph")
+        _part_kernel_interp = PART_INTERP == "kernel"
         _PHYS_R = PHYS_R
         n_rows_p = int(physical_bins.shape[0])   # LOCAL rows (per shard)
         if n_rows_p % _PHYS_R != 0:
@@ -668,39 +604,13 @@ def make_grow_fn(
         # 128-lane granularity is validated there AND by every kernel
         # builder, so the round-3 64-lane class of regression fails at
         # trace time on CPU, not at Mosaic compile time on chip.
-        # Under pack=2 every comb consumer runs in the LOGICAL row
-        # domain: _C_PHYS is the physical line width (128), _CW the
-        # columns each logical row owns (64), and the comb/scratch
-        # matrices are [_n_alloc // 2, _C_PHYS] packed lines.
-        from .device_data import comb_pack_choice
-        from .pallas.layout import (PACK_W, comb_layout, comb_planes,
-                                    set_cols, to_rows)
-        _pack_fit = comb_pack_choice(f_pad_p, _n_extra)
-        if _comb_pack == 2 and _pack_fit == 1:
-            _warn_pack_fallback(
-                f_pad_p + _n_extra, f_cols=f_pad_p, n_extra=_n_extra,
-                efb_src_cols=(int(physical_bins.shape[1])
-                              if _efb_ingest is not None else None))
-        _comb_pack = min(_comb_pack, _pack_fit)
-        _C_PHYS, _comb_pack = comb_layout(
-            f_pad_p + _n_extra, pack=_comb_pack, dtype=_COMB_DT)
-        _CW = PACK_W if _comb_pack == 2 else _C_PHYS
+        from .pallas.layout import (comb_layout, comb_planes, set_cols,
+                                    to_rows)
+        _C_PHYS = comb_layout(f_pad_p + _n_extra, _COMB_DT)
         # the comb is stored plane-major (layout.py): _PLANES matrices
         # of [lines, 128], one after the other in one array
         _PLANES = comb_planes(_C_PHYS)
-        if _comb_pack == 2:
-            # pack=2 routing is permutation-only; under
-            # LGBM_TPU_PARTITION=matmul trees still match bit-for-bit
-            # (both pack=1 schemes produce the identical layout the
-            # pack=2 kernel reproduces in the logical domain)
-            from .pallas.partition_kernel3 import \
-                make_partition_p2 as _mk_p2
-
-            def make_partition(n, C, **kw):
-                return _mk_p2(n, **kw)
-        elif PART_IMPL == "3ph":
-            from .pallas.partition_kernel import make_partition
-        elif PARTITION_IMPL == "permute":
+        if PARTITION_IMPL == "permute":
             from .pallas.partition_kernel3 import \
                 make_partition_perm as make_partition
         else:
@@ -724,11 +634,10 @@ def make_grow_fn(
             # the engaged layout — refuse loudly rather than stream
             # wrong-shaped pages
             _rpp = int(paged["rows_per_page"])
-            if _rpp % _PHYS_R or _rpp % _comb_pack:
+            if _rpp % _PHYS_R:
                 raise ValueError(
                     f"rows_per_page={_rpp} must be a multiple of the "
-                    f"partition block R={_PHYS_R} and pack="
-                    f"{_comb_pack} (LGBM_TPU_PAGE_ROWS)")
+                    f"partition block R={_PHYS_R} (LGBM_TPU_PAGE_ROWS)")
             if (int(paged.get("C", _C_PHYS)) != _C_PHYS
                     or int(paged.get("n_alloc", _n_alloc)) != _n_alloc):
                 raise ValueError(
@@ -736,7 +645,7 @@ def make_grow_fn(
                     f"{paged.get('n_alloc')}) does not match the "
                     f"engaged comb layout (C={_C_PHYS}, n_alloc="
                     f"{_n_alloc}); re-plan with costmodel."
-                    f"page_schedule over the engaged pack/stream")
+                    f"page_schedule over the engaged stream mode")
         _phys_interp = jax.default_backend() != "tpu"
         # fused partition+histogram split kernel (fused_split.py): one
         # dynamic-grid scan per split compacts the parent AND
@@ -744,10 +653,9 @@ def make_grow_fn(
         # blocks, the child the finder's record calls smaller — the
         # separate child-histogram kernel (and its HBM re-read of the
         # rows the scan just streamed) has work only where the record
-        # named the larger one.  The 3-phase bisection knob keeps the
-        # fully-unfused pipeline.
+        # named the larger one.
         from .pallas.fused_split import fused_supported
-        _use_fused = (FUSED_IMPL != "0" and PART_IMPL != "3ph"
+        _use_fused = (FUSED_IMPL != "0"
                       and fused_supported(f_pad_p, int(padded_bins)))
         if _phys_interp:
             # off-TPU reference path keeps the static bucket switch (the
@@ -771,8 +679,7 @@ def make_grow_fn(
                 _fused_dyn = make_fused_split(
                     _n_alloc, _C_PHYS, f_pad=f_pad_p,
                     padded_bins=int(padded_bins), R=_PHYS_R,
-                    dtype=_COMB_DT, dynamic=True, scan=PARTITION_IMPL,
-                    pack=_comb_pack)
+                    dtype=_COMB_DT, dynamic=True, scan=PARTITION_IMPL)
             else:
                 _part_dyn = make_partition(_n_alloc, _C_PHYS, R=_PHYS_R,
                                            dtype=_COMB_DT, dynamic=True)
@@ -789,14 +696,13 @@ def make_grow_fn(
                 f=f_pad_p, n_alloc=_n_alloc, n_pad=n_rows_p, C=_C_PHYS,
                 R=_PHYS_R, interpret=_phys_interp, dtype=_COMB_DT,
                 root_hist=_fused_root, padded_bins=int(padded_bins),
-                root_rpb=rows_per_block, pack=_comb_pack)
+                root_rpb=rows_per_block)
             _stream_init_fn = make_init(
                 kind=stream["kind"],
                 sigmoid=float(stream.get("sigmoid", 1.0)),
                 f_real=f_pad_p, f=f_pad_p, n_alloc=_n_alloc,
                 n_pad=n_rows_p, C=_C_PHYS, R=_PHYS_R,
-                interpret=_phys_interp, dtype=_COMB_DT,
-                pack=_comb_pack)
+                interpret=_phys_interp, dtype=_COMB_DT)
     if use_voting and fax is not None:
         raise ValueError("voting and feature-parallel modes are exclusive")
     if fax is not None and use_ic:
@@ -917,27 +823,11 @@ def make_grow_fn(
         inbag = inbag.astype(jnp.float32)
 
         if physical:
-            # pack-aware comb access: everything row-indexed below runs
-            # in the LOGICAL domain.  _comb_logical is the reshape view
-            # the off-TPU XLA reference paths slice (free on CPU);
-            # _decode_rid turns the stored row-id byte columns of BOTH
-            # lane halves into logical-order row ids with one matmul
-            # (exact: powers of two x bytes <= 255, f32 accumulation
-            # < 2^24 — a [n, 3] column slice would lane-pad to
-            # 512 B/row, the round-2 OOM).
-            def _comb_logical(c):
-                return (c.reshape(_n_alloc, _CW) if _comb_pack == 2
-                        else to_rows(c, _C_PHYS))
-
+            # _decode_rid turns the stored row-id byte columns into row
+            # ids with one matvec (exact: powers of two x bytes <= 255,
+            # f32 accumulation < 2^24 — a [n, 3] column slice would
+            # lane-pad to 512 B/row, the round-2 OOM).
             def _decode_rid(c):
-                if _comb_pack == 2:
-                    rw = jnp.zeros((_C_PHYS, 2), jnp.float32)
-                    for h, off_h in enumerate((0, PACK_W)):
-                        rw = (rw.at[off_h + f + 3, h].set(65536.0)
-                              .at[off_h + f + 4, h].set(256.0)
-                              .at[off_h + f + 5, h].set(1.0))
-                    # [n_phys, 2] -> interleaved == logical order
-                    return jnp.matmul(c, rw).reshape(-1)
                 import numpy as _np
                 rid_w = _np.zeros((_C_PHYS,), _np.float32)
                 rid_w[f + 3:f + 6] = (65536.0, 256.0, 1.0)
@@ -1123,9 +1013,7 @@ def make_grow_fn(
             if _phys_interp:
                 # slack rows hold garbage copies (nonzero w); the XLA
                 # reference path has no row window, so mask by position
-                # (the logical view makes pack=2 slices identical to
-                # pack=1's — same values, same arithmetic)
-                comb_l = _comb_logical(comb)
+                comb_l = to_rows(comb, _C_PHYS)
                 pos_al = jnp.arange(_n_alloc, dtype=jnp.int32)
                 gvals = (jax.lax.slice(comb_l, (0, f), (_n_alloc, f + 3))
                          * (pos_al < n).astype(jnp.float32)[:, None])
@@ -1143,7 +1031,7 @@ def make_grow_fn(
             # tails; their weights are zeroed by position so they never
             # contribute.
             pos_al = jnp.arange(_n_alloc, dtype=jnp.int32)
-            # rid decode as ONE matvec (logical order at every pack):
+            # rid decode as ONE matvec:
             # a [n, 3] column slice would lane-pad to 512 B/row (5.4 GB
             # at 10.5M rows — the round-2 OOM).  The weighted sum is
             # exact at bf16 operand precision (powers of two x bytes
@@ -1165,30 +1053,11 @@ def make_grow_fn(
                 # large fusions (verified on-device — the round-trip was
                 # a silent no-op here).
                 gvp = jax.lax.reduce_precision(gvp, 8, 7)
-            if _comb_pack == 2:
-                # scatter the (g*w, h*w, w) triple into BOTH lane
-                # halves: [n_phys, 6] value rows placed by one 0/1
-                # matmul + a keep mask (exact: gvp is bf16-exact on TPU
-                # after the reduce_precision above, f32 elsewhere, and
-                # each output lane receives exactly one product)
-                gv6 = gvp.reshape(_n_alloc // 2, 6)
-                vcols = (f, f + 1, f + 2,
-                         PACK_W + f, PACK_W + f + 1, PACK_W + f + 2)
-                lane_c = jnp.arange(_C_PHYS)
-                keep = jnp.ones((_C_PHYS,), jnp.float32)
-                for cix in vcols:
-                    keep = keep * (lane_c != cix).astype(jnp.float32)
-                place = jnp.stack(
-                    [(lane_c == cix).astype(jnp.float32)
-                     for cix in vcols])                  # [6, C]
-                comb = (comb_in * keep[None, :]
-                        + jnp.matmul(gv6, place)).astype(comb_in.dtype)
-            else:
-                comb = set_cols(comb_in, gvp, f, _C_PHYS)
+            comb = set_cols(comb_in, gvp, f, _C_PHYS)
             gvals = gvp                     # root histogram values
             # full-width bins slice only for the off-TPU reference path;
             # on TPU the comb-direct kernel reads the matrix in place
-            bins_c = (jax.lax.slice(_comb_logical(comb), (0, 0),
+            bins_c = (jax.lax.slice(to_rows(comb, _C_PHYS), (0, 0),
                                     (_n_alloc, f))
                       if _phys_interp else None)
             use_bf16_comb = False
@@ -1340,7 +1209,7 @@ def make_grow_fn(
                 comb, jnp.int32(0), jnp.int32(0), jnp.int32(n),
                 f_pad=f, size=n, padded_bins=padded_bins,
                 rows_per_block=min(rows_per_block, _HIST_RPB),
-                pack=_comb_pack, planes=_PLANES)
+                planes=_PLANES)
             root_hist = merge_kernel_hist(root_hist)
         else:
             root_hist = expand(hist_merge(
@@ -1748,9 +1617,7 @@ def make_grow_fn(
                                           par_cnt - nleft_)
                     child_start = jnp.where(small_left_, s0, s0 + nleft_)
                     if _phys_interp:
-                        # off-TPU reference path: explicit slice + mask
-                        # (over the logical view, so pack=2 runs the
-                        # identical arithmetic on identical values).
+                        # off-TPU reference path: explicit slice + mask.
                         # Fused or not: the compiled fused route hands
                         # on the scan's histogram where it named the
                         # smaller child and the comb-direct one of
@@ -1760,8 +1627,8 @@ def make_grow_fn(
                                            _n_alloc - s_child)
                         off = child_start - start_c
                         rowsl = jax.lax.dynamic_slice(
-                            _comb_logical(combp),
-                            (start_c, jnp.int32(0)), (s_child, _CW))
+                            to_rows(combp, _C_PHYS),
+                            (start_c, jnp.int32(0)), (s_child, _C_PHYS))
                         posr = jnp.arange(s_child, dtype=jnp.int32)
                         m = ((posr >= off) & (posr < off + child_cnt)
                              & ~done).astype(jnp.float32)
@@ -1776,8 +1643,7 @@ def make_grow_fn(
                             jnp.where(done, 0, child_cnt),
                             f_pad=f, size=s_child,
                             padded_bins=padded_bins,
-                            rows_per_block=rpb_h, pack=_comb_pack,
-                            planes=_PLANES)
+                            rows_per_block=rpb_h, planes=_PLANES)
                     return (st.row_order, combp, scrp,
                             nleft_, small_left_, h, st.paid,
                             jnp.zeros((1, 2), jnp.float32),
@@ -1804,11 +1670,7 @@ def make_grow_fn(
                     # knob off so the compiled program is unchanged
                     sel = jnp.concatenate(
                         [sel, _members_to_words(member_f[None])[0]])
-                # pack=2: one extra block covers the head-parity spill
-                # (nb_live = ceil((cnt + s0 % 2) / R) in the kernel)
-                nb_part = (jnp.maximum(cnt_eff // _PHYS_R + 1, 1)
-                           if _comb_pack == 2
-                           else jnp.maximum(-(-cnt_eff // _PHYS_R), 1))
+                nb_part = jnp.maximum(-(-cnt_eff // _PHYS_R), 1)
                 if _use_fused:
                     # ONE kernel: compaction scan + the histogram of
                     # the child sel[SEL_SIDE] names, from the
@@ -1847,7 +1709,7 @@ def make_grow_fn(
                         comb_c, child_start, jnp.int32(0), cnt_c, f_pad=f,
                         padded_bins=padded_bins,
                         rows_per_block=min(rows_per_block, _HIST_RPB),
-                        pack=_comb_pack, planes=_PLANES)
+                        planes=_PLANES)
 
                 if _use_fused:
                     h_small = jax.lax.cond(
@@ -2312,8 +2174,8 @@ def make_grow_fn(
             return MeshPhysicalPieces(
                 core=grow_p_raw, n_alloc=_n_alloc, C=_C_PHYS,
                 f_pad=f_pad_p, n_local=n_rows_p, dtype=_COMB_DT,
-                fused=_use_fused, pack=_comb_pack,
-                ingest=_efb_ingest, padded_bins=int(padded_bins))
+                fused=_use_fused, ingest=_efb_ingest,
+                padded_bins=int(padded_bins))
         # donation: the carried comb/scratch matrices alias their
         # outputs (the whole point of the in-place design), and the
         # fused-root carry donates the [f_pad, B, 2] root histogram
@@ -2332,9 +2194,7 @@ def make_grow_fn(
             if _phys_interp:
                 @jax.jit
                 def _root0_fn(comb):
-                    comb_l = (comb.reshape(_n_alloc, _CW)
-                              if _comb_pack == 2
-                              else to_rows(comb, _C_PHYS))
+                    comb_l = to_rows(comb, _C_PHYS)
                     pos_al = jnp.arange(_n_alloc, dtype=jnp.int32)
                     gv = (jax.lax.slice(comb_l, (0, f_pad_p),
                                         (_n_alloc, f_pad_p + 3))
@@ -2353,7 +2213,7 @@ def make_grow_fn(
                         jnp.int32(n_rows_p), f_pad=f_pad_p,
                         size=n_rows_p, padded_bins=padded_bins,
                         rows_per_block=min(rows_per_block, _HIST_RPB),
-                        pack=_comb_pack, planes=_PLANES)
+                        planes=_PLANES)
         else:
             _root0_fn = None
         if stream is not None:
@@ -2374,14 +2234,13 @@ def make_grow_fn(
             def _reanchor_bins(comb):
                 # (a transposing copy above one plane; once a
                 # checkpoint, not once a tree)
-                comb_l = (comb.reshape(_n_alloc, _CW)
-                          if _comb_pack == 2
-                          else to_rows(comb, _C_PHYS))
-                rid_w = (jnp.zeros((_CW,), jnp.float32)
+                comb_l = to_rows(comb, _C_PHYS)
+                rid_w = (jnp.zeros((_C_PHYS,), jnp.float32)
                          .at[f_pad_p + 3].set(65536.0)
                          .at[f_pad_p + 4].set(256.0)
                          .at[f_pad_p + 5].set(1.0))
-                real = jax.lax.slice(comb_l, (0, 0), (n_rows_p, _CW))
+                real = jax.lax.slice(comb_l, (0, 0),
+                                     (n_rows_p, _C_PHYS))
                 rid = jnp.matmul(
                     real.astype(jnp.float32), rid_w).astype(jnp.int32)
                 bins_perm = jax.lax.slice(
@@ -2399,8 +2258,7 @@ def make_grow_fn(
             stream_init=(_stream_init_fn
                          if stream is not None else None),
             dtype=_COMB_DT, fused=_use_fused,
-            root0_fn=_root0_fn,
-            pack=_comb_pack, ingest=_efb_ingest,
+            root0_fn=_root0_fn, ingest=_efb_ingest,
             paged_plan=paged, reanchor_fn=_reanchor_fn))
 
     if use_cegb_lazy:
@@ -2430,13 +2288,12 @@ class MeshPhysicalPieces(NamedTuple):
     is_cat, seed, rate) -> (tree, leaf_id, comb, scratch)``; shapes are
     PER-SHARD (n_local rows)."""
     core: object
-    n_alloc: int            # LOGICAL rows (pack-independent)
-    C: int                  # physical line width
+    n_alloc: int            # comb lines (rows + PHYS_ROW_SLACK)
+    C: int                  # line width
     f_pad: int              # comb feature columns (UNBUNDLED under EFB)
     n_local: int
     dtype: object = jnp.float32
     fused: bool = False     # per-split fused partition+histogram kernel
-    pack: int = 1           # logical rows per 128-lane comb line
     ingest: object = None   # EFB: bins_local -> unbundled u8 block
                             # (device_data.unbundle_bins closure); the
                             # caller applies it inside its shard_mapped
@@ -2447,27 +2304,17 @@ class MeshPhysicalPieces(NamedTuple):
 
 
 def phys_init_comb(bins_local, n_alloc: int, C: int, f_pad: int,
-                   dtype=jnp.float32, pack: int = 1):
+                   dtype=jnp.float32):
     """Build the physical row matrix from a (local) [n, f_pad] u8 bin
     block: bins as numeric columns + LOCAL row-id bytes at f_pad+3..5
     (the value columns are refreshed per tree by the grower).  All
     stored values are bf16-exact by the layout contract, so ``dtype``
-    may be bfloat16 (half the DMA bytes of f32).  With ``pack=2`` the
-    returned matrix is [n_alloc // 2, C] packed lines (layout
-    comb_layout pack=2); the logical-view reshape here is a one-time
-    init cost — the per-tree hot paths never unpack to HBM.  With
-    ``pack=1`` it is plane-major (layout.py), built a plane at a time:
-    no [n_alloc, C] row matrix is ever made."""
+    may be bfloat16 (half the DMA bytes of f32).  The matrix is
+    plane-major (layout.py), built a plane at a time: no [n_alloc, C]
+    row matrix is ever made."""
     from .pallas.layout import LANE, set_cols
     rid = jnp.arange(n_alloc, dtype=jnp.int32)
     rid_bytes = (rid // 65536, (rid // 256) % 256, rid % 256)
-    if pack == 2:
-        comb = jnp.zeros((n_alloc, C // 2), dtype)
-        comb = jax.lax.dynamic_update_slice(
-            comb, bins_local.astype(dtype), (0, 0))
-        for i, v in enumerate(rid_bytes):
-            comb = comb.at[:, f_pad + 3 + i].set(v.astype(dtype))
-        return comb.reshape(n_alloc // 2, C)
     planes = []
     for lo in range(0, C, LANE):
         plane = jnp.zeros((n_alloc, LANE), dtype)
@@ -2493,7 +2340,7 @@ class _PhysicalGrow:
 
     def __init__(self, grow_p, bins_dev, n_alloc, C, f_pad,
                  stream_init=None, dtype=jnp.float32, fused=False,
-                 root0_fn=None, pack=1, ingest=None,
+                 root0_fn=None, ingest=None,
                  paged_plan=None, reanchor_fn=None):
         self._grow_p = grow_p
         self._bins_dev = bins_dev
@@ -2504,7 +2351,6 @@ class _PhysicalGrow:
         self._n_alloc = n_alloc
         self._C = C
         self._f_pad = f_pad
-        self.pack = pack             # logical rows per comb line
         self._comb = None
         self._scratch = None
         self._stream_init = stream_init
@@ -2561,7 +2407,7 @@ class _PhysicalGrow:
         if comb is None:
             return False
         bins_anchored = self._reanchor_fn(comb)
-        shape = comb_shape(self._n_alloc // self.pack, self._C)
+        shape = comb_shape(self._n_alloc, self._C)
         comb0 = jnp.zeros(shape, self._dtype)
         self._put_window(self._stream_init(
             comb0, bins_anchored, self._stream_aux_fn()))
@@ -2586,7 +2432,7 @@ class _PhysicalGrow:
 
     def _init_buffers(self):
         f_pad, n_alloc, C = self._f_pad, self._n_alloc, self._C
-        shape = comb_shape(n_alloc // self.pack, C)
+        shape = comb_shape(n_alloc, C)
         bins_src = (self._bins_dev if self._ingest is None
                     else self._ingest(self._bins_dev))
         if self.paged is not None and self._pages is None:
@@ -2594,7 +2440,7 @@ class _PhysicalGrow:
             self._pages = PageStore(
                 n_alloc=n_alloc, C=C,
                 rows_per_page=int(self.paged["rows_per_page"]),
-                pack=self.pack, dtype=self._dtype)
+                dtype=self._dtype)
         if self._stream_init is not None:
             if self._stream_aux_fn is None:
                 raise RuntimeError(
@@ -2605,7 +2451,7 @@ class _PhysicalGrow:
         else:
             init = jax.jit(functools.partial(
                 phys_init_comb, n_alloc=n_alloc, C=C, f_pad=f_pad,
-                dtype=self._dtype, pack=self.pack))
+                dtype=self._dtype))
             comb = init(bins_src)
         self._put_window(comb)
         self._scratch = jnp.zeros(shape, self._dtype)
@@ -2730,7 +2576,7 @@ class _NumericsGuard:
       NumericsSkip) so the async dispatch chain stays intact until the
       booster decides to look.
 
-    Everything else (``pack``, ``fused``, ``set_stream_aux``,
+    Everything else (``fused``, ``set_stream_aux``,
     ``reset_stream``) delegates to the wrapped callable.  ``off``
     never constructs this class at all — ``make_grow_fn`` returns the
     unwrapped program (the ``grow-numerics-off`` purity pin)."""
@@ -2768,5 +2614,5 @@ class _NumericsGuard:
 
     def __getattr__(self, name):
         # only reached when normal lookup fails: delegate wrapped-fn
-        # attributes (pack, fused, stream hooks)
+        # attributes (fused, stream hooks)
         return getattr(self._fn, name)

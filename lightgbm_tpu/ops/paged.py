@@ -206,7 +206,7 @@ def validate_schedule(events: List[Event], n_pages: int,
 
 
 def plan_pages(*, rows: int, f_pad: int, padded_bins: int,
-               num_leaves: int, pack: int = 1, stream: bool = True,
+               num_leaves: int, stream: bool = True,
                fused: bool = True, stream_kind: str = "binary",
                num_class: int = 1,
                rows_per_page: Optional[int] = None,
@@ -222,7 +222,7 @@ def plan_pages(*, rows: int, f_pad: int, padded_bins: int,
     from ..obs.costmodel import page_schedule
     plan = page_schedule(
         rows=rows, f_pad=f_pad, padded_bins=padded_bins,
-        num_leaves=num_leaves, pack=pack, stream=stream, fused=fused,
+        num_leaves=num_leaves, stream=stream, fused=fused,
         stream_kind=stream_kind, num_class=max(int(num_class), 1),
         rows_per_page=rows_per_page, limit_bytes=limit_bytes,
         force=force)
@@ -243,7 +243,7 @@ class PageStore:
     buffers, with the grow-time window assembled and flushed by
     executing the double-buffered schedule.
 
-    Page ``p`` owns logical rows ``[p * rows_per_page, (p + 1) *
+    Page ``p`` owns rows ``[p * rows_per_page, (p + 1) *
     rows_per_page)`` of the comb's ``n_alloc``-row line space; every
     page buffer is allocated at the planner's fixed page size
     (``rows_per_page + slack`` rows — the slack tail is the kernels'
@@ -255,31 +255,21 @@ class PageStore:
     planner's page geometry."""
 
     def __init__(self, *, n_alloc: int, C: int, rows_per_page: int,
-                 pack: int = 1, dtype=None):
+                 dtype=None):
         import jax.numpy as jnp
         from .grow import PHYS_ROW_SLACK
-        self.n_alloc = int(n_alloc)          # logical rows incl. slack
+        self.n_alloc = int(n_alloc)          # rows incl. slack
         self.C = int(C)
-        self.pack = int(pack)
         self.rows_per_page = int(rows_per_page)
         self.dtype = dtype if dtype is not None else jnp.float32
-        if self.rows_per_page % self.pack:
-            raise ValueError(
-                f"rows_per_page={rows_per_page} must be a multiple of "
-                f"pack={pack}")
         self.slack = int(PHYS_ROW_SLACK)
         n_local = self.n_alloc - self.slack
         self.n_pages = -(-n_local // self.rows_per_page)
-        # physical comb LINES per page / per buffer (pack=2 packs two
-        # logical rows per line)
-        self.lines_per_page = self.rows_per_page // self.pack
-        self.n_lines = self.n_alloc // self.pack
         # fixed page-buffer size: owned rows + the kernels' DMA-tail
         # slack (never larger than the window itself — the one-page
         # degenerate case of a forced tiny-budget run)
-        self.page_lines = min(
-            (self.rows_per_page + self.slack) // self.pack,
-            self.n_lines)
+        self.page_lines = min(self.rows_per_page + self.slack,
+                              self.n_alloc)
         self._pages: List[Optional[np.ndarray]] = [None] * self.n_pages
         self.stats = {"fetch_s": 0.0, "flush_s": 0.0, "cycles": 0,
                       "dma_bytes": 0}
@@ -332,12 +322,12 @@ class PageStore:
         # clamp so the last page's full-size buffer stays in range (its
         # tail overlaps the previous page's rows; valid_lines masks the
         # overlap out on update, and flush writes it back verbatim)
-        return min(p * self.lines_per_page,
-                   self.n_lines - self.page_lines)
+        return min(p * self.rows_per_page,
+                   self.n_alloc - self.page_lines)
 
     def _valid_lines(self, p: int) -> int:
-        return self.n_lines - self._line0(p) if p == self.n_pages - 1 \
-            else self.lines_per_page
+        return self.n_alloc - self._line0(p) if p == self.n_pages - 1 \
+            else self.rows_per_page
 
     # -- schedule execution ------------------------------------------
     def flush_window(self, window) -> None:
@@ -371,7 +361,7 @@ class PageStore:
             raise RuntimeError(f"page schedule failed its own audit: "
                                f"{bad}")
         from .pallas.layout import comb_shape
-        window = jnp.zeros(comb_shape(self.n_lines, self.C), self.dtype)
+        window = jnp.zeros(comb_shape(self.n_alloc, self.C), self.dtype)
         upd = self._update_fn()
         bufs: List = [None, None]
         for kind, p, b in sched:
@@ -406,6 +396,5 @@ class PageStore:
             "page_lines": self.page_lines,
             "page_bytes": self.page_lines * self.C
             * np.dtype(self.dtype).itemsize,
-            "pack": self.pack,
             "C": self.C,
         }

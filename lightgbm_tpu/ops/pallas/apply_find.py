@@ -261,8 +261,8 @@ def _apply_find_pool_kernel(sel_i, sel_f, hs_ref, fmask_ref, consts_ref,
     XLA pool staging copies (2 x ~39 us) and the subtraction op chain.
     hs_ref holds the smaller child's histogram; sel_i[SEL_SMALL] says
     which side it is.  pool_out is HBM-aliased to pool_in and written
-    ONLY via manual DMA (the profile_legacy hbm_alias-verified
-    pattern), so untouched rows persist."""
+    ONLY via manual DMA (a pattern verified on the device in round
+    3), so untouched rows persist."""
     _copy_state_through(best_in, lstate_in, nodes_in, seg_in,
                         best_ref, lstate_ref, nodes_ref, seg_ref)
     leaf = sel_i[SEL_LEAF]

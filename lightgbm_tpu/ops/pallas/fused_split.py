@@ -55,8 +55,8 @@ kernel (bins at cols [0, f_pad), (g*w, h*w) at [f_pad, f_pad+2)).
 Trained trees must stay bit-identical to the unfused path: the
 accumulation visits the child's rows in the same ascending block order
 the comb-direct kernel does, masked instead of sliced.  The interpret
-builder COMPOSES the reference implementations (3-phase partition
-emulation + comb-direct histogram of the named child's range) so
+builder COMPOSES the reference implementations (the XLA reference
+partition + comb-direct histogram of the named child's range) so
 off-TPU tests exercise the fused orchestration with exactly the
 unfused arithmetic.
 """
@@ -72,18 +72,19 @@ from jax.experimental.pallas import tpu as pltpu
 from .hist_kernel2 import _LO_N, _diag_extract, _hist_accumulate, \
     build_histogram_comb, hist_geometry
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, SEL_SIDE, \
-    _go_left, make_partition as _make_partition3
+    _go_left, make_reference_partition
 from .partition_kernel2 import _scan_kernel, copyback_call
 
 _CHANNELS = 2       # (grad, hess) — the 2-channel histogram layout
 
 # VMEM budget, priced as TWO resident [ngroups, M, N] accumulators
-# although the one-sided hook keeps one: the predicate decides routes,
-# and widening it is not ISSUE 30's (the scan's four [R, C] buffers,
-# the permute compaction's three scoped ones - routing word + two
-# staging blocks, 768 KB at R = 512 - and the per-block one-hot
-# temporaries ride on top; cap conservatively below apply_find's
-# scoped-VMEM limit)
+# although the one-sided hook keeps one.  ROADMAP C9: the price is
+# stale, but the predicate decides routes, so it is re-priced together
+# with the routing matrix in the PR that does A3.1.  (The scan's four
+# [R, C] buffers, the permute compaction's three scoped ones - routing
+# word + two staging blocks, 768 KB at R = 512 - and the per-block
+# one-hot temporaries ride on top; cap conservatively below
+# apply_find's scoped-VMEM limit.)
 _HIST_VMEM_CAP = 32 * 1024 * 1024
 
 
@@ -102,61 +103,6 @@ def _side_flag(sel_ref, go_left, go_right):
     """The f32 0/1 row mask of the child ``sel[SEL_SIDE]`` names, from
     the two sides' masks (same shape, f32)."""
     return jnp.where(sel_ref[SEL_SIDE] > 0, go_left, go_right)
-
-
-def _fused_scan_kernel_p2(sel_ref, rows_in, scratch_in,
-                          rows_ref, scratch_ref, out_ref, hist_ref,
-                          vx0, vx1, skl0, skl1, skr0, skr1,
-                          carry_l, carry_r, cursor,
-                          sem_r, sem_wl, sem_wr,
-                          *, R: int, f_pad: int, b_hi: int, g: int,
-                          lo_n: int, ngroups: int):
-    """pack=2 twin of _fused_scan_kernel: partition_kernel3's
-    _scan_kernel_p2 + per-block histogram accumulation of the named
-    child through its trace-time hooks.  Each [P, 128] block holds
-    R = 2P logical rows; both lane halves are unpacked in register
-    (static lane slices) and pushed through the shared contraction,
-    even half first then odd — the same in-block order the pack=2
-    comb-direct histogram kernel uses."""
-    from .layout import PACK_W
-    from .partition_kernel3 import _scan_kernel_p2
-
-    def _hist_init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    def _hist_block(x, blk, cnt, par0):
-        P = R // 2
-        # split column of BOTH lane halves in one matvec (the
-        # _pack_permute2 construction; 2-D iotas only)
-        lane2 = jax.lax.broadcasted_iota(jnp.int32, (2 * PACK_W, 2), 0)
-        half2 = jax.lax.broadcasted_iota(jnp.int32, (2 * PACK_W, 2), 1)
-        e2 = (lane2 == sel_ref[SEL_FEAT] + half2 * PACK_W
-              ).astype(jnp.float32)                      # [128, 2]
-        col2 = jax.lax.dot_general(
-            x.astype(jnp.float32), e2, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [P, 2]
-        line = jax.lax.broadcasted_iota(jnp.int32, (P, 1), 0)
-        for h, h0 in ((0, 0), (1, PACK_W)):
-            rel = blk * R + 2 * line + h - par0
-            vmask = (rel >= 0) & (rel < cnt)
-            gl = _go_left(col2[:, h:h + 1], sel_ref) & vmask
-            gr = jnp.logical_xor(gl, vmask)
-            # Mosaic has no direct bf16 -> i32 cast; hop through f32
-            bins_i = (x[:, h0:h0 + f_pad].astype(jnp.float32)
-                      .astype(jnp.int32))
-            v = (x[:, h0 + f_pad:h0 + f_pad + _CHANNELS]
-                 .astype(jnp.float32))
-            flag = _side_flag(sel_ref, gl.astype(jnp.float32),
-                              gr.astype(jnp.float32))
-            _hist_accumulate(bins_i, v * flag, hist_ref, b_hi=b_hi, g=g,
-                             c=_CHANNELS, lo_n=lo_n, ngroups=ngroups)
-
-    _scan_kernel_p2(sel_ref, rows_in, scratch_in,
-                    rows_ref, scratch_ref, out_ref,
-                    vx0, vx1, skl0, skl1, skr0, skr1,
-                    carry_l, carry_r, cursor,
-                    sem_r, sem_wl, sem_wr,
-                    R=R, init_cb=_hist_init, block_cb=_hist_block)
 
 
 def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
@@ -218,7 +164,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                      interpret: bool = False, dynamic: bool = False,
                      cb_block: int = 2048, hist_rpb: int = 2048,
                      scan: str = "permute",
-                     interpret_kernel: bool = False, pack: int = 1,
+                     interpret_kernel: bool = False,
                      fused_kernel_interpret: bool = False):
     """Build ``fused(sel, rows, scratch[, grid_blocks]) -> (rows, scratch,
     nleft, h_side)`` — the single-scan partition contract of
@@ -231,15 +177,6 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     LGBM_TPU_PARTITION default) or ``"matmul"`` (the one-hot
     contraction).  Both produce bit-identical packed layouts, so the
     histogram hook and everything downstream are scheme-blind.
-
-    ``pack=2`` runs the two-logical-rows-per-line scan
-    (partition_kernel3._scan_kernel_p2; ``n``/``size``/``sel``/
-    ``nleft`` stay LOGICAL, rows/scratch are [n // 2, 128] packed) with
-    the histogram hook unpacking both lane halves in register —
-    half the partition DMA bytes per logical row.  pack=2 routing is
-    permutation-only; the ``scan`` knob is accepted and ignored there
-    (both pack=1 schemes produce the identical layout the pack=2
-    kernel reproduces in the logical domain).
 
     The interpret path COMPOSES the reference pieces (partition
     emulation, then the comb-direct histogram of the named child's
@@ -256,13 +193,11 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     check_lane_width(C, dtype)
     if scan not in ("matmul", "permute"):
         raise ValueError(f"unknown scan scheme {scan!r}")
-    if pack not in (1, 2):
-        raise ValueError(f"pack must be 1 or 2, got {pack}")
     b = int(padded_bins)
     b_hi, g, m, nn = hist_geometry(b, _CHANNELS)
     assert f_pad % g == 0, (f_pad, g)
     ngroups = f_pad // g
-    if pack == 1 and scan == "permute":
+    if scan == "permute":
         # shared validated hook (power-of-two R precondition lives in
         # exactly one place; the XOR-reversal rounds are only a
         # permutation for pow2 R)
@@ -270,22 +205,12 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
         _pack = perm_pack_impl(R, C)
     else:
         _pack = None
-    if pack == 2 and fused_kernel_interpret:
-        return _make_fused_p2(n, R=R, size=size, dtype=dtype,
-                              dynamic=dynamic, cb_block=cb_block,
-                              f_pad=f_pad, b=b, b_hi=b_hi, g=g, m=m,
-                              nn=nn, ngroups=ngroups, interpret=True)
     if fused_kernel_interpret and dynamic:
         raise ValueError(
             "fused_kernel_interpret supports static grids only (the "
             "Pallas interpreter cannot run a traced grid bound)")
     if interpret and not fused_kernel_interpret:
-        if pack == 2:
-            from .partition_kernel3 import make_partition_p2
-            part = make_partition_p2(
-                n, R=R, size=size, dtype=dtype, interpret=True,
-                interpret_kernel=interpret_kernel, cb_block=cb_block)
-        elif interpret_kernel:
+        if interpret_kernel:
             if scan == "permute":
                 from .partition_kernel3 import make_partition_perm
                 part = make_partition_perm(
@@ -297,8 +222,8 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                     n, C, R=R, size=size, dtype=dtype, interpret=True,
                     dynamic=dynamic, interpret_kernel=True)
         else:
-            part = _make_partition3(n, C, R=R, size=size, dtype=dtype,
-                                    interpret=True, dynamic=dynamic)
+            part = make_reference_partition(n, C, dtype=dtype,
+                                            dynamic=dynamic)
         # the compiled path sizes its grids dynamically and ignores
         # ``size``; the interpret reference needs the real static bound
         # (build_histogram_comb scans at most ceil(size/rpb)+1 blocks,
@@ -310,7 +235,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
             return build_histogram_comb(
                 rows1, start, jnp.int32(0), count, f_pad=f_pad,
                 size=h_size, padded_bins=b, rows_per_block=hist_rpb,
-                interpret=True, pack=pack, planes=comb_planes(C))
+                interpret=True, planes=comb_planes(C))
 
         def _fused_i(sel, rows, scratch, *gb):
             rows1, scratch1, nleft = part(sel, rows, scratch, *gb)
@@ -328,11 +253,6 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                 return _fused_i(sel, rows, scratch)
         return fused
 
-    if pack == 2:
-        return _make_fused_p2(n, R=R, size=size, dtype=dtype,
-                              dynamic=dynamic, cb_block=cb_block,
-                              f_pad=f_pad, b=b, b_hi=b_hi, g=g, m=m,
-                              nn=nn, ngroups=ngroups)
     nblocks = max((size + R - 1) // R, 1)
     kern = functools.partial(_fused_scan_kernel, R=R, C=C, n=n,
                              f_pad=f_pad, b_hi=b_hi, g=g, lo_n=_LO_N,
@@ -385,83 +305,8 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     return fused
 
 
-def _make_fused_p2(n: int, *, R: int, size: int, dtype, dynamic: bool,
-                   cb_block: int, f_pad: int, b: int, b_hi: int, g: int,
-                   m: int, nn: int, ngroups: int,
-                   interpret: bool = False):
-    """Compiled pack=2 fused split: the pack=2 scan's pallas_call
-    (scratch/carry/cursor shapes from make_partition_p2) extended with
-    the resident histogram accumulator output."""
-    from .layout import LANE, PACK_W
-    from .partition_kernel3 import copyback_call_p2
-    if n % 2 or R % 2:
-        raise ValueError(f"pack=2 needs even n and R (got {n}, {R})")
-    if R & (R - 1):
-        raise ValueError(f"pack=2 routing needs power-of-two R={R}")
-    if f_pad + _CHANNELS > PACK_W:
-        raise ValueError(
-            f"pack=2 fused split needs f_pad + {_CHANNELS} <= {PACK_W} "
-            f"(got {f_pad})")
-    P = R // 2
-    np_phys = n // 2
-    nblocks = max((size + R - 1) // R + 1, 1)  # +1: head-parity spill
-    kern = functools.partial(_fused_scan_kernel_p2, R=R, f_pad=f_pad,
-                             b_hi=b_hi, g=g, lo_n=_LO_N,
-                             ngroups=ngroups)
-
-    def _call(sel, rows, scratch, grid_blocks):
-        rows1, scratch1, res, hist = pl.pallas_call(
-            kern,
-            name="lgbm_split_scan",
-            grid=(grid_blocks,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                      pl.BlockSpec(memory_space=_HBM),
-                      pl.BlockSpec(memory_space=_HBM)],
-            out_specs=[pl.BlockSpec(memory_space=_HBM),
-                       pl.BlockSpec(memory_space=_HBM),
-                       pl.BlockSpec(memory_space=pltpu.SMEM),
-                       pl.BlockSpec((ngroups, m, nn),
-                                    lambda i: (0, 0, 0),
-                                    memory_space=pltpu.VMEM)],
-            out_shape=[jax.ShapeDtypeStruct((np_phys, LANE), dtype),
-                       jax.ShapeDtypeStruct((np_phys, LANE), dtype),
-                       jax.ShapeDtypeStruct((2,), jnp.int32),
-                       jax.ShapeDtypeStruct((ngroups, m, nn),
-                                            jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((P, LANE), dtype),
-                            pltpu.VMEM((P, LANE), dtype),
-                            pltpu.VMEM((P + 1, LANE), dtype),
-                            pltpu.VMEM((P + 1, LANE), dtype),
-                            pltpu.VMEM((P + 1, LANE), dtype),
-                            pltpu.VMEM((P + 1, LANE), dtype),
-                            pltpu.VMEM((1, LANE), dtype),
-                            pltpu.VMEM((1, LANE), dtype),
-                            pltpu.SMEM((8,), jnp.int32),
-                            pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.SemaphoreType.DMA,
-                            pltpu.SemaphoreType.DMA],
-            input_output_aliases={1: 0, 2: 1},
-            interpret=interpret,
-        )(sel, rows, scratch)
-        nleft, mm = res[0], res[1]
-        rows2 = copyback_call_p2(sel, rows1, scratch1, nleft, mm, R=R,
-                                 cb_block=cb_block, n=n, dtype=dtype,
-                                 interpret=interpret)
-        return rows2, scratch1, nleft, _diag_extract(
-            hist, ngroups, g, b_hi, _CHANNELS, _LO_N, f_pad, b)
-
-    if dynamic:
-        def fused(sel, rows, scratch, grid_blocks):
-            return _call(sel, rows, scratch, grid_blocks)
-    else:
-        def fused(sel, rows, scratch):
-            return _call(sel, rows, scratch, nblocks)
-
-    return fused
-
-
 # ---- static-analysis registration (lightgbm_tpu/analysis, ISSUE 7) ----
-from ...analysis.registry import partition_args, register_kernel, sds
+from ...analysis.registry import partition_args, register_kernel
 
 
 @register_kernel("fused_split", kind="fused",
@@ -482,29 +327,3 @@ def _analysis_fused_cat():
     fn = make_fused_split(n, C, f_pad=f, padded_bins=b, R=512,
                           size=2048)
     return fn, partition_args(n, C, sel_words=CAT_BITSET_WORDS)
-
-
-@register_kernel("fused_split_p2", kind="fused", pack=2,
-                 note="pack=2 fused scan + child-histogram hook")
-def _analysis_fused_p2():
-    import jax.numpy as jnp
-    n, f, b = 7168, 16, 32      # n LOGICAL rows over [n//2, 128] lines
-    fn = make_fused_split(n, 128, f_pad=f, padded_bins=b, R=512,
-                          size=2048, pack=2)
-    return fn, (sds((8,), jnp.int32),
-                sds((n // 2, 128), jnp.float32),
-                sds((n // 2, 128), jnp.float32))
-
-
-@register_kernel("fused_split_p2_cat", kind="fused", pack=2,
-                 note="pack=2 fused scan, cat-subset bitset sel "
-                      "(ISSUE 16)")
-def _analysis_fused_p2_cat():
-    import jax.numpy as jnp
-    from .layout import CAT_BITSET_WORDS
-    n, f, b = 7168, 16, 32      # n LOGICAL rows over [n//2, 128] lines
-    fn = make_fused_split(n, 128, f_pad=f, padded_bins=b, R=512,
-                          size=2048, pack=2)
-    return fn, (sds((8 + CAT_BITSET_WORDS,), jnp.int32),
-                sds((n // 2, 128), jnp.float32),
-                sds((n // 2, 128), jnp.float32))

@@ -143,36 +143,6 @@ def _hist2_comb_kernel(sel_ref, comb_ref, out_ref, *, b_hi, g, c, lo_n,
                      ngroups=ngroups)
 
 
-def _hist2_comb2_kernel(sel_ref, comb_ref, out_ref, *, b_hi, g, c, lo_n,
-                        ngroups, f_pad, rpb):
-    """pack=2 comb-direct variant (layout.comb_layout pack=2): the
-    block is [rpb, 128] PHYSICAL lines holding 2*rpb logical rows —
-    logical row 2p in lanes [0, 64) of line p, row 2p+1 in lanes
-    [64, 128).  Both lane halves are unpacked IN REGISTER (static lane
-    slices, no unpacked HBM copy anywhere) and accumulated through the
-    same nibble one-hot contraction, even half first then odd.
-    sel = (start_block, off, count) with off/count in LOGICAL rows
-    relative to the block-aligned start."""
-    from .layout import PACK_W
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    rows = comb_ref[:]                          # [rpb, 128] lines
-    off, cnt = sel_ref[1], sel_ref[2]
-    pos_e = (pl.program_id(0) * (2 * rpb)
-             + 2 * jax.lax.broadcasted_iota(jnp.int32, (rpb, 1), 0))
-    for h0, pos in ((0, pos_e), (PACK_W, pos_e + 1)):
-        b = (rows[:, h0:h0 + f_pad].astype(jnp.float32)
-             .astype(jnp.int32))
-        live = ((pos >= off) & (pos < off + cnt)).astype(jnp.float32)
-        v = (rows[:, h0 + f_pad:h0 + f_pad + c].astype(jnp.float32)
-             * live)
-        _hist_accumulate(b, v, out_ref, b_hi=b_hi, g=g, c=c, lo_n=lo_n,
-                         ngroups=ngroups)
-
-
 def _diag_extract(out, ngroups, g, b_hi, c, lo_n, f_pad, b):
     """Diagonal (same-feature) block extraction shared by both kernels."""
     out = out.reshape(ngroups, g, b_hi, g, c, lo_n)
@@ -183,45 +153,36 @@ def _diag_extract(out, ngroups, g, b_hi, c, lo_n, f_pad, b):
 
 
 def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
-                    interpret, channels=2, pack=1, planes=1):
+                    interpret, channels=2, planes=1):
     """Shared tail of the comb-direct histogram: start-block clamp (both
     ways — a garbage-negative start from a dead partition call must not
     become an OOB DMA), scalar-prefetch grid, diagonal extraction.
     ``nblocks`` may be a python int (static grid) or a traced scalar
-    (Mosaic dynamic grid).  ``rpb`` counts LOGICAL rows per block; under
-    ``pack=2`` each block is rpb // 2 physical lines of the packed comb
-    and the kernel unpacks the lane halves in register."""
-    from .layout import (LANE, PACK_W, check_lane_width, comb_block_spec,
+    (Mosaic dynamic grid).  ``rpb`` counts rows per block."""
+    from .layout import (LANE, check_lane_width, comb_block_spec,
                          comb_operand)
-    # the comb is plane-major (layout.py): ``planes`` x [n_phys, 128]
-    n_phys, C = comb.shape[0] // planes, planes * LANE
+    # the comb is plane-major (layout.py): ``planes`` x [n_rows, 128]
+    n_rows, C = comb.shape[0] // planes, planes * LANE
     check_lane_width(comb.shape[1], comb.dtype)
-    if pack == 2 and f_pad + channels > PACK_W:
-        raise ValueError(
-            f"pack=2 comb histogram needs f_pad + {channels} <= "
-            f"{PACK_W} logical columns (got {f_pad}); the even half "
-            f"would read into the odd half's lanes")
     c = channels
     lo_n = _LO_N
     b_hi, g, m, nn = hist_geometry(b, c)
     assert f_pad % g == 0, (f_pad, g)
     ngroups = f_pad // g
-    rpb_p = rpb // pack            # physical lines per block
     start_blk = start // rpb
     off_total = off + (start - start_blk * rpb)
-    max_blk = jnp.maximum(n_phys // rpb_p - nblocks, 0)
+    max_blk = jnp.maximum(n_rows // rpb - nblocks, 0)
     start_blk_c = jnp.clip(start_blk, 0, max_blk)
     off_total = off_total + (start_blk - start_blk_c) * rpb
     sel = jnp.stack([start_blk_c, off_total, count]).astype(jnp.int32)
 
-    kern_fn = _hist2_comb2_kernel if pack == 2 else _hist2_comb_kernel
     kern = functools.partial(
-        kern_fn, b_hi=b_hi, g=g, c=c, lo_n=lo_n,
-        ngroups=ngroups, f_pad=f_pad, rpb=rpb_p)
+        _hist2_comb_kernel, b_hi=b_hi, g=g, c=c, lo_n=lo_n,
+        ngroups=ngroups, f_pad=f_pad, rpb=rpb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nblocks,),
-        in_specs=[comb_block_spec(rpb_p, C, lambda i, s: s[0] + i,
+        in_specs=[comb_block_spec(rpb, C, lambda i, s: s[0] + i,
                                   memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((ngroups, m, nn), lambda i, s: (0, 0, 0),
                                memory_space=pltpu.VMEM),
@@ -236,19 +197,16 @@ def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
     return _diag_extract(out, ngroups, g, b_hi, c, lo_n, f_pad, b)
 
 
-def _comb_rpb(rows_per_block: int, cap: int, pack: int) -> int:
-    """Logical rows per block, honouring Mosaic's 8-sublane rule on the
-    PHYSICAL line count (pack=2 blocks are rows // 2 lines)."""
-    rpb = min(rows_per_block, max(cap, 8 * pack))
-    rpb_p = max(((rpb // pack) // 8) * 8, 8)
-    return rpb_p * pack
+def _comb_rpb(rows_per_block: int, cap: int) -> int:
+    """Rows per block, honouring Mosaic's 8-sublane rule."""
+    rpb = min(rows_per_block, max(cap, 8))
+    return max((rpb // 8) * 8, 8)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "f_pad", "padded_bins", "rows_per_block", "interpret", "pack",
-    "planes"))
+    "f_pad", "padded_bins", "rows_per_block", "interpret", "planes"))
 def build_histogram_comb_dyn(
-    comb: jnp.ndarray,       # plane-major [planes * n_alloc // pack, 128]
+    comb: jnp.ndarray,       # plane-major [planes * n_alloc, 128]
     start: jnp.ndarray,      # i32 scalar: first row of the parent range
     off: jnp.ndarray,        # i32 scalar: valid rows begin at start+off...
     count: jnp.ndarray,      # ...and span count rows
@@ -257,7 +215,6 @@ def build_histogram_comb_dyn(
     padded_bins: int,
     rows_per_block: int = 2048,
     interpret: bool = False,
-    pack: int = 1,
     planes: int = 1,
 ) -> jnp.ndarray:
     """Dynamic-grid variant of build_histogram_comb: the block count is a
@@ -265,21 +222,20 @@ def build_histogram_comb_dyn(
     one kernel instance serves every parent size — no ``lax.switch``
     over static bucket classes (XLA copies the whole aliased row matrix
     per branch per split otherwise) and no masked overhang blocks
-    (static classes run up to 2x the parent rows).  ``start``/``off``/
-    ``count`` are LOGICAL rows at every pack."""
-    n_phys = comb.shape[0] // planes
-    rpb = _comb_rpb(rows_per_block, n_phys * pack, pack)
+    (static classes run up to 2x the parent rows)."""
+    n_rows = comb.shape[0] // planes
+    rpb = _comb_rpb(rows_per_block, n_rows)
     nblocks = jnp.maximum(-(-count // rpb) + 1, 1)
     return _comb_hist_call(comb, start, off, count, nblocks,
                            f_pad=f_pad, b=int(padded_bins), rpb=rpb,
-                           interpret=interpret, pack=pack, planes=planes)
+                           interpret=interpret, planes=planes)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "f_pad", "size", "padded_bins", "rows_per_block", "interpret",
-    "pack", "planes"))
+    "planes"))
 def build_histogram_comb(
-    comb: jnp.ndarray,       # plane-major [planes * n_alloc // pack, 128]
+    comb: jnp.ndarray,       # plane-major [planes * n_alloc, 128]
     start: jnp.ndarray,      # i32 scalar: first row of the parent range
     off: jnp.ndarray,        # i32 scalar: valid rows begin at start+off...
     count: jnp.ndarray,      # ...and span count rows
@@ -289,30 +245,26 @@ def build_histogram_comb(
     padded_bins: int,
     rows_per_block: int = 2048,
     interpret: bool = False,
-    pack: int = 1,
     planes: int = 1,
 ) -> jnp.ndarray:
     """Histogram of comb rows [start+off, start+off+count) WITHOUT
     materialising any sliced copy: the kernel reads [R, C] blocks of the
     row matrix directly (dynamic block offset via scalar prefetch) and
     slices bins/value lanes in VMEM.  The bucket path previously paid
-    three lane-padded slice copies (512 B/row each) per split.  With
-    ``pack=2`` the comb holds two logical rows per 128-lane line and
-    the kernel unpacks them in register — half the HBM bytes per
-    logical row; ``start``/``off``/``count``/``size`` stay logical."""
-    n_phys = comb.shape[0] // planes
-    rpb = _comb_rpb(rows_per_block, size, pack)
+    three lane-padded slice copies (512 B/row each) per split."""
+    n_rows = comb.shape[0] // planes
+    rpb = _comb_rpb(rows_per_block, size)
     # block-align the dynamic start: one extra block covers the head
     # misalignment, the off/count window masks the rest
     nblocks = -(-size // rpb) + 1
-    if n_phys * pack < nblocks * rpb:
+    if n_rows < nblocks * rpb:
         raise ValueError(
-            f"comb needs >= {nblocks * rpb} logical rows for bucket "
+            f"comb needs >= {nblocks * rpb} rows for bucket "
             f"size {size} at rows_per_block {rpb} (got "
-            f"{n_phys * pack}); pad the row matrix")
+            f"{n_rows}); pad the row matrix")
     return _comb_hist_call(comb, start, off, count, nblocks,
                            f_pad=f_pad, b=int(padded_bins), rpb=rpb,
-                           interpret=interpret, pack=pack, planes=planes)
+                           interpret=interpret, planes=planes)
 
 
 @functools.partial(jax.jit, static_argnames=("padded_bins", "rows_per_block",
@@ -389,16 +341,4 @@ def _analysis_hist_comb():
         return build_histogram_comb(comb, start, off, count, f_pad=f,
                                     size=2048, padded_bins=b)
     return fn, (sds((n, C), jnp.float32), sds((), jnp.int32),
-                sds((), jnp.int32), sds((), jnp.int32))
-
-
-@register_kernel("hist_comb_p2", kind="hist", pack=2,
-                 note="pack=2 comb-direct histogram (both lane halves "
-                      "unpacked in register)")
-def _analysis_hist_comb_p2():
-    n, C, f, b = 7168, 128, 16, 32   # n LOGICAL rows, packed n//2 lines
-    def fn(comb, start, off, count):
-        return build_histogram_comb(comb, start, off, count, f_pad=f,
-                                    size=2048, padded_bins=b, pack=2)
-    return fn, (sds((n // 2, C), jnp.float32), sds((), jnp.int32),
                 sds((), jnp.int32), sds((), jnp.int32))

@@ -12,32 +12,23 @@ it because the 64-lane branch was TPU-only.  Every kernel builder that
 DMA-slices comb rows now validates its width HERE, and
 tests/test_partition_perm.py::TestLaneContract pins the rule off-chip.
 
-Also the home of ``comb_layout`` — the (C, pack, dtype) decision the
-ISSUE-3 pack-aware data path threads through ops/grow.py,
-ops/device_data.py and the partition kernels:
-
-* ``pack=1``: one logical row per line of C lanes; C is the column
-  count rounded up to a multiple of 128.  In HBM the comb is stored
-  PLANE-MAJOR (ISSUE 29): plane p holds lanes [128 p, 128 p + 128) of
-  every row as an [n, 128] matrix, and the C // 128 planes lie one
-  after the other in ONE [C // 128 * n, 128] array (``to_planes`` /
-  ``to_rows``).  A [n, 256] f32 array is tiled (8, 128) in HBM, so a
-  row DMA at an arbitrary row offset - every segment start of the
-  partition scan - is refused by Mosaic ("tile index in dimension 0
-  is divisible by the tiling (8)"); a [n, 128] matrix is linear in
-  memory and takes any row offset.  With one plane (C = 128) the
-  plane-major array IS the [n, 128] row matrix.  In VMEM a block stays
-  [R, C]: the kernels move it as C // 128 row DMAs, one a plane, into
-  and out of its 128-lane column tiles (``plane_copies``), or take it
-  through a [C // 128, R, 128] block of the free 3-D view
-  (``comb_block_spec`` / ``load_rows`` / ``store_rows``).
-* ``pack=2``: TWO logical rows per 128-lane line (logical row 2p in
-  lanes [0, 64), row 2p+1 in lanes [64, 128) of physical line p).
-  Halves partition DMA bytes per logical row while every physical
-  memref stays 128-wide f32/(1,128)-tiled — the half-width scheme that
-  is legal under today's Mosaic tiling rules, unlike a [n, 64] memref
-  (lever #4) or bf16 storage (dynamic row offsets fail the (8,128)x2
-  "tile index divisible by 8" proof; see ops/grow.py).
+Also the one place that says how a logical row lies in the comb
+(``comb_layout`` gives the line width; ops/grow.py, obs/costmodel.py
+and every kernel builder take it from here): ONE logical row a line of
+C lanes, C the column count rounded up to a multiple of 128.  In HBM
+the comb is stored PLANE-MAJOR (ISSUE 29): plane p holds lanes [128 p,
+128 p + 128) of every row as an [n, 128] matrix, and the C // 128
+planes lie one after the other in ONE [C // 128 * n, 128] array
+(``to_planes`` / ``to_rows``).  A [n, 256] f32 array is tiled (8, 128)
+in HBM, so a row DMA at an arbitrary row offset - every segment start
+of the partition scan - is refused by Mosaic ("tile index in dimension
+0 is divisible by the tiling (8)"); a [n, 128] matrix is linear in
+memory and takes any row offset.  With one plane (C = 128) the
+plane-major array IS the [n, 128] row matrix.  In VMEM a block stays
+[R, C]: the kernels move it as C // 128 row DMAs, one a plane, into and
+out of its 128-lane column tiles (``plane_copies``), or take it through
+a [C // 128, R, 128] block of the free 3-D view (``comb_block_spec`` /
+``load_rows`` / ``store_rows``).
 """
 from __future__ import annotations
 
@@ -46,7 +37,6 @@ import jax.numpy as jnp
 
 LANE = 128          # TPU minor-dim tile: every HBM row DMA moves
                     # multiples of this many lanes
-PACK_W = LANE // 2  # logical row width under pack=2
 
 # Physical comb width budget (ISSUE 12, the EFB graduation).  The
 # comb-direct kernels stream [R, C] blocks through VMEM, so C is
@@ -296,20 +286,8 @@ def store_rows(ref, x):
         ref[p] = x[:, p * LANE:(p + 1) * LANE]
 
 
-def comb_layout(n_cols: int, *, pack: int = 1, dtype=jnp.float32):
-    """Physical line layout for a comb matrix with ``n_cols`` logical
-    columns: returns ``(C, pack)`` where C is the 128-lane-aligned
-    physical line width.  ``pack=2`` packs two logical rows per line
-    and requires ``n_cols <= 64`` (each logical row rides one lane
-    half); callers store logical row 2p at lanes [0, 64) and 2p+1 at
-    lanes [64, 128) of physical line p."""
-    if pack not in (1, 2):
-        raise ValueError(f"pack must be 1 or 2, got {pack}")
-    if pack == 2:
-        if n_cols > PACK_W:
-            raise ValueError(
-                f"pack=2 needs <= {PACK_W} logical columns per row "
-                f"(got {n_cols}); fall back to pack=1")
-        return check_lane_width(LANE, dtype), 2
+def comb_layout(n_cols: int, dtype=jnp.float32) -> int:
+    """Line width C of a comb with ``n_cols`` logical columns: the
+    column count rounded up to whole 128-lane planes."""
     C = LANE * ((max(int(n_cols), 1) + LANE - 1) // LANE)
-    return check_lane_width(C, dtype), 1
+    return check_lane_width(C, dtype)

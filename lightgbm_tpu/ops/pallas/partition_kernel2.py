@@ -1,10 +1,10 @@
 """Pallas TPU kernel: single-scan two-sided in-place row partition.
 
-Supersedes the 3-phase kernel in partition_kernel.py (kept for
-reference/bisection).  That design read the parent's rows TWICE (one
+A split compacts the parent's contiguous row range of the comb into
+left|right.  The design this replaced read the parent's rows TWICE (one
 scan keeping left, one keeping right), compacted through carry windows
 so every DMA write held only valid rows, and then copied the whole
-partitioned range back from scratch — 3 full DMA passes, two [2R, R]
+partitioned range back from scratch - 3 full DMA passes, two [2R, R]
 compaction matmuls per block, and inline DMA waits everywhere.
 
 This kernel does ONE scan with OVERLAPPING full-R writes and a SINGLE
@@ -81,7 +81,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .layout import (check_lane_width, comb_shape, hbm_copies,
                      plane_copies)
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, \
-    _go_left, make_partition as _make_partition3
+    _go_left, make_reference_partition
 
 # cursor SMEM i32[8] slots
 _CUR_L, _CUR_TL, _CUR_R = 0, 1, 2
@@ -361,13 +361,14 @@ def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
                       dtype=jnp.float32, interpret: bool = False,
                       dynamic: bool = False, cb_block: int = 2048,
                       pack_impl=None, interpret_kernel: bool = False):
-    """Single-scan partition with the same signature/contract as
-    partition_kernel.make_partition (the copyback sub-call is hidden
-    inside the returned function).  The interpret path reuses the
-    3-phase builder's XLA emulation, which is STABLE — the compiled
-    kernel packs right-segment rows in reverse, so the two agree on
-    segment membership/counts but NOT on row order within the right
-    segment.  Nothing downstream may depend on intra-segment order.
+    """Single-scan partition: ``partition(sel, rows, scratch[,
+    grid_blocks]) -> (rows', scratch', nleft)``, the contract of
+    partition_kernel.make_reference_partition (the copyback sub-call is
+    hidden inside the returned function).  The interpret path IS that
+    XLA reference, which is STABLE — the compiled kernel packs
+    right-segment rows in reverse, so the two agree on segment
+    membership/counts but NOT on row order within the right segment.
+    Nothing downstream may depend on intra-segment order.
 
     ``interpret_kernel=True`` (with ``interpret=True``) instead runs
     the REAL scan + copyback kernels through the Pallas interpreter —
@@ -382,8 +383,8 @@ def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
     permutation packing through here so the schedule has one home."""
     check_lane_width(C, dtype)
     if interpret and not interpret_kernel:
-        return _make_partition3(n, C, R=R, size=size, dtype=dtype,
-                                interpret=True, dynamic=dynamic)
+        return make_reference_partition(n, C, dtype=dtype,
+                                        dynamic=dynamic)
     if interpret_kernel and dynamic:
         raise ValueError(
             "interpret_kernel supports static grids only (the Pallas "

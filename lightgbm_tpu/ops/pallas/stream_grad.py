@@ -191,134 +191,6 @@ def _refresh_kernel(lv_ref, comb_in, comb_ref, *, kind: str, sigmoid: float,
     return x, g, h
 
 
-def _refresh_kernel_p2(lv_ref, comb_in, comb_ref, *, kind: str,
-                       sigmoid: float, f: int, P: int, C: int, nc: int):
-    """pack=2 refresh: the block is [P, C] PHYSICAL lines holding 2P
-    logical rows (layout.comb_layout pack=2 — logical row 2p in lanes
-    [0, C/2), row 2p+1 in lanes [C/2, C)).  Both halves' score/const
-    columns ride the SAME extract/writeback matmuls (the column lists
-    just carry both lane-half offsets), so the per-line matmul count
-    matches pack=1 while each line refreshes TWO logical rows.
-    ``lv_ref`` is [2, P]: row 0 the even-half score deltas, row 1 the
-    odd (pre-split by the wrapper — a strided in-kernel lane split
-    would relayout).  Returns (x, [(g, h, sh, sm, sl)] per half) for
-    the fused root-histogram variant."""
-    x = comb_in[:].astype(jnp.float32)                   # [P, C]
-    half = C // 2
-    base = ([COL_SC, COL_SC + 1, COL_SC + 2, COL_CNT]
-            + [COL_CONSTS + i for i in range(nc)])
-    K = len(base)
-    cols = ([f + c for c in base]
-            + [half + f + c for c in base])
-    V = _extract(x, cols, C=C)                           # [2K, P]
-    outs = []
-    rows, dst = [], []
-    for h in range(2):
-        Vh = V[h * K:(h + 1) * K]
-        s = Vh[0:1] + Vh[1:2] + Vh[2:3] + lv_ref[h:h + 1]
-        cnt = Vh[3:4]
-        consts = [Vh[4 + i:5 + i] for i in range(nc)]
-        g, hs = _grad_core(kind, sigmoid, s, cnt, consts)
-        sh, sm, sl = split_bf16_3(s, mosaic=True)
-        g = g.astype(jnp.bfloat16).astype(jnp.float32)
-        hs = hs.astype(jnp.bfloat16).astype(jnp.float32)
-        outs.append((g, hs))
-        rows += [g, hs, sh, sm, sl]
-        hb = h * half + f
-        dst += [hb + COL_G, hb + COL_H, hb + COL_SC,
-                hb + COL_SC + 1, hb + COL_SC + 2]
-    comb_ref[:] = _writeback(x, rows, dst, R=P, C=C).astype(
-        comb_ref.dtype)
-    return x, outs
-
-
-def _refresh_hist_kernel_p2(lv_ref, comb_in, comb_ref, hist_ref, *,
-                            kind: str, sigmoid: float, f: int, P: int,
-                            C: int, nc: int, b_hi: int, hg: int,
-                            lo_n: int, ngroups: int):
-    """pack=2 twin of _refresh_hist_kernel: refresh + next tree's root
-    histogram, both lane halves unpacked in register (even half
-    accumulated first, then odd — the comb-direct kernel's order)."""
-    from .hist_kernel2 import _hist_accumulate
-    x, outs = _refresh_kernel_p2(lv_ref, comb_in, comb_ref, kind=kind,
-                                 sigmoid=sigmoid, f=f, P=P, C=C, nc=nc)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-
-    half = C // 2
-    for h, (g, hs) in enumerate(outs):
-        v = _transpose_lanes([g, hs], R=P)               # [P, 2]
-        bins_i = x[:, h * half:h * half + f].astype(jnp.int32)
-        _hist_accumulate(bins_i, v, hist_ref, b_hi=b_hi, g=hg, c=2,
-                         lo_n=lo_n, ngroups=ngroups)
-
-
-def _init_kernel_p2(bins_ref, aux_ref, comb_in, comb_ref, *, kind: str,
-                    sigmoid: float, f_real: int, f: int, P: int, C: int,
-                    nc: int):
-    """pack=2 twin of _init_kernel: populate [P, C] packed lines from a
-    [2P, f_real] logical u8 bin block and pre-split aux lanes
-    ([2 * k_aux, P]: even-half rows first).  Even/odd logical rows are
-    separated with constant selection matmuls (strided sublane reads
-    would relayout); all values stay bf16-exact so the MXU passes are
-    exact."""
-    del comb_in  # aliased for the untouched slack lines only
-    half = C // 2
-    R2 = 2 * P
-    binsf = bins_ref[:].astype(jnp.int32).astype(jnp.float32)  # [2P, fr]
-    rcol = jax.lax.broadcasted_iota(jnp.int32, (P, R2), 1)
-    prow = jax.lax.broadcasted_iota(jnp.int32, (P, R2), 0)
-    sel_e = (rcol == 2 * prow).astype(jnp.float32)
-    sel_o = (rcol == 2 * prow + 1).astype(jnp.float32)
-    be = jax.lax.dot_general(                            # [P, f_real]
-        sel_e, binsf, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    bo = jax.lax.dot_general(
-        sel_o, binsf, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    sub_b = jax.lax.broadcasted_iota(jnp.int32, (f_real, C), 0)
-    lane_b = jax.lax.broadcasted_iota(jnp.int32, (f_real, C), 1)
-    Pb_e = (lane_b == sub_b).astype(jnp.float32)
-    Pb_o = (lane_b == sub_b + half).astype(jnp.float32)
-    base = (jax.lax.dot_general(
-        be, Pb_e, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-        + jax.lax.dot_general(
-        bo, Pb_o, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32))             # [P, C]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (P, C), 1)
-    pos_e = (pl.program_id(0) * R2
-             + 2 * jax.lax.broadcasted_iota(jnp.int32, (P, C), 0))
-    for h0, pos in ((0, pos_e), (half, pos_e + 1)):
-        base = base + jnp.where(lane == h0 + f + COL_RID,
-                                (pos // 65536).astype(jnp.float32), 0.0)
-        base = base + jnp.where(lane == h0 + f + COL_RID + 1,
-                                ((pos // 256) % 256).astype(jnp.float32),
-                                0.0)
-        base = base + jnp.where(lane == h0 + f + COL_RID + 2,
-                                (pos % 256).astype(jnp.float32), 0.0)
-    k_aux = 2 + nc
-    rows, dst = [], []
-    for h in range(2):
-        a0 = h * k_aux
-        s = aux_ref[a0:a0 + 1]
-        cnt = aux_ref[a0 + 1:a0 + 2]
-        consts = [aux_ref[a0 + 2 + i:a0 + 3 + i] for i in range(nc)]
-        g, hs = _grad_core(kind, sigmoid, s, cnt, consts)
-        sh, sm, sl = split_bf16_3(s, mosaic=True)
-        g = g.astype(jnp.bfloat16).astype(jnp.float32)
-        hs = hs.astype(jnp.bfloat16).astype(jnp.float32)
-        rows += [g, hs, cnt, sh, sm, sl] + consts
-        hb = h * half + f
-        dst += ([hb + COL_G, hb + COL_H, hb + COL_CNT, hb + COL_SC,
-                 hb + COL_SC + 1, hb + COL_SC + 2]
-                + [hb + COL_CONSTS + i for i in range(nc)])
-    comb_ref[:] = _writeback(base, rows, dst, R=P, C=C).astype(
-        comb_ref.dtype)
-
-
 def _refresh_hist_kernel(lv_ref, comb_in, comb_ref, hist_ref, *,
                          kind: str, sigmoid: float, f: int, R: int,
                          C: int, nc: int, b_hi: int, hg: int, lo_n: int,
@@ -432,7 +304,7 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
                  n_pad: int, C: int, R: int = 512,
                  interpret: bool = False, dtype=jnp.float32,
                  root_hist: bool = False, padded_bins: int = 0,
-                 root_rpb: int = 16384, pack: int = 1,
+                 root_rpb: int = 16384,
                  kernel_interpret: bool = False):
     """Build ``refresh(comb, lv) -> comb`` (in-place over rows
     [0, n_pad); slack rows untouched).  ``lv`` is [1, n_pad] f32: the
@@ -447,13 +319,6 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
     blocks while they are VMEM-resident, saving the full comb read the
     standalone root-histogram kernel would pay one call later.
 
-    ``pack=2``: the comb is [n_alloc // 2, C] packed lines (two logical
-    rows per line); ``R``/``n_pad``/``lv`` stay LOGICAL and the kernel
-    refreshes both lane halves per line — half the refresh DMA bytes
-    per logical row.  The interpret reference unpacks to the logical
-    view, runs the pack=1 reference verbatim and repacks, so off-TPU
-    training is bit-identical across the pack knob.
-
     ``kernel_interpret=True`` builds the REAL Mosaic kernels but runs
     them through the Pallas interpreter (the test seam the partition
     kernels expose as LGBM_TPU_PART_INTERP=kernel) — off-TPU tests pin
@@ -462,53 +327,27 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
     check_lane_width(C, dtype)
     nc = N_CONSTS[kind]
     assert n_pad % R == 0
-    if pack not in (1, 2):
-        raise ValueError(f"pack must be 1 or 2, got {pack}")
-    if pack == 2 and f + COL_CONSTS + nc > C // 2:
-        raise ValueError(
-            f"pack=2 stream layout needs f + {COL_CONSTS + nc} <= "
-            f"{C // 2} logical columns (got f={f})")
     nblocks = n_pad // R
     if interpret and not kernel_interpret:
-        cw = C // pack
         if root_hist:
             ref_h = jax.jit(functools.partial(
                 _xla_refresh_hist, kind=kind, sigmoid=sigmoid, f=f,
-                n_pad=n_pad, C=cw, nc=nc, round_bf16=False,
+                n_pad=n_pad, C=C, nc=nc, round_bf16=False,
                 padded_bins=int(padded_bins), rows_per_block=root_rpb))
-            if pack == 1:
-                def refresh_h1(comb, lv2d):
-                    comb_l, hist = ref_h(to_rows(comb, C), lv2d)
-                    return to_planes(comb_l), hist
 
-                return jax.jit(refresh_h1)
+            def refresh_h1(comb, lv2d):
+                comb_l, hist = ref_h(to_rows(comb, C), lv2d)
+                return to_planes(comb_l), hist
 
-            def refresh_h2(comb, lv2d):
-                comb_l, hist = ref_h(comb.reshape(n_alloc, cw), lv2d)
-                return comb_l.reshape(n_alloc // 2, C), hist
-
-            return jax.jit(refresh_h2)
+            return jax.jit(refresh_h1)
         ref = jax.jit(functools.partial(
             _xla_refresh, kind=kind, sigmoid=sigmoid, f=f, n_pad=n_pad,
-            C=cw, nc=nc, round_bf16=False))
-        if pack == 1:
-            def refresh1(comb, lv2d):
-                return to_planes(ref(to_rows(comb, C), lv2d))
+            C=C, nc=nc, round_bf16=False))
 
-            return jax.jit(refresh1)
+        def refresh1(comb, lv2d):
+            return to_planes(ref(to_rows(comb, C), lv2d))
 
-        def refresh2(comb, lv2d):
-            return ref(comb.reshape(n_alloc, cw),
-                       lv2d).reshape(n_alloc // 2, C)
-
-        return jax.jit(refresh2)
-
-    if pack == 2:
-        return _make_refresh_p2(
-            kind=kind, sigmoid=sigmoid, f=f, n_alloc=n_alloc,
-            n_pad=n_pad, C=C, R=R, dtype=dtype, nc=nc,
-            root_hist=root_hist, padded_bins=padded_bins,
-            interpret=kernel_interpret)
+        return jax.jit(refresh1)
 
     if root_hist:
         from .hist_kernel2 import _LO_N as lo_n, _diag_extract, \
@@ -593,103 +432,6 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
     return refresh
 
 
-def _make_refresh_p2(*, kind, sigmoid, f, n_alloc, n_pad, C, R, dtype,
-                     nc, root_hist, padded_bins,
-                     interpret: bool = False):
-    """Compiled pack=2 refresh builder: grid over PHYSICAL lines
-    (P = R // 2 per block covering R logical rows), lv pre-split into
-    even/odd half rows by the wrapper."""
-    P = R // 2
-    np_pad = n_pad // 2
-    nblocks = np_pad // P
-    np_alloc = n_alloc // 2
-
-    def _lv_split(lv2d):
-        lv2 = lv2d.reshape(n_pad // 2, 2)
-        return jnp.transpose(lv2, (1, 0))                # [2, n_phys]
-
-    if root_hist:
-        from .hist_kernel2 import _LO_N as lo_n, _diag_extract, \
-            hist_geometry
-        b = int(padded_bins)
-        b_hi, hg, m, nn = hist_geometry(b, 2)
-        assert f % hg == 0, (f, hg)
-        ngroups = f // hg
-        kern_h = functools.partial(
-            _refresh_hist_kernel_p2, kind=kind, sigmoid=sigmoid, f=f,
-            P=P, C=C, nc=nc, b_hi=b_hi, hg=hg, lo_n=lo_n,
-            ngroups=ngroups)
-
-        @jax.jit
-        def refresh_h(comb, lv2d):
-            comb_r, out = pl.pallas_call(
-                kern_h,
-                name="lgbm_refresh",
-                grid=(nblocks,),
-                in_specs=[
-                    pl.BlockSpec((2, P), lambda i: (0, i),
-                                 memory_space=pltpu.VMEM),
-                    pl.BlockSpec((P, C), lambda i: (i, 0),
-                                 memory_space=pltpu.VMEM),
-                ],
-                out_specs=[
-                    pl.BlockSpec((P, C), lambda i: (i, 0),
-                                 memory_space=pltpu.VMEM),
-                    pl.BlockSpec((ngroups, m, nn), lambda i: (0, 0, 0),
-                                 memory_space=pltpu.VMEM),
-                ],
-                out_shape=[
-                    jax.ShapeDtypeStruct((np_alloc, C), dtype),
-                    jax.ShapeDtypeStruct((ngroups, m, nn), jnp.float32),
-                ],
-                input_output_aliases={1: 0},
-                cost_estimate=pl.CostEstimate(
-                    flops=2 * np_pad * (C * (P + 16)
-                                        + 2 * ngroups * m * nn // P),
-                    bytes_accessed=2 * np_pad * C * 4
-                    + ngroups * m * nn * 4,
-                    transcendentals=n_pad,
-                ),
-                interpret=interpret,
-            )(_lv_split(lv2d), comb)
-            return comb_r, _diag_extract(out, ngroups, hg, b_hi, 2,
-                                         lo_n, f, b)
-
-        return refresh_h
-
-    # pallas_call kernels must return None; the core's return value
-    # exists for the fused root-hist variant only
-    def kern(*refs):
-        _refresh_kernel_p2(*refs, kind=kind, sigmoid=sigmoid, f=f, P=P,
-                           C=C, nc=nc)
-
-    @jax.jit
-    def refresh(comb, lv2d):
-        return pl.pallas_call(
-            kern,
-            name="lgbm_refresh",
-            grid=(nblocks,),
-            in_specs=[
-                pl.BlockSpec((2, P), lambda i: (0, i),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((P, C), lambda i: (i, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((P, C), lambda i: (i, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((np_alloc, C), dtype),
-            input_output_aliases={1: 0},
-            cost_estimate=pl.CostEstimate(
-                flops=2 * np_pad * C * (P + 16),
-                bytes_accessed=2 * np_pad * C * 4,
-                transcendentals=n_pad,
-            ),
-            interpret=interpret,
-        )(_lv_split(lv2d), comb)
-
-    return refresh
-
-
 def _xla_init(comb0, bins, aux, *, kind, sigmoid, f, n_pad, C, nc,
               round_bf16):
     n_alloc = comb0.shape[0]
@@ -724,78 +466,28 @@ def _xla_init(comb0, bins, aux, *, kind, sigmoid, f, n_pad, C, nc,
 def make_init(*, kind: str, sigmoid: float, f_real: int, f: int,
               n_alloc: int, n_pad: int, C: int, R: int = 512,
               interpret: bool = False, dtype=jnp.float32,
-              pack: int = 1, kernel_interpret: bool = False):
+              kernel_interpret: bool = False):
     """Build ``init(comb0, bins, aux) -> comb``: populate the streaming
     row matrix from the [n_pad, f_real] uint8 bin matrix and the
     [2 + n_consts, n_pad] aux rows (score, validity, objective consts).
-    ``comb0`` must be zeros [n_alloc // pack, C] (its slack rows pass
-    through).  ``pack=2`` packs two logical rows per line (see
-    make_refresh); bins/aux inputs stay logical."""
+    ``comb0`` must be the zero comb of ``n_alloc`` lines of ``C`` lanes
+    (its slack rows pass through)."""
     from .layout import check_lane_width
     check_lane_width(C, dtype)
     nc = N_CONSTS[kind]
     assert n_pad % R == 0
-    if pack not in (1, 2):
-        raise ValueError(f"pack must be 1 or 2, got {pack}")
-    if pack == 2 and f + COL_CONSTS + nc > C // 2:
-        raise ValueError(
-            f"pack=2 stream layout needs f + {COL_CONSTS + nc} <= "
-            f"{C // 2} logical columns (got f={f})")
     nblocks = n_pad // R
     if interpret and not kernel_interpret:
-        cw = C // pack
         ini = jax.jit(functools.partial(
             _xla_init, kind=kind, sigmoid=sigmoid, f=f, n_pad=n_pad,
-            C=cw, nc=nc, round_bf16=False))
-        if pack == 1:
-            def init1(comb0, bins, aux):
-                return to_planes(ini(to_rows(comb0, C), bins, aux))
+            C=C, nc=nc, round_bf16=False))
 
-            return jax.jit(init1)
+        def init1(comb0, bins, aux):
+            return to_planes(ini(to_rows(comb0, C), bins, aux))
 
-        def init2(comb0, bins, aux):
-            return ini(comb0.reshape(n_alloc, cw), bins,
-                       aux).reshape(n_alloc // 2, C)
-
-        return jax.jit(init2)
+        return jax.jit(init1)
 
     k_aux = 2 + nc
-    if pack == 2:
-        P = R // 2
-        kern2 = functools.partial(_init_kernel_p2, kind=kind,
-                                  sigmoid=sigmoid, f_real=f_real, f=f,
-                                  P=P, C=C, nc=nc)
-
-        @jax.jit
-        def init_p2(comb0, bins, aux):
-            aux2 = aux.reshape(k_aux, n_pad // 2, 2)
-            aux_p = jnp.concatenate(
-                [aux2[..., 0], aux2[..., 1]], axis=0)  # [2k_aux, n_phys]
-            return pl.pallas_call(
-                kern2,
-                grid=(nblocks,),
-                in_specs=[
-                    pl.BlockSpec((R, f_real), lambda i: (i, 0),
-                                 memory_space=pltpu.VMEM),
-                    pl.BlockSpec((2 * k_aux, P), lambda i: (0, i),
-                                 memory_space=pltpu.VMEM),
-                    pl.BlockSpec((P, C), lambda i: (i, 0),
-                                 memory_space=pltpu.VMEM),
-                ],
-                out_specs=pl.BlockSpec((P, C), lambda i: (i, 0),
-                                       memory_space=pltpu.VMEM),
-                out_shape=jax.ShapeDtypeStruct((n_alloc // 2, C), dtype),
-                input_output_aliases={2: 0},
-                cost_estimate=pl.CostEstimate(
-                    flops=2 * n_pad * C * (R + f_real + 16),
-                    bytes_accessed=n_pad * (f_real + C * 4),
-                    transcendentals=n_pad,
-                ),
-                interpret=kernel_interpret,
-            )(bins, aux_p, comb0)
-
-        return init_p2
-
     kern = functools.partial(_init_kernel, kind=kind, sigmoid=sigmoid,
                              f_real=f_real, f=f, R=R, C=C, nc=nc)
 
@@ -864,13 +556,4 @@ def _analysis_stream_refresh_root():
     fn = make_refresh(kind="l2", sigmoid=1.0, root_hist=True,
                       padded_bins=32, **s)
     return fn, (sds((s["n_alloc"], s["C"]), jnp.float32),
-                sds((1, s["n_pad"]), jnp.float32))
-
-
-@register_kernel("stream_refresh_p2", kind="stream", pack=2,
-                 note="pack=2 refresh over packed lines")
-def _analysis_stream_refresh_p2():
-    s = _stream_shapes()
-    fn = make_refresh(kind="l2", sigmoid=1.0, pack=2, **s)
-    return fn, (sds((s["n_alloc"] // 2, s["C"]), jnp.float32),
                 sds((1, s["n_pad"]), jnp.float32))

@@ -2,14 +2,13 @@
 
 The trainer's value lives in its fast paths — physical partition mode
 (~25x the row_order path at 1M rows, round-2 table), score-resident
-gradient streaming on top of it, the pack=2 comb layout, and the mesh
-reduce-scatter histogram merge.  Until this module, the predicates
-that select those paths lived as inline boolean soup in
-``models/gbdt.py`` (``use_phys`` / ``use_stream``),
-``ops/device_data.py`` (``comb_pack_choice``) and ``ops/grow.py``
-(``hist_scatter_eligible``): neither the static analyzer nor CI could
-see them, so a config that silently fell to the 0.04x row_order path
-was only discoverable by benchmarking it on a chip.
+gradient streaming on top of it, and the mesh reduce-scatter
+histogram merge.  Until this module, the predicates that select those
+paths lived as inline boolean soup in ``models/gbdt.py`` (``use_phys``
+/ ``use_stream``) and ``ops/grow.py`` (``hist_scatter_eligible``):
+neither the static analyzer nor CI could see them, so a config that
+silently fell to the 0.04x row_order path was only discoverable by
+benchmarking it on a chip.
 
 This module is the single source of truth both sides consume:
 
@@ -66,7 +65,7 @@ class RouteInputs:
 
     ``learner`` is the ENGAGED learner ("serial" when a mesh learner
     was requested but only one device exists).  Shape facts arrive as
-    booleans (``wide_layout``, ``rows_over_limit``) so a runtime
+    booleans (``rows_over_limit``, ``efb_overwide``) so a runtime
     snapshot and a lattice cell share one key space; ``fused_ok``
     (``fused_split.fused_supported`` over the actual geometry) is
     runtime-only and deliberately NOT part of :meth:`key`."""
@@ -79,7 +78,6 @@ class RouteInputs:
     efb_bundled: bool = False          # EFB produced bundled columns
     bins_u8: bool = True               # bin matrix fits uint8
     rows_over_limit: bool = False      # per-shard n_pad >= 2^24 - slack
-    wide_layout: bool = False          # f_pad + extras > layout.PACK_W
     efb_overwide: bool = False         # UNBUNDLED f_pad + extras >
                                        # layout.MAX_COMB_COLS (only
                                        # meaningful with efb_bundled)
@@ -104,9 +102,7 @@ class RouteInputs:
     phys_env: str = "auto"             # auto | 0 | interpret
     stream_env: str = "auto"           # auto | 0
     paged_env: str = "auto"            # auto | 0 | 1 (LGBM_TPU_PAGED)
-    pack_env: int = 1                  # 1 | 2
     partition_env: str = "permute"     # permute | matmul
-    part_impl: str = "ss"              # ss | 3ph
     fused_env: bool = True
     hist_scatter_env: bool = True
     mc_batch_env: str = "auto"         # auto | 0 | 1 (LGBM_TPU_MC_BATCH)
@@ -120,7 +116,7 @@ class RouteInputs:
             f"learner={self.learner};shards={self.n_shards};"
             f"be={self.backend};"
             f"efb={b(self.efb_bundled)};u8={b(self.bins_u8)};"
-            f"over={b(self.rows_over_limit)};wide={b(self.wide_layout)};"
+            f"over={b(self.rows_over_limit)};"
             f"ew={b(self.efb_overwide)};"
             f"fdiv={b(self.f_log_shard_divisible)};"
             f"dp={b(self.gpu_use_dp)};cegb={b(self.cegb_lazy)};"
@@ -132,8 +128,7 @@ class RouteInputs:
             f"mono={b(self.mono_intermediate)};"
             f"cegbc={b(self.cegb_coupled)};"
             f"phys={self.phys_env};stream={self.stream_env};"
-            f"pack={self.pack_env};part={self.partition_env};"
-            f"impl={self.part_impl};fused={b(self.fused_env)};"
+            f"part={self.partition_env};fused={b(self.fused_env)};"
             f"scat={b(self.hist_scatter_env)};"
             f"ob={b(self.over_budget)};pg={self.paged_env};"
             f"mcb={self.mc_batch_env}")
@@ -148,7 +143,8 @@ class RouteInputs:
 @dataclass(frozen=True)
 class Rule:
     name: str
-    blocks: str                  # physical | stream | pack | hist_scatter
+    blocks: str                  # physical | stream | paged |
+                                 # mc_batch | hist_scatter
     knob: str                    # config field or LGBM_TPU_* env knob
     reason: str
     pred: Callable[[RouteInputs], bool] = field(repr=False, default=None)
@@ -234,15 +230,6 @@ RULES: Tuple[Rule, ...] = (
          "score-resident streaming is serial-only (mesh scores are "
          "booster-held)",
          lambda i: i.learner != "serial"),
-    # -- pack=2 comb layout (device_data.comb_pack_choice) -------------
-    Rule("pack_layout_too_wide", "pack", "LGBM_TPU_COMB_PACK",
-         "padded features + value/rid/stream columns exceed the "
-         "64-lane half-line budget (layout.PACK_W)",
-         lambda i: i.wide_layout),
-    Rule("pack_part_3ph", "pack", "LGBM_TPU_PART",
-         "the 3-phase partition kernel has no pack=2 variant "
-         "(config.check_conflicts refuses the combo at runtime)",
-         lambda i: i.part_impl == "3ph"),
     # -- paged comb for larger-than-HBM shapes (ISSUE 15) --------------
     Rule("paged_env_off", "paged", "LGBM_TPU_PAGED",
          "paged comb disabled by LGBM_TPU_PAGED=0; an over-budget "
@@ -302,27 +289,15 @@ RULES: Tuple[Rule, ...] = (
 RULE_BY_NAME: Dict[str, Rule] = {r.name: r for r in RULES}
 
 # contextual reason names decide() emits without a predicate row
-_PACK_REQUIRES_PHYSICAL = "pack_requires_physical"
 _VOTING_ELECTION = "voting_election"
 _PAGED_REQUIRES_PHYSICAL = "paged_requires_physical"
 _MC_BATCH_REQUIRES_PHYSICAL = "mc_batch_requires_physical"
 
 # non-stream physical comb extras: g*w, h*w, w value columns + 3
 # row-id byte columns.  Shared with ops/grow.py's layout sizing so the
-# model's wide_layout decision and the grower's engaged pack can never
-# disagree on the column budget (stream layouts get their count from
-# stream_grad.stream_columns).
+# model's column count and the grower's can never disagree (stream
+# layouts get their count from stream_grad.stream_columns).
 NON_STREAM_EXTRA_COLS = 6
-
-
-def pack_blockers(*, wide_layout: bool, part_impl: str) -> List[str]:
-    """Names of the pack rules blocking a pack=2 request on the
-    physical path — the ONE implementation both :func:`decide` (the
-    matrix side) and :func:`pack_choice` (the runtime side, via
-    ``device_data.comb_pack_choice``) evaluate."""
-    probe = RouteInputs(wide_layout=wide_layout, part_impl=part_impl)
-    return [r.name for r in RULES
-            if r.blocks == "pack" and r.pred(probe)]
 
 
 # ---------------------------------------------------------------------
@@ -332,14 +307,12 @@ def pack_blockers(*, wide_layout: bool, part_impl: str) -> List[str]:
 class RouteDecision:
     """The engaged path plus the named rule behind every loss."""
     path: str                   # stream | physical | row_order
-    pack: int                   # logical comb rows per 128-lane line
-    scheme: str                 # permute | matmul | 3ph | none
+    scheme: str                 # permute | matmul | none
     fused: bool
     learner: str
     n_shards: int
     hist_merge: str             # scatter | psum | none
     reasons: Tuple[str, ...]        # why not the next-faster path
-    pack_reasons: Tuple[str, ...]   # why a requested pack=2 fell to 1
     merge_reasons: Tuple[str, ...]  # why the mesh merge is psum
     program_key: str
     cell: str                   # the RouteInputs.key() this decided
@@ -347,6 +320,12 @@ class RouteDecision:
     paged_reasons: Tuple[str, ...] = ()  # why a wanted paging fell off
     mc_batched: bool = False    # batched multiclass grow (ISSUE 19)
     mc_batch_reasons: Tuple[str, ...] = ()  # why multiclass is serial-K
+    # logical comb rows a 128-lane line: a constant, not a field, since
+    # the two-rows-a-line layout went (ISSUE 32).  Kept for its readers
+    # - benchmarks/kinds/train.py check_route against expect_route
+    # {"pack": 1}, chip_smoke.py, and digest() of saved checkpoints -
+    # until a benchmark issue takes it out of expect_route (ROADMAP C11)
+    pack = 1
 
     def digest(self) -> str:
         """12-hex identity of the ENGAGED path (not the reasons): two
@@ -370,7 +349,6 @@ class RouteDecision:
             "paged": self.paged,
             "mc_batched": self.mc_batched,
             "reasons": list(self.reasons),
-            "pack_reasons": list(self.pack_reasons),
             "merge_reasons": list(self.merge_reasons),
             "paged_reasons": list(self.paged_reasons),
             "mc_batch_reasons": list(self.mc_batch_reasons),
@@ -395,22 +373,8 @@ def decide(i: RouteInputs) -> RouteDecision:
     path = ("stream" if use_stream
             else "physical" if use_phys else "row_order")
 
-    pack, pack_reasons = 1, []
-    if i.pack_env == 2:
-        if not use_phys:
-            pack_reasons = [_PACK_REQUIRES_PHYSICAL]
-        else:
-            pack_reasons = pack_blockers(wide_layout=i.wide_layout,
-                                         part_impl=i.part_impl)
-            if not pack_reasons:
-                pack = 2
-
-    scheme = "none"
-    if use_phys:
-        scheme = ("3ph" if i.part_impl == "3ph"
-                  else "permute" if pack == 2 else i.partition_env)
-    fused = bool(use_phys and i.fused_env and i.part_impl != "3ph"
-                 and i.fused_ok)
+    scheme = i.partition_env if use_phys else "none"
+    fused = bool(use_phys and i.fused_env and i.fused_ok)
 
     # paged comb (ISSUE 15): wanted when the footprint model says the
     # shape cannot sit fully resident (over_budget, the auto default)
@@ -460,16 +424,16 @@ def decide(i: RouteInputs) -> RouteDecision:
     reasons = [r.name for r in
                (phys_block if not use_phys else stream_block)]
     program_key = "|".join([
-        path, f"pack{pack}", scheme, f"fused{int(fused)}",
+        path, scheme, f"fused{int(fused)}",
         i.learner, f"shards{i.n_shards}", hist_merge,
         f"dp{int(i.gpu_use_dp)}", f"cegb{int(i.cegb_lazy)}",
         f"cat{int(i.cat_subset)}", f"efb{int(i.efb_bundled)}",
         f"u8{int(i.bins_u8)}", f"paged{int(paged)}",
         f"mcb{int(mc_batched)}"])
     return RouteDecision(
-        path=path, pack=pack, scheme=scheme, fused=fused,
+        path=path, scheme=scheme, fused=fused,
         learner=i.learner, n_shards=i.n_shards, hist_merge=hist_merge,
-        reasons=tuple(reasons), pack_reasons=tuple(pack_reasons),
+        reasons=tuple(reasons),
         merge_reasons=tuple(merge_reasons), program_key=program_key,
         cell=i.key(), paged=paged, paged_reasons=tuple(paged_reasons),
         mc_batched=mc_batched,
@@ -490,9 +454,9 @@ def objective_kind(objective) -> str:
 def env_snapshot() -> Dict[str, object]:
     """Normalized env-knob fields for :class:`RouteInputs`.
 
-    ``LGBM_TPU_PART`` / ``LGBM_TPU_PARTITION`` / ``LGBM_TPU_FUSED``
-    are read from ``ops.grow``'s import-time constants (what the
-    kernels actually baked), the call-time knobs through
+    ``LGBM_TPU_PARTITION`` / ``LGBM_TPU_FUSED`` are read from
+    ``ops.grow``'s import-time constants (what the kernels actually
+    baked), the call-time knobs through
     ``config.env_knob`` (the documented ENV_KNOBS read — the ISSUE-10
     satellite that retired the inline ``os.environ`` soup in
     ``gbdt.py``)."""
@@ -513,51 +477,32 @@ def env_snapshot() -> Dict[str, object]:
         stream_env=stream,
         paged_env=paged,
         mc_batch_env=mcb,
-        pack_env=2 if env_knob("LGBM_TPU_COMB_PACK") == "2" else 1,
         partition_env=grow_mod.PARTITION_IMPL,
-        part_impl="3ph" if grow_mod.PART_IMPL == "3ph" else "ss",
         fused_env=grow_mod.FUSED_IMPL != "0",
         hist_scatter_env=env_knob("LGBM_TPU_HIST_SCATTER") != "0",
     )
-
-
-def pack_choice(comb_cols: int) -> int:
-    """Logical rows per 128-lane comb line the physical path will use:
-    evaluates the SAME :func:`pack_blockers` rule set the matrix
-    enumerates, over the engaged env (``device_data.comb_pack_choice``
-    is the runtime consumer), so the grower and the matrix can never
-    disagree about the pack=2 fit."""
-    from ..config import env_knob
-    from . import grow as grow_mod
-    from .pallas.layout import PACK_W
-    if int(env_knob("LGBM_TPU_COMB_PACK")) != 2:
-        return 1
-    blocked = pack_blockers(
-        wide_layout=comb_cols > PACK_W,
-        part_impl="3ph" if grow_mod.PART_IMPL == "3ph" else "ss")
-    return 1 if blocked else 2
 
 
 def resolve_layout(i: RouteInputs, *, f_pad: int,
                    padded_bins: int, rows: int = None,
                    num_leaves: int = 0,
                    num_class: int = 1) -> RouteInputs:
-    """Fill the geometry-derived fields (``wide_layout``,
-    ``efb_overwide``, ``fused_ok`` — and, when ``rows`` is given,
+    """Fill the geometry-derived fields (``efb_overwide``,
+    ``fused_ok`` — and, when ``rows`` is given,
     ``over_budget``, the ISSUE-15 paging fact) from the final device
     layout.  ``f_pad`` / ``padded_bins`` are the widths the physical
     path would INGEST — the unbundled logical geometry under EFB
     (``DeviceDataset.phys_f_pad`` / ``phys_padded_bins``, ISSUE 12).
     The stream decision feeds the column count (streaming layouts
     carry extra objective columns), so this runs a provisional
-    :func:`decide` first — pack never feeds back into the stream
-    decision, so one round fixes the point.  ``over_budget`` is then
-    priced over the decision RE-RUN with the resolved geometry
-    fields: pricing it at the provisional decision (fused_ok/
-    wide_layout still defaults) would disagree with the engaged
-    pack/fused footprint by exactly the fused-root-carry / pack
-    bytes, and a limit landing in that band would make routing
-    promise a paging the planner then refuses."""
+    :func:`decide` first — the geometry never feeds back into the
+    stream decision, so one round fixes the point.  ``over_budget`` is
+    then priced over the decision RE-RUN with the resolved geometry
+    fields: pricing it at the provisional decision (fused_ok still
+    defaults) would disagree with the engaged fused footprint by
+    exactly the fused-root-carry bytes, and a limit landing in that
+    band would make routing promise a paging the planner then
+    refuses."""
     d0 = decide(i)
     if d0.path == "stream":
         from .pallas.stream_grad import stream_columns
@@ -565,10 +510,9 @@ def resolve_layout(i: RouteInputs, *, f_pad: int,
     else:
         n_extra = NON_STREAM_EXTRA_COLS
     from .pallas.fused_split import fused_supported
-    from .pallas.layout import PACK_W, comb_cols_fit
+    from .pallas.layout import comb_cols_fit
     resolved = replace(
-        i, wide_layout=bool(f_pad + n_extra > PACK_W),
-        efb_overwide=bool(i.efb_bundled
+        i, efb_overwide=bool(i.efb_bundled
                           and not comb_cols_fit(f_pad + n_extra)),
         fused_ok=bool(fused_supported(int(f_pad), int(padded_bins))))
     if rows is None:
@@ -580,7 +524,7 @@ def resolve_layout(i: RouteInputs, *, f_pad: int,
     fp = grow_footprint(
         rows=int(rows), f_pad=int(f_pad),
         padded_bins=int(padded_bins),
-        num_leaves=max(int(num_leaves), 2), pack=d1.pack,
+        num_leaves=max(int(num_leaves), 2),
         stream=d1.path == "stream",
         fused=d1.fused,
         stream_kind=(i.objective_kind
@@ -972,7 +916,7 @@ _OBJ = (("binary", False), ("l2", False),
         ("other", True), ("other", False))
 
 ENV_TPU = dict(backend="tpu", phys_env="auto", stream_env="auto",
-               pack_env=1, partition_env="permute", part_impl="ss",
+               partition_env="permute",
                fused_env=True, hist_scatter_env=True)
 # the CPU equivalence-test environment (tests force the reference
 # physical path with LGBM_TPU_PHYS=interpret)
@@ -1018,13 +962,12 @@ def enumerate_inputs() -> List[RouteInputs]:
                                         objective_kind=obj,
                                         multi_tree=multi, **ENV_TPU)
     # 1b. one-knob-at-a-time config cells under the CPU test envs
-    # (LGBM_TPU_PHYS=interpret, plus its phys-off / stream-off /
-    # pack=2 variants) — the cells the runtime-parity golden test
+    # (LGBM_TPU_PHYS=interpret, plus its phys-off / stream-off
+    # variants) — the cells the runtime-parity golden test
     # (tests/test_routing.py) trains and compares on CPU
     for env in (ENV_CPU,
                 dict(ENV_CPU, phys_env="0"),
-                dict(ENV_CPU, stream_env="0"),
-                dict(ENV_CPU, pack_env=2)):
+                dict(ENV_CPU, stream_env="0")):
         for learner, shards in _LEARNERS:
             for obj, multi in _OBJ:
                 for flip in (None, "efb_bundled", "bins_u8",
@@ -1047,30 +990,24 @@ def enumerate_inputs() -> List[RouteInputs]:
         for be, phys in (("tpu", "auto"), ("tpu", "0"),
                          ("cpu", "auto"), ("cpu", "0"),
                          ("cpu", "interpret")):
-            for pack in (1, 2):
-                for part in ("permute", "matmul"):
-                    for fused in _BOOL:
-                        for stream in ("auto", "0"):
-                            for scat in _BOOL:
-                                add(learner=learner, n_shards=shards,
-                                    backend=be, phys_env=phys,
-                                    pack_env=pack, partition_env=part,
-                                    fused_env=fused, stream_env=stream,
-                                    hist_scatter_env=scat,
-                                    part_impl="ss")
+            for part in ("permute", "matmul"):
+                for fused in _BOOL:
+                    for stream in ("auto", "0"):
+                        for scat in _BOOL:
+                            add(learner=learner, n_shards=shards,
+                                backend=be, phys_env=phys,
+                                partition_env=part, fused_env=fused,
+                                stream_env=stream,
+                                hist_scatter_env=scat)
     # 3. shape / learner / boosting edge cells
     for env in (ENV_TPU, ENV_CPU):
         for learner, shards in _LEARNERS:
-            for pack in (1, 2):
-                add(learner=learner, n_shards=shards, wide_layout=True,
-                    **dict(env, pack_env=pack))
             add(learner=learner, n_shards=shards, rows_over_limit=True,
                 **env)
             # ISSUE 12: the one EFB shape that still loses the fast
             # path — a bundle expansion past the comb column budget
-            # (necessarily wide_layout too: MAX_COMB_COLS > PACK_W)
             add(learner=learner, n_shards=shards, efb_bundled=True,
-                efb_overwide=True, wide_layout=True, **env)
+                efb_overwide=True, **env)
         add(learner="data", n_shards=8, f_log_shard_divisible=False,
             **env)
         add(learner="data", n_shards=8, forced_splits=True, **env)
@@ -1081,9 +1018,6 @@ def enumerate_inputs() -> List[RouteInputs]:
         for boost in ("dart", "goss", "rf"):
             add(learner="serial", n_shards=1, boosting=boost, **env)
         add(learner="serial", n_shards=1, linear_tree=True, **env)
-        add(learner="serial", n_shards=1, **dict(env, part_impl="3ph"))
-        add(learner="serial", n_shards=1,
-            **dict(env, part_impl="3ph", pack_env=2))
         # ISSUE 15: the paged dimension — over-budget shapes under the
         # auto default, the LGBM_TPU_PAGED force/off overrides, and
         # the edges where a wanted paging falls off (mesh learner,
@@ -1095,9 +1029,8 @@ def enumerate_inputs() -> List[RouteInputs]:
                 **dict(env, paged_env="0"))
             add(learner=learner, n_shards=shards,
                 **dict(env, paged_env="1"))
-        for pack in (1, 2):
-            add(learner="serial", n_shards=1, over_budget=True,
-                **dict(env, pack_env=pack, stream_env="0"))
+        add(learner="serial", n_shards=1, over_budget=True,
+            **dict(env, stream_env="0"))
         add(learner="serial", n_shards=1, over_budget=True,
             **dict(env, fused_env=False))
         add(learner="serial", n_shards=1, over_budget=True,
@@ -1131,10 +1064,10 @@ def enumerate_inputs() -> List[RouteInputs]:
 def encode_cell(d: RouteDecision) -> str:
     """One-line cell encoding (diff-friendly golden file)."""
     j = lambda xs: "+".join(xs) or "-"  # noqa: E731
-    return (f"path={d.path};pack={d.pack};scheme={d.scheme};"
+    return (f"path={d.path};scheme={d.scheme};"
             f"fused={int(d.fused)};merge={d.hist_merge};"
             f"paged={int(d.paged)};mcb={int(d.mc_batched)};"
-            f"why={j(d.reasons)};pack_why={j(d.pack_reasons)};"
+            f"why={j(d.reasons)};"
             f"merge_why={j(d.merge_reasons)};"
             f"paged_why={j(d.paged_reasons)};"
             f"mcb_why={j(d.mc_batch_reasons)};prog={d.program_key}")
@@ -1151,16 +1084,14 @@ def decode_cell(enc: str) -> dict:
         out[k] = v
     lists = {k: ([] if out.get(k, "-") == "-"
                  else str(out[k]).split("+"))
-             for k in ("why", "pack_why", "merge_why", "paged_why",
-                       "mcb_why")}
+             for k in ("why", "merge_why", "paged_why", "mcb_why")}
     return {
-        "path": out["path"], "pack": int(out["pack"]),
+        "path": out["path"],
         "scheme": out["scheme"], "fused": bool(int(out["fused"])),
         "merge": out["merge"],
         "paged": bool(int(out.get("paged", 0))),
         "mc_batched": bool(int(out.get("mcb", 0))),
         "reasons": lists["why"],
-        "pack_reasons": lists["pack_why"],
         "merge_reasons": lists["merge_why"],
         "paged_reasons": lists["paged_why"],
         "mc_batch_reasons": lists["mcb_why"],

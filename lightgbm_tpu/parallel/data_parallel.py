@@ -114,7 +114,6 @@ class DataParallelGrower:
                 physical_bins=local_spec, **grow_kwargs)
             self._pieces = pieces
             self.fused = pieces.fused
-            self.pack = pieces.pack   # logical rows per comb line
             self._bins_global = physical_bins
             # EFB (ISSUE 12): the merge collectives move LOGICAL-width
             # histograms once the ingest unbundles, so the ledger
@@ -130,8 +129,7 @@ class DataParallelGrower:
             ), donate_argnums=(0, 1))
             _init_part = functools.partial(
                 phys_init_comb, n_alloc=pieces.n_alloc, C=pieces.C,
-                f_pad=pieces.f_pad, dtype=pieces.dtype,
-                pack=pieces.pack)
+                f_pad=pieces.f_pad, dtype=pieces.dtype)
             _ingest = pieces.ingest
 
             def _init_local(bins_local):

@@ -25,8 +25,8 @@ therefore captures the exact boosting state:
   fingerprint**, and a **data digest** (binned matrix + labels): a
   resume whose freshly-built booster disagrees on any of them REFUSES
   with a structured finding (exit 2 at the CLI layer, mirroring
-  ``obs diff``'s incomparable-records semantics) — resuming a pack=2
-  checkpoint on a pack=1 path, or onto a refreshed same-shape
+  ``obs diff``'s incomparable-records semantics) — resuming a stream
+  checkpoint on a row_order path, or onto a refreshed same-shape
   dataset, would not reproduce the run, it would silently fork it.
 
 Writes are atomic (tmp dir + rename, then the ``LATEST`` pointer is

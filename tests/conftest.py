@@ -20,8 +20,7 @@ os.environ.setdefault("JAX_ENABLE_X64", "0")
 # by tests/test_physical.py and tests/test_fused.py so the save/restore
 # semantics live in one place
 ENV_KNOBS = ("LGBM_TPU_PHYS", "LGBM_TPU_FUSED", "LGBM_TPU_PART_INTERP",
-             "LGBM_TPU_PARTITION", "LGBM_TPU_COMB_PACK",
-             "LGBM_TPU_STREAM")
+             "LGBM_TPU_PARTITION", "LGBM_TPU_STREAM")
 
 
 def save_env_knobs(keys=ENV_KNOBS):

@@ -150,8 +150,8 @@ def test_dma_start_inside_nested_scope_is_paired():
 
 
 def test_duplicate_kernel_body_names_all_scanned():
-    """Two kernel wrappers sharing one simple name (stream_grad's
-    pack=1/pack=2 ``def kern``) must BOTH be scanned — a host pull in
+    """Two kernel wrappers sharing one simple name (``def kern`` in
+    two builders of one module) must BOTH be scanned — a host pull in
     the second def cannot hide behind the first."""
     import textwrap
 
@@ -191,9 +191,8 @@ def test_registered_entries_trace_to_pallas_calls():
     from lightgbm_tpu.analysis.run import build_context
     ctx = build_context()
     by_name = {e.name: e for e in ctx.entries}
-    for name in ("partition_ss_permute", "partition_p2", "hist_comb",
-                 "fused_split", "fused_split_p2", "stream_refresh",
-                 "apply_find"):
+    for name in ("partition_ss_permute", "hist_comb", "fused_split",
+                 "stream_refresh", "apply_find"):
         calls = pallas_calls(by_name[name].trace())
         assert calls, f"{name} traced to no pallas_call"
         for c in calls:

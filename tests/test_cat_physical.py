@@ -1,13 +1,13 @@
 """Categorical sorted-subset splits on the physical fast path (ISSUE 16).
 
 Graduation contract: high-cardinality categorical splits ride the SAME
-partition / fused / pack=2 / mesh kernels as numerical ones.  The
+partition / fused / mesh kernels as numerical ones.  The
 winning subset's membership travels as bitset words APPENDED to the
 SMEM split descriptor (the exact ``ops/predict.py`` serving encoding,
 one bit per padded bin), decoded per row inside the kernel bodies —
 so ``categorical_feature`` must not change which kernels run:
 
-* bit-parity matrix: permute vs matmul and pack=1 vs pack=2 trees
+* bit-parity matrix: permute vs matmul trees
   BYTE-IDENTICAL on cat-subset data, through the REAL partition kernel
   bodies (``LGBM_TPU_PART_INTERP=kernel``), fused on/off, serial and
   8-shard data-parallel mesh (the mesh cells engage the reduce-scatter
@@ -31,9 +31,9 @@ import pytest
 from conftest import restore_env_knobs as _restore_env
 from conftest import save_env_knobs as _save_env
 
-_KNOBS = ("LGBM_TPU_PHYS", "LGBM_TPU_STREAM", "LGBM_TPU_COMB_PACK",
-          "LGBM_TPU_FUSED", "LGBM_TPU_PARTITION", "LGBM_TPU_PART",
-          "LGBM_TPU_PART_INTERP", "LGBM_TPU_HIST_SCATTER")
+_KNOBS = ("LGBM_TPU_PHYS", "LGBM_TPU_STREAM", "LGBM_TPU_FUSED",
+          "LGBM_TPU_PARTITION", "LGBM_TPU_PART_INTERP",
+          "LGBM_TPU_HIST_SCATTER")
 
 
 def _cat_problem(n=1536, n_cats=48, f=8, seed=7, nan_frac=0.0):
@@ -86,8 +86,8 @@ def _n_multicat_splits(bst):
     return multi
 
 
-def _fresh_train(env, n=1536, rounds=3, nan_frac=0.0, seed=7,
-                 expect_pack=None, **params):
+def _fresh_train(env, n=1536, rounds=3, nan_frac=0.0, seed=7, f=8,
+                 **params):
     """Train the cat problem in a fresh library generation; returns
     digests + predictions + engaged-path facts."""
     saved = _save_env(_KNOBS)
@@ -101,7 +101,7 @@ def _fresh_train(env, n=1536, rounds=3, nan_frac=0.0, seed=7,
                   if k.startswith("lightgbm_tpu")]:
             del sys.modules[m]
         import lightgbm_tpu as lgb
-        x, y = _cat_problem(n=n, seed=seed, nan_frac=nan_frac)
+        x, y = _cat_problem(n=n, f=f, seed=seed, nan_frac=nan_frac)
         p = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
              "min_data_in_leaf": 5, "min_data_per_group": 5,
              "cat_smooth": 2.0, "max_cat_to_onehot": 4, "max_bin": 63}
@@ -110,10 +110,8 @@ def _fresh_train(env, n=1536, rounds=3, nan_frac=0.0, seed=7,
                          params={"max_bin": p["max_bin"],
                                  "min_data_in_bin": 1})
         bst = lgb.train(p, ds, num_boost_round=rounds)
-        if expect_pack is not None:
-            got = int(getattr(bst._inner.grow, "pack", 1))
-            assert got == expect_pack, (got, expect_pack)
         return {
+            "comb_C": getattr(bst._inner.grow, "_C", None),
             "trees": _digest(bst),
             "multicat": _n_multicat_splits(bst),
             "pred": bst.predict(x, raw_score=True),
@@ -129,12 +127,11 @@ def _fresh_train(env, n=1536, rounds=3, nan_frac=0.0, seed=7,
             del sys.modules[m]
 
 
-def _kernel_env(partition, fused, pack="1"):
+def _kernel_env(partition, fused):
     return {"LGBM_TPU_PHYS": "interpret",
             "LGBM_TPU_PART_INTERP": "kernel",
             "LGBM_TPU_PARTITION": partition,
-            "LGBM_TPU_FUSED": fused,
-            "LGBM_TPU_COMB_PACK": pack}
+            "LGBM_TPU_FUSED": fused}
 
 
 def _assert_byte_identical(a, b):
@@ -181,43 +178,21 @@ def test_cat_partition_scheme_equivalence(fused, learner):
     _assert_byte_identical(runs["permute"], runs["matmul"])
 
 
-@pytest.mark.parametrize("partition,fused,learner", [
-    ("permute", "1", "serial"),
-    pytest.param("permute", "0", "serial", marks=pytest.mark.slow),
-    pytest.param("matmul", "1", "serial", marks=pytest.mark.slow),
-    pytest.param("permute", "1", "data", marks=pytest.mark.slow),
-])
-def test_cat_pack_parity(partition, fused, learner):
-    """pack=2 trees BIT-IDENTICAL to pack=1 on cat-subset data — the
-    packed scan decodes the same membership booleans from the same
-    bitset words in the logical domain."""
-    params = {}
-    if learner == "data":
-        # hist_scatter's column padding blows the pack=2 budget at
-        # small max_bin (the test_physical.py mesh-cell caveat)
-        params = {"tree_learner": "data", "max_bin": 31}
-    envs = {p: _kernel_env(partition, fused, pack=p) for p in ("1", "2")}
-    if learner == "data":
-        for e in envs.values():
-            e["LGBM_TPU_HIST_SCATTER"] = "0"
-    runs = {p: _fresh_train(envs[p], expect_pack=int(p), **params)
-            for p in ("1", "2")}
-    for run in runs.values():
-        _assert_engaged(run)
-    _assert_byte_identical(runs["1"], runs["2"])
-
-
 # ---------------------------------------------------------------------
 # CPU-reference parity: graduated path vs row_order host walk
 # ---------------------------------------------------------------------
-def test_cat_physical_matches_row_order_reference():
+@pytest.mark.parametrize("planes", [1, 2])
+def test_cat_physical_matches_row_order_reference(planes):
     """Same bitset member booleans by construction => identical split
     structure; leaf values accumulate in permuted row order (f32
-    drift only)."""
-    ref = _fresh_train({"LGBM_TPU_PHYS": "0"}, rounds=4, nan_frac=0.1)
-    phy = _fresh_train(_kernel_env("permute", "1"), rounds=4,
-                       nan_frac=0.1)
+    drift only).  At one comb plane and at two (130 feature columns:
+    the value columns sit in the second plane, the categorical split
+    column in the first)."""
+    kw = dict(rounds=4, nan_frac=0.1, f=8 if planes == 1 else 130)
+    ref = _fresh_train({"LGBM_TPU_PHYS": "0"}, **kw)
+    phy = _fresh_train(_kernel_env("permute", "1"), **kw)
     assert ref["routing"]["path"] == "row_order"
+    assert phy["comb_C"] == 128 * planes
     _assert_engaged(phy)
     assert ref["multicat"] > 0
     assert len(ref["trees"]) == len(phy["trees"])

@@ -260,7 +260,7 @@ class TestPlanSchema:
         ids = [s["id"] for s in plan["steps"]]
         assert ids[0] == "doctor"
         for required in ("tpu_smoke", "bench_headline", "bench_traced",
-                         "bench_xplane", "bench_pack2_traced",
+                         "bench_xplane",
                          "bench_efb_bundled", "bench_efb_unbundled",
                          "bench_ckpt", "bench_paged",
                          "profile_partition", "attr_join", "mem_join",

@@ -1,7 +1,7 @@
 """EFB bundles on the physical fast path (ISSUE 12).
 
 The graduation contract: bundled datasets ride the SAME physical /
-stream / pack=2 / mesh kernels as unbundled ones, because the comb
+stream / mesh kernels as unbundled ones, because the comb
 ingests the unbundled logical layout (``device_data.unbundle_bins`` —
 per-feature bin offsets subtracted on device).  With zero bundling
 conflicts (the shipping ``max_conflict_rate=0.0``) the unbundled ingest
@@ -9,8 +9,8 @@ is bit-identical to the never-bundled bin matrix, so ``enable_bundle``
 must not change a single tree byte anywhere on the fast path:
 
 * bit-parity matrix: bundled vs pre-unbundled trees BYTE-IDENTICAL
-  across pack={1,2} x serial/8-shard-mesh, through the REAL partition
-  kernel bodies (``LGBM_TPU_PART_INTERP=kernel``);
+  across one and two comb planes x serial/8-shard-mesh, through the REAL
+  partition kernel bodies (``LGBM_TPU_PART_INTERP=kernel``);
 * CPU-reference parity: the bundled physical path agrees with the
   bundled row_order reference on a real one-hot dataset (split
   structure exact, leaf values to f32 accumulation order);
@@ -26,9 +26,9 @@ import pytest
 from conftest import restore_env_knobs as _restore_env
 from conftest import save_env_knobs as _save_env
 
-_KNOBS = ("LGBM_TPU_PHYS", "LGBM_TPU_STREAM", "LGBM_TPU_COMB_PACK",
-          "LGBM_TPU_FUSED", "LGBM_TPU_PARTITION", "LGBM_TPU_PART",
-          "LGBM_TPU_PART_INTERP", "LGBM_TPU_HIST_SCATTER")
+_KNOBS = ("LGBM_TPU_PHYS", "LGBM_TPU_STREAM", "LGBM_TPU_FUSED",
+          "LGBM_TPU_PARTITION", "LGBM_TPU_PART_INTERP",
+          "LGBM_TPU_HIST_SCATTER")
 
 
 def _onehot_problem(n=1024, cats=24, extra=3, seed=5):
@@ -43,7 +43,13 @@ def _onehot_problem(n=1024, cats=24, extra=3, seed=5):
     return x, y
 
 
-def _fresh_train(env, bundle, n=1024, rounds=3, **params):
+def _comb_width(grow):
+    """Line width of the grower's comb; None on the row_order path."""
+    pieces = getattr(grow, "_pieces", None)
+    return pieces.C if pieces is not None else getattr(grow, "_C", None)
+
+
+def _fresh_train(env, bundle, n=1024, rounds=3, cats=24, **params):
     """Train on the one-hot problem in a fresh library generation and
     return (exact tree digests, raw predictions, engaged facts)."""
     saved = _save_env(_KNOBS)
@@ -56,7 +62,7 @@ def _fresh_train(env, bundle, n=1024, rounds=3, **params):
                   if k.startswith("lightgbm_tpu")]:
             del sys.modules[m]
         import lightgbm_tpu as lgb
-        x, y = _onehot_problem(n=n)
+        x, y = _onehot_problem(n=n, cats=cats)
         p = {"objective": "binary", "num_leaves": 15,
              "min_data_in_leaf": 5, "max_bin": 31, "min_data_in_bin": 1,
              "enable_bundle": bundle, "verbosity": -1}
@@ -74,7 +80,7 @@ def _fresh_train(env, bundle, n=1024, rounds=3, **params):
             "pred": bst.predict(x, raw_score=True),
             "routing": inner.routing_info(),
             "bundled": inner.dd.bundle is not None,
-            "pack": int(getattr(inner.grow, "pack", 1)),
+            "comb_C": _comb_width(inner.grow),
         }
     finally:
         _restore_env(saved)
@@ -95,23 +101,25 @@ def _assert_byte_identical(a, b):
 
 
 # ---------------------------------------------------------------------
-# bit-parity matrix: pack x learner, real kernel bodies
+# bit-parity matrix: planes x learner, real kernel bodies.  140 one-hot
+# columns bundle into a few storage columns and UNBUNDLE to a comb line
+# of two 128-lane planes.
 # ---------------------------------------------------------------------
 @pytest.mark.parametrize("learner", ["serial", "data"])
-@pytest.mark.parametrize("pack", ["1", "2"])
-def test_bundled_vs_unbundled_byte_identical(pack, learner):
+@pytest.mark.parametrize("planes", [1, 2])
+def test_bundled_vs_unbundled_byte_identical(planes, learner):
     env = {"LGBM_TPU_PHYS": "interpret",
-           "LGBM_TPU_COMB_PACK": pack,
            "LGBM_TPU_PART_INTERP": "kernel"}
     params = {"tree_learner": learner} if learner != "serial" else {}
-    runs = {f: _fresh_train(env, f, **params) for f in (True, False)}
+    runs = {f: _fresh_train(env, f, cats=24 if planes == 1 else 140,
+                            **params) for f in (True, False)}
     assert runs[True]["bundled"], "EFB did not engage; test is vacuous"
     assert not runs[False]["bundled"]
     for f in (True, False):
         r = runs[f]["routing"]
         assert r["path"] in ("stream", "physical"), \
             (f, r["path"], r["reasons"])
-        assert runs[f]["pack"] == int(pack) == r["pack"], (f, r)
+        assert runs[f]["comb_C"] == 128 * planes, (f, r)
     _assert_byte_identical(runs[True], runs[False])
 
 

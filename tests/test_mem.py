@@ -1,5 +1,5 @@
 """HBM flight recorder (ISSUE 9): footprint-model equality against
-the real grow jaxprs (pack x stream x mesh), the hbm-budget /
+the real grow jaxprs (planes x stream x mesh), the hbm-budget /
 donation-audit pass, the page-schedule planner acceptance pair, the
 ``obs mem`` CLI pins + failure modes, the memory diff gate, and the
 phase-granular residency sampling.
@@ -73,27 +73,27 @@ def _build_grow(n, f, b, L, *, stream=False):
 
 # ---------------------------------------------------------------------
 # footprint-model equality vs the real grow jaxprs (the acceptance
-# criterion: exact bytes, pack=1 AND pack=2, stream on/off, mesh)
+# criterion: exact bytes, one AND two comb planes, stream on/off, mesh)
 # ---------------------------------------------------------------------
-@pytest.mark.parametrize("pack", [1, 2])
+_F_PLANES = {1: 16, 2: 144}     # feature columns -> comb planes
+
+
+@pytest.mark.parametrize("planes", [1, 2])
 @pytest.mark.parametrize("stream", [False, True])
-def test_footprint_equals_grow_jaxpr(monkeypatch, pack, stream):
+def test_footprint_equals_grow_jaxpr(planes, stream):
     import jax
     import jax.numpy as jnp
-    monkeypatch.setenv("LGBM_TPU_COMB_PACK", str(pack))
-    n, f, b, L = 4096, 16, 32, 8
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    n, f, b, L = 4096, _F_PLANES[planes], 32, 8
     gp = _build_grow(n, f, b, L, stream=stream)
     fp = costmodel.grow_footprint(
-        rows=n, f_pad=f, padded_bins=b, num_leaves=L, pack=pack,
+        rows=n, f_pad=f, padded_bins=b, num_leaves=L,
         stream=stream, fused=gp.fused, rows_padded=True)
     geo = fp["geometry"]
-    assert geo["pack"] == gp.pack == pack
     assert geo["n_alloc"] == gp._n_alloc
-    assert geo["C"] == gp._C
+    assert geo["C"] == gp._C == 128 * planes
 
-    n_phys = gp._n_alloc // gp.pack
-    args = [_sds((n_phys, gp._C), jnp.float32),
-            _sds((n_phys, gp._C), jnp.float32)]
+    args = [_sds(comb_shape(gp._n_alloc, gp._C), jnp.float32)] * 2
     args += [_sds((1,) if stream else (n,), jnp.float32)] * 3
     args += [_sds((f,), jnp.float32), _sds((f,), jnp.int32),
              _sds((f,), jnp.bool_), _sds((f,), jnp.bool_),
@@ -104,10 +104,12 @@ def test_footprint_equals_grow_jaxpr(monkeypatch, pack, stream):
     traced = jax.make_jaxpr(gp._grow_p)(*args)
     invars = [v.aval for v in traced.jaxpr.invars]
 
-    # comb / scratch: EXACT equality, shape and bytes
+    # comb / scratch: EXACT equality, shape (the model's lines x lanes,
+    # stored plane-major) and bytes
     for idx, name in ((0, "comb"), (1, "scratch")):
         buf = fp["buffers"][name]
-        assert buf["shape"] == tuple(invars[idx].shape), name
+        assert comb_shape(*buf["shape"]) == tuple(invars[idx].shape), \
+            name
         assert buf["bytes"] == _aval_bytes(invars[idx]), name
     if not stream:
         for idx, name in ((2, "grad"), (3, "hess"), (4, "inbag")):
@@ -151,7 +153,7 @@ def test_footprint_equals_batched_mc_grow_jaxpr():
     assert geo["num_class"] == k and geo["mc_batched"] is True
     assert geo["n_alloc"] == gp._n_alloc and geo["C"] == gp._C
 
-    n_phys = gp._n_alloc // gp.pack
+    n_phys = gp._n_alloc
     args = [_sds((n_phys, gp._C), jnp.float32),
             _sds((n_phys, gp._C), jnp.float32),
             _sds((k, n), jnp.float32), _sds((k, n), jnp.float32),
@@ -255,7 +257,7 @@ def test_footprint_equals_grow_jaxpr_efb():
     assert fp["buffers"]["bins"]["shape"] == (n, f_phys)
     assert fp["buffers"]["bins"]["bytes"] == n * f_phys
 
-    n_phys = gp._n_alloc // gp.pack
+    n_phys = gp._n_alloc
     args = [_sds((n_phys, gp._C), jnp.float32),
             _sds((n_phys, gp._C), jnp.float32)]
     args += [_sds((n,), jnp.float32)] * 3
@@ -277,16 +279,15 @@ def test_footprint_equals_grow_jaxpr_efb():
         f"pool {pool['shape']} not in the traced EFB grow program"
 
 
-def test_footprint_matches_mesh_pieces(monkeypatch):
+def test_footprint_matches_mesh_pieces():
     """Mesh cell of the matrix: the per-shard layout constants the
     data-parallel grower receives (MeshPhysicalPieces) equal the model
-    geometry at n_shards=2, pack=1 AND pack=2."""
+    geometry at n_shards=2, at one AND two comb planes."""
     import jax.numpy as jnp
     from lightgbm_tpu.ops.grow import make_grow_fn
     from lightgbm_tpu.ops.split import SplitHyperParams
-    n_global, f, b, L = 8192, 16, 32, 8
-    for pack in (1, 2):
-        monkeypatch.setenv("LGBM_TPU_COMB_PACK", str(pack))
+    n_global, b, L = 8192, 32, 8
+    for planes, f in _F_PLANES.items():
         n_local = n_global // 2
         pieces = make_grow_fn(
             SplitHyperParams(min_data_in_leaf=2), num_leaves=L,
@@ -294,42 +295,31 @@ def test_footprint_matches_mesh_pieces(monkeypatch):
             physical_bins=_sds((n_local, f), jnp.uint8))
         fp = costmodel.grow_footprint(
             rows=n_global, f_pad=f, padded_bins=b, num_leaves=L,
-            pack=pack, n_shards=2, rows_padded=True)
+            n_shards=2, rows_padded=True)
         geo = fp["geometry"]
         assert geo["n_local"] == pieces.n_local == n_local
         assert geo["n_alloc"] == pieces.n_alloc
-        assert geo["C"] == pieces.C
-        assert geo["pack"] == pieces.pack == pack
+        assert geo["C"] == pieces.C == 128 * planes
         comb = fp["buffers"]["comb"]
-        assert comb["shape"] == (pieces.n_alloc // pieces.pack,
-                                 pieces.C)
+        assert comb["shape"] == (pieces.n_alloc, pieces.C)
 
 
-def test_footprint_pack_fallback_and_peak():
-    """pack=2 with a too-wide layout falls back to 1 (the
-    comb_pack_choice rule), and pack=2 halves the comb line bytes per
-    logical row."""
-    fp2 = costmodel.grow_footprint(rows=4096, f_pad=16, padded_bins=32,
-                                   num_leaves=8, pack=2,
-                                   rows_padded=True)
-    assert fp2["geometry"]["pack"] == 2
-    fp1 = costmodel.grow_footprint(rows=4096, f_pad=16, padded_bins=32,
-                                   num_leaves=8, pack=1,
-                                   rows_padded=True)
-    # pack=2: half the physical lines, so half the comb bytes
-    assert fp2["buffers"]["comb"]["bytes"] * 2 \
-        == fp1["buffers"]["comb"]["bytes"]
-    # same n_alloc, half the physical lines
-    assert fp2["buffers"]["comb"]["shape"][0] * 2 \
-        == fp1["buffers"]["comb"]["shape"][0]
-    # 100 logical columns cannot pack
-    wide = costmodel.grow_footprint(rows=4096, f_pad=100,
-                                    padded_bins=32, num_leaves=8,
-                                    pack=2, rows_padded=True)
-    assert wide["geometry"]["pack"] == 1
+def test_footprint_msltr_comb_bytes_and_peak():
+    """The model at the shape ``msltr-train-2m`` runs - 2,270,296 rows,
+    137 features padded to 144, a line of two planes: 2,275,840 lines
+    x 1,024 B = 2.33e9 bytes a comb-sized array, comb + scratch 4.66e9
+    (PERF.md section 5)."""
+    fp = costmodel.grow_footprint(rows=2_270_296, f_pad=144,
+                                  padded_bins=256, num_leaves=255)
+    geo = fp["geometry"]
+    assert (geo["n_alloc"], geo["C"]) == (2_275_840, 256)
+    comb = fp["buffers"]["comb"]
+    assert comb["shape"] == (2_275_840, 256)
+    assert comb["bytes"] == 2_275_840 * 1_024 == 2_330_460_160
+    assert fp["buffers"]["scratch"]["bytes"] == comb["bytes"]
     # the peak is the max phase live-set
-    assert fp1["peak_bytes"] == max(fp1["phase_live"].values())
-    assert fp1["peak_phase"] in fp1["phase_live"]
+    assert fp["peak_bytes"] == max(fp["phase_live"].values())
+    assert fp["peak_phase"] in fp["phase_live"]
 
 
 def test_hbm_budget_knobs(monkeypatch):
@@ -420,55 +410,54 @@ def test_page_schedule_force_pages_a_fitting_shape():
 # grow program must be the unpaged one (grow-paged-off purity pin's
 # buffer-level counterpart)
 # ---------------------------------------------------------------------
-@pytest.mark.parametrize("pack", [1, 2])
-def test_paged_page_buffers_match_plan(monkeypatch, pack):
+@pytest.mark.parametrize("planes", [1, 2])
+def test_paged_page_buffers_match_plan(planes):
     import jax
     import jax.numpy as jnp
-    monkeypatch.setenv("LGBM_TPU_COMB_PACK", str(pack))
     from lightgbm_tpu.ops.paged import PageStore
-    n, f, b, L = 8192, 16, 32, 8
+    from lightgbm_tpu.ops.pallas.layout import (comb_operand_shape,
+                                                comb_shape)
+    n, f, b, L = 8192, _F_PLANES[planes], 32, 8
     rpp = 2048
     gp = _build_grow(n, f, b, L, stream=True)
     plan = costmodel.page_schedule(
-        rows=n, f_pad=f, padded_bins=b, num_leaves=L, pack=pack,
+        rows=n, f_pad=f, padded_bins=b, num_leaves=L,
         stream=True, rows_per_page=rpp)
     assert plan["paged"]
-    geo_pack = plan["pack"]
-    assert geo_pack == gp.pack
     store = PageStore(n_alloc=gp._n_alloc, C=gp._C,
-                      rows_per_page=rpp, pack=gp.pack)
+                      rows_per_page=rpp)
+    assert store.C == 128 * planes
+    window = comb_shape(store.n_alloc, store.C)
+    page = comb_operand_shape(store.page_lines, store.C)
     # engaged geometry == plan geometry
     assert store.page_lines == plan["page_lines"]
     assert store.n_pages == plan["n_pages"]
     assert plan["page_bytes"] == store.page_lines * store.C * 4
     assert plan["C"] == store.C and plan["n_alloc"] == store.n_alloc
-    # the REAL paged jaxprs: window update consumes exactly one
-    # [page_lines, C] page buffer + the [n_lines, C] window; extract
-    # produces exactly one page buffer
+    # the REAL paged jaxprs: window update consumes exactly one page
+    # buffer of page_lines lines (a line range of every plane) + the
+    # plane-major window; extract produces exactly one page buffer
     upd = jax.make_jaxpr(store._update_fn())(
-        _sds((store.n_lines, store.C), jnp.float32),
-        _sds((store.page_lines, store.C), jnp.float32),
+        _sds(window, jnp.float32), _sds(page, jnp.float32),
         _sds((), jnp.int32), _sds((), jnp.int32))
     page_bytes = [
         _aval_bytes(a) for a in _all_avals(upd)
-        if tuple(a.shape) == (store.page_lines, store.C)
-        and a.dtype == jnp.float32]
+        if tuple(a.shape) == page and a.dtype == jnp.float32]
     assert page_bytes and all(bb == plan["page_bytes"]
                               for bb in page_bytes)
     window_avals = [a for a in _all_avals(upd)
-                    if tuple(a.shape) == (store.n_lines, store.C)
+                    if tuple(a.shape) == window
                     and a.dtype == jnp.float32]
     fp = costmodel.grow_footprint(
-        rows=n, f_pad=f, padded_bins=b, num_leaves=L, pack=pack,
+        rows=n, f_pad=f, padded_bins=b, num_leaves=L,
         stream=True, fused=gp.fused, rows_padded=True)
     assert window_avals and all(
         _aval_bytes(a) == fp["buffers"]["comb"]["bytes"]
         for a in window_avals)
     ext = jax.make_jaxpr(store._extract_fn())(
-        _sds((store.n_lines, store.C), jnp.float32),
-        _sds((), jnp.int32))
+        _sds(window, jnp.float32), _sds((), jnp.int32))
     out_aval = ext.jaxpr.outvars[0].aval
-    assert tuple(out_aval.shape) == (store.page_lines, store.C)
+    assert tuple(out_aval.shape) == page
     assert _aval_bytes(out_aval) == plan["page_bytes"]
 
 

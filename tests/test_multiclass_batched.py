@@ -7,7 +7,7 @@ permutation into class k exactly like the serial loop does, so the
 trees must be BYTE-identical to serial-K — same tree_seed schedule,
 same feature-fraction RNG draws (active classes only, in class
 order), same quantized-gain tie-breaks.  These tests pin that bar
-across the routing matrix (pack x partition scheme x fused x
+across the routing matrix (partition scheme x fused x
 serial/8-shard mesh, K in {3, 4}) through the REAL partition kernels
 (``LGBM_TPU_PART_INTERP=kernel``), plus the two per-class semantics
 the batch must not flatten:
@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 
 _MC_ENV = ("LGBM_TPU_PHYS", "LGBM_TPU_PART_INTERP", "LGBM_TPU_PARTITION",
-           "LGBM_TPU_FUSED", "LGBM_TPU_COMB_PACK", "LGBM_TPU_MC_BATCH",
+           "LGBM_TPU_FUSED", "LGBM_TPU_MC_BATCH",
            "LGBM_TPU_HIST_SCATTER", "LGBM_TPU_NUMERICS")
 
 
@@ -51,7 +51,7 @@ def _digests(bst):
     return out
 
 
-def _train_mc(mcb, k, pack="1", partition="permute", fused="1",
+def _train_mc(mcb, k, partition="permute", fused="1",
               learner="serial", rounds=2, n=1200, fobj=None,
               numerics=None, **params):
     """One (knob-cell, K) multiclass run; returns (digests, engaged,
@@ -60,13 +60,7 @@ def _train_mc(mcb, k, pack="1", partition="permute", fused="1",
            "LGBM_TPU_PART_INTERP": "kernel",
            "LGBM_TPU_PARTITION": partition,
            "LGBM_TPU_FUSED": fused,
-           "LGBM_TPU_COMB_PACK": pack,
            "LGBM_TPU_MC_BATCH": mcb}
-    if learner == "data" and pack == "2":
-        # hist_scatter's column padding (features x 8 shards) blows the
-        # 64-column pack=2 budget; keep the mesh pack cell on the full
-        # psum merge so pack=2 actually engages (test_physical idiom)
-        env["LGBM_TPU_HIST_SCATTER"] = "0"
     if numerics is not None:
         env["LGBM_TPU_NUMERICS"] = numerics
     saved = {kk: os.environ.get(kk) for kk in _MC_ENV}
@@ -116,37 +110,36 @@ def _assert_parity(cell_b, cell_s, k, rounds):
 # ---------------------------------------------------------------------
 # the parity matrix (byte-identical trees, batched vs serial-K)
 # ---------------------------------------------------------------------
-@pytest.mark.parametrize("k,pack,partition,fused,learner", [
-    (3, "1", "permute", "1", "serial"),
-    (3, "1", "matmul", "0", "serial"),
+@pytest.mark.parametrize("k,partition,fused,learner", [
+    (3, "permute", "1", "serial"),
+    (3, "matmul", "0", "serial"),
 ])
-def test_batched_matches_serial(k, pack, partition, fused, learner):
+def test_batched_matches_serial(k, partition, fused, learner):
     kw = {}
     if learner == "data":
         kw = {"tree_learner": "data", "max_bin": 31,
               "min_data_in_leaf": 5}
-    b = _train_mc("auto", k, pack, partition, fused, learner, **kw)
-    s = _train_mc("0", k, pack, partition, fused, learner, **kw)
+    b = _train_mc("auto", k, partition, fused, learner, **kw)
+    s = _train_mc("0", k, partition, fused, learner, **kw)
     _assert_parity(b, s, k, rounds=2)
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("k,pack,partition,fused,learner", [
-    (4, "2", "permute", "1", "serial"),
-    (4, "1", "permute", "1", "data"),
-    (3, "2", "matmul", "1", "serial"),
-    (4, "1", "matmul", "0", "serial"),
-    (3, "2", "permute", "0", "data"),
-    (3, "1", "permute", "1", "data"),
+@pytest.mark.parametrize("k,partition,fused,learner", [
+    (4, "permute", "1", "serial"),
+    (4, "permute", "1", "data"),
+    (3, "matmul", "1", "serial"),
+    (4, "matmul", "0", "serial"),
+    (3, "permute", "0", "data"),
+    (3, "permute", "1", "data"),
 ])
-def test_batched_matches_serial_full(k, pack, partition, fused,
-                                     learner):
+def test_batched_matches_serial_full(k, partition, fused, learner):
     kw = {}
     if learner == "data":
         kw = {"tree_learner": "data", "max_bin": 31,
               "min_data_in_leaf": 5}
-    b = _train_mc("auto", k, pack, partition, fused, learner, **kw)
-    s = _train_mc("0", k, pack, partition, fused, learner, **kw)
+    b = _train_mc("auto", k, partition, fused, learner, **kw)
+    s = _train_mc("0", k, partition, fused, learner, **kw)
     _assert_parity(b, s, k, rounds=2)
 
 

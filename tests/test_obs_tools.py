@@ -6,7 +6,7 @@ Covers the tentpole contracts and satellites:
 * cost-model EXACTNESS: the partition / histogram byte predictions in
   ``obs/costmodel.py`` equal the kernel-contract bytes derived
   independently from the row-movement oracle (the same oracle
-  ``tests/test_partition_perm.py`` pins), for pack=1 AND pack=2, with
+  ``tests/test_partition_perm.py`` pins), at one AND two comb planes, with
   the real kernels run through the Pallas interpreter;
 * the regression gate: self-diff exact-clean, thresholded walls,
   exact counters, knob-mismatch refusal, median-of-k noise immunity,
@@ -82,9 +82,9 @@ class TestCostModelExactness:
     derived independently from the partition oracle: the scan reads
     and writes every row in the window once, the copyback re-reads and
     re-writes the right segment, and every logical row touch moves
-    LANE * itemsize / pack bytes."""
+    one line: C * itemsize bytes."""
 
-    def test_partition_bytes_pack1_match_kernel_contract(self):
+    def test_partition_bytes_match_kernel_contract(self):
         import jax.numpy as jnp
 
         from lightgbm_tpu.ops.pallas.layout import LANE
@@ -117,49 +117,47 @@ class TestCostModelExactness:
             touches = cnt + cnt + 2 * (cnt - nl)
             contract_bytes = touches * LANE * 4
             assert costmodel.partition_split_bytes(
-                cnt, nl, pack=1) == contract_bytes
+                cnt, nl) == contract_bytes
 
-    def test_partition_bytes_pack2_match_kernel_contract(self):
+    def test_partition_bytes_two_plane_match_kernel_contract(self):
+        """A comb line of two 128-lane planes: the same touches, each
+        one row DMA a plane - twice the bytes of a one-plane line."""
         import jax.numpy as jnp
 
-        from lightgbm_tpu.ops.pallas.layout import LANE
+        from lightgbm_tpu.ops.pallas.layout import LANE, to_planes
         from lightgbm_tpu.ops.pallas.partition_kernel import (SEL_CNT,
                                                               SEL_S0)
         from lightgbm_tpu.ops.pallas.partition_kernel3 import \
-            make_partition_p2
+            make_partition_perm
 
-        r2, size2 = 64, 512
-        n2 = size2 + 4 * r2 + 256
-        w = LANE // 2
+        R, C, SIZE = 128, 2 * LANE, 1024
+        N = SIZE + 3 * R + 4096
         rng = np.random.default_rng(2)
-        logical = np.zeros((n2, w), np.float32)
-        logical[:, :8] = rng.integers(0, 32, size=(n2, 8))
-        packed = jnp.asarray(logical.reshape(n2 // 2, LANE))
-        part = make_partition_p2(n2, R=r2, size=size2, interpret=True,
-                                 interpret_kernel=True, cb_block=64)
-        for s0, cnt, feat, sbin in ((64, 400, 3, 15), (65, 401, 3, 15),
-                                    (17, 511, 7, 30)):
+        rows = np.zeros((N, C), np.float32)
+        rows[:, 130:138] = rng.integers(0, 32, size=(N, 8))
+        comb = to_planes(jnp.asarray(rows))
+        part = make_partition_perm(N, C, R=R, size=SIZE, interpret=True,
+                                   interpret_kernel=True)
+        for s0, cnt, feat, sbin in ((64, 400, 133, 15),
+                                    (65, 401, 133, 15),
+                                    (17, 511, 137, 30)):
             sel = np.zeros((8,), np.int32)
             sel[SEL_S0], sel[SEL_CNT], sel[2], sel[3] = (s0, cnt, feat,
                                                          sbin)
             sel[6] = -1
-            _, _, nl = part(jnp.asarray(sel), packed,
-                            jnp.zeros_like(packed))
+            _, _, nl = part(jnp.asarray(sel), comb, jnp.zeros_like(comb))
             nl = int(nl)
-            assert nl == int((logical[s0:s0 + cnt, feat] <= sbin).sum())
-            # pack=2: each LOGICAL row touch moves HALF a line — the
-            # ISSUE-4 bytes-halved claim, as an equality
+            assert nl == int((rows[s0:s0 + cnt, feat] <= sbin).sum())
             touches = 2 * cnt + 2 * (cnt - nl)
-            contract_bytes = touches * (LANE * 4 // 2)
+            contract_bytes = touches * C * 4
             assert costmodel.partition_split_bytes(
-                cnt, nl, pack=2) == contract_bytes
-            assert costmodel.partition_split_bytes(cnt, nl, pack=2) * 2 \
-                == costmodel.partition_split_bytes(cnt, nl, pack=1)
+                cnt, nl, c_phys=C) == contract_bytes
+            assert costmodel.partition_split_bytes(cnt, nl, c_phys=C) \
+                == 2 * costmodel.partition_split_bytes(cnt, nl)
 
     def test_hist_bytes_match_kernel_contract(self):
         """The comb-direct histogram build reads each window row once
-        and writes one [f_pad, padded_bins, 2] f32 histogram — for
-        pack=1 and pack=2 (same logical rows, half the line bytes)."""
+        and writes one [f_pad, padded_bins, 2] f32 histogram."""
         import jax.numpy as jnp
 
         from lightgbm_tpu.ops.pallas.hist_kernel2 import \
@@ -180,20 +178,17 @@ class TestCostModelExactness:
         # output buffer
         assert costmodel.hist_out_bytes(f_pad, padded_bins) \
             == h1.size * h1.dtype.itemsize
-        for pack in (1, 2):
-            contract_bytes = cnt * (LANE * 4 // pack) \
-                + h1.size * h1.dtype.itemsize
-            assert costmodel.hist_build_bytes(
-                cnt, f_pad=f_pad, padded_bins=padded_bins,
-                pack=pack) == contract_bytes
+        contract_bytes = cnt * LANE * 4 + h1.size * h1.dtype.itemsize
+        assert costmodel.hist_build_bytes(
+            cnt, f_pad=f_pad, padded_bins=padded_bins) == contract_bytes
         # fused = partition + the scan's ONE histogram write, nothing
         # else (the deleted child re-read is the fusion win) - and a
         # whole build of the smaller child where the scan was told the
         # other side
         nl = 400
-        part = costmodel.partition_split_bytes(cnt, nl, pack=1)
+        part = costmodel.partition_split_bytes(cnt, nl)
         hw = costmodel.hist_out_bytes(f_pad, padded_bins)
-        kw = dict(f_pad=f_pad, padded_bins=padded_bins, pack=1)
+        kw = dict(f_pad=f_pad, padded_bins=padded_bins)
         assert costmodel.fused_split_bytes(cnt, nl, **kw) == part + hw
         assert costmodel.fused_split_bytes(cnt, nl, rehist_rows=nl, **kw) \
             == part + hw + costmodel.hist_build_bytes(nl, **kw)
@@ -266,13 +261,12 @@ class TestCostModelExactness:
                          "fused_splits": 10},
             "shape": {"rows": 10_000, "f_pad": 32, "padded_bins": 256,
                       "trees": 2, "stream": True},
-            "knobs": {"comb_pack": 2, "partition": "permute",
-                      "fused": True},
+            "knobs": {"partition": "permute", "fused": True},
             "phases": {"Tree::grow": {"total_s": 0.01, "count": 2,
                                       "mean_s": 0.005}},
         }
         model = costmodel.phase_model(rec)
-        lrb = costmodel.logical_row_bytes(pack=2)
+        lrb = costmodel.logical_row_bytes()
         # the whole-loop counter totals land on Tree::grow, the one
         # span whose measured wall covers every split; the root-scale
         # sampled Split / ConstructHistogram probes are gone (ISSUE 27)
@@ -288,8 +282,7 @@ class TestCostModelExactness:
         # unfused vs fused, mirroring the per-split contracts: the
         # smaller-child re-read comes back (rows_hist 40k vs the 20k
         # root passes); one histogram write per split either way
-        unfused = dict(rec, knobs={"comb_pack": 2,
-                                   "partition": "permute",
+        unfused = dict(rec, knobs={"partition": "permute",
                                    "fused": False})
         mu = costmodel.phase_model(unfused)
         hw = costmodel.hist_out_bytes(32, 256)
@@ -324,7 +317,7 @@ def _rec(value=10.0, phases=None, counters_d=None, knobs=None,
          events_d=None, ledger_iters=None, schema="lightgbm_tpu/bench/v3"):
     rec = {"schema": schema, "metric": "iters", "value": value,
            "unit": "iters/sec", "backend": "cpu",
-           "knobs": knobs or {"comb_pack": 1, "fused": True}}
+           "knobs": knobs or {"partition": "permute", "fused": True}}
     if phases is not None:
         rec["phases"] = phases
     if counters_d is not None:
@@ -388,16 +381,16 @@ class TestDiff:
 
     def test_event_appearance_flagged(self):
         a = _rec()
-        b = _rec(events_d={"comb_pack_fallback": 1})
+        b = _rec(events_d={"hist_scatter_psum_fallback": 1})
         f, _ = regress.diff_records(a, b)
         regs = regress.regressions(f)
         assert len(regs) == 1 and regs[0]["kind"] == "event"
 
     def test_knob_mismatch_incomparable(self):
-        a = _rec(knobs={"comb_pack": 1, "fused": True})
-        b = _rec(knobs={"comb_pack": 2, "fused": True})
+        a = _rec(knobs={"partition": "permute", "fused": True})
+        b = _rec(knobs={"partition": "matmul", "fused": True})
         _, incomp = regress.diff_records(a, b)
-        assert incomp and "comb_pack" in incomp[0]
+        assert incomp and "partition" in incomp[0]
         _, incomp = regress.diff_records(a, b, check_knobs=False)
         assert not incomp
 
@@ -553,7 +546,7 @@ class TestCliRobustness:
                          "rows_histogrammed": 800, "fused_splits": 4},
             "shape": {"rows": 500, "f_pad": 16, "padded_bins": 64,
                       "trees": 1},
-            "knobs": {"comb_pack": 1, "fused": True},
+            "knobs": {"partition": "permute", "fused": True},
             "phases": {"Split": {"total_s": 0.01, "count": 1,
                                  "mean_s": 0.01}}}
         p = tmp_path / "v3.json"
@@ -611,26 +604,27 @@ class TestLifecycle:
     def test_events_and_warn_once_reset(self):
         _, obs = _cur()
         from lightgbm_tpu.ops import grow as grow_mod
+        from lightgbm_tpu.ops import routing as routing_mod
         obs.events.record("stale_event")
         grow_mod._HIST_SCATTER_WARNED.add((28, 8))
-        grow_mod._PACK_FALLBACK_WARNED.add(100)
+        routing_mod._ROUTING_WARNED.add("gpu_use_dp")
         obs.reset_run()
         assert obs.events.totals() == {}
         assert not grow_mod._HIST_SCATTER_WARNED
-        assert not grow_mod._PACK_FALLBACK_WARNED
+        assert not routing_mod._ROUTING_WARNED
 
     def test_train_resets_events_and_warn_once(self):
         lgb, obs = _cur()
         from lightgbm_tpu.ops import grow as grow_mod
         obs.events.record("stale_event")
-        grow_mod._PACK_FALLBACK_WARNED.add(77)
+        grow_mod._HIST_SCATTER_WARNED.add((77, 8))
         x, y = _make_problem(n=400)
         lgb.train({"objective": "binary", "num_leaves": 4,
                    "verbosity": -1, "max_bin": 63},
                   lgb.Dataset(x, label=y, params={"max_bin": 63}),
                   num_boost_round=1)
         assert "stale_event" not in obs.events.totals()
-        assert 77 not in grow_mod._PACK_FALLBACK_WARNED
+        assert (77, 8) not in grow_mod._HIST_SCATTER_WARNED
 
     def test_thread_safe_recording(self):
         _, obs = _cur()
@@ -837,12 +831,11 @@ class TestXplaneDecoder:
             "_serve_kernel": "serve_traverse",
             "_serve_traverse_block": "serve_traverse",
             "_fused_scan_kernel": "fused_split",
-            "_fused_scan_kernel_p2": "fused_split",
             "_scan_kernel": "partition_scan",
             "_partition_kernel": "partition_scan",
-            "_copyback_kernel_p2": "partition_copyback",
+            "_copyback_kernel": "partition_copyback",
             "_hist2_comb_kernel": "hist_build",
-            "_refresh_hist_kernel_p2": "stream_refresh",
+            "_refresh_hist_kernel": "stream_refresh",
             "_init_kernel": "stream_refresh",
             "_apply_find_pool_kernel": "find_split",
             "all-reduce.17": "collective",
@@ -878,7 +871,7 @@ class TestKernelModel:
     def test_fused_stream_classes(self):
         rec = xattr.synthetic_bench_record()
         model = costmodel.kernel_model(rec)
-        lrb = costmodel.logical_row_bytes(pack=2)
+        lrb = costmodel.logical_row_bytes()
         hw = costmodel.hist_out_bytes(32, 256)
         fs = model["fused_split"]
         # the scan writes ONE child's histogram a split (ISSUE 30) ...
@@ -891,7 +884,7 @@ class TestKernelModel:
         assert model["hist_build"]["bytes"] == 0
         assert model["stream_refresh"]["bytes"] == \
             3 * costmodel.stream_refresh_bytes(
-                10_000, pack=2, root_hist=True, f_pad=32,
+                10_000, root_hist=True, f_pad=32,
                 padded_bins=256)
         assert "partition_scan" not in model
         assert "collective" not in model
@@ -903,7 +896,7 @@ class TestKernelModel:
         rec["ledger"] = {"collectives": [{"name": "g", "bytes_moved":
                                          1000}, {"bytes_moved": 500}]}
         model = costmodel.kernel_model(rec)
-        lrb = costmodel.logical_row_bytes(pack=2)
+        lrb = costmodel.logical_row_bytes()
         hw = costmodel.hist_out_bytes(32, 256)
         assert model["partition_scan"]["bytes"] == 2 * 200_000 * lrb
         cb = model["partition_copyback"]
@@ -1120,16 +1113,18 @@ def test_provenance_header_and_bench_v3():
 # measured-vs-predicted ICI join, multichip diff gates
 # ---------------------------------------------------------------------
 class TestMeshFlightRecorder:
-    def _train_mesh(self, n=1600, f=8, rounds=2, leaves=8):
+    def _train_mesh(self, n=1600, f=8, rounds=2, leaves=8,
+                    max_bin=63):
         """Traced data-parallel training on the 8-CPU mesh; returns
         (booster, collectives, mesh_summary, n_rows)."""
         lgb, obs = _cur()
         obs.tracer.enable(None)
         x, y = _make_problem(n=n, f=f)
-        ds = lgb.Dataset(x, label=y, params={"max_bin": 63})
+        ds = lgb.Dataset(x, label=y, params={"max_bin": max_bin})
         bst = lgb.Booster(params={
             "objective": "binary", "num_leaves": leaves,
-            "verbosity": -1, "max_bin": 63, "tree_learner": "data"},
+            "verbosity": -1, "max_bin": max_bin,
+            "tree_learner": "data"},
             train_set=ds)
         for _ in range(rounds):
             bst.update()
@@ -1169,24 +1164,24 @@ class TestMeshFlightRecorder:
         assert mesh["bytes_moved_total"] == expect * len(colls)
         assert len(mesh["skew_series"]) == len(colls)
 
-    def test_per_shard_ledger_equivalence_pack1(self):
+    def test_per_shard_ledger_equivalence(self):
         bst, colls, mesh, n = self._train_mesh()
-        assert int(getattr(bst._inner.grow, "pack", 1)) == 1
         self._check_per_shard(bst, colls, mesh, n, leaves=8)
 
-    def test_per_shard_ledger_equivalence_pack2(self, monkeypatch):
-        """Same contract through the pack=2 physical mesh path: the
-        collective bytes are histogram payloads, so they must be
-        IDENTICAL to pack=1 (packing halves comb DMA, not ICI)."""
+    def test_per_shard_ledger_equivalence_two_plane(self, monkeypatch):
+        """Same contract through the physical mesh path at a comb line
+        of two 128-lane planes (128 feature columns + the 6 value /
+        row-id ones): the collective bytes are histogram payloads of
+        the 128 columns, whatever the line holds beside them."""
         monkeypatch.setenv("LGBM_TPU_PHYS", "interpret")
-        monkeypatch.setenv("LGBM_TPU_COMB_PACK", "2")
         # 8192 rows = 8 shards x 2 full PHYS_R=512 partition blocks:
         # every shard holds real rows, so the skew series is defined
         # (an emptier n leaves whole shards as padding — in-bag 0 —
         # and the ratio honestly degenerates to None)
-        bst, colls, mesh, n = self._train_mesh(n=8192, rounds=1)
+        bst, colls, mesh, n = self._train_mesh(n=8192, f=128, rounds=1,
+                                               max_bin=15)
         assert bst._inner.grow.physical
-        assert int(bst._inner.grow.pack) == 2
+        assert bst._inner.grow._pieces.C == 256
         self._check_per_shard(bst, colls, mesh, n, leaves=8)
 
     def test_ledger_mesh_summary_skew_series(self):

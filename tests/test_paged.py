@@ -6,7 +6,7 @@ Pins the tentpole contracts off-chip:
   the audit actually detects broken schedules (the dma-race pass's
   page-granularity rules);
 * paged and unpaged training produce BYTE-IDENTICAL trees across the
-  pack x partition-scheme x fused x stream matrix, through the REAL
+  width x partition-scheme x fused x stream matrix, through the REAL
   scan/copyback kernels (LGBM_TPU_PART_INTERP=kernel);
 * the engaged page geometry equals ``costmodel.page_schedule``'s plan;
 * the routing model's paged dimension (engagement, named losses);
@@ -22,7 +22,7 @@ import pytest
 # knobs any cell below may set; saved/restored around each fresh-import
 # train (the tests/test_physical.py convention)
 KNOBS = ("LGBM_TPU_PHYS", "LGBM_TPU_PART_INTERP", "LGBM_TPU_PARTITION",
-         "LGBM_TPU_FUSED", "LGBM_TPU_COMB_PACK", "LGBM_TPU_STREAM",
+         "LGBM_TPU_FUSED", "LGBM_TPU_STREAM",
          "LGBM_TPU_PAGED", "LGBM_TPU_PAGE_ROWS", "LGBM_TPU_HBM_LIMIT_GB",
          "LGBM_TPU_CKPT_DIR", "LGBM_TPU_CKPT_EVERY",
          "LGBM_TPU_CKPT_AT_REFRESH", "LGBM_TPU_CKPT_KEEP")
@@ -201,27 +201,31 @@ class TestPageStore:
 # ---------------------------------------------------------------------
 # byte-identical trees: the acceptance matrix
 # ---------------------------------------------------------------------
+# (env, feature columns): 130 columns make a comb line of two 128-lane
+# planes, so a page is the same line range of both
 PARITY_CELLS = {
-    "stream_pack1_permute_fused": {},
-    "stream_pack1_permute_unfused": {"LGBM_TPU_FUSED": "0"},
-    "stream_pack1_matmul_fused": {"LGBM_TPU_PARTITION": "matmul"},
-    "stream_pack2_permute_fused": {"LGBM_TPU_COMB_PACK": "2"},
-    "physical_pack1_permute_fused": {"LGBM_TPU_STREAM": "0"},
-    "physical_pack2_permute_fused": {"LGBM_TPU_STREAM": "0",
-                                     "LGBM_TPU_COMB_PACK": "2"},
+    "stream_permute_fused": ({}, 6),
+    "stream_permute_unfused": ({"LGBM_TPU_FUSED": "0"}, 6),
+    "stream_matmul_fused": ({"LGBM_TPU_PARTITION": "matmul"}, 6),
+    "stream_two_plane_permute_fused": ({}, 130),
+    "physical_permute_fused": ({"LGBM_TPU_STREAM": "0"}, 6),
+    "physical_two_plane_permute_fused": ({"LGBM_TPU_STREAM": "0"}, 130),
 }
 
 
 class TestPagedParity:
     @pytest.mark.parametrize("cell", sorted(PARITY_CELLS))
     def test_paged_trees_byte_identical(self, cell):
-        env = dict(BASE_ENV, **PARITY_CELLS[cell])
-        t_ref, info_ref, _, _, _ = _train(env)
+        cell_env, f = PARITY_CELLS[cell]
+        env = dict(BASE_ENV, **cell_env)
+        t_ref, info_ref, _, _, _ = _train(env, f=f)
         assert not info_ref["paged"]
         t_pg, info_pg, _, _, _ = _train(
-            dict(env, LGBM_TPU_PAGED="1", LGBM_TPU_PAGE_ROWS="512"))
+            dict(env, LGBM_TPU_PAGED="1", LGBM_TPU_PAGE_ROWS="512"), f=f)
         assert info_pg["paged"], info_pg
         assert info_pg["page_plan"]["n_pages"] >= 2
+        assert info_pg["page_plan"]["engaged"]["C"] \
+            == 128 * (1 + (f > 6)), "cell is vacuous"
         assert t_ref == t_pg, (
             f"{cell}: paged trees diverged from the unpaged run")
 
@@ -262,7 +266,7 @@ class TestPagedParity:
         ref = page_schedule(
             rows=geo["n_pad"], f_pad=geo["phys_f_pad"],
             padded_bins=geo["phys_padded_bins"], num_leaves=7,
-            pack=1, stream=True, fused=True,
+            stream=True, fused=True,
             limit_bytes=int(0.012 * 2**30))
         assert ref["paged"] and ref["fits"]
         plan = info_pg["page_plan"]
@@ -304,7 +308,7 @@ class TestPagedRouting:
 
     def test_over_budget_priced_at_engaged_geometry(self, monkeypatch):
         # review regression: over_budget must be priced at the FINAL
-        # engaged fused/pack geometry, not the provisional decision's
+        # engaged fused geometry, not the provisional decision's
         # defaults — a budget landing between the fused and unfused
         # peaks of a fused-unsupported shape would otherwise make
         # routing promise a paging the planner then refuses (crash)
@@ -339,7 +343,7 @@ class TestPagedRouting:
         d2 = routing.decide(r2)
         assert r2.over_budget and d2.paged
         plan = plan_pages(rows=102400, f_pad=fp_shape, padded_bins=b,
-                          num_leaves=31, pack=d2.pack,
+                          num_leaves=31,
                           stream=d2.path == "stream", fused=d2.fused,
                           stream_kind="l2")
         assert plan["paged"] and plan["fits"]

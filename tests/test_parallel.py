@@ -185,8 +185,7 @@ def test_pad_features_to_shards_contract():
                      else group * shards // math.gcd(group, shards))
                 assert p - m < f
     # the motivating case: f=28, group=8, 8 shards used to pad to 64
-    # (group x shards granularity) — wide enough to evict pack=2; the
-    # lcm padding ships 32
+    # (group x shards granularity); the lcm padding ships 32
     assert pad_features_to_shards(28, 8, 8) == 32
 
 

@@ -9,9 +9,9 @@ chip — and check them against a numpy oracle and against each other:
   cross-scheme tree-identity claim rests on this);
 * left segments are stable, right segments exactly reversed, rows
   outside the partitioned range untouched;
-* the pack=2 (two logical rows per 128-lane line) kernel honours the
-  same contract at half the DMA width, across odd/even segment starts
-  and counts (the parity-carry scheme);
+* a comb line of two 128-lane planes (the width ``msltr-train-2m``
+  runs) honours the same contract, across odd/even segment starts and
+  counts, with the split column in the second plane;
 * the 128-lane layout contract (ops/pallas/layout.py) rejects the
   BENCH_r03 regression class in EVERY kernel builder, off-chip.
 """
@@ -25,8 +25,7 @@ from lightgbm_tpu.ops.pallas.layout import LANE, check_lane_width, \
 from lightgbm_tpu.ops.pallas.partition_kernel import SEL_S0, SEL_CNT, \
     SEL_SIDE
 from lightgbm_tpu.ops.pallas.partition_kernel2 import make_partition_ss
-from lightgbm_tpu.ops.pallas.partition_kernel3 import make_partition_p2, \
-    make_partition_perm
+from lightgbm_tpu.ops.pallas.partition_kernel3 import make_partition_perm
 
 R, C = 128, 128
 SIZE = 1024
@@ -136,45 +135,6 @@ def test_permute_bf16_payload_exact():
     np.testing.assert_array_equal(out[s0 + nl:s0 + cnt], seg[~gl][::-1])
 
 
-@pytest.mark.parametrize("cfg", [(64, 400, 3, 15), (65, 401, 3, 15),
-                                 (101, 333, 5, 7), (0, 512, 0, 16),
-                                 (33, 64, 2, 0), (200, 0, 1, 9),
-                                 (129, 1, 4, 31), (17, 511, 7, 30)])
-def test_pack2_kernel_contract(cfg):
-    """pack=2 (two logical rows per 128-lane line): same partition
-    contract as pack=1 — stable left, reversed right, neighbours
-    untouched — across odd/even segment starts (the parity-carry
-    scheme) at HALF the physical DMA width."""
-    r2, size2 = 64, 512
-    n2 = size2 + 4 * r2 + 256
-    np2 = n2 // 2
-    w = LANE // 2
-    rng = np.random.default_rng(2)
-    logical = np.zeros((n2, w), np.float32)
-    logical[:, :8] = rng.integers(0, 32, size=(n2, 8))
-    logical[:, 8] = rng.normal(size=n2)
-    packed = jnp.asarray(logical.reshape(np2, LANE))
-    part = make_partition_p2(n2, R=r2, size=size2, interpret=True,
-                             interpret_kernel=True, cb_block=64)
-    emul = make_partition_p2(n2, R=r2, size=size2, interpret=True)
-    s0, cnt, feat, sbin = cfg
-    sel = _sel(s0, cnt, feat, sbin)
-    r_k, _, nl_k = part(sel, packed, jnp.zeros_like(packed))
-    r_e, _, nl_e = emul(sel, packed, jnp.zeros_like(packed))
-    out = np.asarray(r_k).reshape(n2, w)
-    out_e = np.asarray(r_e).reshape(n2, w)
-    seg = logical[s0:s0 + cnt]
-    gl = seg[:, feat] <= sbin
-    nl = int(nl_k)
-    assert nl == int(gl.sum()) == int(nl_e)
-    np.testing.assert_array_equal(out[s0:s0 + nl], seg[gl])
-    np.testing.assert_array_equal(out[s0 + nl:s0 + cnt], seg[~gl][::-1])
-    np.testing.assert_array_equal(out[:s0], logical[:s0])
-    np.testing.assert_array_equal(out[s0 + cnt:], logical[s0 + cnt:])
-    # the stable XLA emulation agrees on membership (left prefix)
-    np.testing.assert_array_equal(out_e[s0:s0 + nl], seg[gl])
-
-
 @pytest.mark.parametrize("side", SIDES)
 def test_fused_scan_selection_bitwise(side):
     """make_fused_split(scan=permute) partitions bit-identically to
@@ -203,74 +163,6 @@ def test_fused_scan_selection_bitwise(side):
     np.testing.assert_array_equal(np.asarray(outs["permute"][0]),
                                   np.asarray(r_p))
     assert int(outs["permute"][2]) == int(nl_p)
-
-
-def test_pack2_comb_histogram_kernel_bitwise():
-    """The pack=2 comb-direct histogram kernel (in-register lane-half
-    unpack) produces BITWISE the histogram the pack=1 kernel builds
-    from the same logical rows, across aligned/unaligned/odd windows
-    and a dead (count == 0) call."""
-    from lightgbm_tpu.ops.pallas.hist_kernel2 import build_histogram_comb
-    n_alloc, f_pad = 2048 + 512, 16
-    rng = np.random.default_rng(0)
-    logical = np.zeros((n_alloc, LANE // 2), np.float32)
-    logical[:, :f_pad] = rng.integers(0, 64, size=(n_alloc, f_pad))
-    logical[:, f_pad] = rng.normal(size=n_alloc)
-    logical[:, f_pad + 1] = rng.normal(size=n_alloc)
-    wide = np.zeros((n_alloc, LANE), np.float32)
-    wide[:, :LANE // 2] = logical
-    packed = jnp.asarray(logical.reshape(n_alloc // 2, LANE))
-    for start, off, cnt in ((0, 0, 2048), (512, 0, 900), (513, 0, 901),
-                            (77, 3, 333), (100, 0, 0)):
-        h1 = build_histogram_comb(
-            jnp.asarray(wide), jnp.int32(start), jnp.int32(off),
-            jnp.int32(cnt), f_pad=f_pad, size=2048, padded_bins=64,
-            rows_per_block=256, interpret=True)
-        h2 = build_histogram_comb(
-            packed, jnp.int32(start), jnp.int32(off), jnp.int32(cnt),
-            f_pad=f_pad, size=2048, padded_bins=64, rows_per_block=256,
-            interpret=True, pack=2)
-        np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
-
-
-@pytest.mark.parametrize("side", SIDES)
-def test_pack2_fused_kernel_contract(side):
-    """The REAL pack=2 fused scan+histogram kernel (Pallas
-    interpreter) partitions bit-identically to the reference
-    composition (pack=2 partition + the named child's comb histogram)
-    and its histogram matches the composition's to
-    accumulation-grouping tolerance — the off-chip pin for
-    _fused_scan_kernel_p2."""
-    from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
-    r2, size2, f_pad = 64, 512, 16
-    n2 = size2 + 4 * r2 + 256
-    rng = np.random.default_rng(2)
-    logical = np.zeros((n2, LANE // 2), np.float32)
-    logical[:, :f_pad] = rng.integers(0, 32, size=(n2, f_pad))
-    logical[:, f_pad] = rng.normal(size=n2)
-    logical[:, f_pad + 1] = rng.normal(size=n2)
-    packed = jnp.asarray(logical.reshape(n2 // 2, LANE))
-    comp = make_fused_split(n2, LANE, f_pad=f_pad, padded_bins=32,
-                            R=r2, size=size2, interpret=True, pack=2,
-                            interpret_kernel=True, hist_rpb=128,
-                            cb_block=64)
-    real = make_fused_split(n2, LANE, f_pad=f_pad, padded_bins=32,
-                            R=r2, size=size2, pack=2,
-                            fused_kernel_interpret=True, cb_block=64)
-    for cfg in [(64, 400, 3, 15), (65, 401, 3, 15), (0, 512, 0, 16),
-                (33, 64, 2, 0), (200, 0, 1, 9), (17, 511, 7, 30)]:
-        sel = _sel(*cfg, side)
-        rc = comp(sel, packed, jnp.zeros_like(packed))
-        rk = real(sel, packed, jnp.zeros_like(packed))
-        np.testing.assert_array_equal(np.asarray(rc[0]),
-                                      np.asarray(rk[0]))
-        assert int(rc[2]) == int(rk[2]), cfg
-        np.testing.assert_allclose(
-            np.asarray(rc[3]), np.asarray(rk[3]), rtol=0,
-            atol=1e-4, err_msg=str(cfg))
-        # an empty child's histogram is empty (cfgs with cnt == 0)
-        n_side = int(rc[2]) if side == "left" else cfg[1] - int(rc[2])
-        assert (np.abs(np.asarray(rk[3])).sum() > 0) == (n_side > 0), cfg
 
 
 # ---------------------------------------------------------------------
@@ -437,12 +329,22 @@ def test_hook_flags_match_recomputation(kind):
     assert (nl, nr) == (int(gl2.sum()), int(gr2.sum()))
 
 
-@pytest.mark.parametrize("side", SIDES)
-@pytest.mark.parametrize("kind", ["numerical", "nan_default_left",
-                                  "nan_default_right", "cat_onehot",
-                                  "cat_bitset"])
-def test_fused_kernel_hook_takes_the_compactions_flags(kind, side):
-    """The REAL pack=1 fused scan + histogram kernel through the Pallas
+# (kind, side, planes): every predicate at one plane, and the cat
+# bitset - the one the two-plane composition test below does not run -
+# at two, its split column (133) in the second plane
+_HOOK_CASES = [(k, s, 1) for k in ("numerical", "nan_default_left",
+                                   "nan_default_right", "cat_onehot",
+                                   "cat_bitset") for s in SIDES] \
+    + [("cat_bitset", s, 2) for s in SIDES]
+
+
+@pytest.mark.parametrize(
+    "kind,side,planes", _HOOK_CASES,
+    ids=[f"{s}-{k}" + ("-two_plane" if p == 2 else "")
+         for k, s, p in _HOOK_CASES])
+def test_fused_kernel_hook_takes_the_compactions_flags(kind, side,
+                                                       planes):
+    """The REAL fused scan + histogram kernel through the Pallas
     interpreter: under the permute compaction the hook masks with the
     named child's flags as the compaction hands them over, under the
     matmul one it recomputes them - same rows, same nleft and BITWISE
@@ -451,25 +353,29 @@ def test_fused_kernel_hook_takes_the_compactions_flags(kind, side):
     accumulation-grouping tolerance."""
     import ml_dtypes
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
-    f_pad, bins = 32, 64
+    from lightgbm_tpu.ops.pallas.layout import to_planes
+    f_pad, bins = (32, 64) if planes == 1 else (144, 64)
+    c = planes * LANE
     rng = np.random.default_rng(7)
-    rows = np.zeros((N, C), np.float32)
+    rows = np.zeros((N, c), np.float32)
     rows[:, :f_pad] = rng.integers(0, bins, size=(N, f_pad))
     rows[:, f_pad:f_pad + 2] = rng.normal(size=(N, 2)).astype(
         ml_dtypes.bfloat16).astype(np.float32)
-    rj = jnp.asarray(rows)
+    rj = to_planes(jnp.asarray(rows))
     sel = _flag_sel(kind)
     sel[SEL_S0], sel[SEL_CNT] = 64, 900
     sel[SEL_SIDE] = side == "left"
+    if planes == 2:
+        sel[2] = 133
     sel = jnp.asarray(sel)
     kw = dict(f_pad=f_pad, padded_bins=bins, R=R, size=SIZE)
-    real = {scan: make_fused_split(N, C, scan=scan,
+    real = {scan: make_fused_split(N, c, scan=scan,
                                    fused_kernel_interpret=True, **kw)(
         sel, rj, jnp.zeros_like(rj)) for scan in ("permute", "matmul")}
     for i in (0, 2, 3):         # rows, nleft, h_side
         np.testing.assert_array_equal(np.asarray(real["permute"][i]),
                                       np.asarray(real["matmul"][i]))
-    comp = make_fused_split(N, C, interpret=True, interpret_kernel=True,
+    comp = make_fused_split(N, c, interpret=True, interpret_kernel=True,
                             hist_rpb=R, **kw)(sel, rj, jnp.zeros_like(rj))
     np.testing.assert_array_equal(np.asarray(real["permute"][0]),
                                   np.asarray(comp[0]))
@@ -499,9 +405,14 @@ def _rows2(seed=0):
     return rows
 
 
-@pytest.mark.parametrize("cfg", [(64, 900, 131, 20), (0, 1024, 0, 31),
-                                 (513, 1, 135, 10), (100, 0, 2, 5),
-                                 (17, 1000, 137, 40)])
+@pytest.mark.parametrize("cfg", [
+    (64, 900, 131, 20), (0, 1024, 0, 31), (513, 1, 135, 10),
+    (100, 0, 2, 5), (17, 1000, 137, 40),
+    # the edge shapes: odd and even starts, cnt 0 / 1 / under a block /
+    # odd, sbin 0 - every split column in the second plane
+    (64, 400, 133, 15), (65, 401, 133, 15), (101, 333, 135, 7),
+    (0, 512, 130, 16), (33, 64, 132, 0), (200, 0, 131, 9),
+    (129, 1, 134, 31), (17, 511, 137, 30)])
 def test_two_plane_scans_match_the_oracle(cfg):
     from lightgbm_tpu.ops.pallas.layout import comb_shape, to_planes, \
         to_rows
@@ -571,6 +482,37 @@ def test_two_plane_fused_kernel_matches_the_composition(side):
                                np.asarray(comp[3]), rtol=0, atol=1e-4)
 
 
+def test_two_plane_comb_histogram_matches_numpy():
+    """The comb-direct histogram kernel over a plane-major comb of two
+    planes (bins through lane 143, the values at 144-145) is BITWISE
+    the numpy histogram of the window's rows: aligned, unaligned and
+    odd windows and a dead (count == 0) call.  The values are small
+    integers, so every grouping of the f32 sums is exact."""
+    from lightgbm_tpu.ops.pallas.hist_kernel2 import build_histogram_comb
+    from lightgbm_tpu.ops.pallas.layout import to_planes
+    n_alloc, f_pad, bins = 2048 + 512, 144, 64
+    rng = np.random.default_rng(0)
+    rows = np.zeros((n_alloc, C2), np.float32)
+    rows[:, :f_pad] = rng.integers(0, bins, size=(n_alloc, f_pad))
+    rows[:, f_pad:f_pad + 2] = rng.integers(-8, 9, size=(n_alloc, 2))
+    comb = to_planes(jnp.asarray(rows))
+    for start, off, cnt in ((0, 0, 2048), (512, 0, 900), (513, 0, 901),
+                            (77, 3, 333), (100, 0, 0)):
+        got = build_histogram_comb(
+            comb, jnp.int32(start), jnp.int32(off), jnp.int32(cnt),
+            f_pad=f_pad, size=2048, padded_bins=bins,
+            rows_per_block=256, interpret=True, planes=2)
+        win = rows[start + off:start + off + cnt]
+        want = np.zeros((f_pad, bins, 2), np.float32)
+        for ch in range(2):
+            for f in range(f_pad):
+                want[f, :, ch] = np.bincount(
+                    win[:, f].astype(np.int64),
+                    weights=win[:, f_pad + ch], minlength=bins)
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert (np.abs(want[128:]).sum() > 0) == (cnt > 0)   # plane 1
+
+
 class TestLaneContract:
     """Off-chip pin for the BENCH_r03 Mosaic regression class: every
     kernel column-slice/comb width in the repo must be a multiple of
@@ -578,17 +520,13 @@ class TestLaneContract:
 
     def test_layout_rules(self):
         for n_cols in (1, 41, 45, 64, 100, 128, 129, 300):
-            c, pack = comb_layout(n_cols)
-            assert c % LANE == 0 and pack == 1
+            assert comb_layout(n_cols) % LANE == 0
         # the exact round-3 snapshot config: 28 features padded to 32
         # + 13 stream columns at 64-lane granularity produced C=64;
         # the contract must yield 128
-        assert comb_layout(45) == (128, 1)
-        assert comb_layout(40, pack=2) == (128, 2)
-        with pytest.raises(ValueError):
-            comb_layout(65, pack=2)      # >64 cols can't pack
-        with pytest.raises(ValueError):
-            comb_layout(4, pack=3)
+        assert comb_layout(45) == 128
+        # and a line is whole planes at any width: 137 features + 6
+        assert comb_layout(143) == comb_layout(129) == 256
         for bad in (64, 32, 127, 192 + 64):
             if bad % LANE == 0:
                 continue
@@ -605,12 +543,12 @@ class TestLaneContract:
         from lightgbm_tpu.ops.pallas.hist_kernel2 import \
             build_histogram_comb
         from lightgbm_tpu.ops.pallas.partition_kernel import \
-            make_partition
+            make_reference_partition
         from lightgbm_tpu.ops.pallas.stream_grad import make_init, \
             make_refresh
 
         with pytest.raises(ValueError):
-            make_partition(4096, bad_c, size=1024)
+            make_reference_partition(4096, bad_c)
         with pytest.raises(ValueError):
             make_partition_ss(4096, bad_c, size=1024)
         with pytest.raises(ValueError):
@@ -638,5 +576,5 @@ class TestLaneContract:
         for f_pad in (8, 16, 28, 32, 64, 120, 128, 256):
             for extra in (6, stream_columns("binary"),
                           stream_columns("l2")):
-                c, _ = comb_layout(f_pad + extra)
+                c = comb_layout(f_pad + extra)
                 assert c % LANE == 0, (f_pad, extra, c)

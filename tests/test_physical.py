@@ -4,8 +4,7 @@ The Pallas streaming partition kernel only compiles on TPU; on CPU the
 mode runs its pure-XLA reference implementation
 (ops/pallas/partition_kernel.py), which these tests exercise via
 ``LGBM_TPU_PHYS=interpret``.  On TPU the compiled kernel was verified to
-produce bit-identical trees to the f32 row_order path (see
-tools/check_partition.py for the kernel-level harness).
+produce bit-identical trees to the f32 row_order path.
 """
 import os
 import sys
@@ -49,6 +48,17 @@ def _fresh_train(env_phys, n=3000, f=6, rounds=4, **params):
             del sys.modules[m]
 
 
+def _assert_trees_close(t_ref, t_phy):
+    assert len(t_ref) == len(t_phy)
+    for i, (a, b) in enumerate(zip(t_ref, t_phy)):
+        assert a[0] == b[0], f"tree {i} num_leaves {a[0]} != {b[0]}"
+        assert a[1] == b[1], f"tree {i} split features differ"
+        assert a[2] == b[2], f"tree {i} thresholds differ"
+        # leaf values accumulate histogram sums in a different row order
+        # (rows are physically permuted), so allow f32 rounding drift
+        np.testing.assert_allclose(a[3], b[3], rtol=2e-3, atol=1e-4)
+
+
 @pytest.mark.parametrize("params", [
     {},
     {"bagging_fraction": 0.7, "bagging_freq": 1},
@@ -57,33 +67,22 @@ def _fresh_train(env_phys, n=3000, f=6, rounds=4, **params):
 def test_physical_matches_row_order(params):
     p_ref, t_ref = _fresh_train("0", **params)
     p_phy, t_phy = _fresh_train("interpret", **params)
-    for i, (a, b) in enumerate(zip(t_ref, t_phy)):
-        assert a[0] == b[0], f"tree {i} num_leaves {a[0]} != {b[0]}"
-        assert a[1] == b[1], f"tree {i} split features differ"
-        assert a[2] == b[2], f"tree {i} thresholds differ"
-        # leaf values accumulate histogram sums in a different row order
-        # (rows are physically permuted), so allow f32 rounding drift
-        np.testing.assert_allclose(a[3], b[3], rtol=2e-3, atol=1e-4)
+    _assert_trees_close(t_ref, t_phy)
     np.testing.assert_allclose(p_ref, p_phy, rtol=5e-3, atol=1e-3)
 
 
 def _train_scheme(partition, fused, learner, monotone, n=1500, f=6,
-                  rounds=2, pack=None, expect_pack=None):
+                  rounds=2, phys="interpret"):
     """Train through the REAL partition kernels (Pallas interpreter,
     compiled row order) under one (scheme, fused, learner, monotone)
-    cell of the ISSUE-3 equivalence matrix; returns exact tree digests.
-    ``pack`` sets LGBM_TPU_COMB_PACK for the run (ISSUE-4 matrix);
-    ``expect_pack`` asserts which pack the grower actually engaged."""
-    env = {"LGBM_TPU_PHYS": "interpret",
+    cell of the ISSUE-3 equivalence matrix; returns the trees, leaf
+    values as (bytes, array).  ``f`` is the feature count: past 122 the comb
+    line is two 128-lane planes.  ``phys="0"`` trains the same
+    configuration on the ``row_order`` path, the plain reference."""
+    env = {"LGBM_TPU_PHYS": phys,
            "LGBM_TPU_PART_INTERP": "kernel",
            "LGBM_TPU_PARTITION": partition,
            "LGBM_TPU_FUSED": fused}
-    if pack is not None:
-        env["LGBM_TPU_COMB_PACK"] = pack
-        # hist_scatter's column padding (features x 8 shards) blows the
-        # 64-column pack=2 budget at small max_bin; keep the mesh cells
-        # on the full-psum merge so the pack path actually engages
-        env["LGBM_TPU_HIST_SCATTER"] = "0" if learner == "data" else ""
     saved = {k: os.environ.get(k) for k in env}
     for k, v in env.items():
         if v == "":
@@ -110,13 +109,15 @@ def _train_scheme(partition, fused, learner, monotone, n=1500, f=6,
         ds = lgb.Dataset(x, label=y,
                          params={"max_bin": p.get("max_bin", 255)})
         bst = lgb.train(p, ds, num_boost_round=rounds)
-        if expect_pack is not None:
-            got = int(getattr(bst._inner.grow, "pack", 1))
-            assert got == expect_pack, (got, expect_pack)
+        if phys != "0":
+            g = bst._inner.grow
+            assert (g._pieces.C if learner == "data" else g._C) \
+                == 128 * (1 + (f > 122))
         return [(int(t.num_leaves),
                  t.split_feature[:int(t.num_leaves) - 1].tolist(),
                  t.threshold_bin[:int(t.num_leaves) - 1].tolist(),
-                 np.asarray(t.leaf_value).tobytes())
+                 np.asarray(t.leaf_value).tobytes(),
+                 np.asarray(t.leaf_value[:int(t.num_leaves)]))
                 for t in bst._models]
     finally:
         _restore_env(saved)
@@ -150,43 +151,54 @@ def test_partition_scheme_equivalence_matrix(fused, learner, monotone):
         assert a[3] == b[3], f"tree {i}: leaf values differ bitwise"
 
 
+_WIDE_F = 130       # + 6 value / row-id columns: a 256-lane comb line
+_MONO_W = [1, -1] + [0] * (_WIDE_F - 2)
+_WIDE = {}          # trained cells of the width matrix, this process
+
+
+def _train_wide(partition, fused, learner, monotone, phys="interpret"):
+    key = (partition, fused, learner, bool(monotone), phys)
+    if key not in _WIDE:
+        _WIDE[key] = _train_scheme(partition, fused, learner, monotone,
+                                   f=_WIDE_F, phys=phys)
+    return _WIDE[key]
+
+
 @pytest.mark.parametrize("partition,fused,learner,monotone", [
     ("permute", "1", "serial", None),
-    ("permute", "0", "serial", [1, -1, 0, 0, 0, 0]),
+    ("permute", "0", "serial", _MONO_W),
     ("matmul", "1", "serial", None),
     ("matmul", "0", "serial", None),
-    ("permute", "1", "serial", [1, -1, 0, 0, 0, 0]),
+    ("permute", "1", "serial", _MONO_W),
     ("permute", "1", "data", None),
     ("permute", "0", "data", None),
     ("matmul", "1", "data", None),
-])
-def test_pack_parity_matrix(partition, fused, learner, monotone):
-    """ISSUE-4 acceptance: LGBM_TPU_COMB_PACK=2 grows trees
-    BIT-IDENTICAL to pack=1 — through the real kernel bodies (Pallas
-    interpreter, LGBM_TPU_PART_INTERP=kernel), across permute/matmul,
-    fused on/off, serial and 8-shard data-parallel mesh, monotone
-    on/off.  The pack=2 scan reproduces the pack=1 row layout in the
-    logical domain and every histogram/stream consumer reads the same
-    logical values, so every downstream float accumulates identically."""
-    t_1 = _train_scheme(partition, fused, learner, monotone,
-                        pack="1", expect_pack=1)
-    t_2 = _train_scheme(partition, fused, learner, monotone,
-                        pack="2", expect_pack=2)
-    assert len(t_1) == len(t_2)
-    for i, (a, b) in enumerate(zip(t_1, t_2)):
-        assert a[0] == b[0], f"tree {i}: num_leaves {a[0]} != {b[0]}"
-        assert a[1] == b[1], f"tree {i}: split features differ"
-        assert a[2] == b[2], f"tree {i}: thresholds differ"
-        assert a[3] == b[3], f"tree {i}: leaf values differ bitwise"
+], ids=lambda v: "mono" if isinstance(v, list) else str(v))
+def test_width_parity_matrix(partition, fused, learner, monotone):
+    """The comb at the width ``msltr-train-2m`` runs: 130 feature
+    columns make a line of two 128-lane planes, with the value and
+    row-id columns in the second.  Trained through the real kernel
+    bodies (Pallas interpreter, LGBM_TPU_PART_INTERP=kernel), across
+    permute/matmul, fused on/off, serial and 8-shard data-parallel
+    mesh, monotone on/off, every cell grows the trees the ``row_order``
+    path grows (structure equal, leaf values to f32 rounding), and the
+    matmul cells grow BITWISE the trees of the permute compaction."""
+    got = _train_wide(partition, fused, learner, monotone)
+    ref = _train_wide(partition, fused, learner, monotone, phys="0")
+    _assert_trees_close([t[:3] + t[4:] for t in ref],
+                        [t[:3] + t[4:] for t in got])
+    assert any(t[0] > 1 for t in got)
+    if partition == "matmul":
+        perm = _train_wide("permute", fused, learner, monotone)
+        assert [t[:4] for t in got] == [t[:4] for t in perm]
 
 
-def _train_counters(pack, tmp_path, n=1200, rounds=2):
+def _train_counters(tmp_path, n=1200, f=_WIDE_F, rounds=2):
     """Serial physical train with the tracer live; returns (per-model
     structure, device counter totals)."""
-    trace = os.path.join(str(tmp_path), f"ctr_pack{pack}.jsonl")
+    trace = os.path.join(str(tmp_path), "ctr.jsonl")
     env = {"LGBM_TPU_PHYS": "interpret",
            "LGBM_TPU_PART_INTERP": "kernel",
-           "LGBM_TPU_COMB_PACK": pack,
            "LGBM_TPU_TRACE": trace}
     saved = {k: os.environ.get(k) for k in env}
     for k, v in env.items():
@@ -198,7 +210,7 @@ def _train_counters(pack, tmp_path, n=1200, rounds=2):
         import lightgbm_tpu as lgb
         from lightgbm_tpu.obs import counters as obs_counters
         rng = np.random.default_rng(4)
-        x = rng.normal(size=(n, 5)).astype(np.float32)
+        x = rng.normal(size=(n, f)).astype(np.float32)
         y = (x[:, 0] - 0.4 * x[:, 1] > 0).astype(np.float32)
         ds = lgb.Dataset(x, label=y)
         bst = lgb.Booster(params={"objective": "binary",
@@ -211,7 +223,7 @@ def _train_counters(pack, tmp_path, n=1200, rounds=2):
         splits = sum(int(t.num_leaves) - 1 for t in models)
         rows_part = sum(int(np.asarray(t.internal_count).sum())
                         for t in models if int(t.num_leaves) > 1)
-        assert int(getattr(bst._inner.grow, "pack", 1)) == int(pack)
+        assert bst._inner.grow._C == 256
         return (splits, rows_part), obs_counters.totals()
     finally:
         _restore_env(saved)
@@ -220,17 +232,14 @@ def _train_counters(pack, tmp_path, n=1200, rounds=2):
             del sys.modules[m]
 
 
-def test_pack2_counters_logical_units(tmp_path):
-    """Device counters under pack=2 count LOGICAL rows (not packed
-    lines): rows_partitioned equals the models' internal_count sum
-    exactly and every total matches the pack=1 run bit-for-bit."""
-    (s1, r1), tot1 = _train_counters("1", tmp_path)
-    (s2, r2), tot2 = _train_counters("2", tmp_path)
-    assert (s1, r1) == (s2, r2)
-    assert s2 > 0 and r2 > 0
-    assert int(tot2["splits"]) == s2
-    assert int(tot2["rows_partitioned"]) == r2
-    assert tot1 == tot2, (tot1, tot2)
+def test_two_plane_counters_count_rows(tmp_path):
+    """Device counters at two planes count ROWS (a plane-major comb
+    holds each row in two matrices): rows_partitioned equals the
+    models' internal_count sum exactly, splits the models' splits."""
+    (splits, rows_part), tot = _train_counters(tmp_path)
+    assert splits > 0 and rows_part > 0
+    assert int(tot["splits"]) == splits
+    assert int(tot["rows_partitioned"]) == rows_part
 
 
 def test_physical_categorical_and_forced():

@@ -3,7 +3,7 @@ resume, fault-injection harness, numerical guardrails.
 
 The hard contract under test: kill-at-iteration-i + resume grows
 BYTE-IDENTICAL trees vs the uninterrupted run — pinned across
-pack={1,2} x serial/8-shard mesh, at every K boundary, under
+one and two comb planes x serial/8-shard mesh, at every K boundary, under
 bagging + feature-fraction RNG state and under GOSS.  A resume whose
 config fingerprint or engaged routing digest disagrees REFUSES with a
 structured finding (exit 2), a torn/corrupt checkpoint surfaces as
@@ -33,7 +33,7 @@ FIXTURE_FILES = ("LATEST", "ckpt_000004/manifest.json",
 RES_KNOBS = ("LGBM_TPU_CKPT_DIR", "LGBM_TPU_CKPT_EVERY",
              "LGBM_TPU_CKPT_KEEP", "LGBM_TPU_FAULT",
              "LGBM_TPU_FAULT_RETRIES", "LGBM_TPU_NUMERICS",
-             "LGBM_TPU_PHYS", "LGBM_TPU_COMB_PACK",
+             "LGBM_TPU_PHYS",
              "LGBM_TPU_PART_INTERP", "LGBM_TPU_HIST_SCATTER")
 
 # deterministic base config: feature_fraction + mid-cycle bagging keep
@@ -60,7 +60,7 @@ def _data(n=600, f=6, seed=3):
 
 
 def _train(rounds, env=None, params=None, n=600, lr_schedule=None,
-           fobj=None, callbacks=None, data_seed=3):
+           fobj=None, callbacks=None, data_seed=3, f=6):
     """Fresh-import train (purge + reimport so env knobs re-resolve,
     the convention from tests/test_physical.py).  Returns
     (model_text, booster)."""
@@ -74,7 +74,7 @@ def _train(rounds, env=None, params=None, n=600, lr_schedule=None,
     try:
         _purge()
         import lightgbm_tpu as lgb
-        x, y = _data(n=n, seed=data_seed)
+        x, y = _data(n=n, f=f, seed=data_seed)
         p = dict(BASE)
         p.update(params or {})
         if fobj is not None:
@@ -101,23 +101,17 @@ def _ck_env(d, every=2, **extra):
     return env
 
 
-# the ISSUE-13 acceptance matrix: pack={1,2} x serial/8-shard mesh
-# (plus the default row_order cell).  Mesh cells mirror the
-# tests/test_physical.py mesh env (hist_scatter's column padding blows
-# the pack=2 lane budget at small max_bin).
+# the ISSUE-13 acceptance matrix: one and two comb planes x
+# serial/8-shard mesh (plus the default row_order cell); (env, params,
+# feature columns).  124 columns + the 6 value / row-id columns is the
+# smallest width whose comb line crosses into a second 128-lane plane.
+_PHYS = {"LGBM_TPU_PHYS": "interpret"}
 CELLS = {
-    "row_order": ({}, {}),
-    "serial_pack1": ({"LGBM_TPU_PHYS": "interpret",
-                      "LGBM_TPU_COMB_PACK": "1"}, {}),
-    "serial_pack2": ({"LGBM_TPU_PHYS": "interpret",
-                      "LGBM_TPU_COMB_PACK": "2"}, {}),
-    "mesh_pack1": ({"LGBM_TPU_PHYS": "interpret",
-                    "LGBM_TPU_COMB_PACK": "1"},
-                   {"tree_learner": "data"}),
-    "mesh_pack2": ({"LGBM_TPU_PHYS": "interpret",
-                    "LGBM_TPU_COMB_PACK": "2",
-                    "LGBM_TPU_HIST_SCATTER": "0"},
-                   {"tree_learner": "data"}),
+    "row_order": ({}, {}, 6),
+    "serial_one_plane": (_PHYS, {}, 6),
+    "serial_two_plane": (_PHYS, {}, 124),
+    "mesh_one_plane": (_PHYS, {"tree_learner": "data"}, 6),
+    "mesh_two_plane": (_PHYS, {"tree_learner": "data"}, 124),
 }
 
 
@@ -127,18 +121,22 @@ CELLS = {
 class TestKillResume:
     @pytest.mark.parametrize("cell", sorted(CELLS))
     def test_kill_resume_byte_identical(self, cell, tmp_path):
-        env, params = CELLS[cell]
+        env, params, f = CELLS[cell]
         rounds, kill_at = 6, 3
-        ref, _ = _train(rounds, env=_ck_env(tmp_path / "ref", 2,
-                                            **env),
-                        params=params)
+        ref, ref_bst = _train(rounds, env=_ck_env(tmp_path / "ref", 2,
+                                                  **env),
+                              params=params, f=f)
+        if env:
+            g = ref_bst._inner.grow
+            c = getattr(g, "_C", None) or g._pieces.C
+            assert c == 128 * (1 + (f > 6)), "cell is vacuous"
         ck = tmp_path / "kill"
         envk = _ck_env(ck, 2, **env)
         # the "kill": train only kill_at rounds — the process dies with
         # the last completed snapshot at the preceding K boundary,
         # exactly what SIGKILL mid-iteration leaves behind
-        _train(kill_at, env=envk, params=params)
-        txt, bst = _train(rounds, env=envk, params=params)
+        _train(kill_at, env=envk, params=params, f=f)
+        txt, bst = _train(rounds, env=envk, params=params, f=f)
         assert bst.resumed_from == (kill_at // 2) * 2
         assert txt == ref, (f"{cell}: resume after kill@{kill_at} did "
                             "not reproduce the uninterrupted run")

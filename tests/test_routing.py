@@ -31,8 +31,7 @@ _MATRIX_PATH = os.path.join(
 # on the SHIPPING defaults, and an ambient CI export (e.g. the leg-2
 # fallback knobs) must not silently reroute them
 _BASE_ENV = {"LGBM_TPU_PHYS": None, "LGBM_TPU_STREAM": None,
-             "LGBM_TPU_COMB_PACK": None, "LGBM_TPU_FUSED": None,
-             "LGBM_TPU_PARTITION": None, "LGBM_TPU_PART": None,
+             "LGBM_TPU_FUSED": None, "LGBM_TPU_PARTITION": None,
              "LGBM_TPU_PART_INTERP": None,
              "LGBM_TPU_HIST_SCATTER": None}
 
@@ -62,17 +61,26 @@ def _fresh_train(env, params=None, n=600, f=5, rounds=1, data="dense"):
         rng = np.random.default_rng(0)
         p = {"objective": "binary", "num_leaves": 7, "verbosity": -1}
         p.update(params or {})
-        if data == "dense":
-            x = rng.normal(size=(n, f)).astype(np.float32)
+        group = None
+        if data in ("dense", "wide", "rank"):
+            # "wide": 130 feature columns, a comb line of two planes
+            x = rng.normal(
+                size=(n, 130 if data == "wide" else f)).astype(np.float32)
             y = (x[:, 0] + 0.5 * x[:, 1] > 0)
+            if data == "rank":
+                y = rng.integers(0, 5, size=n)
+                group = [n // 20] * 20
         elif data == "cat":
             x = rng.normal(size=(n, f)).astype(np.float32)
             x[:, 0] = rng.integers(0, 12, size=n)
             y = (x[:, 1] > 0)
             p.setdefault("categorical_feature", "0")
-        elif data == "onehot":
-            c = rng.integers(0, 24, size=n)
-            onehot = np.zeros((n, 24), np.float32)
+        elif data in ("onehot", "wide_onehot"):
+            # "wide_onehot": bundles whose UNBUNDLED width (140 + 3
+            # columns) crosses into the second plane
+            k = 24 if data == "onehot" else 140
+            c = rng.integers(0, k, size=n)
+            onehot = np.zeros((n, k), np.float32)
             onehot[np.arange(n), c] = 1.0
             dense = rng.normal(size=(n, 3)).astype(np.float32)
             x = np.hstack([onehot, dense])
@@ -82,7 +90,7 @@ def _fresh_train(env, params=None, n=600, f=5, rounds=1, data="dense"):
         if p.get("objective") == "multiclass":
             y = rng.integers(0, p.get("num_class", 3), size=n)
         y = np.asarray(y, np.float32)
-        bst = lgb.train(p, lgb.Dataset(x, label=y),
+        bst = lgb.train(p, lgb.Dataset(x, label=y, group=group),
                         num_boost_round=rounds)
         inner = bst._inner
         grow = inner.grow
@@ -94,7 +102,7 @@ def _fresh_train(env, params=None, n=600, f=5, rounds=1, data="dense"):
             "engaged_path": ("stream" if stream
                             else "physical" if physical
                             else "row_order"),
-            "grow_pack": int(getattr(grow, "pack", 1)),
+            "comb_C": getattr(grow, "_C", None),
             "grow_fused": getattr(grow, "fused", None),
             "hist_scatter": getattr(grow, "hist_scatter", None),
             "bundled": inner.dd.bundle is not None,
@@ -109,7 +117,7 @@ def _fresh_train(env, params=None, n=600, f=5, rounds=1, data="dense"):
 
 def _assert_matches_matrix(out):
     """The runtime decision's cell must exist in the golden matrix and
-    predict the ENGAGED path/pack/scheme/merge exactly."""
+    predict the ENGAGED path/scheme/merge exactly."""
     from lightgbm_tpu.ops.routing import decode_cell
     r = out["routing"]
     assert r is not None, "no routing decision on the booster"
@@ -119,14 +127,13 @@ def _assert_matches_matrix(out):
     cell = decode_cell(cells[r["cell"]])
     assert cell["path"] == r["path"] == out["engaged_path"], (
         cell, r, out["engaged_path"])
-    assert cell["pack"] == r["pack"]
+    assert r["pack"] == 1
     assert cell["scheme"] == r["scheme"]
     assert cell["merge"] == r["hist_merge"]
     assert cell["reasons"] == r["reasons"]
-    if out["engaged_path"] != "row_order":
-        assert out["grow_pack"] == r["pack"]
-        if out["grow_fused"] is not None:
-            assert bool(out["grow_fused"]) == bool(r["fused"])
+    if (out["engaged_path"] != "row_order"
+            and out["grow_fused"] is not None):
+        assert bool(out["grow_fused"]) == bool(r["fused"])
 
 
 # ---------------------------------------------------------------------
@@ -187,8 +194,7 @@ def test_decide_semantics():
     assert d.path == "row_order" and set(d.reasons) == {"cegb_lazy"}
     # ... except the over-wide bundle expansion, which falls back
     # loudly under the narrow shape rule
-    d = decide(RouteInputs(efb_bundled=True, efb_overwide=True,
-                           wide_layout=True, **tpu))
+    d = decide(RouteInputs(efb_bundled=True, efb_overwide=True, **tpu))
     assert d.path == "row_order" and d.reasons == ("efb_overwide",)
     # the shape fact alone (no bundling) never fires the rule
     d = decide(RouteInputs(efb_overwide=True, **tpu))
@@ -201,13 +207,12 @@ def test_decide_semantics():
     assert d.path == "physical"
     assert set(d.reasons) == {"objective_not_streamable",
                               "multi_tree_iter"}
-    # pack=2: fits -> 2; too wide -> 1 with a named reason
-    d = decide(RouteInputs(pack_env=2, **tpu))
-    assert d.pack == 2 and d.scheme == "permute"
-    d = decide(RouteInputs(pack_env=2, wide_layout=True, **tpu))
-    assert d.pack == 1 and d.pack_reasons == ("pack_layout_too_wide",)
-    d = decide(RouteInputs(pack_env=2, gpu_use_dp=True, **tpu))
-    assert d.pack == 1 and d.pack_reasons == ("pack_requires_physical",)
+    # the scheme is the compaction knob's, on the physical path only
+    d = decide(RouteInputs(partition_env="matmul", **tpu))
+    assert (d.pack, d.scheme) == (1, "matmul")
+    d = decide(RouteInputs(partition_env="matmul", gpu_use_dp=True,
+                           **tpu))
+    assert (d.pack, d.scheme) == (1, "none")
     # mesh merge rules
     d = decide(RouteInputs(learner="data", n_shards=8, **tpu))
     assert d.path == "physical" and d.hist_merge == "scatter"
@@ -283,10 +288,10 @@ def test_n_pad_overflow_boundary():
 def test_encode_decode_roundtrip():
     from lightgbm_tpu.ops.routing import (RouteInputs, decide,
                                           decode_cell, encode_cell)
-    d = decide(RouteInputs(gpu_use_dp=True, pack_env=2))
+    d = decide(RouteInputs(gpu_use_dp=True, partition_env="matmul"))
     c = decode_cell(encode_cell(d))
     assert c["path"] == d.path and c["reasons"] == list(d.reasons)
-    assert c["pack_reasons"] == list(d.pack_reasons)
+    assert c["scheme"] == d.scheme
     assert c["program_key"] == d.program_key
     with pytest.raises(ValueError):
         decode_cell("not-a-cell")
@@ -296,7 +301,7 @@ def test_env_knob_helper():
     from lightgbm_tpu.config import env_knob
     assert env_knob("LGBM_TPU_PHYS", environ={}) == "auto"
     assert env_knob("LGBM_TPU_STREAM", environ={}) == "auto"
-    assert env_knob("LGBM_TPU_COMB_PACK", environ={}) == "1"
+    assert env_knob("LGBM_TPU_PARTITION", environ={}) == "permute"
     assert env_knob("LGBM_TPU_PHYS",
                     environ={"LGBM_TPU_PHYS": "0"}) == "0"
     # empty string means unset, not "empty default"
@@ -329,15 +334,6 @@ def test_report_fallbacks_events_and_warn_once():
     routing.report_fallbacks(
         routing.decide(routing.RouteInputs(backend="cpu")))
     assert not events.totals()
-
-
-def test_pack_choice_matches_comb_pack_choice(monkeypatch):
-    from lightgbm_tpu.ops.device_data import comb_pack_choice
-    monkeypatch.setenv("LGBM_TPU_COMB_PACK", "2")
-    assert comb_pack_choice(30, 6) == 2
-    assert comb_pack_choice(60, 6) == 1
-    monkeypatch.delenv("LGBM_TPU_COMB_PACK")
-    assert comb_pack_choice(30, 6) == 1
 
 
 # ---------------------------------------------------------------------
@@ -457,6 +453,20 @@ SERIAL_CELLS = [
      "physical", {"stream_env_off"}),
     ("efb_phys_off", {"LGBM_TPU_PHYS": "0"}, {}, "onehot",
      "row_order", {"phys_env_off"}),
+    # the route the ranking cell pins (benchmarks/configs/
+    # msltr-lambdarank.json expect_route): physical, not streamed
+    ("lambdarank", {"LGBM_TPU_PHYS": "interpret"},
+     {"objective": "lambdarank"}, "rank",
+     "physical", {"objective_not_streamable"}),
+    # ... and its width: a comb line of two 128-lane planes, on both
+    # routes and under EFB
+    ("two_plane_stream", {"LGBM_TPU_PHYS": "interpret"}, {}, "wide",
+     "stream", set()),
+    ("two_plane_physical", {"LGBM_TPU_PHYS": "interpret",
+                            "LGBM_TPU_STREAM": "0"}, {}, "wide",
+     "physical", {"stream_env_off"}),
+    ("efb_two_plane", {"LGBM_TPU_PHYS": "interpret"}, {}, "wide_onehot",
+     "stream", set()),
 ]
 
 
@@ -467,8 +477,10 @@ def test_runtime_parity_serial(name, env, params, data, path, reasons):
     out = _fresh_train(env, params, data=data)
     assert out["engaged_path"] == path, out["routing"]
     assert reasons <= set(out["routing"]["reasons"]), out["routing"]
-    if data == "onehot":
+    if data.endswith("onehot"):
         assert out["bundled"], "EFB did not engage; cell is vacuous"
+    if data.startswith("wide"):
+        assert out["comb_C"] == 256, "one plane; cell is vacuous"
     _assert_matches_matrix(out)
     # loud config fallbacks recorded as structured events
     for r in reasons & {"gpu_use_dp", "cegb_lazy", "non_u8_bins",
@@ -481,32 +493,6 @@ def test_runtime_parity_serial(name, env, params, data, path, reasons):
     assert "routing_fallback_cat_subset" not in out["events"]
 
 
-def test_runtime_parity_pack2():
-    out = _fresh_train({"LGBM_TPU_PHYS": "interpret",
-                        "LGBM_TPU_COMB_PACK": "2",
-                        "LGBM_TPU_PART_INTERP": "kernel"},
-                       n=1024, rounds=2)
-    assert out["engaged_path"] == "stream"
-    assert out["grow_pack"] == 2 == out["routing"]["pack"]
-    assert out["routing"]["scheme"] == "permute"
-    _assert_matches_matrix(out)
-
-
-def test_runtime_parity_pack2_wide_layout():
-    # 70 features + stream extras overflow the 64-lane half-line: the
-    # grower falls back to pack=1 and the decision names the rule.
-    # objective=regression keeps the cell on the enumerated wide=1
-    # lattice edge (obj=l2)
-    out = _fresh_train({"LGBM_TPU_PHYS": "interpret",
-                        "LGBM_TPU_COMB_PACK": "2"},
-                       params={"objective": "regression"}, f=70)
-    assert out["engaged_path"] == "stream"
-    assert out["grow_pack"] == 1 == out["routing"]["pack"]
-    assert out["routing"]["pack_reasons"] == ["pack_layout_too_wide"]
-    assert out["events"].get("comb_pack_fallback", 0) >= 1
-    _assert_matches_matrix(out)
-
-
 def test_runtime_parity_mesh_data_parallel():
     out = _fresh_train({"LGBM_TPU_PHYS": "interpret"},
                        params={"tree_learner": "data"}, n=1024)
@@ -516,19 +502,6 @@ def test_runtime_parity_mesh_data_parallel():
     assert "mesh_stream_unwired" in r["reasons"]
     assert r["hist_merge"] == "scatter"
     assert out["hist_scatter"] is True
-    _assert_matches_matrix(out)
-
-
-def test_runtime_parity_efb_pack2():
-    """Bundled data on the pack=2 stream path, real kernel bodies
-    (ISSUE 12: the graduated class composes with the packed layout)."""
-    out = _fresh_train({"LGBM_TPU_PHYS": "interpret",
-                        "LGBM_TPU_COMB_PACK": "2",
-                        "LGBM_TPU_PART_INTERP": "kernel"},
-                       n=1024, rounds=2, data="onehot")
-    assert out["bundled"], "EFB did not engage; cell is vacuous"
-    assert out["engaged_path"] == "stream"
-    assert out["grow_pack"] == 2 == out["routing"]["pack"]
     _assert_matches_matrix(out)
 
 

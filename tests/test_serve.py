@@ -1,15 +1,14 @@
 """Serving-engine parity + contract suite (ISSUE 14).
 
-The compiled forest engine (``lightgbm_tpu/serve``) must agree with
-the host reference walk (``models/tree.py Tree.predict_leaf`` /
+The compiled forest engine (``lightgbm_tpu/serve``) must agree with the
+host reference walk (``models/tree.py Tree.predict_leaf`` /
 ``Booster.predict``) EXACTLY on leaf indices and within f32-ulp bounds
-on summed scores, across the full matrix: pack=1/2-trained boosters,
-EFB/one-hot datasets, categorical (one-hot and sorted-subset bitset)
-splits, NaN/missing rows, multiclass K>1, iteration slices, and the
-empty/1-row/bucket-boundary batch shapes.  Plus the bucketed-dispatch
-retrace pin (same bucket => one program; novel bucket => exactly one
-compile) and the predict-side routing rules.
-"""
+on summed scores, across the full matrix: boosters trained at one and
+two comb planes, EFB/one-hot datasets, categorical (one-hot and sorted-
+subset bitset) splits, NaN/missing rows, multiclass K>1, iteration
+slices, and the empty/1-row/bucket-boundary batch shapes. Plus the
+bucketed-dispatch retrace pin (same bucket => one program; novel bucket
+=> exactly one compile) and the predict-side routing rules."""
 import os
 
 import numpy as np
@@ -111,19 +110,21 @@ class TestParity:
         xq[:17, 0] = np.nan                 # NaN joins the zero bin
         _assert_parity(bst, xq)
 
-    @pytest.mark.parametrize("pack", ["1", "2"])
-    def test_pack_trained_boosters(self, pack):
-        # pack=1/2-trained boosters (the physical interpret path on
-        # CPU) must serve identically: the pack knob changes the
-        # TRAINING comb layout, never the finalized trees
+    @pytest.mark.parametrize("planes", [1, 2])
+    def test_physical_trained_boosters(self, planes):
+        # boosters trained on the physical path (its interpret form on
+        # the CPU) must serve as any other, at a comb line of one plane
+        # and of two (130 features): the comb is the TRAINING layout,
+        # the finalized trees and the serving quantizer never see it
         saved = save_env_knobs()
         os.environ["LGBM_TPU_PHYS"] = "interpret"
-        os.environ["LGBM_TPU_COMB_PACK"] = pack
+        f = 8 if planes == 1 else 130
         try:
-            x, y = _higgs(1024, f=8, seed=11)
+            x, y = _higgs(1024, f=f, seed=11)
             bst = _train(x, y, {"objective": "binary",
                                 "num_leaves": 8}, n_iter=4)
-            xq, _ = _higgs(300, f=8, seed=12)
+            assert bst._inner.grow._C == 128 * planes
+            xq, _ = _higgs(300, f=f, seed=12)
             _assert_parity(bst, xq)
         finally:
             restore_env_knobs(saved)

@@ -13,7 +13,8 @@ import pytest
 
 
 def _fresh_train(env_phys, env_stream, objective="binary", n=3000, f=6,
-                 rounds=5, weights=None, env_extra=None, **params):
+                 rounds=5, weights=None, env_extra=None, expect_c=None,
+                 **params):
     os.environ["LGBM_TPU_PHYS"] = env_phys
     os.environ["LGBM_TPU_STREAM"] = env_stream
     _extra_saved = {}
@@ -37,6 +38,8 @@ def _fresh_train(env_phys, env_stream, objective="binary", n=3000, f=6,
         ds = lgb.Dataset(x, label=y, weight=weights)
         bst = lgb.train(p, ds, num_boost_round=rounds)
         streaming = bst._inner._stream_grad
+        if expect_c is not None:
+            assert bst._inner.grow._C == expect_c
         trees = [(int(t.num_leaves),
                   t.split_feature[:int(t.num_leaves) - 1].tolist(),
                   t.threshold_bin[:int(t.num_leaves) - 1].tolist(),
@@ -105,35 +108,34 @@ def test_stream_vs_plain_quality():
 
 
 @pytest.mark.parametrize("objective", ["binary", "regression"])
-def test_stream_pack2_bitwise(objective):
-    """ISSUE-4: streamed training under LGBM_TPU_COMB_PACK=2 (packed
-    comb init + refresh through the real kernels,
-    LGBM_TPU_PART_INTERP=kernel) grows trees BIT-IDENTICAL to pack=1,
-    leaf-value bytes included."""
-    extra = {"LGBM_TPU_PART_INTERP": "kernel"}
-    out = {}
-    for pack in ("1", "2"):
-        p, t, s = _fresh_train(
-            "interpret", "", objective,
-            env_extra={**extra, "LGBM_TPU_COMB_PACK": pack})
-        assert s, "stream gate did not engage"
-        out[pack] = [(a, b, c, np.asarray(d).tobytes())
-                     for a, b, c, d in t]
-    assert out["1"] == out["2"]
+def test_stream_two_plane_matches_gather_refresh(objective):
+    """Streamed training at 130 features - a 256-lane comb line whose
+    score, constant and value columns all sit in the second plane -
+    grows the trees the gather-refresh physical path grows, both
+    through the real scan kernels (LGBM_TPU_PART_INTERP=kernel)."""
+    kw = dict(n=1500, f=130, rounds=2, num_leaves=7, expect_c=256,
+              env_extra={"LGBM_TPU_PART_INTERP": "kernel"})
+    p_ref, t_ref, s_ref = _fresh_train("interpret", "0", objective, **kw)
+    p_str, t_str, s_str = _fresh_train("interpret", "", objective, **kw)
+    assert not s_ref and s_str, "stream gate did not engage as expected"
+    assert any(t[0] > 1 for t in t_str)
+    _assert_trees_close(t_ref, t_str)
+    np.testing.assert_allclose(p_ref, p_str, rtol=5e-3, atol=1e-3)
 
 
-def test_stream_pack2_kernels_vs_reference():
-    """The REAL pack=2 stream kernels (init, refresh, fused
-    refresh+root-hist) run through the Pallas interpreter track their
-    XLA references to bf16-rounding tolerance on live rows (the kernels
+def test_stream_two_plane_kernels_vs_reference():
+    """The REAL stream kernels (init, refresh, fused refresh+root-hist)
+    at C = 256 - 144 bin columns, every stream column in the second
+    plane - run through the Pallas interpreter track their XLA
+    references to bf16-rounding tolerance on live rows (the kernels
     round g/h to bf16 — the precision every histogram matmul applies on
     chip anyway; slack rows are contractually dead)."""
     import jax.numpy as jnp
-    from lightgbm_tpu.ops.pallas.layout import LANE
+    from lightgbm_tpu.ops.pallas.layout import comb_shape, to_rows
     from lightgbm_tpu.ops.pallas.stream_grad import (
         binary_consts, build_aux, make_init, make_refresh)
     rng = np.random.default_rng(0)
-    n_alloc, f, n_pad, C, R = 2048 + 512, 16, 2048, LANE, 512
+    n_alloc, f, n_pad, C, R = 2048 + 512, 144, 2048, 256, 512
     bins = jnp.asarray(rng.integers(0, 200, size=(n_pad, f))
                        .astype(np.uint8))
     aux = build_aux(
@@ -146,31 +148,29 @@ def test_stream_pack2_kernels_vs_reference():
                         .astype(np.float32))))
     kw = dict(kind="binary", sigmoid=1.3, f_real=f, f=f,
               n_alloc=n_alloc, n_pad=n_pad, C=C, R=R)
-    comb0 = jnp.zeros((n_alloc // 2, C), jnp.float32)
-    c_ref = np.asarray(make_init(**kw, interpret=True, pack=2)(
-        comb0, bins, aux))
-    c_kern = np.asarray(make_init(**kw, pack=2, kernel_interpret=True)(
-        comb0, bins, aux))
-    live = n_pad // 2
-    assert np.abs(c_ref[:live] - c_kern[:live]).max() < 2e-2
+    comb0 = jnp.zeros(comb_shape(n_alloc, C), jnp.float32)
+
+    def live(comb):
+        return np.asarray(to_rows(comb, C))[:n_pad]
+
+    c_ref = make_init(**kw, interpret=True)(comb0, bins, aux)
+    c_kern = make_init(**kw, kernel_interpret=True)(comb0, bins, aux)
+    assert np.abs(live(c_ref)[:, f:]).sum() > 0
+    assert np.abs(live(c_ref) - live(c_kern)).max() < 2e-2
 
     rkw = dict(kind="binary", sigmoid=1.3, f=f, n_alloc=n_alloc,
                n_pad=n_pad, C=C, R=R)
     lv = jnp.asarray(rng.normal(size=(1, n_pad)).astype(np.float32)
                      * 0.1)
-    r_ref = np.asarray(make_refresh(**rkw, interpret=True, pack=2)(
-        jnp.asarray(c_ref), lv))
-    r_kern = np.asarray(make_refresh(**rkw, pack=2,
-                                     kernel_interpret=True)(
-        jnp.asarray(c_kern), lv))
-    assert np.abs(r_ref[:live] - r_kern[:live]).max() < 2e-2
+    r_ref = make_refresh(**rkw, interpret=True)(c_ref, lv)
+    r_kern = make_refresh(**rkw, kernel_interpret=True)(c_kern, lv)
+    assert np.abs(live(r_ref) - live(r_kern)).max() < 2e-2
 
-    _, h_ref = make_refresh(**rkw, interpret=True, pack=2,
-                            root_hist=True, padded_bins=256,
-                            root_rpb=256)(jnp.asarray(c_ref), lv)
-    _, h_kern = make_refresh(**rkw, pack=2, root_hist=True,
-                             padded_bins=256, kernel_interpret=True)(
-        jnp.asarray(c_kern), lv)
+    _, h_ref = make_refresh(**rkw, interpret=True, root_hist=True,
+                            padded_bins=256, root_rpb=256)(c_ref, lv)
+    _, h_kern = make_refresh(**rkw, root_hist=True, padded_bins=256,
+                             kernel_interpret=True)(c_kern, lv)
+    assert np.abs(np.asarray(h_ref)[128:]).sum() > 0
     assert np.abs(np.asarray(h_ref) - np.asarray(h_kern)).max() < 0.15
 
 

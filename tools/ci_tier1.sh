@@ -1,18 +1,15 @@
 #!/usr/bin/env bash
-# Tier-1 CI with the fallback-path and pack=2 legs (ISSUE 3/4
-# satellites).
+# Tier-1 CI with the fallback-path leg (ISSUE 3 satellite).
 #
 # Leg 1 runs the ROADMAP tier-1 command verbatim (default shipping
-# knobs: fused split kernel on, permute partition packing, pack=1).
+# knobs: fused split kernel on, permute partition packing).
 # Leg 2 re-runs the partition-sensitive suites with the FALLBACK knobs
 # (LGBM_TPU_FUSED=0, LGBM_TPU_PARTITION=matmul) so the bisection paths
 # cannot silently rot: the matmul packing and the separate
 # partition/histogram kernel pair stay trained-and-equivalent even
 # though the defaults no longer exercise them.
-# Leg 3 re-runs them with LGBM_TPU_COMB_PACK=2 over the REAL kernel
-# bodies (LGBM_TPU_PART_INTERP=kernel) so the packed comb layout's
-# trained path — partition, comb-direct histogram, stream refresh/init,
-# fused hooks — stays equivalent to pack=1 (ISSUE 4).
+# (Leg 3 went with the two-rows-a-line comb layout it tested; the leg
+# numbers below keep their names.)
 # Leg 4 (obs, ISSUE 5) captures a 2-iteration traced bench record and
 # runs the perf-regression gate against it: the self-diff must pass
 # exactly (counters exact, walls identical), and a synthetically
@@ -77,7 +74,7 @@
 # routing run over the REGENERATED matrix (the efb_bundle rule is
 # deleted — bundled columns unbundle onto the physical fast path at
 # comb ingest), the bundled-vs-unbundled bit-parity matrix
-# (tests/test_efb_physical.py: byte-identical trees across pack x
+# (tests/test_efb_physical.py: byte-identical trees across width x
 # serial/mesh through the real kernel bodies), a hand-mutated EFB
 # matrix cell must fail at cell level, and the efb_overwide red-team
 # fixture (the over-wide rule claimed without the over-wide shape
@@ -115,7 +112,7 @@
 #
 # Leg 18 (multiclass, ISSUE 19) pins the batched multiclass grow
 # path: the parity suite runs with its slow cells FORCED (batched
-# trees byte-identical to serial-K across pack/partition/fused/
+# trees byte-identical to serial-K across partition/fused/
 # learner cells, feature-fraction RNG alignment, class_need_train
 # gating, per-class NumericsSkip), the analyzer stays --strict over
 # the registered grow_physical_mc entry, the bad_mc_batch red-team
@@ -140,7 +137,6 @@
 #
 # Usage: bash tools/ci_tier1.sh            (all legs)
 #        bash tools/ci_tier1.sh --fallback (leg 2 only, ~2 min)
-#        bash tools/ci_tier1.sh --pack     (leg 3 only, ~3 min)
 #        bash tools/ci_tier1.sh --obs      (leg 4 only, ~1 min)
 #        bash tools/ci_tier1.sh --attr     (leg 5 only, ~10 s)
 #        bash tools/ci_tier1.sh --lint     (leg 6 only, ~30 s)
@@ -163,29 +159,11 @@ cd "$(dirname "$0")/.."
 fallback_leg() {
     echo "=== tier-1 leg 2: fallback paths (LGBM_TPU_FUSED=0" \
          "LGBM_TPU_PARTITION=matmul) ==="
-    # -u LGBM_TPU_COMB_PACK: pack=2 routing is permutation-only, so an
-    # exported COMB_PACK=2 would silently reroute this leg off the
-    # matmul scheme it exists to test
-    env -u LGBM_TPU_COMB_PACK -u LGBM_TPU_PART -u LGBM_TPU_PART_INTERP \
+    env -u LGBM_TPU_PART_INTERP \
         JAX_PLATFORMS=cpu LGBM_TPU_FUSED=0 LGBM_TPU_PARTITION=matmul \
         timeout -k 10 600 python -m pytest \
         tests/test_fused.py tests/test_physical.py \
         tests/test_partition_perm.py \
-        -q -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-}
-
-pack_leg() {
-    echo "=== tier-1 leg 3: pack=2 comb layout (LGBM_TPU_COMB_PACK=2" \
-         "LGBM_TPU_PART_INTERP=kernel) ==="
-    # -u the leg-2 knobs: an exported LGBM_TPU_FUSED=0 or
-    # PARTITION=matmul would silently drop this leg's fused pack=2
-    # coverage
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        JAX_PLATFORMS=cpu LGBM_TPU_COMB_PACK=2 \
-        LGBM_TPU_PART_INTERP=kernel \
-        timeout -k 10 600 python -m pytest \
-        tests/test_partition_perm.py tests/test_physical.py \
-        tests/test_fused.py tests/test_stream_grad.py \
         -q -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
 }
 
@@ -197,8 +175,8 @@ obs_leg() {
     trap "rm -rf '$tmp'" RETURN
     # 2-iteration traced smoke train -> a bench/v3 record with phases,
     # counters and the per-iteration ledger trajectory
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+        -u LGBM_TPU_PART_INTERP \
         JAX_PLATFORMS=cpu LGBM_TPU_TRACE="$tmp/trace.jsonl" \
         timeout -k 10 300 python bench.py --smoke --rows 4096 \
         --iters 2 --leaves 15 --json "$tmp/a.json" > /dev/null \
@@ -300,8 +278,8 @@ lint_leg() {
     # -u the VMEM knobs too: a leftover LGBM_TPU_VMEM_LIMIT_MB sweep
     # export (PERF_NOTES round 10) would either fail every kernel or
     # silently raise the budget this gate exists to pin
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+        -u LGBM_TPU_PART_INTERP \
         -u LGBM_TPU_VMEM_GEN -u LGBM_TPU_VMEM_LIMIT_MB \
         JAX_PLATFORMS=cpu timeout -k 10 300 \
         python -m lightgbm_tpu.analysis --strict \
@@ -338,8 +316,8 @@ mesh_obs_leg() {
     trap "rm -rf '$tmp'" RETURN
     # traced 8-CPU mesh training -> a multichip bench/v3 record with
     # per-shard ledger rows, the skew series and the multichip block
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+        -u LGBM_TPU_PART_INTERP \
         JAX_PLATFORMS=cpu timeout -k 10 600 \
         python tools/multichip_probe.py --rows 6000 --iters 3 \
         --json "$tmp/mc.json" > /dev/null 2> "$tmp/probe.err" \
@@ -456,8 +434,8 @@ mem_leg() {
     trap "rm -rf '$tmp'" RETURN
     # gate 1: pinned `obs mem` table on the checked-in fixture record
     # (footprint model -> phase live-sets -> measured join, exact)
-    env -u LGBM_TPU_HBM_GEN -u LGBM_TPU_HBM_LIMIT_GB -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_R -u LGBM_TPU_COMB_PACK -u LGBM_TPU_STREAM \
+    env -u LGBM_TPU_HBM_GEN -u LGBM_TPU_HBM_LIMIT_GB \
+        -u LGBM_TPU_PART_R -u LGBM_TPU_STREAM \
         JAX_PLATFORMS=cpu python -m lightgbm_tpu.obs mem \
         tests/data/synthetic_mem_record.json \
         > "$tmp/mem.out" 2> "$tmp/mem.err"
@@ -475,8 +453,8 @@ mem_leg() {
     fi
     # gate 2: a freshly-captured traced record carries the memory
     # block, reports cleanly, and self-diffs green
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+        -u LGBM_TPU_PART_INTERP \
         -u LGBM_TPU_HBM_GEN -u LGBM_TPU_HBM_LIMIT_GB \
         JAX_PLATFORMS=cpu LGBM_TPU_TRACE="$tmp/trace.jsonl" \
         timeout -k 10 300 python bench.py --smoke --rows 4096 \
@@ -593,8 +571,8 @@ routing_leg() {
     # every row_order cell justified, recompile audit green).  -u the
     # path knobs: an exported sweep knob would re-route the audited
     # builds
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+        -u LGBM_TPU_PART_INTERP \
         -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM -u LGBM_TPU_HIST_SCATTER \
         JAX_PLATFORMS=cpu timeout -k 10 300 \
         python -m lightgbm_tpu.analysis --passes routing --strict \
@@ -647,7 +625,7 @@ import json, sys
 base = {"schema": "lightgbm_tpu/bench/v3", "metric": "m",
         "value": 1.0, "unit": "iters/sec"}
 a = dict(base, routing={"digest": "aaaaaaaaaaaa", "path": "physical",
-                        "pack": 2, "scheme": "permute",
+                        "pack": 1, "scheme": "permute",
                         "hist_merge": "none"})
 b = dict(base, routing={"digest": "bbbbbbbbbbbb", "path": "row_order",
                         "pack": 1, "scheme": "none",
@@ -803,8 +781,8 @@ efb_leg() {
     # gate 1: clean strict analyzer run with the REGENERATED matrix
     # (the efb_bundle rule is deleted; every formerly-row_order EFB
     # cell must now route physical/stream or carry efb_overwide)
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+        -u LGBM_TPU_PART_INTERP \
         -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM -u LGBM_TPU_HIST_SCATTER \
         JAX_PLATFORMS=cpu timeout -k 10 300 \
         python -m lightgbm_tpu.analysis --passes routing --strict \
@@ -818,10 +796,10 @@ efb_leg() {
         return 1
     fi
     # gate 2: the bit-parity matrix (bundled vs pre-unbundled trees
-    # byte-identical across pack x serial/mesh, real kernel bodies)
+    # byte-identical across width x serial/mesh, real kernel bodies)
     # plus the original EFB invariants stay green
-    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-        -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+        -u LGBM_TPU_PART_INTERP \
         -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
         JAX_PLATFORMS=cpu timeout -k 10 600 python -m pytest \
         tests/test_efb_physical.py tests/test_efb.py \
@@ -876,8 +854,8 @@ faults_leg() {
     # sweep knob would change the engaged routing digest and make the
     # resume legs refuse for the wrong reason
     demo() {
-        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-            -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+            -u LGBM_TPU_PART_INTERP \
             -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
             -u LGBM_TPU_HIST_SCATTER -u LGBM_TPU_NUMERICS \
             -u LGBM_TPU_FAULT -u LGBM_TPU_FAULT_RETRIES \
@@ -1003,8 +981,8 @@ serve_leg() {
     # shellcheck disable=SC2064 -- expand $tmp now, not at RETURN time
     trap "rm -rf '$tmp'" RETURN
     demo() {
-        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-            -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+            -u LGBM_TPU_PART_INTERP \
             -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
             -u LGBM_TPU_SERVE -u LGBM_TPU_SERVE_BUCKETS \
             -u LGBM_TPU_SERVE_QUEUE \
@@ -1121,8 +1099,8 @@ paged_leg() {
     # shellcheck disable=SC2064 -- expand $tmp now, not at RETURN time
     trap "rm -rf '$tmp'" RETURN
     demo() {
-        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-            -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+            -u LGBM_TPU_PART_INTERP \
             -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
             -u LGBM_TPU_PAGED -u LGBM_TPU_PAGE_ROWS \
             -u LGBM_TPU_HBM_LIMIT_GB \
@@ -1133,7 +1111,7 @@ paged_leg() {
             JAX_PLATFORMS=cpu "$@"
     }
     # gate 1: the paged suite — schedule audit, byte-identical paged
-    # vs unpaged matrix (pack x scheme x fused x stream through the
+    # vs unpaged matrix (width x scheme x fused x stream through the
     # real kernels), geometry == planner, AT_REFRESH cadence
     demo timeout -k 10 900 \
         python -m pytest tests/test_paged.py -q -m 'not slow' \
@@ -1204,8 +1182,8 @@ cat_leg() {
     # shellcheck disable=SC2064 -- expand $tmp now, not at RETURN time
     trap "rm -rf '$tmp'" RETURN
     demo() {
-        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-            -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+            -u LGBM_TPU_PART_INTERP \
             -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
             -u LGBM_TPU_HIST_SCATTER \
             JAX_PLATFORMS=cpu "$@"
@@ -1227,7 +1205,7 @@ cat_leg() {
         return 1
     fi
     # gate 2: the bit-parity matrix (categorical trees byte-identical
-    # across pack x partition-scheme x fused x serial/mesh through the
+    # across partition-scheme x fused x serial/mesh through the
     # REAL kernel bodies, edge predictions, serving round-trip, the
     # overwide build defense) plus the original host-side cat-subset
     # finder invariants stay green.  NO 'not slow' filter: tier-1
@@ -1287,8 +1265,8 @@ serve_obs_leg() {
     # shellcheck disable=SC2064 -- expand $tmp now, not at RETURN time
     trap "rm -rf '$tmp'" RETURN
     demo() {
-        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-            -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+            -u LGBM_TPU_PART_INTERP \
             -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
             -u LGBM_TPU_SERVE -u LGBM_TPU_SERVE_BUCKETS \
             -u LGBM_TPU_SERVE_QUEUE -u LGBM_TPU_SERVE_METRICS \
@@ -1422,8 +1400,8 @@ serve_kernel_leg() {
     # shellcheck disable=SC2064 -- expand $tmp now, not at RETURN time
     trap "rm -rf '$tmp'" RETURN
     demo() {
-        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-            -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+            -u LGBM_TPU_PART_INTERP \
             -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
             -u LGBM_TPU_SERVE -u LGBM_TPU_SERVE_BUCKETS \
             -u LGBM_TPU_SERVE_QUEUE -u LGBM_TPU_SERVE_KERNEL \
@@ -1548,8 +1526,8 @@ multiclass_leg() {
     # shellcheck disable=SC2064 -- expand $tmp now, not at RETURN time
     trap "rm -rf '$tmp'" RETURN
     demo() {
-        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION -u LGBM_TPU_PART \
-            -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+        env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
+            -u LGBM_TPU_PART_INTERP \
             -u LGBM_TPU_PHYS -u LGBM_TPU_STREAM \
             -u LGBM_TPU_MC_BATCH -u LGBM_TPU_NUMERICS \
             -u LGBM_TPU_HIST_SCATTER \
@@ -1558,7 +1536,7 @@ multiclass_leg() {
     # gate 1: the byte-identity parity suite with the slow cells
     # FORCED (no -m 'not slow') — batched-vs-serial tree equality is
     # the whole contract of the one-dispatch path, so every
-    # pack/partition/fused/learner cell runs here even though leg 1
+    # partition/fused/learner cell runs here even though leg 1
     # skips the slow half
     demo timeout -k 10 900 \
         python -m pytest tests/test_multiclass_batched.py -q \
@@ -1842,10 +1820,6 @@ if [ "$1" = "--fallback" ]; then
     fallback_leg
     exit $?
 fi
-if [ "$1" = "--pack" ]; then
-    pack_leg
-    exit $?
-fi
 if [ "$1" = "--obs" ]; then
     obs_leg
     exit $?
@@ -1917,7 +1891,7 @@ rm -f /tmp/_t1.log
 # exports fallback knobs (otherwise both legs silently run the same
 # config and the default path goes untested)
 timeout -k 10 870 env -u LGBM_TPU_FUSED -u LGBM_TPU_PARTITION \
-    -u LGBM_TPU_PART -u LGBM_TPU_PART_INTERP -u LGBM_TPU_COMB_PACK \
+    -u LGBM_TPU_PART_INTERP \
     JAX_PLATFORMS=cpu \
     python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
@@ -1928,9 +1902,6 @@ echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log \
 
 fallback_leg
 rc2=$?
-
-pack_leg
-rc3=$?
 
 obs_leg
 rc4=$?
@@ -1980,13 +1951,13 @@ rc18=$?
 pulse_leg
 rc19=$?
 
-echo "=== tier-1 summary: leg1 rc=$rc1 leg2 rc=$rc2 leg3 rc=$rc3" \
+echo "=== tier-1 summary: leg1 rc=$rc1 leg2 rc=$rc2" \
      "leg4 rc=$rc4 leg5 rc=$rc5 leg6 rc=$rc6 leg7 rc=$rc7" \
      "leg8 rc=$rc8 leg9 rc=$rc9 leg10 rc=$rc10 leg11 rc=$rc11" \
      "leg12 rc=$rc12 leg13 rc=$rc13 leg14 rc=$rc14 leg15 rc=$rc15" \
      "leg16 rc=$rc16 leg17 rc=$rc17 leg18 rc=$rc18" \
      "leg19 rc=$rc19 ==="
-[ "$rc1" -eq 0 ] && [ "$rc2" -eq 0 ] && [ "$rc3" -eq 0 ] \
+[ "$rc1" -eq 0 ] && [ "$rc2" -eq 0 ] \
     && [ "$rc4" -eq 0 ] && [ "$rc5" -eq 0 ] && [ "$rc6" -eq 0 ] \
     && [ "$rc7" -eq 0 ] && [ "$rc8" -eq 0 ] && [ "$rc9" -eq 0 ] \
     && [ "$rc10" -eq 0 ] && [ "$rc11" -eq 0 ] && [ "$rc12" -eq 0 ] \

@@ -17,8 +17,8 @@ needs —
   block's skew time series;
 * a schema-additive ``multichip`` block
   (``lightgbm_tpu/multichip/v1``): mesh geometry (axes, shard count,
-  device kind), the engaged learner flags (physical / hist_scatter /
-  comb_pack), and the obs event totals (fallback events are visible in
+  device kind), the engaged learner flags (physical / hist_scatter),
+  and the obs event totals (fallback events are visible in
   the artifact, not just the log).
 
 ``obs diff`` / ``tools/perf_gate.py`` compare two such records with
@@ -122,13 +122,11 @@ def probe_record(n_devices: int, *, learner: str = "data",
         print(f"[multichip_probe] note: requested {n_devices} devices "
               f"but the mesh engaged {n_shards} shard(s); the record "
               "is labeled with the engaged count", file=sys.stderr)
-    pack = int(getattr(grower, "pack", 1))
     rec = bench_record(
         f"multichip_iters_per_sec_{learner}{n_shards}",
         round(iters / elapsed, 4), "iters/sec",
         rows=rows, iters=iters, leaves=leaves,
         knobs={
-            "comb_pack": pack,
             "partition": os.environ.get("LGBM_TPU_PARTITION",
                                         "permute"),
             "fused": os.environ.get("LGBM_TPU_FUSED", "1") != "0",
@@ -169,7 +167,6 @@ def probe_record(n_devices: int, *, learner: str = "data",
         "learner": learner,
         "physical": bool(getattr(grower, "physical", False)),
         "hist_scatter": bool(getattr(grower, "hist_scatter", False)),
-        "comb_pack": pack,
         "events": obs_events.totals(),
     }
     return rec
@@ -258,7 +255,6 @@ def main(argv=None) -> int:
           f"{mc.get('n_shards')} shard(s): {rec.get('value')} "
           f"iters/sec, physical={mc.get('physical')}, "
           f"hist_scatter={mc.get('hist_scatter')}, "
-          f"pack={mc.get('comb_pack')}, "
           f"{len((rec.get('ledger') or {}).get('collectives', []))} "
           "collective row(s)", file=sys.stderr)
     return 0

@@ -6,7 +6,7 @@ leaves (~120 us for the pair at 1k rows; docs/PERF_NOTES.md "Next
 levers" #3).  Each variant runs ONE split of an L-row leaf per
 iteration of an in-jit fori_loop whose accumulator depends on the
 kernel outputs (nleft + histogram sum), barriered by a HOST VALUE PULL
-(PERF_NOTES "round 3b" methodology; see tools/profile_legacy.py part8).
+(PERF_NOTES "round 3b" methodology).
 
   pair   — make_partition_ss + build_histogram_comb_dyn of the smaller
            child: the unfused production path's two pallas_call entries

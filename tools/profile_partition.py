@@ -1,4 +1,4 @@
-"""Partition-kernel sweep: scheme x R x packing x dtype (ISSUE 3).
+"""Partition-kernel sweep: scheme x R x dtype (ISSUE 3).
 
 Measures the single-scan partition's per-row cost for every
 combination of
@@ -7,17 +7,11 @@ combination of
              ([R, R] one-hot contraction, O(R)/row)
   * R:       block rows (LGBM_TPU_PART_R candidates; the round-3b
              sweep put the matmul scheme's knee at 512)
-  * pack:    1 (one row per 128-lane line) vs 2 (two logical rows per
-             line — HALF the partition DMA bytes; permute only).  The
-             pack=2 layout is the TRAINED path behind
-             LGBM_TPU_COMB_PACK=2 since ISSUE 4 (grow wires it through
-             histogram/stream/fused), so this sweep is its floor
-             measurement.  Each record carries the DMA-bytes accounting
-             (dma_bytes_per_logical_row = line bytes / pack x ~4 moves:
-             scan read + rows/scratch writes + copyback) so the
-             bytes-halved claim is checkable per point.
   * dtype:   f32, plus a bf16 attempt that documents the Mosaic
              (8,128)x2 dynamic-offset blocker instead of crashing.
+
+Each record carries the DMA-bytes accounting (dma_bytes_per_logical_row
+= line bytes x ~4 moves: scan read + rows/scratch writes + copyback).
 
 Methodology: ``profile_lib.bench_chain`` — the IN-JIT fori_loop chain
 whose accumulator depends on each call's ``nleft`` output, barriered by
@@ -49,39 +43,26 @@ from profile_lib import bench_chain, bench_record
 from lightgbm_tpu.ops.pallas.layout import LANE
 from lightgbm_tpu.ops.pallas.partition_kernel import SEL_S0, SEL_CNT
 from lightgbm_tpu.ops.pallas.partition_kernel2 import make_partition_ss
-from lightgbm_tpu.ops.pallas.partition_kernel3 import (
-    make_partition_p2, make_partition_perm)
+from lightgbm_tpu.ops.pallas.partition_kernel3 import make_partition_perm
 
 C = 128
 
 
-def _builder(scheme, pack):
-    if pack == 2:
-        assert scheme == "permute", "pack=2 is permute-only"
-        return lambda n, **kw: make_partition_p2(n, **kw)
-    mk = make_partition_perm if scheme == "permute" else make_partition_ss
-    return lambda n, **kw: mk(n, C, **kw)
-
-
-def _rows(n_alloc, pack, dtype, seed=0):
+def _rows(n_alloc, dtype, seed=0):
     rng = np.random.default_rng(seed)
-    w = LANE // pack
-    logical = np.zeros((n_alloc, w), np.float32)
-    logical[:, :16] = rng.integers(0, 256, size=(n_alloc, 16))
-    if pack == 2:
-        logical = logical.reshape(n_alloc // 2, LANE)
-    return jnp.asarray(logical).astype(dtype)
+    rows = np.zeros((n_alloc, LANE), np.float32)
+    rows[:, :16] = rng.integers(0, 256, size=(n_alloc, 16))
+    return jnp.asarray(rows).astype(dtype)
 
 
-def run_point(scheme, r, pack, dtype, n_cnt, interpret, reps):
+def run_point(scheme, r, dtype, n_cnt, interpret, reps):
     n_alloc = n_cnt + 2 * r + 2 * 2048
-    if pack == 2 and n_alloc % 2:
-        n_alloc += 1
     kw = dict(R=r, size=n_cnt, dtype=dtype)
     if interpret:
         kw.update(interpret=True, interpret_kernel=True)
-    part = _builder(scheme, pack)(n_alloc, **kw)
-    rows = _rows(n_alloc, pack, dtype)
+    mk = make_partition_perm if scheme == "permute" else make_partition_ss
+    part = mk(n_alloc, C, **kw)
+    rows = _rows(n_alloc, dtype)
     scratch = jnp.zeros_like(rows)
     sel = np.zeros((8,), np.int32)
     sel[SEL_S0], sel[SEL_CNT], sel[2], sel[3] = 0, n_cnt, 3, 127
@@ -114,45 +95,43 @@ def main() -> int:
     reps = 2 if interpret else args.reps
     rs = [int(x) for x in args.rs.split(",")]
 
-    points = [("matmul", 1, jnp.float32), ("permute", 1, jnp.float32),
-              ("permute", 2, jnp.float32)]
+    dtype = jnp.float32
     for r in rs:
-        for scheme, pack, dtype in points:
+        for scheme in ("matmul", "permute"):
             try:
-                dt = run_point(scheme, r, pack, dtype, n_cnt,
-                               interpret, reps)
+                dt = run_point(scheme, r, dtype, n_cnt, interpret, reps)
             except Exception as e:  # noqa: BLE001 — sweep must finish
                 print(json.dumps(bench_record(
-                    f"partition_{scheme}_R{r}_pack{pack}", -1.0,
+                    f"partition_{scheme}_R{r}", -1.0,
                     "ns/row", error=f"{type(e).__name__}: {e}"[:200])))
                 continue
             line_bytes = LANE * jnp.dtype(dtype).itemsize
             print(json.dumps(bench_record(
-                f"partition_{scheme}_R{r}_pack{pack}",
+                f"partition_{scheme}_R{r}",
                 round(dt / n_cnt * 1e9, 3), "ns/row",
                 rows=n_cnt, reps=reps, secs_per_step=round(dt, 6),
                 interpret=interpret,
-                # bytes each LOGICAL row moves per line touch; the
+                # bytes each row moves per line touch; the
                 # scan/copyback touch every partitioned row ~4x (read,
                 # rows+scratch writes, copyback), so total partition
-                # DMA per logical row ~= 4x this — pack=2 halves it
-                dma_bytes_per_logical_row=line_bytes // pack,
-                dma_bytes_per_row_total=4 * line_bytes // pack)))
+                # DMA per row ~= 4x this
+                dma_bytes_per_logical_row=line_bytes,
+                dma_bytes_per_row_total=4 * line_bytes)))
     # bf16 storage: expected to fail Mosaic's (8,128)x2 dynamic-offset
     # tiling proof today (PERF_NOTES lever #1) — record the outcome so
     # the next chip run documents whether the restriction lifted
     if not interpret:
         try:
-            dt = run_point("permute", rs[0], 1, jnp.bfloat16, n_cnt,
+            dt = run_point("permute", rs[0], jnp.bfloat16, n_cnt,
                            False, reps)
             print(json.dumps(bench_record(
-                f"partition_permute_R{rs[0]}_pack1_bf16",
+                f"partition_permute_R{rs[0]}_bf16",
                 round(dt / n_cnt * 1e9, 3), "ns/row", rows=n_cnt)))
         except Exception as e:  # noqa: BLE001
             # SAME metric key as the success branch so blocked /
             # unblocked outcomes pair across chip runs in obs report
             print(json.dumps(bench_record(
-                f"partition_permute_R{rs[0]}_pack1_bf16", -1.0,
+                f"partition_permute_R{rs[0]}_bf16", -1.0,
                 "ns/row", blocked=f"{type(e).__name__}: {e}"[:200])))
     return 0
 

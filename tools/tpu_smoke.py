@@ -26,10 +26,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # could silently reroute it before jax/lightgbm_tpu import
 for _k, _v in (("LGBM_TPU_PHYS", ""), ("LGBM_TPU_STREAM", ""),
                ("LGBM_TPU_COMB_DT", "f32"), ("LGBM_TPU_APPLY_IMPL", ""),
-               ("LGBM_TPU_PART", ""), ("LGBM_TPU_PART_R", ""),
+               ("LGBM_TPU_PART_R", ""),
                ("LGBM_TPU_COMB_BF16", ""), ("LGBM_TPU_POOL_TAIL", ""),
                ("LGBM_TPU_FUSED", ""), ("LGBM_TPU_PARTITION", ""),
-               ("LGBM_TPU_PART_INTERP", ""), ("LGBM_TPU_COMB_PACK", "")):
+               ("LGBM_TPU_PART_INTERP", "")):
     if _v:
         os.environ[_k] = _v
     else:
@@ -168,18 +168,6 @@ def _check_partition_identity():
                          "partition-identity")
 
 
-def _check_pack_identity():
-    """Compiled pack=2 comb layout (ISSUE 4) must grow BYTE-identical
-    trees to pack=1: the packed scan reproduces the pack=1 layout in
-    the logical domain and every histogram/stream consumer unpacks in
-    register.  The interpret-mode matrix lives in tests/test_physical
-    .py::test_pack_parity_matrix; this is the compiled-path arbiter
-    (accumulation grouping differences must wash out like the fused
-    root carry's — see PERF_NOTES round 7)."""
-    _check_knob_identity("LGBM_TPU_COMB_PACK", ("2", "1"),
-                         "pack-identity")
-
-
 def _check_trace(n_rows: int = 50_048, num_leaves: int = 31,
                  iters: int = 3) -> dict:
     """Observability gate: with LGBM_TPU_TRACE set, a compiled-path run
@@ -313,7 +301,6 @@ def _check_memory(n_rows: int = 50_048, num_leaves: int = 63,
         f_pad=int(inner.dd.phys_f_pad),
         padded_bins=int(inner.dd.phys_padded_bins),
         num_leaves=num_leaves,
-        pack=int(getattr(grower, "pack", 1)),
         stream=bool(getattr(inner, "_stream_grad", False)),
         fused=bool(getattr(grower, "fused", True)),
         bins_cols=int(inner.dd.bins.shape[1]),
@@ -454,11 +441,6 @@ def main() -> int:
         tpi = time.perf_counter()
         _check_partition_identity()
         timings["partition_identity"] = time.perf_counter() - tpi
-        # pack=2 comb layout: trained end to end at half the partition
-        # DMA bytes, trees byte-identical to pack=1 (ISSUE 4)
-        tpk = time.perf_counter()
-        _check_pack_identity()
-        timings["pack_identity"] = time.perf_counter() - tpk
         # observability gate: tracer output well-formed, all reference
         # phases present, counters exact on the compiled path, run
         # ledger sampled per iteration
@@ -476,7 +458,7 @@ def main() -> int:
     total = time.perf_counter() - t0
     print(f"[tpu_smoke] GREEN in {total:.1f}s "
           f"({len(shapes) * 2} configs + memory gate + fused identity "
-          "+ partition identity + pack identity + trace gate + device "
+          "+ partition identity + trace gate + device "
           "attr, compiled TPU path)")
     if args.json:
         # schema-versioned record so the smoke timings land next to the
@@ -489,12 +471,10 @@ def main() -> int:
                            checks={k: round(v, 2)
                                    for k, v in timings.items()},
                            # knob provenance so A/B smoke records can't
-                           # be confused across pack / scheme sweeps
+                           # be confused across scheme sweeps
                            # (bench_record adds the git/jax/device
                            # provenance header itself since bench/v3)
                            knobs={
-                               "comb_pack": int(os.environ.get(
-                                   "LGBM_TPU_COMB_PACK", "1")),
                                "partition": os.environ.get(
                                    "LGBM_TPU_PARTITION", "permute"),
                                "fused": os.environ.get(
