@@ -33,6 +33,7 @@ from ..obs import tracer as obs_tracer
 from ..objective.base import ObjectiveFunction
 from ..ops.device_data import DeviceDataset, to_device
 from ..ops.grow import make_grow_fn
+from ..ops.leaf_lookup import leaf_table_lookup
 from ..ops.predict import (DeviceTree, add_tree_score,
                            device_tree_from_arrays, predict_leaf_bins,
                            tree_to_device)
@@ -1538,14 +1539,17 @@ class GBDT:
         @jax.jit
         def tail(ta, leaf_id, score_k, vbins, vscores_k, rate, init_score):
             is_real = ta.num_leaves > 1
-            delta = jnp.where(is_real, rate * ta.leaf_value[leaf_id], 0.0)
+            delta = jnp.where(
+                is_real, rate * leaf_table_lookup(ta.leaf_value, leaf_id), 0.0)
             new_score = score_k + delta
             dt = device_tree_from_arrays(ta)
             new_vscores = []
             for vb, vsk in zip(vbins, vscores_k):
                 leaf_v = predict_leaf_bins(dt, vb, num_bins, has_nan,
                                            feat_map=fmap)
-                dv = jnp.where(is_real, rate * ta.leaf_value[leaf_v], 0.0)
+                dv = jnp.where(
+                    is_real, rate * leaf_table_lookup(ta.leaf_value, leaf_v),
+                    0.0)
                 new_vscores.append(vsk + dv)
             # replay replica: shrunk values (+ boost-from-average bias,
             # which the host path folds in via add_bias / single_leaf)

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from .histogram import build_histogram
+from .leaf_lookup import leaf_of_position
 # serving's bin-indexed bitset packer, reused verbatim so the partition
 # kernels' sel membership words and the serving gather decode the SAME
 # encoding (ISSUE 16)
@@ -2104,12 +2105,22 @@ def make_grow_fn(
         )
         # reconstruct the per-row leaf assignment ONCE from the partition
         # (row_order/permuted rows + seg tile [0, n)), instead of
-        # scattering a [n] leaf_id vector on every split: sort leaves by
-        # segment start, expand ids across their row spans, undo the
+        # scattering a [n] leaf_id vector on every split: a position's
+        # leaf is the one whose segment holds it (and, on the stream
+        # route, its shrunk output rides the same mask); undo the
         # permutation.
-        order = jnp.argsort(state.seg[:, 0]).astype(jnp.int32)
-        rows_sorted = state.seg[order, 1]
-        leaf_of_pos = jnp.repeat(order, rows_sorted, total_repeat_length=n)
+        streams = physical and stream is not None and not debug_state
+        if streams:
+            # shrinkage arrives as a TRACED per-call scalar: callbacks
+            # (reset_parameter) may change learning_rate mid-training,
+            # and a baked constant would silently desync the in-comb
+            # scores from the booster's
+            lv_leaf = jnp.where(state.num_leaves > 1,
+                                stream_rate * lstate[:, _SOUT], 0.0)
+            leaf_of_pos, lv_row = leaf_of_position(
+                state.seg, n, (lv_leaf,))         # [n] by position
+        else:
+            leaf_of_pos, = leaf_of_position(state.seg, n)
         if physical:
             # positions [0, n) always hold a permutation of the original
             # rows (partitions only permute within segment ranges); decode
@@ -2123,19 +2134,12 @@ def make_grow_fn(
                 leaf_of_pos)
         if debug_state:
             return tree, leaf_id, state.best, state.lstate
-        if physical and stream is not None:
+        if streams:
             # prepare the NEXT tree in-place: every comb position's score
             # gains this tree's shrunk leaf output (positions already sit
             # inside their leaf's segment), then g/h recompute from the
             # new scores — one streaming pass, no gathers.  Mirrors the
             # async score-update tail in gbdt (rate * leaf_value[leaf]).
-            # shrinkage arrives as a TRACED per-call scalar: callbacks
-            # (reset_parameter) may change learning_rate mid-training,
-            # and a baked constant would silently desync the in-comb
-            # scores from the booster's
-            lv_leaf = jnp.where(state.num_leaves > 1,
-                                stream_rate * lstate[:, _SOUT], 0.0)
-            lv_row = jnp.take(lv_leaf, leaf_of_pos)       # [n] by position
             if _fused_root:
                 # fused refresh: the pass that rewrites scores/gradients
                 # also accumulates the NEXT tree's root histogram from

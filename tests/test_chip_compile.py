@@ -278,6 +278,53 @@ def test_the_fused_scan_accumulates_one_child(name, groups, compiled_text):
     assert f"f32[2,{groups},128,256]" not in scans[0]
 
 
+def _hand_off(stream: bool, n: int):
+    """The finalisation of ``ops/grow.py`` after the last split, at a
+    cell's row count: leaf ids (on the stream route also the shrunk
+    leaf outputs) by position from the segment table, and the
+    un-permute to row order by the comb's row ids."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.leaf_lookup import leaf_of_position
+
+    def fn(seg, lv_leaf, ridx):
+        leaf_of_pos, *lv_row = leaf_of_position(
+            seg, n, (lv_leaf,) if stream else ())
+        leaf_id = jnp.zeros((n,), jnp.int32).at[ridx].set(
+            leaf_of_pos, mode="drop")
+        return (leaf_id, *lv_row)
+
+    return fn, (sds((LEAVES, 2), jnp.int32), sds((LEAVES,), jnp.float32),
+                sds((n,), jnp.int32))
+
+
+@pytest.mark.parametrize("stream,n", [
+    (True, 10_500_096), (True, HIGGS[0]), (False, MSLTR[0])],
+    ids=["higgs_10m_stream", "higgs_1m_stream", "msltr_physical"])
+def test_hand_off_compiles_to_compares_not_gathers(stream, n, one_chip,
+                                                   no_compile_cache):
+    """ISSUE 33: at 255 leaves the v5e program of the hand-off holds no
+    gather (8 ns an element on this chip whatever the table's size);
+    the select-sum is a reduce inside a fusion, its [L, n] operand never
+    an array; the one sort is the scatter's (XLA:TPU sorts the (row id,
+    leaf) pairs at 10.5M rows and not at 2.27M); and the temporaries
+    stay under three n-sized vectors, what the repeat + take form held
+    (``higgs-train-10m`` runs 0.46e9 under the chip's memory)."""
+    import re
+    compiled = _compile(functools.partial(_hand_off, stream, n), one_chip)
+    text = compiled.as_text()
+    ops = re.findall(r"^\s*(?:ROOT )?%[\w.-]+ = (\S+) ([\w-]+)\(", text,
+                     re.M)
+    assert ops and not [o for o in ops if o[1] == "gather"]
+    assert len([o for o in ops if o[1] == "sort"]) <= 1
+    wide = [o for o in ops if o[0].startswith((f"s32[{LEAVES},{n}]",
+                                               f"pred[{LEAVES},{n}]"))]
+    assert wide and " reduce(" in text
+    entry = text[text.index("\nENTRY "):]
+    assert f"[{LEAVES},{n}]" not in entry
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * n
+
+
 def test_the_finder_at_the_msltr_width_is_the_xla_tail():
     """144 columns x 256 bins is past the Pallas finder's scoped-VMEM
     budget (apply_find.tail_supported), so that route's split finder is

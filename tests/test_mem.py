@@ -71,6 +71,20 @@ def _build_grow(n, f, b, L, *, stream=False):
                         physical_bins=_sds((n, f), jnp.uint8), **kw)
 
 
+def _grow_args(gp, n, f, b, stream):
+    """The operands of ``gp._grow_p``, as shapes."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    args = [_sds(comb_shape(gp._n_alloc, gp._C), jnp.float32)] * 2
+    args += [_sds((1,) if stream else (n,), jnp.float32)] * 3
+    args += [_sds((f,), jnp.float32), _sds((f,), jnp.int32),
+             _sds((f,), jnp.bool_), _sds((f,), jnp.bool_),
+             _sds((), jnp.int32), _sds((), jnp.float32)]
+    if stream and gp._root0_fn is not None:
+        args.append(_sds((f, b, 2), jnp.float32))
+    return args
+
+
 # ---------------------------------------------------------------------
 # footprint-model equality vs the real grow jaxprs (the acceptance
 # criterion: exact bytes, one AND two comb planes, stream on/off, mesh)
@@ -82,7 +96,6 @@ _F_PLANES = {1: 16, 2: 144}     # feature columns -> comb planes
 @pytest.mark.parametrize("stream", [False, True])
 def test_footprint_equals_grow_jaxpr(planes, stream):
     import jax
-    import jax.numpy as jnp
     from lightgbm_tpu.ops.pallas.layout import comb_shape
     n, f, b, L = 4096, _F_PLANES[planes], 32, 8
     gp = _build_grow(n, f, b, L, stream=stream)
@@ -93,15 +106,8 @@ def test_footprint_equals_grow_jaxpr(planes, stream):
     assert geo["n_alloc"] == gp._n_alloc
     assert geo["C"] == gp._C == 128 * planes
 
-    args = [_sds(comb_shape(gp._n_alloc, gp._C), jnp.float32)] * 2
-    args += [_sds((1,) if stream else (n,), jnp.float32)] * 3
-    args += [_sds((f,), jnp.float32), _sds((f,), jnp.int32),
-             _sds((f,), jnp.bool_), _sds((f,), jnp.bool_),
-             _sds((), jnp.int32), _sds((), jnp.float32)]
     carry = stream and gp._root0_fn is not None
-    if carry:
-        args.append(_sds((f, b, 2), jnp.float32))
-    traced = jax.make_jaxpr(gp._grow_p)(*args)
+    traced = jax.make_jaxpr(gp._grow_p)(*_grow_args(gp, n, f, b, stream))
     invars = [v.aval for v in traced.jaxpr.invars]
 
     # comb / scratch: EXACT equality, shape (the model's lines x lanes,
@@ -198,6 +204,92 @@ def test_footprint_equals_batched_mc_grow_jaxpr():
     assert ta["bytes"] == k * serial["buffers"]["tree_arrays"]["bytes"]
     # the batch only ever ADDS footprint terms vs serial-K
     assert fp["peak_bytes"] > serial["peak_bytes"]
+
+
+def _eqns(traced):
+    """Every equation of a traced program, nested jaxprs included."""
+    inner = getattr(traced, "jaxpr", traced)
+    for eqn in inner.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else [p]):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    yield from _eqns(sub)
+
+
+def _row_sized_lookups(traced, n):
+    """(gathers of n results out of a table of at most 256 entries,
+    scatters into an n-sized operand) of a traced program."""
+    size = lambda v: int(np.prod(v.aval.shape, dtype=np.int64))
+    gathers = [e for e in _eqns(traced) if e.primitive.name == "gather"
+               and size(e.invars[0]) <= 256 and size(e.outvars[0]) == n]
+    scatters = [e for e in _eqns(traced)
+                if e.primitive.name.startswith("scatter")
+                and size(e.invars[0]) == n]
+    return gathers, scatters
+
+
+def _stream_grow_jaxpr(n, f, b, L):
+    import jax
+    gp = _build_grow(n, f, b, L, stream=True)
+    return jax.make_jaxpr(gp._grow_p)(*_grow_args(gp, n, f, b, True))
+
+
+def _tail_jaxpr(n, L):
+    """The boosting loop's score-update program, traced on the
+    operands its first call gets."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.models.gbdt import GBDT
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(n, 4)).astype(np.float32)
+    seen = {}
+    finish = GBDT._finish_tree_async
+
+    def spy(self, ta, leaf_id, kidx, init_score):
+        seen.setdefault("args", (self, ta, leaf_id))
+        return finish(self, ta, leaf_id, kidx, init_score)
+
+    GBDT._finish_tree_async = spy
+    try:
+        lgb.train({"objective": "binary", "num_leaves": L,
+                   "verbosity": -1},
+                  lgb.Dataset(x, label=(x[:, 0] > 0).astype(np.float32)),
+                  num_boost_round=1)
+    finally:
+        GBDT._finish_tree_async = finish
+    inner, ta, leaf_id = seen["args"]
+    assert not inner.valid_sets and leaf_id.shape == (n,)
+    return jax.make_jaxpr(inner._async_tail_fn())(
+        ta, leaf_id, inner.train_score[0], (), (), jnp.float32(0.1),
+        jnp.float32(0.0))
+
+
+@pytest.mark.parametrize("program", ["stream_grow", "tail"])
+@pytest.mark.parametrize("select_max", [256, 0])
+def test_hand_off_reads_no_leaf_table_by_gather(program, select_max,
+                                                monkeypatch):
+    """ISSUE 33: after the last split the stream grow program and the
+    score-update tail take per-row leaf ids / values by compares
+    against the leaf-sized table (ops/leaf_lookup.py): no gather of n
+    results out of a table of at most 256 entries, and ONE n-sized
+    scatter, the un-permute to row order.  With ``SELECT_MAX = 0`` the
+    helper falls back to the lookups this replaced, which the same
+    census must find: that is what shows it can see them."""
+    from lightgbm_tpu.ops import leaf_lookup
+    monkeypatch.setattr(leaf_lookup, "SELECT_MAX", select_max)
+    n, L = 4096, 8
+    traced = (_stream_grow_jaxpr(n, 16, 32, L) if program == "stream_grow"
+              else _tail_jaxpr(n, L))
+    gathers, scatters = _row_sized_lookups(traced, n)
+    if select_max == 0:
+        # leaf_of_pos by repeat (a scatter-add into zeros[n], a gather)
+        # and lv_row, or the tail's leaf_value[leaf_id]
+        assert len(gathers) == (2 if program == "stream_grow" else 1)
+        assert len(scatters) == (2 if program == "stream_grow" else 0)
+        return
+    assert not gathers, [str(e) for e in gathers]
+    assert len(scatters) == (1 if program == "stream_grow" else 0)
 
 
 def test_page_schedule_scales_with_num_class():
