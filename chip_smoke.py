@@ -174,11 +174,17 @@ def phase_four_chips(lgb, data, device_kind: str) -> None:
     r = mesh_bst._inner._routing
     print(f"routing digest {r.digest()}: {json.dumps(r.to_json())}",
           flush=True)
-    got = (r.learner, r.n_shards, r.path, r.reasons)
-    want = ("data", 4, "physical", ("mesh_stream_unwired",))
+    # benchmarks/configs/higgs-data4.json's expect_route
+    got = (r.learner, r.n_shards, r.path, r.hist_merge, r.reasons)
+    want = ("data", 4, "physical", "scatter", ("mesh_stream_unwired",))
     if got != want:
         raise AssertionError(f"mesh route {got}, expected {want}")
     check_no_fallback_events(events0, obs_events)
+    # what a traced run's Tree::grow span says of the mesh, for the
+    # last tree: the grow program's own per-shard row counts among it
+    emit(phase="mesh_tree", smoke=True,
+         **mesh_bst._inner.grow.tree_span_args(
+             mesh_bst._models[-1].num_leaves - 1))
     # code that has only seen one chip may put everything on device 0
     shards = mesh_bst._inner.grow._comb.addressable_shards
     rows = {str(s.device): s.data.shape[0] for s in shards}

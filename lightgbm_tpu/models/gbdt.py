@@ -1508,7 +1508,10 @@ class GBDT:
         the same whether the tracer was enabled before the booster was
         built or after: the grow program knows nothing of it.  A
         batched grow hands ``ta`` stacked [K, ...]; ``kidxs`` then
-        names the active classes."""
+        names the active classes.  A data-parallel grower adds what it
+        knows of the mesh (``tree_span_args``: shards, merges and their
+        analytical bytes, and each shard's own rows, which the grow
+        program counts traced or not)."""
         with obs_tracer.span("WorkCounters"):
             small = jax.device_get(
                 (ta.num_leaves, ta.left_child, ta.right_child,
@@ -1524,6 +1527,9 @@ class GBDT:
             for name, val in d.items():
                 obs_tracer.count(name, val, kidx=kidx)
                 total[name] = total.get(name, 0.0) + val
+        mesh_args = getattr(self.grow, "tree_span_args", None)
+        if mesh_args is not None:
+            total.update(mesh_args(total.get("splits", 0.0), len(kidxs)))
         span.set(**total)
 
     def _async_tail_fn(self):

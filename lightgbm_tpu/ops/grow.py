@@ -410,6 +410,9 @@ def make_grow_fn(
     """
     L = int(num_leaves)
     fax = feature_axis_name
+    # the data-parallel learners' grow program also returns each shard's
+    # own work count (TreeArrays.side_miss is global; this is not)
+    shard_counter = axis_name is not None and fax is None
     if numerics not in ("off", "raise", "skip", "clamp"):
         raise ValueError(
             f"numerics must be off/raise/skip/clamp, got {numerics!r}")
@@ -1230,18 +1233,20 @@ def make_grow_fn(
             # rows carry zero weight and must not count)
             tot0 = jnp.sum(root_hist[0], axis=0)   # [2]
             sg0, sh0 = tot0[0], tot0[1]
-            c0 = jnp.float32(int(stream["count"]))
+            c0 = c0_loc = jnp.float32(int(stream["count"]))
         elif physical:
             # physical gvals keeps (g*w, h*w, w) columns; w is the
             # validity/bag weight (in stream mode the inbag arg is a
             # dummy — the w column is the only count source)
             sg0 = _allreduce_sum(jnp.sum(gvals[:, 0]))
             sh0 = _allreduce_sum(jnp.sum(gvals[:, 1]))
-            c0 = _allreduce_sum(jnp.sum(gvals[:, 2]))
+            c0_loc = jnp.sum(gvals[:, 2])
+            c0 = _allreduce_sum(c0_loc)
         else:
             sg0 = _allreduce_sum(jnp.sum(gvals[:, 0]))
             sh0 = _allreduce_sum(jnp.sum(gvals[:, 1]))
-            c0 = _allreduce_sum(jnp.sum(inbag))
+            c0_loc = jnp.sum(inbag)
+            c0 = _allreduce_sum(c0_loc)
         root_out = calculate_leaf_output(sg0, sh0, hp)
         ninf32 = jnp.float32(-jnp.inf)
         pinf32 = jnp.float32(jnp.inf)
@@ -2134,6 +2139,22 @@ def make_grow_fn(
                 leaf_of_pos)
         if debug_state:
             return tree, leaf_id, state.best, state.lstate
+        if shard_counter:
+            # this shard's own counts, u32 [2]: the sum of parent rows
+            # over the tree's splits - a leaf's rows were scanned once
+            # at each of its ancestors, so the leaf's LOCAL row count
+            # (seg, never merged) times its depth, summed - and the
+            # in-bag rows it gave the root.  From the finished state
+            # and the root's local sum: no loop-carried value, no
+            # collective.  u32: n_local * (L - 1) is under 2^32 for
+            # every n_local the 3-byte row id allows.
+            dep = jnp.where(live, lstate[:, _SDEP], 0.0).astype(jnp.uint32)
+            shard_rows = jnp.stack([
+                jnp.sum(state.seg[:, 1].astype(jnp.uint32) * dep),
+                c0_loc.astype(jnp.uint32)])
+            if physical:
+                return tree, leaf_id, state.comb, state.scratch, shard_rows
+            return tree, leaf_id, shard_rows
         if streams:
             # prepare the NEXT tree in-place: every comb position's score
             # gains this tree's shrunk leaf output (positions already sit
@@ -2289,8 +2310,10 @@ class MeshPhysicalPieces(NamedTuple):
     (parallel/data_parallel.py) shard_maps ``core`` over the row axis and
     carries the [n_alloc, C] comb/scratch matrices as sharded arrays.
     ``core(comb, scratch, grad, hess, inbag, fm, num_bins, has_nan,
-    is_cat, seed, rate) -> (tree, leaf_id, comb, scratch)``; shapes are
-    PER-SHARD (n_local rows)."""
+    is_cat, seed, rate) -> (tree, leaf_id, comb, scratch, shard_rows)``;
+    shapes are PER-SHARD (n_local rows; ``shard_rows`` is u32 [2]: the
+    shard's own sum of parent rows over the tree's splits, and the
+    in-bag rows it gave the root)."""
     core: object
     n_alloc: int            # comb lines (rows + PHYS_ROW_SLACK)
     C: int                  # line width

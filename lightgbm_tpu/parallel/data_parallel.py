@@ -91,9 +91,16 @@ class DataParallelGrower:
                 cegb_coupled=grow_kwargs.get("cegb_coupled")))
         self.physical = physical_bins is not None
         self.fused = False   # set from the grow pieces in physical mode
+        self._f_pad = None   # histogram columns a merge moves
         self._comb = None
         self._scratch = None
         self._sharded_batch = None   # lazily-built batched-K scan core
+        # the last dispatch's per-shard counts: each shard's own sum of
+        # parent rows over its tree's splits and its in-bag rows,
+        # [n_shards * 2] ([K, n_shards * 2] batched), counted by the
+        # grow program traced or not and left on the devices until
+        # ``shard_work_rows()`` is asked
+        self.last_shard_rows = None
 
         row = P(DATA_AXIS)
         row2d = P(DATA_AXIS, None)
@@ -114,6 +121,7 @@ class DataParallelGrower:
                 physical_bins=local_spec, **grow_kwargs)
             self._pieces = pieces
             self.fused = pieces.fused
+            self._f_pad = int(pieces.f_pad)
             self._bins_global = physical_bins
             # EFB (ISSUE 12): the merge collectives move LOGICAL-width
             # histograms once the ingest unbundles, so the ledger
@@ -124,7 +132,7 @@ class DataParallelGrower:
                 pieces.core, mesh=self.mesh,
                 in_specs=(row2d, row2d, row, row, row, rep, rep, rep,
                           rep, rep, rep),
-                out_specs=(tree_specs, row, row2d, row2d),
+                out_specs=(tree_specs, row, row2d, row2d, row),
                 check_vma=False,
             ), donate_argnums=(0, 1))
             _init_part = functools.partial(
@@ -155,7 +163,7 @@ class DataParallelGrower:
             self._sharded_grow = jax.jit(jax.shard_map(
                 grow, mesh=self.mesh,
                 in_specs=(row2d, row, row, row, rep, rep, rep, rep, rep),
-                out_specs=(tree_specs, row),
+                out_specs=(tree_specs, row, row),
                 check_vma=False,
             ))
 
@@ -180,20 +188,20 @@ class DataParallelGrower:
                 def body(carry, xs):
                     comb_c, scr_c = carry
                     g, h, fm, sd = xs
-                    tree, lid, comb_n, scr_n = core(
+                    tree, lid, comb_n, scr_n, rows = core(
                         comb_c, scr_c, g, h, inbag, fm, num_bins,
                         has_nan, is_cat, sd, jnp.float32(0.0))
-                    return (comb_n, scr_n), (tree, lid)
+                    return (comb_n, scr_n), (tree, lid, rows)
 
-                (comb, scratch), (treeK, lidK) = jax.lax.scan(
+                (comb, scratch), (treeK, lidK, rowsK) = jax.lax.scan(
                     body, (comb, scratch), (gradK, hessK, fmK, seedK))
-                return treeK, lidK, comb, scratch
+                return treeK, lidK, comb, scratch, rowsK
 
             self._sharded_batch = jax.jit(jax.shard_map(
                 _core_k, mesh=self.mesh,
                 in_specs=(row2d, row2d, krow, krow, row, rep, rep,
                           rep, rep, rep),
-                out_specs=(tree_specs, krow, row2d, row2d),
+                out_specs=(tree_specs, krow, row2d, row2d, krow),
                 check_vma=False,
             ), donate_argnums=(0, 1))
         return self._sharded_batch
@@ -221,16 +229,14 @@ class DataParallelGrower:
             if self._comb is None:
                 self._comb = self._sharded_init(self._bins_global)
                 self._scratch = jnp.zeros_like(self._comb)
-            (treeK, leaf_idK, self._comb,
-             self._scratch) = self._batched_core()(
+            (treeK, leaf_idK, self._comb, self._scratch,
+             self.last_shard_rows) = self._batched_core()(
                 self._comb, self._scratch, gradK, hessK, inbag,
                 fmK, num_bins, has_nan, is_cat,
                 jnp.asarray(seedK, jnp.int32))
             sp.block_on(leaf_idK)
         if traced:
-            self._ledger_collective(inbag, self._pieces.f_pad,
-                                    _time.perf_counter() - t0,
-                                    trees=k)
+            self._ledger_collective(_time.perf_counter() - t0, trees=k)
         return treeK, leaf_idK
 
     def reset_stream(self) -> None:
@@ -250,37 +256,72 @@ class DataParallelGrower:
     def padded_rows(self, n: int, block: int) -> int:
         return pad_rows_to_shards(n, self.num_shards, 1)
 
-    def _ledger_collective(self, inbag, f_pad: int,
-                           wall_s: float, trees: int = 1) -> None:
-        """Per-grow collective record for the run ledger (tracing only):
-        analytical ICI bytes the per-split histogram merges moved
-        (obs/costmodel) plus the PER-SHARD in-bag row counts keyed by
-        shard id — a skewed bag makes every collective wait on the
-        fullest shard, and the per-shard series is what the mesh
-        flight recorder (ledger.mesh_summary, obs diff) roots the
-        straggler skew in.  Voting mode prices the bounded merge (the
-        elected ~2k feature slices + the vote psum) instead of the
-        full-histogram payload."""
-        import numpy as np
+    def shard_work_rows(self):
+        """The last dispatch's per-shard counts on the host, f64 [trees,
+        n_shards, 2] (rows partitioned, in-bag rows): one small
+        transfer of a finished program's output, no program of its
+        own; kept, so a second reader pays nothing.  None before the
+        first tree, and where this process cannot address every shard
+        (pre-partitioned multi-host data)."""
+        rows = self.last_shard_rows
+        if rows is None or isinstance(rows, np.ndarray):
+            return rows
+        if not rows.is_fully_addressable:
+            return None
+        rows = self.last_shard_rows = np.asarray(
+            jax.device_get(rows), np.float64).reshape(
+                -1, self.num_shards, 2)
+        return rows
 
+    def _merge_bytes(self, merges: int) -> int:
+        """Analytical per-shard ICI bytes of ``merges`` histogram
+        merges (obs/costmodel).  Voting mode prices the bounded merge
+        (the elected ~2k feature slices + the vote psum) instead of the
+        full-histogram payload."""
+        from ..obs.costmodel import learner_dispatch_bytes
+        return learner_dispatch_bytes(
+            "psum_scatter" if self.hist_scatter else "psum",
+            f_pad=self._f_pad, padded_bins=self._padded_bins,
+            n_shards=self.num_shards, num_leaves=int(merges),
+            voting_top_k=self._voting_k)
+
+    def tree_span_args(self, splits: float, trees: int = 1) -> dict:
+        """What the ``Tree::grow`` span says of the mesh, from the
+        finished tree(s) (``splits`` in all), static shapes and the
+        grow program's own per-shard counter (one 32-byte transfer
+        after the span's barrier): no device work."""
+        merges = int(splits) + int(trees)      # the splits + the roots
+        args = {"shards": self.num_shards,
+                "hist_merge": "scatter" if self.hist_scatter else "psum",
+                "merges": merges,
+                "merge_bytes": self._merge_bytes(merges)}
+        rows = self.shard_work_rows()
+        if rows is not None:
+            per_shard = rows[..., 0].sum(axis=0)
+            args["shard_rows_partitioned"] = [float(v) for v in per_shard]
+            args["shard_rows_max"] = float(per_shard.max())
+        return args
+
+    def _ledger_collective(self, wall_s: float, trees: int = 1) -> None:
+        """Per-grow collective record for the run ledger (tracing only;
+        it dispatches nothing): analytical ICI bytes the per-split
+        histogram merges moved at most (obs/costmodel, ``num_leaves``
+        merges a tree) plus the PER-SHARD in-bag row counts keyed by
+        shard id, which the grow program returns beside the tree — a
+        skewed bag makes every collective wait on the fullest shard,
+        and the per-shard series is what the mesh flight recorder
+        (ledger.mesh_summary, obs diff) roots the straggler skew in."""
         from ..obs import ledger as obs_ledger
         from ..obs import tracer as obs_tracer
-        from ..obs.costmodel import learner_dispatch_bytes
 
         n = self.num_shards
         kind = "psum_scatter" if self.hist_scatter else "psum"
-        est = learner_dispatch_bytes(
-            kind, f_pad=int(f_pad), padded_bins=self._padded_bins,
-            n_shards=n, num_leaves=self._num_leaves,
-            voting_top_k=self._voting_k)
         # batched multiclass: K trees' merges ride one dispatch
-        est *= max(int(trees), 1)
-        per_shard_rows = None
-        try:
-            per_shard_rows = [float(v) for v in np.asarray(jnp.sum(
-                jnp.reshape(inbag, (n, -1)), axis=1))]
-        except Exception:  # stream placeholders / odd shapes: skip skew
-            pass
+        est = self._merge_bytes(self._num_leaves * max(int(trees), 1))
+        rows = self.shard_work_rows()
+        # one bag serves the K trees of a batched dispatch
+        per_shard_rows = (None if rows is None
+                          else [float(v) for v in rows[0, :, 1]])
         # a ring collective moves the same per-shard bytes on every
         # shard; recorded per shard anyway so measured per-plane bytes
         # (obs collectives) join against the same shape
@@ -312,27 +353,24 @@ class DataParallelGrower:
                             else "psum"),
                 physical=self.physical) as sp:
             if not self.physical:
-                out = self._sharded_grow(bins, grad, hess, inbag,
-                                         feature_mask, num_bins, has_nan,
-                                         is_cat, jnp.int32(seed))
-                sp.block_on(out[1])
+                self._f_pad = int(bins.shape[1])
+                tree, leaf_id, self.last_shard_rows = self._sharded_grow(
+                    bins, grad, hess, inbag, feature_mask, num_bins,
+                    has_nan, is_cat, jnp.int32(seed))
+                sp.block_on(leaf_id)
             else:
                 if self._comb is None:
                     self._comb = self._sharded_init(self._bins_global)
                     self._scratch = jnp.zeros_like(self._comb)
-                (tree, leaf_id, self._comb,
-                 self._scratch) = self._sharded_core(
+                (tree, leaf_id, self._comb, self._scratch,
+                 self.last_shard_rows) = self._sharded_core(
                     self._comb, self._scratch, grad, hess, inbag,
                     feature_mask, num_bins, has_nan, is_cat,
                     jnp.int32(seed), jnp.float32(0.0))
-                out = (tree, leaf_id)
                 sp.block_on(leaf_id)
         # ledger record OUTSIDE the span: the wall must include the
         # span-exit device barrier, or the collective cost reads as the
         # async enqueue time
         if traced:
-            f_pad = (self._pieces.f_pad if self.physical
-                     else int(bins.shape[1]))
-            self._ledger_collective(inbag, f_pad,
-                                    _time.perf_counter() - t0)
-        return out
+            self._ledger_collective(_time.perf_counter() - t0)
+        return tree, leaf_id

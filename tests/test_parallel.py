@@ -207,3 +207,163 @@ def test_data_parallel_padded_fast_path(problem):
     after = obs_events.totals().get("hist_scatter_psum_fallback", 0)
     assert after == before == 0, (
         "psum fallback fired on the padded fast path")
+
+
+# ---------------------------------------------------------------------
+# the data-parallel learner against the benchmark's float64 reference
+# (benchmarks/reference_mesh.py: no shards, all rows, tree 0 leaf by
+# leaf), and what a traced mesh run says and dispatches (ISSUE 34)
+# ---------------------------------------------------------------------
+MESH_ROWS = 4096        # 8 shards x one 512-row partition block: no padding
+
+
+def _bench_reference():
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import reference
+    import reference_mesh
+    return reference, reference_mesh
+
+
+@pytest.mark.parametrize("features", [10, 124], ids=["1plane", "2planes"])
+@pytest.mark.parametrize("scatter", ["1", "0"], ids=["scatter", "psum"])
+def test_data_parallel_tree0_against_float64_reference(
+        monkeypatch, scatter, features):
+    """Tree 0 of ``tree_learner=data`` on the chip's route (per-shard
+    comb, fused scan, merged histograms) against all rows walked and
+    summed in float64: leaf counts exact, leaf values to float32
+    rounding; both merges, and a comb of one and of two 128-lane
+    planes."""
+    reference, reference_mesh = _bench_reference()
+    monkeypatch.setenv("LGBM_TPU_PHYS", "interpret")
+    monkeypatch.setenv("LGBM_TPU_HIST_SCATTER", scatter)
+    x, y = _make_binary(n=MESH_ROWS, f=features, seed=11)
+    params = dict(BASE_PARAMS, tree_learner="data")
+    ds = lgb.Dataset(x, label=y, params={"max_bin": params["max_bin"]})
+    bst = lgb.train(params, ds, num_boost_round=1)
+    r = bst._inner._routing
+    assert (r.path, r.learner, r.n_shards) == ("physical", "data", 8)
+    assert r.hist_merge == ("scatter" if scatter == "1" else "psum")
+    text = bst.model_to_string()
+    tree0 = reference.parse_model(text)[0]
+    ref = reference_mesh.binary_leaf_sums(tree0, x, y)
+    assert tree0.num_leaves == params["num_leaves"]
+    assert reference_mesh.tree0_leaf_counts(text).tolist() \
+        == ref.count.tolist()
+    assert ref.count.sum() == MESH_ROWS
+    want = ref.leaf_values(params["learning_rate"])
+    np.testing.assert_allclose(tree0.leaf_value, want, rtol=0, atol=2e-5)
+
+
+def test_shards_root_histograms_add_up_to_the_whole(problem):
+    """The share test: the shards' local root histograms, summed in
+    float64, are the reference's histogram of ALL rows; and the tree
+    the data-parallel learner grows from them is the serial learner's."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.histogram import build_histogram
+    _, reference_mesh = _bench_reference()
+    x, y, _ = problem
+    params = dict(BASE_PARAMS)
+    ds = lgb.Dataset(x, label=y, params={"max_bin": params["max_bin"]})
+    trees = {}
+    for learner in ("serial", "data"):
+        bst = lgb.train(dict(params, tree_learner=learner), ds,
+                        num_boost_round=1)
+        t = bst._models[0]
+        trees[learner] = (t.num_leaves, t.split_feature.tolist(),
+                          t.threshold_bin.tolist())
+    assert trees["data"] == trees["serial"]
+    inner = bst._inner
+    n_shards = inner.grow.num_shards
+    bins = np.asarray(inner.dd.bins)[:len(y)]
+    p = float(y.mean())
+    vals = np.stack([p - y, np.full(len(y), p * (1 - p))], 1).astype(
+        np.float32)
+    pb = int(inner.dd.padded_bins)
+    total = np.zeros((bins.shape[1], pb, 2), np.float64)
+    for rows in np.array_split(np.arange(len(y)), n_shards):
+        total += np.asarray(build_histogram(
+            jnp.asarray(bins[rows]), jnp.asarray(vals[rows]),
+            padded_bins=pb, rows_per_block=128), np.float64)
+    for f in range(x.shape[1]):
+        want = reference_mesh.root_histogram(bins[:, f], y, pb)
+        np.testing.assert_allclose(total[f], want, rtol=0, atol=1e-4)
+
+
+@pytest.fixture(scope="module")
+def traced_mesh_run():
+    """One booster on the chip's mesh route: two iterations untraced,
+    then two with the tracer on.  Returns (the tracer's events of the
+    traced two, what JAX built during them)."""
+    import os
+
+    # the library generation that is current NOW: other test files
+    # purge and re-import lightgbm_tpu mid-session, and the grower
+    # resolves its tracer at call time
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs import tracer
+    saved = {k: os.environ.get(k)
+             for k in ("LGBM_TPU_PHYS", "LGBM_TPU_HIST_SCATTER")}
+    os.environ["LGBM_TPU_PHYS"] = "interpret"
+    os.environ.pop("LGBM_TPU_HIST_SCATTER", None)
+    try:
+        x, y = _make_binary(n=MESH_ROWS, f=10, seed=11)
+        params = dict(BASE_PARAMS, tree_learner="data")
+        ds = lgb.Dataset(x, label=y, params={"max_bin": params["max_bin"]})
+        bst = lgb.Booster(params=params, train_set=ds)
+        for _ in range(2):
+            bst.update()
+        float(np.asarray(bst._inner.train_score).sum())
+        tracer.enable(None)
+        try:
+            for _ in range(2):
+                bst.update()
+            events = list(tracer.events)
+        finally:
+            tracer.disable()
+            tracer.reset()
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+    return events
+
+
+def test_traced_mesh_run_builds_no_program_of_its_own(traced_mesh_run):
+    """Turning the tracer on dispatches no extra program on the mesh
+    route either: every program the two traced iterations ran had been
+    built by the two untraced ones, so JAX traced, lowered and compiled
+    nothing (the tracer hears JAX's builds as ``jax::*`` events).  The
+    per-shard rows of the collective ledger come from the grow
+    program's own output, not from a reduction dispatched for them."""
+    built = [e["name"] for e in traced_mesh_run
+             if e["name"].startswith("jax::")]
+    assert built == []
+    ledger = [e for e in traced_mesh_run if e["name"] == "collective"]
+    assert len(ledger) == 2 and all(
+        e["args"]["skew_max"] == e["args"]["skew_min"] == MESH_ROWS / 8
+        for e in ledger)
+
+
+def test_tree_grow_span_says_what_the_mesh_did(traced_mesh_run):
+    grows = [e["args"] for e in traced_mesh_run
+             if e["name"] == "Tree::grow"]
+    assert len(grows) == 2
+    for a in grows:
+        assert a["shards"] == 8 and a["hist_merge"] == "scatter"
+        assert a["merges"] == a["splits"] + 1
+        assert a["merge_bytes"] > 0
+        assert len(a["shard_rows_partitioned"]) == 8
+        # no padding rows at this size, so the shards' own counts are
+        # the tree's row visits, split eight ways: exactly, where
+        # rows_partitioned is made of the tree's internal_count, the
+        # reference's hessian-derived estimate (off by under 1% here)
+        assert sum(a["shard_rows_partitioned"]) == pytest.approx(
+            a["rows_partitioned"], rel=0.02)
+        assert a["shard_rows_max"] == max(a["shard_rows_partitioned"])
