@@ -1500,7 +1500,7 @@ class GBDT:
         """The work counters (obs/counters.py) of the tree(s) just
         grown, derived on the host from the tree itself: one pull of
         its small arrays (a few KB; a transfer, not a program; with
-        them the two numbers the grow program counts for its fused
+        them the four numbers the grow program counts for its fused
         scan, ``ta.side_miss``) after the ``Tree::grow`` barrier,
         recorded as before
         (``obs_counters.record`` + ``tracer.count``) and set as args of

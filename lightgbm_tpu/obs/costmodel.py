@@ -25,11 +25,15 @@ Byte contracts (physical comb layout, ``ops/pallas/layout.py``):
   lives in VMEM);
 * the fused split kernel pays the partition traffic plus ONE child's
   histogram write (ISSUE 30: the child the finder's record calls
-  smaller); only at a split whose record named the larger child
-  (``side_miss_splits`` of them) does a comb-direct build of the
-  smaller one follow (``rows_rehistogrammed`` rows in all) - so the
+  smaller; zeros where its hook was skipped).  A comb-direct build of
+  the smaller child follows at a split whose record named the larger
+  child (``side_miss_splits`` of them, ``rows_rehistogrammed`` rows in
+  all) and at every split whose parent is past the hook's crossover
+  (ISSUE 35: ``splits - hook_splits`` of them; their children's rows
+  are not counted apart and are taken as the smaller children's rows
+  in the share of the row visits that were not hooked) - so the
   smaller-child re-read the unfused pipeline pays at every split is
-  what fusion deletes, up to the misses;
+  what the hook deletes, where it runs and up to the misses;
 * a stream refresh pass reads and rewrites every comb line once
   (plus one root-histogram write when the fused root carry is on).
 
@@ -475,6 +479,8 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     rows_rehist = int(counters.get("rows_rehistogrammed", 0))
     misses = int(counters.get("side_miss_splits", 0))
     lrb = logical_row_bytes()
+    root_rows = n_rows * trees
+    direct, rows_direct = _direct_builds(counters, root_rows)
 
     def _part_row(cnt: int) -> Dict[str, float]:
         # scan touches every partitioned row twice; copyback adds 0..2
@@ -487,14 +493,14 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
         }
 
     out: Dict[str, Dict[str, float]] = {}
-    root_rows = n_rows * trees
     # whole-loop totals from the work counters — joined with the
     # Tree::grow wall, which is the span that covers every split.
     # Histogram traffic mirrors the per-split contracts above: fused
     # writes ONE child's histogram per split from the scan's
     # VMEM-resident blocks, and re-reads and writes again only the
     # smaller children of the side_miss_splits whose record named the
-    # larger one (rows_rehistogrammed; root passes stay); unfused
+    # larger one (rows_rehistogrammed) and of the splits past the
+    # hook's crossover (_direct_builds; root passes stay); unfused
     # re-reads the smaller child (rows_hist already counts it) and
     # writes ONE histogram per split (the sibling comes from the
     # subtraction, in registers) plus one per tree root.
@@ -503,9 +509,9 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     grow = _part_row(rows_part)
     # fused root passes cover at most the in-bag rows per tree
     # (bagging makes them fewer; rows_hist is the honest ceiling)
-    hist_reads = (min(root_rows, rows_hist) + rows_rehist if fused
-                  else rows_hist) * lrb
-    hist_writes = (trees + splits + (misses if fused else 0)) \
+    hist_reads = (min(root_rows, rows_hist) + rows_rehist + rows_direct
+                  if fused else rows_hist) * lrb
+    hist_writes = (trees + splits + (misses + direct if fused else 0)) \
         * hist_out_bytes(f_pad, padded_bins)
     for key in ("bytes", "bytes_lo", "bytes_hi"):
         grow[key] += hist_reads + hist_writes
@@ -522,6 +528,22 @@ def phase_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     return out
 
 
+def _direct_builds(counters: Dict[str, Any], root_rows: int):
+    """(splits, rows) of the comb-direct builds at the parents past the
+    fused scan's hook crossover.  The splits are counted
+    (``hook_splits``; a record from before the counter hooked them
+    all); their children's rows are not counted apart: the smaller
+    children's rows of the whole tree (``rows_histogrammed`` less the
+    root passes) in the share of the row visits that were not hooked."""
+    splits = int(counters.get("splits", 0))
+    rows_part = int(counters.get("rows_partitioned", 0))
+    rows_hist = int(counters.get("rows_histogrammed", 0))
+    direct = splits - int(counters.get("hook_splits", splits))
+    unhooked = rows_part - int(counters.get("rows_hooked", rows_part))
+    child_rows = rows_hist - min(root_rows, rows_hist)
+    return direct, (child_rows * unhooked // rows_part if rows_part else 0)
+
+
 def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     """Predicted HBM bytes per KERNEL CLASS (the ``obs attr``
     classifier's entries, ``xattr.KERNEL_CLASSES``) for a traced
@@ -533,7 +555,8 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     Attribution follows the engaged path: with ``fused`` on, the scan,
     copyback and one child's histogram write execute inside the fused
     kernel, the builds of the ``side_miss_splits`` (their writes, the
-    ``rows_rehistogrammed`` reads) land on ``hist_build`` with the
+    ``rows_rehistogrammed`` reads) and of the splits past the hook's
+    crossover (``_direct_builds``) land on ``hist_build`` with the
     root passes (which ride ``stream_refresh`` instead when the fused
     root carry is on); unfused splits split the same traffic
     across partition_scan / partition_copyback / hist_build.  Copyback
@@ -563,6 +586,7 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
     lrb = logical_row_bytes()
     hw = hist_out_bytes(f_pad, padded_bins)
     root_rows = n_rows * trees
+    direct, rows_direct = _direct_builds(counters, root_rows)
 
     def _exact(b: float) -> Dict[str, float]:
         return {"bytes": float(b), "bytes_lo": float(b),
@@ -576,10 +600,10 @@ def kernel_model(rec: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
             "bytes_hi": 4.0 * rows_part * lrb + splits * hw,
             "bytes": 3.0 * rows_part * lrb + splits * hw,
         }
-        # the builds of the missed splits; the fused root carry
-        # builds root histograms inside the refresh pass, else they
-        # are hist_build's too
-        rehist = rows_rehist * lrb + misses * hw
+        # the builds of the missed splits and of those past the
+        # hook's crossover; the fused root carry builds root histograms
+        # inside the refresh pass, else they are hist_build's too
+        rehist = (rows_rehist + rows_direct) * lrb + (misses + direct) * hw
         out["hist_build"] = _exact(
             rehist if stream
             else rehist + min(root_rows, rows_hist) * lrb + trees * hw)

@@ -5,11 +5,11 @@ while tracing the booster derives them on the host
 (``counters_from_tree``) from one pull of the tree's small arrays after
 the ``Tree::grow`` barrier — no second grow program, no extra
 dispatch, and the same whether the tracer was enabled before the
-booster was built or after.  Two more are not (which child the fused
-scan was told to histogram is forgotten once the split is done): the
-grow program counts them in its state, always, traced or not, and
-they ride the same pull as ``TreeArrays.side_miss``.  Counter
-semantics:
+booster was built or after.  Four more are not (whether the fused
+scan's histogram hook ran at a split, and which child it was told, is
+forgotten once the split is done): the grow program counts them in its
+state, always, traced or not, and they ride the same pull as
+``TreeArrays.side_miss``.  Counter semantics:
 
   splits            — splits taken (== num_leaves - 1 of the tree)
   rows_partitioned  — in-bag rows moved by the physical/logical
@@ -24,8 +24,9 @@ semantics:
                       Pallas kernel (LGBM_TPU_FUSED path): ``splits``
                       on that route on a TPU, 0 on the unfused /
                       non-physical / interpreted paths
-  side_miss_splits  — fused physical route: splits at which the child
-                      the finder's record called smaller (its
+  side_miss_splits  — fused physical route: splits at which the
+                      scan's histogram hook ran and the child the
+                      finder's record called smaller (its
                       hessian-derived left count, ops/grow.py
                       ``pred_left``) was not the smaller one by the
                       exact counts, so the scan's one-sided histogram
@@ -33,12 +34,26 @@ semantics:
                       histogrammed again from the comb.  Counted off
                       the chip too, where the reference path takes
                       the comb-direct histogram at every split; 0 on
-                      the other routes
+                      the other routes; never more than
+                      ``hook_splits``
   rows_rehistogrammed — the rows of those smaller children (global
                       under the mesh learners): over
                       ``rows_partitioned`` it is the share of the
                       scan's row visits the estimate cost a second
                       read for (benchmarks: ``scan_side_miss``)
+  hook_splits       — fused physical route: splits at which the hook
+                      ran, i.e. whose parent held no more rows (a
+                      shard, under the mesh learners) than
+                      ``fused_split.hook_crossover_rows`` says the
+                      hook is worth; at the others the scan was told
+                      no child and the smaller one was histogrammed
+                      from the comb.  Counted off the chip too; 0 on
+                      the other routes
+  rows_hooked       — the parent rows of those splits (global under
+                      the mesh learners): over ``rows_partitioned``
+                      it is the share of the scan's row visits that
+                      went through the hook (benchmarks:
+                      ``scan_rows_hooked``)
 
 Plus HBM watermark sampling: ``hbm_live_bytes`` is the cheap
 ``jax.live_arrays`` census of live device buffers (catches leaks and
@@ -67,7 +82,8 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 COUNTER_NAMES = ("splits", "rows_partitioned", "rows_histogrammed",
-                 "fused_splits", "side_miss_splits", "rows_rehistogrammed")
+                 "fused_splits", "side_miss_splits", "rows_rehistogrammed",
+                 "hook_splits", "rows_hooked")
 
 
 def counters_to_dict(vec) -> Dict[str, float]:
@@ -77,16 +93,17 @@ def counters_to_dict(vec) -> Dict[str, float]:
 
 
 def counters_from_tree(num_leaves, left_child, right_child,
-                       internal_count, leaf_count, side_miss=(0, 0), *,
+                       internal_count, leaf_count,
+                       side_miss=(0, 0, 0, 0), *,
                        fused: bool) -> np.ndarray:
     """The counter vector (``COUNTER_NAMES`` order) of one finished
-    tree, from its host arrays; ``side_miss`` is the pair the grow
+    tree, from its host arrays; ``side_miss`` is the four the grow
     program counted (``TreeArrays.side_miss``).  Counts are integral
     f32 below 2^24 each; sums run in float64, exact far beyond the
     ~n*log2(L) a tree can reach (84M at Higgs 10.5M)."""
     splits = int(num_leaves) - 1
     leaf_c = np.asarray(leaf_count, np.float64)
-    miss = [float(v) for v in np.asarray(side_miss).reshape(2)]
+    miss = [float(v) for v in np.asarray(side_miss).reshape(4)]
     if splits <= 0:
         # a stump: the root pass is all the work there was
         return np.array([0.0, 0.0, leaf_c[0], 0.0] + miss)

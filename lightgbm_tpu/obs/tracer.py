@@ -68,7 +68,7 @@ JAX reports the fetch under this name too) and ``jax::cache_load``
 a load or a retrace says so, inside the span it happened in.
 
 Work counters are derived on the host from the finished tree
-(``obs/counters.counters_from_tree``; two of them are counted by the
+(``obs/counters.counters_from_tree``; four of them are counted by the
 grow program, traced or not, and come with the tree) after the
 ``Tree::grow`` barrier, and set as args of that span.
 

@@ -71,11 +71,13 @@ class TreeArrays(NamedTuple):
     # when the sorted-subset search is off (one-hot sets are then implied
     # by threshold_bin).
     cat_members: jnp.ndarray
-    # i32 [2], telemetry of the fused scan's one-sided histogram hook
+    # i32 [4], telemetry of the fused scan's one-sided histogram hook
     # (obs/counters.py): splits whose predicted smaller child was not
-    # the smaller one, and the rows re-histogrammed for them.  Global
-    # under the mesh learners; zeros off the fused physical route.  Not
-    # part of the model: models/tree.py does not read it.
+    # the smaller one and the rows re-histogrammed for them; splits at
+    # which the hook ran at all (parents under the crossover) and the
+    # parent rows it visited.  Global under the mesh learners; zeros
+    # off the fused physical route.  Not part of the model:
+    # models/tree.py does not read it.
     side_miss: jnp.ndarray
 
 
@@ -126,7 +128,7 @@ class _GrowState(NamedTuple):
     paid: jnp.ndarray            # CEGB lazy paid-rows mask [F, n] bool
                                  # ([1, 1] when off); persists ACROSS
                                  # trees via the grow return value
-    side_miss: jnp.ndarray       # i32 [2]: TreeArrays.side_miss so far
+    side_miss: jnp.ndarray       # i32 [4]: TreeArrays.side_miss so far
 
 
 # _GrowState.best column indices
@@ -221,7 +223,7 @@ def _empty_tree(num_leaves: int, cat_b: int = 0) -> TreeArrays:
         num_leaves=jnp.int32(1),
         cat_members=jnp.zeros((ni, cat_b) if cat_b else (1, 1),
                               jnp.float32),
-        side_miss=zi(2),
+        side_miss=zi(4),
     )
 
 
@@ -652,15 +654,24 @@ def make_grow_fn(
                     f"page_schedule over the engaged stream mode")
         _phys_interp = jax.default_backend() != "tpu"
         # fused partition+histogram split kernel (fused_split.py): one
-        # dynamic-grid scan per split compacts the parent AND
-        # accumulates one child's histogram from the VMEM-resident row
-        # blocks, the child the finder's record calls smaller — the
-        # separate child-histogram kernel (and its HBM re-read of the
-        # rows the scan just streamed) has work only where the record
-        # named the larger one.
-        from .pallas.fused_split import fused_supported
+        # dynamic-grid scan per split compacts the parent AND, at the
+        # parents of up to _hook_cross rows a shard, accumulates one
+        # child's histogram from the VMEM-resident row blocks, the
+        # child the finder's record calls smaller.  The separate
+        # child-histogram kernel (and its HBM re-read of the smaller
+        # child's rows) has the work at the larger parents, where
+        # reading the child again is cheaper than masking and
+        # contracting every row of the parent, and where the record
+        # named the larger child.
+        from .pallas import fused_split as _fs
+        from .pallas.hist_kernel2 import hist_geometry
+        from .pallas.partition_kernel import (SIDE_LEFT, SIDE_NONE,
+                                              SIDE_RIGHT)
         _use_fused = (FUSED_IMPL != "0"
-                      and fused_supported(f_pad_p, int(padded_bins)))
+                      and _fs.fused_supported(f_pad_p, int(padded_bins)))
+        # looked up on the module at build time (tests patch it)
+        _hook_cross = _fs.hook_crossover_rows(
+            f_pad_p // hist_geometry(int(padded_bins))[1])
         if _phys_interp:
             # off-TPU reference path keeps the static bucket switch (the
             # XLA emulation needs static slice sizes)
@@ -679,11 +690,11 @@ def make_grow_fn(
             # 1M; it was the dominant per-split fixed cost)
             _phys_sizes = [n_rows_p]
             if _use_fused:
-                from .pallas.fused_split import make_fused_split
-                _fused_dyn = make_fused_split(
+                _fused_dyn = _fs.make_fused_split(
                     _n_alloc, _C_PHYS, f_pad=f_pad_p,
                     padded_bins=int(padded_bins), R=_PHYS_R,
-                    dtype=_COMB_DT, dynamic=True, scan=PARTITION_IMPL)
+                    dtype=_COMB_DT, dynamic=True, scan=PARTITION_IMPL,
+                    raw_hist=True)
             else:
                 _part_dyn = make_partition(_n_alloc, _C_PHYS, R=_PHYS_R,
                                            dtype=_COMB_DT, dynamic=True)
@@ -1332,7 +1343,7 @@ def make_grow_fn(
                    if use_mono_inter else jnp.zeros((1, 1), jnp.float32)),
             paid=(paid_in if use_cegb_lazy
                   else jnp.zeros((1, 1), jnp.bool_)),
-            side_miss=jnp.zeros((2,), jnp.int32),
+            side_miss=jnp.zeros((4,), jnp.int32),
         )
 
         def body(i, st: _GrowState) -> _GrowState:
@@ -1447,18 +1458,36 @@ def make_grow_fn(
             # pass, serial_tree_learner.cpp:287-327).
             s0 = st.seg[leaf, 0]
             par_cnt = st.seg[leaf, 1]
-            par_sel = (jax.lax.pmax(par_cnt, axis_name)
-                       if axis_name is not None else par_cnt)
+            if axis_name is not None:
+                par_sel = jax.lax.pmax(par_cnt, axis_name)
+                # the parent's GLOBAL rows (the reference's global leaf
+                # counts, data_parallel_tree_learner.cpp:270)
+                par_g = jax.lax.psum(par_cnt, axis_name)
+            else:
+                par_sel = par_g = par_cnt
             # the child the finder's record says is smaller (its left
             # count is the reference's hessian-derived estimate,
             # split.derived_counts; global under the mesh learners, so
             # every shard names the same side without a psum): the
-            # fused scan histograms that one, and a split where the
-            # exact counts say otherwise re-histograms below
+            # fused scan's hook histograms that one, and a split where
+            # the exact counts say otherwise re-histograms below
             lc_rec = brow[_BLC]
             if n_forced:
                 lc_rec = jnp.where(use_forced, f_lc, lc_rec)
             pred_left = lc_rec * 2 <= lrow[_SC]
+            # past the crossover the hook is the dearer way to the
+            # smaller child's histogram (fused_split.hook_crossover_rows)
+            # and the scan is told no child.  The parent's rows a shard:
+            # exact on one device; under the mesh learners the leaf
+            # record's global count (like lc_rec an estimate below the
+            # root) over the shard count, which every shard holds - so
+            # all take the same branch below, the scan waits for no
+            # collective and the two count psums stay one all-reduce
+            direct = jnp.bool_(False)
+            if physical and _use_fused:
+                direct = (par_cnt if axis_name is None else
+                          lrow[_SC] / jax.lax.axis_size(axis_name)
+                          ) > _hook_cross
 
             def make_bucket(size):
                 def fn(_):
@@ -1564,11 +1593,8 @@ def make_grow_fn(
                         st.row_order, seg_new, (start,))
                     # smaller child by GLOBAL physical counts so every
                     # shard histograms the same side
-                    if axis_name is not None:
-                        nl_g = jax.lax.psum(nleft_, axis_name)
-                        par_g = jax.lax.psum(par_cnt, axis_name)
-                    else:
-                        nl_g, par_g = nleft_, par_cnt
+                    nl_g = (jax.lax.psum(nleft_, axis_name)
+                            if axis_name is not None else nleft_)
                     small_left_ = nl_g * 2 <= par_g
                     child_m = jnp.where(small_left_, left_m, right_m)
                     vals = v_part * child_m[:, None].astype(jnp.float32)
@@ -1613,12 +1639,9 @@ def make_grow_fn(
                             [sel, _members_to_words(member_f[None])[0]])
                     combp, scrp, nleft_ = part_fn(sel, st.comb,
                                                   st.scratch)
-                    if axis_name is not None:
-                        nlg_ = jax.lax.psum(nleft_, axis_name)
-                        parg_ = jax.lax.psum(par_cnt, axis_name)
-                    else:
-                        nlg_, parg_ = nleft_, par_cnt
-                    small_left_ = nlg_ * 2 <= parg_
+                    nlg_ = (jax.lax.psum(nleft_, axis_name)
+                            if axis_name is not None else nleft_)
+                    small_left_ = nlg_ * 2 <= par_g
                     child_cnt = jnp.where(small_left_, nleft_,
                                           par_cnt - nleft_)
                     child_start = jnp.where(small_left_, s0, s0 + nleft_)
@@ -1653,7 +1676,7 @@ def make_grow_fn(
                     return (st.row_order, combp, scrp,
                             nleft_, small_left_, h, st.paid,
                             jnp.zeros((1, 2), jnp.float32),
-                            jnp.minimum(nlg_, parg_ - nlg_))
+                            jnp.minimum(nlg_, par_g - nlg_))
                 return fn
 
             if physical and not _phys_interp:
@@ -1669,7 +1692,9 @@ def make_grow_fn(
                 sel = jnp.stack([
                     s0, cnt_eff, feat, sbin, dl.astype(jnp.int32),
                     cat.astype(jnp.int32), nanb_sel,
-                    pred_left.astype(jnp.int32)]).astype(jnp.int32)
+                    jnp.where(direct, SIDE_NONE, jnp.where(
+                        pred_left, SIDE_LEFT, SIDE_RIGHT))
+                    ]).astype(jnp.int32)
                 if hp.use_cat_subset:
                     # membership bitset rides the descriptor (see the
                     # bucket path above); sel stays i32[8] with the
@@ -1678,36 +1703,36 @@ def make_grow_fn(
                         [sel, _members_to_words(member_f[None])[0]])
                 nb_part = jnp.maximum(-(-cnt_eff // _PHYS_R), 1)
                 if _use_fused:
-                    # ONE kernel: compaction scan + the histogram of
-                    # the child sel[SEL_SIDE] names, from the
-                    # VMEM-resident blocks
-                    comb_n, scratch_n, nleft, h_side = _fused_dyn(
+                    # ONE kernel: compaction scan + (under the
+                    # crossover) the histogram of the child
+                    # sel[SEL_SIDE] names, from the VMEM-resident
+                    # blocks, as the kernel's raw accumulator
+                    comb_n, scratch_n, nleft, acc_side = _fused_dyn(
                         sel, st.comb, st.scratch, nb_part)
                 else:
                     comb_n, scratch_n, nleft = _part_dyn(
                         sel, st.comb, st.scratch, nb_part)
                 # smaller child by GLOBAL counts so every shard
-                # histograms the same side (the reference's global leaf
-                # counts, data_parallel_tree_learner.cpp:270)
-                if axis_name is not None:
-                    nl_g = jax.lax.psum(nleft, axis_name)
-                    par_g = jax.lax.psum(par_cnt, axis_name)
-                else:
-                    nl_g, par_g = nleft, par_cnt
+                # histograms the same side
+                nl_g = (jax.lax.psum(nleft, axis_name)
+                        if axis_name is not None else nleft)
                 small_is_left = nl_g * 2 <= par_g
                 child_cnt = jnp.where(small_is_left, nleft,
                                       par_cnt - nleft)
                 child_start = jnp.where(small_is_left, s0, s0 + nleft)
-                # the exactly smaller child is histogrammed directly,
-                # the sibling is parent minus child: unfused at every
-                # split, fused only where the scan was told the other
-                # side.  The cond's branches only READ the comb (one
-                # that handed it on would put 5.4e9 bytes at 10.5M
-                # rows at a branch boundary, like the static-bucket
-                # switch above); a kernel gated by a row count of 0
-                # instead cost 28 ms a tree at 144 columns in launches
-                # and in extracting histograms nobody read (PERF.md,
-                # PR 30)
+                # the exactly smaller child is histogrammed directly
+                # from its contiguous rows, the sibling is parent minus
+                # child: unfused at every split; fused at the parents
+                # past the crossover, where the scan ran without its
+                # hook, and where the hook was told the other side.
+                # Else the hook's histogram is taken out of the scan's
+                # accumulator, in the branch that reads it.  The
+                # cond's branches only READ the comb (one that handed
+                # it on would put 5.4e9 bytes at 10.5M rows at a
+                # branch boundary, like the static-bucket switch
+                # above); a kernel gated by a row count of 0 instead
+                # cost 28 ms a tree at 144 columns in launches and in
+                # extracting histograms nobody read (PERF.md, PR 30)
                 from .pallas.hist_kernel2 import build_histogram_comb_dyn
 
                 def _child_hist(comb_c, cnt_c):
@@ -1719,9 +1744,11 @@ def make_grow_fn(
 
                 if _use_fused:
                     h_small = jax.lax.cond(
-                        (pred_left != small_is_left) & ~done,
+                        (direct | (pred_left != small_is_left)) & ~done,
                         lambda comb_c, _: _child_hist(comb_c, child_cnt),
-                        lambda _, h: h, comb_n, h_side)
+                        lambda _, acc: _fs.hook_histogram(
+                            acc, f, int(padded_bins)),
+                        comb_n, acc_side)
                 else:
                     h_small = _child_hist(
                         comb_n, jnp.where(done, 0, child_cnt))
@@ -1743,13 +1770,16 @@ def make_grow_fn(
                  h_small, paid_n, u2, small_g) = out
             side_miss = st.side_miss
             if physical and _use_fused:
-                # counted here, from the record and the exact counts,
-                # so that the off-chip reference reads what the chip
-                # does (obs/counters.py: side_miss_splits,
-                # rows_rehistogrammed)
-                side_miss += jnp.where(
-                    (pred_left != small_is_left) & ~done,
-                    jnp.stack([jnp.int32(1), small_g]), 0)
+                # counted here, from the parent's rows, the record and
+                # the exact counts, so that the off-chip reference
+                # reads what the chip does (obs/counters.py:
+                # side_miss_splits, rows_rehistogrammed, hook_splits,
+                # rows_hooked).  A miss needs a hook that ran
+                hooked = ~direct & ~done
+                miss = hooked & (pred_left != small_is_left)
+                side_miss += jnp.stack([
+                    miss.astype(jnp.int32), jnp.where(miss, small_g, 0),
+                    hooked.astype(jnp.int32), jnp.where(hooked, par_g, 0)])
             h_small = expand(h_small)   # EFB physical -> logical
             rows_parent = par_cnt
 

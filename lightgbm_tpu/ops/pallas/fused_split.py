@@ -12,53 +12,65 @@ block schedule, same overlapping garbage-tail writes, same copyback
 sub-call, with the per-block packing selected through _scan_kernel's
 ``pack_impl`` hook (permute butterfly routing by default, the one-hot
 matmul under LGBM_TPU_PARTITION=matmul; bit-identical packed layouts
-either way) — and additionally accumulates ONE child's 2-channel
-(grad, hess) histogram in VMEM from the row block already resident for
-the compaction:
+either way) — and, WHERE THE CALLER ASKS FOR IT, additionally
+accumulates ONE child's 2-channel (grad, hess) histogram in VMEM from
+the row block already resident for the compaction (the hook):
 
-  * ``sel[SEL_SIDE]`` names the child (> 0 the left one, else the
-    right): a traced scalar in SMEM, so one compiled kernel serves
-    both;
+  * ``sel[SEL_SIDE]`` names the child (``SIDE_LEFT`` / ``SIDE_RIGHT``)
+    or none (``SIDE_NONE``): a traced scalar in SMEM, so one compiled
+    kernel serves all three.  With no child the hook's body sits
+    behind a ``pl.when`` and costs a scalar branch a block; the
+    accumulator is still zeroed at block 0, so the call returns zeros;
   * the block's values are masked with that child's go-left /
     go-right bits as the permute compaction hands them over
-    (row-oriented and lane-replicated, from its one MXU transpose);
-    under the matmul compaction, whose bits are lane-oriented, the
-    split column is extracted a second time in ROW orientation ([R, 1]
-    matvec) and the bits recomputed;
+    (row-oriented and lane-replicated, from its one MXU transpose - a
+    by-product of the contraction that carries the routing word, so it
+    is not gated); under the matmul compaction, whose bits are
+    lane-oriented, the split column is extracted a second time in ROW
+    orientation ([R, 1] matvec) and the bits recomputed, inside the
+    hook;
   * hist_kernel2._hist_accumulate — the comb-direct kernel's own
     nibble one-hot contraction, the one accumulate body there is —
     adds them into one [ngroups, M, N] VMEM block (constant index map
     -> resident across the dynamic grid);
-  * the wrapper extracts the same-feature diagonal blocks
-    (hist_kernel2._diag_extract) and returns that child's histogram.
+  * ``hook_histogram`` extracts the same-feature diagonal blocks
+    (hist_kernel2._diag_extract): the wrapper calls it, or with
+    ``raw_hist=True`` the caller does, in the branch that reads it.
 
-Which child: the caller wants the SMALLER one (the sibling is parent
-minus child), and that is known exactly only when the scan finishes
-(under the mesh learners after a psum over shards).  But the finder's
-best-split record holds the left child's count before the scan is
-dispatched (ops/split.py ``derived_counts``: the reference's estimate
-from the hessian sums), so ops/grow.py names the side that record says
-is smaller, and on a split where the estimate named the wrong one - a
-split close to even - it histograms the exactly smaller child with the
-comb-direct kernel over the child's now contiguous rows, as the unfused
-path does at every split.  Accumulating BOTH children and throwing one
-away is not free on the v5e: there is no DMA shadow for it to ride
-under.  A 512-row step moves ~1.5 KB a row, 0.94 us at 819 GB/s, and
-takes 5.0 us (9.8 ns a row visit at 10.5M rows; 5.9 us with both
-sides): the scan is bound by what it computes in VMEM, and a side's
-contraction is ~1.2k of a step's VLIW bundles (PERF.md, Findings, PR 28
-and PR 30).
+Which child, and whether any: the caller wants the SMALLER one (the
+sibling is parent minus child).  The hook masks and contracts EVERY row
+of the parent to get the histogram of a child that holds at most half
+of them, where the comb-direct kernel reads only the child's now
+contiguous rows, at a fixed cost of one more kernel launch: past a
+parent size that depends on the histogram's geometry the second way is
+the cheaper one (``hook_crossover_rows``: measured on the chip at the
+two widths the benchmark runs; PERF.md, Findings, PR 35).  So
+ops/grow.py names no child at parents past that size and histograms
+the exactly smaller child from the comb; below it names the side that
+the finder's best-split record says is smaller (ops/split.py
+``derived_counts``: the reference's estimate from the hessian sums,
+known before the scan is dispatched, where the exact counts are known
+only when it finishes - under the mesh learners after a psum), and on
+a split where the estimate named the wrong one - a split close to
+even - it takes the comb-direct histogram too.  Accumulating BOTH
+children and throwing one away is not free on the v5e: there is no DMA
+shadow for it to ride under.  A 512-row step moves ~1.5 KB a row, 0.94
+us at 819 GB/s, and takes 5.0 us with the hook (9.8 ns a row visit;
+5.9 us with both sides): the scan is bound by what it computes in
+VMEM, and a side's contraction is ~1.2k of a step's VLIW bundles
+(PERF.md, Findings, PR 28 and PR 30).
 
 Layout/contract: identical to partition_kernel2.make_partition_ss, plus
 ``f_pad`` value/bin column conventions from hist_kernel2's comb-direct
 kernel (bins at cols [0, f_pad), (g*w, h*w) at [f_pad, f_pad+2)).
-Trained trees must stay bit-identical to the unfused path: the
-accumulation visits the child's rows in the same ascending block order
-the comb-direct kernel does, masked instead of sliced.  The interpret
-builder COMPOSES the reference implementations (the XLA reference
-partition + comb-direct histogram of the named child's range) so
-off-TPU tests exercise the fused orchestration with exactly the
-unfused arithmetic.
+On the CPU trained trees are bit-identical to the unfused path: the
+interpret builder COMPOSES the reference implementations (the XLA
+reference partition + comb-direct histogram of the named child's
+range) so off-TPU tests exercise the fused orchestration with exactly
+the unfused arithmetic.  On the chip the hook sums a child's rows in
+the parent's 512-row blocks and the comb-direct kernel in the child's
+own 2048-row ones: same bf16 operands, same f32 accumulation, other
+groupings, so last bits can differ between the two.
 """
 from __future__ import annotations
 
@@ -72,7 +84,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .hist_kernel2 import _LO_N, _diag_extract, _hist_accumulate, \
     build_histogram_comb, hist_geometry
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, SEL_SIDE, \
-    _go_left, make_reference_partition
+    SIDE_LEFT, SIDE_NONE, _go_left, make_reference_partition
 from .partition_kernel2 import _scan_kernel, copyback_call
 
 _CHANNELS = 2       # (grad, hess) — the 2-channel histogram layout
@@ -99,10 +111,57 @@ def fused_supported(f_pad: int, b: int) -> bool:
     return 2 * ngroups * m * nn * 4 <= _HIST_VMEM_CAP
 
 
+# Per-split cost of the two ways to the smaller child's histogram, as
+# tools/profile_fused.py reads them on the v5e at an even split (the
+# child is half the parent: the comb-direct way's worst case), fitted
+# as fixed + rows x slope over leaves of 1k to 4M rows (PERF.md,
+# Findings, PR 35: the table and the chip call it came from).
+# ngroups -> (us a split, ns a parent row) by which the comb-direct
+# way's fixed cost exceeds the hook's, and the hook's row cost the
+# comb-direct way's.
+_CROSSOVER_MEASURED = {
+    4: (10.57, 2.815),      # 32 columns, one plane (``higgs``): 3,755 rows
+    18: (39.20, 9.135),     # 144 columns, two planes (``msltr``): 4,291
+}
+HOOK_ALWAYS = (1 << 31) - 1     # no i32 row count is past it
+
+
+def hook_crossover_rows(ngroups: int) -> int:
+    """The parent row count (a shard, under the mesh learners) PAST
+    which the scan's histogram hook is the dearer way to the smaller
+    child's histogram, for a histogram of ``ngroups`` feature groups;
+    ``HOOK_ALWAYS`` where the hook wins at every size.
+
+    One algorithm, another parameter at another width: the hook's cost
+    is fixed + parent rows x its contraction, the comb-direct kernel's
+    one more launch (and its own extraction) + child rows x the same
+    contraction in larger blocks, and both grow with ``ngroups``.  The
+    gaps are measured at two geometries and taken linear in ``ngroups``
+    between and beyond them (the comb's planes ride on ``ngroups`` in
+    the two measurements - 1 at 4 groups, 2 at 18 - and are not
+    separated)."""
+    (g0, (f0, r0)), (g1, (f1, r1)) = sorted(_CROSSOVER_MEASURED.items())
+    t = (ngroups - g0) / (g1 - g0)
+    fixed_us = f0 + t * (f1 - f0)
+    row_ns = r0 + t * (r1 - r0)
+    if row_ns <= 0.0:
+        return HOOK_ALWAYS
+    return int(min(max(fixed_us, 0.0) * 1e3 / row_ns, HOOK_ALWAYS))
+
+
+def hook_histogram(acc, f_pad: int, padded_bins: int):
+    """The [f_pad, padded_bins, 2] histogram out of the scan's
+    [ngroups, M, N] accumulator (50.6 us a split at 18 groups: called
+    where the histogram is read, not at every split)."""
+    b_hi, g, _, _ = hist_geometry(padded_bins, _CHANNELS)
+    return _diag_extract(acc, f_pad // g, g, b_hi, _CHANNELS, _LO_N,
+                         f_pad, padded_bins)
+
+
 def _side_flag(sel_ref, go_left, go_right):
     """The f32 0/1 row mask of the child ``sel[SEL_SIDE]`` names, from
     the two sides' masks (same shape, f32)."""
-    return jnp.where(sel_ref[SEL_SIDE] > 0, go_left, go_right)
+    return jnp.where(sel_ref[SEL_SIDE] == SIDE_LEFT, go_left, go_right)
 
 
 def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
@@ -122,34 +181,38 @@ def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
 
     def _hist_block(x, blk, cnt, side):
         # ---- one child's histogram accumulation (the fusion) ----
-        # Mosaic has no direct bf16 -> i32 cast; hop through f32
-        bins_i = x[:, :f_pad].astype(jnp.float32).astype(jnp.int32)
-        v = x[:, f_pad:f_pad + _CHANNELS].astype(jnp.float32)
-        if side is not None:
-            # the compaction's own go-left / go-right bits, already
-            # row-oriented and lane-replicated: take the value lanes
-            flag_l, flag_r = side
-            glf = flag_l[:, f_pad:f_pad + _CHANNELS]
-            grf = flag_r[:, f_pad:f_pad + _CHANNELS]
-        else:
-            # the matmul compaction's bits are lane-oriented ([1, R]);
-            # a [1, R] -> [R, 1] relayout is a Mosaic transpose, a
-            # second exact matvec against the same one-hot column is
-            # ~R*C MACs, noise next to its [R, R] contraction
-            e_colv = (jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-                      == sel_ref[SEL_FEAT]).astype(jnp.float32)
-            col2 = jax.lax.dot_general(
-                x.astype(jnp.float32), e_colv,
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)      # [R, 1]
-            pos_c = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
-            valid2 = pos_c < (cnt - blk * R)
-            gl2 = _go_left(col2, sel_ref) & valid2
-            glf = gl2.astype(jnp.float32)
-            grf = jnp.logical_xor(gl2, valid2).astype(jnp.float32)
-        _hist_accumulate(bins_i, v * _side_flag(sel_ref, glf, grf),
-                         hist_ref, b_hi=b_hi, g=g, c=_CHANNELS, lo_n=lo_n,
-                         ngroups=ngroups)
+        # nothing of it runs where the caller named no child
+        @pl.when(sel_ref[SEL_SIDE] != SIDE_NONE)
+        def _hook():
+            # Mosaic has no direct bf16 -> i32 cast; hop through f32
+            bins_i = x[:, :f_pad].astype(jnp.float32).astype(jnp.int32)
+            v = x[:, f_pad:f_pad + _CHANNELS].astype(jnp.float32)
+            if side is not None:
+                # the compaction's own go-left / go-right bits, already
+                # row-oriented and lane-replicated: take the value lanes
+                flag_l, flag_r = side
+                glf = flag_l[:, f_pad:f_pad + _CHANNELS]
+                grf = flag_r[:, f_pad:f_pad + _CHANNELS]
+            else:
+                # the matmul compaction's bits are lane-oriented ([1,
+                # R]); a [1, R] -> [R, 1] relayout is a Mosaic
+                # transpose, a second exact matvec against the same
+                # one-hot column is ~R*C MACs, noise next to its [R, R]
+                # contraction
+                e_colv = (jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+                          == sel_ref[SEL_FEAT]).astype(jnp.float32)
+                col2 = jax.lax.dot_general(
+                    x.astype(jnp.float32), e_colv,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [R, 1]
+                pos_c = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+                valid2 = pos_c < (cnt - blk * R)
+                gl2 = _go_left(col2, sel_ref) & valid2
+                glf = gl2.astype(jnp.float32)
+                grf = jnp.logical_xor(gl2, valid2).astype(jnp.float32)
+            _hist_accumulate(bins_i, v * _side_flag(sel_ref, glf, grf),
+                             hist_ref, b_hi=b_hi, g=g, c=_CHANNELS,
+                             lo_n=lo_n, ngroups=ngroups)
 
     _scan_kernel(sel_ref, rows_in, scratch_in,
                  rows_ref, scratch_ref, out_ref,
@@ -165,12 +228,18 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                      cb_block: int = 2048, hist_rpb: int = 2048,
                      scan: str = "permute",
                      interpret_kernel: bool = False,
-                     fused_kernel_interpret: bool = False):
+                     fused_kernel_interpret: bool = False,
+                     raw_hist: bool = False):
     """Build ``fused(sel, rows, scratch[, grid_blocks]) -> (rows, scratch,
     nleft, h_side)`` — the single-scan partition contract of
     partition_kernel2.make_partition_ss extended with ONE child's
     [f_pad, padded_bins, 2] f32 histogram, accumulated during the scan:
-    the left child's where ``sel[SEL_SIDE] > 0``, else the right's.
+    the left child's where ``sel[SEL_SIDE]`` is ``SIDE_LEFT``, the
+    right's at ``SIDE_RIGHT``, all zeros at ``SIDE_NONE`` (the hook is
+    skipped; rows, scratch and nleft do not depend on the side).
+    ``raw_hist=True`` (compiled kernel only) hands ``h_side`` back as
+    the kernel's [ngroups, M, N] accumulator, for a caller that calls
+    ``hook_histogram`` only in the branch that reads it.
 
     ``scan`` selects the per-block compaction plugged into the shared
     schedule: ``"permute"`` (partition_kernel3's butterfly routing — the
@@ -210,6 +279,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
             "fused_kernel_interpret supports static grids only (the "
             "Pallas interpreter cannot run a traced grid bound)")
     if interpret and not fused_kernel_interpret:
+        assert not raw_hist, "the composition has no raw accumulator"
         if interpret_kernel:
             if scan == "permute":
                 from .partition_kernel3 import make_partition_perm
@@ -239,10 +309,11 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
 
         def _fused_i(sel, rows, scratch, *gb):
             rows1, scratch1, nleft = part(sel, rows, scratch, *gb)
-            left = sel[SEL_SIDE] > 0
+            left = sel[SEL_SIDE] == SIDE_LEFT
             h_side = _hist_side(
                 rows1, sel[SEL_S0] + jnp.where(left, 0, nleft),
-                jnp.where(left, nleft, sel[SEL_CNT] - nleft))
+                jnp.where(sel[SEL_SIDE] == SIDE_NONE, 0,
+                          jnp.where(left, nleft, sel[SEL_CNT] - nleft)))
             return rows1, scratch1, nleft, h_side
 
         if dynamic:
@@ -292,8 +363,8 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
         rows2 = copyback_call(sel, rows1, scratch1, nleft, mm, R=R,
                               cb_block=cb_block, n=n, C=C, dtype=dtype,
                               interpret=fused_kernel_interpret)
-        return rows2, scratch1, nleft, _diag_extract(
-            hist, ngroups, g, b_hi, _CHANNELS, _LO_N, f_pad, b)
+        return rows2, scratch1, nleft, (
+            hist if raw_hist else hook_histogram(hist, f_pad, b))
 
     if dynamic:
         def fused(sel, rows, scratch, grid_blocks):

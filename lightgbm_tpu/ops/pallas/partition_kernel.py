@@ -43,10 +43,12 @@ _HBM = pltpu.HBM
 
 # sel layout (SMEM i32[8]): s0, par_cnt, feat_col, sbin, default_left,
 # is_cat, nan_bin (== num_bins-1 if feature has a NaN bin else -1), and
-# the child the fused scan's hook histograms (fused_split.py: > 0 the
-# left one, else the right; the plain partition scans do not read it)
+# the child the fused scan's hook histograms (fused_split.py: SIDE_LEFT,
+# SIDE_RIGHT, or SIDE_NONE - the hook is skipped; the plain partition
+# scans do not read it)
 (SEL_S0, SEL_CNT, SEL_FEAT, SEL_SBIN, SEL_DL, SEL_CAT, SEL_NANB,
  SEL_SIDE) = range(8)
+SIDE_RIGHT, SIDE_LEFT, SIDE_NONE = 0, 1, -1
 # bitset extension (ISSUE 16): a caller may append ceil(padded_bins/32)
 # i32 membership words after the 8 descriptor slots — sel becomes
 # i32[8 + W] and a categorical split's go-left bit is bit (bin % 32) of

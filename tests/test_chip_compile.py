@@ -74,11 +74,15 @@ def _part_args(n_alloc, c):
     return partition_args(n_alloc, c) + (sds((), jnp.int32),)
 
 
-def _fused(scan: str, geom=HIGGS):
+def _fused(scan: str, geom=HIGGS, raw_hist=False):
+    """The fused scan, one kernel for the three states of
+    ``sel[SEL_SIDE]`` (left, right, no child: ISSUE 35);
+    ``raw_hist=True`` is the form ops/grow.py calls, the accumulator
+    handed on as it is."""
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
     _, n_alloc, c, f_pad = geom
     fn = make_fused_split(n_alloc, c, f_pad=f_pad, padded_bins=BINS, R=R,
-                          dynamic=True, scan=scan)
+                          dynamic=True, scan=scan, raw_hist=raw_hist)
     return fn, _part_args(n_alloc, c)
 
 
@@ -198,6 +202,10 @@ COMPILES = {
                           True),
     "stream_refresh_root_msltr": (
         functools.partial(_stream, "refresh_root", MSLTR), True),
+    "fused_split_permute_raw": (
+        functools.partial(_fused, "permute", HIGGS, True), True),
+    "fused_split_permute_raw_msltr": (
+        functools.partial(_fused, "permute", MSLTR, True), True),
 }
 
 
@@ -263,7 +271,8 @@ def test_kernel_names_reach_the_compiled_program(kernel, compiled_text):
 
 @pytest.mark.parametrize("name,groups", [
     ("fused_split_permute", 4), ("fused_split_matmul", 4),
-    ("fused_split_permute_msltr", 18), ("fused_split_matmul_msltr", 18)])
+    ("fused_split_permute_msltr", 18), ("fused_split_matmul_msltr", 18),
+    ("fused_split_permute_raw", 4), ("fused_split_permute_raw_msltr", 18)])
 def test_the_fused_scan_accumulates_one_child(name, groups, compiled_text):
     """ISSUE 30: the scan's resident accumulator is ONE [groups, M, N]
     block - the child ``sel[SEL_SIDE]`` names - at the Higgs width (32
@@ -276,6 +285,123 @@ def test_the_fused_scan_accumulates_one_child(name, groups, compiled_text):
     assert len(scans) == 1, scans
     assert f"f32[{groups},128,256]" in scans[0]
     assert f"f32[2,{groups},128,256]" not in scans[0]
+
+
+def _assert_one_scan_no_comb_copy(text, comb):
+    """A compiled grow program's text: one ``lgbm_split_scan``, one
+    conditional, and no copy of a ``comb``-shaped f32 array."""
+    import re
+    assert len(re.findall(r"%lgbm_split_scan(?:\.\d+)? = ", text)) == 1
+    ops = re.findall(r"^\s*(?:ROOT )?%[\w.-]+ = (\S+) ([\w-]+)\(", text,
+                     re.M)
+    assert len([o for o in ops if o[1] == "conditional"]) == 1
+    comb_sized = [o[1] for o in ops
+                  if o[0].startswith(f"f32[{comb[0]},{comb[1]}]")]
+    assert comb_sized and not [o for o in comb_sized if o.startswith("copy")]
+
+
+@pytest.mark.parametrize("crossover", [0, 5000, None],
+                         ids=["never_hook", "mid", "always_hook"])
+def test_the_grow_program_compiles_with_one_scan_and_no_comb_copy(
+        crossover, one_chip, no_compile_cache, monkeypatch):
+    """ISSUE 35: the WHOLE grow program of the ``higgs`` route at the
+    ``higgs-train-10m`` shape (stream, fused, 255 leaves, 10.5M rows)
+    through the v5e compiler, whatever the hook's crossover: one
+    ``lgbm_split_scan`` (the kernel takes the third state, there is no
+    second scan), the comb-direct ``lgbm_hist`` inside the one
+    conditional, no comb-sized ``copy`` anywhere (the cond's branches
+    only read the comb: the cell stands at 97% of the chip's memory)
+    and temporaries far under one comb.  ``make_grow_fn`` asks
+    ``jax.default_backend()`` for its route; the test answers for the
+    described chip."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.grow import make_grow_fn
+    from lightgbm_tpu.ops.pallas import fused_split
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    from lightgbm_tpu.ops.split import SplitHyperParams
+    n, f = 10_500_096, F_PAD
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if crossover is None:
+        crossover = fused_split.HOOK_ALWAYS
+    monkeypatch.setattr(fused_split, "hook_crossover_rows",
+                        lambda ngroups: crossover)
+    gp = make_grow_fn(
+        SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
+        padded_bins=BINS, physical_bins=sds((n, f), jnp.uint8),
+        stream={"kind": "binary", "sigmoid": 1.0, "count": n})
+    assert gp.fused and gp._root0_fn is not None
+    comb = comb_shape(gp._n_alloc, gp._C)
+    args = [sds(comb, jnp.float32)] * 2 + [sds((1,), jnp.float32)] * 3 + [
+        sds((f,), jnp.float32), sds((f,), jnp.int32), sds((f,), jnp.bool_),
+        sds((f,), jnp.bool_), sds((), jnp.int32), sds((), jnp.float32),
+        sds((f, BINS, 2), jnp.float32)]
+    compiled = gp._grow_p.lower(*(
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        for a in args)).compile()
+    text = compiled.as_text()
+    _assert_one_scan_no_comb_copy(text, comb)
+    # the comb-direct histogram sits in a branch computation, not in
+    # the loop body beside the scan
+    hists = re.findall(r"%lgbm_hist(?:\.\d+)? = [^\n]*op_name=\"([^\"]*)\"",
+                       text)
+    assert len(hists) == 1 and "/while/body/cond/branch_1_fun/" in hists[0]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < comb[0] * comb[1] * 4 // 8
+
+
+def test_the_mesh_grow_program_adds_no_collective(topo, no_compile_cache,
+                                                  monkeypatch):
+    """ISSUE 35 on the mesh: the data-parallel grow program of
+    ``higgs-data4-train-21m`` (5.25M rows a shard, four shards) through
+    the v5e compiler.  Whether the hook runs is decided from the leaf
+    record's replicated count, so the split still pays the collectives
+    it paid before - per split one reduce-scatter of the histogram, ONE
+    all-reduce for both row counts (a psum ahead of the scan could not
+    share it), the election's pmin / pmax; the root pays its own - and
+    none sits inside the conditional; one scan, no comb-sized copy."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    from lightgbm_tpu.ops.split import SplitHyperParams
+    from lightgbm_tpu.parallel.data_parallel import (DATA_AXIS,
+                                                     DataParallelGrower)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices), (DATA_AXIS,))
+    shards, n_loc, f = len(topo.devices), 5_250_048, F_PAD
+    grower = DataParallelGrower(
+        SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
+        padded_bins=BINS, mesh=mesh,
+        physical_bins=sds((shards * n_loc, f), jnp.uint8))
+    assert grower.fused and grower.hist_scatter
+    lines, lanes = comb_shape(grower._pieces.n_alloc, grower._pieces.C)
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    comb = arg((shards * lines, lanes), jnp.float32, DATA_AXIS, None)
+    row = arg((shards * n_loc,), jnp.float32, DATA_AXIS)
+    text = grower._sharded_core.lower(
+        comb, comb, row, row, row, arg((f,), jnp.float32),
+        arg((f,), jnp.int32), arg((f,), jnp.bool_), arg((f,), jnp.bool_),
+        arg((), jnp.int32), arg((), jnp.float32)).compile().as_text()
+    _assert_one_scan_no_comb_copy(text, (lines, lanes))
+    # root + split: the parent commit's program reads the same counts
+    assert text.count(" reduce-scatter(") == 2
+    assert text.count(" all-reduce(") == 10
+    branches = re.findall(r"branch_computations=\{([^}]*)\}", text)
+    assert len(branches) == 1
+    for name in branches[0].replace("%", "").split(", "):
+        body = text[text.index(f"\n%{name} "):]
+        body = body[:body.index("\n}\n")]
+        assert "all-reduce" not in body and "reduce-scatter" not in body
 
 
 def _hand_off(stream: bool, n: int):

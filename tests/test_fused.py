@@ -141,12 +141,16 @@ def test_fused_engaged_and_flagged():
 # The grow program counts those splits and their rows, on every route.
 # ---------------------------------------------------------------------
 def _train_counted(fused, *, weighted, part_interp="", mesh=False,
-                   n=3000, rounds=3):
+                   n=3000, rounds=3, crossover=None):
     """Train l2 under LGBM_TPU_PHYS=interpret with a live tracer;
-    returns (trees, counter totals).  ``weighted`` puts the hessian
+    returns (trees, counter totals, Tree::grow args, every tree's
+    parent row counts, one a split).  ``weighted`` puts the hessian
     mass on the FEWER rows: 30% of the rows carry weight 20, the rest
     0.05, and the label steps where the weight does, so the record's
-    left count is far off the rows' at the first splits."""
+    left count is far off the rows' at the first splits.
+    ``crossover`` (a ``pytest.MonkeyPatch`` and a row count) patches
+    ``fused_split.hook_crossover_rows`` on the freshly imported
+    library."""
     saved = _save_env()
     os.environ["LGBM_TPU_PHYS"] = "interpret"
     os.environ["LGBM_TPU_FUSED"] = fused
@@ -156,6 +160,10 @@ def _train_counted(fused, *, weighted, part_interp="", mesh=False,
         _purge()
         import lightgbm_tpu as lgb
         from lightgbm_tpu.obs import counters, tracer
+        if crossover is not None:
+            from lightgbm_tpu.ops.pallas import fused_split
+            crossover[0].setattr(fused_split, "hook_crossover_rows",
+                                 lambda ngroups: crossover[1])
         rng = np.random.default_rng(5)
         x = rng.normal(size=(n, 5)).astype(np.float32)
         heavy = x[:, 0] > 0.5
@@ -176,25 +184,40 @@ def _train_counted(fused, *, weighted, part_interp="", mesh=False,
                     if e["name"] == "Tree::grow"]
         finally:
             tracer.disable()
-        return _tree_bytes(bst._inner.models), tot, args
+        return _tree_bytes(bst._inner.models), tot, args, [
+            np.asarray(t.internal_count)[:int(t.num_leaves) - 1]
+            for t in bst._inner.models]
     finally:
         _restore_env(saved)
         _purge()
 
 
-@pytest.mark.parametrize("variant", ["phys_interpret", "part_kernel",
-                                     "mesh8"])
+# part_kernel runs every split through the Pallas interpreter: one tree
+_VARIANTS = {"phys_interpret": {}, "part_kernel": {"part_interp": "kernel",
+                                                   "rounds": 1},
+             "mesh8": {"mesh": True, "rounds": 2}}
+_UNFUSED = {}
+
+
+def _unfused(variant, weighted, **kw):
+    """The LGBM_TPU_FUSED=0 run of a variant, trained once a module."""
+    key = (variant, weighted, *sorted(kw.items()))
+    if key not in _UNFUSED:
+        _UNFUSED[key] = _train_counted("0", weighted=weighted,
+                                       **_VARIANTS[variant], **kw)
+    return _UNFUSED[key]
+
+
+@pytest.mark.parametrize("variant", sorted(_VARIANTS))
 def test_side_miss_rehistograms_to_the_unfused_trees(variant):
     """Hessian mass on the side with fewer rows: the record names the
     wrong child at some splits (``side_miss_splits > 0``), those
     children are histogrammed again, and the trees are byte-identical
     to LGBM_TPU_FUSED=0 - on one device, through the real partition
     kernel bodies, and over the 8-shard mesh."""
-    kw = {"phys_interpret": {}, "part_kernel": {"part_interp": "kernel",
-                                                "rounds": 2},
-          "mesh8": {"mesh": True, "rounds": 2}}[variant]
-    t0, tot0, _ = _train_counted("0", weighted=True, **kw)
-    t1, tot1, args = _train_counted("1", weighted=True, **kw)
+    t0, tot0, _, _ = _unfused(variant, True)
+    t1, tot1, args, _ = _train_counted("1", weighted=True,
+                                       **_VARIANTS[variant])
     assert t0 == t1
     assert tot1["side_miss_splits"] > 0
     # a missed split re-reads the SMALLER child: at most half its parent
@@ -211,11 +234,73 @@ def test_side_miss_rehistograms_to_the_unfused_trees(variant):
         == tot1["rows_rehistogrammed"]
 
 
+# ---------------------------------------------------------------------
+# ISSUE 35: the hook runs only at parents of up to
+# ``fused_split.hook_crossover_rows`` rows (a shard); past it the scan is
+# told no child and the smaller one is histogrammed from the comb.  The
+# function is patched to 0 (never hook), to a row count that splits the
+# tree's parents, and to HOOK_ALWAYS (the parent commit's program).
+# ---------------------------------------------------------------------
+# the mesh compares the GLOBAL parent with crossover x 8 shards
+_MID = {"phys_interpret": 500, "part_kernel": 500, "mesh8": 60}
+@pytest.mark.parametrize("cross", ["zero", "mid", "never"])
+@pytest.mark.parametrize("variant", sorted(_VARIANTS))
+def test_hook_crossover_keeps_the_unfused_trees(variant, cross,
+                                                monkeypatch):
+    """Whatever the crossover, the trees are byte-identical to
+    LGBM_TPU_FUSED=0 (off the chip both histograms are the reference
+    arithmetic), a miss happens only where the hook ran, and at
+    crossover 0 it never runs."""
+    from lightgbm_tpu.ops.pallas.fused_split import HOOK_ALWAYS
+    rows = {"zero": 0, "mid": _MID[variant], "never": HOOK_ALWAYS}[cross]
+    t0, tot0, _, _ = _unfused(variant, True)
+    t1, tot, args, _ = _train_counted("1", weighted=True,
+                                   crossover=(monkeypatch, rows),
+                                   **_VARIANTS[variant])
+    assert t0 == t1
+    assert tot["splits"] == tot0["splits"] > 0
+    assert tot0["hook_splits"] == tot0["rows_hooked"] == 0
+    assert tot["side_miss_splits"] <= tot["hook_splits"] <= tot["splits"]
+    assert tot["rows_rehistogrammed"] <= tot["rows_hooked"] / 2
+    if cross == "zero":
+        assert tot["hook_splits"] == tot["rows_hooked"] == 0
+        assert tot["side_miss_splits"] == tot["rows_rehistogrammed"] == 0
+    elif cross == "mid":
+        # the first splits' misses (test above) are past the crossover
+        assert 0 < tot["hook_splits"] < tot["splits"]
+    else:
+        assert tot["hook_splits"] == tot["splits"]
+        assert tot["side_miss_splits"] > 0
+    # both ride the Tree::grow span: scan_rows_hooked reads them there
+    assert sum(a["hook_splits"] for a in args) == tot["hook_splits"]
+    assert sum(a["rows_hooked"] for a in args) == tot["rows_hooked"]
+
+
+@pytest.mark.parametrize("variant", ["phys_interpret", "mesh8"])
+def test_rows_hooked_is_the_hooked_parents_rows(variant, monkeypatch):
+    """Constant hessians and a row count that needs no padding (the
+    counter counts the rows the scan visits, a segment's padding rows
+    among them): the model's internal counts ARE the parents' rows, so
+    the counter can be checked split by split - on the mesh against
+    the crossover times the shard count."""
+    rows = _MID[variant]
+    trees, tot, _, parents = _train_counted(
+        "1", weighted=False, n=4096, crossover=(monkeypatch, rows),
+        **_VARIANTS[variant])
+    parents = np.concatenate(parents)
+    hooked = parents[parents <= rows * (8 if variant == "mesh8" else 1)]
+    assert 0 < len(hooked) < len(parents)
+    assert tot["hook_splits"] == len(hooked)
+    assert tot["rows_hooked"] == hooked.sum()
+    assert tot["rows_partitioned"] == parents.sum()
+    assert trees == _unfused(variant, False, n=4096)[0]
+
+
 def test_constant_hessians_never_miss():
     """Unweighted l2: every hessian is 1, the record's left count IS
     the row count, so the scan is always told the smaller child."""
-    t0, _, _ = _train_counted("0", weighted=False)
-    t1, tot, _ = _train_counted("1", weighted=False)
+    t0, _, _, _ = _train_counted("0", weighted=False)
+    t1, tot, _, _ = _train_counted("1", weighted=False)
     assert t0 == t1 and tot["splits"] > 0
     assert tot["side_miss_splits"] == 0
     assert tot["rows_rehistogrammed"] == 0

@@ -633,8 +633,9 @@ class TestLifecycle:
         def hammer():
             for _ in range(per_thread):
                 obs.events.record("e")
-                obs.counters.record(
-                    np.asarray([1.0, 2.0, 3.0, 4.0, 0.0, 0.0]))
+                obs.counters.record(np.asarray(
+                    [1.0, 2.0, 3.0, 4.0]
+                    + [0.0] * (len(obs.COUNTER_NAMES) - 4)))
 
         ts = [threading.Thread(target=hammer) for _ in range(n_threads)]
         for t in ts:

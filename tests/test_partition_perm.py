@@ -386,6 +386,70 @@ def test_fused_kernel_hook_takes_the_compactions_flags(kind, side,
                                np.asarray(comp[3]), rtol=0, atol=1e-4)
 
 
+# ISSUE 35: a third state of sel[SEL_SIDE], "no child": the hook is
+# skipped inside the same kernel.  (kind, planes, scan): NaN and
+# bitset-categorical splits, one and two planes, both compactions
+_NO_CHILD_CASES = [(k, p, sc) for k in ("nan_default_left", "cat_bitset")
+                   for p in (1, 2) for sc in ("permute", "matmul")]
+
+
+@pytest.mark.parametrize(
+    "kind,planes,scan", _NO_CHILD_CASES,
+    ids=[f"{k}-{p}plane-{sc}" for k, p, sc in _NO_CHILD_CASES])
+def test_fused_kernel_no_child_skips_only_the_hook(kind, planes, scan):
+    """The REAL fused kernel through the Pallas interpreter, told
+    SIDE_NONE: the rows, the scratch and nleft of the left and of the
+    right call, bit for bit, and an all-zero histogram (the
+    accumulator is still zeroed at block 0) - also as the raw
+    accumulator, and in the composition the off-chip grow path runs."""
+    import ml_dtypes
+    from lightgbm_tpu.ops.pallas.fused_split import hook_histogram, \
+        make_fused_split
+    from lightgbm_tpu.ops.pallas.layout import to_planes
+    from lightgbm_tpu.ops.pallas.partition_kernel import (
+        SIDE_LEFT, SIDE_NONE, SIDE_RIGHT)
+    f_pad, bins = (32, 64) if planes == 1 else (144, 64)
+    c = planes * LANE
+    rng = np.random.default_rng(13)
+    rows = np.zeros((N, c), np.float32)
+    rows[:, :f_pad] = rng.integers(0, bins, size=(N, f_pad))
+    rows[:, f_pad:f_pad + 2] = rng.normal(size=(N, 2)).astype(
+        ml_dtypes.bfloat16).astype(np.float32)
+    rj = to_planes(jnp.asarray(rows))
+    kw = dict(f_pad=f_pad, padded_bins=bins, R=R, size=SIZE, scan=scan)
+    fused = make_fused_split(N, c, fused_kernel_interpret=True, **kw)
+
+    def call(fn, side):
+        sel = _flag_sel(kind)
+        sel[SEL_S0], sel[SEL_CNT], sel[SEL_SIDE] = 65, 901, side
+        if planes == 2:
+            sel[2] = 133
+        return [np.asarray(o) for o in
+                fn(jnp.asarray(sel), rj, jnp.zeros_like(rj))]
+
+    none, left, right = (call(fused, sd) for sd in (SIDE_NONE, SIDE_LEFT,
+                                                    SIDE_RIGHT))
+    assert 0 < int(none[2]) < 901
+    for other in (left, right):
+        for i in (0, 1, 2):                    # rows, scratch, nleft
+            np.testing.assert_array_equal(none[i], other[i])
+        assert np.abs(other[3]).sum() > 0
+    assert none[3].shape == (f_pad, bins, 2) and not none[3].any()
+    if scan == "permute":
+        # the raw accumulator, as ops/grow.py takes it: zeros at no
+        # child, the histogram at a child
+        raw = make_fused_split(N, c, fused_kernel_interpret=True,
+                               raw_hist=True, **kw)
+        acc_none, acc_left = call(raw, SIDE_NONE)[3], call(raw, SIDE_LEFT)[3]
+        assert acc_none.ndim == 3 and not acc_none.any()
+        np.testing.assert_array_equal(
+            np.asarray(hook_histogram(jnp.asarray(acc_left), f_pad, bins)),
+            left[3])
+        comp = call(make_fused_split(N, c, interpret=True, hist_rpb=R,
+                                     **kw), SIDE_NONE)
+        assert int(comp[2]) == int(none[2]) and not comp[3].any()
+
+
 # ---------------------------------------------------------------------
 # ISSUE 29: a comb line wider than 128 lanes is stored plane-major and
 # moves as one row DMA a plane.  The same scans, two planes, through the
