@@ -117,23 +117,20 @@ def efb_demo_geometry():
                         num_leaves=8)
 
 
-@register_kernel("grow_physical_efb", kind="grow", donate=(0, 1),
-                 note="physical grow over a BUNDLED dataset (ISSUE 12: "
-                      "the EFB graduation) — the comb ingests the "
-                      "unbundled logical width, so the lane/vmem/hbm "
-                      "passes price the post-unbundle geometry, not "
-                      "the narrower bundled storage")
-def _grow_physical_efb():
+def _grow_physical_efb_form(hp, f_comb):
+    """The physical grow over the demo bundle map, with the comb width
+    the form has to engage."""
     import jax.numpy as jnp
     from ..ops.grow import make_grow_fn
     bundle, geo = efb_demo_geometry()
     n, f_log, f_phys = geo["n"], geo["f_log"], geo["f_phys"]
-    gp = make_grow_fn(_hp(), num_leaves=geo["num_leaves"],
+    gp = make_grow_fn(hp, num_leaves=geo["num_leaves"],
                       padded_bins=geo["padded_bins"],
                       padded_bins_log=geo["padded_bins_log"],
                       bundle=bundle,
                       physical_bins=sds((n, f_phys), jnp.uint8))
-    assert gp._f_pad == f_log, gp._f_pad   # unbundled width engaged
+    assert gp._f_pad == {"bundled": f_phys, "unbundled": f_log}[f_comb], \
+        gp._f_pad
     n_phys = gp._n_alloc
     args = (sds((n_phys, gp._C), jnp.float32),
             sds((n_phys, gp._C), jnp.float32),
@@ -143,6 +140,26 @@ def _grow_physical_efb():
             sds((f_log,), jnp.bool_), sds((), jnp.int32),
             sds((), jnp.float32))
     return gp._grow_p, args
+
+
+@register_kernel("grow_physical_efb", kind="grow", donate=(0, 1),
+                 note="physical grow over a BUNDLED dataset with the "
+                      "plain finder (ISSUE 36): the comb keeps one "
+                      "column a bundle, so the lane/vmem/hbm passes "
+                      "price the bundled geometry, and the split "
+                      "finder works in bundle space")
+def _grow_physical_efb():
+    return _grow_physical_efb_form(_hp(), "bundled")
+
+
+@register_kernel("grow_physical_efb_unbundled", kind="grow", donate=(0, 1),
+                 note="physical grow over a BUNDLED dataset with a grow "
+                      "option the bundle-space finder does not cover "
+                      "(ISSUE 12: the comb ingests the unbundled "
+                      "logical width, and the passes price that)")
+def _grow_physical_efb_unbundled():
+    return _grow_physical_efb_form(_hp()._replace(use_extra_trees=True),
+                                   "unbundled")
 
 
 @register_kernel("grow_stream", kind="grow", donate=(0, 1, 11),

@@ -6,14 +6,23 @@ bundled into one bin column with stacked bin ranges, so the histogram pass
 costs one column per bundle instead of one per feature.
 
 TPU re-design: the HOST dataset keeps the logical per-feature view (mappers,
-bin matrix, model space are unchanged); bundling happens at device-layout
-time.  The device bin matrix carries one physical column per bundle, the
-histogram kernel runs over physical columns, and a cheap gather expands the
-physical histogram back to logical features before split search, with each
-feature's default bin reconstructed from the leaf totals (the
-``FixHistogram`` trick, dataset.h:676).  Split search, tree structure and
-the saved model therefore always speak original features — bundles are
-invisible above the histogram, exactly like the reference.
+bin matrix or sparse store, model space are unchanged); bundling happens at
+device-layout time.  The device bin matrix carries one physical column per
+bundle and the histogram kernels run over physical columns.  What turns a
+bundle-space histogram into split candidates of logical features depends
+on the route (ops/routing.py, ``RouteDecision.efb``): on the physical route
+with the plain finder the comb keeps the bundle columns and
+``split.find_best_split_segments`` takes each feature's prefix sums inside
+its static range of the bundle column (ISSUE 36); with the other grow
+options and under the mesh learners the comb ingest unbundles the columns
+(``device_data.unbundle_bins``); on ``row_order`` a gather expands the
+histogram to logical features (``grow.expand``).  No row stores a bundled
+feature's default bin: ``expand`` rebuilds it from the leaf totals (the
+``FixHistogram`` trick, dataset.h:676), the ingest writes it back into
+the rows, and the bundle-space finder sums the column's other bins, where
+those rows sit.  Split search, tree structure and the saved model always
+speak original features — bundles are invisible above the histogram,
+exactly like the reference.
 
 Bundle column layout: bin 0 = "every sub-feature at its default bin";
 sub-feature j owns [offset_j, offset_j + num_bins_j) and a row maps to
@@ -43,6 +52,8 @@ class BundleInfo:
     # physical columns
     num_phys: int
     phys_num_bins: np.ndarray  # [num_phys] i32
+    conflict_rows: int = 0     # sampled rows non-default in two members
+                               # of one bundle (<= max_conflict_rate)
 
     @property
     def any_bundled(self) -> bool:
@@ -50,7 +61,8 @@ class BundleInfo:
 
 
 def find_bundles(
-    bin_matrix: np.ndarray,          # [n, f_log] logical bins
+    bin_matrix,                      # [n, f_log] logical bins, or a
+                                     # dataset_core.SparseBins store
     num_bins: np.ndarray,            # [f_log]
     has_nan: np.ndarray,             # [f_log] bool
     is_cat: np.ndarray,              # [f_log] bool
@@ -78,7 +90,8 @@ def find_bundles(
         sidx = np.random.default_rng(1).choice(n, size=rows, replace=False)
         sample = bin_matrix[np.sort(sidx)]
     else:
-        sample = bin_matrix
+        sample = (bin_matrix if isinstance(bin_matrix, np.ndarray)
+                  else bin_matrix[np.arange(n)])
 
     default_bin = np.zeros(f, np.int32)
     nz_masks: List[Optional[np.ndarray]] = [None] * f
@@ -125,6 +138,7 @@ def find_bundles(
             bundle_conflicts.append(0)
             bundle_bins.append(1 + int(num_bins[j]))
 
+    bundles_all = bundles
     bundles = [b for b in bundles if len(b) >= min_bundle_size]
     if not bundles:
         return None
@@ -154,7 +168,10 @@ def find_bundles(
     info = BundleInfo(
         feat_phys=feat_phys, feat_offset=feat_offset,
         feat_default=default_bin, is_bundled=is_bundled,
-        num_phys=p, phys_num_bins=np.asarray(phys_num_bins, np.int32))
+        num_phys=p, phys_num_bins=np.asarray(phys_num_bins, np.int32),
+        conflict_rows=int(sum(
+            c for b, c in zip(bundles_all, bundle_conflicts)
+            if len(b) >= min_bundle_size)))
     log.info("EFB: bundled %d sparse features into %d columns "
              "(%d physical columns total, was %d)",
              int(is_bundled.sum()), len(bundles), p, f)
@@ -177,4 +194,37 @@ def build_physical_matrix(bin_matrix: np.ndarray,
             nz = col != info.feat_default[j]
             out[nz, p] = (col[nz].astype(np.int64)
                           + int(info.feat_offset[j])).astype(dtype)
+    return out
+
+
+def physical_from_sparse(store, info: BundleInfo) -> np.ndarray:
+    """:func:`build_physical_matrix` from the stored entries of a
+    ``dataset_core.SparseBins`` store: a bundle column is zero except
+    where a row stores a non-default bin of one of its members, so it
+    is written from those entries alone (in feature order: a conflict
+    row keeps the later feature, as above).  An unbundled column, and a
+    member whose implicit zero is not its default bin, go through one
+    dense column each."""
+    n = store.shape[0]
+    dtype = (np.uint16 if int(info.phys_num_bins.max()) > 256
+             else store.dtype)
+    out = np.zeros((n, info.num_phys), dtype=dtype)
+    by_entry = np.asarray(info.is_bundled
+                          & (store.zero_bin == info.feat_default))
+    for j in np.flatnonzero(~by_entry):
+        lo, hi = store.indptr[j], store.indptr[j + 1]
+        col = np.full(n, store.zero_bin[j], dtype)
+        col[store.rows[lo:hi]] = store.bins[lo:hi]
+        p = int(info.feat_phys[j])
+        if not info.is_bundled[j]:
+            out[:, p] = col
+        else:
+            nz = col != info.feat_default[j]
+            out[nz, p] = col[nz] + dtype(info.feat_offset[j])
+    feat = store.feature_of_entry()
+    keep = by_entry[feat] & (store.bins != info.feat_default[feat])
+    feat = feat[keep]
+    out[store.rows[keep], info.feat_phys[feat]] = (
+        store.bins[keep].astype(np.int64)
+        + info.feat_offset[feat]).astype(dtype)
     return out

@@ -91,7 +91,11 @@ class BinnedDataset:
     """
 
     def __init__(self) -> None:
-        self.bin_matrix: Optional[np.ndarray] = None
+        # the dense logical bin matrix; for scipy.sparse input it is
+        # made from ``sparse_bins`` only when something reads it (the
+        # ``bin_matrix`` property)
+        self._bin_matrix: Optional[np.ndarray] = None
+        self.sparse_bins: Optional[SparseBins] = None
         self.mappers: List[BinMapper] = []
         self.used_feature_map: np.ndarray = np.array([], dtype=np.int32)
         self.num_total_features: int = 0
@@ -106,12 +110,39 @@ class BinnedDataset:
 
     # ------------------------------------------------------------------
     @property
+    def bin_matrix(self) -> Optional[np.ndarray]:
+        if self._bin_matrix is None and self.sparse_bins is not None:
+            log.info("densifying the sparse bin store: %d x %d bins",
+                     *self.sparse_bins.shape)
+            self._bin_matrix = self.sparse_bins.to_dense()
+        return self._bin_matrix
+
+    @bin_matrix.setter
+    def bin_matrix(self, mat: Optional[np.ndarray]) -> None:
+        self._bin_matrix = mat
+        self.sparse_bins = None
+
+    def _bins_shape(self):
+        if self._bin_matrix is not None:
+            return self._bin_matrix.shape
+        return (0, 0) if self.sparse_bins is None else self.sparse_bins.shape
+
+    @property
     def num_data(self) -> int:
-        return 0 if self.bin_matrix is None else self.bin_matrix.shape[0]
+        return self._bins_shape()[0]
 
     @property
     def num_features(self) -> int:
-        return 0 if self.bin_matrix is None else self.bin_matrix.shape[1]
+        return self._bins_shape()[1]
+
+    def physical_matrix(self, info) -> np.ndarray:
+        """The bundled device layout of ``info`` (io/bundle.py): from
+        the stored entries of sparse input, 8 a row at Expo's shape,
+        without the ``[n, f]`` logical matrix between."""
+        from .bundle import build_physical_matrix, physical_from_sparse
+        if self._bin_matrix is None and self.sparse_bins is not None:
+            return physical_from_sparse(self.sparse_bins, info)
+        return build_physical_matrix(self.bin_matrix, info)
 
     @property
     def num_bins_per_feature(self) -> np.ndarray:
@@ -187,33 +218,30 @@ class BinnedDataset:
         # TGB_ApplyBins) when built, vectorized numpy otherwise
         dtype = (np.uint16 if any(m.num_bins > 256 for m in self.mappers)
                  else np.uint8)
-        mat = None
         if sp:
-            # sparse: fill each column with the zero bin, then overwrite
-            # stored entries only (sparse_bin.hpp delta-page analog)
+            # sparse: only stored entries are quantized; every other
+            # cell is its column's zero bin (sparse_bin.hpp delta-page
+            # analog).  The entries stay as they are - the bundled
+            # device layout is built from them directly, and the dense
+            # logical matrix only if something asks for it
             csc = data.tocsc()
-            mat = np.empty((n, len(self.mappers)), dtype=dtype)
-            for j, (orig, m) in enumerate(
-                    zip(self.used_feature_map, self.mappers)):
-                zero_bin = m.values_to_bins(np.zeros(1))[0]
-                mat[:, j] = zero_bin
-                lo, hi = csc.indptr[orig], csc.indptr[orig + 1]
-                if hi > lo:
-                    rows_nz = csc.indices[lo:hi]
-                    vals_nz = np.asarray(csc.data[lo:hi], np.float64)
-                    mat[rows_nz, j] = m.values_to_bins(vals_nz).astype(dtype)
-        if mat is None and self.mappers:
-            from .. import native
-            if native.available():
-                applier = native.BinApplier(
-                    self.mappers, self.used_feature_map, dtype)
-                mat = applier.apply(data)
-        if mat is None:
-            mat = np.empty((n, len(self.mappers)), dtype=dtype)
-            for j, (orig, m) in enumerate(
-                    zip(self.used_feature_map, self.mappers)):
-                mat[:, j] = m.values_to_bins(data[:, orig]).astype(dtype)
-        self.bin_matrix = mat
+            self.sparse_bins = SparseBins.from_csc(
+                csc, self.used_feature_map, self.mappers, dtype)
+        else:
+            mat = None
+            if self.mappers:
+                from .. import native
+                if native.available():
+                    applier = native.BinApplier(
+                        self.mappers, self.used_feature_map, dtype)
+                    mat = applier.apply(data)
+            if mat is None:
+                mat = np.empty((n, len(self.mappers)), dtype=dtype)
+                for j, (orig, m) in enumerate(
+                        zip(self.used_feature_map, self.mappers)):
+                    mat[:, j] = m.values_to_bins(
+                        data[:, orig]).astype(dtype)
+            self.bin_matrix = mat
         if config.linear_tree and self.mappers:
             if sp:
                 view = _SparseColumnView(csc)   # csc from the quantize pass
@@ -243,12 +271,23 @@ class BinnedDataset:
             return
         if not config.enable_bundle or len(self.mappers) < 2:
             return
+        from ..obs import tracer
         from .bundle import find_bundles
-        self.bundle_info = find_bundles(
-            self.bin_matrix, self.num_bins_per_feature,
-            np.array([m.has_nan_bin for m in self.mappers], bool),
-            np.array([m.bin_type == BinType.CATEGORICAL
-                      for m in self.mappers], bool))
+        with tracer.span("Dataset::bundle") as sp:
+            # a sparse store hands find_bundles its sampled rows dense
+            self.bundle_info = find_bundles(
+                self.sparse_bins if self._bin_matrix is None
+                else self._bin_matrix, self.num_bins_per_feature,
+                np.array([m.has_nan_bin for m in self.mappers], bool),
+                np.array([m.bin_type == BinType.CATEGORICAL
+                          for m in self.mappers], bool))
+            info = self.bundle_info
+            sp.set(features_bundled=0 if info is None
+                   else int(info.is_bundled.sum()),
+                   bundles=0 if info is None
+                   else int(info.num_phys - (~info.is_bundled).sum()),
+                   conflict_rows=0 if info is None
+                   else int(info.conflict_rows))
 
     # ------------------------------------------------------------------
     def _find_mappers(self, sample, num_total: int, sample_cnt: int,
@@ -520,6 +559,67 @@ def _sync_distributed_mappers(find_one, num_total: int) -> list:
 def _is_scipy_sparse(data) -> bool:
     return (hasattr(data, "tocsc") and hasattr(data, "tocsr")
             and not isinstance(data, np.ndarray))
+
+
+class SparseBins:
+    """The binned form of scipy.sparse input: the stored entries of
+    every used feature, quantized, in CSC order (``indptr`` [f + 1],
+    ``rows`` i32 [nnz], ``bins`` [nnz]), and each feature's ``zero_bin``
+    for every cell that stores nothing.  Reads like a matrix where a
+    reader wants a few rows (``store[row_indices]``, dense)."""
+
+    def __init__(self, n, indptr, rows, bins, zero_bin):
+        self.n, self.indptr, self.rows, self.bins = n, indptr, rows, bins
+        self.zero_bin = zero_bin
+
+    @classmethod
+    def from_csc(cls, csc, used_feature_map, mappers, dtype):
+        zero_bin = np.array(
+            [m.values_to_bins(np.zeros(1))[0] for m in mappers], dtype)
+        counts = np.array([csc.indptr[o + 1] - csc.indptr[o]
+                           for o in used_feature_map], np.int64)
+        indptr = np.concatenate([[0], np.cumsum(counts)])
+        rows = np.empty(int(indptr[-1]), np.int32)
+        bins = np.empty(int(indptr[-1]), dtype)
+        for j, (orig, m) in enumerate(zip(used_feature_map, mappers)):
+            lo, hi = csc.indptr[orig], csc.indptr[orig + 1]
+            if hi > lo:
+                rows[indptr[j]:indptr[j + 1]] = csc.indices[lo:hi]
+                bins[indptr[j]:indptr[j + 1]] = m.values_to_bins(
+                    np.asarray(csc.data[lo:hi], np.float64))
+        return cls(int(csc.shape[0]), indptr, rows, bins, zero_bin)
+
+    @property
+    def shape(self):
+        return self.n, len(self.zero_bin)
+
+    @property
+    def dtype(self):
+        return self.bins.dtype
+
+    def feature_of_entry(self) -> np.ndarray:
+        return np.repeat(np.arange(len(self.zero_bin), dtype=np.int32),
+                         np.diff(self.indptr))
+
+    def _zero_rows(self, n_rows: int) -> np.ndarray:
+        mat = np.empty((n_rows, len(self.zero_bin)), self.dtype)
+        mat[:] = self.zero_bin[None, :]
+        return mat
+
+    def to_dense(self) -> np.ndarray:
+        mat = self._zero_rows(self.n)
+        mat[self.rows, self.feature_of_entry()] = self.bins
+        return mat
+
+    def __getitem__(self, row_idx) -> np.ndarray:
+        row_idx = np.asarray(row_idx)
+        pos = np.full(self.n, -1, np.int64)
+        pos[row_idx] = np.arange(len(row_idx))
+        at = pos[self.rows]
+        keep = at >= 0
+        mat = self._zero_rows(len(row_idx))
+        mat[at[keep], self.feature_of_entry()[keep]] = self.bins[keep]
+        return mat
 
 
 class _SparseColumnView:

@@ -220,6 +220,7 @@ class GBDT:
             grow_kwargs["extra_seed"] = cfg.extra_seed
             grow_kwargs["padded_bins_log"] = self.dd.padded_bins_log
             self._grow_kwargs = grow_kwargs
+            self._set_efb_form("feature")
             grower = FeatureParallelGrower(
                 self.hp, num_leaves=cfg.num_leaves, max_depth=cfg.max_depth,
                 padded_bins=self.dd.padded_bins,
@@ -343,6 +344,7 @@ class GBDT:
                         col_shard_multiple=(n_sh if scat else 1),
                         put_fn=_row_put)
                 _build_constraints(self.dd)
+                self._set_efb_form(cfg.tree_learner)
                 # final routing cell over the REAL layout (bin dtype,
                 # bundle survival, per-shard row count): the decision
                 # the bench record embeds and the golden matrix pins
@@ -368,6 +370,8 @@ class GBDT:
                         rows_per_block=cfg.tpu_rows_per_block,
                         use_dp=cfg.gpu_use_dp, mesh=mesh,
                         bundle=self.dd.bundle, hist_scatter=scat,
+                        bundled_comb=(self.dd.comb_bundled if phys_mesh
+                                      else None),
                         physical_bins=(self.dd.bins if phys_mesh
                                        else None),
                         **self._grow_kwargs)
@@ -389,6 +393,7 @@ class GBDT:
                 from ..ops.grow import PHYS_R
                 self.dd = to_device(ds, row_pad_multiple=PHYS_R)
                 _build_constraints(self.dd)
+                self._set_efb_form("serial")
                 # path selection (ISSUE 10): the declarative routing
                 # model replaces the inline use_phys/use_stream boolean
                 # soup.  The same named predicates (ops/routing.py
@@ -457,6 +462,7 @@ class GBDT:
                     rows_per_block=cfg.tpu_rows_per_block,
                     use_dp=cfg.gpu_use_dp,
                     bundle=self.dd.bundle,
+                    bundled_comb=self.dd.comb_bundled if use_phys else None,
                     physical_bins=self.dd.bins if use_phys else None,
                     stream=stream_spec,
                     paged=page_plan,
@@ -504,6 +510,9 @@ class GBDT:
         # use_phys=False of earlier rounds
         from ..ops import routing as _routing_mod
         _routing_mod.report_fallbacks(self._routing)
+        with obs_tracer.span("Train::layout") as _lsp:
+            if obs_tracer.enabled:
+                _lsp.set(**self.layout_info())
         # score/gradient arrays live at padded length — the LOCAL one
         # under pre-partitioned multi-process data (only the grower
         # boundary sees the assembled global arrays)
@@ -572,6 +581,25 @@ class GBDT:
                      "per compiled dispatch", k)
 
     # ------------------------------------------------------------------
+    def _set_efb_form(self, learner: str) -> None:
+        """Decide ONCE which EFB form a bundled table takes on the
+        physical route (ISSUE 36) and write it on the device layout
+        (``dd.comb_bundled``), after the grow constraints are built and
+        before anything reads a width: the routing inputs, the page
+        planner and the cost model price what ``dd.phys_*`` say, and
+        ``make_grow_fn`` is handed the same answer and raises if its
+        own arguments would build the other form."""
+        from ..ops.grow import bundled_comb_eligible
+        gk = self._grow_kwargs
+        self.dd.comb_bundled = (
+            self.dd.bundle is not None and bundled_comb_eligible(
+                self.hp, axis_name=None if learner == "serial" else learner,
+                n_forced=0 if gk.get("forced") is None else 1,
+                interaction_sets=gk.get("interaction_sets"),
+                cegb_coupled=gk.get("cegb_coupled"),
+                cegb_lazy=gk.get("cegb_lazy"),
+                bynode_count=int(gk.get("bynode_count", 0) or 0)))
+
     def _route_inputs(self, learner: str, n_shards: int, dd):
         """RouteInputs snapshot for the ENGAGED learner and FINAL
         device layout (ISSUE 10): the config / dataset / env-knob
@@ -597,9 +625,11 @@ class GBDT:
             learner=learner, n_shards=n_shards,
             backend=_jax.default_backend(),
             efb_bundled=dd.bundle is not None,
-            # LOGICAL bin width decides (ISSUE 12): the physical path
-            # ingests unbundled u8 columns even when a stacked bundle
-            # column stores u16
+            efb_comb=dd.comb_bundled,
+            # the stored columns under the bundled comb; under the
+            # unbundling ingest the LOGICAL bin width decides (the
+            # comb takes unbundled u8 columns even when a stacked
+            # bundle column stores u16)
             bins_u8=dd.phys_bins_u8,
             rows_over_limit=bool(dd.n_pad // n_shards
                                  >= (1 << 24) - PHYS_ROW_SLACK),
@@ -623,13 +653,35 @@ class GBDT:
             cegb_coupled=gk.get("cegb_coupled") is not None,
             **routing_mod.env_snapshot())
         # geometry facts at the width the physical path actually
-        # ingests: the UNBUNDLED logical layout under EFB (ISSUE 12);
-        # rows + leaves let resolve_layout price the footprint against
-        # the HBM budget (over_budget — the ISSUE-15 paging fact)
+        # allocates (bundle columns or the unbundled logical layout, by
+        # the form set above); rows + leaves let resolve_layout price
+        # the footprint against the HBM budget (over_budget — the
+        # ISSUE-15 paging fact)
         return routing_mod.resolve_layout(
             base, f_pad=dd.phys_f_pad, padded_bins=dd.phys_padded_bins,
             rows=dd.n_pad, num_leaves=cfg.num_leaves,
             num_class=max(self.num_tree_per_iteration, 1))
+
+    def layout_info(self) -> Dict:
+        """What the device layout holds, for the ``Train::layout``
+        event of the set-up and for a benchmark's check: the stored bin
+        columns, the logical features they stand for, the EFB bundles
+        among them, and the bytes of one comb line (0 off the physical
+        route, which holds no comb)."""
+        dd, b = self.dd, self.dd.bundle
+        pieces = getattr(self.grow, "_pieces", None)
+        width = pieces.C if pieces is not None else getattr(
+            self.grow, "_C", 0)
+        dtype = (pieces.dtype if pieces is not None
+                 else getattr(self.grow, "_dtype", jnp.float32))
+        return {
+            "phys_cols": int(dd.f_pad),
+            "logical_features": int(dd.num_features),
+            "bundles": 0 if b is None else int(
+                len(np.unique(b["feat_phys"][b["is_bundled"]]))),
+            "comb_cols": int(dd.phys_f_pad),
+            "comb_line_bytes": int(width) * jnp.dtype(dtype).itemsize,
+        }
 
     def routing_info(self) -> Optional[Dict]:
         """The engaged routing decision as a JSON-ready dict (bench
@@ -1653,7 +1705,8 @@ class GBDT:
             host_tas.extend(unpack_tree_arrays(
                 packed, self.config.num_leaves, CHUNK,
                 cat_b=(self.dd.padded_bins_log or self.dd.padded_bins)
-                if self.hp.use_cat_subset else 0)[:n_real])
+                if self.hp.use_cat_subset else 0,
+                side_n=int(chunk[0].side_miss.shape[-1]))[:n_real])
         k = self.num_tree_per_iteration
         stumps_by_iter: Dict[int, List[bool]] = {}
         for (idx, _ta, kidx, init_score, rate), ta in zip(

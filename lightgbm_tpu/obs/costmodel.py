@@ -684,12 +684,14 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
 
     ``rows`` is the real row count unless ``rows_padded`` (then it is
     the already-padded global n_pad).  ``f_pad`` / ``padded_bins`` are
-    the widths the comb and histogram pool work at — the UNBUNDLED
-    logical geometry under EFB (ISSUE 12, ``DeviceDataset.phys_f_pad``)
+    the widths the comb and histogram pool are ALLOCATED at
+    (``DeviceDataset.phys_f_pad`` / ``phys_padded_bins``): under EFB the
+    bundle columns where the bundles stay in the comb (ISSUE 36), the
+    unbundled logical geometry where the ingest unbundles (ISSUE 12)
     — while ``bins_cols`` / ``bins_itemsize`` price the persistent
     device bin matrix itself, which stays BUNDLED (and possibly u16)
-    on the EFB path; they default to the unbundled f_pad at one byte,
-    the no-bundling identity.  Buffer shapes reproduce
+    on the EFB path; they default to f_pad at one byte, the
+    no-bundling identity.  Buffer shapes reproduce
     ops/grow.py's layout decisions exactly:
 
     * comb/scratch are ``n_alloc`` lines of ``C`` lanes where

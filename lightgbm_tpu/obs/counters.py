@@ -5,9 +5,10 @@ while tracing the booster derives them on the host
 (``counters_from_tree``) from one pull of the tree's small arrays after
 the ``Tree::grow`` barrier — no second grow program, no extra
 dispatch, and the same whether the tracer was enabled before the
-booster was built or after.  Four more are not (whether the fused
-scan's histogram hook ran at a split, and which child it was told, is
-forgotten once the split is done): the grow program counts them in its
+booster was built or after.  Six more are not (whether the fused
+scan's histogram hook ran at a split, which child it was told, and
+whether the split was decided by a membership set, is forgotten once
+the split is done): the grow program counts them in its
 state, always, traced or not, and they ride the same pull as
 ``TreeArrays.side_miss``.  Counter semantics:
 
@@ -55,6 +56,15 @@ state, always, traced or not, and they ride the same pull as
                       went through the hook (benchmarks:
                       ``scan_rows_hooked``)
 
+  member_splits     — bundled comb (ISSUE 36): splits whose go-left
+                      bit was a membership test of a bundle column's
+                      bins, i.e. splits on a bundled sub-feature; 0 on
+                      every other route
+  rows_member       — the parent rows of those splits: over
+                      ``rows_partitioned`` the share of the scan's row
+                      visits decided by a membership set (benchmarks:
+                      ``scan_member_share``)
+
 Plus HBM watermark sampling: ``hbm_live_bytes`` is the cheap
 ``jax.live_arrays`` census of live device buffers (catches leaks and
 order-of-magnitude regressions from the host), and
@@ -83,7 +93,8 @@ import numpy as np
 
 COUNTER_NAMES = ("splits", "rows_partitioned", "rows_histogrammed",
                  "fused_splits", "side_miss_splits", "rows_rehistogrammed",
-                 "hook_splits", "rows_hooked")
+                 "hook_splits", "rows_hooked", "member_splits",
+                 "rows_member")
 
 
 def counters_to_dict(vec) -> Dict[str, float]:
@@ -97,13 +108,15 @@ def counters_from_tree(num_leaves, left_child, right_child,
                        side_miss=(0, 0, 0, 0), *,
                        fused: bool) -> np.ndarray:
     """The counter vector (``COUNTER_NAMES`` order) of one finished
-    tree, from its host arrays; ``side_miss`` is the four the grow
-    program counted (``TreeArrays.side_miss``).  Counts are integral
+    tree, from its host arrays; ``side_miss`` is the four (six under
+    the bundled comb) the grow program counted
+    (``TreeArrays.side_miss``).  Counts are integral
     f32 below 2^24 each; sums run in float64, exact far beyond the
     ~n*log2(L) a tree can reach (84M at Higgs 10.5M)."""
     splits = int(num_leaves) - 1
     leaf_c = np.asarray(leaf_count, np.float64)
-    miss = [float(v) for v in np.asarray(side_miss).reshape(4)]
+    miss = [float(v) for v in np.asarray(side_miss).reshape(-1)]
+    miss += [0.0] * (6 - len(miss))     # off the bundled comb: [4]
     if splits <= 0:
         # a stump: the root pass is all the work there was
         return np.array([0.0, 0.0, leaf_c[0], 0.0] + miss)

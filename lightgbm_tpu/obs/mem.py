@@ -69,9 +69,10 @@ def footprint_from_record(rec: Dict[str, Any]) -> Dict[str, Any]:
         stream=bool(shape.get("stream", False)),
         fused=bool(knobs.get("fused", True)),
         n_shards=int(mc.get("n_shards", 1)),
-        # EFB (ISSUE 12): the bin matrix stays bundled while the comb
-        # works at the unbundled f_pad; older records lack the fields
-        # and fall back to the no-bundling identity
+        # EFB: the bin matrix stays bundled; the record's f_pad is the
+        # width the comb was allocated at (bundle columns, or the
+        # unbundled width under the unbundling ingest); older records
+        # lack the fields and fall back to the no-bundling identity
         bins_cols=int(shape.get("bins_cols", 0)),
         bins_itemsize=int(shape.get("bins_itemsize", 1)))
 

@@ -21,6 +21,20 @@ record carrying the schema version; every span line is a valid Chrome
 ``python -m lightgbm_tpu.obs report --chrome out.json`` only has to
 wrap the lines in an array for chrome://tracing / Perfetto.
 
+Set-up, once a booster (both only in a trace enabled before the
+booster is built)::
+
+    Dataset::bundle                     io/dataset_core.py: find_bundles
+                                        over the sampled rows; args
+                                        features_bundled, bundles,
+                                        conflict_rows
+    Train::layout                       models/gbdt.py, closing the device
+                                        layout and the route decision;
+                                        args phys_cols,
+                                        logical_features, bundles,
+                                        comb_cols, comb_line_bytes
+                                        (GBDT.layout_info())
+
 The span tree of one boosting iteration (serial learner, fast path;
 the names the per-layer metrics of ``benchmarks/`` are keyed on)::
 
@@ -36,6 +50,10 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
         GradSlice                       eager grad[k], hess[k]
         GBDT::grow                      utils/timer.py's twin of the next
           Tree::grow                    args: the work counters
+                                        (obs/counters.py COUNTER_NAMES;
+                                        under the bundled comb
+                                        member_splits and rows_member
+                                        are counted by the grow program)
             Tree::grow::wait            the device runs the grow program
             WorkCounters                pull of the tree's small arrays
         HbmCensus
@@ -68,8 +86,9 @@ JAX reports the fetch under this name too) and ``jax::cache_load``
 a load or a retrace says so, inside the span it happened in.
 
 Work counters are derived on the host from the finished tree
-(``obs/counters.counters_from_tree``; four of them are counted by the
-grow program, traced or not, and come with the tree) after the
+(``obs/counters.counters_from_tree``; four of them - six under the
+bundled comb - are counted by the grow program, traced or not, and come
+with the tree) after the
 ``Tree::grow`` barrier, and set as args of that span.
 
 Xplane correlation: while ``tracer.annotate(True)`` — a profiler

@@ -53,8 +53,11 @@ def pad_features_to_shards(f: int, group: int, n_shards: int) -> int:
 
 
 def unbundle_bins(bins: jnp.ndarray, bundle) -> jnp.ndarray:
-    """EFB graduation (ISSUE 12): expand a bundled physical bin block
-    back into one ordinary uint8 column PER LOGICAL FEATURE, on device.
+    """Expand a bundled physical bin block back into one uint8 column
+    PER LOGICAL FEATURE, on device: the comb ingest of the bundled
+    tables whose grow configuration the bundle-space finder does not
+    cover (``grow.bundled_comb_eligible``; the plain configurations
+    keep one comb column a bundle since ISSUE 36).
 
     ``bins`` is the bundled ``[n, F_phys_pad]`` device matrix
     (uint8/uint16 — a stacked bundle column may exceed 255 bins even
@@ -64,12 +67,11 @@ def unbundle_bins(bins: jnp.ndarray, bundle) -> jnp.ndarray:
     j's stacked range ``[offset_j, offset_j + num_bins_j)`` and as j's
     default (most frequent) bin otherwise — the same semantics the
     row_order path's histogram expansion (``grow.expand`` +
-    FixHistogram) applies at histogram level, applied at ROW level
-    once, at ingest.  With zero bundling conflicts (the default
-    ``max_conflict_rate=0.0``) the result is bit-identical to the
-    never-bundled logical bin matrix, which is what makes the physical
-    fast path's bundled-vs-unbundled trees byte-identical
-    (tests/test_efb_physical.py).
+    FixHistogram) and the bundled comb's finder
+    (``split.find_best_split_segments``) apply at histogram level,
+    applied at ROW level once, at ingest.  With zero bundling conflicts
+    (the default ``max_conflict_rate=0.0``) the result is bit-identical
+    to the never-bundled logical bin matrix.
 
     Unbundled features ride the same formula (offset 0, always in
     range); padded logical features (num_bins 0) decode to bin 0.  The
@@ -98,7 +100,8 @@ class DeviceDataset:
     # EFB mapping (None when no bundling): logical feature -> physical
     # column / bin offset / default bin (io/bundle.py BundleInfo, padded)
     bundle: "object" = None    # dict(feat_phys, feat_offset, feat_default,
-                               #      is_bundled, num_bins_log) np arrays
+                               #      is_bundled, num_bins_log, has_nan,
+                               #      is_cat) np arrays
 
     @property
     def f_pad(self) -> int:
@@ -114,33 +117,41 @@ class DeviceDataset:
     def n_pad(self) -> int:
         return self.bins.shape[0]
 
-    # -- physical-path geometry (ISSUE 12, the EFB graduation) --------
-    # The physical fast path ingests the UNBUNDLED layout (one u8
-    # column per logical feature, ``unbundle_bins``), so its width /
-    # bin facts are the LOGICAL ones whenever EFB bundled.  These are
-    # the numbers the routing model (gbdt._route_inputs ->
-    # routing.resolve_layout), the grow build, and the costmodel
-    # footprint all price — sharing them here keeps the three from
-    # ever disagreeing about the post-unbundle geometry.
+    # -- physical-path geometry under EFB -----------------------------
+    # Two comb forms (ISSUE 36).  ``comb_bundled``: one comb column a
+    # BUNDLE, the kernels at the bundled geometry, the finder in bundle
+    # space (``split.find_best_split_segments``) - what gbdt sets when
+    # ``grow.bundled_comb_eligible`` holds.  Otherwise the comb ingests
+    # the UNBUNDLED layout (``unbundle_bins``, ISSUE 12), one column a
+    # logical feature.  These are the numbers the routing model
+    # (gbdt._route_inputs -> routing.resolve_layout), the grow build,
+    # the page planner and the costmodel footprint all price — sharing
+    # them here keeps them from disagreeing about what is allocated.
+    comb_bundled: bool = False
+
     @property
     def phys_f_pad(self) -> int:
-        """Comb column count of the physical path: the unbundled
-        logical width under EFB, the plain padded width otherwise."""
-        return self.f_log if self.bundle is not None else self.f_pad
+        """Comb feature columns of the physical path: the bundle
+        columns under the bundled comb, the unbundled logical width
+        under the unbundling ingest, the plain padded width without
+        EFB."""
+        if self.bundle is None or self.comb_bundled:
+            return self.f_pad
+        return self.f_log
 
     @property
     def phys_padded_bins(self) -> int:
-        """Per-column bin width the physical path's kernels see
-        (always the logical width; equals ``padded_bins`` when no
-        bundling engaged)."""
-        return self.padded_bins_log
+        """Per-column bin width the physical path's kernels see."""
+        return self.padded_bins if self.comb_bundled else \
+            self.padded_bins_log
 
     @property
     def phys_bins_u8(self) -> bool:
-        """Whether the physical path's ingested columns are uint8:
-        the LOGICAL bin width decides under EFB (a stacked bundle
-        column may be u16 while every logical feature fits u8)."""
-        if self.bundle is None:
+        """Whether the physical path's ingested columns are uint8: the
+        stored columns themselves, except under the unbundling ingest,
+        where the LOGICAL bin width decides (a stacked bundle column
+        may be u16 while every logical feature fits u8)."""
+        if self.bundle is None or self.comb_bundled:
             return bool(self.bins.dtype == jnp.uint8)
         return self.padded_bins_log <= 256
 
@@ -163,19 +174,19 @@ def to_device(ds: BinnedDataset, row_pad_multiple: int = 1,
     ``use_bundles=False`` disables the EFB physical layout (the
     feature-parallel learner shards physical columns and needs the
     identity mapping)."""
-    mat = ds.bin_matrix
-    n, f = mat.shape
+    n, f = ds.num_data, ds.num_features
     nbins = ds.num_bins_per_feature
     info = getattr(ds, "bundle_info", None) if use_bundles else None
     if info is not None and not info.any_bundled:
         info = None
     max_bins_log = int(nbins.max()) if f else 16
     if info is not None:
-        from ..io.bundle import build_physical_matrix
-        phys = build_physical_matrix(mat, info)
+        # (from the stored entries of sparse input: the logical
+        # [n, f] matrix is not made)
+        phys = ds.physical_matrix(info)
         max_bins = max(max_bins_log, int(info.phys_num_bins.max()))
     else:
-        phys = mat
+        phys = ds.bin_matrix
         max_bins = max_bins_log
     b = bins_per_feature_padded(max_bins)
     b_log = (bins_per_feature_padded(max_bins_log) if info is not None
@@ -223,6 +234,10 @@ def to_device(ds: BinnedDataset, row_pad_multiple: int = 1,
             "feat_default": np.pad(info.feat_default, (0, f_log_pad - f)),
             "is_bundled": np.pad(info.is_bundled, (0, f_log_pad - f)),
             "num_bins_log": num_bins.copy(),
+            # per logical feature, for the bundle-space finder's
+            # static position maps (split.segment_maps)
+            "has_nan": has_nan.copy(),
+            "is_cat": is_cat.copy(),
         }
 
     put = put_fn if put_fn is not None else jnp.asarray

@@ -76,8 +76,10 @@ class TreeArrays(NamedTuple):
     # the smaller one and the rows re-histogrammed for them; splits at
     # which the hook ran at all (parents under the crossover) and the
     # parent rows it visited.  Global under the mesh learners; zeros
-    # off the fused physical route.  Not part of the model:
-    # models/tree.py does not read it.
+    # off the fused physical route.  i32 [6] under the bundled comb
+    # (``side_n``): then also the splits decided by a membership set of
+    # a bundle column's bins and their parent rows.  Not part of the
+    # model: models/tree.py does not read it.
     side_miss: jnp.ndarray
 
 
@@ -133,6 +135,8 @@ class _GrowState(NamedTuple):
 
 # _GrowState.best column indices
 _BG, _BF, _BB, _BDL, _BCAT, _BLG, _BLH, _BLC, _BLO, _BRO = range(10)
+# ... and, under the bundled comb, the right child's own sums
+_BRG, _BRH = 10, 11
 # _GrowState.lstate column indices
 _SG, _SH, _SC, _SDEP, _SPAR, _SMN, _SMX, _SOUT = range(8)
 
@@ -151,7 +155,10 @@ def chan4(h):
 
 
 def _pack_si(si: "SplitInfo") -> jnp.ndarray:
-    """SplitInfo -> packed best-row [..., 10] (see _GrowState.best)."""
+    """SplitInfo -> packed best-row [..., 10] (see _GrowState.best);
+    [..., 12] from a finder that gives the right child's sums."""
+    own_right = ([] if si.right_sum_g is None
+                 else [si.right_sum_g, si.right_sum_h])
     return jnp.stack([
         si.gain,
         si.feature.astype(jnp.float32),
@@ -160,7 +167,7 @@ def _pack_si(si: "SplitInfo") -> jnp.ndarray:
         si.is_categorical.astype(jnp.float32),
         si.left_sum_g, si.left_sum_h, si.left_count,
         si.left_output, si.right_output,
-    ], axis=-1)
+    ] + own_right, axis=-1)
 
 
 @jax.jit
@@ -177,12 +184,12 @@ def pack_tree_arrays(tas):
 
 
 def unpack_tree_arrays(flat: "jnp.ndarray", num_leaves: int, count: int,
-                       cat_b: int = 0):
+                       cat_b: int = 0, side_n: int = 4):
     """Inverse of pack_tree_arrays: host numpy TreeArrays list."""
     import numpy as np
     L = int(num_leaves)
     ni = L - 1
-    proto = _empty_tree(L, cat_b)
+    proto = _empty_tree(L, cat_b, side_n)
     flat = np.asarray(flat)
     out = []
     pos = 0
@@ -208,7 +215,8 @@ def unpack_tree_arrays(flat: "jnp.ndarray", num_leaves: int, count: int,
     return out
 
 
-def _empty_tree(num_leaves: int, cat_b: int = 0) -> TreeArrays:
+def _empty_tree(num_leaves: int, cat_b: int = 0,
+                side_n: int = 4) -> TreeArrays:
     ni = num_leaves - 1
     zi = lambda k: jnp.zeros((k,), jnp.int32)
     zf = lambda k: jnp.zeros((k,), jnp.float32)
@@ -223,7 +231,7 @@ def _empty_tree(num_leaves: int, cat_b: int = 0) -> TreeArrays:
         num_leaves=jnp.int32(1),
         cat_members=jnp.zeros((ni, cat_b) if cat_b else (1, 1),
                               jnp.float32),
-        side_miss=zi(4),
+        side_miss=zi(side_n),
     )
 
 
@@ -314,6 +322,57 @@ def hist_scatter_eligible(hp, *, bundle=None, voting: bool = False,
             and not (hp.use_monotone and hp.mono_intermediate))
 
 
+def bundled_comb_eligible(hp, *, axis_name=None, n_forced: int = 0,
+                          interaction_sets=None, cegb_coupled=None,
+                          cegb_lazy=None, bynode_count: int = 0) -> bool:
+    """Whether a bundled table keeps its EFB bundles IN the comb on the
+    physical route (ISSUE 36): one comb column a bundle, histograms over
+    bundle columns, the split finder in bundle space
+    (``split.find_best_split_segments``) and the partition through a
+    membership set of the bundle column's bins.  That finder is the
+    plain one, so every grow option that reads a logical histogram row
+    or adds a per-feature term to the gain keeps the unbundling ingest
+    (``device_data.unbundle_bins``), as do the mesh learners, whose
+    set-up and ledger are wired for it.  gbdt decides with it once
+    (``GBDT._set_efb_form`` -> ``dd.comb_bundled``, which the routing
+    decision's ``efb`` field and every priced width read) and
+    make_grow_fn checks the decision it is handed against it; it reads
+    the configuration, never a knob."""
+    return (axis_name is None and not n_forced
+            and interaction_sets is None and cegb_coupled is None
+            and cegb_lazy is None and bynode_count == 0
+            and not (hp.use_cat_subset or hp.use_monotone
+                     or hp.use_smoothing or hp.use_cegb
+                     or hp.use_extra_trees))
+
+
+def bundled_split_members(bun_maps, feat, sbin, cat, nbins, padded_bins):
+    """What the partition scans are told about a split of logical
+    feature ``feat`` at logical bin ``sbin`` under the bundled comb:
+    ``(comb column, the split is on a bundled sub-feature, [padded_bins]
+    bool go-left membership of the column's bins)``.
+
+    A split "logical bin <= sbin" on a bundled sub-feature is a SET of
+    its bundle column's bins (io/bundle.py layout): its own stacked
+    range up to ``sbin`` and, when its default bin is on the left,
+    every bin outside the range - rows that store another sub-feature,
+    or none, sit at this one's default.  An unbundled column keeps its
+    threshold and needs no set; the membership returned for it is the
+    one winning bin of a categorical one-hot split and empty otherwise,
+    because ``sel`` carries the words at every split of the program and
+    the scans read them wherever the categorical flag is up.
+    ``bun_maps`` = (feat_phys, feat_offset, feat_default, is_bundled)
+    device arrays; ``nbins`` the feature's logical bin count."""
+    phys, off, dflt, bundled = bun_maps
+    in_bun = bundled[feat]
+    v = jnp.arange(int(padded_bins), dtype=jnp.int32)
+    o = off[feat]
+    own = (v >= o) & (v < o + nbins)
+    lbin = jnp.where(own, v - o, dflt[feat])
+    member = jnp.where(in_bun, lbin <= sbin, cat & (v == sbin))
+    return phys[feat], in_bun, member
+
+
 def _bucket_sizes(n: int, rows_per_block: int) -> list:
     """Static bucket size classes for the per-split lax.switch: halving
     from n down to a 1024-row floor (deep-tree leaves are small; the
@@ -356,6 +415,11 @@ def make_grow_fn(
                              # takes/returns a [F, n] paid-rows mask
     forced=None,             # dict(leaf, feature, bin, default_left) np arrays
     bundle=None,             # EFB mapping dict (DeviceDataset.bundle)
+    bundled_comb=None,       # the EFB form the caller's route and layout
+                             # were decided for (DeviceDataset.
+                             # comb_bundled); the physical branch builds
+                             # that form or raises.  None: a caller with
+                             # no layout of its own (tools, tests)
     padded_bins_log: int = 0,  # logical bin width (defaults to padded_bins)
     bynode_count: int = 0,   # >0: sample this many features per node
     bynode_seed: int = 0,    # (ColSampler feature_fraction_bynode,
@@ -483,6 +547,7 @@ def make_grow_fn(
     # for bundled datasets) keys on it even after the physical branch
     # consumes the map into its ingest closure
     _src_bundle = bundle
+    _seg_comb = False
     if physical:
         if fax is not None:
             raise ValueError(
@@ -517,19 +582,45 @@ def make_grow_fn(
                     f"{CAT_BITSET_WORDS} words (layout."
                     f"CAT_BITSET_WORDS); the routing model routes this "
                     f"config to the row_order path (rule cat_overwide)")
-        # ---- EFB graduation (ISSUE 12) ----
-        # Bundled datasets ride the physical fast path by UNBUNDLING at
-        # comb ingest: each bundle expands back into its constituent
-        # logical bin columns on device (device_data.unbundle_bins —
-        # per-feature bin offsets subtracted, defaults filled), so the
-        # partition / histogram / split / stream kernels below run
-        # unchanged over ordinary <= 255-bin u8 columns in the LOGICAL
-        # feature domain.  Only the ingest closure keeps the map; every
-        # kernel build and the grow core see bundle=None, which is what
-        # makes bundled and pre-unbundled inputs compile the IDENTICAL
-        # program (the byte-parity contract).
+        # ---- EFB on the physical route: two comb forms ----
+        # Bundled comb (ISSUE 36; bundled_comb_eligible): the comb holds
+        # one column a BUNDLE, every kernel below is built at the
+        # bundled geometry, the bundle map stays with the grow core -
+        # its finder works in bundle space and a split on a bundled
+        # sub-feature reaches the scans as a membership set of the
+        # bundle column's bins (partition_kernel.SEL_MEMBER).  Expo's
+        # 700 one-hot columns are 12 lanes of one plane that way, not
+        # 700 lanes of six.
+        # Unbundling ingest (ISSUE 12), for the grow options the
+        # bundle-space finder does not cover: each bundle expands back
+        # into its logical bin columns on device
+        # (device_data.unbundle_bins), so the kernels and the grow core
+        # see bundle=None and ordinary <= 255-bin columns in the
+        # LOGICAL feature domain.
         _efb_ingest = None
-        if bundle is not None:
+        _seg_comb = bundle is not None and bundled_comb_eligible(
+            hp, axis_name=axis_name, n_forced=n_forced,
+            interaction_sets=interaction_sets, cegb_coupled=cegb_coupled,
+            cegb_lazy=cegb_lazy, bynode_count=bynode_count)
+        if bundled_comb is not None and bool(bundled_comb) != _seg_comb:
+            # the route, the footprint and dd.phys_f_pad would describe
+            # one form and this program be the other
+            raise ValueError(
+                f"the layout was decided for bundled_comb={bundled_comb} "
+                f"but this grow configuration builds the "
+                f"{'bundled comb' if _seg_comb else 'unbundling ingest'} "
+                "(grow.bundled_comb_eligible): the caller's arguments "
+                "differ from the ones the route was decided on")
+        if _seg_comb:
+            f_pad_p = int(physical_bins.shape[1])
+            from .pallas.layout import cat_bitset_fit
+            if not cat_bitset_fit(int(padded_bins)):
+                raise ValueError(
+                    "the bundled comb needs bundle columns of at most "
+                    f"256 bins (got {int(padded_bins)}): a split on a "
+                    "bundled sub-feature is a membership bitset over "
+                    "them (layout.CAT_BITSET_WORDS)")
+        elif bundle is not None:
             _b_log_p = int(padded_bins_log) or int(padded_bins)
             if _b_log_p > 256:
                 # mirrors the non_u8_bins routing rule at the logical
@@ -733,9 +824,19 @@ def make_grow_fn(
             "EFB bundling and the feature-parallel learner are exclusive "
             "(bundles remap physical columns; disable one of them)")
     b_log = int(padded_bins_log) or int(padded_bins)
-    if bundle is None:
-        b_log = int(padded_bins)   # no expansion: widths must agree
-    if bundle is not None:
+    if bundle is None or _seg_comb:
+        # no expansion (the bundled comb's pool and finder stay in
+        # bundle space): widths must agree
+        b_log = int(padded_bins)
+    if _seg_comb:
+        from .split import (find_best_split_segments, segment_maps,
+                            segment_weights)
+        _seg_maps = segment_maps(bundle, f_pad_p, int(padded_bins))
+        _bun_maps = (jnp.asarray(bundle["feat_phys"], jnp.int32),
+                     jnp.asarray(bundle["feat_offset"], jnp.int32),
+                     jnp.asarray(bundle["feat_default"], jnp.int32),
+                     jnp.asarray(bundle["is_bundled"], jnp.bool_))
+    elif bundle is not None:
         # EFB expansion constants (io/bundle.py layout): gather indices from
         # the physical histogram into logical feature space over the
         # (narrower) LOGICAL bin width, plus the default-bin FixHistogram
@@ -863,7 +964,7 @@ def make_grow_fn(
             reconstruct the default bin from the leaf totals (the
             Dataset::FixHistogram trick, dataset.h:676).  Linear in h, so
             the parent-minus-child subtraction commutes with it."""
-            if bundle is None:
+            if bundle is None or _seg_comb:
                 return h
             nch = h.shape[-1]
             tot = jnp.sum(h[0], axis=0)     # leaf totals (any column)
@@ -921,10 +1022,25 @@ def make_grow_fn(
             _et_base = jax.random.fold_in(
                 jax.random.PRNGKey(extra_seed), seed)
 
+        if _seg_comb:
+            # once a tree, outside the split loop: the contraction
+            # weights of the bundle-space finder and the tree's feature
+            # mask by histogram position (the one gather, f_phys x B
+            # elements; the eligible configurations mask by tree only)
+            _seg_w = segment_weights(_seg_maps)
+            _seg_fmask = jnp.take(
+                jnp.concatenate([feature_mask.astype(jnp.float32),
+                                 jnp.zeros((1,), jnp.float32)]),
+                jnp.asarray(_seg_maps["feat"]), mode="wrap")
+
         def finder(hist, sg, sh, cnt, depth, num_bins, has_nan, is_cat,
                    fmask, mn, mx, pout, cegb_pen, rkey):
             allow = (jnp.asarray(True) if max_depth <= 0
                      else (depth < max_depth))
+            if _seg_comb:
+                return find_best_split_segments(
+                    hist, sg, sh, cnt, _seg_maps, _seg_w, _seg_fmask,
+                    allow, hp)
             if scatter_on:
                 # the histogram arrives pre-chunked (psum_scatter);
                 # metadata and masks are global and slice here
@@ -1295,7 +1411,10 @@ def make_grow_fn(
                      if hp.use_extra_trees else None)
         si0 = sync_best(si0)
 
-        f_pool = f_search if scatter_on else f_log
+        # (the bundled comb pools bundle-space histograms: its finder
+        # reads them as they are)
+        f_pool = (f_search if scatter_on
+                  else f if _seg_comb else f_log)
         # pool layout [L, F, 4, B] (channel-second, padded to 4): the
         # pool-resident kernel DMA-slices rows, so the minor dim must be
         # the 128-aligned bin axis and the channel dim a sublane-tile
@@ -1303,7 +1422,8 @@ def make_grow_fn(
         pool = jnp.zeros((L, f_pool, 4, b), jnp.float32).at[0].set(
             chan4(root_hist))
         ni = L - 1
-        best0 = jnp.full((L, 10), -jnp.inf, jnp.float32)
+        best0 = jnp.full((L, 12 if _seg_comb else 10), -jnp.inf,
+                         jnp.float32)
         best0 = best0.at[:, _BF:].set(0.0).at[0].set(_pack_si(si0))
         lstate0 = jnp.zeros((L, 8), jnp.float32)
         lstate0 = lstate0.at[0].set(jnp.stack([
@@ -1343,7 +1463,7 @@ def make_grow_fn(
                    if use_mono_inter else jnp.zeros((1, 1), jnp.float32)),
             paid=(paid_in if use_cegb_lazy
                   else jnp.zeros((1, 1), jnp.bool_)),
-            side_miss=jnp.zeros((4,), jnp.int32),
+            side_miss=jnp.zeros((6 if _seg_comb else 4,), jnp.int32),
         )
 
         def body(i, st: _GrowState) -> _GrowState:
@@ -1439,6 +1559,20 @@ def make_grow_fn(
                 onehot_b = jnp.arange(b, dtype=jnp.int32) == sbin
                 member_f = (jnp.where(is_sub, mem_sub, onehot_b)
                             & cat).astype(jnp.float32)   # [B]
+
+            # what the partition scans are told (partition_kernel.SEL_*):
+            # the comb column, whether the go-left bit is a membership
+            # test, and the membership words when sel carries them
+            sel_col, sel_cat, sel_words = feat, cat, None
+            if physical and hp.use_cat_subset:
+                sel_words = _members_to_words(member_f[None])[0]
+            if _seg_comb:
+                sel_col, in_bun, member_b = bundled_split_members(
+                    _bun_maps, feat, sbin, cat, num_bins[feat],
+                    int(padded_bins))
+                sel_cat = cat | in_bun
+                sel_words = _members_to_words(
+                    member_b.astype(jnp.float32)[None])[0]
 
             if fax is not None:
                 ax_i = jax.lax.axis_index(fax).astype(jnp.int32)
@@ -1626,17 +1760,16 @@ def make_grow_fn(
                                          num_bins[feat] - 1,
                                          jnp.int32(-1))
                     sel = jnp.stack([
-                        s0, jnp.where(done, 0, par_cnt), feat, sbin,
-                        dl.astype(jnp.int32), cat.astype(jnp.int32),
+                        s0, jnp.where(done, 0, par_cnt), sel_col, sbin,
+                        dl.astype(jnp.int32), sel_cat.astype(jnp.int32),
                         nanb_sel, jnp.int32(0)]).astype(jnp.int32)
-                    if hp.use_cat_subset:
+                    if sel_words is not None:
                         # membership bitset rides the descriptor:
                         # ceil(b/32) i32 words appended after the 8
                         # slots (partition_kernel.SEL_MEMBER); zeroed
                         # for numerical splits, one-hot covered by the
                         # single winning bin's bit
-                        sel = jnp.concatenate(
-                            [sel, _members_to_words(member_f[None])[0]])
+                        sel = jnp.concatenate([sel, sel_words])
                     combp, scrp, nleft_ = part_fn(sel, st.comb,
                                                   st.scratch)
                     nlg_ = (jax.lax.psum(nleft_, axis_name)
@@ -1690,17 +1823,16 @@ def make_grow_fn(
                                      jnp.int32(-1))
                 cnt_eff = jnp.where(done, 0, par_cnt)
                 sel = jnp.stack([
-                    s0, cnt_eff, feat, sbin, dl.astype(jnp.int32),
-                    cat.astype(jnp.int32), nanb_sel,
+                    s0, cnt_eff, sel_col, sbin, dl.astype(jnp.int32),
+                    sel_cat.astype(jnp.int32), nanb_sel,
                     jnp.where(direct, SIDE_NONE, jnp.where(
                         pred_left, SIDE_LEFT, SIDE_RIGHT))
                     ]).astype(jnp.int32)
-                if hp.use_cat_subset:
+                if sel_words is not None:
                     # membership bitset rides the descriptor (see the
-                    # bucket path above); sel stays i32[8] with the
-                    # knob off so the compiled program is unchanged
-                    sel = jnp.concatenate(
-                        [sel, _members_to_words(member_f[None])[0]])
+                    # bucket path above); sel stays i32[8] without one
+                    # so the compiled program is unchanged
+                    sel = jnp.concatenate([sel, sel_words])
                 nb_part = jnp.maximum(-(-cnt_eff // _PHYS_R), 1)
                 if _use_fused:
                     # ONE kernel: compaction scan + (under the
@@ -1777,9 +1909,23 @@ def make_grow_fn(
                 # rows_hooked).  A miss needs a hook that ran
                 hooked = ~direct & ~done
                 miss = hooked & (pred_left != small_is_left)
-                side_miss += jnp.stack([
+                hook_inc = jnp.stack([
                     miss.astype(jnp.int32), jnp.where(miss, small_g, 0),
                     hooked.astype(jnp.int32), jnp.where(hooked, par_g, 0)])
+                side_miss += (jnp.pad(hook_inc, (0, 2)) if _seg_comb
+                              else hook_inc)
+            if _seg_comb:
+                # splits, and their parent rows, whose go-left bit was
+                # a membership test of a bundle column's bins
+                # (obs/counters.py: member_splits, rows_member).  The
+                # rows are the leaf record's count, the node's
+                # internal_count, so that the sum is a share of
+                # rows_partitioned, which is summed from those
+                by_set = in_bun & ~done
+                side_miss += jnp.pad(jnp.stack([
+                    by_set.astype(jnp.int32),
+                    jnp.where(by_set, lrow[_SC].astype(jnp.int32), 0)]),
+                    (4, 0))
             h_small = expand(h_small)   # EFB physical -> logical
             rows_parent = par_cnt
 
@@ -1816,6 +1962,8 @@ def make_grow_fn(
                           - leaf_split_gain(pg, ph, hp))
                 gain_rec = jnp.where(use_forced, gain_f, gain_rec)
             rg, rh, rc = pg - lg, ph - lh, pc - lc
+            if _seg_comb:
+                rg, rh = brow[_BRG], brow[_BRH]
 
             if tail_pool:
                 # one Pallas program for the whole split tail INCLUDING

@@ -78,8 +78,17 @@ class RouteInputs:
     efb_bundled: bool = False          # EFB produced bundled columns
     bins_u8: bool = True               # bin matrix fits uint8
     rows_over_limit: bool = False      # per-shard n_pad >= 2^24 - slack
-    efb_overwide: bool = False         # UNBUNDLED f_pad + extras >
-                                       # layout.MAX_COMB_COLS (only
+    efb_comb: bool = False             # the bundles stay IN the comb
+                                       # (grow.bundled_comb_eligible:
+                                       # serial learner, plain finder;
+                                       # only meaningful with
+                                       # efb_bundled)
+    efb_overwide: bool = False         # the comb's f_pad + extras >
+                                       # layout.MAX_COMB_COLS, at the
+                                       # width the engaged EFB form
+                                       # allocates: bundle columns
+                                       # under efb_comb, the UNBUNDLED
+                                       # logical width otherwise (only
                                        # meaningful with efb_bundled)
     fused_ok: bool = True              # fused_supported(f_pad, B)
     f_log_shard_divisible: bool = True
@@ -115,7 +124,8 @@ class RouteInputs:
         return (
             f"learner={self.learner};shards={self.n_shards};"
             f"be={self.backend};"
-            f"efb={b(self.efb_bundled)};u8={b(self.bins_u8)};"
+            f"efb={b(self.efb_bundled)};ebc={b(self.efb_comb)};"
+            f"u8={b(self.bins_u8)};"
             f"over={b(self.rows_over_limit)};"
             f"ew={b(self.efb_overwide)};"
             f"fdiv={b(self.f_log_shard_divisible)};"
@@ -153,15 +163,23 @@ class Rule:
 
 RULES: Tuple[Rule, ...] = (
     # -- physical partition eligibility (gbdt use_phys) ----------------
-    # efb_bundle is GONE (ISSUE 12): bundled datasets unbundle into
-    # ordinary logical bin columns at comb ingest
-    # (device_data.unbundle_bins), so EFB no longer costs the fast
-    # path.  What remains is the narrow shape fact below: a bundle
-    # expansion whose unbundled width blows the comb column budget.
+    # efb_bundle is GONE (ISSUE 12): EFB no longer costs the fast
+    # path.  A bundled table rides it in one of two comb forms, named
+    # by RouteDecision.efb: "bundled" - one comb column a bundle, the
+    # finder in bundle space, the partition through a membership set
+    # (ISSUE 36; the serial learner with the plain finder,
+    # grow.bundled_comb_eligible) - or "unbundled" - the bundles
+    # expanded into logical bin columns at comb ingest
+    # (device_data.unbundle_bins; the mesh learners and the grow
+    # options the bundle-space finder does not cover).  What remains is
+    # the narrow shape fact below, priced at the width the engaged form
+    # allocates: a comb past the column budget.
     Rule("efb_overwide", "physical", "enable_bundle",
-         "unbundling the EFB bundles would widen the comb layout past "
-         "the lane/VMEM column budget (layout.MAX_COMB_COLS); blocks "
-         "that wide cannot stage through VMEM",
+         "the comb of this EFB-bundled table (its bundle columns, or "
+         "its logical columns where the bundles unbundle at ingest) "
+         "would pass the lane/VMEM column budget "
+         "(layout.MAX_COMB_COLS); blocks that wide cannot stage "
+         "through VMEM",
          lambda i: i.efb_bundled and i.efb_overwide, loud=True),
     # cat_subset is GONE (ISSUE 16): sorted-subset categorical splits
     # ride the fast path — membership ships as a bin-indexed bitset of
@@ -320,6 +338,11 @@ class RouteDecision:
     paged_reasons: Tuple[str, ...] = ()  # why a wanted paging fell off
     mc_batched: bool = False    # batched multiclass grow (ISSUE 19)
     mc_batch_reasons: Tuple[str, ...] = ()  # why multiclass is serial-K
+    # the engaged EFB form (ISSUE 36): none (no bundle found) | bundled
+    # (bundle columns in the comb) | unbundled (logical columns in the
+    # comb, expanded at ingest) | expand (row_order: bundled bin matrix,
+    # histograms expanded by grow.expand)
+    efb: str = "none"
     # logical comb rows a 128-lane line: a constant, not a field, since
     # the two-rows-a-line layout went (ISSUE 32).  Kept for its readers
     # - benchmarks/kinds/train.py check_route against expect_route
@@ -337,6 +360,11 @@ class RouteDecision:
             "n_shards": self.n_shards, "hist_merge": self.hist_merge,
             "paged": self.paged,
         }
+        if self.efb != "none":
+            # the EFB form is part of the path of a bundled table; a
+            # table without bundles keeps the digest it always had
+            # (saved checkpoints carry it and refuse another)
+            ident["efb"] = self.efb
         return hashlib.sha256(
             json.dumps(ident, sort_keys=True).encode()).hexdigest()[:12]
 
@@ -348,6 +376,7 @@ class RouteDecision:
             "n_shards": self.n_shards, "hist_merge": self.hist_merge,
             "paged": self.paged,
             "mc_batched": self.mc_batched,
+            "efb": self.efb,
             "reasons": list(self.reasons),
             "merge_reasons": list(self.merge_reasons),
             "paged_reasons": list(self.paged_reasons),
@@ -421,13 +450,16 @@ def decide(i: RouteInputs) -> RouteDecision:
     else:
         hist_merge, merge_reasons = "none", []
 
+    efb = ("none" if not i.efb_bundled
+           else "expand" if not use_phys
+           else "bundled" if i.efb_comb else "unbundled")
     reasons = [r.name for r in
                (phys_block if not use_phys else stream_block)]
     program_key = "|".join([
         path, scheme, f"fused{int(fused)}",
         i.learner, f"shards{i.n_shards}", hist_merge,
         f"dp{int(i.gpu_use_dp)}", f"cegb{int(i.cegb_lazy)}",
-        f"cat{int(i.cat_subset)}", f"efb{int(i.efb_bundled)}",
+        f"cat{int(i.cat_subset)}", f"efb-{efb}",
         f"u8{int(i.bins_u8)}", f"paged{int(paged)}",
         f"mcb{int(mc_batched)}"])
     return RouteDecision(
@@ -437,7 +469,7 @@ def decide(i: RouteInputs) -> RouteDecision:
         merge_reasons=tuple(merge_reasons), program_key=program_key,
         cell=i.key(), paged=paged, paged_reasons=tuple(paged_reasons),
         mc_batched=mc_batched,
-        mc_batch_reasons=tuple(mc_batch_reasons))
+        mc_batch_reasons=tuple(mc_batch_reasons), efb=efb)
 
 
 # ---------------------------------------------------------------------
@@ -491,8 +523,9 @@ def resolve_layout(i: RouteInputs, *, f_pad: int,
     ``fused_ok`` — and, when ``rows`` is given,
     ``over_budget``, the ISSUE-15 paging fact) from the final device
     layout.  ``f_pad`` / ``padded_bins`` are the widths the physical
-    path would INGEST — the unbundled logical geometry under EFB
-    (``DeviceDataset.phys_f_pad`` / ``phys_padded_bins``, ISSUE 12).
+    path's comb and kernels are built at (``DeviceDataset.phys_f_pad``
+    / ``phys_padded_bins``): under EFB the bundle columns where
+    ``efb_comb`` keeps them, the unbundled logical geometry otherwise.
     The stream decision feeds the column count (streaming layouts
     carry extra objective columns), so this runs a provisional
     :func:`decide` first — the geometry never feeds back into the
@@ -954,6 +987,13 @@ def enumerate_inputs() -> List[RouteInputs]:
                                     add(learner=learner,
                                         n_shards=shards,
                                         efb_bundled=efb,
+                                        # the form
+                                        # bundled_comb_eligible gives
+                                        # these cells at run time
+                                        efb_comb=(efb and not cat
+                                                  and not cegb
+                                                  and learner
+                                                  == "serial"),
                                         bins_u8=u8,
                                         cat_subset=cat,
                                         gpu_use_dp=dp,
@@ -984,6 +1024,11 @@ def enumerate_inputs() -> List[RouteInputs]:
                         kw["bins_u8"] = False
                     elif flip is not None:
                         kw[flip] = True
+                    if flip == "efb_bundled" and learner == "serial":
+                        # ... in both comb forms: the plain finder keeps
+                        # the bundles, any other grow option unbundles
+                        add(learner=learner, n_shards=shards,
+                            efb_comb=True, **kw, **env)
                     add(learner=learner, n_shards=shards, **kw, **env)
     # 2. env-knob sweep over the clean base config
     for learner, shards in _LEARNERS:
@@ -1008,6 +1053,14 @@ def enumerate_inputs() -> List[RouteInputs]:
             # path — a bundle expansion past the comb column budget
             add(learner=learner, n_shards=shards, efb_bundled=True,
                 efb_overwide=True, **env)
+            add(learner=learner, n_shards=shards, efb_bundled=True,
+                efb_comb=learner == "serial", efb_overwide=True, **env)
+        # ISSUE 36: a bundled table whose grow options the bundle-space
+        # finder does not cover keeps the unbundling ingest
+        add(learner="serial", n_shards=1, efb_bundled=True,
+            forced_splits=True, **env)
+        add(learner="serial", n_shards=1, efb_bundled=True,
+            mono_intermediate=True, **env)
         add(learner="data", n_shards=8, f_log_shard_divisible=False,
             **env)
         add(learner="data", n_shards=8, forced_splits=True, **env)
@@ -1067,6 +1120,7 @@ def encode_cell(d: RouteDecision) -> str:
     return (f"path={d.path};scheme={d.scheme};"
             f"fused={int(d.fused)};merge={d.hist_merge};"
             f"paged={int(d.paged)};mcb={int(d.mc_batched)};"
+            f"efb={d.efb};"
             f"why={j(d.reasons)};"
             f"merge_why={j(d.merge_reasons)};"
             f"paged_why={j(d.paged_reasons)};"
@@ -1091,6 +1145,7 @@ def decode_cell(enc: str) -> dict:
         "merge": out["merge"],
         "paged": bool(int(out.get("paged", 0))),
         "mc_batched": bool(int(out.get("mcb", 0))),
+        "efb": out.get("efb", "none"),
         "reasons": lists["why"],
         "merge_reasons": lists["merge_why"],
         "paged_reasons": lists["paged_why"],

@@ -29,7 +29,7 @@ prefix scans in both directions) lives in this file too — see
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +89,10 @@ class SplitInfo(NamedTuple):
     left_count: jnp.ndarray    # f32 (row count as float)
     left_output: jnp.ndarray   # f32 constrained/smoothed left-leaf output
     right_output: jnp.ndarray  # f32
+    # the right child's sums where the finder has them on their own
+    # (find_best_split_segments); None: the parent's minus the left's
+    right_sum_g: Optional[jnp.ndarray] = None
+    right_sum_h: Optional[jnp.ndarray] = None
 
 
 # Winner SELECTION compares gains at reduced precision: the low
@@ -569,4 +573,163 @@ def find_best_split(
         left_count=blc,
         left_output=b_lo,
         right_output=b_ro,
+    )
+
+
+# ---------------------------------------------------------------------
+# the finder over BUNDLE-SPACE histograms (EFB kept in the comb)
+# ---------------------------------------------------------------------
+def segment_maps(bundle, f_phys: int, padded_bins: int):
+    """Static per-position maps of a bundled histogram ``[f_phys,
+    padded_bins]`` (io/bundle.py layout): bin ``q`` of physical column
+    ``p`` is logical bin ``q - offset_j`` of the one logical feature j
+    whose stacked range ``[offset_j, offset_j + num_bins_j)`` holds it
+    (an unbundled column is its feature's range at offset 0).  Numpy
+    dict of ``[f_phys, padded_bins]`` arrays:
+
+      feat   i32  owning logical feature, -1 outside every range
+      lbin   i32  logical bin of the position
+      cat    bool the feature is categorical (one-hot candidates)
+      fix    bool bundled feature and lbin >= its default bin: the
+                  left side of threshold lbin holds the default bin,
+                  which no row stores - its rows sit in the column's
+                  other bins, and ``segment_weights`` sums them there
+                  (the reference rebuilds it from the leaf totals:
+                  Dataset::FixHistogram, dataset.h:676)
+      nan    bool the position is its feature's NaN bin
+      valid  bool [2, ...] candidate of direction d (0: missing right
+                  and categorical one-hot; 1: missing left)
+      rank   i32  [2, ...] the feature-major tie-break order of
+                  ``find_best_split`` (feature, then direction, then
+                  bin)
+
+    ``bundle`` carries ``has_nan`` / ``is_cat`` per logical feature
+    (``device_data.to_device``); absent, no feature has either."""
+    import numpy as np
+    P, B = int(f_phys), int(padded_bins)
+    phys = np.asarray(bundle["feat_phys"], np.int64)
+    off = np.asarray(bundle["feat_offset"], np.int64)
+    dflt = np.asarray(bundle["feat_default"], np.int64)
+    nbl = np.asarray(bundle["num_bins_log"], np.int64)
+    bundled = np.asarray(bundle["is_bundled"], bool)
+    f_log = len(phys)
+    has_nan = np.asarray(bundle.get("has_nan", np.zeros(f_log, bool)), bool)
+    is_cat = np.asarray(bundle.get("is_cat", np.zeros(f_log, bool)), bool)
+    feat = np.full((P, B), -1, np.int64)
+    for j in np.flatnonzero(nbl > 0):
+        feat[phys[j], off[j]:off[j] + nbl[j]] = j
+    own = feat >= 0
+    fj = np.maximum(feat, 0)
+    lbin = np.where(own, np.arange(B)[None, :] - off[fj], 0)
+    cat = own & is_cat[fj]
+    nanf = own & has_nan[fj]
+    fix = own & bundled[fj] & (lbin >= dflt[fj])
+    nan = nanf & (lbin == nbl[fj] - 1)
+    max_t = nbl[fj] - 2 - nanf
+    num_valid = own & ~cat & (lbin <= max_t)
+    valid = np.stack([num_valid | cat, num_valid & nanf])
+    b_rank = int(max(nbl.max(), 1))
+    rank = np.stack([fj * (2 * b_rank) + d * b_rank + lbin
+                     for d in (0, 1)])
+    i32 = lambda a: a.astype(np.int32)  # noqa: E731
+    return {"feat": i32(feat), "lbin": i32(lbin), "cat": cat, "fix": fix, "nan": nan, "valid": valid,
+            "rank": i32(np.where(valid, rank, 1 << 30))}
+
+
+def segment_weights(maps):
+    """The ``[2, D, f_phys, B, B]`` f32 0/1 matrices that turn a
+    bundle-space histogram row into BOTH sides of every candidate with
+    one batched contraction and no gather (built on device from the
+    small maps, once a tree): ``side[s, p, q] = sum_q' h[p, q'] w[s, p,
+    q', q]``, ``s`` 0 the left and 1 the right.  Every row of a leaf
+    sits in exactly one bin of a column - a bundled feature's rows at
+    its default sit in another member's bins, or in bin 0 - so a
+    candidate's two sides are two SETS of the column's bins and each is
+    summed on its own: no side is the leaf totals minus the other, and
+    a small child cut from a large parent keeps the sum of its own
+    rows.  Numerical candidate ``q``: the left is the prefix of its
+    feature's segment up to ``q`` and, where the default bin is on the
+    left (``fix``), every bin outside the segment; categorical: the bin
+    itself; the right is the rest of the column.  ``w[:, 1]`` moves the
+    feature's NaN bin to the left (missing goes left)."""
+    seg = jnp.asarray(maps["feat"])     # a feature is one segment
+    cat = jnp.asarray(maps["cat"])[:, None, :]
+    fix = jnp.asarray(maps["fix"])[:, None, :]
+    nan = jnp.asarray(maps["nan"])
+    b = seg.shape[1]
+    same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, :, None] >= 0)
+    qi = jnp.arange(b, dtype=jnp.int32)
+    le = qi[:, None] <= qi[None, :]                    # q' <= q
+    eye = qi[:, None] == qi[None, :]
+    left0 = jnp.where(cat, same & eye, (same & le) | (~same & fix))
+    lefts = [left0]
+    if maps["valid"][1].any():      # else no NaN bin: one direction
+        lefts.append(left0 | (same & nan[:, :, None] & ~cat))
+    left = jnp.stack(lefts)
+    return jnp.stack([left, ~left]).astype(jnp.float32)
+
+
+def find_best_split_segments(
+    hist: jnp.ndarray,        # [f_phys, B, 2] bundle-space (grad, hess)
+    sum_g, sum_h, count,      # scalar leaf totals
+    maps,                     # segment_maps (static numpy)
+    weights: jnp.ndarray,     # segment_weights(maps), [2, D, f_phys, B, B]
+    pos_mask: jnp.ndarray,    # [f_phys, B] f32: feature_mask by position
+    allow_split, hp: SplitHyperParams,
+) -> SplitInfo:
+    """``find_best_split`` over a BUNDLE-SPACE histogram: the same
+    candidates (every threshold of every logical feature, both missing
+    directions, categorical one-hot), the same gains and the same
+    feature-major quantized election, with each logical feature's
+    prefix sums taken inside its static segment of the bundle column
+    and the rows at its default bin found in the column's other bins -
+    what ``grow.expand`` + ``find_best_split`` give, without
+    materialising (or gathering) the ``[f_log, b_log]`` logical
+    histogram, and with each side of a candidate summed on its own
+    (``segment_weights``).  The plain finder only: no monotone /
+    smoothing / CEGB / extra-trees / sorted-subset terms
+    (``grow.bundled_comb_eligible``)."""
+    d_all = weights.shape[1]
+    h2 = jnp.moveaxis(hist, -1, -2)                     # [P, 2, B]
+    side = jnp.einsum("pcq,sdpqr->sdcpr", h2, weights,
+                      precision=jax.lax.Precision.HIGHEST)
+    lg, lh = side[0, :, 0], side[0, :, 1]               # [D, P, B]
+    rg, rh = side[1, :, 0], side[1, :, 1]
+    lc = derived_counts(lh, count, sum_h)
+    rc = count - lc
+    min_data = jnp.float32(hp.min_data_in_leaf)
+    ok = (
+        jnp.asarray(maps["valid"][:d_all])
+        & (lc >= min_data) & (rc >= min_data)
+        & (lh >= hp.min_sum_hessian_in_leaf)
+        & (rh >= hp.min_sum_hessian_in_leaf)
+        & (pos_mask[None] > 0)
+        & allow_split
+    )
+    gains = (leaf_split_gain(lg, lh, hp) + leaf_split_gain(rg, rh, hp)
+             - leaf_split_gain(sum_g, sum_h, hp) - hp.min_gain_to_split)
+    flat = jnp.where(ok, gains, -jnp.inf).reshape(-1)
+    qflat = selection_key(flat)
+    rank = jnp.asarray(maps["rank"][:d_all]).reshape(-1)
+    best = jnp.argmin(jnp.where(qflat >= jnp.max(qflat), rank,
+                                jnp.int32((1 << 30) + 1))).astype(jnp.int32)
+    pos = best % (rank.shape[0] // d_all)
+    # no valid candidate: every gain is -inf and the election falls on
+    # position 0, which may belong to no feature
+    feat = jnp.maximum(jnp.asarray(maps["feat"]).reshape(-1)[pos], 0)
+    blg, blh = lg.reshape(-1)[best], lh.reshape(-1)[best]
+    brg, brh = rg.reshape(-1)[best], rh.reshape(-1)[best]
+    return SplitInfo(
+        gain=flat[best],
+        feature=feat,
+        threshold_bin=jnp.asarray(maps["lbin"]).reshape(-1)[pos],
+        default_left=best >= rank.shape[0] // d_all,
+        is_categorical=jnp.asarray(maps["cat"]).reshape(-1)[pos],
+        left_sum_g=blg,
+        left_sum_h=blh,
+        left_count=lc.reshape(-1)[best],
+        left_output=calculate_leaf_output(blg, blh, hp),
+        right_output=calculate_leaf_output(brg, brh, hp),
+        right_sum_g=brg,
+        right_sum_h=brh,
     )

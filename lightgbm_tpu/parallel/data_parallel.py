@@ -123,9 +123,11 @@ class DataParallelGrower:
             self.fused = pieces.fused
             self._f_pad = int(pieces.f_pad)
             self._bins_global = physical_bins
-            # EFB (ISSUE 12): the merge collectives move LOGICAL-width
-            # histograms once the ingest unbundles, so the ledger
-            # prices that width, not the bundled storage width
+            # EFB under the mesh learners is the unbundling ingest
+            # (grow.bundled_comb_eligible keeps the bundled comb to the
+            # serial learner): the merge collectives move LOGICAL-width
+            # histograms, so the ledger prices that width, not the
+            # bundled storage width
             if pieces.padded_bins:
                 self._padded_bins = int(pieces.padded_bins)
             self._sharded_core = jax.jit(jax.shard_map(

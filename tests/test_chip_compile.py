@@ -451,6 +451,80 @@ def test_hand_off_compiles_to_compares_not_gathers(stream, n, one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * 4 * n
 
 
+def _expo_bundle(f_log_pad=704):
+    """The bundle map of ``expo-onehot``'s shape: six one-hot fields of
+    12 / 31 / 7 / 22 / 313 / 313 two-bin columns in ten bundle columns
+    of at most 255 bins, and the two numeric columns unbundled."""
+    import numpy as np
+    phys = np.zeros(f_log_pad, np.int32)
+    off = np.zeros(f_log_pad, np.int32)
+    nb = np.zeros(f_log_pad, np.int32)
+    bundled = np.zeros(f_log_pad, bool)
+    j, col = 0, 2
+    for levels in (12, 31, 7, 22, 313, 313):
+        o = 1
+        for _ in range(levels):
+            if o + 2 > 255:
+                col, o = col + 1, 1
+            phys[j], off[j], nb[j], bundled[j] = col, o, 2, True
+            o += 2
+            j += 1
+        col += 1
+    phys[698:700], nb[698:700] = (0, 1), 255
+    assert col == 12
+    return {"feat_phys": phys, "feat_offset": off, "is_bundled": bundled,
+            "feat_default": np.zeros(f_log_pad, np.int32),
+            "num_bins_log": nb, "has_nan": np.zeros(f_log_pad, bool),
+            "is_cat": np.zeros(f_log_pad, bool)}
+
+
+def test_the_bundled_comb_grow_program_compiles_at_the_expo_shape(
+        one_chip, no_compile_cache, monkeypatch):
+    """ISSUE 36: the WHOLE grow program of ``expo-train-10m`` (stream,
+    fused, 255 leaves, 10M rows, 700 logical columns in 12 bundle
+    columns of one comb plane) through the v5e compiler.  The comb is
+    Higgs's: 512 B a line, one ``lgbm_split_scan`` - told its split by
+    the 8 + 8-word descriptor, the membership set riding it - the
+    comb-direct ``lgbm_hist`` in the one conditional, no comb-sized
+    copy; the finder is the XLA tail in bundle space (no
+    ``lgbm_apply_find``, no ``[704, 256]`` logical histogram anywhere),
+    and the pool is ``[255, 16, 4, 256]``."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.grow import make_grow_fn
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    from lightgbm_tpu.ops.split import SplitHyperParams
+    n, f, f_log = 10_000_384, 16, 704
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    gp = make_grow_fn(
+        SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
+        padded_bins=BINS, padded_bins_log=BINS, bundle=_expo_bundle(f_log),
+        physical_bins=sds((n, f), jnp.uint8),
+        stream={"kind": "binary", "sigmoid": 1.0, "count": n})
+    assert gp.fused and gp._f_pad == f and gp._C == 128
+    assert gp._ingest is None           # nothing unbundles
+    comb = comb_shape(gp._n_alloc, gp._C)
+    args = [sds(comb, jnp.float32)] * 2 + [sds((1,), jnp.float32)] * 3 + [
+        sds((f_log,), jnp.float32), sds((f_log,), jnp.int32),
+        sds((f_log,), jnp.bool_), sds((f_log,), jnp.bool_),
+        sds((), jnp.int32), sds((), jnp.float32),
+        sds((f, BINS, 2), jnp.float32)]
+    compiled = gp._grow_p.lower(*(
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+        for a in args)).compile()
+    text = compiled.as_text()
+    _assert_one_scan_no_comb_copy(text, comb)
+    scan = re.search(r"%lgbm_split_scan(?:\.\d+)? = [^\n]*", text).group(0)
+    assert "s32[16]{0}" in scan         # sel + 8 membership words
+    assert "lgbm_apply_find" not in text
+    assert f"f32[{LEAVES},{f},4,{BINS}]" in text
+    assert not re.search(rf"f32\[(?:\d+,)*{f_log},{BINS}[,\]]", text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < comb[0] * comb[1] * 4 // 8
+
+
 def test_the_finder_at_the_msltr_width_is_the_xla_tail():
     """144 columns x 256 bins is past the Pallas finder's scoped-VMEM
     budget (apply_find.tail_supported), so that route's split finder is

@@ -319,13 +319,17 @@ def test_page_schedule_scales_with_num_class():
     assert "error" in tiny
 
 
-def test_footprint_equals_grow_jaxpr_efb():
-    """EFB cell of the matrix (ISSUE 12): the comb prices at the
-    UNBUNDLED logical width while the persistent bin matrix prices at
-    the (narrower, possibly u16) bundled storage width.  Builds the
-    SAME synthetic cell the analyzer registers (`grow_physical_efb`),
-    so the parity guarantee covers the geometry the lane/vmem/hbm
-    passes price."""
+@pytest.mark.parametrize("form", ["bundled", "unbundled"])
+def test_footprint_equals_grow_jaxpr_efb(form):
+    """EFB cells of the matrix: the comb and the histogram pool price at
+    the width the engaged form allocates - the bundle columns where the
+    bundles stay in the comb (ISSUE 36, the plain finder), the
+    UNBUNDLED logical width under the unbundling ingest (ISSUE 12; here
+    extra_trees) - while the persistent bin matrix prices at the
+    bundled storage width either way.  Builds the SAME synthetic cells
+    the analyzer registers (`grow_physical_efb`,
+    `grow_physical_efb_unbundled`), so the parity guarantee covers the
+    geometry the lane/vmem/hbm passes price."""
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.entries import efb_demo_geometry
@@ -334,13 +338,17 @@ def test_footprint_equals_grow_jaxpr_efb():
 
     bundle, geo = efb_demo_geometry()
     n, f_log, f_phys = geo["n"], geo["f_log"], geo["f_phys"]
-    L, b_log = geo["num_leaves"], geo["padded_bins_log"]
-    gp = make_grow_fn(SplitHyperParams(min_data_in_leaf=2),
+    L = geo["num_leaves"]
+    f_comb, b_comb = ((f_phys, geo["padded_bins"]) if form == "bundled"
+                      else (f_log, geo["padded_bins_log"]))
+    gp = make_grow_fn(SplitHyperParams(min_data_in_leaf=2,
+                                       use_extra_trees=form == "unbundled"),
                       num_leaves=L, padded_bins=geo["padded_bins"],
-                      padded_bins_log=b_log, bundle=bundle,
+                      padded_bins_log=geo["padded_bins_log"], bundle=bundle,
                       physical_bins=_sds((n, f_phys), jnp.uint8))
+    assert gp._f_pad == f_comb
     fp = costmodel.grow_footprint(
-        rows=n, f_pad=f_log, padded_bins=b_log, num_leaves=L,
+        rows=n, f_pad=f_comb, padded_bins=b_comb, num_leaves=L,
         rows_padded=True, bins_cols=f_phys, bins_itemsize=1)
     geo = fp["geometry"]
     assert geo["n_alloc"] == gp._n_alloc
@@ -362,11 +370,12 @@ def test_footprint_equals_grow_jaxpr_efb():
         buf = fp["buffers"][name]
         assert buf["shape"] == tuple(invars[idx].shape), name
         assert buf["bytes"] == _aval_bytes(invars[idx]), name
-    # the histogram arena is the LOGICAL [L, f_log, 4, 32] pool
+    # the histogram arena: [L, 8 bundle columns, 4, 48] or the LOGICAL
+    # [L, 16, 4, 32] pool
     all_avals = {(tuple(a.shape), str(a.dtype))
                  for a in _all_avals(traced)}
     pool = fp["buffers"]["hist_pool"]
-    assert pool["shape"] == (L, f_log, 4, b_log)
+    assert pool["shape"] == (L, f_comb, 4, b_comb)
     assert (pool["shape"], "float32") in all_avals, \
         f"pool {pool['shape']} not in the traced EFB grow program"
 

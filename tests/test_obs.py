@@ -379,8 +379,8 @@ def test_counters_from_tree_by_hand():
     """The numbers on a tree small enough to check by eye: root (100
     rows) splits 60 | 40, then the 60 splits 45 | 15.  Leaves are
     encoded ~leaf; padding past ``num_leaves`` is ignored.  The last
-    four are the grow program's own count (``TreeArrays.side_miss``),
-    handed through."""
+    six are the grow program's own count (``TreeArrays.side_miss``: four
+    of them, six under the bundled comb), handed through."""
     from lightgbm_tpu.obs import counters_from_tree
     tree = dict(left_child=[1, -1, 7, 7], right_child=[-2, -3, 7, 7],
                 internal_count=[100.0, 60.0, 999.0, 999.0],
@@ -388,16 +388,21 @@ def test_counters_from_tree_by_hand():
     got = counters_from_tree(3, **tree, fused=True)
     # rows_histogrammed: the root pass + min(60, 40) + min(45, 15)
     assert got.tolist() == [2.0, 160.0, 100.0 + 40.0 + 15.0, 2.0, 0.0,
-                            0.0, 0.0, 0.0]
+                            0.0, 0.0, 0.0, 0.0, 0.0]
     # the hook ran at the second split only (its parent's 60 rows are
     # under the crossover), and its record named the 45: the 15 were
     # read again
     got = counters_from_tree(3, **tree, side_miss=np.array([1, 15, 1, 60]),
                              fused=True)
-    assert got.tolist()[4:] == [1.0, 15.0, 1.0, 60.0]
+    assert got.tolist()[4:] == [1.0, 15.0, 1.0, 60.0, 0.0, 0.0]
     assert len(got) == len(COUNTER_NAMES)
+    # under the bundled comb the program counts six: the last two are
+    # the splits decided by a membership set and their parent rows
+    got = counters_from_tree(
+        3, **tree, side_miss=np.array([0, 0, 0, 0, 1, 100]), fused=True)
+    assert got.tolist()[4:] == [0.0, 0.0, 0.0, 0.0, 1.0, 100.0]
     stump = counters_from_tree(1, [0], [0], [0.0], [77.0], fused=False)
-    assert stump.tolist() == [0.0, 0.0, 77.0] + [0.0] * 5
+    assert stump.tolist() == [0.0, 0.0, 77.0] + [0.0] * 7
 
 
 # ---------------------------------------------------------------------

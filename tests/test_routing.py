@@ -465,8 +465,14 @@ SERIAL_CELLS = [
     ("two_plane_physical", {"LGBM_TPU_PHYS": "interpret",
                             "LGBM_TPU_STREAM": "0"}, {}, "wide",
      "physical", {"stream_env_off"}),
-    ("efb_two_plane", {"LGBM_TPU_PHYS": "interpret"}, {}, "wide_onehot",
-     "stream", set()),
+    # (extra_trees: a grow option the bundle-space finder does not
+    # cover, so the bundles unbundle at ingest, into two planes)
+    ("efb_two_plane", {"LGBM_TPU_PHYS": "interpret"},
+     {"extra_trees": True}, "wide_onehot", "stream", set()),
+    # ISSUE 36: with the plain finder the same table keeps its bundles
+    # in the comb, one plane
+    ("efb_kept_one_plane", {"LGBM_TPU_PHYS": "interpret"}, {},
+     "wide_onehot", "stream", set()),
 ]
 
 
@@ -479,7 +485,13 @@ def test_runtime_parity_serial(name, env, params, data, path, reasons):
     assert reasons <= set(out["routing"]["reasons"]), out["routing"]
     if data.endswith("onehot"):
         assert out["bundled"], "EFB did not engage; cell is vacuous"
-    if data.startswith("wide"):
+    if data.endswith("onehot") and path != "row_order":
+        kept = not params.get("extra_trees")
+        assert out["routing"]["efb"] == ("bundled" if kept
+                                         else "unbundled")
+    if name == "efb_kept_one_plane":
+        assert out["comb_C"] == 128, "the bundles did not stay bundled"
+    elif data.startswith("wide"):
         assert out["comb_C"] == 256, "one plane; cell is vacuous"
     _assert_matches_matrix(out)
     # loud config fallbacks recorded as structured events
