@@ -213,8 +213,6 @@ ENV_KNOBS: Dict[str, tuple] = {
     "LGBM_TPU_PARTITION": ("permute", "single-scan partition packing: "
                                       "permute (O(log R) rounds) or "
                                       "matmul ([R,R] one-hot)"),
-    "LGBM_TPU_PART_R": ("512", "partition block rows for the "
-                               "single-scan kernel"),
     "LGBM_TPU_PART_INTERP": ("off", "kernel runs the REAL scan/copyback "
                                     "bodies through the Pallas "
                                     "interpreter off-TPU"),

@@ -281,8 +281,9 @@ class GBDT:
                 # psum_scatter merges (the reference's parallel learners
                 # template over the serial device kernels,
                 # data_parallel_tree_learner.cpp:279-281).  Rows pad to
-                # a whole partition block PER SHARD.
-                from ..ops.grow import PHYS_R
+                # whole partition blocks PER SHARD (the largest block
+                # the scan can take: the layout is not final yet).
+                from ..ops.grow import PHYS_ROW_PAD
                 binfo_nb = binfo is None or not binfo.any_bundled
                 # pre-layout routing probe (ISSUE 10): whether the
                 # physical mesh path is still in play decides the row
@@ -308,7 +309,7 @@ class GBDT:
                     # grower boundary stays process-local.
                     from jax.experimental import multihost_utils
                     ldev = n_sh // _jax.process_count()
-                    mult = ldev * (PHYS_R if phys_mesh else 1)
+                    mult = ldev * (PHYS_ROW_PAD if phys_mesh else 1)
                     local_need = -(-ds.num_data // mult) * mult
                     all_need = multihost_utils.process_allgather(
                         np.asarray([local_need], np.int64))
@@ -339,8 +340,8 @@ class GBDT:
                 else:
                     self._pre_part = False
                     self.dd = to_device(
-                        ds, row_pad_multiple=(n_sh * PHYS_R if phys_mesh
-                                              else n_sh),
+                        ds, row_pad_multiple=(n_sh * PHYS_ROW_PAD
+                                              if phys_mesh else n_sh),
                         col_shard_multiple=(n_sh if scat else 1),
                         put_fn=_row_put)
                 _build_constraints(self.dd)
@@ -390,8 +391,8 @@ class GBDT:
                 # kernel's block multiple up front so the physical
                 # partition mode can reuse this layout without a second
                 # to_device pass
-                from ..ops.grow import PHYS_R
-                self.dd = to_device(ds, row_pad_multiple=PHYS_R)
+                from ..ops.grow import PHYS_ROW_PAD
+                self.dd = to_device(ds, row_pad_multiple=PHYS_ROW_PAD)
                 _build_constraints(self.dd)
                 self._set_efb_form("serial")
                 # path selection (ISSUE 10): the declarative routing
@@ -1571,17 +1572,22 @@ class GBDT:
         fused = (bool(getattr(self.grow, "fused", False))
                  and jax.default_backend() == "tpu")
         batched = np.ndim(small[0]) > 0
+        scan_r = int(getattr(self.grow, "scan_block_rows", 0))
+        shards = int(getattr(self.grow, "num_shards", 1)) if scan_r else 1
         total: Dict[str, float] = {}
         for kidx in kidxs:
             arrs = tuple(a[kidx] for a in small) if batched else small
-            d = obs_counters.record(
-                obs_counters_from_tree(*arrs, fused=fused))
+            d = obs_counters.record(obs_counters_from_tree(
+                *arrs, fused=fused, scan_block_rows=scan_r,
+                shards=shards))
             for name, val in d.items():
                 obs_tracer.count(name, val, kidx=kidx)
                 total[name] = total.get(name, 0.0) + val
         mesh_args = getattr(self.grow, "tree_span_args", None)
         if mesh_args is not None:
             total.update(mesh_args(total.get("splits", 0.0), len(kidxs)))
+        if scan_r:
+            total["scan_block_rows"] = scan_r
         span.set(**total)
 
     def _async_tail_fn(self):

@@ -647,18 +647,21 @@ PEAK_HOST_BW_ENV = "LGBM_TPU_PEAK_HOST_BW_GBPS"
 DEFAULT_PEAK_HOST_BW_GBPS = 32.0   # PCIe-class host<->HBM staging BW
 
 
-def _phys_r_and_slack():
-    """(PHYS_R, PHYS_ROW_SLACK) from the loaded grow generation (lazy:
-    grow.py reads LGBM_TPU_PART_R at import)."""
-    from ..ops.grow import PHYS_R, PHYS_ROW_SLACK
-    return int(PHYS_R), int(PHYS_ROW_SLACK)
+def _phys_pad_and_slack():
+    """(PHYS_ROW_PAD, PHYS_ROW_SLACK): the block a shard's rows pad to
+    and the lines the comb carries past them - both sized for the
+    largest block the scan can take, so neither depends on the one it
+    takes or on the backend asked from (lazy: keeps this module
+    import-light)."""
+    from ..ops.grow import PHYS_ROW_PAD, PHYS_ROW_SLACK
+    return int(PHYS_ROW_PAD), int(PHYS_ROW_SLACK)
 
 
 def pad_rows(rows: int, n_shards: int = 1) -> int:
     """Global padded row count the physical layout allocates for
     ``rows`` real rows over ``n_shards`` row shards (to_device's
-    row_pad_multiple = n_shards * PHYS_R)."""
-    r, _ = _phys_r_and_slack()
+    row_pad_multiple = n_shards * grow.PHYS_ROW_PAD)."""
+    r, _ = _phys_pad_and_slack()
     mult = max(int(n_shards), 1) * r
     return -(-int(rows) // mult) * mult
 
@@ -716,7 +719,7 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
       per-generation budget.
     """
     from ..ops.pallas.layout import comb_layout
-    phys_r, slack = _phys_r_and_slack()
+    phys_r, slack = _phys_pad_and_slack()
     n_shards = max(int(n_shards), 1)
     n_pad = int(rows) if rows_padded else pad_rows(rows, n_shards)
     if n_pad % n_shards:
@@ -725,8 +728,9 @@ def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
     n_local = n_pad // n_shards
     if n_local % phys_r:
         raise ValueError(
-            f"per-shard rows {n_local} not a multiple of the partition "
-            f"block R={phys_r} (pass real rows, or pad to the layout)")
+            f"per-shard rows {n_local} not a multiple of the largest "
+            f"partition block, {phys_r} rows (pass real rows, or pad to "
+            f"the layout)")
     if stream:
         from ..ops.pallas.stream_grad import N_CONSTS, stream_columns
         n_extra = stream_columns(stream_kind)
@@ -854,7 +858,7 @@ def page_schedule(*, rows: int, f_pad: int, padded_bins: int = 256,
     refresh+root pass, at ``LGBM_TPU_PEAK_HOST_BW_GBPS`` (PCIe-class
     staging, not the on-chip HBM roofline).
     """
-    phys_r, slack = _phys_r_and_slack()
+    phys_r, slack = _phys_pad_and_slack()
     limit = int(limit_bytes or hbm_limit_bytes())
     host_bw = float(host_bw_gbps
                     or os.environ.get(PEAK_HOST_BW_ENV,
@@ -907,10 +911,16 @@ def page_schedule(*, rows: int, f_pad: int, padded_bins: int = 256,
         rpp = (budget_for_pages // (3 * lrb)) - slack
         rpp = max((rpp // phys_r) * phys_r, phys_r)
     else:
+        # the planner's own pages are whole blocks of the largest scan
+        # step (above); a caller's need only be whole blocks of the
+        # smallest - the grower, which knows the block its scan takes,
+        # refuses what does not divide by that one (ops/grow.py)
+        from ..ops.pallas.layout import SCAN_ROWS_MIN
         rpp = int(rows_per_page)
-        if rpp % phys_r:
+        if rpp % SCAN_ROWS_MIN:
             raise ValueError(
-                f"rows_per_page must be a multiple of R={phys_r}")
+                f"rows_per_page must be a multiple of the partition "
+                f"block, at least {SCAN_ROWS_MIN} rows")
     n_pages = -(-geo["n_local"] // rpp)
     levels = max(int(num_leaves - 1).bit_length(), 1)
     sweeps = levels + 1      # per-level partition passes + fused refresh

@@ -1,6 +1,6 @@
 """Training work counters and live-buffer watermarks.
 
-Four work counters of a tree are functions of the finished tree, so
+Five work counters of a tree are functions of the finished tree, so
 while tracing the booster derives them on the host
 (``counters_from_tree``) from one pull of the tree's small arrays after
 the ``Tree::grow`` barrier — no second grow program, no extra
@@ -64,6 +64,19 @@ state, always, traced or not, and they ride the same pull as
                       ``rows_partitioned`` the share of the scan's row
                       visits decided by a membership set (benchmarks:
                       ``scan_member_share``)
+  scan_steps        — physical routes: grid steps of the partition scan,
+                      the sum over the tree's splits of ``ceil(parent
+                      rows / R)`` at the ``R`` rows a step moves
+                      (``partition_kernel2.scan_block_rows``; the
+                      ``Tree::grow`` span carries it as
+                      ``scan_block_rows``); under the mesh learners of
+                      ``shards x ceil(parent rows / shards / R)``, from
+                      the replicated record's global counts, as if the
+                      rows lay evenly.  ``rows_partitioned`` over it is
+                      the rows a step really moved (benchmarks:
+                      ``scan_rows_per_step``): what a larger step wastes
+                      on a parent's last partial block shows there.  0
+                      off the physical routes
 
 Plus HBM watermark sampling: ``hbm_live_bytes`` is the cheap
 ``jax.live_arrays`` census of live device buffers (catches leaks and
@@ -94,7 +107,7 @@ import numpy as np
 COUNTER_NAMES = ("splits", "rows_partitioned", "rows_histogrammed",
                  "fused_splits", "side_miss_splits", "rows_rehistogrammed",
                  "hook_splits", "rows_hooked", "member_splits",
-                 "rows_member")
+                 "rows_member", "scan_steps")
 
 
 def counters_to_dict(vec) -> Dict[str, float]:
@@ -106,11 +119,14 @@ def counters_to_dict(vec) -> Dict[str, float]:
 def counters_from_tree(num_leaves, left_child, right_child,
                        internal_count, leaf_count,
                        side_miss=(0, 0, 0, 0), *,
-                       fused: bool) -> np.ndarray:
+                       fused: bool, scan_block_rows: int = 0,
+                       shards: int = 1) -> np.ndarray:
     """The counter vector (``COUNTER_NAMES`` order) of one finished
     tree, from its host arrays; ``side_miss`` is the four (six under
     the bundled comb) the grow program counted
-    (``TreeArrays.side_miss``).  Counts are integral
+    (``TreeArrays.side_miss``); ``scan_block_rows`` the rows a grid
+    step of the partition scan moves (0: no scan) on each of
+    ``shards`` row shards.  Counts are integral
     f32 below 2^24 each; sums run in float64, exact far beyond the
     ~n*log2(L) a tree can reach (84M at Higgs 10.5M)."""
     splits = int(num_leaves) - 1
@@ -119,7 +135,7 @@ def counters_from_tree(num_leaves, left_child, right_child,
     miss += [0.0] * (6 - len(miss))     # off the bundled comb: [4]
     if splits <= 0:
         # a stump: the root pass is all the work there was
-        return np.array([0.0, 0.0, leaf_c[0], 0.0] + miss)
+        return np.array([0.0, 0.0, leaf_c[0], 0.0] + miss + [0.0])
     int_c = np.asarray(internal_count, np.float64)[:splits]
 
     def child_count(child):
@@ -130,9 +146,12 @@ def counters_from_tree(num_leaves, left_child, right_child,
                         int_c[np.clip(c, 0, splits - 1)])
 
     smaller = np.minimum(child_count(left_child), child_count(right_child))
+    steps = 0.0
+    if scan_block_rows:
+        steps = shards * np.ceil(int_c / (shards * scan_block_rows)).sum()
     # node 0 is the root: its count is the root pass
     return np.array([splits, int_c.sum(), int_c[0] + smaller.sum(),
-                     splits if fused else 0] + miss, np.float64)
+                     splits if fused else 0] + miss + [steps], np.float64)
 
 
 class CounterStore:

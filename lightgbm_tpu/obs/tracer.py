@@ -54,6 +54,9 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
                                         under the bundled comb
                                         member_splits and rows_member
                                         are counted by the grow program)
+                                        and scan_block_rows, the rows a
+                                        grid step of the partition scan
+                                        moves (scan_steps counts them)
             Tree::grow::wait            the device runs the grow program
             WorkCounters                pull of the tree's small arrays
         HbmCensus

@@ -503,21 +503,23 @@ def load_xspace(path: str, prefer_tf: bool = True) -> XSpace:
 # "scan_kernel" and the copyback name contains "kernel", so the fused /
 # copyback rows must precede partition_scan.  Patterns are substring
 # matches on the lowercased op name — Mosaic custom-calls carry the
-# kernel function names from ops/pallas/*.py.
+# ``name=`` of their pallas_call (``lgbm_*`` since PR 27), older captures
+# the kernel function names from ops/pallas/*.py.
 KERNEL_CLASSES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     # serve_kernel contains "kernel" and the inference dispatch names
     # carry "serve", so the serving traversal row precedes every
     # training class (ISSUE 18)
     ("serve_traverse", ("serve_traverse", "serve_kernel")),
-    ("fused_split", ("fused_scan_kernel", "fused_split")),
+    ("fused_split", ("fused_scan_kernel", "fused_split",
+                     "lgbm_split_scan")),
     ("partition_copyback", ("copyback",)),
     ("partition_scan", ("scan_kernel", "partition_kernel",
                         "partition")),
     # refresh_hist_kernel contains "hist_kernel": stream_refresh
     # must be classified before hist_build
     ("stream_refresh", ("refresh_hist_kernel", "refresh_kernel",
-                        "init_kernel", "stream_grad")),
-    ("hist_build", ("hist2", "hist_kernel", "histogram")),
+                        "init_kernel", "stream_grad", "lgbm_refresh")),
+    ("hist_build", ("hist2", "hist_kernel", "histogram", "lgbm_hist")),
     ("find_split", ("apply_find",)),
     ("collective", ("all-reduce", "all-gather", "all-to-all",
                     "reduce-scatter", "collective-permute",

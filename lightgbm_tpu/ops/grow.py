@@ -235,14 +235,12 @@ def _empty_tree(num_leaves: int, cat_b: int = 0,
     )
 
 
-# physical-mode partition kernel selection + block size.
-# LGBM_TPU_PART_R overrides the single-scan kernel's block rows.
+# physical-mode partition kernel selection.
 # LGBM_TPU_PARTITION selects the single-scan kernel's per-block
 # compaction: "permute" (default — butterfly-routing permutation,
 # O(log R)/row, partition_kernel3) or "matmul" (the [R, R] one-hot
 # contraction, O(R)/row, partition_kernel2) — bit-identical packed
-# layouts, so trees match byte-for-byte across the knob (tpu_smoke
-# partition-identity gate).
+# layouts at equal block rows.
 # LGBM_TPU_FUSED=0 disables the fused partition+histogram split kernel
 # (and the fused refresh+root-histogram in stream mode), restoring the
 # separate partition / child-histogram pallas_call pair per split.
@@ -250,6 +248,9 @@ def _empty_tree(num_leaves: int, cat_b: int = 0,
 # REAL scan/copyback kernels through the Pallas interpreter instead of
 # the stable XLA emulation (compiled row order; equivalence-matrix
 # tests use it to pin cross-scheme identity at kernel depth).
+# The rows a grid step of the scan moves are no knob: the builder takes
+# them from the comb's width and the scoped VMEM a kernel gets
+# (partition_kernel2.scan_block_rows, ISSUE 37).
 import os as _os_mod
 PARTITION_IMPL = _os_mod.environ.get("LGBM_TPU_PARTITION", "permute")
 if PARTITION_IMPL not in ("permute", "matmul"):
@@ -258,17 +259,23 @@ if PARTITION_IMPL not in ("permute", "matmul"):
         f"{PARTITION_IMPL!r}")
 PART_INTERP = _os_mod.environ.get("LGBM_TPU_PART_INTERP", "")
 FUSED_IMPL = _os_mod.environ.get("LGBM_TPU_FUSED", "1")
-PHYS_R = int(_os_mod.environ.get("LGBM_TPU_PART_R", "512"))
-# physical-mode row slack: partition DMA tails (2 * PHYS_R — the
-# single-scan kernel's right-zone scratch writes start one block past
-# s0 and round up to a full block) + two comb-direct histogram blocks
-# (2 * 2048 rows); callers gating on the 2^24 row-id limit must
-# subtract this (gbdt use_phys decision).  The histogram term was also
-# what covered a 3 * PHYS_R spill bound of a row format that is gone
-# (ISSUE 32); the value stays, because n_alloc is part of every
-# compiled program's shapes and of the measured memory peak - whether
-# it can be smaller is ROADMAP C12.
-PHYS_ROW_SLACK = 2 * PHYS_R + 2 * 2048
+# What is sized before the device layout is final - and with it before
+# the scan's block rows are known - is sized for the largest block the
+# scan can take (layout.py states both arguments), on every backend:
+# rows pad to whole PHYS_ROW_PAD blocks (a shard's rows under the mesh
+# learners: to_device's row_pad_multiple), which every smaller block
+# divides, and the comb and its scratch carry PHYS_ROW_SLACK lines past
+# them, a constant, so ``n_alloc`` - part of every compiled program's
+# shapes and of the measured memory peak - moves neither with the block
+# the scan takes nor with the backend the planner is asked from.
+from .pallas.layout import COMB_ROW_SLACK as PHYS_ROW_SLACK
+from .pallas.layout import SCAN_ROWS_MAX as PHYS_ROW_PAD
+
+
+# rows a grid step of the stream route's init / refresh kernels takes
+# (stream_grad.py: BlockSpec-pipelined passes over [0, n_pad), their
+# own kernels with their own block; it divides every scan block)
+_STREAM_R = 512
 
 
 _HIST_SCATTER_WARNED = set()
@@ -653,12 +660,7 @@ def make_grow_fn(
                 "comb-direct histogram kernel accumulates f32; disable "
                 "one of them)")
         _part_kernel_interp = PART_INTERP == "kernel"
-        _PHYS_R = PHYS_R
         n_rows_p = int(physical_bins.shape[0])   # LOCAL rows (per shard)
-        if n_rows_p % _PHYS_R != 0:
-            raise ValueError(
-                f"physical mode needs n_pad % {_PHYS_R} == 0 "
-                f"(got {n_rows_p}); pass row_pad_multiple to to_device")
         if stream is not None:
             from .pallas.stream_grad import stream_columns
             _n_extra = stream_columns(stream["kind"])
@@ -713,10 +715,10 @@ def make_grow_fn(
         else:
             from .pallas.partition_kernel2 import \
                 make_partition_ss as make_partition
-        # slack rows: partition DMA tails (_PHYS_R) + the comb-direct
-        # histogram's window (ceil rounding + one alignment block =
-        # up to 2 extra histogram blocks); keep PHYS_ROW_SLACK in sync
-        _HIST_RPB = 2048
+        # slack lines: the longest kernel tail past the padded rows
+        # (layout.COMB_ROW_SLACK: the scan's right zone + the
+        # copy-back's tail block in the scratch)
+        from .pallas.layout import HIST_COMB_ROWS as _HIST_RPB
         _n_alloc = n_rows_p + PHYS_ROW_SLACK
         if _n_alloc >= (1 << 24):
             # row ids ride in three f32 byte columns and are decoded with
@@ -730,11 +732,6 @@ def make_grow_fn(
             # mismatch means the planner and the grower disagree about
             # the engaged layout — refuse loudly rather than stream
             # wrong-shaped pages
-            _rpp = int(paged["rows_per_page"])
-            if _rpp % _PHYS_R:
-                raise ValueError(
-                    f"rows_per_page={_rpp} must be a multiple of the "
-                    f"partition block R={_PHYS_R} (LGBM_TPU_PAGE_ROWS)")
             if (int(paged.get("C", _C_PHYS)) != _C_PHYS
                     or int(paged.get("n_alloc", _n_alloc)) != _n_alloc):
                 raise ValueError(
@@ -758,8 +755,28 @@ def make_grow_fn(
         from .pallas.hist_kernel2 import hist_geometry
         from .pallas.partition_kernel import (SIDE_LEFT, SIDE_NONE,
                                               SIDE_RIGHT)
-        _use_fused = (FUSED_IMPL != "0"
-                      and _fs.fused_supported(f_pad_p, int(padded_bins)))
+        _use_fused = (FUSED_IMPL != "0" and _fs.fused_supported(
+            f_pad_p, int(padded_bins), _C_PHYS))
+        # rows a grid step of the scan moves: from the comb's width and
+        # the scoped VMEM (one function, equal on every shard, and the
+        # same for the fused scan and the pair behind LGBM_TPU_FUSED=0:
+        # a bisection knob must leave a leaf's rows in the same order).
+        # Off the chip no grid step is there to amortise: the XLA
+        # reference partition has no block, and the interpreter seam
+        # (LGBM_TPU_PART_INTERP=kernel), whose cost is the unrolled
+        # routing it traces, runs the smallest.
+        _PHYS_R = _fs.SCAN_ROWS_MIN if _phys_interp else \
+            _fs.scan_block_rows(_C_PHYS, scheme=PARTITION_IMPL)
+        if n_rows_p % _PHYS_R != 0:
+            raise ValueError(
+                f"physical mode needs n_pad % {_PHYS_R} == 0 (got "
+                f"{n_rows_p}); pass row_pad_multiple=grow.PHYS_ROW_PAD to "
+                f"to_device")
+        if paged is not None and int(paged["rows_per_page"]) % _PHYS_R:
+            raise ValueError(
+                f"rows_per_page={paged['rows_per_page']} must be a "
+                f"multiple of the partition block R={_PHYS_R} "
+                f"(LGBM_TPU_PAGE_ROWS)")
         # looked up on the module at build time (tests patch it)
         _hook_cross = _fs.hook_crossover_rows(
             f_pad_p // hist_geometry(int(padded_bins))[1])
@@ -800,14 +817,14 @@ def make_grow_fn(
                 kind=stream["kind"],
                 sigmoid=float(stream.get("sigmoid", 1.0)),
                 f=f_pad_p, n_alloc=_n_alloc, n_pad=n_rows_p, C=_C_PHYS,
-                R=_PHYS_R, interpret=_phys_interp, dtype=_COMB_DT,
+                R=_STREAM_R, interpret=_phys_interp, dtype=_COMB_DT,
                 root_hist=_fused_root, padded_bins=int(padded_bins),
                 root_rpb=rows_per_block)
             _stream_init_fn = make_init(
                 kind=stream["kind"],
                 sigmoid=float(stream.get("sigmoid", 1.0)),
                 f_real=f_pad_p, f=f_pad_p, n_alloc=_n_alloc,
-                n_pad=n_rows_p, C=_C_PHYS, R=_PHYS_R,
+                n_pad=n_rows_p, C=_C_PHYS, R=_STREAM_R,
                 interpret=_phys_interp, dtype=_COMB_DT)
     if use_voting and fax is not None:
         raise ValueError("voting and feature-parallel modes are exclusive")
@@ -2378,7 +2395,8 @@ def make_grow_fn(
                 core=grow_p_raw, n_alloc=_n_alloc, C=_C_PHYS,
                 f_pad=f_pad_p, n_local=n_rows_p, dtype=_COMB_DT,
                 fused=_use_fused, ingest=_efb_ingest,
-                padded_bins=int(padded_bins))
+                padded_bins=int(padded_bins),
+                scan_block_rows=_PHYS_R)
         # donation: the carried comb/scratch matrices alias their
         # outputs (the whole point of the in-place design), and the
         # fused-root carry donates the [f_pad, B, 2] root histogram
@@ -2462,7 +2480,8 @@ def make_grow_fn(
                          if stream is not None else None),
             dtype=_COMB_DT, fused=_use_fused,
             root0_fn=_root0_fn, ingest=_efb_ingest,
-            paged_plan=paged, reanchor_fn=_reanchor_fn))
+            paged_plan=paged, reanchor_fn=_reanchor_fn,
+            scan_block_rows=_PHYS_R))
 
     if use_cegb_lazy:
         @jax.jit
@@ -2506,6 +2525,7 @@ class MeshPhysicalPieces(NamedTuple):
     padded_bins: int = 0    # engaged per-column bin width (LOGICAL
                             # under EFB) — what the mesh caller prices
                             # histogram-merge collectives with
+    scan_block_rows: int = 0    # rows a grid step of the scan moves
 
 
 def phys_init_comb(bins_local, n_alloc: int, C: int, f_pad: int,
@@ -2546,7 +2566,7 @@ class _PhysicalGrow:
     def __init__(self, grow_p, bins_dev, n_alloc, C, f_pad,
                  stream_init=None, dtype=jnp.float32, fused=False,
                  root0_fn=None, ingest=None,
-                 paged_plan=None, reanchor_fn=None):
+                 paged_plan=None, reanchor_fn=None, scan_block_rows=0):
         self._grow_p = grow_p
         self._bins_dev = bins_dev
         # EFB (ISSUE 12): the carried bins stay BUNDLED (the smaller
@@ -2563,6 +2583,9 @@ class _PhysicalGrow:
         self._stream_aux_fn = None   # set by gbdt before the first tree
         self._stream_rate_fn = None  # () -> current shrinkage rate
         self.fused = fused           # fused partition+histogram splits
+        # rows a grid step of the scan moves (obs: Tree::grow's
+        # scan_block_rows / scan_steps)
+        self.scan_block_rows = int(scan_block_rows)
         self._root0_fn = root0_fn    # fused stream: tree-0 root hist
         self._root_hist = None       # fused stream: carried root hist
         # paged comb (ISSUE 15): pages live host-side between trees and

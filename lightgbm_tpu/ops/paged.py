@@ -24,7 +24,8 @@ page ``p+1`` transfer issued while page ``p`` computes:
 
 Geometry comes from ``obs/costmodel.page_schedule`` (the PR-9
 planner): pages are ``rows_per_page`` logical rows (a multiple of the
-partition block R) plus the PHYS_ROW_SLACK tail each page buffer
+partition block R; the planner's own are whole blocks of the largest,
+grow.PHYS_ROW_PAD) plus the PHYS_ROW_SLACK tail each page buffer
 carries for kernel DMA tails, so the partition / hist / stream /
 fused kernels — already dynamic-grid scans over row blocks — extend
 their grid over pages instead of being rewritten.

@@ -54,11 +54,12 @@ only when it finishes - under the mesh learners after a psum), and on
 a split where the estimate named the wrong one - a split close to
 even - it takes the comb-direct histogram too.  Accumulating BOTH
 children and throwing one away is not free on the v5e: there is no DMA
-shadow for it to ride under.  A 512-row step moves ~1.5 KB a row, 0.94
-us at 819 GB/s, and takes 5.0 us with the hook (9.8 ns a row visit;
-5.9 us with both sides): the scan is bound by what it computes in
-VMEM, and a side's contraction is ~1.2k of a step's VLIW bundles
-(PERF.md, Findings, PR 28 and PR 30).
+shadow for it to ride under.  At 512 rows a step (PRs 28 and 30) a
+step moved ~1.5 KB a row, 0.94 us at 819 GB/s, and took 5.0 us with
+the hook (9.8 ns a row visit; 5.9 us with both sides): the scan is
+bound by what it computes in VMEM, and a side's contraction is ~1.2k
+of such a step's VLIW bundles (PERF.md, Findings, PR 28 and PR 30;
+the rows a step moves now come from ``scan_block_rows``, PR 37).
 
 Layout/contract: identical to partition_kernel2.make_partition_ss, plus
 ``f_pad`` value/bin column conventions from hist_kernel2's comb-direct
@@ -68,9 +69,11 @@ interpret builder COMPOSES the reference implementations (the XLA
 reference partition + comb-direct histogram of the named child's
 range) so off-TPU tests exercise the fused orchestration with exactly
 the unfused arithmetic.  On the chip the hook sums a child's rows in
-the parent's 512-row blocks and the comb-direct kernel in the child's
-own 2048-row ones: same bf16 operands, same f32 accumulation, other
-groupings, so last bits can differ between the two.
+the parent's scan blocks (``scan_block_rows``: 1,024-2,048 rows) and
+the comb-direct kernel in the child's own 2048-row ones: same bf16
+operands, same f32 accumulation, other groupings, so last bits can
+differ between the two - as they can between two block sizes of the
+scan, which order a leaf's rows differently.
 """
 from __future__ import annotations
 
@@ -85,43 +88,52 @@ from .hist_kernel2 import _LO_N, _diag_extract, _hist_accumulate, \
     build_histogram_comb, hist_geometry
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, SEL_SIDE, \
     SIDE_LEFT, SIDE_NONE, _go_left, make_reference_partition
-from .partition_kernel2 import _scan_kernel, copyback_call
+from .layout import COMB_ROW_SLACK, COPYBACK_ROWS, HIST_COMB_ROWS, \
+    SCAN_ROWS_MIN
+from .partition_kernel2 import SCAN_VMEM_LIMIT, _scan_kernel, \
+    copyback_call, scan_block_rows, scan_vmem_bytes
 
 _CHANNELS = 2       # (grad, hess) — the 2-channel histogram layout
 
-# VMEM budget, priced as TWO resident [ngroups, M, N] accumulators
-# although the one-sided hook keeps one.  ROADMAP C9: the price is
-# stale, but the predicate decides routes, so it is re-priced together
-# with the routing matrix in the PR that does A3.1.  (The scan's four
-# [R, C] buffers, the permute compaction's three scoped ones - routing
-# word + two staging blocks, 768 KB at R = 512 - and the per-block
-# one-hot temporaries ride on top; cap conservatively below
-# apply_find's scoped-VMEM limit.)
-_HIST_VMEM_CAP = 32 * 1024 * 1024
-
-
-def fused_supported(f_pad: int, b: int) -> bool:
-    """Whether the fused kernel's resident histogram accumulator fits
-    the VMEM budget (grow falls back to the separate partition+hist
-    pair above it).  Mirrors hist_kernel2's geometry constraints."""
-    b_hi, g, m, nn = hist_geometry(b, _CHANNELS)
+def hook_acc_bytes(f_pad: int, b: int) -> int:
+    """Bytes of the hook's resident [ngroups, M, N] f32 accumulator for
+    ``f_pad`` columns of ``b`` padded bins; 0 where the geometry has no
+    whole number of feature groups (no hook can be built)."""
+    _, g, m, nn = hist_geometry(b, _CHANNELS)
     if b % _LO_N != 0 or f_pad % g != 0:
-        return False
-    ngroups = f_pad // g
-    return 2 * ngroups * m * nn * 4 <= _HIST_VMEM_CAP
+        return 0
+    return (f_pad // g) * m * nn * 4
+
+
+def fused_supported(f_pad: int, b: int, C: int) -> bool:
+    """Whether the fused kernel can be built for ``f_pad`` columns of
+    ``b`` padded bins on a comb of ``C`` lanes: the hook's geometry has
+    whole feature groups (hist_kernel2's constraint) and the scan's
+    smallest block fits the scoped VMEM at ``scan_block_rows``'s own
+    price (grow falls back to the separate partition + histogram pair
+    where not).  The accumulator and its output buffer are not on that
+    stack (partition_kernel2.SCAN_VMEM_LIMIT); at the widest comb the
+    price admits, 896 lanes, they are 2 x 13.9 MiB of the chip's 128."""
+    return bool(hook_acc_bytes(f_pad, b)) and \
+        scan_vmem_bytes(SCAN_ROWS_MIN, C) <= SCAN_VMEM_LIMIT
 
 
 # Per-split cost of the two ways to the smaller child's histogram, as
 # tools/profile_fused.py reads them on the v5e at an even split (the
 # child is half the parent: the comb-direct way's worst case), fitted
-# as fixed + rows x slope over leaves of 1k to 4M rows (PERF.md,
-# Findings, PR 35: the table and the chip call it came from).
+# as fixed + rows x slope over leaves of 1k to 1M rows AT THE BLOCK
+# ``scan_block_rows`` gives each width, so the rows a step moves ride
+# on ``ngroups`` in the two measurements as the planes do (PERF.md,
+# Findings, PR 37: the table and the chip call it came from; PR 35 read
+# 10.57 / 2.815 and 39.20 / 9.135 at 512 rows a step).
 # ngroups -> (us a split, ns a parent row) by which the comb-direct
 # way's fixed cost exceeds the hook's, and the hook's row cost the
 # comb-direct way's.
 _CROSSOVER_MEASURED = {
-    4: (10.57, 2.815),      # 32 columns, one plane (``higgs``): 3,755 rows
-    18: (39.20, 9.135),     # 144 columns, two planes (``msltr``): 4,291
+    4: (8.05, 1.974),       # 32 columns, one plane (``higgs``), 2,048
+                            # rows a step: 4,078 rows
+    18: (48.43, 8.858),     # 144 columns, two planes (``msltr``), 1,024
+                            # rows a step: 5,468
 }
 HOOK_ALWAYS = (1 << 31) - 1     # no i32 row count is past it
 
@@ -137,11 +149,14 @@ def hook_crossover_rows(ngroups: int) -> int:
     one more launch (and its own extraction) + child rows x the same
     contraction in larger blocks, and both grow with ``ngroups``.  The
     gaps are measured at two geometries and taken linear in ``ngroups``
-    between and beyond them (the comb's planes ride on ``ngroups`` in
-    the two measurements - 1 at 4 groups, 2 at 18 - and are not
-    separated)."""
+    BETWEEN them; outside them the nearer measurement's pair serves as
+    it is (a line through two points reads 0 rows at one group, which
+    nothing measured; ``expo``'s 2 groups take ``higgs``'s 4,078 rows).
+    The comb's planes and the scan's block ride on ``ngroups`` in the
+    two measurements - 1 plane and 2,048 rows at 4 groups, 2 and 1,024
+    at 18 - and are not separated."""
     (g0, (f0, r0)), (g1, (f1, r1)) = sorted(_CROSSOVER_MEASURED.items())
-    t = (ngroups - g0) / (g1 - g0)
+    t = min(max((ngroups - g0) / (g1 - g0), 0.0), 1.0)
     fixed_us = f0 + t * (f1 - f0)
     row_ns = r0 + t * (r1 - r0)
     if row_ns <= 0.0:
@@ -166,7 +181,7 @@ def _side_flag(sel_ref, go_left, go_right):
 
 def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
                        rows_ref, scratch_ref, out_ref, hist_ref,
-                       vx0, vx1, pk0, pk1, cursor,
+                       vx, pk, cursor,
                        sem_r, sem_wl, sem_wr,
                        *, R: int, C: int, n: int, f_pad: int, b_hi: int,
                        g: int, lo_n: int, ngroups: int, pack_impl=None):
@@ -216,16 +231,18 @@ def _fused_scan_kernel(sel_ref, rows_in, scratch_in,
 
     _scan_kernel(sel_ref, rows_in, scratch_in,
                  rows_ref, scratch_ref, out_ref,
-                 vx0, vx1, pk0, pk1, cursor,
+                 vx, pk, cursor,
                  sem_r, sem_wl, sem_wr,
                  R=R, C=C, n=n, init_cb=_hist_init, block_cb=_hist_block,
                  pack_impl=pack_impl)
 
 
 def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
-                     R: int = 512, size: int = 0, dtype=jnp.float32,
+                     R: int = SCAN_ROWS_MIN, size: int = 0,
+                     dtype=jnp.float32,
                      interpret: bool = False, dynamic: bool = False,
-                     cb_block: int = 2048, hist_rpb: int = 2048,
+                     cb_block: int = COPYBACK_ROWS,
+                     hist_rpb: int = HIST_COMB_ROWS,
                      scan: str = "permute",
                      interpret_kernel: bool = False,
                      fused_kernel_interpret: bool = False,
@@ -348,10 +365,8 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                        jax.ShapeDtypeStruct((2,), jnp.int32),
                        jax.ShapeDtypeStruct((ngroups, m, nn),
                                             jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((R, C), dtype),
-                            pltpu.VMEM((R, C), dtype),
-                            pltpu.VMEM((R, C), dtype),
-                            pltpu.VMEM((R, C), dtype),
+            scratch_shapes=[pltpu.VMEM((2, R, C), dtype),
+                            pltpu.VMEM((2, R, C), dtype),
                             pltpu.SMEM((8,), jnp.int32),
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SemaphoreType.DMA,
@@ -384,9 +399,10 @@ from ...analysis.registry import partition_args, register_kernel
                  note="fused partition+child-histogram scan "
                       "(LGBM_TPU_FUSED default path)")
 def _analysis_fused():
-    n, C, f, b = 7168, 128, 16, 32
-    fn = make_fused_split(n, C, f_pad=f, padded_bins=b, R=512,
-                          size=2048)
+    n, C, f, b = 2048 + COMB_ROW_SLACK, 128, 16, 32
+    fn = make_fused_split(
+        n, C, f_pad=f, padded_bins=b, size=2048,
+        R=scan_block_rows(C))
     return fn, partition_args(n, C)
 
 
@@ -394,7 +410,8 @@ def _analysis_fused():
                  note="fused scan, cat-subset bitset sel (ISSUE 16)")
 def _analysis_fused_cat():
     from .layout import CAT_BITSET_WORDS
-    n, C, f, b = 7168, 128, 16, 32
-    fn = make_fused_split(n, C, f_pad=f, padded_bins=b, R=512,
-                          size=2048)
+    n, C, f, b = 2048 + COMB_ROW_SLACK, 128, 16, 32
+    fn = make_fused_split(
+        n, C, f_pad=f, padded_bins=b, size=2048,
+        R=scan_block_rows(C))
     return fn, partition_args(n, C, sel_words=CAT_BITSET_WORDS)

@@ -53,6 +53,46 @@ LANE = 128          # TPU minor-dim tile: every HBM row DMA moves
 # Mosaic's VMEM allocator on chip.
 MAX_COMB_COLS = 16 * LANE
 
+# Rows a grid step of the single-scan partition moves (ISSUE 37).  The
+# scan takes the largest power of two in [SCAN_ROWS_MIN, SCAN_ROWS_MAX]
+# whose buffers fit its scoped VMEM (partition_kernel2.scan_block_rows:
+# it rides on the comb's width and the histogram hook's accumulator,
+# both known only once the device layout is final), so what has to be
+# sized BEFORE the layout exists is sized for the largest block: rows
+# pad to a whole number of SCAN_ROWS_MAX blocks (a shard's rows, under
+# the mesh learners; grow.PHYS_ROW_PAD), and every smaller power of two
+# divides them.  The upper end is the permute
+# compaction's: both sides' log2(R)-bit routing words share one 23-bit
+# word (partition_kernel3._BIAS).
+SCAN_ROWS_MIN = 512
+SCAN_ROWS_MAX = 2048
+COPYBACK_ROWS = 2048    # rows a step of the copy-back moves (pure DMA)
+HIST_COMB_ROWS = 2048   # rows a step of the comb-direct histogram reads
+
+# Lines the comb and its scratch carry past the padded rows, ``n_alloc
+# - n_pad``, callers gating on the 2^24 row-id limit must subtract this
+# (gbdt's use_phys decision).  Every kernel that runs past the last
+# row's block does so in ONE of the two arrays, so the slack is the
+# largest of the tails, not their sum (ROADMAP C12):
+#   * the SCRATCH: the scan's right zone grows down from T = s0 +
+#     (ceil(cnt / R) + 1) * R < s0 + cnt + 2 R (partition_kernel2's
+#     docstring: the + R headroom keeps every full-R write >= s0), and
+#     the copy-back reads the span [T - m, T) in whole COPYBACK_ROWS
+#     blocks, so its tail block reads up to T + COPYBACK_ROWS - 1:
+#     2 R + COPYBACK_ROWS lines past s0 + cnt <= n_pad;
+#   * the COMB: the scan reads and writes whole R-row blocks of [s0,
+#     s0 + cnt): under R lines past; the copy-back's tail block
+#     read-merges COPYBACK_ROWS lines from its start inside the span:
+#     under COPYBACK_ROWS; the comb-direct histogram reads
+#     ceil(count / HIST_COMB_ROWS) + 1 whole blocks from the block its
+#     range starts in, under 2 HIST_COMB_ROWS past (and clamps its
+#     window to the array's whole blocks: hist_kernel2._comb_hist_call).
+# At the largest R the scratch's tail is the longest: 6,144 lines.  A
+# constant, so n_alloc - every compiled program's shape - does not
+# depend on which R the scan took.
+COMB_ROW_SLACK = max(2 * SCAN_ROWS_MAX + COPYBACK_ROWS,
+                     2 * HIST_COMB_ROWS)
+
 # Categorical bitset budget (ISSUE 16, the cat-subset graduation).  A
 # sorted-subset categorical split ships its membership as ceil(B/32)
 # i32 words appended to the 8-slot SMEM split descriptor (sel becomes

@@ -48,7 +48,7 @@ kernel (was 6).  Layout/contract: identical to partition_kernel.py
 bf16-exact column values, sel i32[8], par_cnt == 0 dead calls
 supported — EXCEPT that right-segment rows land in reverse order
 (partitions are multiset-preserving, not stable).  Right-zone scratch
-writes stay within [s0, s0 + cnt + 2R) (see grow.PHYS_ROW_SLACK).
+writes stay within [s0, s0 + cnt + 2R) (see layout.COMB_ROW_SLACK).
 
 Round 6 (ISSUE 3): the per-block compaction is now a PLUGGABLE
 ``pack_impl`` hook on ``_scan_kernel`` — the matmul packing below is
@@ -58,16 +58,23 @@ prefix sums and moves rows with O(log R) butterfly routing, producing a
 bit-identical packed layout.  The schedule, cursor math and copyback
 in this file serve both schemes unchanged.
 
-Grid-step economics (measured, tools/profile_step_cost.py): an EMPTY
-Mosaic grid step costs ~1.0 us, a handful of SMEM scalar ops ~0.7 us,
-a DMA start+wait ~1.4 us — per-STEP overhead dominates any per-row
-math at practical R.  Hence: (a) the scan is a single 1-D grid (no
-second phase full of skipped-but-billed steps); (b) the copyback runs
-as a SEPARATE pallas_call whose dynamic grid is sized exactly from the
-scan's (nleft, m) outputs, with large blocks (pure DMA); (c) R
-defaults to 512 — the measured sweet spot (the O(R) per-row
-compaction-matmul cost overtakes the amortized step savings above it:
-512/768/1024/1536 measured 10.8/11.4/11.9/12.9 ns/row at 1M rows).
+Grid-step economics.  A step of this scan costs ``F + R x c`` on the
+v5e with F = 1.40 us - the step itself, the issue and wait of its six
+DMA descriptors, its three SMEM cursors: no row's work, and in no VLIW
+bundle (tools/bundle_census.py reads twice the bundles for twice the
+rows, within 2%) - and c = 3.15 ns a row, ~2.1 of it the butterfly
+compaction and ~1.0 the copy-back (tools/profile_fused.py, VARS=scan at
+R = 512 / 1,024 / 2,048: 5.92 / 4.39 / 3.91 ns a parent row; PERF.md,
+Findings, PR 37 holds the table and the chip call).  Hence: (a) the
+scan is a single 1-D grid (no second phase full of skipped-but-billed
+steps); (b) the copyback runs as a SEPARATE pallas_call whose dynamic
+grid is sized exactly from the scan's (nleft, m) outputs, with large
+blocks (pure DMA); (c) R is the largest power of two whose stack fits
+the default scoped VMEM (``scan_block_rows`` below: 2,048 rows at one
+plane, 1,024 at two) - under the permute compaction, whose rounds are
+O(log R) a row.  The matmul compaction is O(R) a row and stays at 512,
+the knee of the only sweep it had (512 / 768 / 1,024 / 1,536 rows: 10.8
+/ 11.4 / 11.9 / 12.9 ns a row at 1M rows, docs/PERF_NOTES.md).
 """
 from __future__ import annotations
 
@@ -78,13 +85,83 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .layout import (check_lane_width, comb_shape, hbm_copies,
+from .layout import (COMB_ROW_SLACK, COPYBACK_ROWS, SCAN_ROWS_MAX,
+                     SCAN_ROWS_MIN,
+                     check_lane_width, comb_shape, hbm_copies,
                      plane_copies)
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, \
     _go_left, make_reference_partition
 
 # cursor SMEM i32[8] slots
 _CUR_L, _CUR_TL, _CUR_R = 0, 1, 2
+
+
+# The scoped VMEM of a kernel that asks Mosaic for nothing, as both
+# scans do (the v5e has 128 MiB): what the kernel's stack - its scratch
+# shapes, the compaction's run_scoped buffers, the compiler's own
+# temporaries - has to fit.  The hook's [ngroups, M, N] accumulator is
+# an OUTPUT block and not on that stack: 1,024 rows x 384 lanes builds
+# under this limit at 12.04 MiB of stack beside 2 x 4.25 MiB of
+# accumulator, 512 x 896 at 14.59 beside 2 x 13.75.
+SCAN_VMEM_LIMIT = 16 * 1024 * 1024
+
+# Lines of the comb ([C] f32) and further bytes a row of the block that
+# the scan's stack is priced at.  An upper envelope of what the TPU
+# compiler itself reports, read off-chip for the described v5e by
+# lowering the limit until it refuses (ISSUE 37; PERF.md, Findings, PR
+# 37: the table).  The UNFUSED permute scan's stack is its seven [R, C]
+# buffers to the byte - the schedule's four and the compaction's three
+# scoped ones (partition_kernel3._pack_permute): 6.98 MiB at 2,048 rows
+# x 128 lanes, 14.01 at 2,048 x 256, 7.01 at 1,024 x 256 - and the
+# matmul compaction's is under it.  The hook adds 1.5 to 2.8 KiB a row
+# at 4 to 50 feature groups and 4.7 at 110 (its one-hot operands, the
+# block's bf16 copy) - MiB of stack at rows x lanes (groups): 2.97 at
+# 512 x 128 (4), 6.05 at 1,024 x 128, 12.42 at 2,048 x 128, 4.58 at 512
+# x 256 (18), 9.04 at 1,024 x 256, 18.39 at 2,048 x 256 (refused), 6.55
+# at 512 x 384 (40), 12.04 at 1,024 x 384 (34), 8.40 at 512 x 512 (50),
+# 14.59 at 512 x 896 (110).  An eighth line and 3 KiB a row lie 6 to
+# 25% over every one of them.
+_SCAN_LINES, _SCAN_ROW_BYTES = 8, 3 * 1024
+
+
+def scan_vmem_bytes(R: int, C: int) -> int:
+    """The scoped VMEM a grid step of ``R`` rows on a comb of ``C``
+    lanes is priced at (see ``_SCAN_LINES``): f32 lines whatever the
+    comb's dtype - the compaction's words are 32-bit, and bf16 storage
+    is refused by Mosaic today (ops/grow.py)."""
+    return R * (_SCAN_LINES * C * 4 + _SCAN_ROW_BYTES)
+
+
+def scan_block_rows(C: int, *, scheme: str = "permute",
+                    vmem_limit: int = SCAN_VMEM_LIMIT) -> int:
+    """Rows one grid step of the single-scan partition moves on a comb
+    of ``C`` lanes, with or without the histogram hook: the largest
+    power of two in [SCAN_ROWS_MIN, SCAN_ROWS_MAX] whose price fits
+    ``vmem_limit``.
+
+    Why the largest: a step costs ``F + R x c`` and F is no row's work
+    - the step itself, its descriptors' issue and wait, its cursors -
+    so a row's share of it halves with every doubling, while c, the
+    O(log R) butterfly, does not grow (tools/profile_fused.py on the
+    v5e, VARS=scan over R = 512 / 1,024 / 2,048 at both widths;
+    PERF.md, Findings, PR 37: the table, F and c).  One algorithm that
+    wants another parameter at another width: 2,048 rows at one plane,
+    1,024 at two and at three.  The hook's accumulator is no input: it
+    is not on the scan's stack (``SCAN_VMEM_LIMIT``), so the fused scan
+    and the pair behind LGBM_TPU_FUSED=0 take the same block and leave
+    a leaf's rows in the same order.  Every shard of a mesh builds the
+    same kernel from the same shapes, so R is equal on all of them.
+
+    The one-hot MATMUL compaction stays at SCAN_ROWS_MIN: its [R, R]
+    contraction is O(R) a row, and the only sweep it ever had (512 /
+    768 / 1,024 / 1,536 rows: 10.8 / 11.4 / 11.9 / 12.9 ns a row,
+    docs/PERF_NOTES.md) lost by every step up."""
+    if scheme == "matmul":
+        return SCAN_ROWS_MIN
+    R = SCAN_ROWS_MAX
+    while R > SCAN_ROWS_MIN and scan_vmem_bytes(R, C) > vmem_limit:
+        R //= 2
+    return R
 
 
 def _pack_matmul(x, sel_ref, cnt, blk, is_last, out_ref, *, R: int,
@@ -146,7 +223,7 @@ def _pack_matmul(x, sel_ref, cnt, blk, is_last, out_ref, *, R: int,
 
 def _scan_kernel(sel_ref, rows_in, scratch_in,
                  rows_ref, scratch_ref, out_ref,
-                 vx0, vx1, pk0, pk1, cursor,
+                 vx, pk, cursor,
                  sem_r, sem_wl, sem_wr,
                  *, R: int, C: int, n: int, init_cb=None, block_cb=None,
                  pack_impl=None):
@@ -156,6 +233,14 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
     rows / scratch are plane-major (layout.py): ``n`` rows a plane, and
     every transfer of an [R, C] block is one row DMA a plane on one
     semaphore (``plane_copies``), all started, then all waited.
+
+    ``vx`` / ``pk`` are the read and the packed blocks, [2, R, C] each:
+    block ``blk`` works in slot ``blk % 2`` and reads ahead into the
+    other, the slot a traced index, so the step's body - the compaction
+    with its unrolled routing, the hook - is traced, lowered and
+    compiled ONCE, not once a parity (ISSUE 37: trace + lower is paid
+    by every process, the persistent cache keying on the lowered
+    module; halving it is what pays for a larger block's unrolling).
 
     ``init_cb()`` / ``block_cb(x, blk, cnt, side)`` are OPTIONAL
     trace-time hooks for
@@ -184,12 +269,12 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
     cnt = sel_ref[SEL_CNT]
     nb_live = (cnt + R - 1) // R
 
-    def _read(start, vx, sem):
-        return plane_copies(rows_in, start, R, vx, sem, n=n, C=C)
+    def _read(start, buf, sem):
+        return plane_copies(rows_in, start, R, buf, sem, n=n, C=C)
 
     def _wait_write(sem):
         # a write's descriptors again, for their sizes only
-        for cp in plane_copies(rows_ref, 0, R, pk0, sem, n=n, C=C,
+        for cp in plane_copies(rows_ref, 0, R, pk.at[0], sem, n=n, C=C,
                                to_hbm=True):
             cp.wait()
 
@@ -215,61 +300,55 @@ def _scan_kernel(sel_ref, rows_in, scratch_in,
 
         @pl.when(blk == 0)
         def _prime():
-            for cp in _read(start, vx0, sem_r.at[0]):
+            for cp in _read(start, vx.at[0], sem_r.at[0]):
                 cp.start()
 
-        parity = jax.lax.rem(blk, 2)
+        slot = jax.lax.rem(blk, 2)
+        vx_cur, pk_cur = vx.at[slot], pk.at[slot]
 
-        def _do(vx_cur, vx_next, pk, cur_slot, nxt_slot):
-            for cp in _read(start, vx_cur, sem_r.at[cur_slot]):
-                cp.wait()
+        for cp in _read(start, vx_cur, sem_r.at[slot]):
+            cp.wait()
 
-            @pl.when(blk + 1 < nb_live)
-            def _ra():
-                for cpn in _read(start + R, vx_next, sem_r.at[nxt_slot]):
-                    cpn.start()
+        @pl.when(blk + 1 < nb_live)
+        def _ra():
+            for cpn in _read(start + R, vx.at[1 - slot],
+                             sem_r.at[1 - slot]):
+                cpn.start()
 
-            x = vx_cur[:]
-            pack = pack_impl or functools.partial(_pack_matmul, R=R, C=C)
-            nl, nr, side = pack(x, sel_ref, cnt, blk, is_last, pk)
+        x = vx_cur[...]
+        pack = pack_impl or functools.partial(_pack_matmul, R=R, C=C)
+        nl, nr, side = pack(x, sel_ref, cnt, blk, is_last, pk_cur)
 
-            if block_cb is not None:
-                block_cb(x, blk, cnt, side)
+        if block_cb is not None:
+            block_cb(x, blk, cnt, side)
 
-            # overlapping same-side writes must issue in order: wait the
-            # previous same-side write first (its latency hid behind this
-            # block's compute, so the wait is normally already satisfied)
-            @pl.when(blk > 0)
-            def _wl_wait():
-                _wait_write(sem_wl)
+        # overlapping same-side writes must issue in order: wait the
+        # previous same-side write first (its latency hid behind this
+        # block's compute, so the wait is normally already satisfied;
+        # packed buffers ping-pong with the slot)
+        @pl.when(blk > 0)
+        def _wl_wait():
+            _wait_write(sem_wl)
 
-            @pl.when(jnp.logical_not(is_last))
-            def _wl_go():
-                for cpo in plane_copies(rows_ref, cursor[_CUR_L], R, pk,
-                                        sem_wl, n=n, C=C, to_hbm=True):
-                    cpo.start()
-                cursor[_CUR_L] = cursor[_CUR_L] + nl
+        @pl.when(jnp.logical_not(is_last))
+        def _wl_go():
+            for cpo in plane_copies(rows_ref, cursor[_CUR_L], R, pk_cur,
+                                    sem_wl, n=n, C=C, to_hbm=True):
+                cpo.start()
+            cursor[_CUR_L] = cursor[_CUR_L] + nl
 
-            @pl.when(is_last)
-            def _wl_last():
-                cursor[_CUR_TL] = nl
+        @pl.when(is_last)
+        def _wl_last():
+            cursor[_CUR_TL] = nl
 
-            @pl.when(blk > 0)
-            def _wr_wait():
-                _wait_write(sem_wr)
+        @pl.when(blk > 0)
+        def _wr_wait():
+            _wait_write(sem_wr)
 
-            for cpr in plane_copies(scratch_ref, cursor[_CUR_R] - R, R,
-                                    pk, sem_wr, n=n, C=C, to_hbm=True):
-                cpr.start()
-            cursor[_CUR_R] = cursor[_CUR_R] - nr
-
-        @pl.when(parity == 0)
-        def _even():
-            _do(vx0, vx1, pk0, 0, 1)
-
-        @pl.when(parity == 1)
-        def _odd():
-            _do(vx1, vx0, pk1, 1, 0)
+        for cpr in plane_copies(scratch_ref, cursor[_CUR_R] - R, R,
+                                pk_cur, sem_wr, n=n, C=C, to_hbm=True):
+            cpr.start()
+        cursor[_CUR_R] = cursor[_CUR_R] - nr
 
     # ---- scan end: drain the outstanding scratch write, emit results ----
     # (the last left write was already waited by the final block's
@@ -357,9 +436,11 @@ def copyback_call(sel, rows1, scratch1, nleft, m, *, R: int,
     )(sel_cb, scratch1, rows1)
 
 
-def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
+def make_partition_ss(n: int, C: int, *, R: int = SCAN_ROWS_MIN,
+                      size: int = 0,
                       dtype=jnp.float32, interpret: bool = False,
-                      dynamic: bool = False, cb_block: int = 2048,
+                      dynamic: bool = False,
+                      cb_block: int = COPYBACK_ROWS,
                       pack_impl=None, interpret_kernel: bool = False):
     """Single-scan partition: ``partition(sel, rows, scratch[,
     grid_blocks]) -> (rows', scratch', nleft)``, the contract of
@@ -406,10 +487,8 @@ def make_partition_ss(n: int, C: int, *, R: int = 512, size: int = 0,
             out_shape=[jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
                        jax.ShapeDtypeStruct(comb_shape(n, C), dtype),
                        jax.ShapeDtypeStruct((2,), jnp.int32)],
-            scratch_shapes=[pltpu.VMEM((R, C), dtype),
-                            pltpu.VMEM((R, C), dtype),
-                            pltpu.VMEM((R, C), dtype),
-                            pltpu.VMEM((R, C), dtype),
+            scratch_shapes=[pltpu.VMEM((2, R, C), dtype),
+                            pltpu.VMEM((2, R, C), dtype),
                             pltpu.SMEM((8,), jnp.int32),
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.SemaphoreType.DMA,
@@ -441,9 +520,10 @@ from ...analysis.registry import partition_args, register_kernel
                  note="single-scan kernel, one-hot matmul packing "
                       "(LGBM_TPU_PARTITION=matmul)")
 def _analysis_partition_ss():
-    n, C = 7168, 128
-    return (make_partition_ss(n, C, R=512, size=2048),
-            partition_args(n, C))
+    n, C = 2048 + COMB_ROW_SLACK, 128
+    return (make_partition_ss(
+        n, C, R=scan_block_rows(C, scheme="matmul"), size=2048),
+        partition_args(n, C))
 
 
 @register_kernel("partition_ss_matmul_cat", kind="partition",
@@ -451,6 +531,7 @@ def _analysis_partition_ss():
                       "(ISSUE 16)")
 def _analysis_partition_ss_cat():
     from .layout import CAT_BITSET_WORDS
-    n, C = 7168, 128
-    return (make_partition_ss(n, C, R=512, size=2048),
-            partition_args(n, C, sel_words=CAT_BITSET_WORDS))
+    n, C = 2048 + COMB_ROW_SLACK, 128
+    return (make_partition_ss(
+        n, C, R=scan_block_rows(C, scheme="matmul"), size=2048),
+        partition_args(n, C, sel_words=CAT_BITSET_WORDS))

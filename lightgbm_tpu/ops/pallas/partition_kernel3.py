@@ -42,8 +42,12 @@ reloaded.  A [K, R] array (K <= 8) is R / 128 vregs.  Hence (ISSUE
   twice a side, not once a round.
 
 permute and matmul kernels produce BIT-IDENTICAL row layouts (not
-just equal multisets) and compiled trees match byte-for-byte across
-``LGBM_TPU_PARTITION=permute|matmul`` (the tpu_smoke identity gate).
+just equal multisets) at equal block rows (tests/test_partition_perm.py
+through the interpreter).  On the chip the permute scan takes 1,024 -
+2,048 rows a step and the matmul scan 512 (``scan_block_rows``), so a
+leaf's rows lie in another order there and trees across
+``LGBM_TPU_PARTITION=permute|matmul`` agree to f32 summation order,
+not byte for byte.
 
 Because rows move through selects and gathers - never through the MXU
 - the permutation packing preserves ARBITRARY f32 column values
@@ -65,9 +69,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .layout import check_lane_width
+from .layout import COMB_ROW_SLACK, COPYBACK_ROWS, SCAN_ROWS_MIN, \
+    check_lane_width
 from .partition_kernel import SEL_FEAT, _go_left
-from .partition_kernel2 import make_partition_ss
+from .partition_kernel2 import make_partition_ss, scan_block_rows
 
 
 def _lane_iota(R: int):
@@ -305,14 +310,16 @@ def perm_pack_impl(R: int, C: int):
         # both sides' log2(R)-bit routing words share one biased word
         raise ValueError(
             f"permutation packing needs a power-of-two block size "
-            f"in [{_SUB}, {1 << (_BIAS - 1) // 2}] (got R={R}); use "
-            f"LGBM_TPU_PART_R or LGBM_TPU_PARTITION=matmul")
+            f"in [{_SUB}, {1 << (_BIAS - 1) // 2}] (got R={R}); "
+            f"partition_kernel2.scan_block_rows gives one")
     return functools.partial(_pack_permute, R=R, C=C)
 
 
-def make_partition_perm(n: int, C: int, *, R: int = 512, size: int = 0,
+def make_partition_perm(n: int, C: int, *, R: int = SCAN_ROWS_MIN,
+                        size: int = 0,
                         dtype=jnp.float32, interpret: bool = False,
-                        dynamic: bool = False, cb_block: int = 2048,
+                        dynamic: bool = False,
+                        cb_block: int = COPYBACK_ROWS,
                         interpret_kernel: bool = False):
     """Permutation-scheme single-scan partition: signature/contract
     identical to partition_kernel2.make_partition_ss (the two differ
@@ -335,8 +342,8 @@ from ...analysis.registry import partition_args, register_kernel
                  note="single-scan kernel, butterfly-routing permutation "
                       "packing (the shipping default)")
 def _analysis_partition_perm():
-    n, C = 7168, 128
-    return (make_partition_perm(n, C, R=512, size=2048),
+    n, C = 2048 + COMB_ROW_SLACK, 128
+    return (make_partition_perm(n, C, R=scan_block_rows(C), size=2048),
             partition_args(n, C))
 
 
@@ -345,6 +352,6 @@ def _analysis_partition_perm():
                       "sel (ISSUE 16)")
 def _analysis_partition_perm_cat():
     from .layout import CAT_BITSET_WORDS
-    n, C = 7168, 128
-    return (make_partition_perm(n, C, R=512, size=2048),
+    n, C = 2048 + COMB_ROW_SLACK, 128
+    return (make_partition_perm(n, C, R=scan_block_rows(C), size=2048),
             partition_args(n, C, sel_words=CAT_BITSET_WORDS))

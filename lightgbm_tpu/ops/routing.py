@@ -90,7 +90,7 @@ class RouteInputs:
                                        # under efb_comb, the UNBUNDLED
                                        # logical width otherwise (only
                                        # meaningful with efb_bundled)
-    fused_ok: bool = True              # fused_supported(f_pad, B)
+    fused_ok: bool = True              # fused_supported(f_pad, B, C)
     f_log_shard_divisible: bool = True
     over_budget: bool = False          # grow_footprint peak exceeds
                                        # the HBM budget (ISSUE 15: the
@@ -543,11 +543,13 @@ def resolve_layout(i: RouteInputs, *, f_pad: int,
     else:
         n_extra = NON_STREAM_EXTRA_COLS
     from .pallas.fused_split import fused_supported
-    from .pallas.layout import comb_cols_fit
+    from .pallas.layout import comb_cols_fit, comb_layout
+    # the width the grower builds its comb at (ops/grow.py: _C_PHYS)
+    C = comb_layout(int(f_pad) + n_extra)
     resolved = replace(
         i, efb_overwide=bool(i.efb_bundled
                           and not comb_cols_fit(f_pad + n_extra)),
-        fused_ok=bool(fused_supported(int(f_pad), int(padded_bins))))
+        fused_ok=bool(fused_supported(int(f_pad), int(padded_bins), C)))
     if rows is None:
         return resolved
     d1 = decide(resolved)

@@ -91,6 +91,7 @@ class DataParallelGrower:
                 cegb_coupled=grow_kwargs.get("cegb_coupled")))
         self.physical = physical_bins is not None
         self.fused = False   # set from the grow pieces in physical mode
+        self.scan_block_rows = 0    # likewise: rows a step of the scan
         self._f_pad = None   # histogram columns a merge moves
         self._comb = None
         self._scratch = None
@@ -121,6 +122,7 @@ class DataParallelGrower:
                 physical_bins=local_spec, **grow_kwargs)
             self._pieces = pieces
             self.fused = pieces.fused
+            self.scan_block_rows = int(pieces.scan_block_rows)
             self._f_pad = int(pieces.f_pad)
             self._bins_global = physical_bins
             # EFB under the mesh learners is the unbundling ingest

@@ -411,12 +411,17 @@ def test_vmem_budget_prices_run_scoped_buffers(entry, scoped):
     from lightgbm_tpu.analysis.jaxpr_tools import pallas_calls
     from lightgbm_tpu.analysis.passes.vmem import kernel_vmem_bytes
     from lightgbm_tpu.analysis.registry import collect
+    from lightgbm_tpu.ops.pallas.partition_kernel2 import scan_block_rows
     fn, args = collect()[entry].builder()
     scan = pallas_calls(jax.make_jaxpr(fn)(*args))[0]
-    block = 512 * 128 * 4
+    # the registered builds take the block ops/grow.py would (ISSUE 37)
+    rows = scan_block_rows(128, scheme="permute" if scoped else "matmul")
+    block = rows * 128 * 4
     shapes = [r.shape for r in scan.vmem_refs(roles=("scratch",))]
-    assert shapes.count((512, 128)) == (5 if scoped else 4), shapes
-    assert shapes.count((2, 512, 128)) == int(scoped), shapes
+    # the schedule's read and packed blocks, a slot pair each; the
+    # compaction's scoped routing word and staging pair
+    assert shapes.count((2, rows, 128)) == (3 if scoped else 2), shapes
+    assert shapes.count((rows, 128)) == int(scoped), shapes
     blocked = sum(r.nbytes for r in scan.vmem_refs(roles=("in", "out")))
     assert kernel_vmem_bytes(scan) == (2 * blocked
                                        + block * (7 if scoped else 4))

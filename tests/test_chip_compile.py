@@ -23,16 +23,25 @@ import functools
 import pytest
 
 # Higgs-like 1M x 28 on the default physical+stream+fused route:
-# 1,000,000 rows pad to a multiple of R=512, plus PHYS_ROW_SLACK
-N_PAD, N_ALLOC, C, F_PAD, BINS, LEAVES, R = (
-    1_000_448, 1_005_568, 128, 32, 256, 255, 512)
+# 1,000,000 rows pad to whole 2,048-row blocks (grow.PHYS_ROW_PAD),
+# plus PHYS_ROW_SLACK.  The rows a step of the scan moves are not
+# written here: every builder below asks ``scan_block_rows`` as
+# ops/grow.py does (``_scan_rows``), 2,048 at one plane and 1,024 at two
+N_PAD, N_ALLOC, C, F_PAD, BINS, LEAVES = (
+    1_001_472, 1_007_616, 128, 32, 256, 255)
 HIGGS = (N_PAD, N_ALLOC, C, F_PAD)
 # MS LTR, 2,270,296 x 137 on the non-stream physical route: 137 features
 # pad to 144 columns, + 6 value / row-id columns = 150 lanes, C = 256.
 # A [n, 256] f32 array is tiled (8, 128) in HBM and Mosaic refuses a row
 # DMA at an arbitrary row offset into it; the comb is plane-major
 # (ops/pallas/layout.py), which is what these cases hold
-MSLTR = (2_270_720, 2_275_840, 256, 144)
+MSLTR = (2_271_232, 2_277_376, 256, 144)
+
+
+def _scan_rows(geom, scan: str = "permute") -> int:
+    """The block ops/grow.py builds the scan at for this geometry."""
+    from lightgbm_tpu.ops.pallas.fused_split import scan_block_rows
+    return scan_block_rows(geom[2], scheme=scan)
 
 
 @pytest.fixture(scope="module")
@@ -81,8 +90,9 @@ def _fused(scan: str, geom=HIGGS, raw_hist=False):
     handed on as it is."""
     from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
     _, n_alloc, c, f_pad = geom
-    fn = make_fused_split(n_alloc, c, f_pad=f_pad, padded_bins=BINS, R=R,
-                          dynamic=True, scan=scan, raw_hist=raw_hist)
+    fn = make_fused_split(n_alloc, c, f_pad=f_pad, padded_bins=BINS,
+                          R=_scan_rows(geom, scan), dynamic=True,
+                          scan=scan, raw_hist=raw_hist)
     return fn, _part_args(n_alloc, c)
 
 
@@ -90,7 +100,8 @@ def _partition_perm(geom=HIGGS):
     from lightgbm_tpu.ops.pallas.partition_kernel3 import \
         make_partition_perm
     _, n_alloc, c, _ = geom
-    return (make_partition_perm(n_alloc, c, R=R, dynamic=True),
+    return (make_partition_perm(n_alloc, c, dynamic=True,
+                                R=_scan_rows(geom)),
             _part_args(n_alloc, c))
 
 
@@ -101,12 +112,13 @@ def _stream(which: str, geom=HIGGS):
     features would)."""
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops.grow import _STREAM_R
     from lightgbm_tpu.ops.pallas.layout import comb_shape
     from lightgbm_tpu.ops.pallas.stream_grad import (N_CONSTS, make_init,
                                                      make_refresh)
     n_pad, n_alloc, c, f_pad = geom
     kw = dict(kind="binary", sigmoid=1.0, f=f_pad, n_alloc=n_alloc,
-              n_pad=n_pad, C=c, R=R)
+              n_pad=n_pad, C=c, R=_STREAM_R)
     comb = sds(comb_shape(n_alloc, c), jnp.float32)
     if which == "init":
         return make_init(f_real=f_pad, **kw), (
@@ -333,6 +345,9 @@ def test_the_grow_program_compiles_with_one_scan_and_no_comb_copy(
         padded_bins=BINS, physical_bins=sds((n, f), jnp.uint8),
         stream={"kind": "binary", "sigmoid": 1.0, "count": n})
     assert gp.fused and gp._root0_fn is not None
+    # ISSUE 37: the scan of this program moves what the function gives
+    # a one-plane comb
+    assert gp.scan_block_rows == _scan_rows((0, 0, gp._C, f)) == 2048
     comb = comb_shape(gp._n_alloc, gp._C)
     args = [sds(comb, jnp.float32)] * 2 + [sds((1,), jnp.float32)] * 3 + [
         sds((f,), jnp.float32), sds((f,), jnp.int32), sds((f,), jnp.bool_),
@@ -374,12 +389,19 @@ def test_the_mesh_grow_program_adds_no_collective(topo, no_compile_cache,
                                                      DataParallelGrower)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array(topo.devices), (DATA_AXIS,))
-    shards, n_loc, f = len(topo.devices), 5_250_048, F_PAD
+    # 21,000,000 rows pad to whole 2,048-row blocks a shard
+    shards, n_loc, f = len(topo.devices), 5_251_072, F_PAD
     grower = DataParallelGrower(
         SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
         padded_bins=BINS, mesh=mesh,
         physical_bins=sds((shards * n_loc, f), jnp.uint8))
     assert grower.fused and grower.hist_scatter
+    # ISSUE 37: every shard's scan moves the block the function gives
+    # this width, and a shard's comb stays inside what the four-chip
+    # cell's ``correct`` allows: its 5,250,000 rows + 8,192 lines
+    assert grower.scan_block_rows == _scan_rows(
+        (0, 0, grower._pieces.C, f)) == 2048
+    assert grower._pieces.n_alloc <= 5_250_000 + 8_192
     lines, lanes = comb_shape(grower._pieces.n_alloc, grower._pieces.C)
 
     def arg(shape, dtype, *spec):
@@ -505,6 +527,7 @@ def test_the_bundled_comb_grow_program_compiles_at_the_expo_shape(
         stream={"kind": "binary", "sigmoid": 1.0, "count": n})
     assert gp.fused and gp._f_pad == f and gp._C == 128
     assert gp._ingest is None           # nothing unbundles
+    assert gp.scan_block_rows == 2048   # Higgs's comb, Higgs's block
     comb = comb_shape(gp._n_alloc, gp._C)
     args = [sds(comb, jnp.float32)] * 2 + [sds((1,), jnp.float32)] * 3 + [
         sds((f_log,), jnp.float32), sds((f_log,), jnp.int32),

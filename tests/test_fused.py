@@ -283,9 +283,12 @@ def test_rows_hooked_is_the_hooked_parents_rows(variant, monkeypatch):
     among them): the model's internal counts ARE the parents' rows, so
     the counter can be checked split by split - on the mesh against
     the crossover times the shard count."""
-    rows = _MID[variant]
+    # whole PHYS_ROW_PAD blocks, a shard: 2 on one device, 1 on each of
+    # 8, with the crossover grown as the rows are
+    n, rows = ((16384, 4 * _MID[variant]) if variant == "mesh8"
+               else (4096, _MID[variant]))
     trees, tot, _, parents = _train_counted(
-        "1", weighted=False, n=4096, crossover=(monkeypatch, rows),
+        "1", weighted=False, n=n, crossover=(monkeypatch, rows),
         **_VARIANTS[variant])
     parents = np.concatenate(parents)
     hooked = parents[parents <= rows * (8 if variant == "mesh8" else 1)]
@@ -293,17 +296,35 @@ def test_rows_hooked_is_the_hooked_parents_rows(variant, monkeypatch):
     assert tot["hook_splits"] == len(hooked)
     assert tot["rows_hooked"] == hooked.sum()
     assert tot["rows_partitioned"] == parents.sum()
-    assert trees == _unfused(variant, False, n=4096)[0]
+    assert trees == _unfused(variant, False, n=n)[0]
 
 
 def test_constant_hessians_never_miss():
     """Unweighted l2: every hessian is 1, the record's left count IS
-    the row count, so the scan is always told the smaller child."""
-    t0, _, _, _ = _train_counted("0", weighted=False)
-    t1, tot, _, _ = _train_counted("1", weighted=False)
+    the row count - at a row count that needs no padding, whose rows
+    the scan moves and no record counts - so the scan is always told
+    the smaller child."""
+    t0, _, _, _ = _train_counted("0", weighted=False, n=4096)
+    t1, tot, _, _ = _train_counted("1", weighted=False, n=4096)
     assert t0 == t1 and tot["splits"] > 0
     assert tot["side_miss_splits"] == 0
     assert tot["rows_rehistogrammed"] == 0
+
+
+@pytest.mark.parametrize("ngroups", [1, 2, 4, 11, 18, 64])
+def test_hook_crossover_stays_inside_its_measurements(ngroups):
+    """``hook_crossover_rows`` is linear in the group count BETWEEN the
+    two geometries it was measured at and holds the nearer one's value
+    outside them: a line through two points read 0 rows at one group
+    (ISSUE 37), which no chip run had said."""
+    from lightgbm_tpu.ops.pallas.fused_split import (_CROSSOVER_MEASURED,
+                                                     hook_crossover_rows)
+    (g0, (f0, r0)), (g1, (f1, r1)) = sorted(_CROSSOVER_MEASURED.items())
+    ends = {g0: int(f0 * 1e3 / r0), g1: int(f1 * 1e3 / r1)}
+    got = hook_crossover_rows(ngroups)
+    assert got == ends[g0 if ngroups <= g0 else g1] \
+        or (g0 < ngroups < g1
+            and min(ends.values()) < got < max(ends.values()))
 
 
 @pytest.mark.parametrize("side", ["left", "right"])

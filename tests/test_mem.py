@@ -407,16 +407,17 @@ def test_footprint_matches_mesh_pieces():
 
 def test_footprint_msltr_comb_bytes_and_peak():
     """The model at the shape ``msltr-train-2m`` runs - 2,270,296 rows,
-    137 features padded to 144, a line of two planes: 2,275,840 lines
-    x 1,024 B = 2.33e9 bytes a comb-sized array, comb + scratch 4.66e9
-    (PERF.md section 5)."""
+    137 features padded to 144, a line of two planes: 2,271,232 rows
+    (whole 2,048-row blocks: ``grow.PHYS_ROW_PAD``) + 6,144 lines of
+    slack = 2,277,376 lines x 1,024 B = 2.33e9 bytes a comb-sized
+    array, comb + scratch 4.66e9 (PERF.md section 4)."""
     fp = costmodel.grow_footprint(rows=2_270_296, f_pad=144,
                                   padded_bins=256, num_leaves=255)
     geo = fp["geometry"]
-    assert (geo["n_alloc"], geo["C"]) == (2_275_840, 256)
+    assert (geo["n_alloc"], geo["C"]) == (2_277_376, 256)
     comb = fp["buffers"]["comb"]
-    assert comb["shape"] == (2_275_840, 256)
-    assert comb["bytes"] == 2_275_840 * 1_024 == 2_330_460_160
+    assert comb["shape"] == (2_277_376, 256)
+    assert comb["bytes"] == 2_277_376 * 1_024 == 2_332_033_024
     assert fp["buffers"]["scratch"]["bytes"] == comb["bytes"]
     # the peak is the max phase live-set
     assert fp["peak_bytes"] == max(fp["phase_live"].values())

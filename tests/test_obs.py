@@ -388,21 +388,45 @@ def test_counters_from_tree_by_hand():
     got = counters_from_tree(3, **tree, fused=True)
     # rows_histogrammed: the root pass + min(60, 40) + min(45, 15)
     assert got.tolist() == [2.0, 160.0, 100.0 + 40.0 + 15.0, 2.0, 0.0,
-                            0.0, 0.0, 0.0, 0.0, 0.0]
+                            0.0, 0.0, 0.0, 0.0, 0.0, 0.0]
     # the hook ran at the second split only (its parent's 60 rows are
     # under the crossover), and its record named the 45: the 15 were
     # read again
     got = counters_from_tree(3, **tree, side_miss=np.array([1, 15, 1, 60]),
                              fused=True)
-    assert got.tolist()[4:] == [1.0, 15.0, 1.0, 60.0, 0.0, 0.0]
+    assert got.tolist()[4:-1] == [1.0, 15.0, 1.0, 60.0, 0.0, 0.0]
     assert len(got) == len(COUNTER_NAMES)
     # under the bundled comb the program counts six: the last two are
     # the splits decided by a membership set and their parent rows
     got = counters_from_tree(
         3, **tree, side_miss=np.array([0, 0, 0, 0, 1, 100]), fused=True)
-    assert got.tolist()[4:] == [0.0, 0.0, 0.0, 0.0, 1.0, 100.0]
-    stump = counters_from_tree(1, [0], [0], [0.0], [77.0], fused=False)
-    assert stump.tolist() == [0.0, 0.0, 77.0] + [0.0] * 7
+    assert got.tolist()[4:-1] == [0.0, 0.0, 0.0, 0.0, 1.0, 100.0]
+    stump = counters_from_tree(1, [0], [0], [0.0], [77.0], fused=False,
+                               scan_block_rows=32)
+    assert stump.tolist() == [0.0, 0.0, 77.0] + [0.0] * 8
+
+
+@pytest.mark.parametrize("block,shards,steps", [
+    (0, 1, 0.0),        # no physical route: no scan, no steps
+    (32, 1, 4 + 2),     # ceil(100 / 32) + ceil(60 / 32)
+    (64, 1, 2 + 1),
+    (128, 1, 1 + 1),    # a parent under a block still takes a step
+    (16, 4, 4 * 2 + 4 * 1),     # 4 x ceil(25 / 16) + 4 x ceil(15 / 16)
+])
+def test_scan_steps_by_hand(block, shards, steps):
+    """``scan_steps`` of the same tree: the grid steps of the partition
+    scan, ``ceil(parent rows / R)`` a split - on a mesh ``shards x
+    ceil(parent rows / shards / R)``, from the global counts - so that
+    ``rows_partitioned`` over it is the rows a step really moved."""
+    from lightgbm_tpu.obs import counters_from_tree
+    got = counters_from_tree(
+        3, left_child=[1, -1, 7, 7], right_child=[-2, -3, 7, 7],
+        internal_count=[100.0, 60.0, 999.0, 999.0],
+        leaf_count=[45.0, 40.0, 15.0, 999.0], fused=True,
+        scan_block_rows=block, shards=shards)
+    d = dict(zip(COUNTER_NAMES, got.tolist()))
+    assert d["scan_steps"] == steps
+    assert d["rows_partitioned"] == 160.0
 
 
 # ---------------------------------------------------------------------

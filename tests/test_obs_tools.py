@@ -839,6 +839,13 @@ class TestXplaneDecoder:
             "_refresh_hist_kernel": "stream_refresh",
             "_init_kernel": "stream_refresh",
             "_apply_find_pool_kernel": "find_split",
+            # the names the pallas_calls carry since PR 27, as the
+            # device plane of a chip capture spells them
+            "lgbm_split_scan.1": "fused_split",
+            "lgbm_copyback.1": "partition_copyback",
+            "lgbm_hist.2": "hist_build",
+            "lgbm_refresh": "stream_refresh",
+            "lgbm_apply_find.3": "find_split",
             "all-reduce.17": "collective",
             "reduce-scatter.3": "collective",
             "dynamic-update-slice.8": "copy",
@@ -1175,11 +1182,11 @@ class TestMeshFlightRecorder:
         row-id ones): the collective bytes are histogram payloads of
         the 128 columns, whatever the line holds beside them."""
         monkeypatch.setenv("LGBM_TPU_PHYS", "interpret")
-        # 8192 rows = 8 shards x 2 full PHYS_R=512 partition blocks:
+        # 16384 rows = 8 shards x one full PHYS_ROW_PAD block of 2,048:
         # every shard holds real rows, so the skew series is defined
         # (an emptier n leaves whole shards as padding — in-bag 0 —
         # and the ratio honestly degenerates to None)
-        bst, colls, mesh, n = self._train_mesh(n=8192, f=128, rounds=1,
+        bst, colls, mesh, n = self._train_mesh(n=16384, f=128, rounds=1,
                                                max_bin=15)
         assert bst._inner.grow.physical
         assert bst._inner.grow._pieces.C == 256

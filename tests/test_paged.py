@@ -317,7 +317,7 @@ class TestPagedRouting:
         from lightgbm_tpu.ops.paged import plan_pages
         from lightgbm_tpu.ops.pallas.fused_split import fused_supported
         fp_shape, b = 10, 64
-        assert not fused_supported(fp_shape, b)
+        assert not fused_supported(fp_shape, b, 128)
         kw = dict(rows=102400, f_pad=fp_shape, padded_bins=b,
                   num_leaves=31, stream=True, stream_kind="l2")
         peak_f = costmodel.grow_footprint(fused=True, **kw)["peak_bytes"]

@@ -214,7 +214,7 @@ def test_data_parallel_padded_fast_path(problem):
 # (benchmarks/reference_mesh.py: no shards, all rows, tree 0 leaf by
 # leaf), and what a traced mesh run says and dispatches (ISSUE 34)
 # ---------------------------------------------------------------------
-MESH_ROWS = 4096        # 8 shards x one 512-row partition block: no padding
+MESH_ROWS = 16384       # 8 shards x one 2,048-row block (PHYS_ROW_PAD): no padding
 
 
 def _bench_reference():

@@ -85,21 +85,53 @@ def test_permute_matches_matmul_bitwise(cfg):
     np.testing.assert_array_equal(out[s0 + cnt:], rows[s0 + cnt:])
 
 
-def test_permute_routing_fuzz():
+def _fuzz_rows(n, c, seed):
+    """``_rows`` at any width: eight bin columns a plane, and f32
+    payload the permute scheme must move bit-exactly."""
+    rng = np.random.default_rng(seed)
+    rows = np.zeros((n, c), np.float32)
+    for lo in range(0, c, LANE):
+        rows[:, lo:lo + 8] = rng.integers(0, 64, size=(n, 8))
+    rows[:, 8] = rng.normal(size=n)
+    rows[:, c - 1] = rng.random(size=n)
+    return rows
+
+
+# (block rows, lanes, bucket size, draws): the small block many times,
+# and ONE draw at each block the chip runs - what scan_block_rows gives
+# a one-plane comb (Higgs) and a two-plane comb (MS LTR) (ISSUE 37;
+# the interpreter traces the unrolled routing, ~1 min a build at 2,048
+# rows, so these two stay single)
+FUZZ_BLOCKS = {
+    "small_block": (R, C, SIZE, 6),
+    "shipped_one_plane": (2048, C, 5000, 1),
+    "shipped_two_planes": (1024, 2 * LANE, 2500, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUZZ_BLOCKS))
+def test_permute_routing_fuzz(case):
     """Randomized (s0, cnt, feat, sbin) sweep of the roll routing
-    against the oracle — the collision-freedom argument, empirically."""
-    rng = np.random.default_rng(11)
-    rows = _rows(seed=5)
-    rj = jnp.asarray(rows)
+    against the oracle — the collision-freedom argument, empirically —
+    through the real scan + copyback kernels."""
+    from lightgbm_tpu.ops.pallas.fused_split import scan_block_rows
+    from lightgbm_tpu.ops.pallas.layout import to_planes, to_rows
+    r, c, size, draws = FUZZ_BLOCKS[case]
+    if case.startswith("shipped"):
+        assert r == scan_block_rows(c)
     cb = 256
-    pm = make_partition_perm(N, C, R=R, size=SIZE, interpret=True,
+    n = size + 3 * r + 2 * cb + 64
+    rng = np.random.default_rng(11)
+    rows = _fuzz_rows(n, c, seed=5)
+    rj = to_planes(jnp.asarray(rows))
+    pm = make_partition_perm(n, c, R=r, size=size, interpret=True,
                              interpret_kernel=True, cb_block=cb)
     # s0 range respects the copyback slack contract: the tail copyback
     # block reads/writes [dst0, dst0 + cb_block) and dst0 < s0 + cnt
-    for _ in range(6):
-        cnt = int(rng.integers(0, SIZE + 1))
-        s0 = int(rng.integers(0, N - SIZE - 3 * R - 2 * cb))
-        feat = int(rng.integers(0, 8))
+    for _ in range(draws):
+        cnt = int(rng.integers(size // 2 if draws == 1 else 0, size + 1))
+        s0 = int(rng.integers(0, n - size - 3 * r - 2 * cb))
+        feat = int(rng.integers(0, 8)) + (c - LANE)  # in the last plane
         sbin = int(rng.integers(0, 64))
         r_p, _, nl_p = pm(_sel(s0, cnt, feat, sbin), rj,
                           jnp.zeros_like(rj))
@@ -107,10 +139,12 @@ def test_permute_routing_fuzz():
         gl = seg[:, feat] <= sbin
         nl = int(nl_p)
         assert nl == int(gl.sum()), (s0, cnt, feat, sbin)
-        out = np.asarray(r_p)
+        out = np.asarray(to_rows(r_p, c))
         np.testing.assert_array_equal(out[s0:s0 + nl], seg[gl])
         np.testing.assert_array_equal(out[s0 + nl:s0 + cnt],
                                       seg[~gl][::-1])
+        np.testing.assert_array_equal(out[:s0], rows[:s0])
+        np.testing.assert_array_equal(out[s0 + cnt:], rows[s0 + cnt:])
 
 
 def test_permute_bf16_payload_exact():
@@ -250,6 +284,83 @@ def test_pack_block_layout(case):
     loff = r - nr - nl if is_last else 0
     np.testing.assert_array_equal(packed[loff:loff + nl], x[gl])
     np.testing.assert_array_equal(packed[r - nr:], x[gr][::-1])
+
+
+# MiB of scoped stack the TPU compiler itself reports for the fused
+# permute scan at (rows a step, lanes), read off-chip for the described
+# v5e by lowering ``vmem_limit_bytes`` until it refuses (ISSUE 37,
+# second session; PERF.md, Findings, PR 37)
+CENSUS_STACK_MIB = {
+    (512, 128): 2.966, (1024, 128): 6.046, (2048, 128): 12.416,
+    (512, 256): 4.576, (1024, 256): 9.036, (2048, 256): 18.39,
+    (512, 384): 6.546, (1024, 384): 12.036, (512, 512): 8.396,
+    (512, 896): 14.586,
+}
+
+
+def test_scan_block_rows_is_one_function_of_width_and_vmem():
+    """ISSUE 37: the rows a grid step of the scan moves come from the
+    comb's width and the scoped VMEM a kernel gets - a power of two in
+    [SCAN_ROWS_MIN, SCAN_ROWS_MAX], never smaller for more VMEM or a
+    narrower comb, 512 under the matmul compaction (O(R) a row), and
+    what the compiled kernels were measured at: 2,048 rows at one
+    plane, 1,024 at two.  The price bounds every reading the compiler
+    gave of its own stack, and decides as the compiler did."""
+    from lightgbm_tpu.ops.pallas.fused_split import (
+        SCAN_VMEM_LIMIT, scan_block_rows, scan_vmem_bytes)
+    from lightgbm_tpu.ops.pallas.layout import (COMB_ROW_SLACK,
+                                                COPYBACK_ROWS,
+                                                SCAN_ROWS_MAX,
+                                                SCAN_ROWS_MIN)
+    assert SCAN_VMEM_LIMIT == 16 * 2**20    # what a kernel gets unasked
+    assert scan_block_rows(128) == 2048     # higgs, expo, data4
+    assert scan_block_rows(256) == 1024     # msltr
+    for (r, c), mib in CENSUS_STACK_MIB.items():
+        price = scan_vmem_bytes(r, c) / 2**20
+        assert mib <= price <= 1.25 * mib, (r, c, price)
+        # all of these built under the default limit but 2,048 x 256
+        assert (price <= 16) == (mib <= 16), (r, c)
+    seen = set()
+    for c in range(128, 2049, 128):
+        prev = 0
+        for limit in (2**20, 2**23, SCAN_VMEM_LIMIT, 2**25, 2**26,
+                      2**28):
+            rr = scan_block_rows(c, vmem_limit=limit)
+            assert SCAN_ROWS_MIN <= rr <= SCAN_ROWS_MAX
+            assert rr & (rr - 1) == 0
+            assert rr >= prev                   # monotone in VMEM
+            assert rr <= scan_block_rows(max(c - 128, 128),
+                                         vmem_limit=limit)
+            assert scan_block_rows(c, scheme="matmul",
+                                   vmem_limit=limit) == 512
+            prev = rr
+            seen.add(rr)
+    assert seen == {512, 1024, 2048}
+    # what was sized before the block was known covers the largest
+    assert COMB_ROW_SLACK >= 2 * SCAN_ROWS_MAX + COPYBACK_ROWS
+    # a shard of a mesh builds its kernel from the same shapes: the
+    # function has no other input
+    assert {scan_block_rows(128) for _ in range(4)} == {2048}
+
+
+@pytest.mark.parametrize("f_pad,b,c,ok", [
+    (32, 256, 128, True),       # higgs
+    (16, 256, 128, True),       # expo's bundle columns
+    (144, 256, 256, True),      # msltr
+    (10, 64, 128, False),       # no whole feature group: no hook
+    (400, 256, 512, True),      # 50 groups: built at 8.40 MiB of stack
+    (880, 256, 896, True),      # 110 groups: 14.59 MiB, the last plane
+    (1000, 256, 1024, False),   # the price's 17.5 MiB is past the limit
+])
+def test_fused_supported_reads_the_scans_price(f_pad, b, c, ok):
+    """ROADMAP C9: the predicate that decides the fused route charges
+    what ``scan_block_rows`` charges, at the smallest block."""
+    from lightgbm_tpu.ops.pallas.fused_split import (
+        SCAN_VMEM_LIMIT, fused_supported, hook_acc_bytes, scan_vmem_bytes)
+    from lightgbm_tpu.ops.pallas.layout import SCAN_ROWS_MIN
+    assert fused_supported(f_pad, b, c) is ok
+    assert ok == bool(hook_acc_bytes(f_pad, b) and scan_vmem_bytes(
+        SCAN_ROWS_MIN, c) <= SCAN_VMEM_LIMIT)
 
 
 @pytest.mark.parametrize("r", [4, 96, 4096])
@@ -642,3 +753,34 @@ class TestLaneContract:
                           stream_columns("l2")):
                 c = comb_layout(f_pad + extra)
                 assert c % LANE == 0, (f_pad, extra, c)
+
+
+@pytest.mark.parametrize("kind,stream", [("binary", True), ("l2", True),
+                                         ("l2", False)])
+def test_routing_and_grower_price_the_same_comb(kind, stream, monkeypatch):
+    """ISSUE 37 (review): ``routing.resolve_layout`` hands
+    ``fused_supported`` the width the grower will build its comb at -
+    the stream layouts carry more columns than the non-stream one - so
+    ``fused_ok`` and ``make_grow_fn``'s own decision agree where those
+    extra columns cross into another plane at the edge of the price:
+    888 bin columns make 7 planes off the stream route (the fused
+    scan's last) and 8 on it (the unfused pair)."""
+    import jax
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.ops import routing
+    from lightgbm_tpu.ops.grow import make_grow_fn
+    from lightgbm_tpu.ops.split import SplitHyperParams
+    f_pad, bins, n = 888, 256, 4096
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    inputs = routing.RouteInputs(
+        backend="tpu", objective_kind=kind,
+        stream_env="auto" if stream else "0")
+    r = routing.resolve_layout(inputs, f_pad=f_pad, padded_bins=bins)
+    assert (routing.decide(r).path == "stream") is stream
+    gp = make_grow_fn(
+        SplitHyperParams(min_data_in_leaf=20), num_leaves=15,
+        padded_bins=bins, physical_bins=sds((n, f_pad), jnp.uint8),
+        stream=({"kind": kind, "sigmoid": 1.0, "count": n}
+                if stream else None))
+    assert gp._C == (1024 if stream else 896)
+    assert r.fused_ok is gp.fused is (not stream)

@@ -13,7 +13,27 @@ bundle) show where a [R, 1] column costs a whole block.  No chip time.
 
     python tools/bundle_census.py fused_permute [--ops 24]
     python tools/bundle_census.py part_perm --R 1024
+    python tools/bundle_census.py fused_permute --R 1024 --cols 144
     python tools/bundle_census.py registry:fused_split_cat
+
+Read on this tree (ISSUE 37; one step's body, spill stores in
+brackets), so the next writer need not re-derive them:
+
+    kernel          columns   R = 512         1,024            2,048
+    part_perm       32        1,564 (209)     3,060 (771)      6,073 (1,672)
+    fused_permute   32        5,119 (835)     9,565 (2,168)    18,594 (5,506)
+    fused_permute   144       15,387 (2,851)  30,127 (5,518)   58,809 (13,200)
+
+(At the parent the scan held a body a parity and read 3,003 / 6,009 /
+12,006, 9,970 / 18,842 / 36,935 and 29,998 / 59,473 / 116,969.)  Twice
+the rows, twice the bundles, within 2% in the unfused scan: the
+compaction has no fixed part and the butterfly's O(log R) rounds add
+nothing that shows, so what a grid step costs beyond its rows is in no
+bundle (the chip reads 1.4 us of it: PERF.md, Findings, PR 37).  The
+two-plane kernel at R = 2,048 is refused: its stack is 18.39 MiB and a
+kernel that asks for nothing gets 16 (the compiler's own message names
+both; partition_kernel2._SCAN_LINES holds ten such readings, taken by
+lowering ``vmem_limit_bytes`` until the compiler refuses).
 
 Kernels: ``fused_permute`` / ``fused_matmul`` (the ``higgs`` route's
 fused scan at the Higgs width), ``part_perm`` / ``part_matmul`` (the
@@ -36,20 +56,21 @@ import sys
 import tempfile
 
 ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
-N_ALLOC, C, F_PAD, BINS = 1_005_568, 128, 32, 256   # Higgs 1M, as the
-#                                                      chip-compile tests
+N_ALLOC, BINS = 1_007_616, 256      # Higgs 1M, as the chip-compile tests
 
 
-def _builder(kernel: str, R: int):
+def _builder(kernel: str, R: int, f_pad: int):
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.registry import collect, partition_args, sds
+    from lightgbm_tpu.ops.pallas.layout import comb_layout
     if kernel.startswith("registry:"):
         return collect()[kernel.split(":", 1)[1]].builder()
+    C = comb_layout(f_pad + 6)
     args = partition_args(N_ALLOC, C) + (sds((), jnp.int32),)
     if kernel.startswith("fused_"):
         from lightgbm_tpu.ops.pallas.fused_split import make_fused_split
         return make_fused_split(
-            N_ALLOC, C, f_pad=F_PAD, padded_bins=BINS, R=R, dynamic=True,
+            N_ALLOC, C, f_pad=f_pad, padded_bins=BINS, R=R, dynamic=True,
             scan=kernel.split("_", 1)[1]), args
     if kernel == "part_perm":
         from lightgbm_tpu.ops.pallas.partition_kernel3 import \
@@ -62,7 +83,7 @@ def _builder(kernel: str, R: int):
     return make(N_ALLOC, C, R=R, dynamic=True), args
 
 
-def _child(kernel: str, R: int) -> None:
+def _child(kernel: str, R: int, f_pad: int) -> None:
     sys.path.insert(0, ROOT)
     import jax
     from jax.experimental import topologies
@@ -71,7 +92,7 @@ def _child(kernel: str, R: int) -> None:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
-    fn, args = _builder(kernel, R)
+    fn, args = _builder(kernel, R, f_pad)
     args = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one)
                  for a in args)
     jax.jit(fn).lower(*args).compile()     # may abort in the dumper
@@ -120,12 +141,15 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("kernel")
     ap.add_argument("--R", type=int, default=512, help="block rows")
+    ap.add_argument("--cols", type=int, default=32,
+                    help="feature columns of the comb (32: one 128-lane "
+                         "plane, the Higgs width; 144: two, MS LTR's)")
     ap.add_argument("--ops", type=int, default=0,
                     help="also print the N commonest opcodes")
     ap.add_argument("--keep", help="keep the dump in this directory")
     a = ap.parse_args()
     if os.environ.get("_BUNDLE_CENSUS_CHILD"):
-        _child(a.kernel, a.R)
+        _child(a.kernel, a.R, a.cols)
         return 0
     dump = a.keep or tempfile.mkdtemp(prefix="bundle_census_")
     env = dict(os.environ, _BUNDLE_CENSUS_CHILD="1", JAX_PLATFORMS="cpu",

@@ -435,7 +435,7 @@ mem_leg() {
     # gate 1: pinned `obs mem` table on the checked-in fixture record
     # (footprint model -> phase live-sets -> measured join, exact)
     env -u LGBM_TPU_HBM_GEN -u LGBM_TPU_HBM_LIMIT_GB \
-        -u LGBM_TPU_PART_R -u LGBM_TPU_STREAM \
+        -u LGBM_TPU_STREAM \
         JAX_PLATFORMS=cpu python -m lightgbm_tpu.obs mem \
         tests/data/synthetic_mem_record.json \
         > "$tmp/mem.out" 2> "$tmp/mem.err"

@@ -24,15 +24,27 @@ of every variant that reads the child's rows again.
   direct — what ops/grow.py runs at a split past the crossover (ISSUE
            35): the same kernel told SIDE_NONE, its hook skipped, and
            the cond histograms the smaller child from the comb
+  scan   — ``direct`` without the histogram: the scan with its hook
+           skipped and the copy-back, nothing else.  What a grid step
+           costs is read from this one (ISSUE 37)
 
 Env: WIDTH=32,144 (feature columns: 32 on one 128-lane plane, the
 ``higgs`` layout; 144 on two, ``msltr-lambdarank``'s), LS=1024,4096
 (leaf-row sweep), REPS (in-jit splits per timing; default 4e8 / L
 clipped to [100, 4000]: keep the timed loop well over the ~20-50 ms
-dispatch floor), R=512 (partition block rows).  The last lines fit
-fixed + rows x slope to ``fused`` and ``direct`` over the sweep and
-print the crossover.  Off-TPU the kernels run in interpret mode with
-tiny REPS — a functional check only, not a timing.
+dispatch floor), R=512,1024,2048 (partition block rows: a sweep),
+VARS=pair,fused,miss,direct,scan (the variants to run).  The last
+lines fit fixed + rows x slope to ``fused`` and ``direct`` over the
+leaf sweep and print the crossover at each R, and - over an R sweep -
+fit ``step_us = F + R x c`` to the row slopes of ``scan`` (``direct``
+where ``scan`` was not run): F is what a grid step costs whatever it
+moves, c what a row costs.  A kernel the chip's compiler refuses (a
+block past the scoped VMEM) prints ``does not compile`` and the sweep
+goes on.  Off-TPU the kernels run in interpret mode with tiny REPS — a
+functional check only, not a timing.
+
+Read on the v5e (PERF.md, Findings, PR 37: the table and the chip call)
+before ``fused_split.scan_block_rows`` took R from the comb's width.
 """
 from __future__ import annotations
 
@@ -116,7 +128,7 @@ def build(var: str, L: int, R: int, interpret: bool, small_left: bool,
             padded_bins=B, rows_per_block=min(HIST_RPB, L),
             interpret=interpret, planes=comb_planes(C))
 
-    if var in ("fused", "miss", "direct"):
+    if var in ("fused", "miss", "direct", "scan"):
         from lightgbm_tpu.ops.pallas.fused_split import hook_histogram
         # the compiled kernel hands on its raw accumulator, as in
         # ops/grow.py: the extraction is paid in the branch that reads it
@@ -130,12 +142,17 @@ def build(var: str, L: int, R: int, interpret: bool, small_left: bool,
         # the record's side: the leaf is re-split on the same column
         # every time, so the smaller child is known beforehand
         side = small_left if var == "fused" else not small_left
-        direct = var == "direct"
+        direct = var in ("direct", "scan")
         sel = sel.at[SEL_SIDE].set(
             SIDE_NONE if direct else SIDE_LEFT if side else SIDE_RIGHT)
 
         def split(comb, scratch):
             comb, scratch, nleft, h_side = fused(sel, comb, scratch, nb)
+            if var == "scan":
+                # the accumulator is all zeros; summing it keeps the
+                # kernel's fourth output alive at no row-sized cost
+                return (comb, scratch,
+                        nleft.astype(jnp.float32) + jnp.sum(h_side))
             h = jax.lax.cond(direct | ((nleft * 2 <= L) != side),
                              lambda c, _: hist_child(c, nleft),
                              lambda _, h_: extract(h_), comb, h_side)
@@ -164,46 +181,85 @@ def _fit(points):
     return fixed, slope
 
 
+def _step_fit(slopes):
+    """Least squares of ns_per_row = F / R + c over {R: ns a row}:
+    (F in us a step, c in ns a row)."""
+    inv = np.array([1.0 / r for r in slopes], np.float64)
+    y = np.array(list(slopes.values()), np.float64)
+    f_ns, c = np.polyfit(inv, y, 1)
+    return f_ns * 1e-3, c
+
+
 def main():
     on_tpu = jax.default_backend() == "tpu"
     interpret = not on_tpu
-    R = int(os.environ.get("R", 512))
+    blocks = [int(r) for r in os.environ.get("R", "512").split(",")]
     sizes = [int(s) for s in os.environ.get("LS", "1024,4096").split(",")]
     widths = [int(w) for w in os.environ.get("WIDTH", "32").split(",")]
+    variants = os.environ.get("VARS", "pair,fused,miss,direct").split(",")
     if not on_tpu:
         print(f"[profile_fused] backend={jax.default_backend()}: "
               "interpret-mode functional check, timings meaningless")
 
     for f_pad in widths:
-        curves = {}
-        for L in sizes:
-            reps = int(os.environ.get(
-                "REPS", min(max(int(4e8 / L), 100), 4000) if on_tpu else 2))
-            base = {}
-            for var in ("pair", "fused", "miss", "direct"):
-                n_alloc = L + 2 * R + 2 * HIST_RPB
-                comb, scratch, n_left = make_leaf(n_alloc, L, f_pad)
-                split = build(var, L, R, interpret, n_left * 2 <= L, f_pad)
-
-                dt, _ = bench_chain(split, comb, scratch, reps=reps)
-                base[var] = dt
-                curves.setdefault(var, []).append((L, dt))
-                print(f"W={f_pad:3d} L={L:7d} {var:6s}: "
-                      f"{dt*1e6:9.1f} us/split  ({dt/L*1e9:6.2f} ns/row)"
-                      f"  reps={reps}", flush=True)
-            for var in ("fused", "miss", "direct"):
-                red = 100.0 * (1.0 - base[var] / base["pair"])
-                print(f"W={f_pad:3d} L={L:7d} {var} vs pair: {red:+.1f}% "
-                      "floor reduction", flush=True)
-        if len(sizes) >= 2:
-            (fh, sh), (fd, sd) = _fit(curves["fused"]), _fit(curves["direct"])
-            gap_us, gap_ns = (fd - fh) * 1e6, (sh - sd) * 1e9
-            cross = gap_us * 1e3 / gap_ns if gap_ns > 0 else float("inf")
-            print(f"W={f_pad:3d} fit: fused {fh*1e6:.2f} us + {sh*1e9:.3f} "
-                  f"ns/row, direct {fd*1e6:.2f} us + {sd*1e9:.3f} ns/row; "
-                  f"direct's fixed gap {gap_us:.2f} us, the hook's row gap "
-                  f"{gap_ns:.3f} ns: crossover at {cross:.0f} rows",
-                  flush=True)
+        slopes = {}             # R -> ns a parent row of the scan
+        for R in blocks:
+            curves, refused = {}, False
+            for L in sizes:
+                if refused:         # no size of this kernel will build
+                    break
+                reps = int(os.environ.get(
+                    "REPS",
+                    min(max(int(4e8 / L), 100), 4000) if on_tpu else 2))
+                base = {}
+                for var in variants:
+                    n_alloc = L + 2 * R + 2 * HIST_RPB
+                    comb, scratch, n_left = make_leaf(n_alloc, L, f_pad)
+                    split = build(var, L, R, interpret, n_left * 2 <= L,
+                                  f_pad)
+                    try:
+                        dt, _ = bench_chain(split, comb, scratch, reps=reps)
+                    except Exception as e:      # the compiler's refusal
+                        print(f"W={f_pad:3d} R={R:4d} L={L:7d} {var:6s}: "
+                              f"does not compile ({type(e).__name__}: "
+                              f"{str(e)[:200]!r})", flush=True)
+                        refused = True
+                        break
+                    finally:
+                        del comb, scratch
+                    base[var] = dt
+                    curves.setdefault(var, []).append((L, dt))
+                    print(f"W={f_pad:3d} R={R:4d} L={L:7d} {var:6s}: "
+                          f"{dt*1e6:9.1f} us/split  ({dt/L*1e9:6.2f} ns/row)"
+                          f"  reps={reps}", flush=True)
+                for var in ("fused", "miss", "direct"):
+                    if var in base and "pair" in base:
+                        red = 100.0 * (1.0 - base[var] / base["pair"])
+                        print(f"W={f_pad:3d} R={R:4d} L={L:7d} {var} vs "
+                              f"pair: {red:+.1f}% floor reduction",
+                              flush=True)
+            fits = {v: _fit(c) for v, c in curves.items() if len(c) >= 2}
+            for v, (fx, sl) in fits.items():
+                print(f"W={f_pad:3d} R={R:4d} fit: {v} {fx*1e6:.2f} us + "
+                      f"{sl*1e9:.3f} ns/row = {sl*1e9*R*1e-3:.3f} us a "
+                      f"{R}-row step", flush=True)
+            if "fused" in fits and "direct" in fits:
+                (fh, sh), (fd, sd) = fits["fused"], fits["direct"]
+                gap_us, gap_ns = (fd - fh) * 1e6, (sh - sd) * 1e9
+                cross = gap_us * 1e3 / gap_ns if gap_ns > 0 else float("inf")
+                print(f"W={f_pad:3d} R={R:4d} direct's fixed gap "
+                      f"{gap_us:.2f} us, the hook's row gap {gap_ns:.3f} "
+                      f"ns: crossover at {cross:.0f} rows", flush=True)
+            of = "scan" if "scan" in fits else "direct"
+            if of in fits:
+                slopes[R] = fits[of][1] * 1e9
+        if len(slopes) >= 2:
+            F, c = _step_fit(slopes)
+            print(f"W={f_pad:3d} step fit over R={sorted(slopes)} from "
+                  f"`{of}`: step_us = {F:.3f} + R x {c:.4f}e-3  (F us a "
+                  f"grid step; c ns a parent row, the copy-back's "
+                  + ("" if of == "scan" else "and the child histogram's ")
+                  + "share included)", flush=True)
 
 
 if __name__ == "__main__":

@@ -5,8 +5,9 @@ combination of
 
   * scheme:  permute (butterfly routing, O(log R)/row)  vs  matmul
              ([R, R] one-hot contraction, O(R)/row)
-  * R:       block rows (LGBM_TPU_PART_R candidates; the round-3b
-             sweep put the matmul scheme's knee at 512)
+  * R:       block rows (the round-3b sweep put the matmul scheme's
+             knee at 512; the permute scheme's block is
+             partition_kernel2.scan_block_rows)
   * dtype:   f32, plus a bf16 attempt that documents the Mosaic
              (8,128)x2 dynamic-offset blocker instead of crashing.
 

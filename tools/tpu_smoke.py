@@ -10,8 +10,9 @@ device gate: it trains real trees through the compiled physical+stream
 path at two shapes, with monotone constraints off and on, and fails loudly
 on any compile or runtime error.
 
-Run: ``python tools/tpu_smoke.py`` (needs the TPU; ~60-90 s, dominated by
-Mosaic compiles).  Exit code 0 = green.  ``--fast`` skips the 1M shape.
+Run: ``python tools/tpu_smoke.py`` (needs the TPU; 4-5 min on the v5e,
+dominated by Mosaic compiles).  Exit code 0 = green.  ``--fast`` skips
+the 1M shape.
 """
 from __future__ import annotations
 
@@ -26,7 +27,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # could silently reroute it before jax/lightgbm_tpu import
 for _k, _v in (("LGBM_TPU_PHYS", ""), ("LGBM_TPU_STREAM", ""),
                ("LGBM_TPU_COMB_DT", "f32"), ("LGBM_TPU_APPLY_IMPL", ""),
-               ("LGBM_TPU_PART_R", ""),
                ("LGBM_TPU_COMB_BF16", ""), ("LGBM_TPU_POOL_TAIL", ""),
                ("LGBM_TPU_FUSED", ""), ("LGBM_TPU_PARTITION", ""),
                ("LGBM_TPU_PART_INTERP", "")):
@@ -158,14 +158,81 @@ def _check_fused_identity():
     _check_knob_identity("LGBM_TPU_FUSED", ("1", "0"), "fused-identity")
 
 
-def _check_partition_identity():
-    """Compiled permute vs matmul partition schemes must grow
-    BYTE-identical trees (ISSUE 3): the permute packing reproduces the
-    matmul scheme's exact row layout — reversed right segments included
-    — so every histogram accumulates in the same order.  Any
-    divergence here means the butterfly routing reordered rows."""
-    _check_knob_identity("LGBM_TPU_PARTITION", ("permute", "matmul"),
-                         "partition-identity")
+def _check_partition_identity(block: int = 512, draws: int = 6,
+                              n_rows: int = 48 * 2048) -> None:
+    """Compiled permute vs matmul compaction must leave BIT-identical
+    packed combs and equal ``nleft`` (ISSUE 3): the permute packing
+    reproduces the matmul scheme's exact row layout - left ascending,
+    right reversed.  The interpreter pins that
+    (tests/test_partition_perm.py); what Mosaic makes of the butterfly
+    routing can only be held to it here.  Kernel against kernel at
+    EQUAL block rows, at one plane and at two: since ISSUE 37 the
+    growers hand the two schemes other blocks
+    (partition_kernel2.scan_block_rows), so their TREES agree to f32
+    summation order only.  The permute scan is also held to the numpy
+    oracle at the block it ships with at each width."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.pallas.layout import (COMB_ROW_SLACK, LANE,
+                                                to_planes, to_rows)
+    from lightgbm_tpu.ops.pallas.partition_kernel import SEL_CNT, SEL_S0
+    from lightgbm_tpu.ops.pallas.partition_kernel2 import (
+        make_partition_ss, scan_block_rows)
+    from lightgbm_tpu.ops.pallas.partition_kernel3 import \
+        make_partition_perm
+
+    rng = np.random.default_rng(5)
+    n = n_rows + COMB_ROW_SLACK
+    for planes in (1, 2):
+        C = planes * LANE
+        # whole bytes in every lane: exact through the matmul
+        # compaction's bf16 MXU pass, and rows told apart by content
+        rows = rng.integers(0, 256, size=(n, C)).astype(np.float32)
+        comb = to_planes(jnp.asarray(rows))
+        shipped = scan_block_rows(C)
+        fns = {
+            ("permute", block): make_partition_perm(
+                n, C, R=block, dynamic=True),
+            ("matmul", block): make_partition_ss(
+                n, C, R=block, dynamic=True),
+            ("permute", shipped): make_partition_perm(
+                n, C, R=shipped, dynamic=True),
+        }
+        fns = {k: jax.jit(f) for k, f in fns.items()}
+        # unaligned starts and counts over many blocks, one row, a dead
+        # call, a whole-comb parent; the split column in the last plane
+        cases = [(3, 1), (777, 0), (0, n_rows)] + [
+            (int(rng.integers(0, n_rows // 2)),
+             int(rng.integers(block, n_rows // 2)))
+            for _ in range(draws)]
+        for s0, cnt in cases:
+            sel = np.zeros((8,), np.int32)
+            sel[SEL_S0], sel[SEL_CNT] = s0, cnt
+            sel[2] = C - LANE + int(rng.integers(0, 8))
+            sel[3], sel[6] = int(rng.integers(16, 240)), -1
+            seg = rows[s0:s0 + cnt]
+            gl = seg[:, sel[2]] <= sel[3]
+            want = rows.copy()
+            want[s0:s0 + cnt] = np.concatenate([seg[gl], seg[~gl][::-1]])
+            out = {}
+            for (scheme, r), fn in fns.items():
+                r2, _, nleft = fn(jnp.asarray(sel), comb,
+                                  jnp.zeros_like(comb),
+                                  jnp.int32(max(-(-cnt // r), 1)))
+                out[scheme, r] = (np.asarray(to_rows(r2, C)), int(nleft))
+            for key, (got, nleft) in out.items():
+                if nleft != int(gl.sum()) or not np.array_equal(got, want):
+                    raise RuntimeError(
+                        f"partition-identity: {key[0]} scan at {key[1]} "
+                        f"rows a step, {planes} plane(s), s0={s0} "
+                        f"cnt={cnt} sel={sel.tolist()}: nleft {nleft} "
+                        f"(oracle {int(gl.sum())}), "
+                        f"{int((got != want).any(axis=1).sum())} lines "
+                        "differ from the oracle's packed comb")
+        print(f"[tpu_smoke] partition-identity: {planes} plane(s), "
+              f"{len(cases)} parents: permute == matmul == oracle at "
+              f"{block} rows a step, permute == oracle at {shipped}")
 
 
 def _check_trace(n_rows: int = 50_048, num_leaves: int = 31,
@@ -435,9 +502,11 @@ def main() -> int:
         tfi = time.perf_counter()
         _check_fused_identity()
         timings["fused_identity"] = time.perf_counter() - tfi
-        # permutation vs matmul partition packing: bit-identical trees
-        # on the compiled path (the ISSUE-3 equivalence bar; the
-        # interpret-mode matrix lives in tests/test_physical.py)
+        # permutation vs matmul partition packing: bit-identical packed
+        # combs from the compiled kernels at equal block rows, one plane
+        # and two (the ISSUE-3 equivalence bar, at kernel level since
+        # ISSUE 37 gave the schemes other blocks; the interpret-mode
+        # matrix lives in tests/test_partition_perm.py)
         tpi = time.perf_counter()
         _check_partition_identity()
         timings["partition_identity"] = time.perf_counter() - tpi
