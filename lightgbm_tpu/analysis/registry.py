@@ -169,6 +169,18 @@ def collect(force: bool = False) -> Dict[str, KernelEntry]:
         "lightgbm_tpu.analysis.entries",
     ):
         importlib.import_module(mod)
+    # Tests and tools/tpu_smoke.py drop the library from ``sys.modules``
+    # to re-read its knobs.  A caller bound to this module from before
+    # such a purge (a test file's top-level import) then asks a
+    # registry the hooks above did not register with: they ran, or had
+    # run, against the one ``sys.modules`` holds now.  Take that one's.
+    import sys
+    live = sys.modules.get(__name__)
+    if live is not None and live.KERNELS is not KERNELS:
+        live.collect()
+        KERNELS.update(live.KERNELS)
+        PURITY_PINS.update(live.PURITY_PINS)
+        MESH_CONFIGS[:] = list(live.MESH_CONFIGS)
     _collected = True
     return KERNELS
 

@@ -15,6 +15,7 @@ AddBias, matching gbdt.cpp:505-512, so saved models are self-contained.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -30,6 +31,7 @@ from ..obs import events as obs_events
 from ..obs import hbm_live_bytes as obs_hbm_live_bytes
 from ..obs import ledger as obs_ledger
 from ..obs import tracer as obs_tracer
+from ..obs.tracer import phase as obs_phase
 from ..objective.base import ObjectiveFunction
 from ..ops.device_data import DeviceDataset, to_device
 from ..ops.grow import make_grow_fn
@@ -45,7 +47,6 @@ from ..resilience import faults as resilience_faults
 from ..resilience import numerics as resilience_numerics
 from ..utils import log
 from ..utils.random import make_rng
-from ..utils.timer import global_timer
 from .tree import Tree
 
 
@@ -71,7 +72,9 @@ def _jit_with_operands(fn, example):
     closed = jax.make_jaxpr(fn)(example)
     run = jax.jit(lambda consts, x: jax.core.eval_jaxpr(
         closed.jaxpr, consts, x))
-    return lambda x: tuple(run(closed.consts, x))
+    # a partial: the tracer's ``Program::ops`` table lowers ``.func``
+    # at ``.args``
+    return functools.partial(run, closed.consts)
 
 
 class GBDT:
@@ -1068,10 +1071,15 @@ class GBDT:
     def _sample_phase_hbm(self, phase: str) -> None:
         """Live-buffer watermark census (obs.hbm_live_bytes) at PHASE
         granularity (ISSUE 9): an upper bound on device HBM held by
-        live jax arrays, sampled right after each reference phase while
-        tracing — the measured side of the footprint model's per-phase
-        live-sets (obs/costmodel.grow_footprint), rendered by
-        ``obs mem`` as the memory timeline.  Tracing off: never called
+        live jax arrays, sampled at each reference phase while tracing
+        — ``Tree::grow`` and ``UpdateScore`` between the dispatch and
+        its ``::wait``, under the running program: the walk is host
+        bookkeeping and a dispatched program's outputs are live arrays
+        already, so it reads the bytes it read after the barrier and
+        the device does not wait for it — the measured side of the
+        footprint model's per-phase live-sets
+        (obs/costmodel.grow_footprint), rendered by ``obs mem`` as the
+        memory timeline.  Tracing off: never called
         on the hot path (every call site is behind ``tracer.enabled``),
         and the census is host-side only — the grow jaxpr is pinned
         unchanged by the ``grow-phase-hbm`` purity pin.  Module-level
@@ -1239,19 +1247,17 @@ class GBDT:
             act = jnp.asarray(np.asarray(active, np.float32))
             gK = grad * act[:, None]
             hK = hess * act[:, None]
-        with global_timer.time("GBDT::grow"), \
-                obs_tracer.span("Tree::grow", batched=k) as _gsp:
+        with obs_tracer.span("Tree::grow", batched=k) as _gsp:
             obs_events.record("grow_dispatch")
             taK, leaf_idK = self.grow.grow_batch(
                 self.dd.bins, gK, hK, inbag, fmK,
                 self.dd.num_bins, self.dd.has_nan, self.dd.is_cat,
                 np.asarray(seeds, np.int32))
             if obs_tracer.enabled:
+                self._sample_phase_hbm("Tree::grow")
                 _gsp.wait(leaf_idK)
                 self._record_work_counters(
                     _gsp, taK, [kidx for kidx in range(k) if active[kidx]])
-        if obs_tracer.enabled:
-            self._sample_phase_hbm("Tree::grow")
         badK = None
         if (self._numerics in ("raise", "skip")
                 and getattr(self.grow, "last_numerics_bad", None)
@@ -1280,8 +1286,8 @@ class GBDT:
                 r = self._finish_tree_async(
                     ta_k, leaf_idK[kidx], kidx, init_scores[kidx])
                 _usp.block_on(self.train_score)
-            if obs_tracer.enabled:
-                self._sample_phase_hbm("UpdateScore")
+                if obs_tracer.enabled:
+                    self._sample_phase_hbm("UpdateScore")
             if r:
                 should_continue = True
         return should_continue
@@ -1392,6 +1398,7 @@ class GBDT:
             nr, npad = self._n_real, self._n_rows_host
             obj = self.objective
 
+            @obs_phase("gradients")
             def fn(score):
                 s = score[:, :nr]
                 g, h = obj.get_gradients(s if k > 1 else s[0])
@@ -1406,7 +1413,11 @@ class GBDT:
             # must re-trace each call; everything else gets one cached jit
             self._grad_fn = (fn if obj.STATEFUL_GRADIENTS
                              else _jit_with_operands(fn, score))
-        return self._grad_fn(score)
+        out = tuple(self._grad_fn(score))
+        if isinstance(self._grad_fn, functools.partial):
+            obs_tracer.program("gradients", self._grad_fn.func,
+                               *self._grad_fn.args, score)
+        return out
 
     def _sample(self, grad, hess, it):
         """Bagging hook; GOSS overrides (reference goss.hpp)."""
@@ -1425,8 +1436,7 @@ class GBDT:
         # call does not donate this buffer, so the old array stays
         # valid)
         cegb_prev = getattr(self, "_cegb_paid", None)
-        with global_timer.time("GBDT::grow"), \
-                obs_tracer.span("Tree::grow", kidx=kidx) as _gsp:
+        with obs_tracer.span("Tree::grow", kidx=kidx) as _gsp:
             tree_seed = (self.iter_ * max(self.num_tree_per_iteration, 1)
                          + kidx)
             fmask = self._feature_mask(tree_seed)
@@ -1457,10 +1467,9 @@ class GBDT:
                     self.dd.num_bins, self.dd.has_nan, self.dd.is_cat,
                     tree_seed)
             if obs_tracer.enabled:
+                self._sample_phase_hbm("Tree::grow")
                 _gsp.wait(leaf_id)
                 self._record_work_counters(_gsp, ta, [kidx])
-        if obs_tracer.enabled:
-            self._sample_phase_hbm("Tree::grow")
         if (self._numerics in ("raise", "skip")
                 and getattr(self.grow, "last_numerics_bad", None)
                 is not None):
@@ -1487,8 +1496,8 @@ class GBDT:
             with obs_tracer.span("UpdateScore") as _usp:
                 r = self._finish_tree_async(ta, leaf_id, kidx, init_score)
                 _usp.block_on(self.train_score)
-            if obs_tracer.enabled:
-                self._sample_phase_hbm("UpdateScore")
+                if obs_tracer.enabled:
+                    self._sample_phase_hbm("UpdateScore")
             return r
         nl = int(ta.num_leaves)
         lin = None
@@ -1601,6 +1610,7 @@ class GBDT:
                                    self._fmap)
 
         @jax.jit
+        @obs_phase("score")
         def tail(ta, leaf_id, score_k, vbins, vscores_k, rate, init_score):
             is_real = ta.num_leaves > 1
             delta = jnp.where(
@@ -1637,10 +1647,12 @@ class GBDT:
             score_k = self.train_score[kidx]
             vscores_k = tuple(vs.score[kidx] for vs in self.valid_sets)
         with obs_tracer.span("UpdateScore::tail"):
-            new_score, new_vscores, dt = tail(
-                ta, leaf_id, score_k,
-                tuple(vs.bins for vs in self.valid_sets), vscores_k,
-                jnp.float32(rate), jnp.float32(init_score))
+            tail_args = (ta, leaf_id, score_k,
+                         tuple(vs.bins for vs in self.valid_sets),
+                         vscores_k, jnp.float32(rate),
+                         jnp.float32(init_score))
+            new_score, new_vscores, dt = tail(*tail_args)
+        obs_tracer.program("score", tail, *tail_args)
         with obs_tracer.span("UpdateScore::set", op="set"):
             self.train_score = self.train_score.at[kidx].set(new_score)
             for vs, sk in zip(self.valid_sets, new_vscores):

@@ -1,10 +1,10 @@
 """Phase tracer: nested wall-clock spans with device barriers.
 
-Generalizes ``utils/timer.py`` (the reference ``Common::Timer`` /
-``FunctionTimer`` analog, utils/common.h:973) from flat named
-accumulators into a structured trace: nested spans, JSON-lines output
-that doubles as Chrome-trace events, per-phase accumulators, and
-counter channels.
+The reference's ``Common::Timer`` / ``FunctionTimer``
+(utils/common.h:973) as a structured trace: nested spans, JSON-lines
+output that doubles as Chrome-trace events, per-phase accumulators,
+counter channels, and the phase of the algorithm every device op
+serves.
 
 **The rule: turning the tracer on, at any moment, changes no compiled
 program and dispatches no extra one; it only adds names.**  It may be
@@ -46,10 +46,10 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
                                         (ranking: queries, buckets,
                                         pairs_visited, pair_slots)
             Boosting::wait
-        HbmCensus                       live-array census (obs mem)
+        HbmCensus                       live-array census (obs mem);
+                                        arg phase, here BeforeTrain
         GradSlice                       eager grad[k], hess[k]
-        GBDT::grow                      utils/timer.py's twin of the next
-          Tree::grow                    args: the work counters
+        Tree::grow                      args: the work counters
                                         (obs/counters.py COUNTER_NAMES;
                                         under the bundled comb
                                         member_splits and rows_member
@@ -57,14 +57,16 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
                                         and scan_block_rows, the rows a
                                         grid step of the partition scan
                                         moves (scan_steps counts them)
-            Tree::grow::wait            the device runs the grow program
-            WorkCounters                pull of the tree's small arrays
-        HbmCensus
+          HbmCensus                     phase Tree::grow: under the
+                                        running program, so the device
+                                        does not wait for the walk
+          Tree::grow::wait              the device runs the grow program
+          WorkCounters                  pull of the tree's small arrays
         UpdateScore
           UpdateScore::tail             dispatch of the score/valid tail
           UpdateScore::set              eager slice + .at[].set
+          HbmCensus                     phase UpdateScore, likewise
           UpdateScore::wait
-        HbmCensus
         StallProbe                      every 8th iteration
         FlushPending                    every 32nd iteration
       Eval                              when a metric is due
@@ -94,6 +96,72 @@ bundled comb - are counted by the grow program, traced or not, and come
 with the tree) after the
 ``Tree::grow`` barrier, and set as args of that span.
 
+Phases: the busy time of a capture, named by the program.  Every op
+of the three programs an iteration dispatches is traced under exactly
+one ``jax.named_scope("lgbm.<phase>")`` - always, traced or not: a
+scope is metadata of the ops under it, and the compiled program with
+its ``metadata={...}`` stripped is the same text without the scopes
+(``tests/test_chip_compile.py``).  The names mirror the reference's
+``FunctionTimer`` ones (serial_tree_learner.cpp: BeforeTrain,
+ConstructHistograms, FindBestSplits, Split)::
+
+    lgbm.root        ops/grow.py: the per-tree start.  Off the stream
+                     route g / h / w gathered by row id and written
+                     into the comb, the bf16 rounding, the root sums,
+                     the root histogram, the root's finder call; on it
+                     the little that is left of those
+    lgbm.hist        the smaller child's histogram: lgbm_hist, the
+                     scan hook's accumulator taken apart, the pool's
+                     reads and writes, the subtraction
+    lgbm.merge       the mesh learners' collectives, wherever they are
+                     written (the root's too): psum_scatter / psum of
+                     histograms and row counts, sync_best's election
+    lgbm.find        the finder over the two children: lgbm_apply_find
+                     or the XLA tail, find_best_split_segments
+    lgbm.partition   lgbm_split_scan, lgbm_copyback, what they are told
+                     (the descriptor, bundled_split_members), the
+                     segment table's writes
+    lgbm.glue        what a split does besides: leaf election, state
+                     and tree-array writes, the counters the program
+                     keeps, the ``while`` itself; and the tree arrays
+                     taken out of the state after the last split
+    lgbm.leafrows    end of tree: per-position leaf (and value) from
+                     the segment table, the row-id decode, the
+                     un-permute to row order (scatter and its sort)
+    lgbm.refresh     stream route: lgbm_refresh (scores, gradients, the
+                     next tree's root histogram)
+    lgbm.score       models/gbdt.py: the jitted score tail (train and
+                     valid scores, the replay replica)
+    lgbm.gradients   models/gbdt.py: the objective's gradient program
+                     (not on the stream route, which has none)
+
+``phased`` / ``next_phase`` cut a long function at its seams, ``phase``
+scopes a block or decorates a function (below).  An instruction the
+compiler makes of several ops (a fusion) carries its root's scope; the
+ops of a library's own nested ``jit`` (``jnp.cumsum``) carry the scope
+of the call.
+
+``Program::ops``: a capture's ``XLA Ops`` line names an event by its
+HLO instruction (``%fusion.9 = f32[32,256]{...} fusion(...)``), which
+the compiler numbers anew with every change; the phase is in the
+instruction's ``metadata``, which the capture does not print.  So the
+first iteration the tracer is live, each dispatch site hands
+``tracer.program(name, jitted, *args)`` its program (``grow``: the
+growers in ops/grow.py and parallel/data_parallel.py; ``score`` and
+``gradients``: models/gbdt.py), which keeps ``{phase: [op_key, ...]}``
+parsed from the compiled module's text (``program_ops``; ``""`` holds
+the instructions under no phase) - under a span ``Program::table``
+(arg ``program``); nothing is built for it: handed the dispatch's own
+arguments, ``lower`` and ``compile`` find its trace and its executable
+in JAX's caches -, and ``annotate(True)``
+writes one zero-length ``X`` event ``Program::ops`` (args ``program``,
+``ops``) for each into the capture's span file.  ``op_key`` is the
+instruction name and the result's shape without layouts,
+``fusion.9 f32[32,256]``.
+A reader books an op whose key two programs put under different phases
+as ambiguous; the eager one-op programs of an iteration (``GradSlice``,
+``UpdateScore::set``) are in no table.
+
 Xplane correlation: while ``tracer.annotate(True)`` — a profiler
 capture is live — every span additionally enters a
 ``jax.profiler.TraceAnnotation("obs::<name>")``, so the capture's host
@@ -107,8 +175,10 @@ from __future__ import annotations
 
 import atexit
 import contextlib
+import functools
 import json
 import os
+import re
 import threading
 import time
 import weakref
@@ -132,6 +202,179 @@ BUILD_EVENTS = {
 # to the tracers that are enabled at that moment
 _LISTENING: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
 _registered = False
+
+# The phases of the algorithm a device op can serve (the docstring's
+# table): every op of the grow, score and gradient programs is traced
+# under exactly one ``lgbm.<phase>`` of ``jax.named_scope``, which the
+# compiler carries into each instruction's ``metadata={op_name=...}``.
+PHASES = ("root", "hist", "merge", "find", "partition", "glue",
+          "leafrows", "refresh", "score", "gradients")
+PHASE_PREFIX = "lgbm."
+_phase_local = threading.local()
+
+
+def _phase_scope(name: str):
+    if name not in PHASES:
+        raise ValueError(f"no phase {name!r}: one of {PHASES}")
+    import jax
+    return jax.named_scope(PHASE_PREFIX + name)
+
+
+def _jaxpr_level():
+    """What tells one jaxpr being traced from the next: JAX starts
+    each (a jitted function, a loop body, a branch) with an empty name
+    stack, so a phase held open in one is not open in the other."""
+    import jax
+    return jax.core.get_opaque_trace_state()
+
+
+class _PhaseCursor:
+    """The one phase open in a ``phased`` function, and the scope that
+    holds it open."""
+
+    __slots__ = ("name", "level", "_open")
+
+    def __init__(self) -> None:
+        self.name = None
+        self.level = _jaxpr_level()
+        self._open = contextlib.ExitStack()
+
+    def switch(self, name) -> None:
+        self._open.close()
+        self.name = name
+        if name is not None:
+            self._open.enter_context(_phase_scope(name))
+
+
+def _cursor():
+    """The cursor of the jaxpr being traced, or None."""
+    cursor = getattr(_phase_local, "cursor", None)
+    if cursor is not None and cursor.level == _jaxpr_level():
+        return cursor
+    return None
+
+
+def phased(fn):
+    """Decorator of a function that passes through several phases:
+    inside it ``next_phase(name)`` closes the phase open before and
+    holds ``lgbm.<name>`` open until the next seam or the function's
+    end, so a long function is cut at its seams by one line each and
+    is not re-indented.  A function traced as its own jaxpr (a jitted
+    function, a loop body, a branch) that has seams is decorated
+    itself; called in line from another ``phased`` function, it moves
+    that one's cursor and hands it back as it found it."""
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        outer = getattr(_phase_local, "cursor", None)
+        cursor = _cursor()          # called in line: the caller's
+        before = None if cursor is None else cursor.name
+        if cursor is None:
+            cursor = _phase_local.cursor = _PhaseCursor()
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            cursor.switch(before)
+            _phase_local.cursor = outer
+    return wrapper
+
+
+def next_phase(name: str) -> None:
+    """A seam of a ``phased`` function: from here on the ops are
+    ``lgbm.<name>``'s."""
+    cursor = _cursor()
+    if cursor is None:
+        raise RuntimeError(f"next_phase({name!r}) outside a phased "
+                           "function of the jaxpr being traced")
+    cursor.switch(name)
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """``lgbm.<name>`` for a block, or as a decorator for a function.
+    Always on: a scope is metadata of the ops traced under it, not an
+    instruction.  Inside a ``phased`` function the phase open there is
+    set aside for the block (an op is under one phase, never two) and
+    taken up again after it."""
+    cursor = _cursor()
+    if cursor is None:
+        with _phase_scope(name):
+            yield
+        return
+    before = cursor.name
+    cursor.switch(name)
+    try:
+        yield
+    finally:
+        cursor.switch(before)
+
+
+# What never runs as an op of its own on the device, and so never
+# shows on a capture's ``XLA Ops`` line.
+_NOT_AN_OP = frozenset({"parameter", "constant", "get-tuple-element",
+                        "tuple", "bitcast"})
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.-]+) \(.*\) -> .* \{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%([\w.-]+) = (.*)$")
+_HLO_OPCODE = re.compile(r"\s([a-z][a-z0-9_-]*)\(")
+# a layout, or the ``/*index=5*/`` a long tuple is printed with
+_HLO_LAYOUT = re.compile(r"\{[^{}]*\}|/\*.*?\*/")
+_HLO_CALLED = re.compile(
+    r"(?:condition|body|true_computation|false_computation)=%([\w.-]+)"
+    r"|branch_computations=\{([^}]*)\}")
+_HLO_APPLIED = re.compile(r"to_apply=%([\w.-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_OP_NAME_PHASE = re.compile(re.escape(PHASE_PREFIX) + r"([a-z]+)(?:/|$)")
+
+
+def op_key(name: str, shape: str) -> str:
+    """How ``Program::ops`` names an instruction: its name and its
+    result's shape without the layouts (the capture prints a tiled
+    layout, the compiled text need not), ``fusion.9 f32[32,256]``."""
+    return name.lstrip("%") + " " + _HLO_LAYOUT.sub("", shape).strip()
+
+
+def program_ops(hlo_text: str) -> Dict[str, List[str]]:
+    """``{phase: [op_key, ...]}`` of a compiled module's text: the
+    instructions a capture's ``XLA Ops`` line can show - the entry
+    computation's, and those of the ``while`` bodies and conditions,
+    branches and calls reached from it, not the insides of fused
+    computations - each under the innermost ``lgbm.<phase>`` of its
+    ``metadata={op_name=...}`` (a fusion carries its root's), or under
+    ``""`` where it has none: an op of a nested library ``jit``, or of
+    code no phase was written for."""
+    computations: Dict[str, List[tuple]] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            current = computations.setdefault(m.group(1), [])
+            if line.startswith("ENTRY "):
+                entry = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line) if current is not None else None
+        if m:
+            current.append((m.group(1), m.group(2)))
+    ops: Dict[str, List[str]] = {}
+    seen, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in computations:
+            continue
+        seen.add(comp)
+        for name, rest in computations[comp]:
+            m = _HLO_OPCODE.search(rest)
+            if m is None or m.group(1) in _NOT_AN_OP:
+                continue
+            for one, many in _HLO_CALLED.findall(rest):
+                todo += [one] if one else [
+                    c.strip().lstrip("%") for c in many.split(",")]
+            if m.group(1) == "call":
+                todo += _HLO_APPLIED.findall(rest)
+            named = _HLO_OP_NAME.search(rest)
+            phases = _OP_NAME_PHASE.findall(named.group(1)) if named else []
+            phase_ = phases[-1] if phases and phases[-1] in PHASES else ""
+            ops.setdefault(phase_, []).append(
+                op_key(name, rest[:m.start()]))
+    return ops
 
 
 def _on_jax_duration(event: str, secs: float, **kwargs) -> None:
@@ -203,6 +446,7 @@ class Tracer:
         self._t0 = time.perf_counter()
         self._env_checked = False
         self._annotate = False
+        self._programs: Dict[str, tuple] = {}   # name -> (jitted, ops)
         self._max_events = int(os.environ.get("LGBM_TPU_TRACE_MAX_EVENTS",
                                               "200000"))
 
@@ -264,6 +508,8 @@ class Tracer:
         self._annotate = on
         stack = self._stack()
         if on:
+            for name in self._programs:
+                self._record_program(name)
             for entry in stack:
                 if entry[1] is None:
                     entry[1] = self._mirror(entry[0])
@@ -274,6 +520,46 @@ class Tracer:
     @property
     def annotating(self) -> bool:
         return self._annotate
+
+    # -- what a capture's instruction names mean --------------------------
+    def program(self, name: str, jitted, *args) -> None:
+        """Keep, for the jitted function an iteration has just
+        dispatched as ``name`` (``grow``, ``score``, ``gradients``),
+        the phase of each instruction of its compiled module
+        (``program_ops``), to be written into every capture as a
+        ``Program::ops`` event.  Once a function, and nothing is
+        built for it: called after the dispatch with the very
+        arguments it was given (a donated buffer, deleted by now, is
+        asked only its shape and placement), ``lower`` finds the
+        dispatch's trace and ``compile`` its executable in JAX's own
+        caches; arguments described afresh (a ``ShapeDtypeStruct``
+        with the sharding an uncommitted array merely has) lower to
+        another module and compile it anew, 17-80 s on the chip (PR
+        38).  Off, a single attribute check."""
+        if not self.enabled:
+            return
+        known = self._programs.get(name)
+        if known is not None and known[0]() is jitted:
+            return
+        # a span of its own: should this ever build (a ``jax::lower``
+        # or ``jax::backend_compile`` event), it says so as the parent
+        with self.span("Program::table", program=name):
+            try:
+                ops = program_ops(
+                    jitted.lower(*args).compile().as_text())
+            except Exception as e:      # a table is never worth a run
+                ops = {}
+                self.instant("Program::ops::failed", program=name,
+                             error=f"{type(e).__name__}: {e}"[:400])
+        self._programs[name] = (weakref.ref(jitted), ops)
+        if self._annotate:
+            self._record_program(name)
+
+    def _record_program(self, name: str) -> None:
+        stack = self._stack()
+        self._record("Program::ops", time.perf_counter(), 0.0,
+                     stack[-1][0] if stack else None, len(stack),
+                     {"program": name, "ops": self._programs[name][1]})
 
     def close(self) -> None:
         self._close_file()
@@ -296,6 +582,7 @@ class Tracer:
             self._events.clear()
             self._acc.clear()
             self._counters.clear()
+            self._programs.clear()
             self._t0 = time.perf_counter()
 
     # -- spans -----------------------------------------------------------

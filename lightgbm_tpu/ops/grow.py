@@ -308,6 +308,8 @@ def _warn_hist_scatter_fallback(f_log: int, n_shards: int) -> None:
 # (called between lgb.train calls, engine.py) clears these sets so a
 # second training run re-reports the fallbacks ITS configuration takes
 from ..obs.counters import on_reset as _obs_on_reset
+from ..obs.tracer import next_phase, phase, phased
+from ..obs.tracer import tracer as _obs_tracer
 
 _obs_on_reset(_HIST_SCATTER_WARNED.clear)
 
@@ -938,9 +940,16 @@ def make_grow_fn(
         fs_bin = jnp.asarray(forced["bin"], jnp.int32)
         fs_dl = jnp.asarray(forced["default_left"], jnp.bool_)
 
+    # The phases of a tree (obs/tracer.py PHASES): every op below is
+    # traced under exactly one, the code cut at the seams it already
+    # has.  Whatever crosses the mesh scopes itself ``merge`` where it
+    # is written (the root's own merges too), so no call site can
+    # leave a collective out.
+    @phase("merge")
     def _allreduce_sum(x):
         return jax.lax.psum(x, axis_name) if axis_name is not None else x
 
+    @phased
     def grow_core(bins, comb_in, scratch_in, grad, hess, inbag,
                   feature_mask, num_bins, has_nan, is_cat, seed,
                   stream_rate=None, paid_in=None, root_hist_in=None):
@@ -953,6 +962,10 @@ def make_grow_fn(
             n, f = bins.shape   # f = LOCAL feature count (feature sharding)
         b = b_log           # logical (pool / split-search) bin width
         f_log = num_bins.shape[0]   # logical features (== f without EFB)
+        # per-tree start: on the stream route the comb arrives fresh
+        # and this is small; off it, the g / h / w gather by row id,
+        # the comb write and the root histogram are here
+        next_phase("root")
         inbag = inbag.astype(jnp.float32)
 
         if physical:
@@ -1074,6 +1087,7 @@ def make_grow_fn(
                                    cegb_penalty=cegb_pen,
                                    rand_key=rkey)
 
+        @phase("merge")
         def sync_best(si: SplitInfo) -> SplitInfo:
             """Global best split across feature chunks: the reference's
             SyncUpGlobalBestSplit allreduce (parallel_tree_learner.h:191)
@@ -1116,6 +1130,7 @@ def make_grow_fn(
             el_k = min(2 * voting_top_k, int(num_bins.shape[0]))
             top_k = min(voting_top_k, int(num_bins.shape[0]))
 
+            @phase("merge")
             def vote_sync(h_loc, fmask, cegb_pen, leaf_cnt):
                 """PV-tree histogram merge (voting_parallel_tree_learner.cpp
                 :151 GlobalVoting + :184 CopyLocalHistogram): each shard
@@ -1308,6 +1323,7 @@ def make_grow_fn(
             def node_fmask(base, salt):
                 return base
 
+        @phase("merge")
         def merge_kernel_hist(h):
             """Collective tail for kernel-produced histograms (the
             physical comb-direct path bypasses hist_merge): the
@@ -1324,19 +1340,23 @@ def make_grow_fn(
             h = build_histogram(
                 bins_, vals_, padded_bins=padded_bins,
                 rows_per_block=blk_, use_dp=use_dp)
-            if scatter_on:
-                # the reference's Network::ReduceScatter +
-                # HistogramSumReducer (data_parallel_tree_learner.cpp:185)
-                # verbatim: each shard receives ONLY its owned feature
-                # chunk of the merged histogram — half the ICI traffic of
-                # a full psum and 1/n_shards the downstream search work
-                return jax.lax.psum_scatter(
-                    h, axis_name, scatter_dimension=0, tiled=True)
-            if axis_name is not None and not use_voting:
-                # full-histogram merge as one psum over ICI.  In voting
-                # mode the merge is deferred to vote_sync so only elected
-                # features' histograms ride the interconnect.
-                h = jax.lax.psum(h, axis_name)
+            with phase("merge"):
+                if scatter_on:
+                    # the reference's Network::ReduceScatter +
+                    # HistogramSumReducer
+                    # (data_parallel_tree_learner.cpp:185) verbatim:
+                    # each shard receives ONLY its owned feature chunk
+                    # of the merged histogram — half the ICI traffic of
+                    # a full psum and 1/n_shards the downstream search
+                    # work
+                    return jax.lax.psum_scatter(
+                        h, axis_name, scatter_dimension=0, tiled=True)
+                if axis_name is not None and not use_voting:
+                    # full-histogram merge as one psum over ICI.  In
+                    # voting mode the merge is deferred to vote_sync so
+                    # only elected features' histograms ride the
+                    # interconnect.
+                    h = jax.lax.psum(h, axis_name)
             return h
 
         # ---- root ----
@@ -1484,6 +1504,8 @@ def make_grow_fn(
         )
 
         def body(i, st: _GrowState) -> _GrowState:
+            # (traced inside ``while_body``, whose cursor the seams
+            # below move: a split starts as ``glue``)
             # NOTE: the body is UNCONDITIONAL — no lax.cond identity branch.
             # When `done` flips on in this very iteration, every state write
             # is routed to an out-of-bounds index and dropped
@@ -1564,8 +1586,9 @@ def make_grow_fn(
                     own_h = (lf_h >= 0) & (lf_h < f_search)
                     hrow_loc = st.pool[
                         leaf, jnp.clip(lf_h, 0, f_search - 1)][:2]
-                    hrow = jax.lax.psum(
-                        jnp.where(own_h, hrow_loc, 0.0), search_ax)
+                    with phase("merge"):
+                        hrow = jax.lax.psum(
+                            jnp.where(own_h, hrow_loc, 0.0), search_ax)
                 else:
                     hrow = st.pool[leaf, feat][:2]   # [2, B]
                 from .split import derived_counts as _dcnt2
@@ -1577,6 +1600,7 @@ def make_grow_fn(
                 member_f = (jnp.where(is_sub, mem_sub, onehot_b)
                             & cat).astype(jnp.float32)   # [B]
 
+            next_phase("partition")
             # what the partition scans are told (partition_kernel.SEL_*):
             # the comb column, whether the go-left bit is a membership
             # test, and the membership words when sel carries them
@@ -1610,10 +1634,11 @@ def make_grow_fn(
             s0 = st.seg[leaf, 0]
             par_cnt = st.seg[leaf, 1]
             if axis_name is not None:
-                par_sel = jax.lax.pmax(par_cnt, axis_name)
-                # the parent's GLOBAL rows (the reference's global leaf
-                # counts, data_parallel_tree_learner.cpp:270)
-                par_g = jax.lax.psum(par_cnt, axis_name)
+                with phase("merge"):
+                    par_sel = jax.lax.pmax(par_cnt, axis_name)
+                    # the parent's GLOBAL rows (the reference's global
+                    # leaf counts, data_parallel_tree_learner.cpp:270)
+                    par_g = jax.lax.psum(par_cnt, axis_name)
             else:
                 par_sel = par_g = par_cnt
             # the child the finder's record says is smaller (its left
@@ -1641,7 +1666,9 @@ def make_grow_fn(
                           ) > _hook_cross
 
             def make_bucket(size):
+                @phased         # a branch of the switch: its own jaxpr
                 def fn(_):
+                    next_phase("partition")
                     start = jnp.clip(s0, 0, n - size)
                     off = s0 - start
                     idx = jax.lax.dynamic_slice(
@@ -1708,9 +1735,10 @@ def make_grow_fn(
                         # the feature axis (the reference instead
                         # replicates all columns on every rank,
                         # feature_parallel_tree_learner.cpp:60-77)
-                        glb = jax.lax.psum(
-                            jnp.where(owner, glb.astype(jnp.float32),
-                                      0.0), fax) > 0.5
+                        with phase("merge"):
+                            glb = jax.lax.psum(
+                                jnp.where(owner, glb.astype(jnp.float32),
+                                          0.0), fax) > 0.5
                     left_m = pos_ok & glb
                     right_m = pos_ok & ~glb
                     nleft_ = jnp.sum(left_m.astype(jnp.int32))
@@ -1744,9 +1772,9 @@ def make_grow_fn(
                         st.row_order, seg_new, (start,))
                     # smaller child by GLOBAL physical counts so every
                     # shard histograms the same side
-                    nl_g = (jax.lax.psum(nleft_, axis_name)
-                            if axis_name is not None else nleft_)
+                    nl_g = _allreduce_sum(nleft_)
                     small_left_ = nl_g * 2 <= par_g
+                    next_phase("hist")
                     child_m = jnp.where(small_left_, left_m, right_m)
                     vals = v_part * child_m[:, None].astype(jnp.float32)
                     h = hist_merge(b_part, vals,
@@ -1772,7 +1800,9 @@ def make_grow_fn(
                     size // 2, 1)
                 rpb_h = min(rows_per_block, s_child, _HIST_RPB)
 
+                @phased         # a branch of the switch: its own jaxpr
                 def fn(_):
+                    next_phase("partition")
                     nanb_sel = jnp.where(has_nan[feat],
                                          num_bins[feat] - 1,
                                          jnp.int32(-1))
@@ -1789,9 +1819,9 @@ def make_grow_fn(
                         sel = jnp.concatenate([sel, sel_words])
                     combp, scrp, nleft_ = part_fn(sel, st.comb,
                                                   st.scratch)
-                    nlg_ = (jax.lax.psum(nleft_, axis_name)
-                            if axis_name is not None else nleft_)
+                    nlg_ = _allreduce_sum(nleft_)
                     small_left_ = nlg_ * 2 <= par_g
+                    next_phase("hist")
                     child_cnt = jnp.where(small_left_, nleft_,
                                           par_cnt - nleft_)
                     child_start = jnp.where(small_left_, s0, s0 + nleft_)
@@ -1863,9 +1893,9 @@ def make_grow_fn(
                         sel, st.comb, st.scratch, nb_part)
                 # smaller child by GLOBAL counts so every shard
                 # histograms the same side
-                nl_g = (jax.lax.psum(nleft, axis_name)
-                        if axis_name is not None else nleft)
+                nl_g = _allreduce_sum(nleft)
                 small_is_left = nl_g * 2 <= par_g
+                next_phase("hist")
                 child_cnt = jnp.where(small_is_left, nleft,
                                       par_cnt - nleft)
                 child_start = jnp.where(small_is_left, s0, s0 + nleft)
@@ -1917,6 +1947,7 @@ def make_grow_fn(
                     out = jax.lax.switch(bidx, branches, None)
                 (row_order, comb_n, scratch_n, nleft, small_is_left,
                  h_small, paid_n, u2, small_g) = out
+            next_phase("glue")
             side_miss = st.side_miss
             if physical and _use_fused:
                 # counted here, from the parent's rows, the record and
@@ -1943,7 +1974,8 @@ def make_grow_fn(
                     by_set.astype(jnp.int32),
                     jnp.where(by_set, lrow[_SC].astype(jnp.int32), 0)]),
                     (4, 0))
-            h_small = expand(h_small)   # EFB physical -> logical
+            with phase("hist"):
+                h_small = expand(h_small)   # EFB physical -> logical
             rows_parent = par_cnt
 
             # drop-guarded write targets (out of bounds when done)
@@ -1952,10 +1984,12 @@ def make_grow_fn(
             wnode = jnp.where(done, L - 1, node)
             widx2 = jnp.stack([wleaf, wright])
 
-            seg = st.seg.at[wleaf].set(
-                jnp.stack([s0, nleft]), mode="drop")
-            seg = seg.at[wright].set(
-                jnp.stack([s0 + nleft, rows_parent - nleft]), mode="drop")
+            with phase("partition"):
+                seg = st.seg.at[wleaf].set(
+                    jnp.stack([s0, nleft]), mode="drop")
+                seg = seg.at[wright].set(
+                    jnp.stack([s0 + nleft, rows_parent - nleft]),
+                    mode="drop")
 
             # ---- child sums ----
             pg, ph, pc = lrow[_SG], lrow[_SH], lrow[_SC]
@@ -1994,12 +2028,14 @@ def make_grow_fn(
                     small_is_left.astype(jnp.int32)]).astype(jnp.int32)
                 sel_f = jnp.concatenate(
                     [brow, lrow, jnp.zeros(6, jnp.float32)])
-                best_n, lstate_n, nodes_n, seg_n, pool_n = \
-                    apply_find_pool(
-                        sel_i, sel_f, chan4(h_small),
-                        feature_mask.reshape(1, f_log).astype(jnp.float32),
-                        finder_consts, iscat_i, mono_s_t,
-                        st.best, st.lstate, st.nodes, st.seg, st.pool)
+                with phase("find"):
+                    best_n, lstate_n, nodes_n, seg_n, pool_n = \
+                        apply_find_pool(
+                            sel_i, sel_f, chan4(h_small),
+                            feature_mask.reshape(1, f_log).astype(
+                                jnp.float32),
+                            finder_consts, iscat_i, mono_s_t,
+                            st.best, st.lstate, st.nodes, st.seg, st.pool)
                 return st._replace(
                     side_miss=side_miss,
                     row_order=row_order, comb=comb_n, scratch=scratch_n,
@@ -2011,12 +2047,14 @@ def make_grow_fn(
                 )
 
             # ---- subtraction trick (serial_tree_learner.cpp:428) ----
+            next_phase("hist")
             h_parent = jnp.transpose(st.pool[leaf][:, :2, :],
                                      (0, 2, 1))            # [F, B, 2]
             h_left = jnp.where(small_is_left, h_small, h_parent - h_small)
             h_right = h_parent - h_left
             pool = (st.pool.at[wleaf].set(chan4(h_left), mode="drop")
                     .at[wright].set(chan4(h_right), mode="drop"))
+            next_phase("glue")
 
             if use_tail:
                 # interpret-mode kernel tail: pool stays in XLA
@@ -2025,12 +2063,13 @@ def make_grow_fn(
                     nleft, s0, par_cnt, jnp.int32(0)]).astype(jnp.int32)
                 sel_f = jnp.concatenate(
                     [brow, lrow, jnp.zeros(6, jnp.float32)])
-                best_n, lstate_n, nodes_n, seg_n = apply_find(
-                    sel_i, sel_f,
-                    jnp.stack([chan4(h_left), chan4(h_right)]),
-                    feature_mask.reshape(1, f_log).astype(jnp.float32),
-                    finder_consts, iscat_i, mono_s_t,
-                    st.best, st.lstate, st.nodes, st.seg)
+                with phase("find"):
+                    best_n, lstate_n, nodes_n, seg_n = apply_find(
+                        sel_i, sel_f,
+                        jnp.stack([chan4(h_left), chan4(h_right)]),
+                        feature_mask.reshape(1, f_log).astype(jnp.float32),
+                        finder_consts, iscat_i, mono_s_t,
+                        st.best, st.lstate, st.nodes, st.seg)
                 return st._replace(
                     side_miss=side_miss,
                     row_order=row_order, comb=comb_n, scratch=scratch_n,
@@ -2153,16 +2192,17 @@ def make_grow_fn(
                                    jax.random.fold_in(_et_base, i * 2 + 2)])
             else:
                 rkeys = jnp.zeros((2, 2), jnp.uint32)
-            si: SplitInfo = jax.vmap(
-                finder, in_axes=(0, 0, 0, 0, 0, None, None, None, 0,
-                                 0, 0, 0, cegb_in_axes, 0)
-            )(finder_h,
-              jnp.stack([lg, rg]), jnp.stack([lh, rh]),
-              jnp.stack([lc, rc]),
-              jnp.stack([d_child, d_child]),
-              num_bins, has_nan, is_cat, fmask_pair,
-              jnp.stack([l_mn, r_mn]), jnp.stack([l_mx, r_mx]),
-              jnp.stack([lo, ro]), cegb_pen_child, rkeys)
+            with phase("find"):
+                si: SplitInfo = jax.vmap(
+                    finder, in_axes=(0, 0, 0, 0, 0, None, None, None, 0,
+                                     0, 0, 0, cegb_in_axes, 0)
+                )(finder_h,
+                  jnp.stack([lg, rg]), jnp.stack([lh, rh]),
+                  jnp.stack([lc, rc]),
+                  jnp.stack([d_child, d_child]),
+                  num_bins, has_nan, is_cat, fmask_pair,
+                  jnp.stack([l_mn, r_mn]), jnp.stack([l_mx, r_mx]),
+                  jnp.stack([lo, ro]), cegb_pen_child, rkeys)
             si = sync_best(si)
             best = st.best.at[widx2].set(_pack_si(si), mode="drop")
 
@@ -2240,13 +2280,16 @@ def make_grow_fn(
                         salts.astype(jnp.int32))
                 else:
                     rkeys_all = jnp.zeros((L, 2), jnp.uint32)
-                si_all = jax.vmap(
-                    finder, in_axes=(0, 0, 0, 0, 0, None, None, None, 0,
-                                     0, 0, 0, None, 0))(
-                    h_all, lstate[:, _SG], lstate[:, _SH],
-                    lstate[:, _SC], lstate[:, _SDEP], num_bins, has_nan,
-                    is_cat, fml, lstate[:, _SMN], lstate[:, _SMX],
-                    lstate[:, _SOUT], cegb_pen_child, rkeys_all)
+                with phase("find"):
+                    si_all = jax.vmap(
+                        finder,
+                        in_axes=(0, 0, 0, 0, 0, None, None, None, 0,
+                                 0, 0, 0, None, 0))(
+                        h_all, lstate[:, _SG], lstate[:, _SH],
+                        lstate[:, _SC], lstate[:, _SDEP], num_bins,
+                        has_nan, is_cat, fml, lstate[:, _SMN],
+                        lstate[:, _SMX], lstate[:, _SOUT],
+                        cegb_pen_child, rkeys_all)
                 si_all = sync_best(si_all)
                 best = jnp.where(changed[:, None], _pack_si(si_all),
                                  best)
@@ -2272,10 +2315,15 @@ def make_grow_fn(
             i, st = carry
             return (i < L - 1) & ~st.done
 
+        @phased                 # the loop body: its own jaxpr
         def while_body(carry):
+            next_phase("glue")
             i, st = carry
             return i + 1, body(i, st)
 
+        # the loop itself (its condition, its carry) is glue, and so is
+        # whatever a split does under no seam of ``body``
+        next_phase("glue")
         _, state = jax.lax.while_loop(
             while_cond, while_body, (jnp.int32(0), state))
 
@@ -2303,6 +2351,7 @@ def make_grow_fn(
             cat_members=state.cat_members,
             side_miss=state.side_miss,
         )
+        next_phase("leafrows")
         # reconstruct the per-row leaf assignment ONCE from the partition
         # (row_order/permuted rows + seg tile [0, n)), instead of
         # scattering a [n] leaf_id vector on every split: a position's
@@ -2334,6 +2383,7 @@ def make_grow_fn(
                 leaf_of_pos)
         if debug_state:
             return tree, leaf_id, state.best, state.lstate
+        next_phase("glue")
         if shard_counter:
             # this shard's own counts, u32 [2]: the sum of parent rows
             # over the tree's splits - a leaf's rows were scanned once
@@ -2356,6 +2406,7 @@ def make_grow_fn(
             # inside their leaf's segment), then g/h recompute from the
             # new scores — one streaming pass, no gathers.  Mirrors the
             # async score-update tail in gbdt (rate * leaf_value[leaf]).
+            next_phase("refresh")
             if _fused_root:
                 # fused refresh: the pass that rewrites scores/gradients
                 # also accumulates the NEXT tree's root histogram from
@@ -2702,16 +2753,19 @@ class _PhysicalGrow:
             # calls (each tree's refresh pass builds the next one)
             if self._root_hist is None:
                 self._root_hist = self._root0_fn(comb)
-            out = self._grow_p(
-                comb, self._scratch, grad, hess, inbag,
-                feature_mask, num_bins, has_nan, is_cat, seed, rate,
-                self._root_hist)
+            args = (comb, self._scratch, grad, hess, inbag,
+                    feature_mask, num_bins, has_nan, is_cat, seed, rate,
+                    self._root_hist)
+            out = self._grow_p(*args)
             ta, leaf_id, comb_n, self._scratch, self._root_hist = out[:5]
         else:
-            out = self._grow_p(
-                comb, self._scratch, grad, hess, inbag,
-                feature_mask, num_bins, has_nan, is_cat, seed, rate)
+            args = (comb, self._scratch, grad, hess, inbag,
+                    feature_mask, num_bins, has_nan, is_cat, seed, rate)
+            out = self._grow_p(*args)
             ta, leaf_id, comb_n, self._scratch = out[:4]
+        # while tracing: what this program's instruction names mean
+        # (once; the donated buffers are asked only their shapes)
+        _obs_tracer.program("grow", self._grow_p, *args)
         self._put_window(comb_n)
         return ta, leaf_id
 
@@ -2743,8 +2797,9 @@ class _PhysicalGrow:
                     ta, lid, comb_n, scr_n = out[:4]
                     return (comb_n, scr_n), (ta, lid)
 
-                (comb, scratch), ys = jax.lax.scan(
-                    body, (comb, scratch), (gradK, hessK, fmK, seedK))
+                with phase("glue"):
+                    (comb, scratch), ys = jax.lax.scan(
+                        body, (comb, scratch), (gradK, hessK, fmK, seedK))
                 return ys[0], ys[1], comb, scratch
 
             self._grow_batch_p = jax.jit(_scan_k, donate_argnums=(0, 1))

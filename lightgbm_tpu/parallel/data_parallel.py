@@ -38,6 +38,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.grow import (MeshPhysicalPieces, TreeArrays, make_grow_fn,
                         phys_init_comb)
+from ..obs.tracer import phase
 from ..ops.split import SplitHyperParams
 from ..utils import log
 from .mesh import DATA_AXIS, build_mesh, pad_rows_to_shards
@@ -197,8 +198,10 @@ class DataParallelGrower:
                         has_nan, is_cat, sd, jnp.float32(0.0))
                     return (comb_n, scr_n), (tree, lid, rows)
 
-                (comb, scratch), (treeK, lidK, rowsK) = jax.lax.scan(
-                    body, (comb, scratch), (gradK, hessK, fmK, seedK))
+                with phase("glue"):
+                    (comb, scratch), (treeK, lidK, rowsK) = jax.lax.scan(
+                        body, (comb, scratch),
+                        (gradK, hessK, fmK, seedK))
                 return treeK, lidK, comb, scratch, rowsK
 
             self._sharded_batch = jax.jit(jax.shard_map(
@@ -358,20 +361,24 @@ class DataParallelGrower:
                 physical=self.physical) as sp:
             if not self.physical:
                 self._f_pad = int(bins.shape[1])
-                tree, leaf_id, self.last_shard_rows = self._sharded_grow(
-                    bins, grad, hess, inbag, feature_mask, num_bins,
-                    has_nan, is_cat, jnp.int32(seed))
-                sp.block_on(leaf_id)
+                program = self._sharded_grow
+                args = (bins, grad, hess, inbag, feature_mask, num_bins,
+                        has_nan, is_cat, jnp.int32(seed))
+                tree, leaf_id, self.last_shard_rows = program(*args)
             else:
                 if self._comb is None:
                     self._comb = self._sharded_init(self._bins_global)
                     self._scratch = jnp.zeros_like(self._comb)
+                program = self._sharded_core
+                args = (self._comb, self._scratch, grad, hess, inbag,
+                        feature_mask, num_bins, has_nan, is_cat,
+                        jnp.int32(seed), jnp.float32(0.0))
                 (tree, leaf_id, self._comb, self._scratch,
-                 self.last_shard_rows) = self._sharded_core(
-                    self._comb, self._scratch, grad, hess, inbag,
-                    feature_mask, num_bins, has_nan, is_cat,
-                    jnp.int32(seed), jnp.float32(0.0))
-                sp.block_on(leaf_id)
+                 self.last_shard_rows) = program(*args)
+            # while tracing: what this program's instruction names
+            # mean (once; a donated buffer is asked only its shape)
+            obs_tracer.program("grow", program, *args)
+            sp.block_on(leaf_id)
         # ledger record OUTSIDE the span: the wall must include the
         # span-exit device barrier, or the collective cost reads as the
         # async enqueue time

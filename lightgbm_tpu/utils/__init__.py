@@ -1,4 +1,3 @@
 from . import log
-from .timer import global_timer
 
-__all__ = ["log", "global_timer"]
+__all__ = ["log"]

@@ -19,8 +19,17 @@ def cache_dir() -> str:
 
 
 def enable_compile_cache() -> None:
-    """Call before the first jit of an entry point."""
+    """Call before the first jit of an entry point.
+
+    An instruction's ``metadata`` is part of how a cached program is
+    found: JAX leaves it out of the key by default, and a program that
+    differs from a cached one only in its ``lgbm.<phase>`` scopes
+    would come back with the old ones, which is what a traced run's
+    ``Program::ops`` table is read from (``obs/tracer.py``; PR 38: the
+    gradient programs came back from the parent's entries with no
+    phase at all)."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get(_ENV):
         return
-    import jax
     jax.config.update("jax_compilation_cache_dir", cache_dir())

@@ -312,21 +312,18 @@ def _assert_one_scan_no_comb_copy(text, comb):
     assert comb_sized and not [o for o in comb_sized if o.startswith("copy")]
 
 
-@pytest.mark.parametrize("crossover", [0, 5000, None],
-                         ids=["never_hook", "mid", "always_hook"])
-def test_the_grow_program_compiles_with_one_scan_and_no_comb_copy(
-        crossover, one_chip, no_compile_cache, monkeypatch):
-    """ISSUE 35: the WHOLE grow program of the ``higgs`` route at the
-    ``higgs-train-10m`` shape (stream, fused, 255 leaves, 10.5M rows)
-    through the v5e compiler, whatever the hook's crossover: one
-    ``lgbm_split_scan`` (the kernel takes the third state, there is no
-    second scan), the comb-direct ``lgbm_hist`` inside the one
-    conditional, no comb-sized ``copy`` anywhere (the cond's branches
-    only read the comb: the cell stands at 97% of the chip's memory)
-    and temporaries far under one comb.  ``make_grow_fn`` asks
-    ``jax.default_backend()`` for its route; the test answers for the
-    described chip."""
-    import re
+# The whole grow programs, each through the v5e compiler once for all
+# the tests that read it: (compiled text, temporary bytes, comb shape).
+_GROW_PROGRAMS = {}
+
+
+def _serial_grow_program(one_chip, n, f, stream, crossover=None,
+                         scoped=True):
+    """``make_grow_fn`` asks ``jax.default_backend()`` for its route;
+    the builder answers for the described chip.  ``scoped=False``
+    builds the program with the phases of ``obs/tracer.py`` taken out."""
+    import contextlib
+    import sys
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.registry import sds
@@ -334,50 +331,183 @@ def test_the_grow_program_compiles_with_one_scan_and_no_comb_copy(
     from lightgbm_tpu.ops.pallas import fused_split
     from lightgbm_tpu.ops.pallas.layout import comb_shape
     from lightgbm_tpu.ops.split import SplitHyperParams
-    n, f = 10_500_096, F_PAD
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    key = (n, f, stream, crossover, scoped)
+    if key in _GROW_PROGRAMS:
+        return _GROW_PROGRAMS[key]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        if crossover is not None:
+            patch.setattr(fused_split, "hook_crossover_rows",
+                          lambda ngroups: crossover)
+        if not scoped:
+            patch.setattr(sys.modules["lightgbm_tpu.obs.tracer"],
+                          "_phase_scope",
+                          lambda name: contextlib.nullcontext())
+        gp = make_grow_fn(
+            SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
+            padded_bins=BINS, physical_bins=sds((n, f), jnp.uint8),
+            **({"stream": {"kind": "binary", "sigmoid": 1.0, "count": n}}
+               if stream else {}))
+        assert gp.fused and (gp._root0_fn is not None) == stream
+        # ISSUE 37: the scan of this program moves what the function
+        # gives a comb of its width
+        assert gp.scan_block_rows == _scan_rows((0, 0, gp._C, f)) == (
+            2048 if gp._C == 128 else 1024)
+        comb = comb_shape(gp._n_alloc, gp._C)
+        rows = sds((1,) if stream else (n,), jnp.float32)
+        args = [sds(comb, jnp.float32)] * 2 + [rows] * 3 + [
+            sds((f,), jnp.float32), sds((f,), jnp.int32),
+            sds((f,), jnp.bool_), sds((f,), jnp.bool_), sds((), jnp.int32),
+            sds((), jnp.float32)]
+        if stream:
+            args.append(sds((f, BINS, 2), jnp.float32))
+        compiled = gp._grow_p.lower(*(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in args)).compile()
+    out = _GROW_PROGRAMS[key] = (
+        compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes,
+        comb)
+    return out
+
+
+def _higgs_grow_program(one_chip, crossover=5000, scoped=True):
+    """The ``higgs`` route at the ``higgs-train-10m`` shape (stream,
+    fused, 255 leaves, 10.5M rows, one plane)."""
+    return _serial_grow_program(one_chip, 10_500_096, F_PAD, True,
+                                crossover, scoped)
+
+
+def _msltr_grow_program(one_chip):
+    """The non-stream route at the ``msltr-train-2m`` shape (fused, 255
+    leaves, 2.27M rows, 144 columns over two planes, the XLA finder)."""
+    return _serial_grow_program(one_chip, MSLTR[0], MSLTR[3], False)
+
+
+@pytest.mark.parametrize("crossover", [0, 5000, None],
+                         ids=["never_hook", "mid", "always_hook"])
+def test_the_grow_program_compiles_with_one_scan_and_no_comb_copy(
+        crossover, one_chip, no_compile_cache):
+    """ISSUE 35: the WHOLE grow program of the ``higgs`` route at the
+    ``higgs-train-10m`` shape (stream, fused, 255 leaves, 10.5M rows)
+    through the v5e compiler, whatever the hook's crossover: one
+    ``lgbm_split_scan`` (the kernel takes the third state, there is no
+    second scan), the comb-direct ``lgbm_hist`` inside the one
+    conditional, no comb-sized ``copy`` anywhere (the cond's branches
+    only read the comb: the cell stands at 97% of the chip's memory)
+    and temporaries far under one comb."""
+    import re
+    from lightgbm_tpu.ops.pallas import fused_split
     if crossover is None:
         crossover = fused_split.HOOK_ALWAYS
-    monkeypatch.setattr(fused_split, "hook_crossover_rows",
-                        lambda ngroups: crossover)
-    gp = make_grow_fn(
-        SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
-        padded_bins=BINS, physical_bins=sds((n, f), jnp.uint8),
-        stream={"kind": "binary", "sigmoid": 1.0, "count": n})
-    assert gp.fused and gp._root0_fn is not None
-    # ISSUE 37: the scan of this program moves what the function gives
-    # a one-plane comb
-    assert gp.scan_block_rows == _scan_rows((0, 0, gp._C, f)) == 2048
-    comb = comb_shape(gp._n_alloc, gp._C)
-    args = [sds(comb, jnp.float32)] * 2 + [sds((1,), jnp.float32)] * 3 + [
-        sds((f,), jnp.float32), sds((f,), jnp.int32), sds((f,), jnp.bool_),
-        sds((f,), jnp.bool_), sds((), jnp.int32), sds((), jnp.float32),
-        sds((f, BINS, 2), jnp.float32)]
-    compiled = gp._grow_p.lower(*(
-        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
-        for a in args)).compile()
-    text = compiled.as_text()
+    text, temp_bytes, comb = _higgs_grow_program(one_chip, crossover)
     _assert_one_scan_no_comb_copy(text, comb)
     # the comb-direct histogram sits in a branch computation, not in
     # the loop body beside the scan
     hists = re.findall(r"%lgbm_hist(?:\.\d+)? = [^\n]*op_name=\"([^\"]*)\"",
                        text)
-    assert len(hists) == 1 and "/while/body/cond/branch_1_fun/" in hists[0]
-    mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < comb[0] * comb[1] * 4 // 8
+    assert len(hists) == 1 and re.search(
+        r"/while/body/lgbm\.hist/cond/branch_1_fun/", hists[0])
+    assert temp_bytes < comb[0] * comb[1] * 4 // 8
 
 
-def test_the_mesh_grow_program_adds_no_collective(topo, no_compile_cache,
-                                                  monkeypatch):
-    """ISSUE 35 on the mesh: the data-parallel grow program of
-    ``higgs-data4-train-21m`` (5.25M rows a shard, four shards) through
-    the v5e compiler.  Whether the hook runs is decided from the leaf
-    record's replicated count, so the split still pays the collectives
-    it paid before - per split one reduce-scatter of the histogram, ONE
-    all-reduce for both row counts (a psum ahead of the scan could not
-    share it), the election's pmin / pmax; the root pays its own - and
-    none sits inside the conditional; one scan, no comb-sized copy."""
+# ISSUE 38: the ops a capture's ``XLA Ops`` line can show, by the phase
+# the program wrote them under (``obs/tracer.program_ops``, which is
+# what a traced run's ``Program::ops`` event holds).  Under no phase
+# are only ops the program did not write: what the compiler adds
+# without metadata (copies and moves between memory spaces,
+# broadcasts, the pieces it cuts a ``reduce-window`` into) and the
+# cached lowering of ``jnp.cumsum`` (``op_name="reduce_window_sum"``).
+# Counted, so that a scope lost in a later change shows here.
+UNPHASED_AT_MOST = {"higgs": 64, "msltr": 120, "mesh": 130}
+NEVER_UNPHASED = ("lgbm_", "while", "conditional", "sort", "scatter",
+                  "all-reduce", "reduce-scatter", "all-gather",
+                  "collective-permute", "pmin", "pmax", "psum")
+
+
+def _phase_table(which, one_chip, topo):
+    from lightgbm_tpu.obs.tracer import program_ops
+    text = (_higgs_grow_program(one_chip)[0] if which == "higgs"
+            else _msltr_grow_program(one_chip)[0] if which == "msltr"
+            else _mesh_grow_program(topo)[0])
+    return text, program_ops(text)
+
+
+@pytest.mark.parametrize("which", ["higgs", "msltr", "mesh"])
+def test_every_op_the_grow_program_wrote_has_a_phase(
+        which, one_chip, topo, no_compile_cache):
     import re
+    from lightgbm_tpu.obs.tracer import PHASES
+    text, ops = _phase_table(which, one_chip, topo)
+    # every instruction that carries the program's own op_name - at the
+    # top level, in a loop body or a branch, inside a fusion - is under
+    # a phase
+    # (not the program's: ``cumsum``'s pieces, and what the partitioner
+    # of the mesh program makes, named after the container alone or
+    # after one instruction, ``.../shard_map/slice.395``: no path)
+    written = [n for n in re.findall(
+        r'op_name="jit\([^"/]*\)/(?:shard_map/)?([^"]+)"', text)
+        if n != "reduce_window_sum" and (which != "mesh" or "/" in n)]
+    assert len(written) > 500
+    lost = [n for n in written if not re.match(
+        r"(?:.*/)?lgbm\.(?:" + "|".join(PHASES) + r")(?:/|$)", n)]
+    assert lost == []
+    want = {"root", "hist", "find", "partition", "glue", "leafrows"}
+    want |= {"refresh"} if which == "higgs" else set()
+    want |= {"merge"} if which == "mesh" else set()
+    assert set(ops) - {""} == want
+    unphased = ops.get("", [])
+    assert len(unphased) <= UNPHASED_AT_MOST[which], len(unphased)
+    assert not [k for k in unphased if k.startswith(NEVER_UNPHASED)]
+    # the kernels, by the phase they serve
+    where = {k.split(".")[0].split(" ")[0]: ph for ph, keys in ops.items()
+             for k in keys if k.startswith("lgbm_")}
+    assert where["lgbm_split_scan"] == where["lgbm_copyback"] == "partition"
+    if which == "higgs":
+        assert where["lgbm_hist"] == "hist"
+        assert where["lgbm_refresh"] == "refresh"
+        assert where["lgbm_apply_find"] == "find"
+    else:
+        hists = sorted(ph for ph, keys in ops.items() for k in keys
+                       if k.startswith("lgbm_hist"))
+        assert hists == ["hist", "root"]
+    if which == "mesh":
+        crossing = {ph for ph, keys in ops.items() for k in keys
+                    if k.startswith(("all-reduce", "reduce-scatter",
+                                     "psum", "pmin", "pmax"))}
+        assert crossing == {"merge"}
+
+
+def _without_names(text):
+    """A compiled module's text without what a scope may touch: each
+    instruction's ``metadata``, the stack-frame tables under the
+    header, and the locations inside a Mosaic kernel's payload (the
+    bytecode ``body``: a scope above a ``pallas_call`` is in the
+    locations of the kernel's ops, PR 27)."""
+    import re
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r'"body":"[^"]*"', '"body":""', text)
+    lines = text.splitlines()
+    first = next(i for i, l in enumerate(lines)
+                 if re.match(r"(ENTRY )?%", l))
+    return [lines[0]] + lines[first:]
+
+
+def test_the_scopes_add_names_and_nothing_else(one_chip, no_compile_cache):
+    """ISSUE 38 (c): the ``higgs`` grow program compiled with the
+    phases taken out (the helper's scope patched to a null context) is
+    the same module: same instructions, same names and numbers, same
+    schedule."""
+    scoped = _higgs_grow_program(one_chip)
+    bare = _higgs_grow_program(one_chip, scoped=False)
+    assert "lgbm.partition" in scoped[0] and "lgbm." not in bare[0]
+    assert _without_names(scoped[0]) == _without_names(bare[0])
+    assert scoped[1:] == bare[1:]
+
+
+def _mesh_grow_program(topo):
+    """The data-parallel grow program of ``higgs-data4-train-21m``
+    (5.25M rows a shard, four shards): (compiled text, comb lines and
+    lanes a shard)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -387,34 +517,54 @@ def test_the_mesh_grow_program_adds_no_collective(topo, no_compile_cache,
     from lightgbm_tpu.ops.split import SplitHyperParams
     from lightgbm_tpu.parallel.data_parallel import (DATA_AXIS,
                                                      DataParallelGrower)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if "mesh" in _GROW_PROGRAMS:
+        return _GROW_PROGRAMS["mesh"]
     mesh = Mesh(np.array(topo.devices), (DATA_AXIS,))
     # 21,000,000 rows pad to whole 2,048-row blocks a shard
     shards, n_loc, f = len(topo.devices), 5_251_072, F_PAD
-    grower = DataParallelGrower(
-        SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
-        padded_bins=BINS, mesh=mesh,
-        physical_bins=sds((shards * n_loc, f), jnp.uint8))
-    assert grower.fused and grower.hist_scatter
-    # ISSUE 37: every shard's scan moves the block the function gives
-    # this width, and a shard's comb stays inside what the four-chip
-    # cell's ``correct`` allows: its 5,250,000 rows + 8,192 lines
-    assert grower.scan_block_rows == _scan_rows(
-        (0, 0, grower._pieces.C, f)) == 2048
-    assert grower._pieces.n_alloc <= 5_250_000 + 8_192
-    lines, lanes = comb_shape(grower._pieces.n_alloc, grower._pieces.C)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        grower = DataParallelGrower(
+            SplitHyperParams(min_data_in_leaf=20), num_leaves=LEAVES,
+            padded_bins=BINS, mesh=mesh,
+            physical_bins=sds((shards * n_loc, f), jnp.uint8))
+        assert grower.fused and grower.hist_scatter
+        # ISSUE 37: every shard's scan moves the block the function
+        # gives this width, and a shard's comb stays inside what the
+        # four-chip cell's ``correct`` allows: its 5,250,000 rows +
+        # 8,192 lines
+        assert grower.scan_block_rows == _scan_rows(
+            (0, 0, grower._pieces.C, f)) == 2048
+        assert grower._pieces.n_alloc <= 5_250_000 + 8_192
+        lines, lanes = comb_shape(grower._pieces.n_alloc, grower._pieces.C)
 
-    def arg(shape, dtype, *spec):
-        return jax.ShapeDtypeStruct(shape, dtype,
-                                    sharding=NamedSharding(mesh, P(*spec)))
+        def arg(shape, dtype, *spec):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
 
-    comb = arg((shards * lines, lanes), jnp.float32, DATA_AXIS, None)
-    row = arg((shards * n_loc,), jnp.float32, DATA_AXIS)
-    text = grower._sharded_core.lower(
-        comb, comb, row, row, row, arg((f,), jnp.float32),
-        arg((f,), jnp.int32), arg((f,), jnp.bool_), arg((f,), jnp.bool_),
-        arg((), jnp.int32), arg((), jnp.float32)).compile().as_text()
-    _assert_one_scan_no_comb_copy(text, (lines, lanes))
+        comb = arg((shards * lines, lanes), jnp.float32, DATA_AXIS, None)
+        row = arg((shards * n_loc,), jnp.float32, DATA_AXIS)
+        text = grower._sharded_core.lower(
+            comb, comb, row, row, row, arg((f,), jnp.float32),
+            arg((f,), jnp.int32), arg((f,), jnp.bool_),
+            arg((f,), jnp.bool_), arg((), jnp.int32),
+            arg((), jnp.float32)).compile().as_text()
+    out = _GROW_PROGRAMS["mesh"] = (text, (lines, lanes))
+    return out
+
+
+def test_the_mesh_grow_program_adds_no_collective(topo, no_compile_cache):
+    """ISSUE 35 on the mesh: the data-parallel grow program of
+    ``higgs-data4-train-21m`` (5.25M rows a shard, four shards) through
+    the v5e compiler.  Whether the hook runs is decided from the leaf
+    record's replicated count, so the split still pays the collectives
+    it paid before - per split one reduce-scatter of the histogram, ONE
+    all-reduce for both row counts (a psum ahead of the scan could not
+    share it), the election's pmin / pmax; the root pays its own - and
+    none sits inside the conditional; one scan, no comb-sized copy."""
+    import re
+    text, comb = _mesh_grow_program(topo)
+    _assert_one_scan_no_comb_copy(text, comb)
     # root + split: the parent commit's program reads the same counts
     assert text.count(" reduce-scatter(") == 2
     assert text.count(" all-reduce(") == 10

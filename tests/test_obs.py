@@ -447,7 +447,7 @@ def test_training_trace_has_nested_grow_phases(tmp_path):
     parents = {
         "Train::iteration": None, "GBDT::TrainOneIter": "Train::iteration",
         "BeforeTrain": "GBDT::TrainOneIter", "Boosting": "BeforeTrain",
-        "Boosting::wait": "Boosting", "HbmCensus": "GBDT::TrainOneIter",
+        "Boosting::wait": "Boosting",
         "GradSlice": "GBDT::TrainOneIter",
         "Tree::grow::wait": "Tree::grow", "WorkCounters": "Tree::grow",
         "UpdateScore": "GBDT::TrainOneIter",
@@ -458,8 +458,21 @@ def test_training_trace_has_nested_grow_phases(tmp_path):
     for name, parent in parents.items():
         assert name in spans, f"missing span {name}"
         assert spans[name]["args"].get("parent") == parent, name
-    # the root-scale sampled probes went with their producer
-    for name in ("ConstructHistogram", "FindBestSplits", "Split"):
+    # the censuses of a dispatched program run under it, between the
+    # dispatch and its barrier (ISSUE 38): the device does not wait
+    census = {e["args"]["phase"]: e for e in events
+              if e["ph"] == "X" and e["name"] == "HbmCensus"}
+    assert {p: e["args"]["parent"] for p, e in census.items()} == {
+        "BeforeTrain": "GBDT::TrainOneIter", "Tree::grow": "Tree::grow",
+        "UpdateScore": "UpdateScore"}
+    for phase, wait in (("Tree::grow", "Tree::grow::wait"),
+                        ("UpdateScore", "UpdateScore::wait")):
+        assert census[phase]["ts"] + census[phase]["dur"] \
+            <= spans[wait]["ts"]
+    # the root-scale sampled probes went with their producer, the
+    # legacy timer's twin of ``Tree::grow`` with the timer
+    for name in ("ConstructHistogram", "FindBestSplits", "Split",
+                 "GBDT::grow"):
         assert name not in spans
     assert spans["BeforeTrain"]["args"]["parent"] == "GBDT::TrainOneIter"
     # TraceCallback history carries the counter telemetry
