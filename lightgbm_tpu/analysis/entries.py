@@ -22,6 +22,8 @@ Registered here:
 """
 from __future__ import annotations
 
+import functools
+
 from .registry import (register_kernel, register_mesh_config,
                        register_purity_pin, sds)
 
@@ -258,8 +260,31 @@ def _pin_paged_off():
     if unpaged._root0_fn is not None:
         args.append(sds((f, b, 2), jnp.float32))
     args = tuple(args)
-    return [("unpaged", unpaged._grow_p, args),
-            ("paged", paged._grow_p, args)]
+    # ISSUE 39: the paged build still returns the row-order ``leaf_id``
+    # (its pages leave the device between trees, so its booster keeps
+    # the train score every tree); the unpaged build returns None
+    # there.  The pin holds everything else: the program that grows
+    # the tree and refreshes the comb.
+    return [("unpaged", _without_leaf_id(unpaged._grow_p, args), args),
+            ("paged", _without_leaf_id(paged._grow_p, args), args)]
+
+
+def _without_leaf_id(grow_p, args):
+    """``grow_p`` less its second output (``leaf_id``) and the
+    equations only that output needs."""
+    import jax
+    from jax._src.interpreters import partial_eval as pe
+    # the program itself, out of the one ``pjit`` that wraps it: traced
+    # again in line, a constant nothing reads any more is not in it
+    (eqn,) = jax.make_jaxpr(grow_p)(*args).jaxpr.eqns
+    inner = eqn.params["jaxpr"]
+    out = jax.eval_shape(grow_p, *args)
+    first = len(jax.tree.leaves(out[0]))
+    width = len(jax.tree.leaves(out[1]))
+    used = [not first <= i < first + width
+            for i in range(len(inner.jaxpr.outvars))]
+    jaxpr, _ = pe.dce_jaxpr(inner.jaxpr, used, instantiate=True)
+    return functools.partial(jax.core.eval_jaxpr, jaxpr, inner.consts)
 
 
 @register_purity_pin("grow-tracer-live")

@@ -818,12 +818,15 @@ def _run_feval(booster: Booster, feval, dataset_name: str) -> List:
     out = []
     fevals = feval if isinstance(feval, (list, tuple)) else [feval]
     inner = booster._inner
-    datasets = {"training": (inner.train_score, inner.train_set)}
-    for vs in inner.valid_sets:
-        datasets[vs.name] = (vs.score, vs.data)
-    if dataset_name not in datasets:
+    # (the train score is read only when it is asked for: on the stream
+    # route a read runs a program, models/gbdt.py ``train_score``)
+    datasets = {vs.name: (vs.score, vs.data) for vs in inner.valid_sets}
+    if dataset_name == "training":
+        score, bds = inner.train_score, inner.train_set
+    elif dataset_name in datasets:
+        score, bds = datasets[dataset_name]
+    else:
         return out
-    score, bds = datasets[dataset_name]
     prob, raw_s = inner._converted_scores(score)
     # scores are padded to the device row layout; feval sees num_data rows
     prob = np.asarray(prob)[..., :bds.num_data]

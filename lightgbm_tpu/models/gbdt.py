@@ -474,6 +474,8 @@ class GBDT:
                     **self._grow_kwargs,
                 )
                 self._numerics_in_grow = self._numerics != "off"
+                self._lazy_score = bool(
+                    getattr(self.grow, "lazy_score", False))
                 if use_stream:
                     # rate read per call: reset_parameter callbacks may
                     # change learning_rate mid-training
@@ -879,6 +881,7 @@ class GBDT:
         reset = getattr(self.grow, "reset_stream", None)
         if reset is None:
             return
+        self._sync_train_score()
         from ..config import env_knob
         if env_knob("LGBM_TPU_CKPT_AT_REFRESH") == "1":
             inplace = getattr(self.grow, "reanchor_inplace", None)
@@ -971,6 +974,55 @@ class GBDT:
     _numerics_in_grow = False  # serial learner: sentinel lives in-grow
 
     _routing = None   # RouteDecision of the engaged path (ISSUE 10)
+
+    # ------------------------------------------------------------------
+    # the row-order train score [K, n_pad].  Off the unpaged stream
+    # route it is kept every tree.  On it (``_lazy_score``) the comb's
+    # score columns are the score of record - the refresh adds every
+    # tree's shrunk outputs there, by position, and no training
+    # iteration reads a row-order score -, ``_train_score`` is the last
+    # value somebody asked for and ``_score_behind`` counts the trees
+    # grown since: a read runs ``grow.pull_score()`` once (one program:
+    # ``TrainScore::materialise``) and clears the count, an assignment
+    # stores and clears it.  Whatever drops or rebuilds the comb reads
+    # the score first (``_sync_train_score``): after ``reset_stream``
+    # the only up-to-date copy is gone.  A traced run's barriers wait
+    # on what a program returned, never on the property.
+    #
+    # A numerics policy of ``raise`` / ``skip`` keeps such a booster
+    # EAGER: it pulls after every tree the sentinel let through, so
+    # that a dropped tree (whose outputs the comb already holds)
+    # leaves ``_train_score`` at the last-good score, from which the
+    # comb is then rebuilt.
+    _train_score = None
+    _score_behind = 0
+    _lazy_score = False
+
+    @property
+    def train_score(self):
+        self._sync_train_score()
+        return self._train_score
+
+    @train_score.setter
+    def train_score(self, value) -> None:
+        self._train_score = value
+        self._score_behind = 0
+
+    def _sync_train_score(self) -> None:
+        """Bring ``_train_score`` up to date: for a reader, and before
+        the comb, the only other copy, is dropped or rebuilt."""
+        if not self._score_behind:
+            return
+        with obs_tracer.span("TrainScore::materialise",
+                             trees_behind=self._score_behind) as _sp:
+            obs_events.record("TrainScore::materialise")
+            score = self.grow.pull_score()
+            if score is None:
+                raise RuntimeError(
+                    f"the comb was dropped with {self._score_behind} "
+                    "trees the booster's train score does not hold")
+            self.train_score = score
+            _sp.block_on(score)
 
     def _stream_aux(self):
         """Aux rows for the streaming init kernel: [2 + n_consts, n_pad]
@@ -1468,7 +1520,9 @@ class GBDT:
                     tree_seed)
             if obs_tracer.enabled:
                 self._sample_phase_hbm("Tree::grow")
-                _gsp.wait(leaf_id)
+                # (an output of the program: with no row-order leaf id,
+                # its tree)
+                _gsp.wait(ta if leaf_id is None else leaf_id)
                 self._record_work_counters(_gsp, ta, [kidx])
         if (self._numerics in ("raise", "skip")
                 and getattr(self.grow, "last_numerics_bad", None)
@@ -1483,6 +1537,10 @@ class GBDT:
                     # the dropped tree must not leave features marked
                     # paid-for by a tree that will never exist
                     self._cegb_paid = cegb_prev
+                if self._lazy_score:
+                    # the comb already holds the dropped tree's
+                    # outputs: rebuild it from the last-good score
+                    self.grow.reset_stream()
                 if self._numerics == "raise":
                     raise resilience_numerics.NumericalFault(
                         "grad/hess/leaf/gain", self.iter_, bad)
@@ -1495,7 +1553,9 @@ class GBDT:
         if fast:
             with obs_tracer.span("UpdateScore") as _usp:
                 r = self._finish_tree_async(ta, leaf_id, kidx, init_score)
-                _usp.block_on(self.train_score)
+                # the tail's outputs; never the property, which would
+                # run the pull
+                _usp.block_on((self._train_score, self._device_trees[-1]))
                 if obs_tracer.enabled:
                     self._sample_phase_hbm("UpdateScore")
             return r
@@ -1613,9 +1673,15 @@ class GBDT:
         @obs_phase("score")
         def tail(ta, leaf_id, score_k, vbins, vscores_k, rate, init_score):
             is_real = ta.num_leaves > 1
-            delta = jnp.where(
-                is_real, rate * leaf_table_lookup(ta.leaf_value, leaf_id), 0.0)
-            new_score = score_k + delta
+            if leaf_id is None:
+                # the unpaged stream route: the refresh added this
+                # delta to the comb's scores, by position
+                new_score = None
+            else:
+                delta = jnp.where(
+                    is_real,
+                    rate * leaf_table_lookup(ta.leaf_value, leaf_id), 0.0)
+                new_score = score_k + delta
             dt = device_tree_from_arrays(ta)
             new_vscores = []
             for vb, vsk in zip(vbins, vscores_k):
@@ -1638,13 +1704,18 @@ class GBDT:
         """Asynchronous tree finalization: all score updates and the valid
         replay replica stay on device; the host Tree is materialised lazily
         by _flush_pending.  A stump (num_leaves==1) contributes zero score
-        delta on device, matching the sync path's skip."""
+        delta on device, matching the sync path's skip.  ``leaf_id`` None
+        (the unpaged stream route): the train half is skipped - the comb
+        holds the new score by position - and ``train_score`` falls one
+        more tree behind; the valid replay, the device tree and the
+        pending-tree bookkeeping are the same."""
         rate = self.shrinkage_rate
         tail = self._async_tail_fn()
+        lazy = leaf_id is None
         # the eager ops and the jitted tail each may block in the
         # runtime (a launch, an allocation), so each has its own name
         with obs_tracer.span("UpdateScore::set", op="slice"):
-            score_k = self.train_score[kidx]
+            score_k = None if lazy else self.train_score[kidx]
             vscores_k = tuple(vs.score[kidx] for vs in self.valid_sets)
         with obs_tracer.span("UpdateScore::tail"):
             tail_args = (ta, leaf_id, score_k,
@@ -1654,9 +1725,14 @@ class GBDT:
             new_score, new_vscores, dt = tail(*tail_args)
         obs_tracer.program("score", tail, *tail_args)
         with obs_tracer.span("UpdateScore::set", op="set"):
-            self.train_score = self.train_score.at[kidx].set(new_score)
+            if lazy:
+                self._score_behind += 1
+            else:
+                self.train_score = self.train_score.at[kidx].set(new_score)
             for vs, sk in zip(self.valid_sets, new_vscores):
                 vs.score = vs.score.at[kidx].set(sk)
+        if lazy and self._numerics in ("raise", "skip"):
+            self._sync_train_score()            # eager: see train_score
         self._device_trees.append(dt)
         self._device_linear.append(None)
         self.models.append(None)
@@ -1905,6 +1981,8 @@ class GBDT:
         self._nl_seen.clear()
         if self.iter_ <= 0:
             return
+        # un-pulled trees live in the comb alone, which this drops
+        self._sync_train_score()
         k = self.num_tree_per_iteration
         for kidx in reversed(range(k)):
             if not self.models:

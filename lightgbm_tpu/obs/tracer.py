@@ -64,13 +64,23 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
           WorkCounters                  pull of the tree's small arrays
         UpdateScore
           UpdateScore::tail             dispatch of the score/valid tail
-          UpdateScore::set              eager slice + .at[].set
+          UpdateScore::set              eager slice + .at[].set (on the
+                                        unpaged stream route the valid
+                                        sets' alone: the train score is
+                                        not kept every tree)
           HbmCensus                     phase UpdateScore, likewise
-          UpdateScore::wait
+          UpdateScore::wait             on the tail's outputs
         StallProbe                      every 8th iteration
         FlushPending                    every 32nd iteration
       Eval                              when a metric is due
       Callbacks                         cbs_after (a benchmark's pulls)
+        TrainScore::materialise         wherever ``GBDT.train_score`` is
+                                        read on the unpaged stream route
+                                        with trees un-pulled (arg
+                                        trees_behind): one run of the
+                                        grower's ``pull_score``; also an
+                                        obs event of the same name
+          TrainScore::materialise::wait
 
 Device work is asynchronous under JAX: a span that covers a dispatch
 measures only the enqueue unless it blocks.  ``span(...)`` yields a
@@ -147,7 +157,8 @@ the compiler numbers anew with every change; the phase is in the
 instruction's ``metadata``, which the capture does not print.  So the
 first iteration the tracer is live, each dispatch site hands
 ``tracer.program(name, jitted, *args)`` its program (``grow``: the
-growers in ops/grow.py and parallel/data_parallel.py; ``score`` and
+growers in ops/grow.py and parallel/data_parallel.py; ``pull_score``:
+ops/grow.py, its ops ``leafrows``'s; ``score`` and
 ``gradients``: models/gbdt.py), which keeps ``{phase: [op_key, ...]}``
 parsed from the compiled module's text (``program_ops``; ``""`` holds
 the instructions under no phase) - under a span ``Program::table``
@@ -524,7 +535,8 @@ class Tracer:
     # -- what a capture's instruction names mean --------------------------
     def program(self, name: str, jitted, *args) -> None:
         """Keep, for the jitted function an iteration has just
-        dispatched as ``name`` (``grow``, ``score``, ``gradients``),
+        dispatched as ``name`` (``grow``, ``pull_score``, ``score``,
+        ``gradients``),
         the phase of each instruction of its compiled module
         (``program_ops``), to be written into every capture as a
         ``Program::ops`` event.  Once a function, and nothing is

@@ -813,8 +813,39 @@ def make_grow_fn(
         # (lever #5 — drops one full comb read per tree); grow then
         # takes the carried histogram instead of re-reading the matrix
         _fused_root = stream is not None and _use_fused
+        # the unpaged stream program keeps no row-order leaf id: the
+        # comb's score columns are the booster's score of record
+        # between pulls (``pull_score`` below).  The paged comb keeps
+        # the un-permute: its pages leave the device between trees.
+        _lazy_score = stream is not None and paged is None
+
+        def _comb_dot(c, weights):
+            """``sum_j weights[j] * column j`` of a comb, [n_alloc]:
+            one matvec a plane that holds one of the columns (a
+            [n, k] column slice would lane-pad to 512 B/row, the
+            round-2 OOM)."""
+            import numpy as _np
+            w = _np.zeros((_C_PHYS,), _np.float32)
+            for j, v in weights.items():
+                w[j] = v
+            out = None
+            for p in range(_PLANES):
+                w_p = w[p * 128:(p + 1) * 128]
+                if w_p.any():
+                    part = jnp.matmul(
+                        c[p * _n_alloc:(p + 1) * _n_alloc],
+                        jnp.asarray(w_p))
+                    out = part if out is None else out + part
+            return out
+
+        def _decode_rid(c):
+            """The stored row-id byte columns as row ids (exact: powers
+            of two x bytes <= 255, f32 accumulation < 2^24)."""
+            return _comb_dot(c, {f_pad_p + 3: 65536.0, f_pad_p + 4: 256.0,
+                                 f_pad_p + 5: 1.0})
         if stream is not None:
-            from .pallas.stream_grad import make_init, make_refresh
+            from .pallas.stream_grad import (COL_SC, make_init,
+                                             make_refresh)
             _refresh_fn = make_refresh(
                 kind=stream["kind"],
                 sigmoid=float(stream.get("sigmoid", 1.0)),
@@ -967,26 +998,6 @@ def make_grow_fn(
         # the comb write and the root histogram are here
         next_phase("root")
         inbag = inbag.astype(jnp.float32)
-
-        if physical:
-            # _decode_rid turns the stored row-id byte columns into row
-            # ids with one matvec (exact: powers of two x bytes <= 255,
-            # f32 accumulation < 2^24 — a [n, 3] column slice would
-            # lane-pad to 512 B/row, the round-2 OOM).
-            def _decode_rid(c):
-                import numpy as _np
-                rid_w = _np.zeros((_C_PHYS,), _np.float32)
-                rid_w[f + 3:f + 6] = (65536.0, 256.0, 1.0)
-                # one matvec a plane that holds a row-id byte column
-                out = None
-                for p in range(_PLANES):
-                    w_p = rid_w[p * 128:(p + 1) * 128]
-                    if w_p.any():
-                        part = jnp.matmul(
-                            c[p * _n_alloc:(p + 1) * _n_alloc],
-                            jnp.asarray(w_p))
-                        out = part if out is None else out + part
-                return out
 
         def expand(h):
             """Physical -> logical histogram (EFB): gather every logical
@@ -2356,8 +2367,13 @@ def make_grow_fn(
         # (row_order/permuted rows + seg tile [0, n)), instead of
         # scattering a [n] leaf_id vector on every split: a position's
         # leaf is the one whose segment holds it (and, on the stream
-        # route, its shrunk output rides the same mask); undo the
-        # permutation.
+        # route, its shrunk output rides the same mask).  Off the
+        # stream route the gradients are a program over ROW-order
+        # scores, so the permutation is undone here, every tree; on it
+        # the refresh below adds the outputs by POSITION and nothing
+        # reads a row-order leaf id: the unpaged stream program stops
+        # at ``lv_row`` and returns no ``leaf_id`` (``pull_score`` puts
+        # the comb's scores in row order when somebody asks).
         streams = physical and stream is not None and not debug_state
         if streams:
             # shrinkage arrives as a TRACED per-call scalar: callbacks
@@ -2370,7 +2386,9 @@ def make_grow_fn(
                 state.seg, n, (lv_leaf,))         # [n] by position
         else:
             leaf_of_pos, = leaf_of_position(state.seg, n)
-        if physical:
+        if streams and _lazy_score:
+            leaf_id = None
+        elif physical:
             # positions [0, n) always hold a permutation of the original
             # rows (partitions only permute within segment ranges); decode
             # the stored row-id bytes to undo it.  Matvec, not a [n, 3]
@@ -2507,14 +2525,9 @@ def make_grow_fn(
                 # (a transposing copy above one plane; once a
                 # checkpoint, not once a tree)
                 comb_l = to_rows(comb, _C_PHYS)
-                rid_w = (jnp.zeros((_C_PHYS,), jnp.float32)
-                         .at[f_pad_p + 3].set(65536.0)
-                         .at[f_pad_p + 4].set(256.0)
-                         .at[f_pad_p + 5].set(1.0))
                 real = jax.lax.slice(comb_l, (0, 0),
                                      (n_rows_p, _C_PHYS))
-                rid = jnp.matmul(
-                    real.astype(jnp.float32), rid_w).astype(jnp.int32)
+                rid = _decode_rid(comb)[:n_rows_p].astype(jnp.int32)
                 bins_perm = jax.lax.slice(
                     real, (0, 0), (n_rows_p, f_pad_p))
                 anchored = (jnp.zeros((n_rows_p, f_pad_p),
@@ -2525,6 +2538,25 @@ def make_grow_fn(
             _reanchor_fn = jax.jit(_reanchor_bins)
         else:
             _reanchor_fn = None
+        if _lazy_score:
+            # the row-order train score, on demand: each position's
+            # score is the sum of its three bf16-exact terms in the
+            # order the refresh sums them (exactly the f32 it split),
+            # its row the decoded id bytes; one lane product a column,
+            # never a [n, 3] slice, which lane-pads to 512 B a row.
+            # Reads the comb and leaves it as it is: not donated.
+            @jax.jit
+            @phase("leafrows")
+            def _pull_score_fn(comb):
+                rid = _decode_rid(comb)[:n_rows_p].astype(jnp.int32)
+                hi, mid, lo = (
+                    _comb_dot(comb, {f_pad_p + COL_SC + i: 1.0})[:n_rows_p]
+                    for i in range(3))
+                # [K, n_pad] with the route's one tree an iteration
+                return jnp.zeros((n_rows_p,), jnp.float32).at[rid].set(
+                    hi + mid + lo, mode="drop")[None]
+        else:
+            _pull_score_fn = None
         return _maybe_guard(_PhysicalGrow(
             grow_p, physical_bins, _n_alloc, _C_PHYS, f_pad_p,
             stream_init=(_stream_init_fn
@@ -2532,7 +2564,7 @@ def make_grow_fn(
             dtype=_COMB_DT, fused=_use_fused,
             root0_fn=_root0_fn, ingest=_efb_ingest,
             paged_plan=paged, reanchor_fn=_reanchor_fn,
-            scan_block_rows=_PHYS_R))
+            scan_block_rows=_PHYS_R, pull_score_fn=_pull_score_fn))
 
     if use_cegb_lazy:
         @jax.jit
@@ -2612,12 +2644,16 @@ class _PhysicalGrow:
     row matrix + scratch across trees (donated each call) while keeping
     the plain ``grow(bins, ...) -> (tree, leaf_id)`` calling convention
     (the ``bins`` argument is accepted and ignored — the rows live inside
-    the carried matrix)."""
+    the carried matrix).  On the unpaged stream route (``lazy_score``)
+    ``leaf_id`` is None: the tree's outputs were added to the comb's
+    score columns by position, nothing was put in row order, and
+    ``pull_score()`` is how the booster reads the row-order score."""
 
     def __init__(self, grow_p, bins_dev, n_alloc, C, f_pad,
                  stream_init=None, dtype=jnp.float32, fused=False,
                  root0_fn=None, ingest=None,
-                 paged_plan=None, reanchor_fn=None, scan_block_rows=0):
+                 paged_plan=None, reanchor_fn=None, scan_block_rows=0,
+                 pull_score_fn=None):
         self._grow_p = grow_p
         self._bins_dev = bins_dev
         # EFB (ISSUE 12): the carried bins stay BUNDLED (the smaller
@@ -2644,6 +2680,9 @@ class _PhysicalGrow:
         self.paged = paged_plan      # plan dict or None
         self._pages = None           # ops/paged.PageStore once built
         self._reanchor_fn = reanchor_fn  # stream: in-place re-anchor
+        self._pull_score_fn = pull_score_fn
+        # the comb's score columns are the score of record between pulls
+        self.lazy_score = pull_score_fn is not None
         self._grow_batch_p = None    # lazily-jitted batched-K scan core
 
     def set_stream_aux(self, fn, rate_fn=None) -> None:
@@ -2665,6 +2704,19 @@ class _PhysicalGrow:
         self._root_hist = None
         if self._pages is not None:
             self._pages.drop()
+
+    def pull_score(self):
+        """The train score the comb carries by position, in ROW order
+        ([1, n_pad] f32), or None before the comb first builds (the
+        booster's own copy is then the only one).  One program, run
+        when somebody reads ``GBDT.train_score``; the comb stays as it
+        is."""
+        if self._comb is None:
+            return None
+        score = self._pull_score_fn(self._comb)
+        # while tracing: this program's ops are ``leafrows``'s
+        _obs_tracer.program("pull_score", self._pull_score_fn, self._comb)
+        return score
 
     def reanchor_inplace(self) -> bool:
         """Checkpoint re-anchor at the stream refresh boundary WITHOUT

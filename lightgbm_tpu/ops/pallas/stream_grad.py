@@ -18,7 +18,14 @@ bit-for-bit):
   [f+0 .. f+2]     g*w, h*w, w       (refreshed per tree; w = validity)
   [f+3 .. f+5]     row-id bytes (hi, mid, lo)
   [f+6 .. f+8]     score as 3 bf16-exact f32 terms (hi, mid, lo —
-                   ~24 mantissa bits total, f32-faithful accumulation)
+                   ~24 mantissa bits total; (hi + mid) + lo IS the f32
+                   that was split).  On the unpaged stream route this
+                   is the booster's train score OF RECORD between
+                   pulls: the refresh adds every tree's shrunk leaf
+                   output here, by position, and the row-order copy
+                   (``GBDT.train_score``) is made from these columns
+                   and the row-id bytes beside them when somebody
+                   reads it (``ops/grow.py`` ``pull_score``)
   [f+9 .. ]        objective constants:
                      binary: sign (±1), lw_hi, lw_mid, lw_lo
                              (label_weight = scale_pos_weight x sample

@@ -235,6 +235,13 @@ def _stream_grow_jaxpr(n, f, b, L):
     return jax.make_jaxpr(gp._grow_p)(*_grow_args(gp, n, f, b, True))
 
 
+def _pull_score_jaxpr(n, f, b, L):
+    import jax
+    gp = _build_grow(n, f, b, L, stream=True)
+    return jax.make_jaxpr(gp._pull_score_fn)(
+        _grow_args(gp, n, f, b, True)[0])
+
+
 def _tail_jaxpr(n, L):
     """The boosting loop's score-update program, traced on the
     operands its first call gets."""
@@ -265,31 +272,37 @@ def _tail_jaxpr(n, L):
         jnp.float32(0.0))
 
 
-@pytest.mark.parametrize("program", ["stream_grow", "tail"])
+@pytest.mark.parametrize("program", ["stream_grow", "tail", "pull_score"])
 @pytest.mark.parametrize("select_max", [256, 0])
 def test_hand_off_reads_no_leaf_table_by_gather(program, select_max,
                                                 monkeypatch):
     """ISSUE 33: after the last split the stream grow program and the
     score-update tail take per-row leaf ids / values by compares
     against the leaf-sized table (ops/leaf_lookup.py): no gather of n
-    results out of a table of at most 256 entries, and ONE n-sized
-    scatter, the un-permute to row order.  With ``SELECT_MAX = 0`` the
-    helper falls back to the lookups this replaced, which the same
-    census must find: that is what shows it can see them."""
+    results out of a table of at most 256 entries.  ISSUE 39: and the
+    stream grow program holds NO n-sized scatter - the un-permute to
+    row order is ``pull_score``'s, the one such scatter, run when the
+    train score is read.  With ``SELECT_MAX = 0`` the helper falls back
+    to the lookups this replaced, which the same census must find:
+    that is what shows it can see them."""
     from lightgbm_tpu.ops import leaf_lookup
     monkeypatch.setattr(leaf_lookup, "SELECT_MAX", select_max)
     n, L = 4096, 8
-    traced = (_stream_grow_jaxpr(n, 16, 32, L) if program == "stream_grow"
-              else _tail_jaxpr(n, L))
+    traced = {"stream_grow": lambda: _stream_grow_jaxpr(n, 16, 32, L),
+              "pull_score": lambda: _pull_score_jaxpr(n, 16, 32, L),
+              "tail": lambda: _tail_jaxpr(n, L)}[program]()
     gathers, scatters = _row_sized_lookups(traced, n)
+    if program == "pull_score":
+        assert not gathers and len(scatters) == 1
+        return
     if select_max == 0:
         # leaf_of_pos by repeat (a scatter-add into zeros[n], a gather)
         # and lv_row, or the tail's leaf_value[leaf_id]
         assert len(gathers) == (2 if program == "stream_grow" else 1)
-        assert len(scatters) == (2 if program == "stream_grow" else 0)
+        assert len(scatters) == (1 if program == "stream_grow" else 0)
         return
     assert not gathers, [str(e) for e in gathers]
-    assert len(scatters) == (1 if program == "stream_grow" else 0)
+    assert not scatters, [str(e) for e in scatters]
 
 
 def test_page_schedule_scales_with_num_class():

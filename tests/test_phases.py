@@ -179,6 +179,50 @@ def test_every_equation_of_the_grow_program_is_under_one_phase(
     loops = [ph for eqn, ph in walked if eqn.primitive.name == "while"
              and own_phases(eqn)]
     assert loops == ["glue"]
+    # ISSUE 39: the stream program does not undo the permutation (no
+    # row-id decode, no scatter to row order, no [n] i32 result); the
+    # routes whose gradients read ROW-order scores do, as before
+    unpermute = {eqn.primitive.name for eqn, ph in walked
+                 if ph == "leafrows" and eqn.primitive.name in (
+                     "dot_general", "scatter", "sort")}
+    row_ids = [v for v in jax.eval_shape(fn, *args)[1:2]
+               if v is not None]
+    if route == "stream":
+        assert not unpermute and not row_ids
+    else:
+        assert unpermute == {"dot_general", "scatter"}
+        assert row_ids[0].dtype == jnp.int32 and row_ids[0].ndim == 1
+
+
+def test_pull_score_is_leafrows_and_reads_the_comb_alone(as_the_chip):
+    """ISSUE 39: the program behind a read of ``GBDT.train_score`` on
+    the stream route: one argument (the comb, not donated), every
+    equation under ``leafrows``, one scatter."""
+    from lightgbm_tpu.ops.grow import make_grow_fn
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    from lightgbm_tpu.ops.split import SplitHyperParams
+    gp = make_grow_fn(
+        SplitHyperParams(min_data_in_leaf=20), num_leaves=L, padded_bins=B,
+        physical_bins=_sds((N, F), jnp.uint8),
+        stream={"kind": "binary", "sigmoid": 1.0, "count": N})
+    assert gp.lazy_score
+    comb = _sds(comb_shape(gp._n_alloc, gp._C), jnp.float32)
+    traced = jax.make_jaxpr(gp._pull_score_fn)(comb)
+    (pjit,) = traced.jaxpr.eqns
+    assert not any(pjit.params["donated_invars"])
+    walked = walk(traced.jaxpr)
+    assert {ph for _, ph in walked} == {"leafrows"}
+    names = [eqn.primitive.name for eqn, _ in walked]
+    assert names.count("scatter") == 1 and names.count("dot_general") == 4
+    assert [v.aval.shape for v in traced.jaxpr.outvars] == [(1, N)]
+    # off the stream route, and on the paged comb, there is none
+    for kw in ({}, {"stream": {"kind": "binary", "sigmoid": 1.0,
+                               "count": N},
+                    "paged": {"rows_per_page": 2048}}):
+        other = make_grow_fn(
+            SplitHyperParams(min_data_in_leaf=20), num_leaves=L,
+            padded_bins=B, physical_bins=_sds((N, F), jnp.uint8), **kw)
+        assert not other.lazy_score and other._pull_score_fn is None
 
 
 @pytest.mark.parametrize("entry", ["grow_serial", "grow_physical",
