@@ -244,6 +244,27 @@ def _efb_overwide() -> FixtureBundle:
 
 
 # ---------------------------------------------------------------------
+# routing matrix: an UNJUSTIFIED over-wide dense fallback.
+# Every comb kernel now stages up to sixteen planes; a cell that blames
+# comb_overwide while its key lacks the shape fact (cw=1) sends a dense
+# table the kernels can build to the 0.04x row_order path.  The routing
+# pass must reject it (ROUTING_COMB_OVERWIDE_UNJUSTIFIED).
+# ---------------------------------------------------------------------
+def _comb_overwide() -> FixtureBundle:
+    key = ("learner=serial;shards=1;be=tpu;efb=0;u8=1;over=0;"
+           "ew=0;fdiv=1;dp=0;cegb=0;cat=0;bag=0;lin=0;boost=gbdt;"
+           "obj=binary;k=1;forced=0;mono=0;cegbc=0;phys=auto;"
+           "stream=auto;part=permute;fused=1;scat=1;"
+           "ob=0;pg=auto;fixture=comb_overwide")
+    cell = ("path=row_order;scheme=none;fused=0;merge=none;"
+            "paged=0;why=comb_overwide;merge_why=-;"
+            "paged_why=-;"
+            "prog=row_order|none|fused0|serial|shards1|none|"
+            "dp0|cegb0|cat0|efb0|u81|paged0")
+    return FixtureBundle(routing_cells=[(key, cell)])
+
+
+# ---------------------------------------------------------------------
 # lane-contract cat bitset (ISSUE 16): an oversized/misaligned bitset
 # memref.  The graduated cat-subset path carries the per-node
 # membership bitset as i32 SMEM words appended to sel (8 + W words,
@@ -451,4 +472,5 @@ FIXTURES = {
     "bad_retrace": _bad_retrace,
     "bad_serve_kernel": _bad_serve_kernel,
     "efb_overwide": _efb_overwide,
+    "comb_overwide": _comb_overwide,
 }

@@ -258,6 +258,20 @@ def _check_matrix(ctx) -> List[Finding]:
                     "graduation); this cell re-opens the deleted "
                     "efb_bundle class under a new name"),
                 fixture=key in fixture_keys))
+        # comb_overwide is a pure shape rule too: only a
+        # cell whose key carries the fact (cw=1) may blame it
+        if ("comb_overwide" in c["reasons"]
+                and "cw=1" not in key.split(";")):
+            out.append(Finding(
+                pass_name=PASS_NAME,
+                code="ROUTING_COMB_OVERWIDE_UNJUSTIFIED",
+                severity=SEV_ERROR, where=f"cell:{key}",
+                message=(
+                    "cell blames comb_overwide for a row_order fallback "
+                    "but its key says every comb kernel stages the line "
+                    "(no cw=1) - a dense table up to sixteen planes must "
+                    "ride the physical fast path"),
+                fixture=key in fixture_keys))
         # paged audit (ISSUE 15): an over-budget cell (ob=1) whose
         # engaged path holds the comb HBM-resident must either page or
         # name the paged rule that cost it — a resident over-budget

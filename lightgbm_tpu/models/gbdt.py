@@ -673,7 +673,8 @@ class GBDT:
         event of the set-up and for a benchmark's check: the stored bin
         columns, the logical features they stand for, the EFB bundles
         among them, and the bytes of one comb line (0 off the physical
-        route, which holds no comb)."""
+        route, which holds no comb), its 128-lane planes and the tiles
+        a comb histogram sweeps (0 off the physical route)."""
         dd, b = self.dd, self.dd.bundle
         pieces = getattr(self.grow, "_pieces", None)
         width = pieces.C if pieces is not None else getattr(
@@ -687,6 +688,8 @@ class GBDT:
                 len(np.unique(b["feat_phys"][b["is_bundled"]]))),
             "comb_cols": int(dd.phys_f_pad),
             "comb_line_bytes": int(width) * jnp.dtype(dtype).itemsize,
+            "comb_planes": int(width) // 128,
+            "hist_tiles": int(getattr(self.grow, "hist_tiles", 0)),
         }
 
     def routing_info(self) -> Optional[Dict]:
@@ -1657,6 +1660,11 @@ class GBDT:
             total.update(mesh_args(total.get("splits", 0.0), len(kidxs)))
         if scan_r:
             total["scan_block_rows"] = scan_r
+        # the comb's planes, the tiles a comb histogram sweeps and the
+        # rows a step of it reads, from the built program
+        for name in ("comb_planes", "hist_tiles", "hist_block_rows"):
+            if getattr(self.grow, name, 0):
+                total[name] = int(getattr(self.grow, name))
         span.set(**total)
 
     def _async_tail_fn(self):

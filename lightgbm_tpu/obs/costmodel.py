@@ -673,6 +673,52 @@ def _buf(shape, itemsize: int, scope: str, dtype: str,
             "bytes": count * buffer_bytes(shape, itemsize)}
 
 
+def comb_kernel_plan(*, features: int, max_bins: int,
+                     stream_kind: Optional[str] = "binary",
+                     scheme: str = "permute") -> Dict[str, Any]:
+    """What each comb kernel of the physical route is built at for a
+    dense table of ``features`` columns whose widest column has
+    ``max_bins`` bins (``stream_kind`` None: the non-stream layout):
+    the line, its planes, the tiles a comb histogram sweeps, and per
+    kernel the rows a grid step moves, the scoped VMEM that block is
+    priced at and the limit it is built under.  From shapes alone:
+    nothing is placed on a device and nothing is compiled, so a caller
+    can ask before it makes a full-size array.
+    ``stageable`` is the ``comb_overwide`` routing fact's negation."""
+    from ..ops.histogram import bins_per_feature_padded, feature_group_size
+    from ..ops.pallas import hist_kernel2 as hk
+    from ..ops.pallas import partition_kernel2 as pk
+    from ..ops.pallas import stream_grad as sg
+    from ..ops.pallas.layout import LANE, SCOPED_VMEM_LIMIT, comb_layout
+    from ..ops.routing import NON_STREAM_EXTRA_COLS, comb_stageable
+    b = bins_per_feature_padded(max_bins)
+    g = feature_group_size(b)
+    f_pad = -(-max(int(features), 1) // g) * g
+    n_extra = (sg.stream_columns(stream_kind) if stream_kind
+               else NON_STREAM_EXTRA_COLS)
+    C = comb_layout(f_pad + n_extra)
+    h_rows = hk.hist_block_rows(C)
+    scan_r = pk.scan_block_rows(C, scheme=scheme)
+    cb = pk.copyback_block_rows(C)
+    kernels = {
+        "scan": (scan_r, pk.scan_vmem_bytes(scan_r, C)),
+        "copyback": (cb, pk.copyback_vmem_bytes(cb, C)),
+        "hist": (h_rows, hk.hist_vmem_bytes(h_rows, min(C, 2 * LANE))),
+    }
+    if stream_kind:
+        s_r = sg.stream_block_rows(C)
+        kernels["stream"] = (s_r, sg.stream_vmem_bytes(s_r, C))
+    return {
+        "f_pad": f_pad, "padded_bins": b, "C": C,
+        "comb_planes": C // LANE, "comb_line_bytes": C * F32,
+        "hist_tiles": hk.hist_tiles(f_pad, C),
+        "stageable": bool(comb_stageable(C, scheme)),
+        "kernels": {k: {"rows": r, "vmem_bytes": int(p),
+                        "vmem_limit": SCOPED_VMEM_LIMIT}
+                    for k, (r, p) in kernels.items()},
+    }
+
+
 def grow_footprint(*, rows: int, f_pad: int, padded_bins: int,
                    num_leaves: int,
                    stream: bool = False, fused: bool = True,

@@ -32,7 +32,8 @@ booster is built)::
                                         layout and the route decision;
                                         args phys_cols,
                                         logical_features, bundles,
-                                        comb_cols, comb_line_bytes
+                                        comb_cols, comb_line_bytes,
+                                        comb_planes, hist_tiles
                                         (GBDT.layout_info())
 
 The span tree of one boosting iteration (serial learner, fast path;
@@ -56,7 +57,11 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
                                         are counted by the grow program)
                                         and scan_block_rows, the rows a
                                         grid step of the partition scan
-                                        moves (scan_steps counts them)
+                                        moves (scan_steps counts them);
+                                        comb_planes, hist_tiles (tiles a
+                                        comb histogram sweeps) and
+                                        hist_block_rows, from the built
+                                        program's shapes
           HbmCensus                     phase Tree::grow: under the
                                         running program, so the device
                                         does not wait for the walk
@@ -128,7 +133,8 @@ ConstructHistograms, FindBestSplits, Split)::
                      histograms and row counts, sync_best's election
     lgbm.find        the finder over the two children: lgbm_apply_find
                      or the XLA tail, find_best_split_segments
-    lgbm.partition   lgbm_split_scan, lgbm_copyback, what they are told
+    lgbm.partition   lgbm_split_scan (the fused scan) or
+                     lgbm_partition_scan, lgbm_copyback, what they are told
                      (the descriptor, bundled_split_members), the
                      segment table's writes
     lgbm.glue        what a split does besides: leaf election, state

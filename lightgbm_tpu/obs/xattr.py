@@ -513,8 +513,10 @@ KERNEL_CLASSES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("fused_split", ("fused_scan_kernel", "fused_split",
                      "lgbm_split_scan")),
     ("partition_copyback", ("copyback",)),
-    ("partition_scan", ("scan_kernel", "partition_kernel",
-                        "partition")),
+    # the unfused scan's pallas_call name (the fused one's is
+    # lgbm_split_scan): no fused pattern matches it
+    ("partition_scan", ("lgbm_partition_scan", "scan_kernel",
+                        "partition_kernel", "partition")),
     # refresh_hist_kernel contains "hist_kernel": stream_refresh
     # must be classified before hist_build
     ("stream_refresh", ("refresh_hist_kernel", "refresh_kernel",

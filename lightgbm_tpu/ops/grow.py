@@ -272,12 +272,6 @@ from .pallas.layout import COMB_ROW_SLACK as PHYS_ROW_SLACK
 from .pallas.layout import SCAN_ROWS_MAX as PHYS_ROW_PAD
 
 
-# rows a grid step of the stream route's init / refresh kernels takes
-# (stream_grad.py: BlockSpec-pipelined passes over [0, n_pad), their
-# own kernels with their own block; it divides every scan block)
-_STREAM_R = 512
-
-
 _HIST_SCATTER_WARNED = set()
 
 
@@ -711,6 +705,19 @@ def make_grow_fn(
         # the comb is stored plane-major (layout.py): _PLANES matrices
         # of [lines, 128], one after the other in one array
         _PLANES = comb_planes(_C_PHYS)
+        if _efb_ingest is None:
+            # build-time defense mirroring the comb_overwide routing
+            # rule (the efb_overwide one above covers the unbundling
+            # ingest): no line reaches Mosaic wider than it stages
+            from .routing import comb_stageable
+            if not comb_stageable(_C_PHYS, PARTITION_IMPL):
+                raise ValueError(
+                    f"a comb line of {_C_PHYS} lanes ({f_pad_p} bin "
+                    f"columns + {_n_extra} value/rid/stream extras) is "
+                    f"past what the kernels stage through VMEM at the "
+                    f"{PARTITION_IMPL} scan; the routing model routes "
+                    f"this config to the row_order path (rule "
+                    f"comb_overwide)")
         if PARTITION_IMPL == "permute":
             from .pallas.partition_kernel3 import \
                 make_partition_perm as make_partition
@@ -720,7 +727,12 @@ def make_grow_fn(
         # slack lines: the longest kernel tail past the padded rows
         # (layout.COMB_ROW_SLACK: the scan's right zone + the
         # copy-back's tail block in the scratch)
-        from .pallas.layout import HIST_COMB_ROWS as _HIST_RPB
+        # rows a step of the comb-direct histogram reads, and the tiles
+        # it sweeps (one a bin plane past two planes:
+        # hist_kernel2.hist_tiles)
+        from .pallas.hist_kernel2 import hist_block_rows, hist_tiles
+        _HIST_RPB = hist_block_rows(_C_PHYS)
+        _HIST_TILES = hist_tiles(f_pad_p, _C_PHYS)
         _n_alloc = n_rows_p + PHYS_ROW_SLACK
         if _n_alloc >= (1 << 24):
             # row ids ride in three f32 byte columns and are decoded with
@@ -845,7 +857,11 @@ def make_grow_fn(
                                  f_pad_p + 5: 1.0})
         if stream is not None:
             from .pallas.stream_grad import (COL_SC, make_init,
-                                             make_refresh)
+                                             make_refresh,
+                                             stream_block_rows)
+            # rows a step of the init / refresh kernels: their own
+            # block, from the comb's width
+            _STREAM_R = stream_block_rows(_C_PHYS)
             _refresh_fn = make_refresh(
                 kind=stream["kind"],
                 sigmoid=float(stream.get("sigmoid", 1.0)),
@@ -2564,7 +2580,8 @@ def make_grow_fn(
             dtype=_COMB_DT, fused=_use_fused,
             root0_fn=_root0_fn, ingest=_efb_ingest,
             paged_plan=paged, reanchor_fn=_reanchor_fn,
-            scan_block_rows=_PHYS_R, pull_score_fn=_pull_score_fn))
+            scan_block_rows=_PHYS_R, pull_score_fn=_pull_score_fn,
+            hist_tiles=_HIST_TILES, hist_block_rows=_HIST_RPB))
 
     if use_cegb_lazy:
         @jax.jit
@@ -2653,7 +2670,7 @@ class _PhysicalGrow:
                  stream_init=None, dtype=jnp.float32, fused=False,
                  root0_fn=None, ingest=None,
                  paged_plan=None, reanchor_fn=None, scan_block_rows=0,
-                 pull_score_fn=None):
+                 pull_score_fn=None, hist_tiles=0, hist_block_rows=0):
         self._grow_p = grow_p
         self._bins_dev = bins_dev
         # EFB (ISSUE 12): the carried bins stay BUNDLED (the smaller
@@ -2673,6 +2690,12 @@ class _PhysicalGrow:
         # rows a grid step of the scan moves (obs: Tree::grow's
         # scan_block_rows / scan_steps)
         self.scan_block_rows = int(scan_block_rows)
+        # the comb's 128-lane planes, the tiles a comb histogram sweeps
+        # and the rows a step of it reads (obs: Tree::grow's
+        # comb_planes / hist_tiles / hist_block_rows)
+        self.comb_planes = C // 128
+        self.hist_tiles = int(hist_tiles)
+        self.hist_block_rows = int(hist_block_rows)
         self._root0_fn = root0_fn    # fused stream: tree-0 root hist
         self._root_hist = None       # fused stream: carried root hist
         # paged comb (ISSUE 15): pages live host-side between trees and

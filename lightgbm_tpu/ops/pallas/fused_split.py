@@ -88,10 +88,10 @@ from .hist_kernel2 import _LO_N, _diag_extract, _hist_accumulate, \
     build_histogram_comb, hist_geometry
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, SEL_SIDE, \
     SIDE_LEFT, SIDE_NONE, _go_left, make_reference_partition
-from .layout import COMB_ROW_SLACK, COPYBACK_ROWS, HIST_COMB_ROWS, \
-    SCAN_ROWS_MIN
-from .partition_kernel2 import SCAN_VMEM_LIMIT, _scan_kernel, \
-    copyback_call, scan_block_rows, scan_vmem_bytes
+from .layout import COMB_ROW_SLACK, HIST_COMB_ROWS, SCAN_ROWS_MIN, \
+    SCOPED_VMEM_LIMIT
+from .partition_kernel2 import _scan_kernel, \
+    copyback_block_rows, copyback_call, scan_block_rows, scan_vmem_bytes
 
 _CHANNELS = 2       # (grad, hess) — the 2-channel histogram layout
 
@@ -112,10 +112,10 @@ def fused_supported(f_pad: int, b: int, C: int) -> bool:
     smallest block fits the scoped VMEM at ``scan_block_rows``'s own
     price (grow falls back to the separate partition + histogram pair
     where not).  The accumulator and its output buffer are not on that
-    stack (partition_kernel2.SCAN_VMEM_LIMIT); at the widest comb the
+    stack (layout.SCOPED_VMEM_LIMIT); at the widest comb the
     price admits, 896 lanes, they are 2 x 13.9 MiB of the chip's 128."""
     return bool(hook_acc_bytes(f_pad, b)) and \
-        scan_vmem_bytes(SCAN_ROWS_MIN, C) <= SCAN_VMEM_LIMIT
+        scan_vmem_bytes(SCAN_ROWS_MIN, C) <= SCOPED_VMEM_LIMIT
 
 
 # Per-split cost of the two ways to the smaller child's histogram, as
@@ -241,7 +241,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
                      R: int = SCAN_ROWS_MIN, size: int = 0,
                      dtype=jnp.float32,
                      interpret: bool = False, dynamic: bool = False,
-                     cb_block: int = COPYBACK_ROWS,
+                     cb_block: int = 0,
                      hist_rpb: int = HIST_COMB_ROWS,
                      scan: str = "permute",
                      interpret_kernel: bool = False,
@@ -342,6 +342,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
         return fused
 
     nblocks = max((size + R - 1) // R, 1)
+    cb_block = cb_block or copyback_block_rows(C)
     kern = functools.partial(_fused_scan_kernel, R=R, C=C, n=n,
                              f_pad=f_pad, b_hi=b_hi, g=g, lo_n=_LO_N,
                              ngroups=ngroups, pack_impl=_pack)

@@ -35,7 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..histogram import feature_group_size
-from .layout import load_rows
+from .layout import HIST_COMB_ROWS, load_rows
 
 _LO_N = 16   # hi/lo nibble split shared by every histogram kernel
 
@@ -143,6 +143,29 @@ def _hist2_comb_kernel(sel_ref, comb_ref, out_ref, *, b_hi, g, c, lo_n,
                      ngroups=ngroups)
 
 
+def _hist2_tile_kernel(sel_ref, bins_ref, vals_ref, out_ref, *, b_hi, g, c,
+                       lo_n, ngroups, vcol, rpb):
+    """One tile of the comb-direct histogram: grid (tile, row block).
+    ``bins_ref`` is the tile's own plane of the block, [R, 128] (bin
+    columns [128 t, 128 t + 128)), ``vals_ref`` the plane the value
+    columns lie in (lanes [vcol, vcol + c)); the tile's [ngroups, M, N]
+    accumulator is resident across the row blocks.  The last tile's
+    lanes past the bin columns are accumulated too, into groups the
+    caller drops."""
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    b = bins_ref[...].astype(jnp.float32).astype(jnp.int32)
+    off, cnt = sel_ref[1], sel_ref[2]
+    pos = (pl.program_id(1) * rpb
+           + jax.lax.broadcasted_iota(jnp.int32, (rpb, 1), 0))
+    live = ((pos >= off) & (pos < off + cnt)).astype(jnp.float32)
+    v = vals_ref[:, vcol:vcol + c].astype(jnp.float32) * live
+    _hist_accumulate(b, v, out_ref, b_hi=b_hi, g=g, c=c, lo_n=lo_n,
+                     ngroups=ngroups)
+
+
 def _diag_extract(out, ngroups, g, b_hi, c, lo_n, f_pad, b):
     """Diagonal (same-feature) block extraction shared by both kernels."""
     out = out.reshape(ngroups, g, b_hi, g, c, lo_n)
@@ -152,13 +175,49 @@ def _diag_extract(out, ngroups, g, b_hi, c, lo_n, f_pad, b):
     return hist.reshape(f_pad, b, c)
 
 
+def hist_vmem_bytes(rpb: int, lanes: int) -> int:
+    """Scoped VMEM of a comb histogram that reads ``lanes`` f32 lanes of
+    ``rpb`` rows a grid step (the whole line untiled; two planes a
+    tile: its own and the values').  The pipelined input block, double
+    buffered, to the byte: the compiler's own report (a 1 MiB limit)
+    reads 2.00 MiB at 2,048 rows x 128 lanes and 4.00 at 2,048 x 256 -
+    the resident [ngroups, M, N] accumulator is an output block beside
+    it, and the one-hot operands live in the kernel's internal scratch."""
+    return 2 * rpb * lanes * 4
+
+
+def hist_tiles(f_pad: int, C: int) -> int:
+    """Tiles a comb histogram of ``f_pad`` columns sweeps on a comb of
+    ``C`` lanes: 1 - the whole line a row block, every group in one
+    resident accumulator - up to two planes; past them one a plane of
+    bin columns, 128 // g groups each.  The group loop of
+    ``_hist_accumulate`` is unrolled and the compile grows faster than
+    its groups (16 groups 20 s, 64 more than 200 s off the chip for the
+    described v5e), and the untiled block and accumulator grow with the
+    line: a tile bounds both by one plane."""
+    from .layout import LANE
+    return 1 if C <= 2 * LANE else -(-f_pad // LANE)
+
+
+def hist_block_rows(C: int) -> int:
+    """Rows a grid step of the comb histogram reads on a comb of ``C``
+    lanes: the largest power of two up to ``HIST_COMB_ROWS`` whose block
+    fits the scoped VMEM (2,048 at every width: a block reads two planes
+    at most)."""
+    from .layout import LANE, fit_rows
+    lanes = min(C, 2 * LANE)
+    return fit_rows(lambda r: hist_vmem_bytes(r, lanes), HIST_COMB_ROWS, 8)
+
+
 def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
                     interpret, channels=2, planes=1):
     """Shared tail of the comb-direct histogram: start-block clamp (both
     ways — a garbage-negative start from a dead partition call must not
     become an OOB DMA), scalar-prefetch grid, diagonal extraction.
     ``nblocks`` may be a python int (static grid) or a traced scalar
-    (Mosaic dynamic grid).  ``rpb`` counts rows per block."""
+    (Mosaic dynamic grid).  ``rpb`` counts rows per block.  Past
+    ``hist_tiles`` == 1 the grid is (tile, row block): each tile reads
+    its own plane and the values' and keeps its own groups resident."""
     from .layout import (LANE, check_lane_width, comb_block_spec,
                          comb_operand)
     # the comb is plane-major (layout.py): ``planes`` x [n_rows, 128]
@@ -175,6 +234,38 @@ def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
     start_blk_c = jnp.clip(start_blk, 0, max_blk)
     off_total = off_total + (start_blk - start_blk_c) * rpb
     sel = jnp.stack([start_blk_c, off_total, count]).astype(jnp.int32)
+
+    tiles = hist_tiles(f_pad, C)
+    if tiles > 1:
+        gpt = LANE // g
+        kern = functools.partial(
+            _hist2_tile_kernel, b_hi=b_hi, g=g, c=c, lo_n=lo_n,
+            ngroups=gpt, vcol=f_pad % LANE, rpb=rpb)
+        vplane = f_pad // LANE
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tiles, nblocks),
+            in_specs=[
+                pl.BlockSpec((None, rpb, LANE),
+                             lambda t, i, s: (t, s[0] + i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((None, rpb, LANE),
+                             lambda t, i, s: (vplane, s[0] + i, 0),
+                             memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((gpt, m, nn), lambda t, i, s: (t, 0, 0),
+                                   memory_space=pltpu.VMEM),
+        )
+        view = comb_operand(comb, C)
+        out = pl.pallas_call(
+            kern,
+            name="lgbm_hist",
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((tiles * gpt, m, nn),
+                                           jnp.float32),
+            interpret=interpret,
+        )(sel, view, view)
+        return _diag_extract(out, tiles * gpt, g, b_hi, c, lo_n,
+                             tiles * LANE, b)[:f_pad]
 
     kern = functools.partial(
         _hist2_comb_kernel, b_hi=b_hi, g=g, c=c, lo_n=lo_n,

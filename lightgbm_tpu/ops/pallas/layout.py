@@ -38,18 +38,17 @@ import jax.numpy as jnp
 LANE = 128          # TPU minor-dim tile: every HBM row DMA moves
                     # multiples of this many lanes
 
-# Physical comb width budget (ISSUE 12, the EFB graduation).  The
-# comb-direct kernels stream [R, C] blocks through VMEM, so C is
-# bounded by the staging budget, not the lane contract: the histogram
-# kernel double-buffers [2048, C] f32 row blocks (16 KiB per column)
-# and must leave room for its one-hot operands and the [f_pad, B, 2]
-# accumulator inside the post-reserve VMEM budget
-# (obs/costmodel.vmem_limit_bytes, 96 MiB on v5e).  16 physical lines
-# keeps the staged blocks at 32 MiB — one third of the budget — and
-# covers any real tabular dataset short of a pathological bundle
-# expansion.  A wider layout (an EFB dataset whose bundles unbundle to
-# > MAX_COMB_COLS columns) must fall back to the row_order path via
-# the routing model's ``efb_overwide`` rule instead of dying in
+# Physical comb width budget, and the width every comb kernel is
+# built to stage.  The comb-direct kernels stream [R, C] blocks through
+# VMEM, so C is bounded by the staging budget, not the lane contract.
+# Every kernel takes its block from a price of its width that is fitted
+# to the compiler's own report and fits the default scoped VMEM at 16
+# planes: the scan 128 rows (SCAN_ROWS_WIDE), the copy-back 512, the
+# init / refresh 128, and the histogram sweeps one tile a plane
+# (hist_kernel2.hist_tiles).  A wider layout - a dense table of more
+# than ~2,030 columns, or an EFB dataset whose bundles unbundle past
+# the budget - falls back to the row_order path by the routing model's
+# ``comb_overwide`` / ``efb_overwide`` rules instead of dying in
 # Mosaic's VMEM allocator on chip.
 MAX_COMB_COLS = 16 * LANE
 
@@ -65,9 +64,32 @@ MAX_COMB_COLS = 16 * LANE
 # compaction's: both sides' log2(R)-bit routing words share one 23-bit
 # word (partition_kernel3._BIAS).
 SCAN_ROWS_MIN = 512
+# ... and on a comb past seven planes, where 512 rows do not fit the
+# scoped VMEM, down to this (partition_kernel2.scan_block_rows)
+SCAN_ROWS_WIDE = 128
 SCAN_ROWS_MAX = 2048
 COPYBACK_ROWS = 2048    # rows a step of the copy-back moves (pure DMA)
 HIST_COMB_ROWS = 2048   # rows a step of the comb-direct histogram reads
+
+# The scoped VMEM of a kernel that asks Mosaic for nothing, as every
+# comb kernel does (the v5e has 128 MiB): what a kernel's stack - its
+# pipelined blocks, scratch shapes, run_scoped buffers, the compiler's
+# own temporaries - has to fit.  Each kernel's price of a block is
+# fitted to the compiler's own report (partition_kernel2
+# ``scan_vmem_bytes`` / ``copyback_vmem_bytes``, stream_grad
+# ``stream_vmem_bytes``, hist_kernel2 ``hist_vmem_bytes``).
+SCOPED_VMEM_LIMIT = 16 * 1024 * 1024
+
+
+def fit_rows(price, top: int, floor: int,
+             limit: int = SCOPED_VMEM_LIMIT) -> int:
+    """The largest power of two in [``floor``, ``top``] rows a grid
+    step whose ``price(rows)`` fits ``limit``; ``floor`` where none
+    does (the caller's price says so: ``routing.comb_stageable``)."""
+    rows = top
+    while rows > floor and price(rows) > limit:
+        rows //= 2
+    return rows
 
 # Lines the comb and its scratch carry past the padded rows, ``n_alloc
 # - n_pad``, callers gating on the 2^24 row-id limit must subtract this
@@ -178,9 +200,10 @@ def cat_bitset_fit(padded_bins: int) -> bool:
 def comb_cols_fit(n_cols: int) -> bool:
     """Whether ``n_cols`` logical comb columns (features + value/rid/
     stream extras) fit the lane/VMEM column budget — the shape fact
-    behind the ``efb_overwide`` routing rule (ops/routing.py), shared
-    with the grow-build defense in ops/grow.py so the matrix and the
-    runtime can never disagree about which bundle expansions fit."""
+    behind the ``comb_overwide`` and ``efb_overwide`` routing rules
+    (ops/routing.py), shared with the grow-build defense in ops/grow.py
+    so the matrix and the runtime can never disagree about which
+    layouts fit."""
     return 0 < int(n_cols) <= MAX_COMB_COLS
 
 

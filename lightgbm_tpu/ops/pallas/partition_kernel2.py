@@ -86,8 +86,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .layout import (COMB_ROW_SLACK, COPYBACK_ROWS, SCAN_ROWS_MAX,
-                     SCAN_ROWS_MIN,
-                     check_lane_width, comb_shape, hbm_copies,
+                     SCAN_ROWS_MIN, SCAN_ROWS_WIDE, SCOPED_VMEM_LIMIT,
+                     check_lane_width, comb_shape, fit_rows, hbm_copies,
                      plane_copies)
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, \
     _go_left, make_reference_partition
@@ -96,15 +96,12 @@ from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, \
 _CUR_L, _CUR_TL, _CUR_R = 0, 1, 2
 
 
-# The scoped VMEM of a kernel that asks Mosaic for nothing, as both
-# scans do (the v5e has 128 MiB): what the kernel's stack - its scratch
-# shapes, the compaction's run_scoped buffers, the compiler's own
-# temporaries - has to fit.  The hook's [ngroups, M, N] accumulator is
-# an OUTPUT block and not on that stack: 1,024 rows x 384 lanes builds
-# under this limit at 12.04 MiB of stack beside 2 x 4.25 MiB of
-# accumulator, 512 x 896 at 14.59 beside 2 x 13.75.
-SCAN_VMEM_LIMIT = 16 * 1024 * 1024
-
+# Both scans build under layout.SCOPED_VMEM_LIMIT.  The hook's
+# [ngroups, M, N] accumulator is an OUTPUT block and not on that stack:
+# 1,024 rows x 384 lanes builds under the limit at 12.04 MiB of stack
+# beside 2 x 4.25 MiB of accumulator, 512 x 896 at 14.59 beside 2 x
+# 13.75.
+#
 # Lines of the comb ([C] f32) and further bytes a row of the block that
 # the scan's stack is priced at.  An upper envelope of what the TPU
 # compiler itself reports, read off-chip for the described v5e by
@@ -133,11 +130,15 @@ def scan_vmem_bytes(R: int, C: int) -> int:
 
 
 def scan_block_rows(C: int, *, scheme: str = "permute",
-                    vmem_limit: int = SCAN_VMEM_LIMIT) -> int:
+                    vmem_limit: int = SCOPED_VMEM_LIMIT) -> int:
     """Rows one grid step of the single-scan partition moves on a comb
     of ``C`` lanes, with or without the histogram hook: the largest
     power of two in [SCAN_ROWS_MIN, SCAN_ROWS_MAX] whose price fits
-    ``vmem_limit``.
+    ``vmem_limit`` - and below SCAN_ROWS_MIN, down to SCAN_ROWS_WIDE,
+    on a comb past seven planes, where not even 512 rows fit (128
+    rows at sixteen; the unfused scan's stack there reads 7.28 MiB
+    at 128 x 2,048, 14.54 at 256 and 28.75 at 512, the compiler's own
+    report, under prices of 8.4 / 16.8 / 33.5).
 
     Why the largest: a step costs ``F + R x c`` and F is no row's work
     - the step itself, its descriptors' issue and wait, its cursors -
@@ -147,7 +148,7 @@ def scan_block_rows(C: int, *, scheme: str = "permute",
     PERF.md, Findings, PR 37: the table, F and c).  One algorithm that
     wants another parameter at another width: 2,048 rows at one plane,
     1,024 at two and at three.  The hook's accumulator is no input: it
-    is not on the scan's stack (``SCAN_VMEM_LIMIT``), so the fused scan
+    is not on the scan's stack (``SCOPED_VMEM_LIMIT``), so the fused scan
     and the pair behind LGBM_TPU_FUSED=0 take the same block and leave
     a leaf's rows in the same order.  Every shard of a mesh builds the
     same kernel from the same shapes, so R is equal on all of them.
@@ -158,10 +159,25 @@ def scan_block_rows(C: int, *, scheme: str = "permute",
     docs/PERF_NOTES.md) lost by every step up."""
     if scheme == "matmul":
         return SCAN_ROWS_MIN
-    R = SCAN_ROWS_MAX
-    while R > SCAN_ROWS_MIN and scan_vmem_bytes(R, C) > vmem_limit:
-        R //= 2
-    return R
+    return fit_rows(lambda r: scan_vmem_bytes(r, C), SCAN_ROWS_MAX,
+                    SCAN_ROWS_WIDE, vmem_limit)
+
+
+def copyback_vmem_bytes(CB: int, C: int) -> int:
+    """The scoped VMEM the copy-back is priced at for ``CB`` rows a
+    step on a comb of ``C`` lanes: its two [CB, C] f32 tail buffers and
+    512 B a row.  The compiler's report: 2.72 MiB at 2,048 x 128, 4.81
+    at 2,048 x 256, 4.08 at 256 x 2,048, 8.14 at 512 x 2,048."""
+    return CB * (2 * C * 4 + 512)
+
+
+def copyback_block_rows(C: int) -> int:
+    """Rows a step of the copy-back moves on a comb of ``C`` lanes:
+    the largest power of two up to COPYBACK_ROWS whose price fits the
+    scoped VMEM (2,048 up to seven planes, 512 at sixteen).  The full
+    blocks are HBM -> HBM DMAs; only the tail block stages in VMEM, so
+    the block only bounds the descriptors a step."""
+    return fit_rows(lambda r: copyback_vmem_bytes(r, C), COPYBACK_ROWS, 8)
 
 
 def _pack_matmul(x, sel_ref, cnt, blk, is_last, out_ref, *, R: int,
@@ -440,7 +456,7 @@ def make_partition_ss(n: int, C: int, *, R: int = SCAN_ROWS_MIN,
                       size: int = 0,
                       dtype=jnp.float32, interpret: bool = False,
                       dynamic: bool = False,
-                      cb_block: int = COPYBACK_ROWS,
+                      cb_block: int = 0,
                       pack_impl=None, interpret_kernel: bool = False):
     """Single-scan partition: ``partition(sel, rows, scratch[,
     grid_blocks]) -> (rows', scratch', nleft)``, the contract of
@@ -461,7 +477,9 @@ def make_partition_ss(n: int, C: int, *, R: int = SCAN_ROWS_MIN,
 
     ``pack_impl`` swaps the per-block compaction (see _scan_kernel);
     partition_kernel3.make_partition_perm passes the butterfly-routing
-    permutation packing through here so the schedule has one home."""
+    permutation packing through here so the schedule has one home.
+    ``cb_block`` 0 takes the copy-back's block from the width
+    (``copyback_block_rows``)."""
     check_lane_width(C, dtype)
     if interpret and not interpret_kernel:
         return make_reference_partition(n, C, dtype=dtype,
@@ -471,12 +489,14 @@ def make_partition_ss(n: int, C: int, *, R: int = SCAN_ROWS_MIN,
             "interpret_kernel supports static grids only (the Pallas "
             "interpreter cannot run a traced grid bound)")
     nblocks = max((size + R - 1) // R, 1)
+    cb_block = cb_block or copyback_block_rows(C)
     kern = functools.partial(_scan_kernel, R=R, C=C, n=n,
                              pack_impl=pack_impl)
 
     def _call(sel, rows, scratch, grid_blocks):
         rows1, scratch1, res = pl.pallas_call(
             kern,
+            name="lgbm_partition_scan",
             grid=(grid_blocks,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                       pl.BlockSpec(memory_space=_HBM),

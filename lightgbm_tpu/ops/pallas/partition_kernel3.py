@@ -69,8 +69,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .layout import COMB_ROW_SLACK, COPYBACK_ROWS, SCAN_ROWS_MIN, \
-    check_lane_width
+from .layout import COMB_ROW_SLACK, SCAN_ROWS_MIN, check_lane_width
 from .partition_kernel import SEL_FEAT, _go_left
 from .partition_kernel2 import make_partition_ss, scan_block_rows
 
@@ -319,7 +318,7 @@ def make_partition_perm(n: int, C: int, *, R: int = SCAN_ROWS_MIN,
                         size: int = 0,
                         dtype=jnp.float32, interpret: bool = False,
                         dynamic: bool = False,
-                        cb_block: int = COPYBACK_ROWS,
+                        cb_block: int = 0,
                         interpret_kernel: bool = False):
     """Permutation-scheme single-scan partition: signature/contract
     identical to partition_kernel2.make_partition_ss (the two differ

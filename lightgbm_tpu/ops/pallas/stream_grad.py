@@ -66,6 +66,30 @@ COL_CONSTS = 9         # objective constants start here
 
 N_CONSTS = {"binary": 4, "l2": 6}
 
+# Rows a grid step of the init / refresh kernels takes (BlockSpec-
+# pipelined passes over [0, n_pad), their own block; every power of two
+# up to it divides the scan's), and the scoped VMEM both are priced at:
+# eight f32 comb lines and 1 KiB a row, an envelope of the compiler's own
+# report for the described v5e - the refresh 4.09 / 8.06 / 16.37 MiB at
+# 128 / 256 / 512 rows x 2,048 lanes, the init 7.38 / 14.73 / 29.81
+# (its [R, f] bins beside the line it builds).  512 rows up to seven
+# planes, 128 at sixteen.
+STREAM_ROWS = 512
+
+
+def stream_vmem_bytes(R: int, C: int) -> int:
+    """The scoped VMEM an init or refresh step of ``R`` rows on a comb
+    of ``C`` lanes is priced at."""
+    return R * (8 * C * 4 + 1024)
+
+
+def stream_block_rows(C: int) -> int:
+    """Rows a grid step of the init / refresh kernels takes on a comb of
+    ``C`` lanes: the largest power of two up to ``STREAM_ROWS`` whose
+    price fits the scoped VMEM."""
+    from .layout import fit_rows
+    return fit_rows(lambda r: stream_vmem_bytes(r, C), STREAM_ROWS, 8)
+
 
 def stream_columns(kind: str) -> int:
     """Total non-bin columns the streaming layout needs."""

@@ -90,6 +90,12 @@ class RouteInputs:
                                        # under efb_comb, the UNBUNDLED
                                        # logical width otherwise (only
                                        # meaningful with efb_bundled)
+    comb_overwide: bool = False        # a comb of no EFB bundles whose
+                                       # line no kernel stages:
+                                       # f_pad + extras past
+                                       # layout.MAX_COMB_COLS, or a
+                                       # scan block over its price at
+                                       # the engaged scheme
     fused_ok: bool = True              # fused_supported(f_pad, B, C)
     f_log_shard_divisible: bool = True
     over_budget: bool = False          # grow_footprint peak exceeds
@@ -141,7 +147,9 @@ class RouteInputs:
             f"part={self.partition_env};fused={b(self.fused_env)};"
             f"scat={b(self.hist_scatter_env)};"
             f"ob={b(self.over_budget)};pg={self.paged_env};"
-            f"mcb={self.mc_batch_env}")
+            f"mcb={self.mc_batch_env}"
+            # only where it holds: every other cell keeps the key it had
+            + (";cw=1" if self.comb_overwide else ""))
 
 
 # ---------------------------------------------------------------------
@@ -181,6 +189,15 @@ RULES: Tuple[Rule, ...] = (
          "(layout.MAX_COMB_COLS); blocks that wide cannot stage "
          "through VMEM",
          lambda i: i.efb_bundled and i.efb_overwide, loud=True),
+    # every comb kernel takes its block from its width, up to
+    # sixteen planes; a dense line past that (or a scan scheme whose
+    # block is over its price there) cannot be staged at all
+    Rule("comb_overwide", "physical", "num_features",
+         "the comb line of this table (its bin columns + the value / "
+         "row-id / stream columns) is wider than the kernels stage "
+         "through VMEM (layout.MAX_COMB_COLS, 16 planes; the scan's "
+         "block at the engaged scheme over its price)",
+         lambda i: i.comb_overwide, loud=True),
     # cat_subset is GONE (ISSUE 16): sorted-subset categorical splits
     # ride the fast path — membership ships as a bin-indexed bitset of
     # ceil(padded_bins/32) i32 words appended to the SMEM split
@@ -515,6 +532,18 @@ def env_snapshot() -> Dict[str, object]:
     )
 
 
+def comb_stageable(C: int, scheme: str = "permute") -> bool:
+    """Whether every comb kernel stages a line of ``C`` lanes: the
+    line within ``layout.MAX_COMB_COLS`` and the scan's block at
+    ``scheme`` within its price (the copy-back, histogram and stream
+    kernels size theirs from the width down to a few rows).  The shape
+    fact behind ``comb_overwide``."""
+    from .pallas.layout import SCOPED_VMEM_LIMIT, comb_cols_fit
+    from .pallas.partition_kernel2 import scan_block_rows, scan_vmem_bytes
+    return comb_cols_fit(C) and scan_vmem_bytes(
+        scan_block_rows(C, scheme=scheme), C) <= SCOPED_VMEM_LIMIT
+
+
 def resolve_layout(i: RouteInputs, *, f_pad: int,
                    padded_bins: int, rows: int = None,
                    num_leaves: int = 0,
@@ -549,6 +578,8 @@ def resolve_layout(i: RouteInputs, *, f_pad: int,
     resolved = replace(
         i, efb_overwide=bool(i.efb_bundled
                           and not comb_cols_fit(f_pad + n_extra)),
+        comb_overwide=bool(not i.efb_bundled
+                           and not comb_stageable(C, i.partition_env)),
         fused_ok=bool(fused_supported(int(f_pad), int(padded_bins), C)))
     if rows is None:
         return resolved
@@ -1014,7 +1045,8 @@ def enumerate_inputs() -> List[RouteInputs]:
             for obj, multi in _OBJ:
                 for flip in (None, "efb_bundled", "bins_u8",
                              "cat_subset", "gpu_use_dp", "cegb_lazy",
-                             "bagging", "linear_tree", "cat_overwide"):
+                             "bagging", "linear_tree", "cat_overwide",
+                             "comb_overwide"):
                     kw = dict(objective_kind=obj, multi_tree=multi)
                     if flip == "bins_u8":
                         kw[flip] = False
@@ -1057,6 +1089,9 @@ def enumerate_inputs() -> List[RouteInputs]:
                 efb_overwide=True, **env)
             add(learner=learner, n_shards=shards, efb_bundled=True,
                 efb_comb=learner == "serial", efb_overwide=True, **env)
+            # a dense line no kernel can stage
+            add(learner=learner, n_shards=shards, comb_overwide=True,
+                **env)
         # ISSUE 36: a bundled table whose grow options the bundle-space
         # finder does not cover keeps the unbundling ingest
         add(learner="serial", n_shards=1, efb_bundled=True,
@@ -1171,6 +1206,7 @@ FALLBACK_POPULATION: Dict[str, float] = {
     "cegb_lazy": 0.02,
     "cat_overwide": 0.02,
     "efb_overwide": 0.01,
+    "comb_overwide": 0.01,
 }
 
 

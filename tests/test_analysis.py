@@ -119,7 +119,8 @@ def test_every_pass_has_a_fixture():
     assert set(FIXTURES) == {"bad_lane", "bad_vmem", "bad_donation",
                              "bad_dma", "bad_host", "bad_purity",
                              "bad_mesh", "bad_route", "bad_retrace",
-                             "efb_overwide", "bad_page", "bad_cat",
+                             "efb_overwide", "comb_overwide",
+                             "bad_page", "bad_cat",
                              "bad_serve_kernel", "bad_mc_batch"}
     assert set(PASS_NAMES) == {"lane-contract", "vmem-budget",
                                "hbm-budget", "dma-race", "host-sync",

@@ -96,3 +96,48 @@ def test_subset():
     sub = ds.subset(np.array([5, 10, 20]))
     assert sub.num_data == 3
     np.testing.assert_array_equal(sub.metadata.label, [5, 10, 20])
+
+
+def _greedy_loop(dv, counts, max_bin, total_cnt, min_data_in_bin):
+    """The per-distinct-value loop of ``_greedy_find_boundaries`` when
+    no value is big enough for a bin of its own: the oracle of the
+    cumulative-count search that replaced it there."""
+    bounds, cur = [], 0
+    remaining_cnt, remaining_bins = total_cnt, max(max_bin, 1)
+    mean_rest = remaining_cnt / remaining_bins
+    lower = max(min_data_in_bin, 1)
+    for i in range(len(dv) - 1):
+        cur += counts[i]
+        if cur >= max(lower, mean_rest):
+            bounds.append((dv[i] + dv[i + 1]) / 2.0)
+            remaining_cnt -= cur
+            remaining_bins = max(remaining_bins - 1, 1)
+            mean_rest = remaining_cnt / remaining_bins
+            cur = 0
+        if len(bounds) >= max_bin - 1:
+            break
+    return bounds
+
+
+@pytest.mark.parametrize("kind", ["normal", "float32", "rounded",
+                                  "repeats", "tiny"])
+@pytest.mark.parametrize("max_bin,min_data_in_bin", [
+    (63, 3), (255, 3), (15, 20), (2, 1)])
+def test_greedy_bounds_without_big_values_match_the_loop(
+        kind, max_bin, min_data_in_bin):
+    """Where no sampled value holds a bin's worth of rows
+    (continuous columns), the bounds come from a search on cumulative
+    counts - the same floats the per-value loop gives, bit for bit."""
+    from lightgbm_tpu.io.binning import _greedy_find_boundaries
+    rng = np.random.default_rng(max_bin * 7 + min_data_in_bin)
+    n = {"tiny": 300}.get(kind, 20000)
+    v = {"normal": lambda: rng.normal(size=n),
+         "float32": lambda: rng.normal(size=n).astype(np.float32),
+         "rounded": lambda: np.round(rng.exponential(size=n), 3),
+         "repeats": lambda: rng.integers(0, n // 5, size=n),
+         "tiny": lambda: rng.normal(size=n)}[kind]().astype(np.float64)
+    dv, cnt = np.unique(v, return_counts=True)
+    assert len(dv) > max_bin and not (cnt >= n / max_bin).any()
+    got = _greedy_find_boundaries(dv, cnt, max_bin, n, min_data_in_bin)
+    assert got == _greedy_loop(dv, cnt, max_bin, n, min_data_in_bin)
+    assert 0 < len(got) <= max_bin - 1

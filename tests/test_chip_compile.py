@@ -36,6 +36,10 @@ HIGGS = (N_PAD, N_ALLOC, C, F_PAD)
 # DMA at an arbitrary row offset into it; the comb is plane-major
 # (ops/pallas/layout.py), which is what these cases hold
 MSLTR = (2_271_232, 2_277_376, 256, 144)
+# Epsilon, 400,000 x 2,000 dense on the stream route at 63 bins (64
+# padded): 401,408 rows + 6,144 lines of slack, 2,000 bin columns + 13
+# stream columns = 2,048 lanes, sixteen planes
+EPSILON = (401_408, 407_552, 2048, 2000)
 
 
 def _scan_rows(geom, scan: str = "permute") -> int:
@@ -112,13 +116,13 @@ def _stream(which: str, geom=HIGGS):
     features would)."""
     import jax.numpy as jnp
     from lightgbm_tpu.analysis.registry import sds
-    from lightgbm_tpu.ops.grow import _STREAM_R
     from lightgbm_tpu.ops.pallas.layout import comb_shape
     from lightgbm_tpu.ops.pallas.stream_grad import (N_CONSTS, make_init,
-                                                     make_refresh)
+                                                     make_refresh,
+                                                     stream_block_rows)
     n_pad, n_alloc, c, f_pad = geom
     kw = dict(kind="binary", sigmoid=1.0, f=f_pad, n_alloc=n_alloc,
-              n_pad=n_pad, C=c, R=_STREAM_R)
+              n_pad=n_pad, C=c, R=stream_block_rows(c))
     comb = sds(comb_shape(n_alloc, c), jnp.float32)
     if which == "init":
         return make_init(f_real=f_pad, **kw), (
@@ -218,6 +222,11 @@ COMPILES = {
         functools.partial(_fused, "permute", HIGGS, True), True),
     "fused_split_permute_raw_msltr": (
         functools.partial(_fused, "permute", MSLTR, True), True),
+    # sixteen planes: the grow program holds the scan, the
+    # copy-back, both histograms and the refresh (below); the init is
+    # the one comb kernel outside it
+    "stream_init_epsilon": (functools.partial(_stream, "init", EPSILON),
+                            True),
 }
 
 
@@ -354,6 +363,10 @@ def _serial_grow_program(one_chip, n, f, stream, crossover=None,
         # gives a comb of its width
         assert gp.scan_block_rows == _scan_rows((0, 0, gp._C, f)) == (
             2048 if gp._C == 128 else 1024)
+        # ... and at one and two planes the comb histogram
+        # is one tile of 2,048 rows, as before sixteen were built
+        assert (gp.comb_planes, gp.hist_tiles, gp.hist_block_rows) == (
+            gp._C // 128, 1, 2048)
         comb = comb_shape(gp._n_alloc, gp._C)
         rows = sds((1,) if stream else (n,), jnp.float32)
         args = [sds(comb, jnp.float32)] * 2 + [rows] * 3 + [
@@ -791,11 +804,83 @@ def test_the_bundled_comb_grow_program_compiles_at_the_expo_shape(
 def test_the_finder_at_the_msltr_width_is_the_xla_tail():
     """144 columns x 256 bins is past the Pallas finder's scoped-VMEM
     budget (apply_find.tail_supported), so that route's split finder is
-    the XLA tail and ``lgbm_apply_find`` is not in its program.  Flip
-    this pin in the PR that tiles the finder over features."""
+    the XLA tail and ``lgbm_apply_find`` is not in its program; so is
+    Epsilon's 2,000 x 64 (and 64 bins is under one 128-lane tile).
+    Flip this pin in the PR that tiles the finder over features."""
     from lightgbm_tpu.ops.pallas.apply_find import tail_supported
     assert tail_supported(F_PAD, BINS)
     assert not tail_supported(MSLTR[3], BINS)
+    assert not tail_supported(EPSILON[3], 64)
+
+
+def test_the_grow_program_compiles_at_the_epsilon_shape(
+        one_chip, no_compile_cache, record_property):
+    """The WHOLE grow program of ``epsilon-train-400k``
+    (401,408 x 2,000, 64 bins, stream binary, 255 leaves; a comb of
+    sixteen planes, 8 KiB a line) through the v5e compiler, in seconds
+    (the parent's did not finish in 900).  Unfused; the scan takes the
+    block ``scan_block_rows`` gives 2,048 lanes (128 rows) and the
+    copy-back 512; both comb histograms sweep sixteen one-plane tiles
+    of 2,048 rows (a [16 x 8, 64, 512] accumulator, 8 groups of 16
+    columns a tile); the finder is the XLA tail (no
+    ``lgbm_apply_find``) over the ``[255, 2000, 4, 64]`` pool; and
+    the footprint model's comb, scratch and pool are the compiled
+    program's."""
+    import re
+    import time
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.analysis.registry import sds
+    from lightgbm_tpu.obs import costmodel
+    from lightgbm_tpu.ops.grow import make_grow_fn
+    from lightgbm_tpu.ops.pallas.layout import comb_shape
+    from lightgbm_tpu.ops.split import SplitHyperParams
+    n, _, c, f = EPSILON
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        gp = make_grow_fn(
+            SplitHyperParams(min_data_in_leaf=1,
+                             min_sum_hessian_in_leaf=100.0),
+            num_leaves=LEAVES, padded_bins=64,
+            physical_bins=sds((n, f), jnp.uint8),
+            stream={"kind": "binary", "sigmoid": 1.0, "count": n})
+        assert (gp._C, gp.comb_planes, gp.fused) == (c, 16, False)
+        assert gp.scan_block_rows == _scan_rows(EPSILON) == 128
+        assert (gp.hist_tiles, gp.hist_block_rows) == (16, 2048)
+        comb = comb_shape(gp._n_alloc, gp._C)
+        args = [sds(comb, jnp.float32)] * 2 + [sds((1,), jnp.float32)] * 3 + [
+            sds((f,), jnp.float32), sds((f,), jnp.int32),
+            sds((f,), jnp.bool_), sds((f,), jnp.bool_), sds((), jnp.int32),
+            sds((), jnp.float32)]
+        t0 = time.perf_counter()
+        compiled = gp._grow_p.lower(*(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in args)).compile()
+        compile_s = time.perf_counter() - t0
+    record_property("compile_s", compile_s)
+    print(f"epsilon grow program: lowered and compiled in {compile_s:.1f} s")
+    assert compile_s < 120.0
+    text = compiled.as_text()
+    # the unfused scan, under a name of its own
+    assert len(re.findall(r"%lgbm_partition_scan(?:\.\d+)? = ", text)) == 1
+    assert "lgbm_split_scan" not in text
+    assert len(re.findall(r"%lgbm_copyback(?:\.\d+)? = ", text)) == 1
+    assert "lgbm_apply_find" not in text
+    assert f"f32[{LEAVES},{f},4,64]" in text
+    hists = re.findall(r"%lgbm_hist(?:\.\d+)? = (f32\[[\d,]+\])", text)
+    assert hists == ["f32[128,64,512]"] * 2, hists
+    fp = costmodel.grow_footprint(rows=400_000, f_pad=f, padded_bins=64,
+                                  num_leaves=LEAVES, stream=True,
+                                  fused=False)
+    assert (fp["geometry"]["n_alloc"], fp["geometry"]["C"]) == (
+        gp._n_alloc, c)
+    buf = fp["buffers"]
+    assert buf["comb"]["bytes"] == comb[0] * comb[1] * 4 == 3_338_665_984
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= buf["comb"]["bytes"] + buf[
+        "scratch"]["bytes"]
+    assert buf["hist_pool"]["bytes"] <= mem.temp_size_in_bytes < comb[
+        0] * comb[1] * 4 // 4
 
 
 # Off the default path, refused by the v5e compiler on jax 0.9.0 /

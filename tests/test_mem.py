@@ -89,10 +89,10 @@ def _grow_args(gp, n, f, b, stream):
 # footprint-model equality vs the real grow jaxprs (the acceptance
 # criterion: exact bytes, one AND two comb planes, stream on/off, mesh)
 # ---------------------------------------------------------------------
-_F_PLANES = {1: 16, 2: 144}     # feature columns -> comb planes
+_F_PLANES = {1: 16, 2: 144, 16: 2000}   # feature columns -> comb planes
 
 
-@pytest.mark.parametrize("planes", [1, 2])
+@pytest.mark.parametrize("planes", [1, 2, 16])
 @pytest.mark.parametrize("stream", [False, True])
 def test_footprint_equals_grow_jaxpr(planes, stream):
     import jax

@@ -842,6 +842,7 @@ class TestXplaneDecoder:
             # the names the pallas_calls carry since PR 27, as the
             # device plane of a chip capture spells them
             "lgbm_split_scan.1": "fused_split",
+            "lgbm_partition_scan.4": "partition_scan",
             "lgbm_copyback.1": "partition_copyback",
             "lgbm_hist.2": "hist_build",
             "lgbm_refresh": "stream_refresh",

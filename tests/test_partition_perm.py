@@ -301,20 +301,24 @@ CENSUS_STACK_MIB = {
 def test_scan_block_rows_is_one_function_of_width_and_vmem():
     """ISSUE 37: the rows a grid step of the scan moves come from the
     comb's width and the scoped VMEM a kernel gets - a power of two in
-    [SCAN_ROWS_MIN, SCAN_ROWS_MAX], never smaller for more VMEM or a
+    [SCAN_ROWS_MIN, SCAN_ROWS_MAX] (down to SCAN_ROWS_WIDE past seven
+    planes), never smaller for more VMEM or a
     narrower comb, 512 under the matmul compaction (O(R) a row), and
     what the compiled kernels were measured at: 2,048 rows at one
     plane, 1,024 at two.  The price bounds every reading the compiler
     gave of its own stack, and decides as the compiler did."""
-    from lightgbm_tpu.ops.pallas.fused_split import (
-        SCAN_VMEM_LIMIT, scan_block_rows, scan_vmem_bytes)
+    from lightgbm_tpu.ops.pallas.fused_split import (scan_block_rows,
+                                                     scan_vmem_bytes)
     from lightgbm_tpu.ops.pallas.layout import (COMB_ROW_SLACK,
                                                 COPYBACK_ROWS,
                                                 SCAN_ROWS_MAX,
-                                                SCAN_ROWS_MIN)
-    assert SCAN_VMEM_LIMIT == 16 * 2**20    # what a kernel gets unasked
+                                                SCAN_ROWS_MIN,
+                                                SCAN_ROWS_WIDE,
+                                                SCOPED_VMEM_LIMIT)
+    assert SCOPED_VMEM_LIMIT == 16 * 2**20    # what a kernel gets unasked
     assert scan_block_rows(128) == 2048     # higgs, expo, data4
     assert scan_block_rows(256) == 1024     # msltr
+    assert scan_block_rows(2048) == 128     # epsilon
     for (r, c), mib in CENSUS_STACK_MIB.items():
         price = scan_vmem_bytes(r, c) / 2**20
         assert mib <= price <= 1.25 * mib, (r, c, price)
@@ -323,11 +327,14 @@ def test_scan_block_rows_is_one_function_of_width_and_vmem():
     seen = set()
     for c in range(128, 2049, 128):
         prev = 0
-        for limit in (2**20, 2**23, SCAN_VMEM_LIMIT, 2**25, 2**26,
+        for limit in (2**20, 2**23, SCOPED_VMEM_LIMIT, 2**25, 2**26,
                       2**28):
             rr = scan_block_rows(c, vmem_limit=limit)
-            assert SCAN_ROWS_MIN <= rr <= SCAN_ROWS_MAX
+            assert SCAN_ROWS_WIDE <= rr <= SCAN_ROWS_MAX
             assert rr & (rr - 1) == 0
+            # under 512 rows only where 512 do not fit
+            assert rr >= SCAN_ROWS_MIN or scan_vmem_bytes(
+                SCAN_ROWS_MIN, c) > limit
             assert rr >= prev                   # monotone in VMEM
             assert rr <= scan_block_rows(max(c - 128, 128),
                                          vmem_limit=limit)
@@ -335,7 +342,7 @@ def test_scan_block_rows_is_one_function_of_width_and_vmem():
                                    vmem_limit=limit) == 512
             prev = rr
             seen.add(rr)
-    assert seen == {512, 1024, 2048}
+    assert seen == {128, 256, 512, 1024, 2048}
     # what was sized before the block was known covers the largest
     assert COMB_ROW_SLACK >= 2 * SCAN_ROWS_MAX + COPYBACK_ROWS
     # a shard of a mesh builds its kernel from the same shapes: the
@@ -356,11 +363,12 @@ def test_fused_supported_reads_the_scans_price(f_pad, b, c, ok):
     """ROADMAP C9: the predicate that decides the fused route charges
     what ``scan_block_rows`` charges, at the smallest block."""
     from lightgbm_tpu.ops.pallas.fused_split import (
-        SCAN_VMEM_LIMIT, fused_supported, hook_acc_bytes, scan_vmem_bytes)
-    from lightgbm_tpu.ops.pallas.layout import SCAN_ROWS_MIN
+        fused_supported, hook_acc_bytes, scan_vmem_bytes)
+    from lightgbm_tpu.ops.pallas.layout import (SCAN_ROWS_MIN,
+                                                SCOPED_VMEM_LIMIT)
     assert fused_supported(f_pad, b, c) is ok
     assert ok == bool(hook_acc_bytes(f_pad, b) and scan_vmem_bytes(
-        SCAN_ROWS_MIN, c) <= SCAN_VMEM_LIMIT)
+        SCAN_ROWS_MIN, c) <= SCOPED_VMEM_LIMIT)
 
 
 @pytest.mark.parametrize("r", [4, 96, 4096])
@@ -784,3 +792,123 @@ def test_routing_and_grower_price_the_same_comb(kind, stream, monkeypatch):
                 if stream else None))
     assert gp._C == (1024 if stream else 896)
     assert r.fused_ok is gp.fused is (not stream)
+
+
+# ---------------------------------------------------------------------
+# A comb line of sixteen planes (2,000 dense columns + the 13
+# stream columns of the binary objective: 2,048 lanes, 8 KiB a line).
+# Every kernel takes its block from its width: the scan 128 rows, the
+# copy-back 512, the init / refresh 128, and the comb histogram sweeps
+# one tile a plane.
+# ---------------------------------------------------------------------
+C16, F16 = 16 * LANE, 2000
+N16 = 2048 + 512
+
+
+def _rows16(seed=0):
+    rng = np.random.default_rng(seed)
+    rows = np.zeros((N16, C16), np.float32)
+    rows[:, :F16] = rng.integers(0, 64, size=(N16, F16))
+    rows[:, F16:F16 + 2] = rng.integers(-8, 9, size=(N16, 2))
+    # the stream columns past the values: arbitrary f32 the tiled
+    # histogram's last tile reads as bins of groups it drops
+    rows[:, F16 + 2:] = rng.normal(size=(N16, C16 - F16 - 2)) * 100
+    return rows
+
+
+def test_every_comb_kernel_is_priced_under_its_limit_at_sixteen_planes():
+    """The plan the benchmark asks for (``costmodel.comb_kernel_plan``):
+    at 2,000 columns every comb kernel's block is priced under the limit
+    it is compiled with; at one and two planes each kernel takes the
+    block it took before sixteen were built."""
+    from lightgbm_tpu.obs.costmodel import comb_kernel_plan
+    plan = comb_kernel_plan(features=2000, max_bins=63)
+    assert (plan["C"], plan["comb_planes"], plan["hist_tiles"]) == (
+        2048, 16, 16)
+    assert plan["stageable"]
+    rows = {k: v["rows"] for k, v in plan["kernels"].items()}
+    assert rows == {"scan": 128, "copyback": 512, "hist": 2048,
+                    "stream": 128}
+    for kern in plan["kernels"].values():
+        assert kern["vmem_bytes"] <= kern["vmem_limit"] == 16 * 2**20
+    for f, mb, stream, scan in ((28, 255, "binary", 2048),
+                                (137, 255, None, 1024)):
+        p = comb_kernel_plan(features=f, max_bins=mb, stream_kind=stream)
+        assert p["hist_tiles"] == 1 and p["kernels"]["scan"]["rows"] == scan
+        assert p["kernels"]["copyback"]["rows"] == 2048
+        if stream:
+            assert p["kernels"]["stream"]["rows"] == 512
+    # past MAX_COMB_COLS nothing stages: the comb_overwide rule's fact
+    assert not comb_kernel_plan(features=2100, max_bins=63)["stageable"]
+
+
+@pytest.mark.parametrize("f_pad,window", [
+    (256, (77, 3, 1333)), (528, (77, 3, 1333)), (F16, (77, 3, 1333)),
+    (F16, (0, 0, 2048)), (F16, (100, 0, 0))])
+def test_tiled_comb_histogram_is_the_untiled_one(f_pad, window,
+                                                 monkeypatch):
+    """Past two planes the comb-direct histogram sweeps one tile a plane
+    (a grid axis over tiles, each tile's groups resident): BITWISE the
+    one-tile kernel's and the numpy histogram of the window's rows, at
+    widths both build (256 columns at 64 bins: three planes, the values
+    in a plane of their own; 528: five planes) and at sixteen planes,
+    where the last tile reads the stream columns as bins of the groups
+    it drops."""
+    from lightgbm_tpu.ops.pallas import hist_kernel2 as hk
+    from lightgbm_tpu.ops.pallas.layout import to_planes
+    c, bins = comb_layout(f_pad + 13), 64
+    rows = _rows16()
+    if c != C16:
+        values = rows[:, F16:F16 + 2]
+        rows = rows[:, :c].copy()
+        rows[:, f_pad:] = 0.0
+        rows[:, f_pad:f_pad + 2] = values
+    comb = to_planes(jnp.asarray(rows))
+    assert hk.hist_tiles(f_pad, c) == -(-f_pad // LANE) > 1
+    start, off, cnt = window
+
+    def hist():
+        return np.asarray(hk.build_histogram_comb(
+            comb, jnp.int32(start), jnp.int32(off), jnp.int32(cnt),
+            f_pad=f_pad, size=2048, padded_bins=bins, rows_per_block=256,
+            interpret=True, planes=c // LANE))
+
+    tiled = hist()
+    monkeypatch.setattr(hk, "hist_tiles", lambda *a: 1)
+    np.testing.assert_array_equal(tiled, hist())
+    win = rows[start + off:start + off + cnt]
+    want = np.zeros((f_pad, bins, 2), np.float32)
+    for ch in range(2):
+        for f in range(f_pad):
+            want[f, :, ch] = np.bincount(
+                win[:, f].astype(np.int64), weights=win[:, f_pad + ch],
+                minlength=bins)
+    np.testing.assert_array_equal(tiled, want)
+
+
+@pytest.mark.parametrize("cfg", [(64, 900, 1999, 20), (129, 1, 1024, 31)])
+def test_sixteen_plane_scan_matches_the_oracle(cfg):
+    """The scan and copy-back at the blocks a 2,048-lane comb takes
+    (128 and 512 rows), through the Pallas interpreter: left segment
+    stable, right reversed, rows outside untouched - split columns in
+    the last plane over several blocks, and in a middle plane."""
+    from lightgbm_tpu.ops.pallas.layout import to_planes, to_rows
+    from lightgbm_tpu.ops.pallas.partition_kernel2 import (
+        copyback_block_rows, scan_block_rows)
+    r, cb = scan_block_rows(C16), copyback_block_rows(C16)
+    assert (r, cb) == (128, 512)
+    s0, cnt, feat, sbin = cfg
+    rows = _rows16()
+    rj = to_planes(jnp.asarray(rows))
+    fn = make_partition_perm(N16, C16, R=r, size=SIZE, interpret=True,
+                             interpret_kernel=True)
+    out, _, nl = fn(_sel(*cfg), rj, jnp.zeros_like(rj))
+    got = np.asarray(to_rows(out, C16))
+    seg = rows[s0:s0 + cnt]
+    gl = seg[:, feat] <= sbin
+    assert int(nl) == int(gl.sum())
+    np.testing.assert_array_equal(got[s0:s0 + int(nl)], seg[gl])
+    np.testing.assert_array_equal(got[s0 + int(nl):s0 + cnt],
+                                  seg[~gl][::-1])
+    np.testing.assert_array_equal(got[:s0], rows[:s0])
+    np.testing.assert_array_equal(got[s0 + cnt:], rows[s0 + cnt:])

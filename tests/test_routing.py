@@ -62,10 +62,12 @@ def _fresh_train(env, params=None, n=600, f=5, rounds=1, data="dense"):
         p = {"objective": "binary", "num_leaves": 7, "verbosity": -1}
         p.update(params or {})
         group = None
-        if data in ("dense", "wide", "rank"):
-            # "wide": 130 feature columns, a comb line of two planes
-            x = rng.normal(
-                size=(n, 130 if data == "wide" else f)).astype(np.float32)
+        if data in ("dense", "wide", "rank", "overwide"):
+            # "wide": 130 feature columns, a comb line of two planes;
+            # "overwide": 2,100, past the sixteen planes any comb
+            # kernel stages
+            x = rng.normal(size=(n, {"wide": 130, "overwide": 2100}.get(
+                data, f))).astype(np.float32)
             y = (x[:, 0] + 0.5 * x[:, 1] > 0)
             if data == "rank":
                 y = rng.integers(0, 5, size=n)
@@ -167,7 +169,7 @@ def test_every_row_order_cell_is_justified():
     # the over-wide residues remain priced
     pri = {p["reason"] for p in doc["summary"]["bench_priority"]}
     assert {"efb_overwide", "non_u8_bins", "gpu_use_dp", "cegb_lazy",
-            "cat_overwide", "n_pad_overflow"} == pri
+            "cat_overwide", "n_pad_overflow", "comb_overwide"} == pri
     assert "efb_bundle" not in doc["summary"]["fallback_reasons"]
     assert "cat_subset" not in doc["summary"]["fallback_reasons"]
 
@@ -199,6 +201,11 @@ def test_decide_semantics():
     # the shape fact alone (no bundling) never fires the rule
     d = decide(RouteInputs(efb_overwide=True, **tpu))
     assert d.path == "stream"
+    # a dense line no kernel stages falls back by its own name
+    d = decide(RouteInputs(comb_overwide=True, **tpu))
+    assert d.path == "row_order" and d.reasons == ("comb_overwide",)
+    assert "cw=1" in d.cell and "cw=" not in decide(
+        RouteInputs(**tpu)).cell
     # stream blockers leave the physical path engaged
     d = decide(RouteInputs(bagging=True, **tpu))
     assert d.path == "physical" and d.reasons == ("bagging_on",)
@@ -382,6 +389,16 @@ def test_fixture_bad_route():
     assert hits and all(f.fixture for f in hits)
 
 
+def test_fixture_comb_overwide():
+    """A cell that blames comb_overwide without the shape
+    fact in its key is refused by name."""
+    from lightgbm_tpu.analysis import run_analysis
+    rep = run_analysis(passes=["routing"], fixtures=["comb_overwide"])
+    hits = [f for f in rep.failing()
+            if f.code == "ROUTING_COMB_OVERWIDE_UNJUSTIFIED"]
+    assert hits and all(f.fixture for f in hits)
+
+
 def test_fixture_bad_retrace():
     from lightgbm_tpu.analysis import run_analysis
     rep = run_analysis(passes=["routing"], fixtures=["bad_retrace"])
@@ -441,6 +458,9 @@ SERIAL_CELLS = [
     ("cat_overwide", {"LGBM_TPU_PHYS": "interpret"},
      {"max_cat_to_onehot": 4, "max_bin": 300, "min_data_in_bin": 1},
      "cat", "row_order", {"cat_overwide", "non_u8_bins"}),
+    # a dense table past MAX_COMB_COLS takes row_order by name
+    ("comb_overwide", {"LGBM_TPU_PHYS": "interpret"}, {}, "overwide",
+     "row_order", {"comb_overwide"}),
     # EFB GRADUATED (ISSUE 12): trained bundled cells now engage the
     # physical fast path (stream on a streamable objective), with the
     # env knobs still walking the bundled config down the same ladder
@@ -496,7 +516,7 @@ def test_runtime_parity_serial(name, env, params, data, path, reasons):
     _assert_matches_matrix(out)
     # loud config fallbacks recorded as structured events
     for r in reasons & {"gpu_use_dp", "cegb_lazy", "non_u8_bins",
-                        "cat_overwide", "efb_overwide"}:
+                        "cat_overwide", "efb_overwide", "comb_overwide"}:
         assert out["events"].get(f"routing_fallback_{r}", 0) >= 1, \
             (r, out["events"])
     # the graduated rules' warn-once paths are DEAD code — no run may

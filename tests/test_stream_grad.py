@@ -96,12 +96,21 @@ def test_stream_gates_off_for_bagging_and_renew():
     assert not s_l1, "renew objectives must disable streaming"
 
 
-def test_stream_vs_plain_quality():
+@pytest.mark.parametrize("n,f,rounds,kw", [
+    (6000, 6, 8, {}),
+    # a comb of nine planes and one of sixteen (1,100 and
+    # 2,000 columns at 63 bins: 1,152 and 2,048 lanes)
+    (1500, 1100, 2, {"max_bin": 63, "expect_c": 1152}),
+    (1500, 2000, 2, {"max_bin": 63, "expect_c": 2048})],
+    ids=["narrow", "nine_planes", "sixteen_planes"])
+def test_stream_vs_plain_quality(n, f, rounds, kw):
     # end-to-end sanity at slightly larger scale against the row_order
     # path: identical early trees, close predictions
-    p_ref, t_ref, _ = _fresh_train("0", "0", "binary", n=6000, rounds=8)
-    p_str, t_str, s = _fresh_train("interpret", "", "binary", n=6000,
-                                   rounds=8)
+    p_ref, t_ref, _ = _fresh_train("0", "0", "binary", n=n, f=f,
+                                   rounds=rounds, max_bin=kw.get("max_bin",
+                                                                 255))
+    p_str, t_str, s = _fresh_train("interpret", "", "binary", n=n, f=f,
+                                   rounds=rounds, **kw)
     assert s
     _assert_trees_close(t_ref[:4], t_str[:4])
     np.testing.assert_allclose(p_ref, p_str, rtol=2e-2, atol=2e-3)
@@ -123,19 +132,25 @@ def test_stream_two_plane_matches_gather_refresh(objective):
     np.testing.assert_allclose(p_ref, p_str, rtol=5e-3, atol=1e-3)
 
 
-def test_stream_two_plane_kernels_vs_reference():
+@pytest.mark.parametrize("f,C,root", [(144, 256, True),
+                                       (2000, 2048, False)])
+def test_stream_two_plane_kernels_vs_reference(f, C, root):
     """The REAL stream kernels (init, refresh, fused refresh+root-hist)
     at C = 256 - 144 bin columns, every stream column in the second
-    plane - run through the Pallas interpreter track their XLA
-    references to bf16-rounding tolerance on live rows (the kernels
-    round g/h to bf16 — the precision every histogram matmul applies on
-    chip anyway; slack rows are contractually dead)."""
+    plane - and at C = 2,048 (2,000 bin columns, sixteen planes, the
+    block the width gives: 128 rows; no fused root there) run through
+    the Pallas interpreter track their XLA references to bf16-rounding
+    tolerance on live rows (the kernels round g/h to bf16 — the
+    precision every histogram matmul applies on chip anyway; slack
+    rows are contractually dead)."""
     import jax.numpy as jnp
     from lightgbm_tpu.ops.pallas.layout import comb_shape, to_rows
     from lightgbm_tpu.ops.pallas.stream_grad import (
-        binary_consts, build_aux, make_init, make_refresh)
+        binary_consts, build_aux, make_init, make_refresh,
+        stream_block_rows)
     rng = np.random.default_rng(0)
-    n_alloc, f, n_pad, C, R = 2048 + 512, 144, 2048, 256, 512
+    n_alloc, n_pad, R = 2048 + 512, 2048, stream_block_rows(C)
+    assert R == (512 if C == 256 else 128)
     bins = jnp.asarray(rng.integers(0, 200, size=(n_pad, f))
                        .astype(np.uint8))
     aux = build_aux(
@@ -165,6 +180,8 @@ def test_stream_two_plane_kernels_vs_reference():
     r_ref = make_refresh(**rkw, interpret=True)(c_ref, lv)
     r_kern = make_refresh(**rkw, kernel_interpret=True)(c_kern, lv)
     assert np.abs(live(r_ref) - live(r_kern)).max() < 2e-2
+    if not root:
+        return
 
     _, h_ref = make_refresh(**rkw, interpret=True, root_hist=True,
                             padded_bins=256, root_rpb=256)(c_ref, lv)
