@@ -673,8 +673,9 @@ class GBDT:
         event of the set-up and for a benchmark's check: the stored bin
         columns, the logical features they stand for, the EFB bundles
         among them, and the bytes of one comb line (0 off the physical
-        route, which holds no comb), its 128-lane planes and the tiles
-        a comb histogram sweeps (0 off the physical route)."""
+        route, which holds no comb), its 128-lane planes, the tiles
+        a comb histogram sweeps and the split of a bin its one-hots
+        take (``hist_lo_n``; 0 off the physical route)."""
         dd, b = self.dd, self.dd.bundle
         pieces = getattr(self.grow, "_pieces", None)
         width = pieces.C if pieces is not None else getattr(
@@ -690,6 +691,7 @@ class GBDT:
             "comb_line_bytes": int(width) * jnp.dtype(dtype).itemsize,
             "comb_planes": int(width) // 128,
             "hist_tiles": int(getattr(self.grow, "hist_tiles", 0)),
+            "hist_lo_n": int(getattr(self.grow, "hist_lo_n", 0)),
         }
 
     def routing_info(self) -> Optional[Dict]:
@@ -1660,9 +1662,11 @@ class GBDT:
             total.update(mesh_args(total.get("splits", 0.0), len(kidxs)))
         if scan_r:
             total["scan_block_rows"] = scan_r
-        # the comb's planes, the tiles a comb histogram sweeps and the
-        # rows a step of it reads, from the built program
-        for name in ("comb_planes", "hist_tiles", "hist_block_rows"):
+        # the comb's planes, the tiles a comb histogram sweeps, the rows
+        # a step of it reads and the split of a bin, from the built
+        # program
+        for name in ("comb_planes", "hist_tiles", "hist_block_rows",
+                     "hist_lo_n"):
             if getattr(self.grow, name, 0):
                 total[name] = int(getattr(self.grow, name))
         span.set(**total)

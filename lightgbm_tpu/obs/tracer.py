@@ -33,7 +33,8 @@ booster is built)::
                                         args phys_cols,
                                         logical_features, bundles,
                                         comb_cols, comb_line_bytes,
-                                        comb_planes, hist_tiles
+                                        comb_planes, hist_tiles,
+                                        hist_lo_n
                                         (GBDT.layout_info())
 
 The span tree of one boosting iteration (serial learner, fast path;
@@ -59,9 +60,11 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
                                         grid step of the partition scan
                                         moves (scan_steps counts them);
                                         comb_planes, hist_tiles (tiles a
-                                        comb histogram sweeps) and
-                                        hist_block_rows, from the built
-                                        program's shapes
+                                        comb histogram sweeps),
+                                        hist_block_rows and hist_lo_n
+                                        (the split of a bin:
+                                        bin = hi * lo_n + lo), from the
+                                        built program's shapes
           HbmCensus                     phase Tree::grow: under the
                                         running program, so the device
                                         does not wait for the walk

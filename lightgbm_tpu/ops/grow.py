@@ -727,12 +727,15 @@ def make_grow_fn(
         # slack lines: the longest kernel tail past the padded rows
         # (layout.COMB_ROW_SLACK: the scan's right zone + the
         # copy-back's tail block in the scratch)
-        # rows a step of the comb-direct histogram reads, and the tiles
-        # it sweeps (one a bin plane past two planes:
-        # hist_kernel2.hist_tiles)
-        from .pallas.hist_kernel2 import hist_block_rows, hist_tiles
+        # rows a step of the comb-direct histogram reads, the tiles it
+        # sweeps (one a bin plane past two planes:
+        # hist_kernel2.hist_tiles) and the split of a bin its one-hots
+        # take (hist_kernel2.hist_geometry's lo_n)
+        from .pallas.hist_kernel2 import hist_block_rows, hist_geometry, \
+            hist_tiles
         _HIST_RPB = hist_block_rows(_C_PHYS)
         _HIST_TILES = hist_tiles(f_pad_p, _C_PHYS)
+        _HIST_LO_N = hist_geometry(int(padded_bins))[4]
         _n_alloc = n_rows_p + PHYS_ROW_SLACK
         if _n_alloc >= (1 << 24):
             # row ids ride in three f32 byte columns and are decoded with
@@ -766,7 +769,6 @@ def make_grow_fn(
         # contracting every row of the parent, and where the record
         # named the larger child.
         from .pallas import fused_split as _fs
-        from .pallas.hist_kernel2 import hist_geometry
         from .pallas.partition_kernel import (SIDE_LEFT, SIDE_NONE,
                                               SIDE_RIGHT)
         _use_fused = (FUSED_IMPL != "0" and _fs.fused_supported(
@@ -2581,7 +2583,8 @@ def make_grow_fn(
             root0_fn=_root0_fn, ingest=_efb_ingest,
             paged_plan=paged, reanchor_fn=_reanchor_fn,
             scan_block_rows=_PHYS_R, pull_score_fn=_pull_score_fn,
-            hist_tiles=_HIST_TILES, hist_block_rows=_HIST_RPB))
+            hist_tiles=_HIST_TILES, hist_block_rows=_HIST_RPB,
+            hist_lo_n=_HIST_LO_N))
 
     if use_cegb_lazy:
         @jax.jit
@@ -2670,7 +2673,8 @@ class _PhysicalGrow:
                  stream_init=None, dtype=jnp.float32, fused=False,
                  root0_fn=None, ingest=None,
                  paged_plan=None, reanchor_fn=None, scan_block_rows=0,
-                 pull_score_fn=None, hist_tiles=0, hist_block_rows=0):
+                 pull_score_fn=None, hist_tiles=0, hist_block_rows=0,
+                 hist_lo_n=0):
         self._grow_p = grow_p
         self._bins_dev = bins_dev
         # EFB (ISSUE 12): the carried bins stay BUNDLED (the smaller
@@ -2690,12 +2694,14 @@ class _PhysicalGrow:
         # rows a grid step of the scan moves (obs: Tree::grow's
         # scan_block_rows / scan_steps)
         self.scan_block_rows = int(scan_block_rows)
-        # the comb's 128-lane planes, the tiles a comb histogram sweeps
-        # and the rows a step of it reads (obs: Tree::grow's
-        # comb_planes / hist_tiles / hist_block_rows)
+        # the comb's 128-lane planes, the tiles a comb histogram sweeps,
+        # the rows a step of it reads and the split of a bin its
+        # one-hots take (obs: Tree::grow's comb_planes / hist_tiles /
+        # hist_block_rows / hist_lo_n)
         self.comb_planes = C // 128
         self.hist_tiles = int(hist_tiles)
         self.hist_block_rows = int(hist_block_rows)
+        self.hist_lo_n = int(hist_lo_n)
         self._root0_fn = root0_fn    # fused stream: tree-0 root hist
         self._root_hist = None       # fused stream: carried root hist
         # paged comb (ISSUE 15): pages live host-side between trees and

@@ -3,16 +3,20 @@
 Reference analog: the CUDA histogram kernel
 (src/treelearner/cuda/cuda_histogram_constructor.cu:18-126) which uses
 shared-memory atomicAdd per (feature, bin).  TPUs have no fast scatter-atomics,
-so the op is re-expressed for the MXU as a **nibble-decomposed one-hot
-matmul**:
+so the op is re-expressed for the MXU as a **split-bin one-hot matmul**:
 
-    bin = hi * 16 + lo          (hi in [0, B/16), lo in [0, 16))
+    bin = hi * lo_n + lo        (hi in [0, B_hi), lo in [0, lo_n), B_hi * lo_n = B)
     hist[f, hi, lo, c] = sum_r onehot_hi[r, f, hi] * onehot_lo[r, f, lo] * val[r, c]
 
-Features are packed in groups of ``G`` so the matmul operands are
-``[R, G * B_hi]`` x ``[R, G * 16 * C]`` with ``G * B_hi == 128`` — a full MXU
-tile on the M axis, contraction over rows.  Cross-feature blocks of the
-``[128, G*16*C]`` product are garbage and discarded (the diagonal g==g' blocks
+Features are packed in groups of ``G`` (``feature_group_size``) so the matmul
+operands are ``[R, G * B_hi]`` x ``[R, G * lo_n * C]``, contraction over rows.
+The Pallas kernels (ops/pallas/hist_kernel2.hist_geometry) take the split from
+the bin count: ``lo_n = B / 8`` up to 128 bins (``B_hi = 8``, ``G = 16``) and
+``lo_n = 16`` past it, so ``G * B_hi == 128`` — a full MXU tile on the M axis —
+up to 128 bins and at 256 (at most 128 between them).  The XLA matmul
+formulation below (no chip route runs it) keeps ``lo_n = 16``, whose M axis is
+``G * B/16``: 128 at 256 bins, less below 128.  Cross-feature blocks of the
+``[M, G*lo_n*C]`` product are garbage and discarded (the diagonal g==g' blocks
 are the per-feature histograms); this costs a factor ``G`` of extra FLOPs but
 turns an un-TPU-friendly scatter into dense matmuls, which wins by orders of
 magnitude.  Rows are streamed in blocks with ``lax.scan`` to bound the one-hot
@@ -44,16 +48,20 @@ import numpy as np
 
 
 def bins_per_feature_padded(max_num_bins: int) -> int:
-    """Pad per-feature bin count to a multiple of 16 (nibble decomposition)."""
+    """Pad per-feature bin count to a multiple of 16 (the split-bin
+    decomposition: a whole ``lo_n`` at every split)."""
     b = max(int(max_num_bins), 16)
     return int(np.ceil(b / 16) * 16)
 
 
 def feature_group_size(padded_bins: int) -> int:
-    """Features per matmul group: G * (B/16) <= 128 (one MXU tile on the M
-    axis), with G capped at 16 to bound the Pallas kernel's unrolled one-hot
-    construction.  The XLA matmul impl and the Pallas kernel share this value
-    so the dataset's feature padding satisfies both."""
+    """Features per matmul group: G * (B/16) <= 128 (at most one MXU tile
+    on the M axis of the XLA matmul's 16-wide split), with G capped at 16 to
+    bound the Pallas kernel's unrolled one-hot construction: 16 up to 128
+    bins, where the Pallas kernels split a bin 8 ways on hi and fill the M
+    axis (hist_kernel2.hist_geometry).  The XLA matmul impl and the Pallas
+    kernel share this value so the dataset's feature padding satisfies
+    both."""
     b_hi = max(padded_bins // 16, 1)
     return max(min(128 // b_hi, 16), 1)
 

@@ -84,7 +84,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .hist_kernel2 import _LO_N, _diag_extract, _hist_accumulate, \
+from .hist_kernel2 import _diag_extract, _hist_accumulate, \
     build_histogram_comb, hist_geometry
 from .partition_kernel import _HBM, SEL_S0, SEL_CNT, SEL_FEAT, SEL_SIDE, \
     SIDE_LEFT, SIDE_NONE, _go_left, make_reference_partition
@@ -99,8 +99,8 @@ def hook_acc_bytes(f_pad: int, b: int) -> int:
     """Bytes of the hook's resident [ngroups, M, N] f32 accumulator for
     ``f_pad`` columns of ``b`` padded bins; 0 where the geometry has no
     whole number of feature groups (no hook can be built)."""
-    _, g, m, nn = hist_geometry(b, _CHANNELS)
-    if b % _LO_N != 0 or f_pad % g != 0:
+    _, g, m, nn, lo_n = hist_geometry(b, _CHANNELS)
+    if b % lo_n != 0 or f_pad % g != 0:
         return 0
     return (f_pad // g) * m * nn * 4
 
@@ -168,8 +168,8 @@ def hook_histogram(acc, f_pad: int, padded_bins: int):
     """The [f_pad, padded_bins, 2] histogram out of the scan's
     [ngroups, M, N] accumulator (50.6 us a split at 18 groups: called
     where the histogram is read, not at every split)."""
-    b_hi, g, _, _ = hist_geometry(padded_bins, _CHANNELS)
-    return _diag_extract(acc, f_pad // g, g, b_hi, _CHANNELS, _LO_N,
+    b_hi, g, _, _, lo_n = hist_geometry(padded_bins, _CHANNELS)
+    return _diag_extract(acc, f_pad // g, g, b_hi, _CHANNELS, lo_n,
                          f_pad, padded_bins)
 
 
@@ -280,7 +280,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     if scan not in ("matmul", "permute"):
         raise ValueError(f"unknown scan scheme {scan!r}")
     b = int(padded_bins)
-    b_hi, g, m, nn = hist_geometry(b, _CHANNELS)
+    b_hi, g, m, nn, lo_n = hist_geometry(b, _CHANNELS)
     assert f_pad % g == 0, (f_pad, g)
     ngroups = f_pad // g
     if scan == "permute":
@@ -344,7 +344,7 @@ def make_fused_split(n: int, C: int, *, f_pad: int, padded_bins: int,
     nblocks = max((size + R - 1) // R, 1)
     cb_block = cb_block or copyback_block_rows(C)
     kern = functools.partial(_fused_scan_kernel, R=R, C=C, n=n,
-                             f_pad=f_pad, b_hi=b_hi, g=g, lo_n=_LO_N,
+                             f_pad=f_pad, b_hi=b_hi, g=g, lo_n=lo_n,
                              ngroups=ngroups, pack_impl=_pack)
 
     def _call(sel, rows, scratch, grid_blocks):

@@ -4,12 +4,16 @@ The TPU re-design of the reference's hottest kernel
 (``CUDAConstructHistogramDenseKernel``,
 src/treelearner/cuda/cuda_histogram_constructor.cu:18-68; CUDA uses
 shared-memory atomicAdd per (feature, bin)).  TPUs have no scatter-atomics,
-so the histogram is a nibble-decomposed one-hot contraction on the MXU
-(see ops/histogram.py for the math).  v2 fixes the two things that made both
-the pure-XLA formulation and the v1 kernel bandwidth/VPU-bound:
+so the histogram is a split-bin one-hot contraction on the MXU
+(see ops/histogram.py for the math; here ``bin = hi * lo_n + lo`` with the
+split ``hist_geometry`` takes from the bin count, so a group of columns
+fills the M axis at every bin count up to 128).  v2 fixes the two things
+that made both the pure-XLA formulation and the v1 kernel
+bandwidth/VPU-bound:
 
 1. **One-hot construction via constant matmuls.**  Expanding ``hi[r, g]`` to
-   its 16-lane span (and ``lo``/values to their 48-lane spans) with
+   its ``b_hi``-lane span (and ``lo``/values to their ``lo_n * C``-lane
+   spans) with
    reshape/concat causes TPU relayouts — sublane shuffles that dominated v1.
    Instead the lane-broadcast is itself a matmul with a tiny constant 0/1
    matrix (``[G, M]`` / ``[C, N]``), so the MXU does the replication and the
@@ -37,18 +41,23 @@ from jax.experimental.pallas import tpu as pltpu
 from ..histogram import feature_group_size
 from .layout import HIST_COMB_ROWS, load_rows
 
-_LO_N = 16   # hi/lo nibble split shared by every histogram kernel
-
 
 def hist_geometry(b: int, channels: int = 2):
-    """(b_hi, g, m, nn) of the [ngroups, M, N] nibble-one-hot
-    accumulator layout for padded_bins ``b`` — the single source of
-    truth for every kernel that embeds this accumulation (hist_kernel2
-    itself, fused_split's scan hook, stream_grad's fused refresh+root
-    pass)."""
-    b_hi = max(b // _LO_N, 1)
+    """(b_hi, g, m, nn, lo_n) of the [ngroups, M, N] one-hot
+    accumulator layout for padded_bins ``b`` (a multiple of 16) — the
+    single source of truth for every kernel that embeds this
+    accumulation (hist_kernel2 itself, fused_split's scan hook,
+    stream_grad's fused refresh+root pass).
+
+    A bin splits as ``bin = hi * lo_n + lo``.  Up to 128 bins
+    ``lo_n = b / 8``, so ``b_hi = 8`` and the group's ``g = 16`` columns
+    fill the M axis (``g * b_hi == 128``, one whole MXU pass); past 128
+    ``lo_n = 16``.  ``M x N = g^2 x b x channels`` whatever the split:
+    only the place of an entry in the product changes."""
+    lo_n = b // 8 if b <= 128 else 16
+    b_hi = b // lo_n
     g = feature_group_size(b)
-    return b_hi, g, g * b_hi, g * _LO_N * channels
+    return b_hi, g, g * b_hi, g * lo_n * channels, lo_n
 
 
 def onehot_consts(b_hi, g, c, lo_n):
@@ -79,8 +88,9 @@ def onehot_consts(b_hi, g, c, lo_n):
 
 
 def _hist_accumulate(b, v, out_ref, *, b_hi, g, c, lo_n, ngroups):
-    """Shared accumulation body: one-hot nibble contraction of a block's
-    bins [R, F] (i32) and values [R, C] (f32) into out_ref [ngroups, M, N]."""
+    """Shared accumulation body: one-hot (hi, lo) contraction of a
+    block's bins [R, F] (i32) and values [R, C] (f32) into out_ref
+    [ngroups, M, N], at the split ``hist_geometry`` gives."""
     e_hi, e_lo, e_v, lane_hi, lane_lo = onehot_consts(b_hi, g, c, lo_n)
 
     hi = b // lo_n
@@ -224,8 +234,7 @@ def _comb_hist_call(comb, start, off, count, nblocks, *, f_pad, b, rpb,
     n_rows, C = comb.shape[0] // planes, planes * LANE
     check_lane_width(comb.shape[1], comb.dtype)
     c = channels
-    lo_n = _LO_N
-    b_hi, g, m, nn = hist_geometry(b, c)
+    b_hi, g, m, nn, lo_n = hist_geometry(b, c)
     assert f_pad % g == 0, (f_pad, g)
     ngroups = f_pad // g
     start_blk = start // rpb
@@ -372,8 +381,7 @@ def build_histogram_pallas2(
     n, f_pad = bins.shape
     c = values.shape[1]
     b = int(padded_bins)
-    lo_n = _LO_N
-    b_hi, g, m, nn = hist_geometry(b, c)
+    b_hi, g, m, nn, lo_n = hist_geometry(b, c)
     assert f_pad % g == 0, (f_pad, g)
     ngroups = f_pad // g
 

@@ -381,10 +381,9 @@ def make_refresh(*, kind: str, sigmoid: float, f: int, n_alloc: int,
         return jax.jit(refresh1)
 
     if root_hist:
-        from .hist_kernel2 import _LO_N as lo_n, _diag_extract, \
-            hist_geometry
+        from .hist_kernel2 import _diag_extract, hist_geometry
         b = int(padded_bins)
-        b_hi, hg, m, nn = hist_geometry(b, 2)
+        b_hi, hg, m, nn, lo_n = hist_geometry(b, 2)
         assert f % hg == 0, (f, hg)
         ngroups = f // hg
         kern_h = functools.partial(

@@ -821,9 +821,9 @@ def test_the_grow_program_compiles_at_the_epsilon_shape(
     (the parent's did not finish in 900).  Unfused; the scan takes the
     block ``scan_block_rows`` gives 2,048 lanes (128 rows) and the
     copy-back 512; both comb histograms sweep sixteen one-plane tiles
-    of 2,048 rows (a [16 x 8, 64, 512] accumulator, 8 groups of 16
-    columns a tile); the finder is the XLA tail (no
-    ``lgbm_apply_find``) over the ``[255, 2000, 4, 64]`` pool; and
+    of 2,048 rows (a [16 x 8, 128, 256] accumulator, 8 groups of 16
+    columns a tile, a bin split 8 x 8: whole MXU passes); the finder
+    is the XLA tail (no ``lgbm_apply_find``) over the ``[255, 2000, 4, 64]`` pool; and
     the footprint model's comb, scratch and pool are the compiled
     program's."""
     import re
@@ -868,7 +868,7 @@ def test_the_grow_program_compiles_at_the_epsilon_shape(
     assert "lgbm_apply_find" not in text
     assert f"f32[{LEAVES},{f},4,64]" in text
     hists = re.findall(r"%lgbm_hist(?:\.\d+)? = (f32\[[\d,]+\])", text)
-    assert hists == ["f32[128,64,512]"] * 2, hists
+    assert hists == ["f32[128,128,256]"] * 2, hists
     fp = costmodel.grow_footprint(rows=400_000, f_pad=f, padded_bins=64,
                                   num_leaves=LEAVES, stream=True,
                                   fused=False)
