@@ -483,10 +483,9 @@ class Booster:
         return out
 
     def _eval(self, dataset_name: str, feval=None) -> List:
-        res = []
-        for ds_name, metric, value, hb in self._inner.eval():
-            if ds_name == dataset_name:
-                res.append((ds_name, metric, value, hb))
+        # one data set's metrics, no other's computed
+        # (LGBM_BoosterGetEval(data_idx))
+        res = list(self._inner.eval(dataset_name))
         if feval is not None:
             res.extend(_run_feval(self, feval, dataset_name))
         return res

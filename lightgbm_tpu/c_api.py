@@ -425,7 +425,7 @@ def LGBM_BoosterGetEval(handle: int, data_idx: int, out_results: List[float]):
                 f"data_idx {data_idx} out of range "
                 f"({len(names)} validation sets)")
         want = names[data_idx - 1]
-        res = [r for r in bst.eval_valid() if r[0] == want]
+        res = bst.eval(None, want)
     out_results[:] = [v for _, _, v, _ in res]
     return 0
 

@@ -16,7 +16,7 @@ AddBias, matching gbdt.cpp:505-512, so saved models are self-contained.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,7 @@ from ..obs import events as obs_events
 from ..obs import hbm_live_bytes as obs_hbm_live_bytes
 from ..obs import ledger as obs_ledger
 from ..obs import tracer as obs_tracer
+from ..obs.counters import tree_depth
 from ..obs.tracer import phase as obs_phase
 from ..objective.base import ObjectiveFunction
 from ..ops.device_data import DeviceDataset, to_device
@@ -58,6 +59,62 @@ class _ValidSet:
         self.metrics = metrics
         self.score = None  # [K, n] device
         self.raw = None    # [n, f] device raw values (linear_tree only)
+
+
+class _PendingEval(NamedTuple):
+    dataset: str
+    metric: Metric
+    value: jax.Array        # the metric program's output, not pulled
+
+
+def _eval_dispatch(ds_name, metric, arg) -> _PendingEval:
+    """A metric's device program dispatched, under the tracer's
+    ``eval:<data set>:<metric>``."""
+    fn = metric.device_program()
+    value = fn(arg)
+    obs_tracer.program(f"eval:{ds_name}:{metric.NAME}", fn, arg)
+    return _PendingEval(ds_name, metric, value)
+
+
+def make_score_tail(num_bins, has_nan, fmap=None):
+    """The jitted score tail ``GBDT`` dispatches after every tree:
+    ``tail(ta, leaf_id, score_k, vbins, vscores_k, rate, init_score)``
+    -> (the train score plus the tree, or None where ``leaf_id`` is
+    None; each valid score plus the tree, replayed over that set's bins;
+    the device tree with shrunk leaf values).  Its ops are ``score``'s,
+    each valid set's replay ``valid``'s."""
+
+    @jax.jit
+    @obs_phase("score")
+    def tail(ta, leaf_id, score_k, vbins, vscores_k, rate, init_score):
+        is_real = ta.num_leaves > 1
+        if leaf_id is None:
+            # the unpaged stream route: the refresh added this
+            # delta to the comb's scores, by position
+            new_score = None
+        else:
+            delta = jnp.where(
+                is_real,
+                rate * leaf_table_lookup(ta.leaf_value, leaf_id), 0.0)
+            new_score = score_k + delta
+        dt = device_tree_from_arrays(ta)
+        new_vscores = []
+        for vb, vsk in zip(vbins, vscores_k):
+            # a phase of its own inside the tail: with no valid set
+            # the program is the one it always was
+            with obs_phase("valid"):
+                leaf_v = predict_leaf_bins(dt, vb, num_bins, has_nan,
+                                           feat_map=fmap)
+                dv = jnp.where(
+                    is_real,
+                    rate * leaf_table_lookup(ta.leaf_value, leaf_v), 0.0)
+                new_vscores.append(vsk + dv)
+        # replay replica: shrunk values (+ boost-from-average bias,
+        # which the host path folds in via add_bias / single_leaf)
+        lv = jnp.where(is_real, ta.leaf_value * rate, 0.0) + init_score
+        return new_score, tuple(new_vscores), dt._replace(leaf_value=lv)
+
+    return tail
 
 
 def _jit_with_operands(fn, example):
@@ -1657,6 +1714,8 @@ class GBDT:
             for name, val in d.items():
                 obs_tracer.count(name, val, kidx=kidx)
                 total[name] = total.get(name, 0.0) + val
+            total["tree_depth"] = total.get("tree_depth", 0) + tree_depth(
+                *arrs[:3])
         mesh_args = getattr(self.grow, "tree_span_args", None)
         if mesh_args is not None:
             total.update(mesh_args(total.get("splits", 0.0), len(kidxs)))
@@ -1678,39 +1737,10 @@ class GBDT:
         key = len(self.valid_sets)
         if getattr(self, "_tail_cache_key", None) == key:
             return self._tail_cache
-        num_bins, has_nan, fmap = (self.dd.num_bins, self.dd.has_nan,
-                                   self._fmap)
-
-        @jax.jit
-        @obs_phase("score")
-        def tail(ta, leaf_id, score_k, vbins, vscores_k, rate, init_score):
-            is_real = ta.num_leaves > 1
-            if leaf_id is None:
-                # the unpaged stream route: the refresh added this
-                # delta to the comb's scores, by position
-                new_score = None
-            else:
-                delta = jnp.where(
-                    is_real,
-                    rate * leaf_table_lookup(ta.leaf_value, leaf_id), 0.0)
-                new_score = score_k + delta
-            dt = device_tree_from_arrays(ta)
-            new_vscores = []
-            for vb, vsk in zip(vbins, vscores_k):
-                leaf_v = predict_leaf_bins(dt, vb, num_bins, has_nan,
-                                           feat_map=fmap)
-                dv = jnp.where(
-                    is_real, rate * leaf_table_lookup(ta.leaf_value, leaf_v),
-                    0.0)
-                new_vscores.append(vsk + dv)
-            # replay replica: shrunk values (+ boost-from-average bias,
-            # which the host path folds in via add_bias / single_leaf)
-            lv = jnp.where(is_real, ta.leaf_value * rate, 0.0) + init_score
-            return new_score, tuple(new_vscores), dt._replace(leaf_value=lv)
-
-        self._tail_cache = tail
+        self._tail_cache = make_score_tail(self.dd.num_bins,
+                                           self.dd.has_nan, self._fmap)
         self._tail_cache_key = key
-        return tail
+        return self._tail_cache
 
     def _finish_tree_async(self, ta, leaf_id, kidx, init_score):
         """Asynchronous tree finalization: all score updates and the valid
@@ -1729,12 +1759,18 @@ class GBDT:
         with obs_tracer.span("UpdateScore::set", op="slice"):
             score_k = None if lazy else self.train_score[kidx]
             vscores_k = tuple(vs.score[kidx] for vs in self.valid_sets)
-        with obs_tracer.span("UpdateScore::tail"):
-            tail_args = (ta, leaf_id, score_k,
-                         tuple(vs.bins for vs in self.valid_sets),
-                         vscores_k, jnp.float32(rate),
-                         jnp.float32(init_score))
+        with obs_tracer.span("UpdateScore::tail") as tail_span:
+            vbins = tuple(vs.bins for vs in self.valid_sets)
+            tail_args = (ta, leaf_id, score_k, vbins, vscores_k,
+                         jnp.float32(rate), jnp.float32(init_score))
             new_score, new_vscores, dt = tail(*tail_args)
+            if vbins:
+                # the replay's work from shapes: every set walks the
+                # tree's whole node array, one step a node
+                tail_span.set(
+                    valid_sets=len(vbins),
+                    valid_rows=sum(int(b.shape[0]) for b in vbins),
+                    replay_steps=len(vbins) * int(ta.split_feature.shape[-1]))
         obs_tracer.program("score", tail, *tail_args)
         with obs_tracer.span("UpdateScore::set", op="set"):
             if lazy:
@@ -1909,21 +1945,36 @@ class GBDT:
             L=L, alpha=alpha, weighted=weighted)
 
     # ------------------------------------------------------------------
-    def eval(self) -> List[Tuple[str, str, float, bool]]:
+    def eval(self, dataset: Optional[str] = None
+             ) -> List[Tuple[str, str, float, bool]]:
         """[(dataset_name, metric_name, value, higher_better)] like
-        GBDT::OutputMetric.
+        GBDT::OutputMetric; ``dataset`` (``"training"`` or a valid
+        set's name): that data set's metrics alone, no other's computed,
+        as LGBM_BoosterGetEval(data_idx) asks for one.
 
         Rank metrics (AUC/NDCG) evaluate ON DEVICE when possible — the
         host path pulls the full score vector every eval, ~44 MB/iter at
-        Higgs scale with metric_freq=1; the device path pulls scalars."""
-        with obs_tracer.span("Eval"):
-            return self._eval_impl()
+        Higgs scale with metric_freq=1; the device path pulls scalars,
+        all of a call's at one barrier (``Eval::wait``)."""
+        todo = []
+        if self._train_metrics and dataset in (None, "training"):
+            todo.append(("training", self._train_metrics, None))
+        todo += [(vs.name, vs.metrics, vs) for vs in self.valid_sets
+                 if vs.metrics and dataset in (None, vs.name)]
+        with obs_tracer.span("Eval", datasets=len(todo),
+                             metrics=sum(len(t[1]) for t in todo)) as span:
+            return self._eval_impl(todo, span)
 
-    def _eval_impl(self) -> List[Tuple[str, str, float, bool]]:
-        out = []
-
-        def run(metrics, score, n_real, ds_name):
-            k = self.num_tree_per_iteration
+    def _eval_impl(self, todo, span) -> List[Tuple[str, str, float, bool]]:
+        out = []        # a metric's results, or its device value to pull
+        rows = 0
+        k = self.num_tree_per_iteration
+        for ds_name, metrics, vs in todo:
+            # the train score is read only here: on the stream route a
+            # read runs a program (``train_score``)
+            score = self.train_score if vs is None else vs.score
+            n_real = self._n_real if vs is None else None
+            rows += int(metrics[0].num_data)
             if k == 1:
                 dev_ms = [m for m in metrics if hasattr(m, "eval_device")]
             else:
@@ -1938,8 +1989,7 @@ class GBDT:
                     raw_dev = score[0][:m.num_data]
                     if self.average_output:
                         raw_dev = raw_dev / max(self.iter_, 1)
-                    for name, v, hb in m.eval_device(raw_dev):
-                        out.append((ds_name, name, v, hb))
+                    out.append(_eval_dispatch(ds_name, m, raw_dev))
             elif dev_ms:
                 raw_dev = score[:, :dev_ms[0].num_data]
                 if self.average_output:
@@ -1947,20 +1997,21 @@ class GBDT:
                 prob_dev = (self.objective.convert_output(raw_dev)
                             if self.objective is not None else raw_dev)
                 for m in dev_ms:
-                    for name, v, hb in m.eval_device_prob(prob_dev):
-                        out.append((ds_name, name, v, hb))
+                    out.append(_eval_dispatch(ds_name, m, prob_dev))
             if host_ms:
                 prob, raw = self._converted_scores(score, n_real)
                 for m in host_ms:
-                    for name, v, hb in m.eval(prob, raw):
-                        out.append((ds_name, name, v, hb))
-
-        if self._train_metrics:
-            run(self._train_metrics, self.train_score, self._n_real,
-                "training")
-        for vs in self.valid_sets:
-            run(vs.metrics, vs.score, None, vs.name)
-        return out
+                    out.append([(ds_name, name, v, hb)
+                                for name, v, hb in m.eval(prob, raw)])
+        span.set(rows=rows)
+        pending = [p.value for p in out if isinstance(p, _PendingEval)]
+        if pending:
+            span.wait(pending)
+            pulled = iter(jax.device_get(pending))
+            out = [[(p.dataset, name, v, hb) for name, v, hb
+                    in p.metric.device_results(next(pulled))]
+                   if isinstance(p, _PendingEval) else p for p in out]
+        return [r for results in out for r in results]
 
     def _converted_scores(self, score, n_real: Optional[int] = None):
         k = self.num_tree_per_iteration

@@ -154,6 +154,24 @@ def counters_from_tree(num_leaves, left_child, right_child,
                      splits if fused else 0] + miss + [steps], np.float64)
 
 
+def tree_depth(num_leaves, left_child, right_child) -> int:
+    """Internal nodes on the finished tree's longest root-to-leaf path:
+    the steps of a bin-space walk (``ops/predict.predict_leaf_bins``)
+    that a row of its deepest leaf needs; 0 for a stump.  Children are
+    encoded ``~leaf`` and inner nodes by index, from the host arrays."""
+    splits = int(num_leaves) - 1
+    if splits <= 0:
+        return 0
+    children = np.stack([np.asarray(left_child, np.int64)[:splits],
+                         np.asarray(right_child, np.int64)[:splits]], 1)
+    deepest, level, nodes = 0, 1, np.array([0])
+    while len(nodes):
+        deepest = level
+        nxt = children[nodes].reshape(-1)
+        nodes, level = nxt[nxt >= 0], level + 1
+    return deepest
+
+
 class CounterStore:
     """Per-tree counter history + totals (host side, thread-safe)."""
 

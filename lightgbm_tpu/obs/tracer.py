@@ -64,14 +64,23 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
                                         hist_block_rows and hist_lo_n
                                         (the split of a bin:
                                         bin = hi * lo_n + lo), from the
-                                        built program's shapes
+                                        built program's shapes;
+                                        tree_depth, the finished tree's
+                                        deepest leaf (the walk steps a
+                                        row of it needs), from its child
+                                        arrays (``depth`` is every
+                                        span's nesting depth)
           HbmCensus                     phase Tree::grow: under the
                                         running program, so the device
                                         does not wait for the walk
           Tree::grow::wait              the device runs the grow program
           WorkCounters                  pull of the tree's small arrays
         UpdateScore
-          UpdateScore::tail             dispatch of the score/valid tail
+          UpdateScore::tail             dispatch of the score/valid tail;
+                                        with valid sets args valid_sets,
+                                        valid_rows (rows replayed) and
+                                        replay_steps (the walk's trip
+                                        count), summed over the sets
           UpdateScore::set              eager slice + .at[].set (on the
                                         unpaged stream route the valid
                                         sets' alone: the train score is
@@ -80,7 +89,10 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
           UpdateScore::wait             on the tail's outputs
         StallProbe                      every 8th iteration
         FlushPending                    every 32nd iteration
-      Eval                              when a metric is due
+      Eval                              when a metric is due: one data
+                                        set's metrics a call (args
+                                        datasets, metrics, rows)
+        Eval::wait                      the metric programs' scalars
       Callbacks                         cbs_after (a benchmark's pulls)
         TrainScore::materialise         wherever ``GBDT.train_score`` is
                                         read on the unpaged stream route
@@ -149,10 +161,18 @@ ConstructHistograms, FindBestSplits, Split)::
                      un-permute to row order (scatter and its sort)
     lgbm.refresh     stream route: lgbm_refresh (scores, gradients, the
                      next tree's root histogram)
-    lgbm.score       models/gbdt.py: the jitted score tail (train and
-                     valid scores, the replay replica)
+    lgbm.score       models/gbdt.py: the jitted score tail (train
+                     score, the replay replica)
+    lgbm.valid       inside the score tail, each valid set's replay of
+                     the new tree: the bin-space walk
+                     (ops/predict.predict_leaf_bins), the leaf-table
+                     lookup and the add.  A tail without valid sets
+                     has no op here
     lgbm.gradients   models/gbdt.py: the objective's gradient program
                      (not on the stream route, which has none)
+    lgbm.eval        metric/metrics.py: a metric's device program (the
+                     AUC's sort and segment sums, NDCG, the multiclass
+                     logloss and error)
 
 ``phased`` / ``next_phase`` cut a long function at its seams, ``phase``
 scopes a block or decorates a function (below).  An instruction the
@@ -168,7 +188,8 @@ first iteration the tracer is live, each dispatch site hands
 ``tracer.program(name, jitted, *args)`` its program (``grow``: the
 growers in ops/grow.py and parallel/data_parallel.py; ``pull_score``:
 ops/grow.py, its ops ``leafrows``'s; ``score`` and
-``gradients``: models/gbdt.py), which keeps ``{phase: [op_key, ...]}``
+``gradients``: models/gbdt.py; ``eval:<data set>:<metric>``: each
+metric's device program, models/gbdt.py ``eval``), which keeps ``{phase: [op_key, ...]}``
 parsed from the compiled module's text (``program_ops``; ``""`` holds
 the instructions under no phase) - under a span ``Program::table``
 (arg ``program``); nothing is built for it: handed the dispatch's own
@@ -228,7 +249,7 @@ _registered = False
 # under exactly one ``lgbm.<phase>`` of ``jax.named_scope``, which the
 # compiler carries into each instruction's ``metadata={op_name=...}``.
 PHASES = ("root", "hist", "merge", "find", "partition", "glue",
-          "leafrows", "refresh", "score", "gradients")
+          "leafrows", "refresh", "score", "valid", "gradients", "eval")
 PHASE_PREFIX = "lgbm."
 _phase_local = threading.local()
 
@@ -545,7 +566,7 @@ class Tracer:
     def program(self, name: str, jitted, *args) -> None:
         """Keep, for the jitted function an iteration has just
         dispatched as ``name`` (``grow``, ``pull_score``, ``score``,
-        ``gradients``),
+        ``gradients``, ``eval:<data set>:<metric>``),
         the phase of each instruction of its compiled module
         (``program_ops``), to be written into every capture as a
         ``Program::ops`` event.  Once a function, and nothing is

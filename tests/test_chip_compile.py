@@ -883,6 +883,55 @@ def test_the_grow_program_compiles_at_the_epsilon_shape(
         0] * comb[1] * 4 // 4
 
 
+def _score_tail_program(one_chip, valid_sets):
+    """The score tail ``epsilon-valid-train-400k`` dispatches after every
+    tree (255 leaves, 2,000 columns at 64 bins; on the stream route no
+    train score), with that many ``[100,000, 2,000]`` u8 valid sets:
+    (compiled text, its memory analysis)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from lightgbm_tpu.models.gbdt import make_score_tail
+    from lightgbm_tpu.ops.grow import TreeArrays
+    f, n, ni = EPSILON[3], 100_000, LEAVES - 1
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
+    ta = TreeArrays(s((ni,), i32), s((ni,), i32), s((ni,), f32),
+                    s((ni,), b), s((ni,), b), s((ni,), i32), s((ni,), i32),
+                    s((ni,), f32), s((ni,), f32), s((ni,), f32),
+                    s((LEAVES,), f32), s((LEAVES,), f32), s((LEAVES,), f32),
+                    s((), i32), s((1, 1), f32), s((4,), i32))
+    tail = make_score_tail(np.full(f, 64, np.int32), np.zeros(f, bool))
+    compiled = tail.lower(
+        ta, None, None, (s((n, f), jnp.uint8),) * valid_sets,
+        (s((n,), f32),) * valid_sets, s((), f32), s((), f32)).compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+def test_the_score_tail_compiles_with_an_epsilon_valid_set(
+        one_chip, no_compile_cache):
+    """The valid replay at ``epsilon-valid-train-400k``'s shape gets
+    through the v5e compiler: the 254-step walk is one ``while`` under
+    ``lgbm.valid`` that reads the u8 bins in place (no copy of the
+    matrix, temporaries under 1% of it); without a valid set the tail
+    has no op of the phase and the scope's name is not in its text."""
+    import re
+    from lightgbm_tpu.obs.tracer import program_ops
+    bare, _ = _score_tail_program(one_chip, 0)
+    assert "lgbm.valid" not in bare and "valid" not in program_ops(bare)
+    text, mem = _score_tail_program(one_chip, 1)
+    ops = program_ops(text)
+    loops = [k for k in ops["valid"] if k.startswith("while")]
+    assert len(loops) == 1 and "u8[100000,2000]" in loops[0]
+    assert not [k for ph, keys in ops.items() if ph != "valid"
+                for k in keys if k.startswith(("while", "gather"))]
+    assert not re.search(r"\w+\[100000,2000\]", text.replace(
+        "u8[100000,2000]", ""))
+    assert mem.temp_size_in_bytes < 100_000 * 2_000 // 100
+
+
 # Off the default path, refused by the v5e compiler on jax 0.9.0 /
 # libtpu 0.0.34 (PR 22).  serve_traverse: ``sf[gidx]`` gathers a flat
 # VMEM vector by a [BR, T] index array ("Only 2D gather is supported").
