@@ -39,7 +39,7 @@ from ..ops.grow import make_grow_fn
 from ..ops.leaf_lookup import leaf_table_lookup
 from ..ops.predict import (DeviceTree, add_tree_score,
                            device_tree_from_arrays, predict_leaf_bins,
-                           tree_to_device)
+                           replay_block_rows, tree_paths, tree_to_device)
 from ..ops.split import SplitHyperParams
 # module-level bindings (the gbdt purge/reimport convention): each
 # generation's booster must poison/guard/record through ITS OWN
@@ -98,13 +98,14 @@ def make_score_tail(num_bins, has_nan, fmap=None):
                 rate * leaf_table_lookup(ta.leaf_value, leaf_id), 0.0)
             new_score = score_k + delta
         dt = device_tree_from_arrays(ta)
-        new_vscores = []
+        new_vscores, paths = [], None
         for vb, vsk in zip(vbins, vscores_k):
             # a phase of its own inside the tail: with no valid set
             # the program is the one it always was
             with obs_phase("valid"):
+                paths = tree_paths(dt) if paths is None else paths
                 leaf_v = predict_leaf_bins(dt, vb, num_bins, has_nan,
-                                           feat_map=fmap)
+                                           feat_map=fmap, paths=paths)
                 dv = jnp.where(
                     is_real,
                     rate * leaf_table_lookup(ta.leaf_value, leaf_v), 0.0)
@@ -1765,12 +1766,16 @@ class GBDT:
                          jnp.float32(rate), jnp.float32(init_score))
             new_score, new_vscores, dt = tail(*tail_args)
             if vbins:
-                # the replay's work from shapes: every set walks the
-                # tree's whole node array, one step a node
+                # the replay's work from shapes: every row of every set
+                # decides each of the tree's nodes (replay_steps, a
+                # row's decisions), by the decision matrix on u8 bins
+                ni = int(ta.split_feature.shape[-1])
                 tail_span.set(
                     valid_sets=len(vbins),
                     valid_rows=sum(int(b.shape[0]) for b in vbins),
-                    replay_steps=len(vbins) * int(ta.split_feature.shape[-1]))
+                    replay_matmul_rows=sum(int(b.shape[0]) for b in vbins
+                                           if replay_block_rows(b, ni)),
+                    replay_steps=len(vbins) * ni)
         obs_tracer.program("score", tail, *tail_args)
         with obs_tracer.span("UpdateScore::set", op="set"):
             if lazy:

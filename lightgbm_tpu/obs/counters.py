@@ -156,8 +156,9 @@ def counters_from_tree(num_leaves, left_child, right_child,
 
 def tree_depth(num_leaves, left_child, right_child) -> int:
     """Internal nodes on the finished tree's longest root-to-leaf path:
-    the steps of a bin-space walk (``ops/predict.predict_leaf_bins``)
-    that a row of its deepest leaf needs; 0 for a stump.  Children are
+    the node decisions a row of its deepest leaf needs (the bin-space
+    replay, ``ops/predict.predict_leaf_bins``, makes one at every inner
+    node); 0 for a stump.  Children are
     encoded ``~leaf`` and inner nodes by index, from the host arrays."""
     splits = int(num_leaves) - 1
     if splits <= 0:

@@ -78,9 +78,13 @@ the names the per-layer metrics of ``benchmarks/`` are keyed on)::
         UpdateScore
           UpdateScore::tail             dispatch of the score/valid tail;
                                         with valid sets args valid_sets,
-                                        valid_rows (rows replayed) and
-                                        replay_steps (the walk's trip
-                                        count), summed over the sets
+                                        valid_rows (rows replayed),
+                                        replay_matmul_rows (of them, the
+                                        rows the decision-matrix route
+                                        replayed: u8 bins) and
+                                        replay_steps (node decisions a
+                                        row: the tree's inner nodes),
+                                        summed over the sets
           UpdateScore::set              eager slice + .at[].set (on the
                                         unpaged stream route the valid
                                         sets' alone: the train score is
@@ -164,10 +168,12 @@ ConstructHistograms, FindBestSplits, Split)::
     lgbm.score       models/gbdt.py: the jitted score tail (train
                      score, the replay replica)
     lgbm.valid       inside the score tail, each valid set's replay of
-                     the new tree: the bin-space walk
-                     (ops/predict.predict_leaf_bins), the leaf-table
-                     lookup and the add.  A tail without valid sets
-                     has no op here
+                     the new tree (ops/predict.predict_leaf_bins: the
+                     tree's path matrix, built once a tail, and a
+                     block of rows a trip, its node columns and leaves
+                     by two matmuls; the lock-step walk on i32 bins),
+                     the leaf-table lookup and the add.  A tail
+                     without valid sets has no op here
     lgbm.gradients   models/gbdt.py: the objective's gradient program
                      (not on the stream route, which has none)
     lgbm.eval        metric/metrics.py: a metric's device program (the

@@ -2,10 +2,27 @@
 
 Reference analog: Tree::Predict / NumericalDecisionInner walks
 (include/LightGBM/tree.h:133,360) and the CUDA score updater's leaf-indexed
-AddScore (src/boosting/cuda/cuda_score_updater.cu).  On TPU the walk is a
-``fori_loop`` over depth with all rows advanced in lock-step (vectorised
-node-pointer chasing: one dynamic gather per level); leaves encode as
-negative node ids so finished rows simply stop moving.
+AddScore (src/boosting/cuda/cuda_score_updater.cu).
+
+Binned rows take one of two routes in :func:`predict_leaf_bins`, by the
+bins' dtype.  **u8 bins: the decision-matrix replay.**  Rows go in blocks
+of B (:func:`replay_block_rows`: the largest multiple of 256 whose
+temporaries, with the tree's path matrix, fit ``REPLAY_BYTES``; a static
+ragged block closes).  For a block, one matmul of its bins (bf16) by the
+one-hot of each inner node's physical column reads every node's column
+for every row; :func:`_goes_left` decides every node at once; one matmul
+of the +1 / -1 decisions by the tree's path matrix (:func:`tree_paths`)
+counts, for each leaf, the nodes on its path the row agrees with, and the
+row's leaf is the one whose count is its path length.  Both matmuls are
+exact: a bin <= 255 and +-1 are exact in bfloat16 and the sums are
+integers in f32.  The work is a row's decisions at every node, whatever
+the tree's depth, on the MXU with no per-row gather (one XLA fusion a
+block on the v5e).  **i32 bins** (past 256 levels) and a tree whose path
+matrix leaves no room in ``REPLAY_BYTES`` for 256 rows: the lock-step
+walk (:func:`_walk_leaves`), a ``fori_loop`` of ``split_feature.shape[0]``
+steps with every row advanced one node a step (a per-row gather of its
+column and node fields; leaves encode as negative node ids so finished
+rows stop moving).  Both routes decide by one rule, :func:`_goes_left`.
 
 Used for: validation-set score updates each iteration, DART's
 add/subtract-tree score manipulation, and batch prediction of binned data.
@@ -80,6 +97,171 @@ def device_tree_from_arrays(ta) -> DeviceTree:
     )
 
 
+class _Split(NamedTuple):
+    """Inner nodes' splits as the bin-space rule reads them; each field
+    broadcasts against the stored column values it decides."""
+    threshold: jnp.ndarray
+    default_left: jnp.ndarray
+    categorical: jnp.ndarray
+    num_bins: jnp.ndarray        # of the node's feature
+    has_nan: jnp.ndarray
+    offset: jnp.ndarray = None   # EFB: the feature's first bin in its
+    default_bin: jnp.ndarray = None  # bundle column, and its default bin
+
+
+def _splits_at(tree: DeviceTree, nd, num_bins, has_nan, feat_map):
+    """(physical column, :class:`_Split`) of the nodes ``nd`` (an index
+    array, or ``slice(None)`` for every node)."""
+    feat = tree.split_feature[nd]
+    phys, off, dflt = feat, None, None
+    if feat_map is not None:
+        fp_, fo_, fd_ = feat_map
+        phys, off, dflt = fp_[feat], fo_[feat], fd_[feat]
+    return phys, _Split(tree.threshold_bin[nd], tree.default_left[nd],
+                        tree.is_categorical[nd], num_bins[feat],
+                        has_nan[feat], off, dflt)
+
+
+def _goes_left(col, s: _Split, word_of):
+    """NumericalDecisionInner / CategoricalDecisionInner in bin space:
+    does a row whose split column stores ``col`` (i32) go left.  Under
+    EFB the column is a bundle's and is mapped back to the feature's own
+    bin space first (rows outside its stacked range -> its default bin).
+    ``word_of(b)``: the node's bitset word that holds bin ``b`` (bit
+    ``b % 32``), or None where every categorical split is one-hot."""
+    b = col
+    if s.offset is not None:
+        inr = (col >= s.offset) & (col < s.offset + s.num_bins)
+        b = jnp.where(inr, col - s.offset, s.default_bin)
+    at_nan = s.has_nan & (b == s.num_bins - 1)
+    if word_of is None:
+        cat_go = b == s.threshold
+    else:
+        cat_go = ((word_of(b) >> (b % 32)) & 1) > 0
+    return jnp.where(s.categorical, cat_go,
+                     ((b <= s.threshold) & ~at_nan)
+                     | (at_nan & s.default_left))
+
+
+# the decision-matrix replay's working set: the path matrix, the column
+# one-hot and one block of rows' temporaries (module docstring)
+REPLAY_BYTES = 32 << 20
+_BLOCK_ALIGN = 256
+
+
+def replay_block_rows(bins, ni: int) -> int:
+    """Rows a block of the decision-matrix replay of ``bins`` ([n,
+    F_phys]) under a tree of ``ni`` inner nodes holds, from shapes alone;
+    0 where the lock-step walk replays instead: bins past 256 levels
+    (i32: not exact in bfloat16), no inner node, or a tree whose path
+    matrix leaves no room for a block of ``_BLOCK_ALIGN`` rows."""
+    n, f = bins.shape
+    if bins.dtype != jnp.uint8 or ni == 0:
+        return 0
+    nl = ni + 1
+    fixed = ni * nl * 6 + f * ni * 2      # i32 build + bf16 P, bf16 S
+    # a row: its u8 block and bf16 copy, the f32 columns and bf16 signs,
+    # the f32 path sums and their compare
+    per_row = 3 * f + 6 * ni + 8 * nl
+    rows = (REPLAY_BYTES - fixed) // per_row // _BLOCK_ALIGN * _BLOCK_ALIGN
+    if rows < _BLOCK_ALIGN:
+        return 0
+    return min(rows, n)
+
+
+def tree_paths(tree: DeviceTree):
+    """The tree's path matrix and path lengths, from its child arrays:
+    ``paths[j, l]`` (bf16 [ni, ni + 1]) is +1 where leaf ``l`` lies
+    under node ``j``'s left child, -1 under its right, 0 elsewhere (and
+    on every node past ``num_leaves - 1``); ``path_len[l]`` (f32) is the
+    number of nodes over leaf ``l``, -1 for a leaf past ``num_leaves``.
+    A row whose decisions are ``s[j]`` = +1 (left) / -1 reaches leaf
+    ``l`` iff ``sum_j s[j] paths[j, l] == path_len[l]``: exactly one
+    leaf, and a stump's leaf 0 (length 0) takes every row.  Node-sized
+    work: a ``while`` that climbs from every leaf at once, the tree's
+    depth in trips."""
+    ni = tree.left_child.shape[0]
+    nl = ni + 1
+    nodes = jnp.arange(ni, dtype=jnp.int32)
+    real = nodes < tree.num_leaves - 1
+    # ``ni`` is neither a node nor a leaf code: a node past the tree's
+    # points at nothing
+    lc = jnp.where(real, tree.left_child, ni)
+    rc = jnp.where(real, tree.right_child, ni)
+
+    def parent_of(codes):
+        is_l = lc[:, None] == codes[None, :]
+        is_r = rc[:, None] == codes[None, :]
+        up = jnp.max(jnp.where(is_l | is_r, nodes[:, None], -1), axis=0)
+        return up, jnp.where(jnp.any(is_l, axis=0), 1, -1)
+
+    node_up, node_side = parent_of(nodes)
+    leaf_up, leaf_side = parent_of(~jnp.arange(nl, dtype=jnp.int32))
+
+    def climb(carry):
+        p, cur, side, depth = carry
+        p = p + jnp.where(nodes[:, None] == cur[None, :], side[None, :], 0)
+        at = jnp.maximum(cur, 0)
+        up = jnp.where(cur >= 0, node_up[at], -1)
+        return p, up, node_side[at], depth + (cur >= 0)
+
+    p, _, _, depth = jax.lax.while_loop(
+        lambda c: jnp.any(c[1] >= 0), climb,
+        (jnp.zeros((ni, nl), jnp.int32), leaf_up, leaf_side,
+         jnp.zeros(nl, jnp.int32)))
+    path_len = jnp.where(jnp.arange(nl) < tree.num_leaves, depth, -1)
+    return p.astype(jnp.bfloat16), path_len.astype(jnp.float32)
+
+
+def _matmul_leaves(tree, bins, num_bins, has_nan, feat_map, paths,
+                   block):
+    """The decision-matrix replay: rows in blocks of ``block``, each
+    block's node columns read by one matmul against the nodes' column
+    one-hot, every node decided at once by :func:`_goes_left`, the leaf
+    found by one matmul against the path matrix."""
+    n, f = bins.shape
+    ni = tree.split_feature.shape[0]
+    w = tree.cat_words.shape[1]
+    p, path_len = tree_paths(tree) if paths is None else paths
+    phys, s = _splits_at(tree, slice(None), num_bins, has_nan, feat_map)
+    s = jax.tree.map(lambda a: a[None, :], s)
+    onehot = (jnp.arange(f, dtype=jnp.int32)[:, None]
+              == phys[None, :]).astype(jnp.bfloat16)      # [F_phys, ni]
+    leaf_ids = jnp.arange(ni + 1, dtype=jnp.int32)
+
+    def word_of(b):
+        # the node's word by a select over its W words, not a gather
+        q = b // 32
+        word = jnp.zeros_like(b)
+        for k in range(w):
+            word = jnp.where(q == k, tree.cat_words[None, :, k], word)
+        return word
+
+    def leaves(blk):
+        # a bin <= 255 times 1.0 is exact in bf16 x bf16 -> f32
+        col = jnp.dot(blk.astype(jnp.bfloat16), onehot,
+                      preferred_element_type=jnp.float32)
+        go = _goes_left(col.astype(jnp.int32), s,
+                        word_of if w > 0 else None)
+        hits = jnp.dot(jnp.where(go, 1.0, -1.0).astype(jnp.bfloat16), p,
+                       preferred_element_type=jnp.float32)
+        return jnp.max(jnp.where(hits == path_len[None, :],
+                                 leaf_ids[None, :], -1), axis=1)
+
+    whole = n // block
+    out = jnp.zeros(n, jnp.int32)
+    if whole:
+        def body(i, out):
+            at = i * block
+            blk = jax.lax.dynamic_slice_in_dim(bins, at, block)
+            return jax.lax.dynamic_update_slice_in_dim(out, leaves(blk),
+                                                       at, 0)
+        out = jax.lax.fori_loop(0, whole, body, out)
+    if n > whole * block:
+        out = out.at[whole * block:].set(leaves(bins[whole * block:]))
+    return out
+
+
 @jax.jit
 def predict_leaf_bins(
     tree: DeviceTree,
@@ -87,47 +269,43 @@ def predict_leaf_bins(
     num_bins: jnp.ndarray,   # [F_log] i32
     has_nan: jnp.ndarray,    # [F_log] bool
     feat_map=None,           # EFB: (feat_phys, feat_offset, feat_default)
+    paths=None,              # tree_paths(tree), where a caller shares it
 ) -> jnp.ndarray:
-    """Rows -> leaf index, walking in bin space (NumericalDecisionInner).
+    """Rows -> leaf index, in bin space (NumericalDecisionInner).
 
-    With ``feat_map`` set (EFB device layout), tree features are logical
-    and the walk reads the bundle column, mapping back to the feature's
-    own bin space (rows outside its stacked range -> its default bin)."""
+    u8 bins take the decision-matrix replay (module docstring); i32
+    bins and trees whose path matrix outgrows ``REPLAY_BYTES`` take the
+    lock-step walk, one node a step.  With ``feat_map`` set (EFB device
+    layout), tree features are logical and the split reads the bundle
+    column, mapping back to the feature's own bin space."""
+    block = replay_block_rows(bins, tree.split_feature.shape[0])
+    if block:
+        return _matmul_leaves(tree, bins, num_bins, has_nan, feat_map,
+                              paths, block)
+    return _walk_leaves(tree, bins, num_bins, has_nan, feat_map)
+
+
+def _walk_leaves(tree, bins, num_bins, has_nan, feat_map=None):
+    """The lock-step walk: every row advanced one node a step, a per-row
+    gather of its split column and of each node field, for
+    ``split_feature.shape[0]`` steps whatever the tree's depth; leaves
+    encode as negative node ids, so a finished row stops moving."""
     n = bins.shape[0]
     max_steps = tree.split_feature.shape[0]  # depth <= num internal nodes
+    w = tree.cat_words.shape[1]
 
     def body(_, node):
         active = node >= 0
         nd = jnp.maximum(node, 0)
-        feat = tree.split_feature[nd]
+        phys, s = _splits_at(tree, nd, num_bins, has_nan, feat_map)
         # per-row feature gather
-        if feat_map is not None:
-            fp_, fo_, fd_ = feat_map
-            colp = jnp.take_along_axis(
-                bins, fp_[feat][:, None].astype(jnp.int32),
-                axis=1)[:, 0].astype(jnp.int32)
-            off_ = fo_[feat]
-            inr = (colp >= off_) & (colp < off_ + num_bins[feat])
-            b = jnp.where(inr, colp - off_, fd_[feat])
-        else:
-            b = jnp.take_along_axis(
-                bins, feat[:, None].astype(jnp.int32),
-                axis=1)[:, 0].astype(jnp.int32)
-        tb = tree.threshold_bin[nd]
-        dl = tree.default_left[nd]
-        cat = tree.is_categorical[nd]
-        nanb = num_bins[feat] - 1
-        at_nan = has_nan[feat] & (b == nanb)
-        if tree.cat_words.shape[1] > 0:
-            # bitset membership walk (Tree::CategoricalDecision)
-            w = tree.cat_words.shape[1]
-            word = jnp.take(tree.cat_words.reshape(-1),
-                            nd * w + (b // 32))
-            cat_go = ((word >> (b % 32)) & 1) > 0
-        else:
-            cat_go = b == tb
-        go_left = jnp.where(cat, cat_go,
-                            ((b <= tb) & ~at_nan) | (at_nan & dl))
+        col = jnp.take_along_axis(
+            bins, phys[:, None].astype(jnp.int32),
+            axis=1)[:, 0].astype(jnp.int32)
+        # bitset membership walk (Tree::CategoricalDecision)
+        go_left = _goes_left(col, s, None if w == 0 else (
+            lambda b: jnp.take(tree.cat_words.reshape(-1),
+                               nd * w + (b // 32))))
         nxt = jnp.where(go_left, tree.left_child[nd], tree.right_child[nd])
         return jnp.where(active, nxt, node)
 

@@ -913,23 +913,43 @@ def _score_tail_program(one_chip, valid_sets):
 def test_the_score_tail_compiles_with_an_epsilon_valid_set(
         one_chip, no_compile_cache):
     """The valid replay at ``epsilon-valid-train-400k``'s shape gets
-    through the v5e compiler: the 254-step walk is one ``while`` under
-    ``lgbm.valid`` that reads the u8 bins in place (no copy of the
-    matrix, temporaries under 1% of it); without a valid set the tail
-    has no op of the phase and the scope's name is not in its text."""
+    through the v5e compiler as the decision-matrix replay: under
+    ``lgbm.valid`` one ``while`` over the u8 bins moves a block of B
+    rows a trip (n // B trips, not one a node), its node-column and
+    path matmuls are there at [B, 254] and [B, 255], no gather reads a
+    row, nothing copies the matrix and the temporaries stay under 64
+    MiB; without a valid set the tail has no op of the phase and the
+    scope's name is not in its text."""
     import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
     from lightgbm_tpu.obs.tracer import program_ops
+    from lightgbm_tpu.ops.predict import replay_block_rows
     bare, _ = _score_tail_program(one_chip, 0)
     assert "lgbm.valid" not in bare and "valid" not in program_ops(bare)
     text, mem = _score_tail_program(one_chip, 1)
     ops = program_ops(text)
-    loops = [k for k in ops["valid"] if k.startswith("while")]
-    assert len(loops) == 1 and "u8[100000,2000]" in loops[0]
+    block = replay_block_rows(
+        jax.ShapeDtypeStruct((100_000, 2_000), jnp.uint8), LEAVES - 1)
+    assert 0 < block < 100_000
+    loops = [k for k in ops["valid"] if k.startswith("while")
+             and "u8[100000,2000]" in k]
+    assert len(loops) == 1
+    assert f"s32[{block}]" in " ".join(ops["valid"])  # a block's leaves
+    dots = {m for line in text.splitlines() if "lgbm.valid" in line
+            for m in re.findall(r"= (f32\[\d+,\d+\])\S* convolution\(",
+                                line)}
+    assert {f"f32[{block},254]", f"f32[{block},255]"} <= dots
+    gathered = [int(np.prod([int(d) for d in dims.split(",")]))
+                for dims in re.findall(r"= \w+\[([\d,]+)\]\S* gather\(", text)]
+    assert max(gathered, default=0) <= LEAVES     # node tables only
     assert not [k for ph, keys in ops.items() if ph != "valid"
                 for k in keys if k.startswith(("while", "gather"))]
     assert not re.search(r"\w+\[100000,2000\]", text.replace(
         "u8[100000,2000]", ""))
-    assert mem.temp_size_in_bytes < 100_000 * 2_000 // 100
+    assert mem.temp_size_in_bytes <= 64 << 20
 
 
 # Off the default path, refused by the v5e compiler on jax 0.9.0 /

@@ -27,6 +27,7 @@ from lightgbm_tpu.models.gbdt import make_score_tail  # noqa: E402
 from lightgbm_tpu.obs import tracer  # noqa: E402
 from lightgbm_tpu.obs.counters import tree_depth  # noqa: E402
 from lightgbm_tpu.obs.tracer import program_ops  # noqa: E402
+from lightgbm_tpu.ops import predict  # noqa: E402
 
 ROWS, COLS, LEVELS, TREES, LEAVES = 4096, 48, 12, 5, 15
 PARAMS = {"objective": "binary", "num_leaves": LEAVES, "max_bin": 63,
@@ -204,6 +205,7 @@ def test_the_spans_count_the_replay_and_the_tree_depth(two_sets):
     assert len(tails) == len(grows) == TREES
     rows = sum(len(v[0]) for v in valid.values())
     assert all(a["valid_sets"] == 2 and a["valid_rows"] == rows
+               and a["replay_matmul_rows"] == rows
                and a["replay_steps"] == 2 * (LEAVES - 1) for a in tails)
     text = bst.model_to_string()
     depths = []
@@ -215,6 +217,101 @@ def test_the_spans_count_the_replay_and_the_tree_depth(two_sets):
     evals = [e["args"] for e in events if e["name"] == "Eval"]
     assert {(a["datasets"], a["metrics"]) for a in evals} == {(1, 2)}
     assert sum(e["name"] == "Eval::wait" for e in events) == len(evals)
+
+
+def _random_tree(rng, leaves, ni, features, bins, *, words=0, cat=0.0,
+                 chain=False):
+    """A bin-space tree grown leaf-wise as the grower numbers it (node j
+    splits a leaf: its left child keeps the leaf's id, its right child is
+    leaf j + 1), on arrays padded to ``ni`` nodes past ``leaves - 1``
+    with what the grower leaves there (zeros).  ``chain``: every split
+    takes the newest leaf and almost every row goes right, so rows reach
+    the bottom of a ``leaves - 1`` deep path."""
+    lc, rc = np.zeros(ni, np.int32), np.zeros(ni, np.int32)
+    home = {0: None}
+    for j in range(leaves - 1):
+        leaf = j if chain else int(rng.integers(0, j + 1))
+        if home[leaf] is not None:
+            (lc, rc)[home[leaf][1]][home[leaf][0]] = j
+        lc[j], rc[j] = ~leaf, ~(j + 1)
+        home[leaf], home[j + 1] = (j, 0), (j, 1)
+    real = np.arange(ni) < leaves - 1
+    sf = np.where(real, rng.integers(0, features, ni), 0)
+    tb = np.where(real, rng.integers(0, 2 if chain else bins, ni), 0)
+    return predict.DeviceTree(
+        split_feature=jnp.asarray(sf, jnp.int32),
+        threshold_bin=jnp.asarray(tb, jnp.int32),
+        default_left=jnp.asarray(real & (rng.random(ni) < 0.5)),
+        is_categorical=jnp.asarray(real & (rng.random(ni) < cat)),
+        left_child=jnp.asarray(lc), right_child=jnp.asarray(rc),
+        leaf_value=jnp.zeros(ni + 1, jnp.float32),
+        num_leaves=jnp.int32(leaves),
+        cat_words=jnp.asarray(rng.integers(-2**31, 2**31, (ni, words)),
+                              jnp.int32))
+
+
+# (leaves, padded inner nodes, rows, words, categorical share, chain,
+#  EFB, block: 0 = the one predict_leaf_bins takes)
+_REPLAY_CASES = {
+    "nan_default_left": (31, 30, 700, 0, 0.0, False, False, 0),
+    "categorical_bitsets": (31, 30, 700, 2, 0.5, False, False, 0),
+    "one_hot_categoricals": (31, 30, 700, 0, 0.5, False, False, 0),
+    "efb_feat_map": (31, 30, 700, 0, 0.3, False, True, 0),
+    "stump": (1, 30, 700, 0, 0.0, False, False, 0),
+    "fewer_leaves_than_padded": (9, 30, 700, 2, 0.3, False, False, 0),
+    "chain_254_deep": (255, 254, 700, 0, 0.0, True, False, 0),
+    "rows_not_a_multiple_of_the_block": (31, 30, 700, 2, 0.3, False,
+                                         False, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_REPLAY_CASES))
+def test_the_decision_matrix_replay_finds_the_walks_leaf_on_every_row(case):
+    """Every row's leaf from the u8 route (two matmuls a block of rows)
+    is the lock-step walk's.  Bins are drawn over every level, the NaN
+    bin of the features that have one included, so both default
+    directions, bitset words and one-hot equalities are hit."""
+    leaves, ni, n, words, cat, chain, efb, block = _REPLAY_CASES[case]
+    rng = np.random.default_rng(sorted(_REPLAY_CASES).index(case))
+    levels = 64
+    if efb:
+        # 12 features of 8 bins, three a bundle column from bin 1 on
+        fl, levels = 12, 8
+        fmap = (jnp.arange(fl, dtype=jnp.int32) // 3,
+                1 + 8 * (jnp.arange(fl, dtype=jnp.int32) % 3),
+                jnp.asarray(rng.integers(0, 8, fl), jnp.int32))
+        bins = rng.integers(0, 25, (n, 4))
+    else:
+        fl, fmap = 12, None
+        bins = rng.integers(0, levels, (n, fl))
+    num_bins = jnp.full(fl, levels, jnp.int32)
+    has_nan = jnp.asarray(rng.random(fl) < 0.5)
+    tree = _random_tree(rng, leaves, ni, fl, levels, words=words, cat=cat,
+                        chain=chain)
+    bins = jnp.asarray(bins, jnp.uint8)
+    walk_tree = tree
+    if leaves == 1:
+        # a grown stump's zero children hold the walk at node 0 (leaf -1,
+        # which the tail masks); its one leaf is the walk of the stump as
+        # tree_to_device builds it, with no node
+        walk_tree = tree._replace(split_feature=tree.split_feature[:0],
+                                  cat_words=tree.cat_words[:0])
+    walked = jax.jit(predict._walk_leaves)(walk_tree, bins, num_bins,
+                                           has_nan, fmap)
+    if block:
+        got = jax.jit(predict._matmul_leaves, static_argnums=6)(
+            tree, bins, num_bins, has_nan, fmap, None, block)
+    else:
+        assert predict.replay_block_rows(bins, ni) == n
+        got = predict.predict_leaf_bins(tree, bins, num_bins, has_nan,
+                                        feat_map=fmap)
+    walked, got = np.asarray(walked), np.asarray(got)
+    assert walked.min() >= 0 and walked.max() < leaves
+    np.testing.assert_array_equal(got, walked)
+    if chain:
+        assert walked.max() == leaves - 1       # some row went all the way
+    # the walk is what i32 bins (past 256 levels) still take
+    assert predict.replay_block_rows(bins.astype(jnp.int32), ni) == 0
 
 
 def test_tree_depth_counts_the_inner_nodes_of_the_longest_path():
